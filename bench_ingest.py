@@ -326,8 +326,8 @@ def _bench_shard_scaling(args, tmp: str) -> None:
     threads hammer ``insert_raw_rows`` (pre-built rows, minimal python
     per batch, so the per-shard lock + WAL commit is the visible cost)
     against 1..K shard files.  The REST path is deliberately excluded:
-    round 4 measured per-request HTTP+JSON under the GIL as its wall
-    (SERVING_BENCH.md), and sharding the store cannot amortize that
+    per-request HTTP+JSON under the GIL is its wall (CPU builder
+    measurement), and sharding the store cannot amortize that
     from below.  On a single-core host thread-scaling is GIL-bound —
     the ``nproc`` field rides every line so a flat curve reads as the
     environment, not the design."""

@@ -21,7 +21,7 @@ additionally reports the SERVER's own histogram view
 (``server_p50_ms`` from the deployed engine's status JSON).
 
 Usage: python bench_serving.py [--items 100000] [--rank 64] [--n 200]
-       [--threads 16] [--platform cpu]
+       [--threads 16]
        [--tenants N] [--shared-batcher on|off] [--microbatch-max 64]
 
 The ``--tenants N`` sweep serves N co-resident tenants through the
@@ -141,20 +141,12 @@ def main() -> None:
                     "with its per-role CPU split + dominant stacks "
                     "(the server runs in this process, so the split "
                     "is the exact server-side attribution)")
-    ap.add_argument("--platform")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args()
     if args.http and args.threads <= 0:
         ap.error("--http requires --threads N")
 
-    if args.platform:
-        import os
-
-        os.environ["JAX_PLATFORMS"] = args.platform
     import jax
-
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
 
     from predictionio_tpu.storage.bimap import StringIndex
     from predictionio_tpu.templates.recommendation import (
@@ -236,7 +228,7 @@ def main() -> None:
         bench_gate.write_pr_summary(
             {
                 **serving_rec,
-                "platform": args.platform or jax.default_backend(),
+                "platform": jax.default_backend(),
                 "scale": None,
                 "items": args.items,
                 "rank": args.rank,
@@ -696,7 +688,7 @@ def _bench_sweep(args, model, rng) -> None:
             for s in SERVE_SEGMENTS
         }
 
-    platform = args.platform or jax.default_backend()
+    platform = jax.default_backend()
     points = []
     for c in points_c:
         before = seg_snapshot()

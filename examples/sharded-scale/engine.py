@@ -11,9 +11,9 @@ both the factor matrices AND the rating blocks across the cluster
 * the rating COO is co-partitioned with the bucket rows each device
   solves (`models/als._plan_shard_layout`) so DATA capacity scales with
   total HBM too, and the int32-offset ceiling applies per shard,
-* ``solver="fused"`` additionally runs each side's
-  gather+Gram+solve as one VMEM-resident Pallas kernel where a tile
-  plan exists (compile-probed; degrades to XLA automatically).
+* ``solver="fused"`` additionally runs each bucket's
+  gather+Gram+solve as one Pallas kernel where a tile plan exists
+  (a kernel that does not compile fails the train).
 
 Multi-host, the same layout extends across processes (datasource
 ``coo: "local"`` + `ALSTrainer.distributed`): rating triples travel
@@ -73,7 +73,7 @@ def main() -> None:
         f"{L:,} (~1/{mesh.size} + padding) in sharded placement vs "
         f"{len(v):,} replicated"
     )
-    print(f"resolved solver: {sharded.solver!r} (compile-probed)")
+    print(f"solver: {sharded.cfg.solver!r}")
 
     f_rep = replicated.train()
     f_sh = sharded.train()

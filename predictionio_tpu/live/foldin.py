@@ -33,7 +33,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from ..models.als import ALSConfig, _resolve_solver, _solve_buckets
+from ..models.als import ALSConfig, _solve_buckets
 from ..obs import xray
 from ..ops.topk import pow2_ceil
 from .watermark import ScanBatch
@@ -97,21 +97,16 @@ class FoldInSolver:
     """Fixed-capacity row solver over a frozen opposite table.
 
     One instance per daemon/session: it owns the jitted kernel (so the
-    xray signature history is per-process coherent) and the resolved
-    solver backend (compile-probed once, like ``ALSTrainer``).
+    xray signature history is per-process coherent) and the solver
+    backend its rows are solved with.
     """
 
     def __init__(self, cfg: ALSConfig, max_k: int = _MAX_K):
         self.cfg = cfg
         self.max_k = max_k
-        solver, _ = _resolve_solver(
-            cfg if cfg.solver != "fused"
-            # the fused kernel is a whole-table training pass; fold-in
-            # solves a handful of rows — route its config to the plain
-            # solver probe instead
-            else ALSConfig(rank=cfg.rank, solver="xla")
-        )
-        self.solver = "xla" if solver == "fused" else solver
+        # the fused kernel is a whole-table training pass; fold-in
+        # solves a handful of rows with the plain solver instead
+        self.solver = "xla" if cfg.solver == "fused" else cfg.solver
         self._kernel = _jit_foldin()
 
     def padded_shape(

@@ -50,7 +50,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import TRAIN_PHASE_SECONDS, tower, xray
-from ..parallel.mesh import DATA_AXIS, fence, pad_to_multiple, replicated
+from ..parallel.mesh import DATA_AXIS, pad_to_multiple, replicated
 from ..storage.columnar import Ratings
 
 logger = logging.getLogger(__name__)
@@ -101,16 +101,11 @@ class ALSConfig:
     matmul_precision: str = "highest"
     # batched SPD solver: "xla" (lax.linalg), "pallas" (ops/solve.py
     # Gauss-Jordan kernel for the solves alone), or "fused"
-    # (ops/fused_als.py single-pass gather+Gram+solve kernel on sides
-    # whose opposite table fits VMEM; other sides fall back to xla)
+    # (ops/fused_als.py single-pass gather+Gram+solve kernel; buckets
+    # too wide for its SMEM index block keep the xla path).  A kernel
+    # the backend's compiler rejects fails the first half-iteration
+    # with the compiler's message; nothing is substituted for it
     solver: str = "xla"
-    # in-kernel gather form of the fused kernel (solver="fused" only):
-    # "taa" = same-shape take_along_axis(axis=0) sub-gathers (Mosaic
-    # tpu.dynamic_gather), "dma" = scalar-prefetched rolling-window
-    # async row copies, "auto" = per-backend compile-and-run probe
-    # (ops/fused_als.resolve_gather_impl; docs/PERF_PLAN.md §4).  The
-    # resolved value lands in bench artifacts as fused_gather_resolved.
-    fused_gather: str = "auto"
     # rank-sweep strategy: "full" solves the complete R×R normal
     # equations per row (today's behavior, the default); "subspace"
     # (iALS++, arXiv 2110.14044) sweeps the rank dimension in blocks of
@@ -180,18 +175,14 @@ class ALSConfig:
                 f"solver must be 'xla', 'pallas' or 'fused', "
                 f"got {self.solver!r}"
             )
-        if self.fused_gather not in ("auto", "taa", "dma"):
+        if self.gather_dtype == "bfloat16" and self.solver == "fused":
+            # the kernel fetches table rows by one-row DMA, which Mosaic
+            # only aligns for 32-bit rows (v5e: "Slice shape along
+            # dimension 0 must be aligned to tiling (8), but is 1")
             raise ValueError(
-                f"fused_gather must be 'auto', 'taa' or 'dma', "
-                f"got {self.fused_gather!r}"
-            )
-        if self.fused_gather != "auto" and self.solver != "fused":
-            # an explicit gather form with a non-fused solver would be
-            # silently ignored — the same foot-gun class as the other
-            # exact-equality knobs above
-            raise ValueError(
-                f"fused_gather={self.fused_gather!r} only applies to "
-                "solver='fused'"
+                "gather_dtype='bfloat16' does not compose with "
+                "solver='fused' (the kernel's row DMAs need a float32 "
+                "table)"
             )
         if self.solver_mode not in ("full", "subspace"):
             raise ValueError(
@@ -534,21 +525,46 @@ def _device_expand_sides(col_by_row, val_by_row, row_counts, val_scale):
 # --------------------------------------------------------------------------
 
 
-def _spd_solve(A: jax.Array, b: jax.Array, solver: str) -> jax.Array:
+# a bucket's batch dim, sharded over the mesh's data axis
+_BATCH = P(DATA_AXIS)
+
+
+def _per_device(fn, mesh: Optional[Mesh], in_specs, out_specs):
+    """``fn`` run by every device of ``mesh`` on its own slice of the
+    data-sharded batch; ``mesh=None`` (one device, or already inside a
+    ``shard_map`` body) is ``fn`` itself.
+
+    XLA partitions the replicated-placement half-iteration from its
+    input shardings, but it cannot partition a Pallas kernel ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call in
+    a shard_map" — the 2x2 v5e host, PR 21), so the kernels are handed
+    their shards explicitly.
+    """
+    if mesh is None:
+        return fn
+    from ..parallel.collectives import shard_map
+
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+
+
+def _spd_solve(A: jax.Array, b: jax.Array, solver: str,
+               mesh: Optional[Mesh] = None) -> jax.Array:
     """Batched SPD solve ``A[i] x[i] = b[i]`` via the configured backend.
 
     One routing point for BOTH the full R×R systems and the subspace
     mode's B×B subsystems: ``"pallas"`` runs the Gauss-Jordan kernel
     (`ops/solve.py` — smaller systems pack more rows per VMEM tile, so
     the kernel gets FASTER per system as B shrinks), anything else the
-    XLA Cholesky + two triangular solves.
+    XLA Cholesky + two triangular solves.  ``mesh``: see
+    :func:`_per_device`.
     """
     if solver == "pallas":
         from ..ops.solve import cholesky_solve_batched
 
-        return cholesky_solve_batched(
-            A.astype(jnp.float32), b.astype(jnp.float32)
-        )
+        return _per_device(
+            cholesky_solve_batched, mesh, in_specs=(_BATCH, _BATCH),
+            out_specs=_BATCH,
+        )(A.astype(jnp.float32), b.astype(jnp.float32))
     L = jax.lax.linalg.cholesky(A)
     y = jax.lax.linalg.triangular_solve(
         L, b[..., None], left_side=True, lower=True
@@ -576,7 +592,7 @@ def _half_iteration_impl(
     gather_mode: str = "row",
     solver_mode: str = "full",
     subspace_size: int = 0,
-    fused_gather: str = "taa",
+    mesh: Optional[Mesh] = None,
 ) -> jax.Array:
     def write(acc, rows, x):
         acc = upd if acc is None else acc
@@ -590,8 +606,7 @@ def _half_iteration_impl(
         ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
         precision=precision, solver=solver, gather_dtype=gather_dtype,
         gather_mode=gather_mode, solver_mode=solver_mode,
-        subspace_size=subspace_size, fused_gather=fused_gather,
-        upd_table=upd,
+        subspace_size=subspace_size, upd_table=upd, mesh=mesh,
     )
     return upd if out is None else out
 
@@ -607,7 +622,7 @@ _half_iteration = xray.instrument("als.half_iteration")(
         static_argnames=(
             "ks", "implicit", "weighted_lambda", "precision", "solver",
             "gather_dtype", "gather_mode", "solver_mode", "subspace_size",
-            "fused_gather",
+            "mesh",
         ),
         donate_argnums=(0,),
     )(_half_iteration_impl)
@@ -620,14 +635,14 @@ _half_iteration = xray.instrument("als.half_iteration")(
     static_argnames=(
         "ks", "implicit", "weighted_lambda", "precision", "solver",
         "gather_dtype", "gather_mode", "solver_mode", "subspace_size",
-        "fused_gather", "stop_after",
+        "stop_after",
     ),
 )
 def _half_phase_probe(upd, opp, c_sorted, v_sorted, bucket_args, lam,
                       alpha, *, ks, implicit, weighted_lambda, precision,
                       solver, gather_dtype="float32", gather_mode="row",
                       solver_mode="full", subspace_size=0,
-                      fused_gather="taa", stop_after="gather"):
+                      stop_after="gather"):
     """Truncated half-iteration for pio-obs phase tracing: the same
     kernel prefix ``tools/breakdown_matrix.py`` probes (gather only /
     gather+Gram), jitted WITHOUT donation — the real, donating half
@@ -637,16 +652,17 @@ def _half_phase_probe(upd, opp, c_sorted, v_sorted, bucket_args, lam,
         ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
         precision=precision, solver=solver, gather_dtype=gather_dtype,
         gather_mode=gather_mode, solver_mode=solver_mode,
-        subspace_size=subspace_size, fused_gather=fused_gather,
-        upd_table=upd, stop_after=stop_after,
+        subspace_size=subspace_size, upd_table=upd,
+        stop_after=stop_after,
     )
 
 
 def _als_phase_trace_enabled() -> bool:
     """``PIO_TPU_TRACE_ALS=1`` arms per-phase span recording.  Opt-in
-    because honest phase timing needs a fence per probe and per half —
-    the async dispatch pipelining ``run()`` normally rides is exactly
-    what the fences suspend (same trade bench.py makes)."""
+    because honest phase timing needs a ``block_until_ready`` per probe
+    and per half — the async dispatch pipelining ``run()`` normally
+    rides is exactly what the waits suspend (same trade bench.py
+    makes)."""
     import os
 
     return os.environ.get("PIO_TPU_TRACE_ALS") == "1"
@@ -670,10 +686,10 @@ def _solve_buckets(
     gather_mode: str = "row",
     solver_mode: str = "full",
     subspace_size: int = 0,
-    fused_gather: str = "taa",
     upd_table: Optional[jax.Array] = None,
     gram: Optional[jax.Array] = None,
     stop_after: Optional[str] = None,
+    mesh: Optional[Mesh] = None,
 ):
     """Shared bucket-solve math for the replicated and sharded paths
     (and the pio-live fold-in: `live/foldin.py` routes its
@@ -715,17 +731,16 @@ def _solve_buckets(
     HBM bytes.  The YtY gram, regularization, and solves stay f32.
 
     ``solver="fused"`` routes buckets through the single-pass Pallas
-    kernel (`ops/fused_als.py`: in-kernel gather+Gram+regularize+
-    Gauss-Jordan, ~12 B/rating of HBM traffic), using the RESOLVED
-    ``fused_gather`` impl ("taa" take_along_axis sub-gathers or "dma"
-    scalar-prefetched row copies — `ALSConfig.fused_gather`, resolved
-    by `_resolve_solver` before any trace).  Under "taa", VMEM-fitting
-    opposite tables stay resident and bigger ones STREAM through the
-    kernel's third grid axis in id-range-masked chunks; under "dma"
-    the table stays in HBM and rows arrive by async copy.  Only shapes
-    with no tile plan at all (`fused_tile_plan` None: pathological
-    chunk/sub-gather counts or a tiny VMEM/SMEM budget) keep the XLA
-    path below.
+    kernel (`ops/fused_als.py`: in-kernel row-DMA gather + Gram +
+    regularize + Gauss-Jordan; the table stays in HBM).  A bucket whose
+    width has no tile plan (`fused_tile_plan` None: its index block
+    would not fit SMEM) keeps the XLA path below — a choice made from
+    the bucket's static shape, never from a failed compile.
+
+    ``mesh`` is the multi-device mesh of the REPLICATED-placement caller
+    (whose bucket batches arrive data-sharded): the Pallas kernels then
+    run per device (:func:`_per_device`).  The sharded path calls this
+    from inside its own ``shard_map`` body and leaves it None.
     """
     r = opp.shape[-1]
     nnz = c_sorted.shape[0]
@@ -763,13 +778,10 @@ def _solve_buckets(
         opp_grp = jnp.pad(
             opp_g, ((0, mg - opp_g.shape[0]), (0, 0))
         ).reshape(mg // grp, grp, r)
-    fused_side = False
-    if solver == "fused" and stop_after is None and ks:
-        from ..ops.fused_als import fused_side_fits
-
-        fused_side = fused_side_fits(
-            opp_g.shape[0], r, max(ks), opp_g.dtype.itemsize,
-            fused_gather,
+    fused = solver == "fused" and stop_after is None
+    if fused:
+        from ..ops.fused_als import (
+            fused_gather_gram_solve, fused_tile_plan,
         )
     out = None
     for (rows, starts, counts), k in zip(bucket_args, ks):
@@ -779,9 +791,7 @@ def _solve_buckets(
         idx = jnp.where(valid, c_sorted[pos], 0)
         val = jnp.where(valid, v_sorted[pos], 0.0)       # f32, masked
         maskf = valid.astype(f32)
-        if fused_side:
-            from ..ops.fused_als import fused_gather_gram_solve
-
+        if fused and fused_tile_plan(r, k) is not None:
             n_row = counts.astype(f32)
             lam_t = lam.astype(f32)
             if implicit:
@@ -796,10 +806,14 @@ def _solve_buckets(
                 reg = lam_t * jnp.maximum(n_row, 1.0)
             else:
                 reg = jnp.broadcast_to(lam_t, n_row.shape)
-            x = fused_gather_gram_solve(
-                opp_g, idx, cwk, bwk, reg, g0, precision=prec,
-                gather_impl=fused_gather,
-            )
+            if g0 is None:
+                g0 = jnp.zeros((r, r), f32)
+            x = _per_device(
+                functools.partial(fused_gather_gram_solve, precision=prec),
+                mesh,
+                in_specs=(P(), _BATCH, _BATCH, _BATCH, _BATCH, P()),
+                out_specs=_BATCH,
+            )(opp_g, idx, cwk, bwk, reg, g0)
             out = upd_write(out, rows, x)
             continue
         if opp_grp is not None:
@@ -854,6 +868,7 @@ def _solve_buckets(
             res = _subspace_sweep(
                 Vm, val, maskf, x0, reg, cw_b, gram, prec, solver,
                 subspace_size, gram_probe=stop_after == "gram",
+                mesh=mesh,
             )
             if stop_after == "gram":
                 out = (0.0 if out is None else out) + res
@@ -884,7 +899,7 @@ def _solve_buckets(
         if stop_after == "gram":
             out = (0.0 if out is None else out) + A.sum() + b.sum()
             continue
-        x = _spd_solve(A, b, solver)
+        x = _spd_solve(A, b, solver, mesh)
         out = upd_write(out, rows, x)
     return out
 
@@ -902,6 +917,7 @@ def _subspace_sweep(
     block: int,
     *,
     gram_probe: bool = False,
+    mesh: Optional[Mesh] = None,
 ):
     """One iALS++ rank-block sweep over a bucket's rows (arXiv
     2110.14044 Alg. 2, batched over rows).
@@ -960,7 +976,7 @@ def _subspace_sweep(
         if gram_probe:
             acc = acc + H.sum() + g.sum()
             continue
-        d = -_spd_solve(H, g, solver)                    # [B, w]
+        d = -_spd_solve(H, g, solver, mesh)              # [B, w]
         x0 = jax.lax.dynamic_update_slice_in_dim(x0, xs + d, s, axis=1)
         dp = jnp.einsum("bks,bs->bk", Vs, d.astype(Vs.dtype),
                         precision=prec, preferred_element_type=f32)
@@ -985,7 +1001,6 @@ def build_sharded_half(
     gather_mode: str = "row",
     solver_mode: str = "full",
     subspace_size: int = 0,
-    fused_gather: str = "taa",
     coded: bool = False,
 ):
     """ALX-style half-iteration over block-sharded factor tables.
@@ -1027,17 +1042,7 @@ def build_sharded_half(
     padding rows carry ids >= the padded row count, so they drop out of
     every shard's scatter window.
     """
-    import functools as _ft
-    import inspect
-
-    raw = getattr(jax, "shard_map", None)
-    if raw is None:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as raw
-    # the replication-check kwarg was renamed check_rep -> check_vma; probe
-    # the signature rather than the jax version
-    params = inspect.signature(raw).parameters
-    flag = "check_vma" if "check_vma" in params else "check_rep"
-    shard_map = _ft.partial(raw, **{flag: False})
+    from ..parallel.collectives import shard_map
 
     axis = DATA_AXIS
     d = mesh.shape[axis]
@@ -1079,7 +1084,7 @@ def build_sharded_half(
             precision=precision, solver=solver,
             gather_dtype=gather_dtype, gather_mode=gather_mode,
             solver_mode=solver_mode, subspace_size=subspace_size,
-            fused_gather=fused_gather, upd_table=upd_full, gram=gram,
+            upd_table=upd_full, gram=gram,
         )
         return upd if out is None else out
 
@@ -1195,48 +1200,6 @@ def build_sharded_half(
     )
 
 
-def _resolve_solver(cfg: ALSConfig) -> tuple[str, Optional[str]]:
-    """Compile-probe kernel-backed solvers; degrade to "xla" on failure.
-
-    Returns ``(solver, fused_gather_resolved)``: ``"pallas"`` probes
-    the Gauss-Jordan solve kernel at this rank; ``"fused"`` resolves
-    the in-kernel gather form (``cfg.fused_gather``; ``"auto"`` walks
-    the per-backend probe order) and probes the EXACT (shape, dtype,
-    precision, gather-impl) kernel variant production would run.  A
-    fused request that resolves to no runnable variant degrades to
-    ``("xla", None)`` — the loud-degradation artifacts
-    (``solver_requested``/``degraded``/``fused_gather_resolved``) make
-    that visible in every bench record.  All probes cache per
-    (backend, variant) so trainers after the first pay nothing.
-    """
-    if cfg.solver == "pallas":
-        from ..ops.solve import pallas_solver_ok
-
-        # probe the dimension the kernel will actually solve: subspace
-        # mode dispatches B×B subsystems, not R×R (tail blocks are
-        # narrower still — probing the widest block suffices)
-        dim = cfg.rank
-        if cfg.solver_mode == "subspace" and 0 < cfg.subspace_size < cfg.rank:
-            dim = cfg.subspace_size
-        if not pallas_solver_ok(dim):
-            return "xla", None
-    elif cfg.solver == "fused":
-        from ..ops.fused_als import resolve_gather_impl
-
-        tb = 2 if cfg.gather_dtype == "bfloat16" else 4
-        # probe the exact kernel variant production will run: precision,
-        # table dtype, and gather impl are all static args of the
-        # pallas lowering, so probing any other variant validates a
-        # different kernel
-        impl = resolve_gather_impl(
-            512, cfg.rank, tb, cfg.matmul_precision, cfg.fused_gather
-        )
-        if impl is None:
-            return "xla", None
-        return "fused", impl
-    return cfg.solver, None
-
-
 class ALSTrainer:
     """Staged ALS state: build once, iterate cheaply.
 
@@ -1265,14 +1228,6 @@ class ALSTrainer:
         self.mesh = mesh if (mesh is not None and mesh.size > 1) else None
         self.n_users = n_users
         self.n_items = n_items
-        # resolve the solver once: kernel-backed solvers are
-        # compile-probed and degrade to XLA with a warning if the kernel
-        # doesn't lower on this backend (round 2: a Mosaic regression
-        # was only caught on the real chip; a user's train must survive
-        # the next one).  fused_gather is the RESOLVED in-kernel gather
-        # form (None unless the fused kernel is live) — bench artifacts
-        # record it as fused_gather_resolved
-        self.solver, self.fused_gather = _resolve_solver(cfg)
 
         n_dev = self.mesh.size if self.mesh is not None else 1
         # sharded factor tables need a real mesh and row counts divisible
@@ -1350,6 +1305,27 @@ class ALSTrainer:
         if self.sharded:
             self._build_sharded_halves()
         self._init_loss(u, i, v)
+        staged = {
+            "solver": cfg.solver,
+            "staging": self.staging,
+            "placement": "sharded" if self.sharded else "replicated",
+            "devices": n_dev,
+            "devicesWithData": self.data_devices(),
+        }
+        logger.info("ALS staged: %s", staged)
+        tower.note_event("als_staged", **staged)
+
+    def data_devices(self) -> int:
+        """How many devices hold staged training data (the rating COO
+        and the per-bucket index vectors).  What a multi-chip bring-up
+        checks: code that put everything on device 0 reports 1."""
+        devices: set = set()
+        for side in (self._user_side, self._item_side):
+            arrays = [side["c_sorted"], side["v_sorted"]]
+            arrays += [a for bucket in side["buckets"] for a in bucket]
+            for a in arrays:
+                devices.update(a.devices())
+        return len(devices)
 
     # per-sweep loss sample cap: the watchdog needs a *trajectory*, not
     # the exact training RMSE, so the loss pass runs over a fixed
@@ -1417,12 +1393,11 @@ class ALSTrainer:
             implicit=cfg.implicit,
             weighted_lambda=cfg.weighted_lambda,
             precision=cfg.matmul_precision,
-            solver=self.solver,
+            solver=cfg.solver,
             gather_dtype=cfg.gather_dtype,
             gather_mode=cfg.gather_mode,
             solver_mode=cfg.solver_mode,
             subspace_size=cfg.subspace_size,
-            fused_gather=self.fused_gather or "taa",
             coded=self.coded,
         )
         self._sharded_user_half = build_sharded_half(
@@ -1505,7 +1480,6 @@ class ALSTrainer:
         self.mesh = mesh
         self.n_users = n_users
         self.n_items = n_items
-        self.solver, self.fused_gather = _resolve_solver(cfg)
         n_dev = mesh.size
         self.sharded = True
         self.staging = "sharded-distributed"
@@ -1658,7 +1632,7 @@ class ALSTrainer:
         (``repeat``), and the item side is one argsort + gathers.
 
         The TPU lesson generalizes: host↔device bytes are the scarce
-        resource (PCIe, or worse a DCN/tunnel hop), device sort is cheap
+        resource (PCIe, or worse a DCN hop), device sort is cheap
         — and bytes you can DERIVE device-side are cheaper still.
         """
         if len(v) >= np.iinfo(np.int32).max:
@@ -1892,24 +1866,24 @@ class ALSTrainer:
             implicit=cfg.implicit,
             weighted_lambda=cfg.weighted_lambda,
             precision=cfg.matmul_precision,
-            solver=self.solver,
+            solver=cfg.solver,
             gather_dtype=cfg.gather_dtype,
             gather_mode=cfg.gather_mode,
             solver_mode=cfg.solver_mode,
             subspace_size=cfg.subspace_size,
-            fused_gather=self.fused_gather or "taa",
+            mesh=self.mesh,
         )
 
     def _traced_half(self, upd, opp, side, side_name: str, it: int,
                      lam: Optional[float],
                      collect: Optional[dict] = None) -> jax.Array:
         """One half-iteration with pio-obs phase spans (als.gather /
-        als.gram / als.solve), attributed by the fence-probe subtraction
-        idiom: time the gather-only truncation, the gather+Gram
-        truncation, and the full half, each fenced; the deltas are the
-        per-phase device times (ALX §5: per-phase timing is what makes
-        TPU factorization tunable).  Sharded placement has no probe
-        entry point — it records the fenced full half as ``als.half``.
+        als.gram / als.solve), attributed by probe subtraction: time the
+        gather-only truncation, the gather+Gram truncation, and the full
+        half, each to completion; the deltas are the per-phase device
+        times (ALX §5: per-phase timing is what makes TPU factorization
+        tunable).  Sharded placement has no probe entry point — it
+        records the completed full half as ``als.half``.
 
         ``collect`` (pio-tower) accumulates the emitted phase times as
         side-qualified keys (``user.gather`` ...) for the run
@@ -1922,10 +1896,10 @@ class ALSTrainer:
 
         def timed(fn, warm: bool):
             if warm:
-                fence(fn())  # compile outside the measured span
+                # compile outside the measured span
+                jax.block_until_ready(fn())
             t0 = time.perf_counter()
-            out = fn()
-            fence(out)
+            out = jax.block_until_ready(fn())
             return out, time.perf_counter() - t0
 
         def emit(phase: str, dt: float) -> None:
@@ -1952,12 +1926,11 @@ class ALSTrainer:
                 side["buckets"], lam_t, alpha_t,
                 ks=side["ks"], implicit=cfg.implicit,
                 weighted_lambda=cfg.weighted_lambda,
-                precision=cfg.matmul_precision, solver=self.solver,
+                precision=cfg.matmul_precision, solver=cfg.solver,
                 gather_dtype=cfg.gather_dtype,
                 gather_mode=cfg.gather_mode,
                 solver_mode=cfg.solver_mode,
                 subspace_size=cfg.subspace_size,
-                fused_gather=self.fused_gather or "taa",
                 stop_after=stop,
             )
 
@@ -2009,7 +1982,7 @@ class ALSTrainer:
             t_sweep = time.perf_counter()
             phases: dict[str, float] = {}
             if trace_phases:
-                # half-iteration granularity (fence-probe subtraction):
+                # half-iteration granularity (probe subtraction):
                 # opt-in via PIO_TPU_TRACE_ALS=1 — the probes re-run
                 # truncated halves, overhead the always-on path refuses
                 U = self._traced_half(U, V, self._user_side, "user", it,
@@ -2017,17 +1990,17 @@ class ALSTrainer:
                 V = self._traced_half(V, U, self._item_side, "item", it,
                                       lam, collect=phases)
             else:
-                # always-on sweep telemetry: one fence per half gives
+                # always-on sweep telemetry: one wait per half gives
                 # the user/item split with zero extra device work (the
                 # halves are data-dependent, so the device pipeline
                 # loses nothing; only host dispatch-ahead is traded)
                 t0 = time.perf_counter()
-                U = self._half(U, V, self._user_side, lam=lam)
-                fence(U)
+                U = jax.block_until_ready(
+                    self._half(U, V, self._user_side, lam=lam))
                 phases["user_half"] = time.perf_counter() - t0
                 t0 = time.perf_counter()
-                V = self._half(V, U, self._item_side, lam=lam)
-                fence(V)
+                V = jax.block_until_ready(
+                    self._half(V, U, self._item_side, lam=lam))
                 phases["item_half"] = time.perf_counter() - t0
                 TRAIN_PHASE_SECONDS.labels(phase="als.user_half").observe(
                     phases["user_half"]
@@ -2059,11 +2032,8 @@ class ALSTrainer:
             )
             logger.debug("ALS iteration %d/%d complete", it + 1,
                          num_iterations)
-        # fence, not block_until_ready: the latter is a no-op on some
-        # remote-tunnel backends (parallel/mesh.py fence docstring), which
-        # would make every caller's wall-clock a dispatch time
-        fence(U, V)
-        return U, V
+        # callers time this call: return completed arrays
+        return jax.block_until_ready((U, V))
 
     def train(
         self,
@@ -2186,7 +2156,6 @@ def sweep_train_als(
         precision=cfg.matmul_precision, solver=cfg.solver,
         gather_dtype=cfg.gather_dtype, gather_mode=cfg.gather_mode,
         solver_mode=cfg.solver_mode, subspace_size=cfg.subspace_size,
-        fused_gather=trainer.fused_gather or "taa",
     )
 
     def make_half(side):
@@ -2209,7 +2178,6 @@ def sweep_train_als(
     for _ in range(cfg.num_iterations):
         U = half_u(U, V, lam_arr)
         V = half_i(V, U, lam_arr)
-    fence(U, V)
     Uh, Vh = np.asarray(U), np.asarray(V)
     return [
         ALSFactors(user_factors=Uh[k], item_factors=Vh[k]) for k in range(K)

@@ -599,6 +599,15 @@ def note_shard_event(event: dict) -> None:
         session.note_shard_event(event)
 
 
+def note_event(event: str, **fields) -> None:
+    """An out-of-band fact about the active run (what the trainer
+    staged where, with which solver) appended to its manifest as an
+    ``event`` record; a no-op without an active session."""
+    session = active_session()
+    if session is not None and session.manifest is not None:
+        session.manifest.event(event, **fields)
+
+
 def record_candidate(index: int, **fields) -> None:
     """One evaluation candidate scored (eval-run manifests)."""
     session = active_session()

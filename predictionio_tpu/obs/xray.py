@@ -63,6 +63,7 @@ __all__ = [
     "instrument",
     "jit_stats",
     "note_compilation_cache",
+    "compile_cache_summary",
     "recompile_events",
     "sample_devices_once",
     "set_sample_period",
@@ -660,6 +661,14 @@ def device_high_water() -> Optional[int]:
 
 def recompile_events() -> list:
     return _STATE.snapshot()["recompiles"]
+
+
+def compile_cache_summary() -> dict:
+    """``{"dir", "events"}``: the persistent compile cache this process
+    used and its hit/miss/request counts so far (the same numbers as
+    ``pio_compile_cache_events_total{kind}``)."""
+    snap = _STATE.snapshot()
+    return {"dir": snap["cacheDir"], "events": snap["cacheEvents"]}
 
 
 def xray_payload() -> dict:

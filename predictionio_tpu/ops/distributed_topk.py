@@ -311,7 +311,14 @@ class ShardedTopK:
         return min(max(self.candidate_factor * k, k), shard_rows)
 
     def __call__(self, queries, k: int, deadline=None):
-        q = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
+        if isinstance(queries, jax.Array):
+            q = jnp.atleast_2d(queries.astype(jnp.float32))
+        else:
+            # serving hands host arrays: shape them on the host —
+            # `jnp.atleast_2d` is a jit of its own per batch size,
+            # compiled in the middle of the first request of each size
+            q = jnp.asarray(
+                np.atleast_2d(np.asarray(queries, np.float32)))
         k = min(k, self.n_items)
         if self.q_table is not None:
             ok = None

@@ -1,64 +1,31 @@
 """Fused gather+Gram+solve ALS half-iteration as one Pallas TPU kernel.
 
-The measured bottleneck of the ALS hot loop (docs/ARCHITECTURE.md
-"Measured performance", fenced on v5e): the ``[B, K, R]`` gathered
-factor expansion materializes ~5 GB/half in HBM and feeds the Gram
-einsums at an effective ~17 GB/s — 303 ms gather + 793 ms Gram per user
-half vs a ~10 ms MXU roofline.  At rank 64 the opposite (item) factor
-table is only ~7 MB f32 (~3.5 MB bf16): it FITS IN VMEM.  This kernel
-keeps the whole table resident and, per batch tile, streams only the
-``[TB, KC]`` rating-index/weight blocks from HBM.
+The unfused ALS hot loop (`models/als._solve_buckets`) materializes the
+``[B, K, R]`` gathered factor expansion in HBM and feeds it to the Gram
+einsums.  This kernel never materializes it: per batch tile it copies
+the needed opposite-table rows HBM -> VMEM itself, accumulates the
+normal equations on the MXU, and solves in place, so HBM sees only the
+``[TB, KC]`` index/weight blocks and the row reads.
 
-Round 5 proved on silicon that the original in-kernel ``jnp.take`` row
-gather NEVER lowers: Mosaic's gather rule
-(jax/_src/pallas/mosaic/lowering.py:2481-2484) accepts only
-``take_along_axis``-shaped operands.  The kernel now implements the two
-Mosaic-lowerable forms ``tools/probe_gather.py`` was built to arbitrate,
-selectable via ``ALSConfig(fused_gather=...)``:
+The in-kernel gather is a rolling window of ``pltpu.make_async_copy``
+row copies: the indices are scalar-prefetched to SMEM
+(``PrefetchScalarGridSpec``), the table stays in HBM (``pl.ANY``), and
+each needed row is one async copy with ``_DMA_WINDOW`` outstanding.
+Mosaic only slices a 32-bit HBM ref in whole 128-lane rows, so the
+table is lane-padded to a multiple of 128 and must be float32 (what the
+v5e compiler said about the alternatives is in CHANGES.md, PR 21: a
+``take_along_axis`` gather needs its whole source in one vreg, and a
+one-row slice of a bf16 ref is not tile-aligned).
 
-* ``"taa"`` — same-shape ``take_along_axis(axis=0)`` sub-gathers: the
-  row ids are broadcast across lanes and the ``[TB*KC]`` id vector is
-  processed as ``ceil(TB*KC/MC)`` gathers of the ``[MC, R]`` table
-  chunk (each lowers to ``tpu.dynamic_gather`` along sublanes).  Keeps
-  the streamed-table third grid axis: tables beyond VMEM flow through
-  in id-range-masked chunks exactly as before.
-* ``"dma"`` — an in-kernel rolling-window ``pltpu.make_async_copy`` row
-  loop: the indices are scalar-prefetched to SMEM
-  (``PrefetchScalarGridSpec``) and each needed row is one async HBM ->
-  VMEM copy with ``_DMA_WINDOW`` outstanding.  Lowers by construction;
-  the table never occupies VMEM at all, so there is no streamed grid
-  and no id-range masking — the open question is pure issue rate,
-  answered on-chip by ``probe_gather``/``fused_smoke``.
+Per chunk: the row copies, then ``A += (cw·rows)ᵀ rows`` on the MXU and
+``b += Σ bw·rows`` on the VPU, accumulated in fp32 VMEM scratch; on the
+last chunk the kernel regularizes and solves in place with the same
+augmented Gauss-Jordan as ``ops/solve.py``, writing only ``x[TB, R]``.
 
-``fused_gather="auto"`` resolves per backend: ``resolve_gather_impl``
-ranks the forms with the SAME probe library the measurement battery
-runs (`ops/gather_probe.preferred_order`) and commits to the first form
-whose full-kernel compile-and-run probe (`fused_solver_ok`) passes.
-
-Mixed precision (the GPU-MF recipe, arXiv 1808.03843: reduced-precision
-operands, full-precision accumulation): the kernel accepts a bf16
-factor table — halving the resident-table VMEM footprint AND the
-streamed/DMA'd bytes, so ``fused_tile_plan`` residency reaches twice
-the table height — and keeps the gathered rows in the table dtype
-through both MXU contractions while accumulating the normal equations
-in fp32 VMEM scratch (``preferred_element_type=f32``; ``precision``
-threads through unchanged).  Regularization and the in-place augmented
-Gauss-Jordan solve stay f32.
-
-Per chunk: the gather, then two MXU contractions accumulate
-``A += (cw·rows)ᵀ rows`` and ``b += bw·rows``; on the last chunk the
-kernel regularizes and solves in place with the same augmented
-Gauss-Jordan used by ``ops/solve.py``, writing only ``x[TB, R]``.  HBM
-traffic drops from ~256 bytes/rating (the materialized expansion) to
-~12 bytes/rating (idx + two weights).
-
-``models/als._solve_buckets`` routes any side through the kernel when a
-tile plan exists; ``fused_tile_plan`` caps the chunk count (and, for
-``"taa"``, the unrolled sub-gather count; for ``"dma"``, the SMEM
-footprint of a batch tile's indices) so pathological shapes fall back
-to XLA.  Every jit entry is wrapped ``xray.instrument("als.fused")`` so
-a new tile plan, precision, table dtype, or gather impl shows up as a
-recompile with a per-arg delta at ``/debug/xray``.
+``models/als._solve_buckets`` routes a bucket through the kernel when
+``fused_tile_plan`` finds a tile for its width; wider buckets keep the
+XLA path.  The jit entry is wrapped ``xray.instrument("als.fused")`` so
+a new tile plan or precision shows up as a recompile at ``/debug/xray``.
 
 Reference provenance: this fuses what MLlib ALS does in separate stages
 per block (gather factors, accumulate YtY·normal equations, solve —
@@ -73,24 +40,21 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..obs import xray
-from .solve import _EPS, solver_smem_budget, solver_vmem_budget
+from .solve import (
+    _EPS,
+    pallas_interpret,
+    solver_smem_budget,
+    solver_vmem_budget,
+)
 
 __all__ = [
-    "GATHER_IMPLS",
     "fused_gather_gram_solve",
-    "fused_side_fits",
-    "fused_solver_ok",
     "fused_tile_plan",
-    "resolve_gather_impl",
 ]
-
-# the Mosaic-lowerable in-kernel gather forms (docs/PERF_PLAN.md §4)
-GATHER_IMPLS = ("taa", "dma")
 
 
 def _pad8(n: int) -> int:
@@ -101,127 +65,46 @@ def _pad128(n: int) -> int:
     return max(-(-n // 128) * 128, 128)
 
 
-def _pad_sub(n: int, itemsize: int = 4) -> int:
-    """Pad to the dtype's memory-tile sublane count (8 f32 / 16 bf16)."""
-    s = max(32 // max(itemsize, 1), 8)
-    return max(-(-n // s) * s, s)
-
-
-# Cap on streamed table chunks.  The per-chunk re-read of the
-# [TB, KC] index/weight blocks costs ~T x 12 B/rating — at T=64 that is
-# ~3x the unfused path's ~256 B/rating, BUT every streamed byte is a
-# big contiguous DMA at full HBM bandwidth (~800 GB/s on v5e) while the
-# unfused bytes move at the measured ~17 GB/s random-gather rate, so
-# streaming stays ~15x cheaper in time at the cap.  The cap guards the
-# truly pathological shapes (T in the hundreds), where the plan's
-# working-set math stops being the dominant consideration.
-_MAX_TABLE_CHUNKS = 64
-
-# Cap on the "taa" impl's unrolled same-shape sub-gathers per chunk
-# (ceil(TB*KC/MC) take_along_axis calls): each is a full [MC, R] pass,
-# so past this count both the compile size and the VMEM-bandwidth waste
-# (g*MC rows touched for TB*KC wanted) stop being worth a kernel.
-_MAX_TAA_SUBGATHERS = 32
-
-# rolling window of outstanding row DMAs in the "dma" impl
+# rolling window of outstanding row DMAs
 _DMA_WINDOW = 16
 
 
-def fused_tile_plan(
-    m: int, r: int, k: int, table_bytes: int = 4, gather_impl: str = "taa"
-):
-    """Choose ``(TB, KC, MC)`` so the working set fits the VMEM budget.
+def fused_tile_plan(r: int, k: int):
+    """Choose ``(TB, KC)`` so the working set fits VMEM and SMEM.
 
-    ``MC`` is the table-chunk height: ``MC >= M`` means the whole table
-    is VMEM-resident (single chunk, no masking waste); smaller tables
-    stream through in ``ceil(M/MC)`` chunks along the kernel's third
-    grid axis.  Accounts for the PADDED footprints (Mosaic tiles the
-    trailing two dims to (8, 128) for f32, (16, 128) for bf16): the
-    double-buffered ``[MC, R]`` table chunk, the ``[TB, R, R]`` +
-    ``[TB, R, R+1]`` + ``[TB, R]`` f32 scratches, the ``[TB, KC, R]``
-    gathered chunk (in the TABLE dtype — a bf16 table halves it), and
-    the double-buffered ``[TB, KC]`` input / ``[TB, R]`` output blocks.
+    VMEM holds the PADDED footprints (Mosaic tiles the trailing two dims
+    of f32 values to (8, 128)) of the ``[TB, R, R]`` + ``[TB, R, R+1]``
+    + ``[TB, R]`` f32 scratches, the ``[TB*KC, R128]`` landing pad of
+    the row copies, and the double-buffered ``[TB, KC]`` weight /
+    ``[TB, R]`` output blocks.  SMEM (``solver_smem_budget``) must hold
+    one batch tile's scalar-prefetched ``[TB, Kpad]`` int32 index block.
+    The table itself stays in HBM, so its height does not enter.
 
-    ``gather_impl="taa"`` additionally requires the unrolled sub-gather
-    count ``ceil(TB*KC/MC)`` within ``_MAX_TAA_SUBGATHERS``.
+    Like ``ops/solve._tile_rows`` the plan fills only half the VMEM
+    budget: the chunk's weighted copy and the MXU operands Mosaic
+    stages are stack temporaries of the same order as the landing pad
+    (v5e: a plan at 13 of 16 MiB compiled to 18.7 MiB and was refused).
 
-    ``gather_impl="dma"`` budgets differently: the table stays in HBM
-    (rows arrive by per-row DMA into a ``[TB*KC, R]`` scratch), the
-    indices live in SMEM (``solver_smem_budget`` must hold one batch
-    tile's ``[TB, Kpad]`` int32 block), and ``MC`` is always the padded
-    table height (no streaming, no masking).
-
-    Returns ``None`` when no plan fits (caller falls back to XLA).
+    Returns ``None`` when no tile fits (the caller keeps the XLA path
+    for that bucket).
     """
-    if gather_impl not in GATHER_IMPLS:
-        raise ValueError(
-            f"gather_impl must be one of {GATHER_IMPLS}, "
-            f"got {gather_impl!r}"
-        )
-    budget = int(solver_vmem_budget() * 0.9)
+    budget = solver_vmem_budget() // 2
     r8, r128, w128 = _pad8(r), _pad128(r), _pad128(r + 1)
-    m8 = _pad8(m)
-    best_stream = None
-    # a RESIDENT table (fetched once, idx blocks read once) beats bigger
-    # batch tiles with a streamed table (T x index re-reads + table
-    # re-fetch per batch tile), so residency at any tile size wins over
-    # streaming at any tile size; within each mode, larger tiles first
     for tb in (64, 32, 16, 8):
         for kc in (512, 256, 128):
-            kc_eff = min(kc, max(-(-k // 128) * 128, 128))
+            kc_eff = min(kc, _pad128(k))
             a_scr = tb * r8 * r128 * 4
             m_scr = tb * r8 * w128 * 4
             b_scr = _pad8(tb) * r128 * 4
-            rows = (
-                tb * _pad_sub(kc_eff, table_bytes) * r128 * table_bytes
-            )
+            rows = tb * kc_eff * r128 * 4
+            io = 2 * 2 * _pad8(tb) * _pad128(kc_eff) * 4  # cw/bw x2
             out = 2 * _pad8(tb) * r128 * 4
             gram0 = r8 * r128 * 4
-            if gather_impl == "dma":
-                # idx rides SMEM (scalar prefetch), so VMEM holds only
-                # the two weight blocks; the table never enters VMEM
-                io = 2 * 2 * _pad8(tb) * _pad128(kc_eff) * 4
-                fixed = a_scr + m_scr + b_scr + rows + io + out + gram0
-                kp = -(-k // kc_eff) * kc_eff
-                if (
-                    fixed <= budget
-                    and tb * kp * 4 <= solver_smem_budget()
-                ):
-                    return tb, kc_eff, m8
-                continue
-            io = 3 * 2 * _pad8(tb) * _pad128(kc_eff) * 4  # idx/cw/bw x2
             fixed = a_scr + m_scr + b_scr + rows + io + out + gram0
-            avail = budget - fixed
-            if avail <= 0:
-                continue
-            # whole table resident (single chunk, not double-buffered)?
-            if m8 * r128 * table_bytes <= avail:
-                if -(-(tb * kc_eff) // m8) <= _MAX_TAA_SUBGATHERS:
-                    return tb, kc_eff, m8
-                # tiny table under a big tile: the unroll would explode;
-                # a smaller tile may still make residency work
-                continue
-            # else stream chunks (double-buffered by the pipeline);
-            # remember the largest-tile streaming plan as the fallback
-            if best_stream is None:
-                mc = (avail // 2 // (r128 * table_bytes)) // 8 * 8
-                if (
-                    mc >= 8
-                    and -(-m8 // mc) <= _MAX_TABLE_CHUNKS
-                    and -(-(tb * kc_eff) // mc) <= _MAX_TAA_SUBGATHERS
-                ):
-                    best_stream = (tb, kc_eff, int(mc))
-    return best_stream
-
-
-def fused_side_fits(
-    m: int, r: int, k_max: int, table_bytes: int = 4,
-    gather_impl: str = "taa",
-) -> bool:
-    """Does a fused tile plan exist for this side and gather impl?"""
-    return fused_tile_plan(
-        m, r, max(k_max, 1), table_bytes, gather_impl
-    ) is not None
+            kp = -(-k // kc_eff) * kc_eff
+            if fixed <= budget and tb * kp * 4 <= solver_smem_budget():
+                return tb, kc_eff
+    return None
 
 
 def _gj_solve_writeback(a_scr, b_scr, m_scr, reg_ref, x_ref):
@@ -259,158 +142,39 @@ def _gj_solve_writeback(a_scr, b_scr, m_scr, reg_ref, x_ref):
 
 
 def _accumulate(rows, cw, bw, a_scr, b_scr, precision):
-    """The two MXU contractions: fp32 accumulation over operands kept
-    in the TABLE dtype (bf16 tables feed the MXU bf16 operands — the
-    mixed-precision half of the GPU-MF recipe; the weights are cast
-    DOWN to match so the big ``rows`` operand is never silently
-    promoted and re-materialized in f32)."""
-    wdt = rows.dtype
-    rw = rows * cw.astype(wdt)[:, :, None]
+    """Normal-equation accumulation over one ``[TB, KC, R]`` chunk.
+
+    The Gram update is a batched MXU contraction over the chunk dim.
+    The rhs update is a VPU multiply + sublane reduction: as a
+    ``dot_general`` it has no lhs non-contracting dim, which Mosaic's
+    dot-dimension attribute cannot express (v5e, jax 0.9.0: "failed to
+    parse TPU_DotDimensionNumbersAttr parameter
+    'lhs_non_contracting_dims'").
+    """
+    rw = rows * cw[:, :, None]
     a_scr[:] += jax.lax.dot_general(
         rw, rows, (((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32, precision=precision,
     )
-    b_scr[:] += jax.lax.dot_general(
-        bw.astype(wdt), rows, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32, precision=precision,
-    )
+    b_scr[:] += jnp.sum(rows * bw[:, :, None], axis=1)
 
 
-# ------------------------------------------------------------- taa --
-
-def _taa_rows(table_ref, safe, tb, kc, mc, r):
-    """``ceil(TB*KC/MC)`` same-shape ``take_along_axis(axis=0)``
-    sub-gathers (the Mosaic ``tpu.dynamic_gather`` form): the flat id
-    vector is padded to a multiple of MC, each MC-slice is broadcast
-    across the lane dim to the table chunk's own ``[MC, R]`` shape, and
-    the gathered slabs concatenate back to ``[TB, KC, R]``."""
-    flat_n = tb * kc
-    g = -(-flat_n // mc)
-    pad = g * mc - flat_n
-    flat = safe.reshape(flat_n)
-    if pad:
-        flat = jnp.concatenate(
-            [flat, jnp.zeros((pad,), jnp.int32)]
-        )
-    parts = []
-    for s in range(g):
-        sl = jax.lax.slice_in_dim(flat, s * mc, (s + 1) * mc, axis=0)
-        idx_b = jnp.broadcast_to(sl[:, None], (mc, r))
-        parts.append(jnp.take_along_axis(table_ref[:], idx_b, axis=0))
-    rows = parts[0] if g == 1 else jnp.concatenate(parts, axis=0)
-    return jax.lax.slice_in_dim(rows, 0, flat_n, axis=0).reshape(
-        tb, kc, r
-    )
-
-
-def _fused_kernel_taa(
+def _fused_kernel(
+    idx_sref,    # [Bp, Kp] int32, scalar-prefetched to SMEM
     gram0_ref,   # [R, R] f32 (YtY for implicit mode; zeros otherwise)
-    table_ref,   # [MC, R] opposite-table chunk (f32 or bf16)
-    idx_ref,     # [TB, KC] int32 (masked entries point at row 0)
+    table_ref,   # [M, R128] FULL lane-padded table in HBM; rows by DMA
     cw_ref,      # [TB, KC] f32 Gram weights (0 at masked entries)
     bw_ref,      # [TB, KC] f32 rhs weights (0 at masked entries)
     reg_ref,     # [TB, 1] f32 ridge diagonal
     x_ref,       # [TB, R] f32 out
+    rows_scr,    # [TB*KC, R128] f32 landing pad for the row DMAs
     a_scr,       # [TB, R, R] f32 normal-equation accumulator
     b_scr,       # [TB, R] f32 rhs accumulator
     m_scr,       # [TB, R, R+1] f32 augmented Gauss-Jordan scratch
-    *,
-    precision,   # lax.Precision for the MXU contractions — the same
-                 # knob the unfused Gram einsums honor (RMSE parity
-                 # wants HIGHEST; a bf16 table already bounds operand
-                 # precision, so "default" is the natural pair there)
-):
-    t, j = pl.program_id(1), pl.program_id(2)
-    nt, nj = pl.num_programs(1), pl.num_programs(2)
-    tb, kc = idx_ref.shape
-    mc, r = table_ref.shape
-
-    @pl.when((t == 0) & (j == 0))
-    def _init():
-        a_scr[:] = jnp.broadcast_to(
-            gram0_ref[:][None], (tb, r, r)
-        ).astype(jnp.float32)
-        b_scr[:] = jnp.zeros((tb, r), jnp.float32)
-
-    # ids owned by THIS table chunk contribute; the rest are masked out
-    # of the weights (single-chunk tables: the mask is all-true and the
-    # clip a no-op)
-    local = idx_ref[:] - t * mc
-    inr = ((local >= 0) & (local < mc)).astype(jnp.float32)
-    safe = jnp.clip(local, 0, mc - 1)
-    rows = _taa_rows(table_ref, safe, tb, kc, mc, r)
-    _accumulate(
-        rows, cw_ref[:] * inr, bw_ref[:] * inr, a_scr, b_scr, precision
-    )
-
-    @pl.when((t == nt - 1) & (j == nj - 1))
-    def _solve():
-        _gj_solve_writeback(a_scr, b_scr, m_scr, reg_ref, x_ref)
-
-
-@xray.instrument("als.fused")
-@functools.partial(
-    jax.jit, static_argnames=("tb", "kc", "mc", "interpret", "precision")
-)
-def _fused_padded_taa(
-    gram0, table, idx, cw, bw, reg, *, tb, kc, mc, interpret, precision
-):
-    bp, kp = idx.shape
-    mp, r = table.shape
-    grid = (bp // tb, mp // mc, kp // kc)
-    # constant index map when the table is resident (single chunk): a
-    # grid-invariant map is provably single-buffered, which is what the
-    # tile plan budgeted; the streamed map only appears when the plan
-    # ALSO budgeted the chunk double-buffered
-    table_map = (
-        (lambda i, t, j: (0, 0)) if mp == mc else (lambda i, t, j: (t, 0))
-    )
-    return pl.pallas_call(
-        functools.partial(_fused_kernel_taa, precision=precision),
-        out_shape=jax.ShapeDtypeStruct((bp, r), jnp.float32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((r, r), lambda i, t, j: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((mc, r), table_map,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, kc), lambda i, t, j: (i, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, kc), lambda i, t, j: (i, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, kc), lambda i, t, j: (i, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, 1), lambda i, t, j: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((tb, r), lambda i, t, j: (i, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((tb, r, r), jnp.float32),
-            pltpu.VMEM((tb, r), jnp.float32),
-            pltpu.VMEM((tb, r, r + 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(gram0, table, idx, cw, bw, reg)
-
-
-# ------------------------------------------------------------- dma --
-
-def _fused_kernel_dma(
-    idx_sref,    # [Bp, Kp] int32, scalar-prefetched to SMEM
-    gram0_ref,   # [R, R] f32
-    table_ref,   # [Mp, R] FULL table in ANY (HBM); rows arrive by DMA
-    cw_ref,      # [TB, KC] f32
-    bw_ref,      # [TB, KC] f32
-    reg_ref,     # [TB, 1] f32
-    x_ref,       # [TB, R] f32 out
-    rows_scr,    # [TB*KC, R] table-dtype landing pad for the row DMAs
-    a_scr,       # [TB, R, R] f32
-    b_scr,       # [TB, R] f32
-    m_scr,       # [TB, R, R+1] f32
     sem,         # DMA semaphores, rolling window
     *,
-    precision,
+    precision,   # lax.Precision for the MXU contraction — the same
+                 # knob the unfused Gram einsums honor
 ):
     i, j = pl.program_id(0), pl.program_id(1)
     nj = pl.num_programs(1)
@@ -428,7 +192,7 @@ def _fused_kernel_dma(
 
     # one row DMA per (tile-row, chunk-col) with a rolling window of
     # outstanding copies; wait re-materializes the same (src, dst, sem)
-    # triple, the probe-validated idiom
+    # triple
     def issue(k):
         row = idx_sref[i * tb + k // kc, j * kc + k % kc]
         return pltpu.make_async_copy(
@@ -453,10 +217,9 @@ def _fused_kernel_dma(
 
     jax.lax.fori_loop(0, window, drain, 0)
 
-    rows = rows_scr[:].reshape(tb, kc, r)
-    # no id-range mask: the whole table is addressable from HBM, and
-    # masked entries already carry zero weights (idx contract: they
-    # point at row 0)
+    rows = rows_scr[:, :r].reshape(tb, kc, r)
+    # masked entries carry zero weights (idx contract: they point at
+    # row 0, which the DMA really fetches)
     _accumulate(rows, cw_ref[:], bw_ref[:], a_scr, b_scr, precision)
 
     @pl.when(j == nj - 1)
@@ -468,18 +231,18 @@ def _fused_kernel_dma(
 @functools.partial(
     jax.jit, static_argnames=("tb", "kc", "interpret", "precision")
 )
-def _fused_padded_dma(
+def _fused_padded(
     gram0, table, idx, cw, bw, reg, *, tb, kc, interpret, precision
 ):
     bp, kp = idx.shape
-    mp, r = table.shape
+    r = gram0.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bp // tb, kp // kc),
         in_specs=[
             pl.BlockSpec((r, r), lambda i, j, idx_s: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((tb, kc), lambda i, j, idx_s: (i, j),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((tb, kc), lambda i, j, idx_s: (i, j),
@@ -490,7 +253,7 @@ def _fused_padded_dma(
         out_specs=pl.BlockSpec((tb, r), lambda i, j, idx_s: (i, 0),
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((tb * kc, r), table.dtype),
+            pltpu.VMEM((tb * kc, table.shape[1]), jnp.float32),
             pltpu.VMEM((tb, r, r), jnp.float32),
             pltpu.VMEM((tb, r), jnp.float32),
             pltpu.VMEM((tb, r, r + 1), jnp.float32),
@@ -498,26 +261,22 @@ def _fused_padded_dma(
         ],
     )
     return pl.pallas_call(
-        functools.partial(_fused_kernel_dma, precision=precision),
+        functools.partial(_fused_kernel, precision=precision),
         out_shape=jax.ShapeDtypeStruct((bp, r), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
     )(idx, gram0, table, cw, bw, reg)
 
 
-# ------------------------------------------------------------ entry --
-
 def fused_gather_gram_solve(
-    table,          # [M, R] opposite factor table (f32 or bf16)
+    table,          # [M, R] float32 opposite factor table
     idx,            # [B, K] int32 opposite ids, masked entries point at 0
     cw,             # [B, K] f32 Gram weights (0 where masked)
     bw,             # [B, K] f32 rhs weights (0 where masked)
     reg,            # [B]    f32 ridge diagonal
     gram0=None,     # [R, R] f32 base Gram (implicit YtY); zeros if None
     interpret: bool | None = None,
-    plan: tuple | None = None,
     precision=None,
-    gather_impl: str = "taa",
 ):
     """One fused normal-equation build + solve for a bucket of rows.
 
@@ -525,53 +284,42 @@ def fused_gather_gram_solve(
     Σₖ bwₖ·vₖ`` with ``vₖ = table[idx[:, k]]``.  Masking rides the
     weights: a masked entry's ``cw = bw = 0`` makes its gathered row
     irrelevant (``idx`` must point at a valid row, conventionally 0 —
-    the ``"dma"`` impl really fetches it).
+    the kernel really fetches it).
 
-    ``gather_impl`` selects the Mosaic-lowerable in-kernel gather form
-    (``GATHER_IMPLS``; module docstring).  ``plan`` overrides the
-    ``(TB, KC, MC)`` tile plan — used by the compile probe to force the
-    streamed multi-chunk grid on a small table; production callers
-    leave it None.
-
-    ``precision`` is the MXU precision for the two in-kernel
-    contractions — the same ``lax.Precision`` knob the unfused Gram
+    ``precision`` is the MXU precision for the in-kernel Gram
+    contraction — the same ``lax.Precision`` knob the unfused Gram
     einsums honor (``ALSConfig.matmul_precision``).  ``None`` means
-    HIGHEST: RMSE parity is the default contract.  A bf16 table bounds
-    operand precision regardless (the contraction operands stay in the
-    table dtype; only the accumulators are f32).
+    HIGHEST: RMSE parity is the default contract.  ``interpret=None``
+    follows :func:`ops.solve.pallas_interpret`.
     """
-    if gather_impl not in GATHER_IMPLS:
+    if table.dtype != jnp.float32:
         raise ValueError(
-            f"gather_impl must be one of {GATHER_IMPLS}, "
-            f"got {gather_impl!r}"
+            f"fused ALS kernel needs a float32 table, got {table.dtype}: "
+            "its row DMAs slice the HBM table one row at a time, which "
+            "Mosaic only aligns for 32-bit rows"
         )
     if precision is None:
         precision = jax.lax.Precision.HIGHEST
     else:
         precision = jax.lax.Precision(precision)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     b, k = idx.shape
     m, r = table.shape
-    if plan is None:
-        plan = fused_tile_plan(
-            m, r, k, table.dtype.itemsize, gather_impl
-        )
+    plan = fused_tile_plan(r, k)
     if plan is None:
         raise ValueError(
-            f"fused ALS kernel ({gather_impl}): no tile plan for table "
-            f"[{m}, {r}] within the VMEM budget "
-            f"({solver_vmem_budget()} B)"
+            f"fused ALS kernel: no tile plan for rank {r}, bucket width "
+            f"{k} within the VMEM/SMEM budgets "
+            f"({solver_vmem_budget()} / {solver_smem_budget()} B)"
         )
-    tb, kc, mc = plan
+    tb, kc = plan
     bp = -(-b // tb) * tb
     kp = -(-k // kc) * kc
-    mp = -(-m // mc) * mc
     if gram0 is None:
         gram0 = jnp.zeros((r, r), jnp.float32)
-    # zero-padded table rows are unreachable: valid ids are < m, masked
-    # entries carry zero weights
-    table = jnp.pad(table, ((0, mp - m), (0, 0)))
+    # whole-lane rows: a row DMA slices [1, R128] of the HBM table
+    table = jnp.pad(table, ((0, 0), (0, _pad128(r) - r)))
     idx = jnp.pad(idx, ((0, bp - b), (0, kp - k)))
     cw = jnp.pad(cw.astype(jnp.float32), ((0, bp - b), (0, kp - k)))
     bw = jnp.pad(bw.astype(jnp.float32), ((0, bp - b), (0, kp - k)))
@@ -580,149 +328,18 @@ def fused_gather_gram_solve(
         reg.astype(jnp.float32), (0, bp - b), constant_values=1.0
     )[:, None]
     gram0 = gram0.astype(jnp.float32)
-    if gather_impl == "dma":
-        # the scalar-prefetched [bs, Kp] index slab must fit SMEM: slice
-        # the batch dim so each pallas_call's slab stays under budget
-        # (equal tb-multiple slices share one compiled executable)
-        bs = max(
-            tb,
-            (solver_smem_budget() // max(kp * 4, 1)) // tb * tb,
-        )
-        outs = [
-            _fused_padded_dma(
-                gram0, table, idx[lo:lo + bs], cw[lo:lo + bs],
-                bw[lo:lo + bs], reg[lo:lo + bs],
-                tb=tb, kc=kc, interpret=bool(interpret),
-                precision=precision,
-            )
-            for lo in range(0, bp, bs)
-        ]
-        x = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
-    else:
-        x = _fused_padded_taa(
-            gram0, table, idx, cw, bw, reg,
-            tb=tb, kc=kc, mc=mc, interpret=bool(interpret),
+    # the scalar-prefetched [bs, Kp] index slab must fit SMEM: slice
+    # the batch dim so each pallas_call's slab stays under budget
+    # (equal tb-multiple slices share one compiled executable)
+    bs = max(tb, (solver_smem_budget() // max(kp * 4, 1)) // tb * tb)
+    outs = [
+        _fused_padded(
+            gram0, table, idx[lo:lo + bs], cw[lo:lo + bs],
+            bw[lo:lo + bs], reg[lo:lo + bs],
+            tb=tb, kc=kc, interpret=bool(interpret),
             precision=precision,
         )
+        for lo in range(0, bp, bs)
+    ]
+    x = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
     return x[:b]
-
-
-# (backend, m, r, bytes, precision, impl) -> probe result; process-wide
-# like the GJ solver probe
-_PROBE_CACHE: dict[tuple, bool] = {}
-
-
-def fused_solver_ok(
-    m: int, r: int, table_bytes: int = 4, precision=None,
-    gather_impl: str = "taa",
-) -> bool:
-    """Compile-and-run probe for ONE fused-kernel variant.
-
-    The kernel's speculative ops are the in-kernel gather form
-    (``take_along_axis`` sub-gathers for ``"taa"``; the scalar-prefetch
-    DMA row loop for ``"dma"``) and, for ``"taa"``, the streamed-table
-    grid — M selects between the resident and streamed shapes in
-    production, so BOTH are probed on small tables (a forced
-    multi-chunk plan stands in for the big-table case; the pipeline
-    shape, not the table height, is what lowering depends on).
-    ``precision`` and ``table_bytes`` must be the values production
-    will run with: both are static args of the pallas lowering, so a
-    probe at a different variant validates a different kernel.  Round 2
-    proved kernels must be probed ON the target backend before
-    production use; round 5 proved a kernel can pass every interpret
-    test and still never lower.  Cached per (backend, m, r, bytes,
-    precision, impl).
-    """
-    import logging
-
-    logger = logging.getLogger(__name__)
-    if gather_impl not in GATHER_IMPLS:
-        raise ValueError(
-            f"gather_impl must be one of {GATHER_IMPLS}, "
-            f"got {gather_impl!r}"
-        )
-    prec = (
-        jax.lax.Precision.HIGHEST if precision is None
-        else jax.lax.Precision(precision)
-    )
-    key = (
-        jax.default_backend(), int(m), int(r), int(table_bytes), prec,
-        gather_impl,
-    )
-    cached = _PROBE_CACHE.get(key)
-    if cached is not None:
-        return cached
-    if fused_tile_plan(m, r, 8, table_bytes, gather_impl) is None:
-        _PROBE_CACHE[key] = False
-        return False
-    # "taa" must also prove the streamed multi-chunk grid; "dma" has no
-    # streamed shape (the table never enters VMEM)
-    probe_plans = (
-        (None, (8, 128, 64)) if gather_impl == "taa" else (None,)
-    )
-    try:
-        dtype = jnp.bfloat16 if table_bytes == 2 else jnp.float32
-        idx = jnp.zeros((8, 8), jnp.int32)
-        one = jnp.ones((8, 8), jnp.float32)
-        reg = jnp.ones((8,), jnp.float32)
-        # 8 ratings of weight 1 on the all-ones row: A = 8·J + I,
-        # b = 8·1 -> x = 8/(8r+1)·1
-        want = 8.0 / (8.0 * r + 1.0)
-        ok = True
-        for probe_plan in probe_plans:
-            table = jnp.ones((128, r), dtype)
-            x = fused_gather_gram_solve(
-                table, idx, one, one, reg, plan=probe_plan,
-                precision=prec, gather_impl=gather_impl,
-            )
-            got = float(np.asarray(x[0, :1])[0])
-            if abs(got - want) >= 1e-4:
-                logger.warning(
-                    "fused ALS kernel probe (%s, %s) returned %g "
-                    "(want %g) at r=%d; using the unfused path",
-                    gather_impl,
-                    "streamed" if probe_plan else "resident",
-                    got, want, r,
-                )
-                ok = False
-                break
-    except Exception as e:  # noqa: BLE001 — any compile/lowering error
-        logger.warning(
-            "fused ALS kernel (%s) unavailable at m=%d r=%d on %r "
-            "(%s); using the unfused path",
-            gather_impl, m, r, jax.default_backend(), e,
-        )
-        ok = False
-    _PROBE_CACHE[key] = ok
-    return ok
-
-
-def resolve_gather_impl(
-    m: int, r: int, table_bytes: int = 4, precision=None,
-    requested: str = "auto",
-) -> str | None:
-    """Resolve ``ALSConfig(fused_gather=...)`` to a runnable impl.
-
-    An explicit request is probed as-is (``None`` if its kernel does
-    not pass on this backend — the caller degrades to XLA, loudly).
-    ``"auto"`` walks the per-backend preference order from the SAME
-    probe library the measurement battery runs
-    (`ops/gather_probe.preferred_order`: static documentation order
-    off-TPU, measured gather timings on silicon) and commits to the
-    first impl whose full-kernel compile-and-run probe passes.
-    """
-    if requested in GATHER_IMPLS:
-        return requested if fused_solver_ok(
-            m, r, table_bytes, precision, requested
-        ) else None
-    if requested != "auto":
-        raise ValueError(
-            f"fused_gather must be 'auto' or one of {GATHER_IMPLS}, "
-            f"got {requested!r}"
-        )
-    from .gather_probe import preferred_order
-
-    for impl in preferred_order(r, table_bytes):
-        if fused_solver_ok(m, r, table_bytes, precision, impl):
-            return impl
-    return None

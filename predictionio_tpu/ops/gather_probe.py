@@ -1,38 +1,24 @@
-"""Mosaic-lowerable gather forms: importable probe library.
+"""Gather forms the ALS hot loop can use, as timeable probes.
 
-Round-5 on-chip finding (docs/PERF_PLAN.md §0): the fused ALS kernel's
-flat ``jnp.take(table, flat_idx)`` does NOT lower on TPU — Mosaic's
-``lax.gather`` rule (jax/_src/pallas/mosaic/lowering.py:2481-2484,
-jax 0.9.0) requires ``take_along_axis`` semantics: input, indices and
-output sharing one 2D shape, gathering along axis 0 or 1
-(``tpu.dynamic_gather``).  ``tools/probe_gather.py`` was built to
-arbitrate the lowerable replacements on the real chip; this module is
-the library form of those probes (A-D) so that
+What the v5e compiler accepts was settled in PR 21 (CHANGES.md): of the
+in-kernel forms once arbitrated here only the row-DMA loop compiles, so
+there is nothing left to rank at run time.  What remains is what ROADMAP
+S3 still has to TIME against each other on the chip, at identical
+shapes:
 
-* the fused kernel's ``fused_gather="auto"`` resolution can reuse the
-  SAME compile-and-run arbitration (`preferred_order`) instead of a
-  drifting copy of it, and
-* ``tools/probe_gather.py`` stays a thin CLI over functions the test
-  suite can exercise in interpret mode (the ``--smoke`` gate step).
+  * ``dma_row_gather`` — the fused kernel's in-kernel gather
+    (`ops/fused_als.py`): rolling-window ``pltpu.make_async_copy`` row
+    copies, indices scalar-prefetched to SMEM, table in HBM.  Float32
+    only, rows lane-padded to 128 (Mosaic slices an HBM ref in whole
+    128-lane, 32-bit rows).
+  * ``xla_take`` — the XLA ``jnp.take`` baseline (what the unfused path
+    pays); the bar the Pallas form must beat.
+  * ``probe_xla_grouped_take`` — the tile-slab gather behind
+    ``ALSConfig(gather_mode="grouped")``, with its lane-slab control.
 
-The probe forms:
-
-  A. ``taa0_gather`` — same-shape ``take_along_axis(axis=0)``: indices
-     broadcast across lanes; the form the fused kernel's ``"taa"``
-     gather impl unrolls as ``ceil(TB*KC/MC)`` sub-gathers per chunk.
-  B. ``taa1_gather`` — the transposed lane-dim variant (axis=1 on
-     ``[R, M]``); measured for completeness, not used by the kernel
-     (a lane-dim gather of rank-R columns wastes the sublane dim).
-  C. ``dma_row_gather`` — in-kernel rolling-window
-     ``pltpu.make_async_copy`` row loop, indices scalar-prefetched to
-     SMEM (``PrefetchScalarGridSpec``); the kernel's ``"dma"`` impl.
-  D. ``xla_take`` — the XLA ``jnp.take`` baseline on identical shapes
-     (what the unfused path pays); the bar every Pallas form must beat.
-
-Off-TPU everything runs through the Pallas interpreter: that validates
-shapes and math (the CPU smoke) and answers nothing about Mosaic
-lowering — ``preferred_order`` therefore returns the static
-documentation order off-TPU and only measures on the real chip.
+On the CPU the Pallas form runs through the interpreter
+(`ops.solve.pallas_interpret`): that validates shapes and math (the
+gate's smoke) and says nothing about speed.
 """
 
 from __future__ import annotations
@@ -46,27 +32,18 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .solve import pallas_interpret
+
 __all__ = [
     "dma_row_gather",
-    "preferred_order",
     "probe_dma",
-    "probe_taa0",
-    "probe_taa1",
     "probe_xla_grouped_take",
     "probe_xla_take",
     "smoke",
-    "taa0_gather",
-    "taa1_gather",
     "xla_take",
 ]
 
 _DMA_WINDOW = 16
-
-
-def _interpret() -> bool:
-    # off-TPU the probes run in interpret mode: validates shapes/logic
-    # (a CPU smoke), answers nothing about Mosaic lowering
-    return jax.default_backend() != "tpu"
 
 
 def _bench(fn, *args, reps=20):
@@ -79,104 +56,7 @@ def _bench(fn, *args, reps=20):
     return (time.perf_counter() - t0) / reps, out
 
 
-# ---------------------------------------------------------------- A --
-
-def _taa0_kernel(table_ref, idx_ref, out_ref):
-    # idx_ref [N, R] (row id broadcast across lanes); supported form:
-    # out[i, j] = table[idx[i, j], j]
-    out_ref[:] = jnp.take_along_axis(table_ref[:], idx_ref[:], axis=0)
-
-
-@functools.partial(jax.jit, static_argnames=())
-def taa0_gather(table, idx):
-    """Same-shape ``take_along_axis(axis=0)`` gather as a Pallas call.
-
-    ``table [N, R]``, ``idx [N, R]`` (row ids broadcast across lanes)
-    -> ``[N, R]``.  The Mosaic-supported ``tpu.dynamic_gather`` form.
-    """
-    n, r = table.shape
-    return pl.pallas_call(
-        _taa0_kernel,
-        out_shape=jax.ShapeDtypeStruct((n, r), table.dtype),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        interpret=_interpret(),
-    )(table, idx)
-
-
-def probe_taa0(n, r, dtype) -> dict:
-    rng = np.random.default_rng(0)
-    table = jnp.asarray(
-        rng.normal(size=(n, r)).astype(np.float32)
-    ).astype(dtype)
-    rows = rng.integers(0, n, size=(n,)).astype(np.int32)
-    idx = jnp.asarray(np.broadcast_to(rows[:, None], (n, r)).copy())
-    try:
-        dt, out = _bench(taa0_gather, table, idx)
-        good = bool(
-            np.allclose(
-                np.asarray(out, np.float32),
-                np.asarray(table, np.float32)[rows],
-                atol=1e-2,
-            )
-        )
-        return dict(metric="taa_axis0", n=n, r=r,
-                    dtype=str(jnp.dtype(dtype).name), ok=good,
-                    seconds=dt, ns_per_row=dt / n * 1e9)
-    except Exception as e:  # noqa: BLE001 — lowering failures are data
-        return dict(metric="taa_axis0", n=n, r=r, ok=False,
-                    error=repr(e)[:300])
-
-
-# ---------------------------------------------------------------- B --
-
-def _taa1_kernel(table_ref, idx_ref, out_ref):
-    out_ref[:] = jnp.take_along_axis(table_ref[:], idx_ref[:], axis=1)
-
-
-@functools.partial(jax.jit, static_argnames=())
-def taa1_gather(table, idx):
-    """Lane-dim ``take_along_axis(axis=1)`` on ``[R, M]`` (form B)."""
-    r, m = table.shape
-    return pl.pallas_call(
-        _taa1_kernel,
-        out_shape=jax.ShapeDtypeStruct((r, m), table.dtype),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        interpret=_interpret(),
-    )(table, idx)
-
-
-def probe_taa1(m, r, dtype) -> dict:
-    rng = np.random.default_rng(0)
-    table = jnp.asarray(
-        rng.normal(size=(r, m)).astype(np.float32)
-    ).astype(dtype)
-    cols = rng.integers(0, m, size=(m,)).astype(np.int32)
-    idx = jnp.asarray(np.broadcast_to(cols[None, :], (r, m)).copy())
-    try:
-        dt, out = _bench(taa1_gather, table, idx)
-        good = bool(
-            np.allclose(
-                np.asarray(out, np.float32),
-                np.asarray(table, np.float32)[:, cols],
-                atol=1e-2,
-            )
-        )
-        return dict(metric="taa_axis1", m=m, r=r, ok=good, seconds=dt,
-                    ns_per_col=dt / m * 1e9)
-    except Exception as e:  # noqa: BLE001
-        return dict(metric="taa_axis1", m=m, r=r, ok=False,
-                    error=repr(e)[:300])
-
-
-# ---------------------------------------------------------------- C --
+# ------------------------------------------------------ row DMA --
 
 def _dma_kernel(idx_ref, table_ref, out_ref, sem):
     # idx_ref is scalar-prefetched (SMEM); issue one row DMA per output
@@ -210,51 +90,44 @@ def _dma_kernel(idx_ref, table_ref, out_ref, sem):
 
 @functools.partial(jax.jit, static_argnames=("nout",))
 def dma_row_gather(table, idx, *, nout):
-    """Rolling-window async row-copy gather (form C): ``table [M, R]``
-    stays in ANY/HBM, ``idx [nout]`` is scalar-prefetched to SMEM, one
+    """Rolling-window async row-copy gather: ``table [M, R]`` (float32)
+    stays in HBM, ``idx [nout]`` is scalar-prefetched to SMEM, one
     ``make_async_copy`` per output row."""
     _, r = table.shape
+    # whole-lane rows, like the fused kernel's table
+    r128 = -(-r // 128) * 128
+    table = jnp.pad(table, ((0, 0), (0, r128 - r)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.SemaphoreType.DMA((_DMA_WINDOW,))],
     )
     return pl.pallas_call(
         _dma_kernel,
-        out_shape=jax.ShapeDtypeStruct((nout, r), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((nout, r128), table.dtype),
         grid_spec=grid_spec,
-        interpret=_interpret(),
-    )(idx, table)
+        interpret=pallas_interpret(),
+    )(idx, table)[:, :r]
 
 
-def probe_dma(m, nout, r, dtype) -> dict:
+def probe_dma(m, nout, r) -> dict:
     rng = np.random.default_rng(0)
-    table = jnp.asarray(
-        rng.normal(size=(m, r)).astype(np.float32)
-    ).astype(dtype)
+    table = jnp.asarray(rng.normal(size=(m, r)).astype(np.float32))
     rows = rng.integers(0, m, size=(nout,)).astype(np.int32)
     idx = jnp.asarray(rows)
-    try:
-        dt, out = _bench(
-            functools.partial(dma_row_gather, nout=nout), table, idx
-        )
-        good = bool(
-            np.allclose(
-                np.asarray(out, np.float32),
-                np.asarray(table, np.float32)[rows],
-                atol=1e-2,
-            )
-        )
-        return dict(metric="dma_row_gather", m=m, nout=nout, r=r,
-                    ok=good, seconds=dt, ns_per_row=dt / nout * 1e9)
-    except Exception as e:  # noqa: BLE001
-        return dict(metric="dma_row_gather", m=m, nout=nout, r=r,
-                    ok=False, error=repr(e)[:300])
+    dt, out = _bench(
+        functools.partial(dma_row_gather, nout=nout), table, idx
+    )
+    good = bool(
+        np.allclose(np.asarray(out), np.asarray(table)[rows], atol=1e-2)
+    )
+    return dict(metric="dma_row_gather", m=m, nout=nout, r=r,
+                ok=good, seconds=dt, ns_per_row=dt / nout * 1e9)
 
 
-# ---------------------------------------------------------------- E --
+# ------------------------------------------------- grouped take --
 
 def probe_xla_grouped_take(m, nout, r, dtype, group=None) -> list[dict]:
     """Grouped slab gather, BOTH layouts, vs the plain row take.
@@ -317,10 +190,10 @@ def probe_xla_grouped_take(m, nout, r, dtype, group=None) -> list[dict]:
     return out
 
 
-# ---------------------------------------------------------------- D --
+# ----------------------------------------------------- XLA take --
 
 def xla_take(table, idx):
-    """The XLA row-take baseline on identical shapes (form D)."""
+    """The XLA row-take baseline on identical shapes."""
     return jnp.take(table, idx, axis=0)
 
 
@@ -339,62 +212,13 @@ def probe_xla_take(m, nout, r, dtype) -> dict:
                 effective_gbps=bytes_moved / dt / 1e9)
 
 
-# -- arbitration ------------------------------------------------------------
-
-# fused-kernel gather impls in documentation order; "taa" first because
-# the sub-gather form keeps the MXU pipeline fed from VMEM while the DMA
-# loop's issue rate is the open on-chip question (PERF_PLAN §4 item 2)
-_STATIC_ORDER = ("taa", "dma")
-
-# (backend, r, table_bytes) -> measured preference order
-_ORDER_CACHE: dict[tuple, tuple] = {}
-
-
-def preferred_order(r: int = 64, table_bytes: int = 4) -> tuple:
-    """Gather-impl preference order for ``fused_gather="auto"``.
-
-    Off-TPU (interpret mode: every form "lowers", timings are
-    meaningless) this is the static documentation order — deterministic,
-    which the CPU test suite depends on.  On TPU it compile-and-runs the
-    small form-A and form-C probes once per (backend, rank, dtype) and
-    ranks the forms that actually lowered by measured per-row gather
-    time; forms that failed sort last so ``resolve_gather_impl`` still
-    probes them (the standalone probe and the full kernel can disagree —
-    only `fused_solver_ok` is authoritative for the kernel).
-    """
-    if jax.default_backend() != "tpu":
-        return _STATIC_ORDER
-    key = (jax.default_backend(), int(r), int(table_bytes))
-    cached = _ORDER_CACHE.get(key)
-    if cached is not None:
-        return cached
-    dtype = jnp.bfloat16 if table_bytes == 2 else jnp.float32
-    n = 2048
-    results = {
-        "taa": probe_taa0(n, r, dtype),
-        "dma": probe_dma(n, n, r, dtype),
-    }
-
-    def rank_key(impl):
-        rec = results[impl]
-        ok = bool(rec.get("ok"))
-        return (not ok, rec.get("ns_per_row", float("inf")))
-
-    order = tuple(sorted(_STATIC_ORDER, key=rank_key))
-    _ORDER_CACHE[key] = order
-    return order
-
-
 def smoke(r: int = 16) -> list[dict]:
     """Small-shape run of every probe form: CPU interpret-mode shape and
     logic validation (the gate.sh step), no lowering claims.  Returns
-    the records; raises nothing — a failed form carries ok=False."""
+    the records; a form whose math is wrong carries ok=False."""
     recs = [
         probe_xla_take(512, 256, r, jnp.float32),
-        probe_taa0(256, r, jnp.float32),
-        probe_taa0(256, r, jnp.bfloat16),
-        probe_taa1(256, r, jnp.float32),
-        probe_dma(512, 256, r, jnp.float32),
+        probe_dma(512, 256, r),
     ]
     recs.extend(probe_xla_grouped_take(512, 256, r, jnp.float32))
     return recs

@@ -3,9 +3,9 @@
 The ALS hot loop solves hundreds of thousands of small (R<=128) SPD
 normal-equation systems per half-iteration (`models/als.py`).  XLA lowers
 ``lax.linalg.cholesky`` + two ``triangular_solve`` calls on TPU to
-loop-heavy code that runs at ~13 GFLOP/s (measured on v5e: 1.35 s for
-165k rank-64 systems — comparable to the *entire* rest of the
-half-iteration).  This kernel instead keeps a tile of systems resident in
+loop-heavy code (the 2026-07-30 phase split in docs/ARCHITECTURE.md put
+it at ~13 GFLOP/s; not re-measured on the current toolchain).  This
+kernel instead keeps a tile of systems resident in
 VMEM and runs **augmented Gauss-Jordan elimination** lock-step across the
 batch:
 
@@ -31,35 +31,41 @@ equations in ``solver_mode="full"`` AND for the B×B subsystems of the
 iALS++ subspace sweep (``solver_mode="subspace"``, `models/als.py
 _subspace_sweep`): the tile sizing (`_tile_rows`) packs MORE systems
 per VMEM tile as R shrinks, so the kernel gets faster per system at
-block sizes, not bypassed.  ``interpret=True`` (automatic off-TPU)
-runs the same kernel through the Pallas interpreter, which is what the
-CPU test suite exercises.
+block sizes, not bypassed.  ``interpret=True`` runs the same kernel
+through the Pallas interpreter; :func:`pallas_interpret` selects it only
+for a process the operator put on the CPU (the test suite, dry runs).
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "spd_solve_batched",
     "cholesky_solve_batched",
-    "pallas_solver_ok",
+    "pallas_interpret",
     "solver_smem_budget",
     "solver_vmem_budget",
     "solver_tile_footprint",
 ]
 
-logger = logging.getLogger(__name__)
-
 _EPS = 1e-20
+
+
+def pallas_interpret() -> bool:
+    """Whether the Pallas TPU kernels run through the interpreter.
+
+    Only when the process is on the CPU backend, which takes
+    ``JAX_PLATFORMS=cpu`` — the operator's word.  Every other backend
+    gets the Mosaic compile, and its error if there is one.
+    """
+    return jax.default_backend() == "cpu"
 
 
 def _gj_kernel(a_ref, b_ref, x_ref, m_scr):
@@ -92,13 +98,11 @@ def _gj_kernel(a_ref, b_ref, x_ref, m_scr):
 def solver_vmem_budget() -> int:
     """Per-core VMEM budget (bytes) the tile sizing works against.
 
-    There is no public query API for scoped VMEM; every shipping TPU
-    generation exposes ~16 MiB per core to a Pallas program (pallas
-    guide "VMEM ~16 MB/core"; confirmed empirically on v5e where an
-    ~8 MiB scratch + double-buffered input blocks failed to compile and
-    half that fit).  ``PIO_TPU_VMEM_BYTES`` overrides for a future
-    generation or a deliberately tighter/looser budget — the knob the
-    round-2 verdict asked for in place of a hardcoded heuristic.
+    There is no public query API for scoped VMEM; Mosaic's default
+    scoped limit is 16 MiB per core, and the tiles `_tile_rows` derives
+    from it compile on v5e at ranks 10/16/64/128 (CHANGES.md, PR 21).
+    ``PIO_TPU_VMEM_BYTES`` overrides for a future generation or a
+    deliberately tighter/looser budget.
     """
     env = os.environ.get("PIO_TPU_VMEM_BYTES")
     if env:
@@ -109,13 +113,12 @@ def solver_vmem_budget() -> int:
 def solver_smem_budget() -> int:
     """Per-core SMEM budget (bytes) for scalar-prefetched operands.
 
-    The fused kernel's ``"dma"`` gather impl prefetches a batch tile's
+    The fused kernel (`ops/fused_als.py`) prefetches a batch tile's
     ``[TB, Kpad]`` int32 index block to SMEM
     (``PrefetchScalarGridSpec``); SMEM is the scalar core's memory and
     far smaller than VMEM, with no public query API either.  256 KiB is
-    a deliberately conservative planning default — the on-chip
-    ``fused_smoke``/``probe_gather`` battery is what validates the real
-    ceiling; ``PIO_TPU_SMEM_BYTES`` overrides it the same way
+    a deliberately conservative planning default;
+    ``PIO_TPU_SMEM_BYTES`` overrides it the same way
     ``PIO_TPU_VMEM_BYTES`` overrides the VMEM budget.
     """
     env = os.environ.get("PIO_TPU_SMEM_BYTES")
@@ -144,9 +147,8 @@ def solver_tile_footprint(tb: int, r: int) -> int:
 
 def _tile_rows(r: int) -> int:
     """Largest power-of-two batch tile whose total footprint fits in half
-    the VMEM budget (headroom for Mosaic's own temporaries; the same
-    margin the v5e observation implied: at R=64 this yields a 64-row
-    tile where 128 was observed to fit and 256 to fail)."""
+    the VMEM budget (headroom for Mosaic's own temporaries): a 64-row
+    tile at R=64, 16 rows at R=128."""
     budget = solver_vmem_budget() // 2
     tb = 512
     while tb > 8 and solver_tile_footprint(tb, r) > budget:
@@ -182,10 +184,10 @@ def spd_solve_batched(A, b, interpret: bool | None = None):
     """Solve ``A[i] x[i] = b[i]`` for a batch of SPD systems.
 
     A: [B, R, R] float32, b: [B, R] float32 -> x: [B, R] float32.
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU.
+    ``interpret=None`` follows :func:`pallas_interpret`.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     B = A.shape[0]
     tb = _tile_rows(A.shape[-1])
     pad = (-B) % tb
@@ -205,52 +207,3 @@ def spd_solve_batched(A, b, interpret: bool | None = None):
 # historical name (the first revision of this kernel factorized via
 # Cholesky); ALSConfig docs and tests may refer to either
 cholesky_solve_batched = spd_solve_batched
-
-
-# (backend, rank) -> did the kernel compile AND run there?  Process-wide:
-# a Mosaic regression doesn't vary within a process, and re-probing per
-# trainer would pay a compile each time.
-_PROBE_CACHE: dict[tuple[str, int], bool] = {}
-
-
-def pallas_solver_ok(rank: int) -> bool:
-    """Compile-probe the Gauss-Jordan kernel at ``rank`` on this backend.
-
-    Round 2 proved the failure mode is real: the first kernel revision
-    didn't lower on v5e at all (Mosaic ``dynamic_slice``, VMEM overrun)
-    and only a real-chip compile caught it.  ``ALSTrainer`` calls this
-    before committing to ``solver="pallas"`` so a Mosaic regression on a
-    new chip generation degrades to the XLA solver with a warning
-    instead of failing the train.  One tile-sized probe per
-    (backend, rank) per process; failures log the compiler error.
-    """
-    key = (jax.default_backend(), int(rank))
-    cached = _PROBE_CACHE.get(key)
-    if cached is not None:
-        return cached
-    try:
-        tb = _tile_rows(rank)
-        A = jnp.broadcast_to(
-            jnp.eye(rank, dtype=jnp.float32) * 2.0, (tb, rank, rank)
-        )
-        b = jnp.ones((tb, rank), jnp.float32)
-        x = spd_solve_batched(A, b)
-        # d2h fetch: both compile and runtime failures must surface here
-        # (block_until_ready is a no-op on some tunnel backends); 2I·x=1
-        # has the known solution 0.5, so a silently-wrong kernel also
-        # fails the probe
-        ok = bool(abs(float(np.asarray(x[0, :1])[0]) - 0.5) < 1e-3)
-        if not ok:
-            logger.warning(
-                "pallas GJ solver probe returned wrong values at "
-                "rank %d; falling back to the XLA solver", rank,
-            )
-    except Exception as e:  # noqa: BLE001 — any compile/lowering error
-        logger.warning(
-            "pallas GJ solver unavailable at rank %d on backend %r "
-            "(%s); falling back to the XLA solver",
-            rank, jax.default_backend(), e,
-        )
-        ok = False
-    _PROBE_CACHE[key] = ok
-    return ok

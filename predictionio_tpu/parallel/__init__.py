@@ -23,8 +23,8 @@ from .mesh import (
     MODEL_AXIS,
     data_sharding,
     distributed_init,
+    describe_devices,
     enable_compilation_cache,
-    fence,
     make_mesh,
     pad_to_multiple,
     replicated,
@@ -47,8 +47,8 @@ __all__ = [
     "MODEL_AXIS",
     "data_sharding",
     "distributed_init",
+    "describe_devices",
     "enable_compilation_cache",
-    "fence",
     "make_mesh",
     "pad_to_multiple",
     "replicated",

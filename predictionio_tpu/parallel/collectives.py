@@ -21,30 +21,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-import inspect
-
-if hasattr(jax, "shard_map"):            # jax >= 0.8
-    _shard_map_impl = jax.shard_map
-else:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
+from .mesh import DATA_AXIS
 
 # collective outputs (psum/all_gather) are replicated in ways the static
-# checker can't always infer; disable it under whichever flag name this
-# jax spells it
-_CHECK_FLAG = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map_impl).parameters
-    else "check_rep"
-)
+# checker can't always infer
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
-
-def shard_map(f=None, **kw):
-    kw.setdefault(_CHECK_FLAG, False)
-    if f is None:
-        return functools.partial(_shard_map_impl, **kw)
-    return _shard_map_impl(f, **kw)
-
-from .mesh import DATA_AXIS
 
 __all__ = [
     "all_reduce_sum",

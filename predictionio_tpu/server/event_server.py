@@ -481,7 +481,7 @@ class EventServer(HTTPServerBase):
                 # Parse/validate first, then insert every valid event in
                 # ONE insert_batch (one executemany + one WAL commit):
                 # per-event inserts put this route at 7.3k ev/s vs 33k
-                # for the importer (SERVING_BENCH.md).  Statuses stay
+                # for the importer (CPU builder number).  Statuses stay
                 # positional; invalid events don't block valid siblings;
                 # duplicate eventIds keep last-in-batch-wins order
                 # (executemany preserves row order).  from_json already

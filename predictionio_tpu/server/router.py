@@ -543,7 +543,7 @@ class RouterServer(HTTPServerBase):
             self._pool = None
 
     # -- health ------------------------------------------------------------
-    def check_replica(self, replica: Replica) -> bool:
+    def probe_replica(self, replica: Replica) -> bool:
         try:
             status, data, _ = replica.request(
                 "GET", "/", None,
@@ -560,7 +560,7 @@ class RouterServer(HTTPServerBase):
 
     def check_all(self) -> None:
         for r in self.replicas:
-            self.check_replica(r)
+            self.probe_replica(r)
 
     def scrape_all(self) -> None:
         """pio-lens: pull every replica's /metrics on the pooled
@@ -1147,14 +1147,28 @@ class RouterServer(HTTPServerBase):
 # -- replica process spawning ----------------------------------------------
 
 
+def chip_pin_env(chip: int) -> dict:
+    """Environment that gives a process exactly ONE TPU chip of this
+    host.  libtpu reads these when it loads, so they must be in the
+    child's environment before it imports jax: the visible chip, and
+    process bounds of one chip so that several processes may load
+    libtpu side by side, each on its own chip."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 def spawn_replica(engine_json, index: int, coord_dir,
                   extra_args=(), env=None,
                   python: str = sys.executable,
-                  engine_name=None) -> dict:
+                  engine_name=None, chip=None) -> dict:
     """Launch one replica as a real subprocess (`pio-tpu deploy` on an
     ephemeral port, announcing it through a port file in
     ``coord_dir``).  ``engine_name`` dispatches a pio-forge registry
     engine (``deploy --engine NAME``) instead of an engine.json path.
+    ``chip`` pins the replica to that TPU chip (:func:`chip_pin_env`).
     Returns ``{"proc", "port_file", "log_path", "index"}``; pair with
     :func:`wait_for_port_file`."""
     coord_dir = Path(coord_dir)
@@ -1166,6 +1180,8 @@ def spawn_replica(engine_json, index: int, coord_dir,
 
     pkg_root = str(Path(__file__).resolve().parent.parent.parent)
     env = dict(env if env is not None else _os.environ)
+    if chip is not None:
+        env.update(chip_pin_env(chip))
     pp = env.get("PYTHONPATH", "")
     if pkg_root not in pp.split(_os.pathsep):
         env["PYTHONPATH"] = (

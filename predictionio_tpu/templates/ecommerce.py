@@ -112,13 +112,11 @@ class ECommAlgorithmParams(Params):
     lam: float = 0.01
     alpha: float = 1.0
     seed: int = 3
-    # scaling knobs (models/als.py): "fused"/"pallas" kernels
-    # compile-probe and degrade to "xla"; "sharded" placement
-    # shards factor tables AND the rating COO over the mesh
+    # scaling knobs (models/als.py): "fused"/"pallas" kernels fail
+    # the train if they do not compile;
+    # "sharded" placement shards factor tables AND the rating COO
+    # over the mesh
     solver: str = "xla"
-    # in-kernel gather form of the fused kernel (solver="fused"):
-    # "auto" | "taa" | "dma" (engine.json key fusedGather)
-    fused_gather: str = "auto"
     solver_mode: str = "full"    # "subspace" = iALS++ block sweep
     subspace_size: int = 16
     factor_placement: str = "replicated"
@@ -151,7 +149,6 @@ class ECommAlgorithm(Algorithm):
                 rank=p.rank, num_iterations=p.num_iterations, lam=p.lam,
                 implicit=implicit, alpha=p.alpha, seed=p.seed,
                 solver=p.solver, factor_placement=p.factor_placement,
-                fused_gather=p.fused_gather,
                 solver_mode=p.solver_mode,
                 subspace_size=p.subspace_size,
                 gather_dtype=p.gather_dtype,

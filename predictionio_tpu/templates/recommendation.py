@@ -350,12 +350,9 @@ class ALSAlgorithmParams(Params):
     # gather access pattern: "row" | "grouped" (tile-aligned slab
     # gather — models/als.py ALSConfig.gather_mode)
     gather_mode: str = "row"
-    # batched SPD solver: "xla" | "pallas" | "fused" (compile-probed;
-    # degrades to xla if the kernel doesn't lower on this backend)
+    # batched SPD solver: "xla" | "pallas" | "fused" (a kernel that
+    # does not compile on this backend fails the train)
     solver: str = "xla"
-    # fused kernel's in-kernel gather form ("auto" | "taa" | "dma" —
-    # engine.json key fusedGather; models/als.py ALSConfig.fused_gather)
-    fused_gather: str = "auto"
     # rank-sweep strategy: "full" (R×R solve per row) | "subspace"
     # (iALS++ block sweep — engine.json keys solverMode/subspaceSize;
     # models/als.py ALSConfig.solver_mode)
@@ -481,7 +478,6 @@ class ALSAlgorithm(Algorithm):
             gather_dtype=p.gather_dtype,
             gather_mode=p.gather_mode,
             solver=p.solver,
-            fused_gather=p.fused_gather,
             solver_mode=p.solver_mode,
             subspace_size=p.subspace_size,
             factor_placement=p.factor_placement,
@@ -605,13 +601,17 @@ class ALSAlgorithm(Algorithm):
         if getattr(self.params, "distributed_topk", False):
             # the ring index compiles BOTH variants (clean + parity-
             # coded; + the quantized candidate variant under
-            # retrieval != exact) per (batch, k): cover the common
-            # solo shapes so a first degradation never pays a
-            # mid-request compile; rarer batched shapes compile once
-            # under load like the local pow2 ladder
+            # retrieval != exact) per (batch, k).  Warm the two shapes
+            # serving dispatches, like the local ladder above: solo
+            # queries ride predict (the query's own k, batch 1),
+            # coalesced ones ride batch_predict (pow2 k, every pow2 B
+            # the padded batcher can dispatch) — so neither a first
+            # degradation nor a first burst pays a mid-request compile
             idx = self._sharded_index(model)
-            for k in {min(pow2_ceil(k), n) for k in (1, 4, 10, 16, 20)}:
+            for k in {min(k, n) for k in (1, 4, 10, 20)}:
                 idx.warm(k, batch=1)
+            for b in pow2_ladder(max_batch):
+                idx.warm(min(pow2_ceil(10), n), batch=b)
 
     def predict(self, model: ALSModel, query: Query) -> PredictedResult:
         uix = model.users.get(query.user)

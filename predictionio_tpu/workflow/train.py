@@ -121,6 +121,7 @@ def run_train(
     import jax
 
     from ..obs import get_tracer, tower, xray
+    from ..parallel.mesh import describe_devices
 
     # compile/device observability for the whole training run: every
     # half-iteration compile books into pio_jit_compiles_total{fn} and
@@ -130,6 +131,7 @@ def run_train(
 
     ctx = ctx or WorkflowContext(mode="Training")
     wp = workflow_params or WorkflowParams()
+    device = describe_devices()
     md = ctx.storage.get_metadata()
     chief = jax.process_index() == 0
     if jax.process_count() > 1:
@@ -155,6 +157,10 @@ def run_train(
             "engineVariant": engine_variant,
             "batch": wp.batch,
             "nDevices": ctx.n_devices,
+            # what jax reports, so a manifest says which device its
+            # numbers came from
+            "platform": device["platform"],
+            "deviceKind": device["kind"],
         },
         worker=jax.process_index(),
         n_workers=jax.process_count(),
@@ -199,7 +205,9 @@ def run_train(
         if chief:
             md.engine_instance_update(ei)
         completed = True
-        session.finalize("completed")
+        session.finalize(
+            "completed", compileCache=xray.compile_cache_summary()
+        )
         logger.info("training finished: instance %s", instance_id)
         return instance_id
     except TrainingInterrupted as e:

@@ -563,8 +563,7 @@ def test_knob_lattice_consistency():
     the bounds are deliberately looser than the dedicated single-knob
     tests' (and hold on REAL TPU kernels, not just the near-exact
     interpret mode CPU runs them in — kernel f32 needs ~5e-3 at factor
-    level, fused+bf16 ~0.1: tests/test_als.py pallas bound,
-    tests/test_fused_als.py).  Implicit mode adds only two extreme
+    level: tests/test_als.py pallas bound, tests/test_fused_als.py).  Implicit mode adds only two extreme
     corners: the knob plumbing is implicit-agnostic."""
     import itertools
 
@@ -581,13 +580,15 @@ def test_knob_lattice_consistency():
         )
     ] + [
         (True, "pallas", "bfloat16", "grouped", "sharded"),
-        (True, "fused", "bfloat16", "row", "replicated"),
+        (True, "fused", "float32", "row", "replicated"),
     ]
     refs = {}
     data = {}
     for implicit, solver, dtype, mode, placement in combos:
-        if solver == "fused" and mode == "grouped":
-            continue  # rejected combination
+        if solver == "fused" and (
+            mode == "grouped" or dtype == "bfloat16"
+        ):
+            continue  # rejected combinations
         if implicit not in data:
             u, i, v, nu, ni = _toy(density=0.5, seed=11)
             vals = np.abs(v) + 1.0 if implicit else v
@@ -934,3 +935,48 @@ def test_device_expand_sides_reconstruction():
                           vi2[starts_i[r]:starts_i[r] + n].tolist()))
         assert got == want, f"item {r}"
         pos += n
+
+
+@pytest.mark.parametrize("extra", [
+    dict(solver="pallas"),
+    dict(solver="fused"),
+    dict(solver="pallas", solver_mode="subspace", subspace_size=2),
+])
+def test_kernel_solvers_run_per_device_on_a_replicated_mesh(
+        extra, monkeypatch):
+    """Replicated placement on a multi-device mesh hands each Pallas
+    kernel its own data shard through `shard_map`: XLA partitions the
+    rest of the half-iteration from the input shardings but refuses to
+    partition a Mosaic kernel ("Mosaic kernels cannot be automatically
+    partitioned", the 2x2 v5e host, PR 21 — interpret mode on the CPU
+    never shows it).  The sharded path is already inside a `shard_map`
+    body and must not nest another."""
+    from predictionio_tpu.models import als as als_mod
+    from predictionio_tpu.parallel import make_mesh
+
+    mesh = make_mesh()
+    assert mesh.size == 8
+    seen = []
+    real = als_mod._per_device
+
+    def spy(fn, m, in_specs, out_specs):
+        seen.append(m)
+        return real(fn, m, in_specs, out_specs)
+
+    monkeypatch.setattr(als_mod, "_per_device", spy)
+    u, i, v, nu, ni = _toy(density=0.5, seed=21)
+    kw = dict(rank=4, num_iterations=2, lam=0.1, seed=5)
+    mode = {k: x for k, x in extra.items() if k != "solver"}
+    ref = train_als((u, i, v), nu, ni, ALSConfig(**kw, **mode), mesh=mesh)
+    assert seen == []  # the xla solver has no kernel to place
+    got = train_als((u, i, v), nu, ni, ALSConfig(**kw, **extra), mesh=mesh)
+    assert seen and all(m is mesh for m in seen)
+    np.testing.assert_allclose(
+        got.user_factors, ref.user_factors, rtol=2e-4, atol=2e-4
+    )
+    seen.clear()
+    train_als(
+        (u, i, v), nu, ni,
+        ALSConfig(**kw, **extra, factor_placement="sharded"), mesh=mesh,
+    )
+    assert seen and all(m is None for m in seen)

@@ -361,3 +361,51 @@ def test_serving_template_distributed_topk_rides_request_deadline(mesh):
     ]
     assert elapsed < 15.0, elapsed
     assert model.sharded_topk_index().summary()["degradedPolls"] >= 1
+
+
+def test_ring_warmup_covers_what_serving_dispatches(mesh):
+    """The ring index's warm-up covers both shapes serving dispatches —
+    solo queries through `predict` (the query's own k, batch 1) and
+    coalesced ones through `batch_predict` (pow2 k, every pow2 batch the
+    padded batcher can produce) — so no query after warm-up compiles
+    (what `chip_smoke.py` checks on the chips for the sharded variant)."""
+    from predictionio_tpu.controller.base import instantiate
+    from predictionio_tpu.ops.distributed_topk import _ring_callable
+    from predictionio_tpu.storage.bimap import StringIndex
+    from predictionio_tpu.templates.recommendation import (
+        ALSAlgorithm, ALSModel, Query, recommendation_engine,
+    )
+
+    p = recommendation_engine().params_from_variant({
+        "datasource": {"params": {"app_name": "x"}},
+        "algorithms": [{"name": "als", "params": {
+            "rank": 4, "distributedTopk": True}}],
+    })
+    algo = instantiate(ALSAlgorithm, p.algorithms[0][1])
+    rng = np.random.default_rng(5)
+    model = ALSModel(
+        user_factors=rng.normal(size=(9, 4)).astype(np.float32),
+        item_factors=rng.normal(size=(43, 4)).astype(np.float32),
+        users=StringIndex.from_values([f"u{i}" for i in range(9)]),
+        items=StringIndex.from_values([f"i{i}" for i in range(43)]),
+        item_props={},
+    )
+    algo.warmup(model, max_batch=8)
+    idx = model.sharded_topk_index()
+
+    def executables():
+        return {
+            (k, coded): _ring_callable(
+                idx.mesh, idx.axis, k, coded)._cache_size()
+            for k in (1, 4, 10, 16, 20) for coded in (False, True)
+        }
+
+    warmed = executables()
+    for num in (1, 4, 10, 20):
+        assert len(algo.predict(
+            model, Query(user="u1", num=num)).item_scores) == num
+    for batch in (2, 4, 8):
+        out = algo.batch_predict(
+            model, [Query(user=f"u{i}", num=10) for i in range(batch)])
+        assert all(len(r.item_scores) == 10 for r in out)
+    assert executables() == warmed

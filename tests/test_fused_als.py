@@ -1,12 +1,9 @@
 """Fused gather+Gram+solve kernel (`ops/fused_als.py`): interpret-mode
-parity against the unfused `_solve_buckets` path, per-side routing, tile
-sizing, and fail-safe degradation — for BOTH Mosaic-lowerable gather
-impls ("taa" take_along_axis sub-gathers, "dma" scalar-prefetched row
-copies) on resident AND forced-streamed plans, including indices that
-cross (8,128) tile boundaries, masked out-of-chunk ids, tail blocks,
-and the bf16-table/fp32-accumulation path.  The on-chip lowering
-answer comes from `tools/measure_tpu.sh` `fused_smoke` /
-`probe_gather`; everything here proves the math.
+parity against the unfused `_solve_buckets` path and a dense float64
+reference, per-bucket routing, tile sizing, and a kernel that does not
+compile failing the train — including indices that cross (8,128) tile
+boundaries, masked entries and tail blocks.  Whether the kernel compiles is answered on
+the chip (`chip_smoke.py`); everything here proves the math.
 """
 
 import numpy as np
@@ -14,12 +11,8 @@ import pytest
 
 from predictionio_tpu.models.als import ALSConfig, ALSTrainer, train_als
 from predictionio_tpu.ops.fused_als import (
-    GATHER_IMPLS,
     fused_gather_gram_solve,
-    fused_side_fits,
-    fused_solver_ok,
     fused_tile_plan,
-    resolve_gather_impl,
 )
 
 
@@ -64,16 +57,17 @@ def test_kernel_matches_dense_reference():
 @pytest.mark.parametrize("weighted", [False, True])
 def test_fused_train_matches_xla(implicit, weighted):
     """End-to-end ALS with solver='fused' must reproduce the XLA path
-    (both sides fit VMEM at toy scale, so BOTH halves run fused)."""
+    (every toy-scale bucket has a tile plan, so BOTH halves run
+    fused)."""
     u, i, v, nu, ni = _toy()
     if implicit:
         v = np.abs(v) + 0.5
     kw = dict(rank=5, num_iterations=3, lam=0.05, implicit=implicit,
               alpha=1.5, weighted_lambda=weighted)
     ref = train_als((u, i, v), nu, ni, ALSConfig(**kw))
-    tr = ALSTrainer((u, i, v), nu, ni, ALSConfig(solver="fused", **kw))
-    assert tr.solver == "fused"
-    got = tr.train()
+    got = ALSTrainer(
+        (u, i, v), nu, ni, ALSConfig(solver="fused", **kw)
+    ).train()
     np.testing.assert_allclose(
         got.user_factors, ref.user_factors, rtol=5e-4, atol=5e-4
     )
@@ -82,73 +76,45 @@ def test_fused_train_matches_xla(implicit, weighted):
     )
 
 
-def test_fused_bf16_gather_close_to_f32():
-    u, i, v, nu, ni = _toy(seed=5)
-    kw = dict(rank=5, num_iterations=2, lam=0.1)
-    ref = train_als((u, i, v), nu, ni, ALSConfig(**kw))
-    got = train_als((u, i, v), nu, ni, ALSConfig(
-        solver="fused", gather_dtype="bfloat16", **kw))
-    np.testing.assert_allclose(
-        got.user_factors, ref.user_factors, rtol=0.1, atol=0.1
-    )
+def test_fused_refuses_bf16_table():
+    """The row DMAs need 32-bit rows (v5e Mosaic: a one-row slice of a
+    bf16 ref is not tile-aligned), so the combination is refused at
+    config time and at the kernel entry — never run as something
+    else."""
+    with pytest.raises(ValueError, match="bfloat16"):
+        ALSConfig(solver="fused", gather_dtype="bfloat16")
+    import jax.numpy as jnp
+
+    table, idx, cw, bw, reg = _parity_case()
+    with pytest.raises(ValueError, match="float32 table"):
+        fused_gather_gram_solve(
+            jnp.asarray(table).astype(jnp.bfloat16), idx, cw, bw, reg
+        )
 
 
-def test_fused_chunked_table_matches_resident(monkeypatch):
-    """A VMEM budget too small for the whole table forces the streamed
-    multi-chunk path (third grid axis + id-range masking); results must
-    match the dense reference exactly like the resident path."""
-    from predictionio_tpu.ops import fused_als as fmod
-
-    rng = np.random.default_rng(2)
-    # 20k x 8 table: ~10 MB padded (lane dim pads 8 -> 128), resident at
-    # the default 16 MB budget but forced to stream at 4 MB
-    M, R, B, K = 20000, 8, 11, 19
-    table = rng.normal(size=(M, R)).astype(np.float32)
-    idx = rng.integers(0, M, size=(B, K)).astype(np.int32)
-    mask = (rng.random((B, K)) < 0.8).astype(np.float32)
-    val = (rng.random((B, K)) * 3 + 1).astype(np.float32)
-    reg = rng.random(B).astype(np.float32) + 0.5
-
-    resident_plan = fmod.fused_tile_plan(M, R, K, 4)
-    assert resident_plan is not None and resident_plan[2] >= M
-    resident = np.asarray(fused_gather_gram_solve(
-        table, idx, mask, val * mask, reg
-    ))
-    monkeypatch.setenv("PIO_TPU_VMEM_BYTES", str(4 << 20))
-    plan = fmod.fused_tile_plan(M, R, K, 4)
-    assert plan is not None and plan[2] < M, plan
-    assert -(-M // plan[2]) > 1  # really multi-chunk
-    chunked = np.asarray(fused_gather_gram_solve(
-        table, idx, mask, val * mask, reg
-    ))
-    np.testing.assert_allclose(chunked, resident, rtol=1e-4, atol=1e-4)
-
-
-def test_fused_mixed_routing_when_one_side_too_big(monkeypatch):
-    """Per-side routing: when only the smaller table fits VMEM, that
-    side fuses and the other transparently keeps the XLA path — the
-    ML-20M shape (item table fits, user table doesn't)."""
+def test_fused_routes_per_bucket_when_a_width_has_no_plan(monkeypatch):
+    """Per-bucket routing: a bucket too wide for the SMEM index block
+    keeps the XLA path while narrower buckets of the same side fuse —
+    chosen from the bucket's static width, never from a failure."""
     from predictionio_tpu.ops import fused_als as fmod
 
     u, i, v, nu, ni = _toy(seed=7)
-    real_fits = fmod.fused_side_fits
-    calls = []
+    real_plan = fmod.fused_tile_plan
+    seen = []
 
-    def gated(m, r, k_max, table_bytes=4, gather_impl="taa"):
-        fits = m <= ni and real_fits(m, r, k_max, table_bytes,
-                                     gather_impl)
-        calls.append((m, fits))
-        return fits
+    def gated(r, k):
+        plan = real_plan(r, k) if k <= 8 else None
+        seen.append((k, plan is not None))
+        return plan
 
-    monkeypatch.setattr(fmod, "fused_side_fits", gated)
+    monkeypatch.setattr(fmod, "fused_tile_plan", gated)
     ref = train_als((u, i, v), nu, ni,
                     ALSConfig(rank=5, num_iterations=3, lam=0.05))
     got = train_als((u, i, v), nu, ni,
                     ALSConfig(rank=5, num_iterations=3, lam=0.05,
                               solver="fused"))
-    # both sides were consulted; only the item-table side fused
-    assert {m for m, _ in calls} == {nu, ni}
-    assert all(fits == (m == ni) for m, fits in calls)
+    # both kinds of bucket occurred
+    assert {ok for _, ok in seen} == {True, False}
     np.testing.assert_allclose(
         got.user_factors, ref.user_factors, rtol=5e-4, atol=5e-4
     )
@@ -175,51 +141,35 @@ def test_fused_sharded_placement_matches():
 
 
 def test_fused_tile_plan_respects_budget(monkeypatch):
-    plan = fused_tile_plan(26744, 64, 4096, 4)
-    assert plan is not None and plan[0] >= 8 and plan[1] >= 128
-    # the ML-20M item table is small enough to stay VMEM-resident at
-    # bf16 (one chunk); f32 pads rank 64's lanes to 128 so it streams
-    tb, kc, mc = fused_tile_plan(26744, 64, 4096, 2)
-    assert mc >= 26744
-    # the ML-20M USER table (138k rows) STREAMS in bounded chunks
-    tb, kc, mc = fused_tile_plan(138493, 64, 4096, 4)
-    assert mc < 138493
-    assert -(-138493 // mc) <= 64
-    assert fused_side_fits(138493, 64, 4096, 4)
-    # a tiny budget rejects everything
+    tb, kc = fused_tile_plan(64, 4096)
+    assert tb >= 8 and kc >= 128
+    # the table stays in HBM: only rank and bucket width enter the plan,
+    # and the index block of one batch tile fits SMEM
+    assert tb * 4096 * 4 <= 256 << 10
+    # a width whose index block cannot fit SMEM at any tile has no plan
+    assert fused_tile_plan(64, 1 << 14) is None
+    # a tiny VMEM budget rejects everything
     monkeypatch.setenv("PIO_TPU_VMEM_BYTES", str(1 << 20))
-    assert fused_tile_plan(26744, 64, 4096, 4) is None
-    assert not fused_side_fits(26744, 64, 4096, 4)
+    assert fused_tile_plan(64, 4096) is None
 
 
-def test_fused_probe_failure_degrades_to_xla(monkeypatch, caplog):
-    import logging
-
+def test_fused_kernel_that_does_not_compile_fails_the_train(monkeypatch):
+    """A fused kernel the compiler rejects FAILS the train with the
+    compiler's message; nothing trains on another solver."""
     from predictionio_tpu.ops import fused_als as fmod
 
     def boom(*a, **k):
-        raise RuntimeError("Mosaic dynamic gather unsupported (injected)")
+        raise RuntimeError("Mosaic failed to compile TPU kernel (injected)")
 
     monkeypatch.setattr(fmod, "fused_gather_gram_solve", boom)
-    monkeypatch.setattr(fmod, "_PROBE_CACHE", {})
     u, i, v, nu, ni = _toy(seed=11)
-    with caplog.at_level(logging.WARNING, logger="predictionio_tpu"):
-        tr = ALSTrainer((u, i, v), nu, ni,
-                        ALSConfig(rank=6, num_iterations=2, solver="fused"))
-        factors = tr.train()
-    assert tr.solver == "xla"
-    assert np.isfinite(factors.user_factors).all()
-    assert any("unfused path" in r.message for r in caplog.records)
+    # a rank no other test traces, so the jit cannot answer from cache
+    with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+        ALSTrainer((u, i, v), nu, ni,
+                   ALSConfig(rank=7, num_iterations=2, solver="fused")).train()
 
 
-def test_probe_ok_in_interpret_mode(monkeypatch):
-    from predictionio_tpu.ops import fused_als as fmod
-
-    monkeypatch.setattr(fmod, "_PROBE_CACHE", {})
-    assert fused_solver_ok(512, 8)
-
-
-# -- gather-impl parity suite (the PR-7 rewrite contract) --------------------
+# -- parity suite -------------------------------------------------------------
 
 
 def _dense_solve(table, idx, cw, bw, reg, gram0=None):
@@ -264,181 +214,66 @@ def _parity_case(seed=0, M=300, R=8, B=11, K=24):
     return table, idx, cw, bw, reg
 
 
-@pytest.mark.parametrize("impl", GATHER_IMPLS)
-def test_gather_impl_matches_kernel_math_resident(impl):
-    """Both impls reproduce the dense normal-equation solve to 1e-5 on
-    a resident plan, tile-boundary ids and masked entries included."""
+def test_kernel_matches_dense_on_tile_boundaries():
+    """The kernel reproduces the dense normal-equation solve to 1e-5,
+    tile-boundary ids and masked entries included."""
     table, idx, cw, bw, reg = _parity_case()
-    plan = fused_tile_plan(table.shape[0], table.shape[1],
-                           idx.shape[1], 4, impl)
-    assert plan is not None and plan[2] >= table.shape[0]
-    x = np.asarray(fused_gather_gram_solve(
-        table, idx, cw, bw, reg, gather_impl=impl
-    ))
+    x = np.asarray(fused_gather_gram_solve(table, idx, cw, bw, reg))
     want = _dense_solve(table, idx, cw, bw, reg)
     np.testing.assert_allclose(x, want, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("impl", GATHER_IMPLS)
-def test_gather_impl_forced_streamed_plan(impl):
-    """The forced multi-chunk plan (the big-table pipeline shape): for
-    "taa" this exercises the third grid axis + id-range masking with
-    ids scattered across EVERY chunk (out-of-chunk ids masked per
-    chunk); "dma" has no streamed grid — the same plan override must
-    still give identical results (mc only affects table padding)."""
-    table, idx, cw, bw, reg = _parity_case(seed=3)
-    x = np.asarray(fused_gather_gram_solve(
-        table, idx, cw, bw, reg, plan=(8, 128, 64), gather_impl=impl
-    ))
-    assert -(-table.shape[0] // 64) > 1  # really multi-chunk for taa
+def test_kernel_multi_chunk_width():
+    """A bucket wider than one K chunk accumulates across the second
+    grid axis (init on the first chunk, solve on the last)."""
+    table, idx, cw, bw, reg = _parity_case(seed=3, K=700)
+    tb, kc = fused_tile_plan(table.shape[1], idx.shape[1])
+    assert -(-idx.shape[1] // kc) > 1  # really multi-chunk
+    x = np.asarray(fused_gather_gram_solve(table, idx, cw, bw, reg))
     want = _dense_solve(table, idx, cw, bw, reg)
     np.testing.assert_allclose(x, want, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("impl", GATHER_IMPLS)
-def test_gather_impls_bitwise_identical_outputs(impl):
-    """Each impl gathers the SAME rows — against the original flat-take
-    semantics (numpy fancy indexing) the gathered Gram systems must
-    agree to f32 accumulation noise, so cross-impl outputs match far
-    tighter than the dense-reference bound."""
-    table, idx, cw, bw, reg = _parity_case(seed=7)
-    ref = np.asarray(fused_gather_gram_solve(
-        table, idx, cw, bw, reg, gather_impl="taa"
-    ))
-    got = np.asarray(fused_gather_gram_solve(
-        table, idx, cw, bw, reg, gather_impl=impl
-    ))
-    np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-6)
-
-
-@pytest.mark.parametrize("impl", GATHER_IMPLS)
-def test_gather_impl_bf16_table_fp32_accum(impl):
-    """bf16 table operands with fp32 accumulation: the mixed-precision
-    contract is ~bf16 operand noise (<1% relative), NOT f32 parity —
-    and must hold on both impls and both plan shapes."""
-    table, idx, cw, bw, reg = _parity_case(seed=11)
-    want = _dense_solve(table, idx, cw, bw, reg)
-    scale = np.abs(want).max()
-    import jax.numpy as jnp
-
-    t16 = jnp.asarray(table).astype(jnp.bfloat16)
-    for plan in (None, (8, 128, 64)):
-        x = np.asarray(fused_gather_gram_solve(
-            t16, idx, cw, bw, reg, plan=plan, gather_impl=impl
-        ))
-        rel = np.abs(x - want).max() / scale
-        assert rel < 0.01, (impl, plan, rel)
-
-
-@pytest.mark.parametrize("impl", GATHER_IMPLS)
-def test_fused_train_rmse_within_1pct_of_unfused(impl):
-    """End-to-end ALS: each impl's bf16-table train must land within
-    the 1% RMSE parity bound vs the f32 unfused reference (the
-    acceptance bound the on-chip A/B gates against)."""
+def test_fused_train_rmse_within_1pct_of_unfused():
+    """End-to-end ALS: the fused train must land within the 1% RMSE
+    parity bound vs the unfused reference (the acceptance bound
+    ROADMAP S3 gates against)."""
     from predictionio_tpu.models.als import rmse
 
     u, i, v, nu, ni = _toy(seed=13)
     kw = dict(rank=5, num_iterations=4, lam=0.05)
     ref = train_als((u, i, v), nu, ni, ALSConfig(**kw))
     rmse_ref = rmse(ref, u, i, v)
-    got = train_als((u, i, v), nu, ni, ALSConfig(
-        solver="fused", fused_gather=impl,
-        gather_dtype="bfloat16", **kw))
+    got = train_als((u, i, v), nu, ni, ALSConfig(solver="fused", **kw))
     rmse_got = rmse(got, u, i, v)
     assert abs(rmse_got - rmse_ref) <= 0.01 * max(rmse_ref, 1e-9), (
-        impl, rmse_ref, rmse_got,
+        rmse_ref, rmse_got,
     )
 
 
-def test_dma_smem_budget_slices_batches(monkeypatch):
-    """A tight SMEM budget must slice the dma impl's batch dim (each
-    pallas_call's scalar-prefetch slab under budget) without changing
-    results; an impossibly tight one must kill the plan entirely."""
+def test_smem_budget_slices_batches(monkeypatch):
+    """A tight SMEM budget must slice the batch dim (each pallas_call's
+    scalar-prefetch slab under budget) without changing results; an
+    impossibly tight one must kill the plan entirely."""
     table, idx, cw, bw, reg = _parity_case(seed=17, B=24)
-    ref = np.asarray(fused_gather_gram_solve(
-        table, idx, cw, bw, reg, gather_impl="dma"
-    ))
+    ref = np.asarray(fused_gather_gram_solve(table, idx, cw, bw, reg))
     # 8 rows x 128 padded K x 4 B = 4096 B per tile: a 4 KiB budget
     # forces bs == tb == 8, i.e. 3 slices for B=24
     monkeypatch.setenv("PIO_TPU_SMEM_BYTES", str(4096))
-    plan = fused_tile_plan(table.shape[0], table.shape[1],
-                           idx.shape[1], 4, "dma")
+    plan = fused_tile_plan(table.shape[1], idx.shape[1])
     assert plan is not None and plan[0] == 8
-    sliced = np.asarray(fused_gather_gram_solve(
-        table, idx, cw, bw, reg, gather_impl="dma"
-    ))
+    sliced = np.asarray(fused_gather_gram_solve(table, idx, cw, bw, reg))
     np.testing.assert_allclose(sliced, ref, rtol=1e-6, atol=1e-6)
     monkeypatch.setenv("PIO_TPU_SMEM_BYTES", str(64))
-    assert fused_tile_plan(table.shape[0], table.shape[1],
-                           idx.shape[1], 4, "dma") is None
-    assert not fused_side_fits(table.shape[0], table.shape[1],
-                               idx.shape[1], 4, "dma")
-
-
-def test_fused_gather_config_validation():
-    with pytest.raises(ValueError, match="fused_gather"):
-        ALSConfig(solver="fused", fused_gather="take")
-    with pytest.raises(ValueError, match="only applies"):
-        ALSConfig(solver="xla", fused_gather="taa")
-    # the default composes with every solver
-    assert ALSConfig(solver="pallas").fused_gather == "auto"
-
-
-def test_resolve_gather_impl_auto_and_explicit(monkeypatch):
-    from predictionio_tpu.ops import fused_als as fmod
-
-    monkeypatch.setattr(fmod, "_PROBE_CACHE", {})
-    # interpret mode: every impl passes; auto commits to the static
-    # preference order's head
-    assert resolve_gather_impl(512, 8) == "taa"
-    assert resolve_gather_impl(512, 8, requested="dma") == "dma"
-    with pytest.raises(ValueError, match="fused_gather"):
-        resolve_gather_impl(512, 8, requested="nope")
-    # a dead impl resolves to the next candidate under auto, None when
-    # requested explicitly
-    monkeypatch.setattr(fmod, "_PROBE_CACHE", {})
-    real_ok = fmod.fused_solver_ok
-
-    def taa_dead(m, r, table_bytes=4, precision=None, gather_impl="taa"):
-        if gather_impl == "taa":
-            return False
-        return real_ok(m, r, table_bytes, precision, gather_impl)
-
-    monkeypatch.setattr(fmod, "fused_solver_ok", taa_dead)
-    assert fmod.resolve_gather_impl(512, 8) == "dma"
-    assert fmod.resolve_gather_impl(512, 8, requested="taa") is None
-
-
-def test_trainer_resolves_and_records_gather_impl(monkeypatch):
-    """ALSTrainer exposes the RESOLVED impl (the bench-honesty field):
-    live fused -> the impl; degraded fused -> ("xla", None)."""
-    from predictionio_tpu.ops import fused_als as fmod
-
-    u, i, v, nu, ni = _toy(seed=19)
-    tr = ALSTrainer((u, i, v), nu, ni,
-                    ALSConfig(rank=5, num_iterations=2, solver="fused",
-                              fused_gather="dma"))
-    assert tr.solver == "fused" and tr.fused_gather == "dma"
-    assert np.isfinite(tr.train().user_factors).all()
-    # non-fused solvers carry None
-    tr2 = ALSTrainer((u, i, v), nu, ni, ALSConfig(rank=5,
-                                                  num_iterations=1))
-    assert tr2.fused_gather is None
-
-    monkeypatch.setattr(fmod, "_PROBE_CACHE", {})
-    monkeypatch.setattr(
-        fmod, "fused_solver_ok", lambda *a, **k: False
-    )
-    tr3 = ALSTrainer((u, i, v), nu, ni,
-                     ALSConfig(rank=5, num_iterations=1, solver="fused"))
-    assert tr3.solver == "xla" and tr3.fused_gather is None
+    assert fused_tile_plan(table.shape[1], idx.shape[1]) is None
+    with pytest.raises(ValueError, match="no tile plan"):
+        fused_gather_gram_solve(table, idx, cw, bw, reg)
 
 
 def test_fused_recompiles_land_in_xray_ring():
-    """The fused entries are xray-instrumented as "als.fused": a tile-
-    plan change (forced streamed plan) and a gather-impl change must
-    each register a new signature — the /debug/xray visibility the
-    loud-degradation contract requires."""
+    """The fused entry is xray-instrumented as "als.fused": a new shape
+    and a precision change must each register a new signature at
+    /debug/xray."""
     from predictionio_tpu.obs import xray
 
     # shapes unique to THIS test: signatures are structural, so reusing
@@ -446,13 +281,11 @@ def test_fused_recompiles_land_in_xray_ring():
     table, idx, cw, bw, reg = _parity_case(seed=23, M=320, R=6, B=13,
                                            K=26)
     before = xray.jit_stats().get("als.fused", {}).get("signatures", 0)
-    fused_gather_gram_solve(table, idx, cw, bw, reg, gather_impl="taa")
-    fused_gather_gram_solve(table, idx, cw, bw, reg, gather_impl="taa",
-                            plan=(8, 128, 64))
-    fused_gather_gram_solve(table, idx, cw, bw, reg, gather_impl="dma")
+    fused_gather_gram_solve(table, idx, cw, bw, reg)
+    fused_gather_gram_solve(table, idx, cw, bw, reg, precision="default")
     stats = xray.jit_stats().get("als.fused")
     assert stats is not None, "als.fused never registered with xray"
-    assert stats.get("signatures", 0) >= before + 3
+    assert stats.get("signatures", 0) >= before + 2
     fused_events = [
         e for e in xray.recompile_events() if e.get("fn") == "als.fused"
     ]
@@ -464,8 +297,7 @@ def test_fused_kernel_high_ranks(r):
     """Ranks up to 128 (the GJ augmented column rides lane padding only
     below 128, so 128 exercises the widened [TB, R, R+1] scratch) must
     plan within budget and match the dense solve."""
-    plan = fused_tile_plan(2000, r, 64, 4)
-    assert plan is not None
+    assert fused_tile_plan(r, 64) is not None
     rng = np.random.default_rng(0)
     M, B, K = 500, 5, 9
     table = rng.normal(size=(M, r)).astype(np.float32)

@@ -21,36 +21,12 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from tools.multihost_harness import (
-    collectives_unavailable_reason,
-    spawn_workers,
-)
+from tools.multihost_harness import spawn_workers
 
 from predictionio_tpu.storage.event import DataMap, Event
 from predictionio_tpu.storage.sqlite_events import SQLiteEventStore
 
 UTC = dt.timezone.utc
-
-
-# -- multiprocess-collectives capability gate --------------------------------
-#
-# Every spawning test below needs jax.distributed collectives across
-# REAL processes.  Some jaxlib builds' CPU backend refuses them
-# ("Multiprocess computations aren't implemented on the CPU backend"),
-# which made these 7 tests fail ENVIRONMENTALLY on every tier-1 run
-# since PR 3 — red noise that buried real regressions.  The capability
-# probe, the coordinator rendezvous (worker 0 binds port 0 itself —
-# no parent-side free-port TOCTOU), and the worker launcher all live in
-# tools/multihost_harness.py now: the tests, the gate's verdict line,
-# and operators share ONE arbiter.  The probe verdict is cached on disk
-# per (interpreter, jaxlib), so collection stops spawning 2 processes
-# per pytest run; PIO_TPU_RUN_MULTIHOST=1 forces the tests to run and
-# PIO_TPU_REPROBE_MULTIHOST=1 refreshes the cached verdict.
-
-needs_collectives = pytest.mark.skipif(
-    collectives_unavailable_reason() is not None,
-    reason=str(collectives_unavailable_reason()),
-)
 
 
 def _make_events(n_users=12, n_items=8, seed=0):
@@ -116,7 +92,6 @@ def _spawn_workers(nprocs, args_of, timeout=300, device_count=0):
     return [r.stdout for r in results]
 
 
-@needs_collectives
 @pytest.mark.parametrize("nprocs", [2, 4])
 def test_multi_process_ingest_and_train(tmp_path, nprocs):
     """jax.distributed CPU processes each read their shard; the gathered
@@ -171,7 +146,6 @@ def test_multi_process_ingest_and_train(tmp_path, nprocs):
         )
 
 
-@needs_collectives
 def test_two_process_run_train_end_to_end(tmp_path):
     """The FULL workflow across 2 processes sharing one storage home:
     run_train (sharded ingest, SPMD train, chief-only metadata/model
@@ -210,7 +184,6 @@ def test_two_process_run_train_end_to_end(tmp_path):
     )
 
 
-@needs_collectives
 @pytest.mark.parametrize(
     "nprocs,device_count",
     [(2, 2), (4, 0)],
@@ -286,7 +259,6 @@ def test_sharded_coo_distributed_trainer(tmp_path, nprocs, device_count):
         )
 
 
-@needs_collectives
 def test_run_train_no_full_coo_end_to_end(tmp_path):
     """The FULL workflow with datasource coo='local' + sharded placement:
     run_train never gathers the rating set to any process, yet trains,
@@ -348,7 +320,6 @@ def test_run_train_no_full_coo_end_to_end(tmp_path):
     )
 
 
-@needs_collectives
 def test_sharded_distributed_trainer_fused_solver(tmp_path):
     """The fused gather+Gram+solve kernel inside the distributed
     sharded-COO path (2 jax.distributed processes x 2 devices): the

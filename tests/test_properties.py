@@ -4,8 +4,8 @@ The reference proves these with hand-picked cases (`DataMapSpec`,
 `LEventAggregatorSpec`, `BiMapSpec`); generated inputs cover the same
 contracts over the whole input space — JSON wire round-trips, the
 $set/$unset/$delete fold semantics, id-index bijection, and the fused
-kernel's VMEM tile-plan accounting (a wrong plan silently degrades the
-solver, so the arithmetic is load-bearing).
+kernel's VMEM/SMEM tile-plan accounting (a wrong plan fails to compile
+on the chip, so the arithmetic is load-bearing).
 """
 
 import datetime as dt
@@ -149,49 +149,46 @@ def test_string_index_bijection(ids):
 
 
 @given(
-    m=st.integers(min_value=8, max_value=200_000),
     r=st.integers(min_value=2, max_value=128),
-    k=st.integers(min_value=1, max_value=4096),
-    table_bytes=st.sampled_from([2, 4]),
+    k=st.integers(min_value=1, max_value=1 << 14),
     budget_mib=st.integers(min_value=2, max_value=64),
+    smem_kib=st.integers(min_value=4, max_value=1024),
 )
-@settings(max_examples=60, deadline=None)
-def test_fused_tile_plan_accounting(m, r, k, table_bytes, budget_mib):
-    """Any plan the planner returns must actually FIT the budget it was
-    given: padded scratch + double-buffered IO + the table chunk stay
-    within 90% of VMEM, chunk counts respect the cap, and dimensions
-    tile (8, 128).  A wrong plan is a silent solver degrade in
-    production, so the arithmetic is a contract, not a heuristic."""
+@settings(max_examples=60, deadline=None, derandomize=True)
+def test_fused_tile_plan_accounting(r, k, budget_mib, smem_kib):
+    """Any plan the planner returns must actually FIT the budgets it
+    was given: padded scratch + the row-copy landing pad +
+    double-buffered IO stay within half of VMEM (the other half is
+    Mosaic's stack temporaries), one batch tile's index block fits
+    SMEM, and dimensions tile (8, 128).  A wrong plan is a compile
+    failure on the chip, so the arithmetic is a contract, not a
+    heuristic."""
     from predictionio_tpu.ops.fused_als import (
-        _MAX_TABLE_CHUNKS, _pad8, _pad128, fused_tile_plan,
+        _pad8, _pad128, fused_tile_plan,
     )
-
-    import pytest
 
     budget = budget_mib << 20
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("PIO_TPU_VMEM_BYTES", str(budget))
-        plan = fused_tile_plan(m, r, k, table_bytes)
+        mp.setenv("PIO_TPU_SMEM_BYTES", str(smem_kib << 10))
+        plan = fused_tile_plan(r, k)
     if plan is None:
         return
-    tb, kc, mc = plan
-    assert tb >= 8 and kc >= 128 and mc >= 8
-    assert tb % 8 == 0 and kc % 128 == 0 and mc % 8 == 0
-    assert -(-_pad8(m) // mc) <= _MAX_TABLE_CHUNKS
+    tb, kc = plan
+    assert tb >= 8 and kc >= 128
+    assert tb % 8 == 0 and kc % 128 == 0
     r8, r128, w128 = _pad8(r), _pad128(r), _pad128(r + 1)
     fixed = (
         tb * r8 * r128 * 4          # A scratch
         + tb * r8 * w128 * 4        # GJ scratch
         + _pad8(tb) * r128 * 4      # b scratch
-        + tb * _pad8(kc) * r128 * 4  # gathered rows
-        + 3 * 2 * _pad8(tb) * _pad128(kc) * 4  # idx/cw/bw double-buffered
+        + tb * kc * r128 * 4        # landing pad of the row copies
+        + 2 * 2 * _pad8(tb) * _pad128(kc) * 4  # cw/bw double-buffered
         + 2 * _pad8(tb) * r128 * 4  # out double-buffered
         + r8 * r128 * 4             # gram0
     )
-    table_cost = mc * r128 * table_bytes
-    if mc < _pad8(m):               # streamed: double-buffered chunk
-        table_cost *= 2
-    assert fixed + table_cost <= int(budget * 0.9)
+    assert fixed <= budget // 2
+    assert tb * (-(-k // kc) * kc) * 4 <= smem_kib << 10
 
 
 # -- sharded-store routing + dedup invariants (round 5) -------------------

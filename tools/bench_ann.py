@@ -237,17 +237,12 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--append-history", action="store_true",
                     help="append every record to BENCH_HISTORY.jsonl")
-    ap.add_argument("--platform")
     args = ap.parse_args(argv)
     args.modes = [m.strip() for m in args.modes.split(",") if m.strip()]
 
-    if args.platform:
-        import os
-
-        os.environ["JAX_PLATFORMS"] = args.platform
     import jax
 
-    platform = args.platform or jax.default_backend()
+    platform = jax.default_backend()
     all_records = []
     for tier in (int(x) for x in args.items.split(",")):
         for rec in bench_tier(tier, args, platform):

@@ -17,13 +17,11 @@ as tests stayed green.  This tool closes the loop:
 
   - baseline = median of the last ``--window`` comparable records with
     the same ``(metric, platform, scale)`` key — *fenced* records only
-    (unfenced numbers measured dispatch, not compute; see the round-2
-    postmortem at the top of the history file);
+    (an unfenced number measured dispatch, not compute);
   - noise    = the robust sigma ``1.4826 * MAD`` of those records;
   - fail when ``value > median + max(min_rel * median,
     noise_mult * sigma)`` — a quiet history gets a tight gate, a noisy
-    one (CPU fallback runs, tunnel staging jitter) a proportionally
-    loose one, and a min-sample guard (``--min-samples``) keeps a
+    one a proportionally loose one, and a min-sample guard (``--min-samples``) keeps a
     2-point "trend" from ever failing anyone.
 
 Exit codes: 0 pass, 1 regression, 2 not checkable (no candidate /

@@ -73,6 +73,14 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="fleet_smoke.json")
     ap.add_argument("--seed", type=int, default=20260805)
     args = ap.parse_args(argv)
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
+        # one process per chip: this one trains in-process (taking the
+        # chip) and THEN spawns replica processes that would need it
+        print("fleet_smoke: trains in this process, then spawns replica "
+              "processes; on an accelerator the parent would hold the "
+              "chip its replicas need.  Run it with JAX_PLATFORMS=cpu.",
+              file=sys.stderr)
+        return 2
 
     home = tempfile.mkdtemp(prefix="pio_fleet_smoke_")
     telemetry = os.path.join(home, "telemetry")

@@ -23,15 +23,6 @@ if [ "${1:-}" = "--strict" ]; then
   shift
 fi
 
-# 0) multihost capability verdict: make skip-vs-run of the multihost
-# suite VISIBLE in CI logs (the probe verdict is disk-cached per
-# interpreter+jaxlib, so this line costs milliseconds after the first
-# run; tools/multihost_harness.py is the same arbiter the tests ride)
-echo "gate [0/17] multihost collectives verdict" >&2
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
-  python tools/multihost_harness.py --probe >&2 \
-  || echo "  (verdict unavailable — probe errored; multihost tests will skip)" >&2
-
 # 1) piolint: JAX/lock/deadlock/contract static analysis
 #    (PIO1xx/PIO2xx incl. PIO210-213 deadlock, PIO3xx, PIO4xx contract)
 REPORT="${PIOLINT_REPORT:-/tmp/piolint_report.json}"
@@ -56,12 +47,13 @@ else
   echo "  ruff not installed; skipping generic lint" >&2
 fi
 
-# 3) gather-form + fused-kernel smoke: every Mosaic-lowerable gather
-# form's math in interpret mode (tools/probe_gather.py --smoke — shape/
-# logic validation, NO lowering claims; lowering is answered on-chip by
-# the measure_tpu.sh battery) plus the fused-kernel interpret parity
-# suite — cheap-first so a kernel math break fails in ~1 min, not after
-# the full suite
+# 3) gather-form + fused-kernel smoke: every gather form's math in
+# interpret mode (tools/probe_gather.py --smoke — shape/logic
+# validation; whether the GJ and fused kernels compile at rank 64 is
+# answered on the chip by chip_smoke.py's pallas and fused trains)
+# plus the fused-kernel interpret parity suite —
+# cheap-first so a kernel math break fails in ~1 min, not after the
+# full suite
 echo "gate [3/17] gather probe smoke + fused interpret parity" >&2
 if ! JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" \
      python tools/probe_gather.py --smoke > /tmp/probe_gather_smoke.json; then

@@ -1,41 +1,25 @@
 #!/usr/bin/env python
-"""Multi-host launch harness: real processes where collectives exist,
-a simulated in-process cluster where they don't.
+"""Multi-host launch harness: real `jax.distributed` CPU processes.
 
-Three PRs of history motivated this file: the 7 ``tests/test_multihost.py``
-cases spawn real ``jax.distributed`` CPU processes, and on jaxlib builds
-whose CPU backend refuses multiprocess collectives they failed (PR 3-5)
-then skipped (PR 6+) ENVIRONMENTALLY — the distributed path was certified
-nowhere.  This harness is the single arbiter both the tests and operators
-use:
+The one process launcher every multihost test and operator drill rides:
 
-* :func:`collectives_unavailable_reason` — the capability probe, run at
-  most once per (interpreter, jaxlib) and CACHED ON DISK, so repeated
-  pytest collections stop paying two process spawns each.  The verdict
-  (and the exact backend error when negative) is printable from the CLI
-  (``--probe``) and is surfaced by ``tools/gate.sh`` so skip-vs-run is
-  visible in CI logs instead of silent.
-* :func:`spawn_workers` — the one process launcher every multihost test
-  rides (replacing per-test private spawn code).  The coordinator port
-  is bound to **port 0 inside worker 0** and published through a
-  coordination directory (:func:`resolve_coordinator`) — the parent
-  never picks a port, which kills the ``_free_port()`` TOCTOU race two
-  concurrent collections used to lose.
-* ``--demo`` — the zero-to-aha run: where collectives exist it launches
-  N real processes through the same path the tests use; where they
-  don't it REPORTS THE REASON and runs the simulated cluster instead
-  (in-process virtual devices via ``XLA_FLAGS=
-  --xla_force_host_platform_device_count=N``), driving a coded-shard
-  chaos train (straggler + dead worker under a deterministic
-  ``PIO_FAULT_PLAN``) so the parity/deadline logic is exercised on
-  every box, not just on silicon.
+* :func:`spawn_workers` — launches N worker processes on the CPU
+  backend (whose multiprocess collectives work on the installed
+  jaxlib).  The coordinator port is bound to **port 0 inside worker
+  0** and published through a coordination directory
+  (:func:`resolve_coordinator`) — the parent never picks a port, which
+  kills the ``_free_port()`` TOCTOU race two concurrent collections
+  used to lose.
+* ``--demo`` — the zero-to-aha run: N real processes through the same
+  path the tests use, ingesting and training over a scratch store.
+
+The parent never imports jax; every worker is put on the CPU in the
+open (``JAX_PLATFORMS=cpu``).
 """
 
 from __future__ import annotations
 
 import argparse
-import functools
-import hashlib
 import json
 import os
 import socket
@@ -50,10 +34,8 @@ from typing import Callable, Optional, Sequence
 REPO_ROOT = Path(__file__).resolve().parent.parent
 
 __all__ = [
-    "collectives_unavailable_reason",
     "resolve_coordinator",
     "spawn_workers",
-    "simulated_cluster_demo",
     "WorkerResult",
 ]
 
@@ -95,107 +77,6 @@ def resolve_coordinator(coord_dir, pid: int, nprocs: int,
             )
         time.sleep(0.05)
     return path.read_text().strip()
-
-
-# -- capability probe -------------------------------------------------------
-
-# the minimal 2-process broadcast — the exact op the workers die on
-# when the CPU backend lacks multiprocess collectives
-_PROBE_SRC = """
-import sys
-sys.path.insert(0, {root!r})
-from tools.multihost_harness import resolve_coordinator
-pid = int(sys.argv[2])
-coordinator = resolve_coordinator(sys.argv[1], pid, 2)
-import jax
-jax.distributed.initialize(coordinator, num_processes=2, process_id=pid)
-import numpy as np
-from jax.experimental import multihost_utils
-multihost_utils.broadcast_one_to_all(np.ones(1))
-print("COLLECTIVES_OK")
-"""
-
-
-def _probe_cache_path() -> Path:
-    """Per-(interpreter, jaxlib) on-disk verdict so repeated pytest
-    collections in one environment stop re-spawning the probe."""
-    try:
-        import jaxlib
-
-        ver = getattr(jaxlib, "__version__", "?")
-    except Exception:  # pragma: no cover — no jax at all
-        ver = "nojax"
-    key = hashlib.sha256(
-        f"{sys.executable}:{ver}".encode()
-    ).hexdigest()[:16]
-    return Path(tempfile.gettempdir()) / f"pio_tpu_collectives_{key}.json"
-
-
-def _run_probe(timeout: float = 120.0) -> Optional[str]:
-    env = {**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": ""}
-    with tempfile.TemporaryDirectory(prefix="pio-coord-") as coord:
-        procs = [
-            subprocess.Popen(
-                [sys.executable, "-c",
-                 _PROBE_SRC.format(root=str(REPO_ROOT)), coord, str(p)],
-                env=env, stdout=subprocess.PIPE,
-                stderr=subprocess.STDOUT, text=True,
-            )
-            for p in range(2)
-        ]
-        outs = []
-        for p in procs:
-            try:
-                out, _ = p.communicate(timeout=timeout)
-            except subprocess.TimeoutExpired:
-                for q in procs:
-                    q.kill()
-                return (
-                    f"2-process collectives probe timed out after "
-                    f"{timeout:.0f}s"
-                )
-            outs.append((p.returncode, out or ""))
-    if all(rc == 0 and "COLLECTIVES_OK" in out for rc, out in outs):
-        return None
-    bad = next((o for rc, o in outs if rc != 0), outs[0][1])
-    tail = bad.strip().splitlines()[-1][-300:] if bad.strip() else "?"
-    return (
-        "this jax backend cannot run multiprocess collectives "
-        f"(2-process broadcast probe failed: {tail}); the multihost "
-        "suite is environmental here — run it where collectives exist, "
-        "or force with PIO_TPU_RUN_MULTIHOST=1"
-    )
-
-
-@functools.lru_cache(maxsize=1)
-def collectives_unavailable_reason() -> Optional[str]:
-    """None when 2-process ``jax.distributed`` collectives work on this
-    backend; otherwise the specific failure (the skip reason).
-
-    Cached twice: in-process (lru_cache) AND on disk per
-    (interpreter, jaxlib) — a fresh pytest collection reads the disk
-    verdict in microseconds instead of spawning two probe processes.
-    ``PIO_TPU_RUN_MULTIHOST=1`` forces "available" (re-confirm a
-    failure mode / exercise a candidate jaxlib);
-    ``PIO_TPU_REPROBE_MULTIHOST=1`` drops the disk cache first."""
-    if os.environ.get("PIO_TPU_RUN_MULTIHOST") == "1":
-        return None
-    cache = _probe_cache_path()
-    if os.environ.get("PIO_TPU_REPROBE_MULTIHOST") == "1":
-        cache.unlink(missing_ok=True)
-    try:
-        verdict = json.loads(cache.read_text())
-        return verdict["reason"]
-    except (OSError, ValueError, KeyError):
-        pass
-    reason = _run_probe()
-    try:
-        tmp = cache.with_suffix(".tmp")
-        tmp.write_text(json.dumps({"reason": reason}))
-        tmp.rename(cache)
-    except OSError:  # pragma: no cover — read-only tmpdir
-        pass
-    return reason
 
 
 # -- worker launch ----------------------------------------------------------
@@ -275,65 +156,6 @@ def spawn_workers(
     return results
 
 
-# -- simulated-cluster fallback demo ---------------------------------------
-
-
-def simulated_cluster_demo(n_devices: int = 4) -> dict:
-    """The in-process fallback: a coded-shard chaos train on a virtual
-    CPU mesh — straggler then dead worker under a deterministic fault
-    plan, RMSE checked against the clean sweep.  Runs in a SUBPROCESS so
-    the virtual device count applies regardless of the caller's jax
-    state."""
-    src = f"""
-import json, sys
-sys.path.insert(0, {str(REPO_ROOT)!r})
-import numpy as np
-from predictionio_tpu.models.als import ALSConfig, ALSTrainer, rmse, train_als
-from predictionio_tpu.parallel import make_mesh
-from predictionio_tpu.resilience import faults
-
-rng = np.random.default_rng(0)
-n_u, n_i, nnz = 60, 40, 900
-u = rng.integers(0, n_u, nnz).astype(np.int32)
-i = rng.integers(0, n_i, nnz).astype(np.int32)
-v = rng.integers(1, 6, nnz).astype(np.float32)
-base = dict(rank=4, num_iterations=8, lam=0.1, seed=3)
-clean = rmse(train_als((u, i, v), n_u, n_i, ALSConfig(**base)), u, i, v)
-mesh = make_mesh()
-cfg = ALSConfig(**base, factor_placement="sharded", coded_shards=True)
-out = {{"devices": mesh.size, "clean_rmse": clean, "scenarios": {{}}}}
-for name, plan in (
-    ("straggler", "dist.shard_delay:nth=7,times=1,shard=2,delay=0.05"),
-    ("dead_worker", "dist.worker_kill:nth=15,shard=1"),
-):
-    faults.arm(plan)
-    tr = ALSTrainer((u, i, v), n_u, n_i, cfg, mesh=mesh)
-    r = rmse(tr.train(), u, i, v)
-    faults.disarm()
-    out["scenarios"][name] = {{
-        "plan": plan, "rmse": r, "rmse_ratio": r / clean,
-        "health": tr.shard_health.summary(),
-    }}
-print("SIM_DEMO " + json.dumps(out))
-"""
-    env = {
-        **os.environ,
-        "JAX_PLATFORMS": "cpu",
-        "XLA_FLAGS": f"--xla_force_host_platform_device_count={n_devices}",
-    }
-    proc = subprocess.run(
-        [sys.executable, "-c", src], env=env, capture_output=True,
-        text=True, timeout=600,
-    )
-    for line in proc.stdout.splitlines():
-        if line.startswith("SIM_DEMO "):
-            return json.loads(line[len("SIM_DEMO "):])
-    raise RuntimeError(
-        f"simulated-cluster demo failed (rc={proc.returncode}):\n"
-        f"{proc.stdout}\n{proc.stderr}"
-    )
-
-
 def _make_demo_db(path: Path):
     """Scratch sqlite event store for the real-process demo (the same
     synthetic shape the multihost tests read)."""
@@ -374,65 +196,34 @@ def _make_demo_db(path: Path):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--probe", action="store_true",
-                    help="print the collectives capability verdict")
     ap.add_argument("--demo", action="store_true",
-                    help="run the multi-process demo (real processes "
-                         "when collectives exist, simulated otherwise)")
+                    help="run the multi-process ingest+train demo")
     ap.add_argument("--nprocs", type=int, default=2)
-    ap.add_argument("--devices", type=int, default=4,
-                    help="virtual device count of the simulated fallback")
     args = ap.parse_args(argv)
-
-    reason = collectives_unavailable_reason()
-    verdict = {
-        "collectives": reason is None,
-        "reason": reason,
-        "cache": str(_probe_cache_path()),
-    }
-    if args.probe or not args.demo:
-        print(json.dumps(verdict, indent=2))
+    if not args.demo:
+        ap.print_help()
         return 0
-
-    if reason is None:
-        import tempfile as _tf
-
-        with _tf.TemporaryDirectory(prefix="pio-mh-demo-") as td:
-            td = Path(td)
-            coord = td / "coord"
-            # the ingest-and-train worker path over a scratch store
-            sys.path.insert(0, str(REPO_ROOT))
-            db = _make_demo_db(td / "events.db")
-            outs = [td / f"out{p}.npz" for p in range(args.nprocs)]
-            results = spawn_workers(
-                args.nprocs,
-                lambda p: [p, args.nprocs, coord, db, td / "exch",
-                           outs[p]],
-            )
-            ok = all(r.ok for r in results)
-            print(json.dumps({
-                **verdict, "mode": "real-processes",
-                "nprocs": args.nprocs, "ok": ok,
-                "workers": [
-                    {"pid": r.pid, "rc": r.returncode,
-                     "timed_out": r.timed_out}
-                    for r in results
-                ],
-            }, indent=2))
-            return 0 if ok else 1
-
-    print(f"# collectives unavailable -> simulated cluster "
-          f"({args.devices} virtual devices)\n# reason: {reason}",
-          file=sys.stderr)
-    demo = simulated_cluster_demo(args.devices)
-    bounded = all(
-        s["rmse_ratio"] <= 1.01 for s in demo["scenarios"].values()
-    )
-    print(json.dumps({
-        **verdict, "mode": "simulated-cluster", **demo,
-        "rmse_within_1pct": bounded,
-    }, indent=2))
-    return 0 if bounded else 1
+    with tempfile.TemporaryDirectory(prefix="pio-mh-demo-") as td:
+        td = Path(td)
+        coord = td / "coord"
+        # the ingest-and-train worker path over a scratch store
+        sys.path.insert(0, str(REPO_ROOT))
+        db = _make_demo_db(td / "events.db")
+        outs = [td / f"out{p}.npz" for p in range(args.nprocs)]
+        results = spawn_workers(
+            args.nprocs,
+            lambda p: [p, args.nprocs, coord, db, td / "exch", outs[p]],
+        )
+        ok = all(r.ok for r in results)
+        print(json.dumps({
+            "mode": "real-processes", "nprocs": args.nprocs, "ok": ok,
+            "workers": [
+                {"pid": r.pid, "rc": r.returncode,
+                 "timed_out": r.timed_out}
+                for r in results
+            ],
+        }, indent=2))
+        return 0 if ok else 1
 
 
 if __name__ == "__main__":

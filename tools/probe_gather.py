@@ -1,35 +1,18 @@
-"""On-chip probes for Mosaic-lowerable dynamic-gather forms (CLI).
+"""Gather-form timings for ROADMAP S3 (CLI over `ops/gather_probe.py`).
 
-Round-5 finding: the fused ALS kernel's ``jnp.take(table, flat_idx)``
-does NOT lower on TPU — Mosaic's ``lax.gather`` rule
-(jax/_src/pallas/mosaic/lowering.py:2481-2484, jax 0.9.0) requires
-``take_along_axis`` semantics.  The probe implementations now live in
-``predictionio_tpu/ops/gather_probe.py`` so the fused kernel's
-``fused_gather="auto"`` resolution reuses the SAME compile-and-run
-arbitration this battery step records; this file is the thin CLI the
-measurement battery (``tools/measure_tpu.sh``) and the gate's CPU
-smoke invoke.
+Which in-kernel gather forms the v5e compiler accepts was settled in
+PR 21 (CHANGES.md): only the row-DMA loop.  This script times, at the
+ML-20M table shapes, what is left to compare on the chip:
 
-This script measures, on the real chip, every candidate form:
+  * the XLA ``jnp.take`` baseline (what the unfused path pays), f32 and
+    bf16;
+  * the grouped tile-slab take behind ``gather_mode="grouped"``;
+  * the fused kernel's rolling-window ``pltpu.make_async_copy`` row
+    loop (indices scalar-prefetched to SMEM, float32 rows).
 
-  A. same-shape ``take_along_axis(axis=0)`` sub-gathers — indices
-     broadcast across lanes (the fused kernel's ``"taa"`` impl);
-  B. the transposed lane-dim variant (``axis=1`` on ``[R, M]``);
-  C. an in-kernel rolling-window ``pltpu.make_async_copy`` row loop
-     (indices scalar-prefetched to SMEM — the ``"dma"`` impl);
-  D. the XLA ``jnp.take`` baseline on identical shapes (what the
-     unfused path pays today), f32 and bf16.
-
-Each probe prints one JSON line; lowering failures print
-``{"ok": false, "error": ...}`` instead of raising, so the battery can
-run this unattended.  Decision rule: a Pallas form wins if its
-per-element gather time beats D's; ``resolve_gather_impl`` applies the
-same ordering in-process, and docs/PERF_PLAN.md §4 records the
-standing answer.
-
-``--smoke`` runs every form at small shapes (CPU interpret-mode shape
-and logic validation for ``tools/gate.sh`` — NO lowering claims) and
-exits nonzero if any form's math is wrong.
+Each probe prints one JSON line.  ``--smoke`` runs every form at small
+shapes (CPU interpret-mode shape and logic validation for
+``tools/gate.sh``) and exits nonzero if any form's math is wrong.
 """
 
 from __future__ import annotations
@@ -55,8 +38,7 @@ def run_smoke() -> int:
     """Small-shape run of every form: interpret-mode math validation."""
     _emit({"metric": "probe_env", "backend": jax.default_backend(),
            "mode": "smoke",
-           "note": "shape/logic validation only — lowering claims "
-                   "require a TPU backend"})
+           "note": "shape/logic validation only"})
     recs = gp.smoke()
     bad = 0
     for rec in recs:
@@ -81,9 +63,6 @@ def main(argv=None) -> int:
     _emit({"metric": "probe_env", "backend": jax.default_backend(),
            "device": str(jax.devices()[0])})
     r = 64
-    # guaranteed-lowerable XLA rows FIRST: the speculative Pallas forms
-    # below can hit pathological Mosaic compiles, and a dying step must
-    # still leave the rows the grouped-gather decision needs
     _emit({"metric": "section", "form": "xla_take_baseline"})
     for dtype in (jnp.float32, jnp.bfloat16):
         _emit(gp.probe_xla_take(26744, 32768, r, dtype))
@@ -97,25 +76,9 @@ def main(argv=None) -> int:
             _emit(rec)
         for rec in gp.probe_xla_grouped_take(138493, 32768, r, dtype):
             _emit(rec)
-    # speculative Pallas forms (the fused kernel's gather impls)
-    for dtype in (jnp.float32, jnp.bfloat16):
-        name = jnp.dtype(dtype).name
-        _emit({"metric": "section", "form": "taa_axis0", "dtype": name})
-        for n in (8, 256, 2048, 8192, 26744):
-            _emit(gp.probe_taa0(n, r, dtype))
-    _emit({"metric": "section", "form": "taa_axis1"})
-    _emit(gp.probe_taa1(4096, r, jnp.float32))
-    _emit(gp.probe_taa1(26744, r, jnp.float32))
     _emit({"metric": "section", "form": "dma_row_gather"})
-    for dtype in (jnp.float32, jnp.bfloat16):
-        for nout in (4096, 32768):
-            _emit(gp.probe_dma(26744, nout, r, dtype))
-    # the in-process arbitration the fused kernel's "auto" mode applies
-    # (measured order on TPU, static documentation order elsewhere)
-    _emit({"metric": "gather_impl_preferred_order",
-           "backend": jax.default_backend(),
-           "order": list(gp.preferred_order(r, 4)),
-           "order_bf16": list(gp.preferred_order(r, 2))})
+    for nout in (4096, 32768):
+        _emit(gp.probe_dma(26744, nout, r))
     return 0
 
 

@@ -50,6 +50,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import TRAIN_PHASE_SECONDS, tower, xray
+from ..obs.timeline import annotate
 from ..parallel.mesh import DATA_AXIS, pad_to_multiple, replicated
 from ..storage.columnar import Ratings
 
@@ -784,13 +785,16 @@ def _solve_buckets(
             fused_gather_gram_solve, fused_tile_plan,
         )
     out = None
+    # the als.* scopes name each bucket's steps in the HLO metadata, so a
+    # profile finds the kernels by name whatever XLA fuses them into
     for (rows, starts, counts), k in zip(bucket_args, ks):
-        iota = jnp.arange(k, dtype=jnp.int32)
-        pos = jnp.minimum(starts[:, None] + iota[None, :], nnz - 1)
-        valid = iota[None, :] < counts[:, None]          # [B, K]
-        idx = jnp.where(valid, c_sorted[pos], 0)
-        val = jnp.where(valid, v_sorted[pos], 0.0)       # f32, masked
-        maskf = valid.astype(f32)
+        with jax.named_scope("als.positions"):
+            iota = jnp.arange(k, dtype=jnp.int32)
+            pos = jnp.minimum(starts[:, None] + iota[None, :], nnz - 1)
+            valid = iota[None, :] < counts[:, None]          # [B, K]
+            idx = jnp.where(valid, c_sorted[pos], 0)
+            val = jnp.where(valid, v_sorted[pos], 0.0)       # f32, masked
+            maskf = valid.astype(f32)
         if fused and fused_tile_plan(r, k) is not None:
             n_row = counts.astype(f32)
             lam_t = lam.astype(f32)
@@ -814,40 +818,48 @@ def _solve_buckets(
                 in_specs=(P(), _BATCH, _BATCH, _BATCH, _BATCH, P()),
                 out_specs=_BATCH,
             )(opp_g, idx, cwk, bwk, reg, g0)
-            out = upd_write(out, rows, x)
+            with jax.named_scope("als.scatter"):
+                out = upd_write(out, rows, x)
             continue
-        if opp_grp is not None:
-            # slab gather + in-slab select: exact same rows as the row
-            # gather, but every HBM read is a full memory tile.  The
-            # [*, K, G, R] slab is G times the row gather's output, so
-            # it's produced in row-chunks bounded by _GROUPED_SLAB_BYTES
-            # — the select shrinks each chunk back to [*, K, R] before
-            # the next one materializes.
-            bsz, k_ = idx.shape
-            per_row = k_ * grp * r * opp_grp.dtype.itemsize
-            bc = max(1, min(bsz, _GROUPED_SLAB_BYTES // max(per_row, 1)))
-
-            def _slab_rows(ix):
-                rows_n = ix.shape[0]
-                slab = jnp.take(opp_grp, ix // grp, axis=0)  # [n,K,G,R]
-                sel = jnp.broadcast_to(
-                    (ix % grp)[..., None, None], (rows_n, k_, 1, r)
+        with jax.named_scope("als.gather"):
+            if opp_grp is not None:
+                # slab gather + in-slab select: exact same rows as the
+                # row gather, but every HBM read is a full memory tile.
+                # The [*, K, G, R] slab is G times the row gather's
+                # output, so it's produced in row-chunks bounded by
+                # _GROUPED_SLAB_BYTES — the select shrinks each chunk
+                # back to [*, K, R] before the next one materializes.
+                bsz, k_ = idx.shape
+                per_row = k_ * grp * r * opp_grp.dtype.itemsize
+                bc = max(
+                    1, min(bsz, _GROUPED_SLAB_BYTES // max(per_row, 1))
                 )
-                return jnp.take_along_axis(slab, sel, axis=2)[..., 0, :]
 
-            if bc >= bsz:
-                Vm = _slab_rows(idx)
+                def _slab_rows(ix):
+                    rows_n = ix.shape[0]
+                    slab = jnp.take(opp_grp, ix // grp, axis=0)  # [n,K,G,R]
+                    sel = jnp.broadcast_to(
+                        (ix % grp)[..., None, None], (rows_n, k_, 1, r)
+                    )
+                    return jnp.take_along_axis(
+                        slab, sel, axis=2
+                    )[..., 0, :]
+
+                if bc >= bsz:
+                    Vm = _slab_rows(idx)
+                else:
+                    Vm = jnp.concatenate(
+                        [
+                            _slab_rows(idx[lo : lo + bc])
+                            for lo in range(0, bsz, bc)
+                        ],
+                        axis=0,
+                    )
+                Vm = Vm * valid[..., None].astype(Vm.dtype)
             else:
-                Vm = jnp.concatenate(
-                    [
-                        _slab_rows(idx[lo : lo + bc])
-                        for lo in range(0, bsz, bc)
-                    ],
-                    axis=0,
-                )
-            Vm = Vm * valid[..., None].astype(Vm.dtype)
-        else:
-            Vm = opp_g[idx] * valid[..., None].astype(opp_g.dtype)  # [B,K,R]
+                Vm = opp_g[idx] * valid[..., None].astype(
+                    opp_g.dtype
+                )                                            # [B, K, R]
         if stop_after == "gather":
             out = (0.0 if out is None else out) + Vm.astype(f32).sum()
             continue
@@ -873,34 +885,38 @@ def _solve_buckets(
             if stop_after == "gram":
                 out = (0.0 if out is None else out) + res
             else:
-                out = upd_write(out, rows, res)
+                with jax.named_scope("als.scatter"):
+                    out = upd_write(out, rows, res)
             continue
         # weight vectors are computed in f32 then cast to the gather dtype
         # right before the einsum, so a mixed-dtype contraction never
         # silently promotes (and re-materializes) the big Vm operand
-        if implicit:
-            cw = alpha.astype(f32) * val * maskf         # (c - 1), f32
-            A = gram + jnp.einsum(
-                "bk,bkr,bks->brs", cw.astype(Vm.dtype), Vm, Vm,
-                precision=prec, preferred_element_type=f32,
-            )
-            b = jnp.einsum(
-                "bk,bkr->br", ((1.0 + cw) * maskf).astype(Vm.dtype), Vm,
-                precision=prec, preferred_element_type=f32,
-            )
-        else:
-            A = jnp.einsum("bkr,bks->brs", Vm, Vm, precision=prec,
-                           preferred_element_type=f32)
-            b = jnp.einsum(
-                "bk,bkr->br", (val * maskf).astype(Vm.dtype), Vm,
-                precision=prec, preferred_element_type=f32,
-            )
-        A = A + reg[:, None, None] * jnp.eye(r, dtype=A.dtype)
+        with jax.named_scope("als.gram"):
+            if implicit:
+                cw = alpha.astype(f32) * val * maskf     # (c - 1), f32
+                A = gram + jnp.einsum(
+                    "bk,bkr,bks->brs", cw.astype(Vm.dtype), Vm, Vm,
+                    precision=prec, preferred_element_type=f32,
+                )
+                b = jnp.einsum(
+                    "bk,bkr->br", ((1.0 + cw) * maskf).astype(Vm.dtype),
+                    Vm, precision=prec, preferred_element_type=f32,
+                )
+            else:
+                A = jnp.einsum("bkr,bks->brs", Vm, Vm, precision=prec,
+                               preferred_element_type=f32)
+                b = jnp.einsum(
+                    "bk,bkr->br", (val * maskf).astype(Vm.dtype), Vm,
+                    precision=prec, preferred_element_type=f32,
+                )
+            A = A + reg[:, None, None] * jnp.eye(r, dtype=A.dtype)
         if stop_after == "gram":
             out = (0.0 if out is None else out) + A.sum() + b.sum()
             continue
-        x = _spd_solve(A, b, solver, mesh)
-        out = upd_write(out, rows, x)
+        with jax.named_scope("als.solve"):
+            x = _spd_solve(A, b, solver, mesh)
+        with jax.named_scope("als.scatter"):
+            out = upd_write(out, rows, x)
     return out
 
 
@@ -1995,12 +2011,14 @@ class ALSTrainer:
                 # halves are data-dependent, so the device pipeline
                 # loses nothing; only host dispatch-ahead is traded)
                 t0 = time.perf_counter()
-                U = jax.block_until_ready(
-                    self._half(U, V, self._user_side, lam=lam))
+                with annotate("pio.als.user_half"):
+                    U = jax.block_until_ready(
+                        self._half(U, V, self._user_side, lam=lam))
                 phases["user_half"] = time.perf_counter() - t0
                 t0 = time.perf_counter()
-                V = jax.block_until_ready(
-                    self._half(V, U, self._item_side, lam=lam))
+                with annotate("pio.als.item_half"):
+                    V = jax.block_until_ready(
+                        self._half(V, U, self._item_side, lam=lam))
                 phases["item_half"] = time.perf_counter() - t0
                 TRAIN_PHASE_SECONDS.labels(phase="als.user_half").observe(
                     phases["user_half"]

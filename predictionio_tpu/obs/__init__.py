@@ -548,12 +548,15 @@ def phase_span(name: str, attrs: Optional[dict] = None) -> Iterator[dict]:
 # ``from . import ...`` and register their metric families at import,
 # so every process's first scrape carries the full schema.  None
 # imports jax at module level — obs stays jax-free.
-from . import fleet, runlog, scope, timeline, tower, xray  # noqa: E402
+from . import (  # noqa: E402
+    fleet, gcpause, runlog, scope, timeline, tower, xray,
+)
 from .flight import FlightRecorder, get_flight_recorder  # noqa: E402
 
 __all__ += [
     "FlightRecorder",
     "fleet",
+    "gcpause",
     "get_flight_recorder",
     "runlog",
     "scope",

@@ -400,6 +400,15 @@ class MetricsRegistry:
     def __init__(self):
         self._lock = threading.Lock()
         self._families: dict[str, _Family] = {}
+        self._collect_hooks: list[Callable[[], None]] = []
+
+    def add_collect_hook(self, fn: Callable[[], None]) -> None:
+        """Run ``fn()`` before every collection of the registry.  For an
+        instrument that may take no lock where it measures (the garbage
+        collector's callback runs inside whatever allocation triggered
+        it) and hands its readings over here instead."""
+        with self._lock:
+            self._collect_hooks.append(fn)
 
     def _register(self, name: str, help_text: str, kind: str,
                   label_names: Sequence[str], ctor: Callable) -> _Family:
@@ -433,6 +442,10 @@ class MetricsRegistry:
         )
 
     def families(self) -> list:
+        with self._lock:
+            hooks = list(self._collect_hooks)
+        for fn in hooks:
+            fn()
         with self._lock:
             return sorted(self._families.values(), key=lambda f: f.name)
 

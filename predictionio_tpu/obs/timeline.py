@@ -37,21 +37,32 @@ never dropped), so per-segment means read off ``/metrics`` reconcile
 with the end-to-end latency histogram instead of silently leaking tail
 time.  ``tests/test_timeline.py`` holds the property test.
 
-On-demand deep dive: :func:`capture_profile` (mounted at
-``GET /debug/profile?seconds=S`` on all four servers) records a
-``jax.profiler`` trace into ``$PIO_TPU_HOME/telemetry/profiles/``;
-while a capture is live, the serving path and the micro-batcher wrap
-their work in ``jax.profiler.TraceAnnotation`` scopes (:class:`annotate`
-— a no-op boolean check otherwise), so timeline segments appear as
-named rows in the xplane/perfetto view next to the XLA ops they
-dispatched.
+The dispatcher's turn has a timeline of its own: the ``batch`` family
+(:class:`Turn`, ``pio_batch_turn_seconds{segment}``), one per claim,
+whose segments ``park -> claim -> prepare -> dispatch -> fetch ->
+decode -> complete`` say what the batcher's thread did between two
+device calls, in wall and in thread-CPU seconds.  Finished turns stay
+in a bounded in-memory deque (:func:`batch_turns`); each served
+request's ``serve.query`` span names its turn (``batchTurn``).
 
-Pure stdlib at import; jax loads lazily inside an active capture only.
+Profiler bridge: :class:`annotate` enters a
+``jax.profiler.TraceAnnotation`` in every process that has imported
+jax, whoever started the profiler (``GET /debug/profile`` through
+:func:`capture_profile`, ``jax.profiler.start_trace`` /
+``start_server``, a benchmark's own session); with no session live the
+annotation is the profiler's own cheap no-op.  Used as a ``with`` under
+a :class:`Turn`, the same call books the segment, so a span in the
+trace and a segment in memory are one call.
+
+Pure stdlib at import; jax is only looked up in ``sys.modules``.
 """
 
 from __future__ import annotations
 
+import collections
+import itertools
 import os
+import sys
 import threading
 import time
 from pathlib import Path
@@ -60,16 +71,18 @@ from typing import Optional, Sequence, Tuple
 from . import get_registry, log_buckets, telemetry_home
 
 __all__ = [
+    "BATCH_SEGMENTS",
     "EVENT_SEGMENTS",
     "ProfileBusy",
     "SERVE_SEGMENTS",
     "Timeline",
+    "Turn",
     "annotate",
+    "batch_turns",
     "capture_profile",
     "current_timeline",
     "mark",
     "profiles_dir",
-    "profiling_active",
     "register_segment_family",
     "timeline_scope",
 ]
@@ -83,6 +96,10 @@ SERVE_SEGMENTS = (
     "write",
 )
 EVENT_SEGMENTS = ("parse", "auth", "store_write", "reply")
+# the dispatcher's turn, in the order a turn passes through them
+BATCH_SEGMENTS = (
+    "park", "claim", "prepare", "dispatch", "fetch", "decode", "complete",
+)
 
 SERVE_SEGMENT_SECONDS = _registry.histogram(
     "pio_serve_segment_seconds",
@@ -95,6 +112,13 @@ EVENTS_SEGMENT_SECONDS = _registry.histogram(
     "pio_events_segment_seconds",
     "Per-request event-ingest segment durations "
     "(parse/auth/store_write/reply)",
+    labels=("segment",),
+)
+BATCH_TURN_SECONDS = _registry.histogram(
+    "pio_batch_turn_seconds",
+    "Wall seconds of one turn of the batch dispatcher's thread by "
+    "segment (park/claim/prepare/dispatch/fetch/decode/complete); a "
+    "turn's segments sum to its wall time, claim to claim",
     labels=("segment",),
 )
 SERVE_INFLIGHT = _registry.gauge(
@@ -168,6 +192,8 @@ def register_segment_family(family: str, histogram_family,
     }
 
 
+register_segment_family("batch", BATCH_TURN_SECONDS, BATCH_SEGMENTS)
+
 SERVE_INFLIGHT.child()
 MICROBATCH_QUEUE_DEPTH.child()
 MICROBATCH_BATCH_SIZE.child()
@@ -193,12 +219,15 @@ class Timeline:
     thread carrying the request), hence no lock.
     """
 
-    __slots__ = ("family", "segments", "t0", "_last")
+    __slots__ = ("family", "segments", "t0", "_last", "turn")
 
     def __init__(self, family: str = "serve"):
         self.family = family
         self.segments: dict[str, float] = {}
         self.t0 = self._last = time.perf_counter()
+        # number of the dispatcher's turn that served this request (the
+        # batcher books it with the entry's segments); a Turn's own
+        self.turn: Optional[int] = None
 
     def mark(self, segment: str) -> None:
         now = time.perf_counter()
@@ -284,6 +313,117 @@ def mark(segment: str) -> None:
         tl.mark(segment)
 
 
+# -- the dispatcher's turn ---------------------------------------------------
+
+# finished turns, newest last: over two minutes at 30 turns/s.  Appended
+# by the thread that ran the turn and copied whole by readers; both are
+# one call into the deque under the interpreter lock.
+_TURNS: collections.deque = collections.deque(maxlen=4096)
+_turn_numbers = itertools.count(1)
+
+
+def batch_turns() -> list:
+    """The finished turns still in memory, oldest first: per turn its
+    number ``turn``, ``t0`` (``perf_counter``), ``rows`` and ``padded``
+    rows sent to the device, per segment ``wall`` and thread-``cpu``
+    seconds, and ``gcSec`` the collector took on the turn's thread."""
+    return list(_TURNS)
+
+
+class Turn(Timeline):
+    """One turn of a batcher's leading thread, from where it starts to
+    wait for work to where its last completion callback has returned.
+
+    Segments are not marked but booked by :class:`annotate` scopes
+    named ``pio.turn.<segment>``, each with its own time only (a scope
+    inside another takes its time out of the outer one), in wall and in
+    thread-CPU seconds: wall minus CPU of a segment that does not wait
+    by design is time the thread sat runnable but off the CPU.
+    :meth:`finish` books what no scope covered to ``complete``, so the
+    segments still sum to the turn's wall time."""
+
+    __slots__ = ("cpu", "rows", "padded", "gc_s", "_c0", "_wall", "_cpu")
+
+    PREFIX = "pio.turn."    # + segment: the scopes that book themselves
+
+    def __init__(self):
+        super().__init__("batch")
+        self.turn = next(_turn_numbers)
+        self.cpu: dict[str, float] = {}
+        self.rows = self.padded = 0
+        self.gc_s = 0.0
+        self._c0 = time.thread_time()
+        self._wall = self._cpu = 0.0   # booked so far, all segments
+
+    def book(self, segment: str, wall: float, cpu: float) -> None:
+        self.segments[segment] = self.segments.get(segment, 0.0) + wall
+        self.cpu[segment] = self.cpu.get(segment, 0.0) + cpu
+        self._wall += wall
+        self._cpu += cpu
+
+    def finish(self) -> dict:
+        """Close the turn: residual to ``complete``, segments into
+        ``pio_batch_turn_seconds``, the record into :func:`batch_turns`."""
+        self.book("complete", self.elapsed() - self._wall,
+                  time.thread_time() - self._c0 - self._cpu)
+        _TURNS.append({
+            "turn": self.turn, "t0": self.t0, "rows": self.rows,
+            "padded": self.padded, "wall": dict(self.segments),
+            "cpu": dict(self.cpu), "gcSec": self.gc_s,
+        })
+        return super().finish()
+
+
+# -- jax.profiler bridge ------------------------------------------------------
+
+
+class annotate:
+    """A named scope on the profiler's clock: enters a
+    ``jax.profiler.TraceAnnotation`` whenever this process has imported
+    jax, whoever started the profiler (with no session live that is the
+    profiler's own no-op; a jax-free process pays one look-up).  The
+    name is a fixed string; sizes ride as keyword metadata
+    (``rows=``, ``padded=``), which the trace shows as the event's
+    stats.
+
+    Named ``pio.turn.<segment>`` under a :class:`Turn`, the same
+    ``with`` books the scope's own wall and thread-CPU time as that
+    segment; under a request's timeline, or none, it books nothing."""
+
+    __slots__ = ("name", "meta", "_cm", "_turn", "_t0", "_c0", "_w0",
+                 "_u0")
+
+    def __init__(self, name: str, **meta):
+        self.name = name
+        self.meta = meta
+
+    def __enter__(self) -> "annotate":
+        scope = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                        None)
+        self._cm = scope(self.name, **self.meta) if scope else None
+        if self._cm is not None:
+            self._cm.__enter__()
+        tl = getattr(_local, "tl", None)
+        if isinstance(tl, Turn) and self.name.startswith(Turn.PREFIX):
+            self._turn = tl
+            self._w0, self._u0 = tl._wall, tl._cpu
+            self._t0, self._c0 = time.perf_counter(), time.thread_time()
+        else:
+            self._turn = None
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tl = self._turn
+        if tl is not None:
+            wall = time.perf_counter() - self._t0
+            cpu = time.thread_time() - self._c0
+            tl.book(self.name[len(Turn.PREFIX):],
+                    wall - (tl._wall - self._w0),
+                    cpu - (tl._cpu - self._u0))
+        if self._cm is not None:
+            self._cm.__exit__(*exc)
+
+
 # -- on-demand jax.profiler capture ----------------------------------------
 
 
@@ -293,40 +433,6 @@ class ProfileBusy(RuntimeError):
 
 
 _capture_lock = threading.Lock()
-_profiling = False  # bare bool read on the hot path (GIL-atomic)
-
-
-def profiling_active() -> bool:
-    return _profiling
-
-
-class annotate:
-    """``jax.profiler.TraceAnnotation`` bridge: a named scope that
-    appears in the xplane while a :func:`capture_profile` is live and
-    costs one module-bool check otherwise.  Never raises — a jax-free
-    process simply produces no annotation."""
-
-    __slots__ = ("name", "_cm")
-
-    def __init__(self, name: str):
-        self.name = name
-        self._cm = None
-
-    def __enter__(self) -> "annotate":
-        if _profiling:
-            try:
-                import jax.profiler
-
-                self._cm = jax.profiler.TraceAnnotation(self.name)
-                self._cm.__enter__()
-            except Exception:
-                self._cm = None
-        return self
-
-    def __exit__(self, *exc) -> None:
-        if self._cm is not None:
-            cm, self._cm = self._cm, None
-            cm.__exit__(*exc)
 
 
 def profiles_dir() -> Path:
@@ -340,12 +446,12 @@ def capture_profile(seconds: float,
     Records a ``jax.profiler`` trace for ``seconds`` (clamped to
     [0.05, 60] — a scrape typo must not wedge a handler thread for an
     hour) into a fresh timestamped directory under
-    ``telemetry/profiles/``, with :class:`annotate` scopes live so
-    timeline segments land in the xplane.  Raises :class:`ProfileBusy`
+    ``telemetry/profiles/``; the program's :class:`annotate` scopes
+    (``pio.turn.*``, ``pio.serve.query``, ``pio.als.*``) land in the
+    xplane beside the XLA ops they dispatched.  Raises :class:`ProfileBusy`
     when a capture is already running; any profiler failure propagates
     to the caller (the HTTP mount answers 500 — a broken profiler must
     be loud, not an empty artifact)."""
-    global _profiling
     seconds = min(max(float(seconds), 0.05), 60.0)
     if not _capture_lock.acquire(blocking=False):
         raise ProfileBusy("a profile capture is already running")
@@ -357,11 +463,9 @@ def capture_profile(seconds: float,
         stamp = time.strftime("%Y%m%d-%H%M%S", time.gmtime())
         target = base / f"{stamp}-pid{os.getpid()}"
         jax.profiler.start_trace(str(target))
-        _profiling = True
         try:
             time.sleep(seconds)
         finally:
-            _profiling = False
             jax.profiler.stop_trace()
         files = sorted(
             str(p.relative_to(target))

@@ -31,16 +31,21 @@ def pow2_ceil(x: int) -> int:
 
 # xray.instrument: these three are THE serving-path executables — a
 # mid-traffic recompile here (un-pow2'd k or batch) is precisely what
-# the /debug/xray recompile ring exists to catch
+# the /debug/xray recompile ring exists to catch.  Their named scopes
+# put `topk.scores` / `topk.select` into the HLO metadata of the product
+# and of the selection, so a profile finds both by name whatever XLA
+# lowers them to.
 @xray.instrument("topk.topk_scores")
 @functools.partial(jax.jit, static_argnames=("k",))
 def topk_scores(query_vec: jax.Array, table: jax.Array, k: int,
                 bias: jax.Array | None = None):
     """scores = table @ query_vec (+bias); returns (values, indices) top-k."""
-    scores = table @ query_vec
-    if bias is not None:
-        scores = scores + bias
-    return jax.lax.top_k(scores, k)
+    with jax.named_scope("topk.scores"):
+        scores = table @ query_vec
+        if bias is not None:
+            scores = scores + bias
+    with jax.named_scope("topk.select"):
+        return jax.lax.top_k(scores, k)
 
 
 @xray.instrument("topk.batch_topk_scores")
@@ -49,10 +54,12 @@ def batch_topk_scores(query_vecs: jax.Array, table: jax.Array, k: int,
                       mask: jax.Array | None = None):
     """[B, R] x [M, R] -> top-k per row; ``mask`` (additive, [B, M] or [M])
     suppresses entries (use -inf)."""
-    scores = query_vecs @ table.T
-    if mask is not None:
-        scores = scores + mask
-    return jax.lax.top_k(scores, k)
+    with jax.named_scope("topk.scores"):
+        scores = query_vecs @ table.T
+        if mask is not None:
+            scores = scores + mask
+    with jax.named_scope("topk.select"):
+        return jax.lax.top_k(scores, k)
 
 
 @xray.instrument("topk.batch_topk_scores_t")
@@ -69,10 +76,12 @@ def batch_topk_scores_t(query_vecs: jax.Array, table_t: jax.Array, k: int,
     that the MXU never showed).  Serving keeps a transposed device
     cache (``DeviceTableMixin.device_item_factors_t``) so the hot path
     pays the transpose once per model advance, not per batch."""
-    scores = query_vecs @ table_t
-    if mask is not None:
-        scores = scores + mask
-    return jax.lax.top_k(scores, k)
+    with jax.named_scope("topk.scores"):
+        scores = query_vecs @ table_t
+        if mask is not None:
+            scores = scores + mask
+    with jax.named_scope("topk.select"):
+        return jax.lax.top_k(scores, k)
 
 
 @xray.instrument("topk.rerank_topk")

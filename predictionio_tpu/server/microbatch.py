@@ -39,6 +39,14 @@ queue+service time so the serving edge can reject a request that
 cannot make its SLO *up front* as a structured 503
 (:class:`AdmissionRejected`) rather than queue it to die.
 
+What the leading thread (the dispatcher, or a blocking leader) does
+between two device calls is one ``obs/timeline.Turn`` per claim: every
+step below runs under an ``annotate("pio.turn.<segment>")`` (park, claim,
+fetch, complete; an engine's ``batch_predict`` may carve prepare /
+dispatch / decode out of fetch), which is at once a span in any
+profiler session and a wall + thread-CPU segment of the turn's record
+(``timeline.batch_turns()``, ``pio_batch_turn_seconds{segment}``).
+
 Batch size therefore adapts to the arrival rate with no tuning knob
 doing latency/throughput trades behind the operator's back
 (``max_wait_s`` exists for completeness but defaults to 0).
@@ -65,8 +73,10 @@ from ..obs.timeline import (
     MICROBATCH_ROLE_TOTAL,
     MICROBATCH_TENANTS_PER_BATCH,
     MICROBATCH_WAIT_SECONDS,
+    Turn,
     annotate,
     current_timeline,
+    timeline_scope,
 )
 from ..resilience.policy import Deadline, DeadlineExceeded
 
@@ -175,7 +185,7 @@ class _Entry:
     # carries its fn for its whole life, so in-flight queries complete
     # on the model they snapshotted even across a tenant reload.
     __slots__ = ("item", "done", "value", "error", "deadline", "tl",
-                 "on_done", "tenant", "fn", "cb_fired",
+                 "on_done", "tenant", "fn", "cb_fired", "turn",
                  "t_enq", "t_claim", "t_run0", "t_run1")
 
     def __init__(self, item, deadline: Optional[Deadline] = None,
@@ -191,6 +201,7 @@ class _Entry:
         self.on_done = on_done
         self.tenant = tenant
         self.fn = fn
+        self.turn = None    # number of the turn that ran it (timeline.Turn)
         self.t_enq = time.perf_counter()
         self.t_claim = None
         self.t_run0 = None
@@ -342,16 +353,24 @@ class MicroBatcher:
                 if entry.done:
                     break
                 if not self._running and not self._dispatcher_alive:
-                    # become the leader for everything pending now
+                    # become the leader for everything pending now; the
+                    # turn shadows the caller's own serve timeline,
+                    # which _book_timeline below finds restored
                     self._running = True
-                    batch = self._claim_locked()
-                    # role bookkeeping: with > max_batch entries ahead,
-                    # the claimed batch may not include our own entry —
-                    # then we led for OTHERS and our request is still a
-                    # follower of some later batch
-                    if any(e is entry for e in batch):
-                        led_own = True
-                    self._lead(batch)
+                    turn = Turn()
+                    with timeline_scope(turn):
+                        with annotate("pio.turn.claim"):
+                            batch = self._claim_locked()
+                        # role bookkeeping: with > max_batch entries
+                        # ahead, the claimed batch may not include our
+                        # own entry — then we led for OTHERS and our
+                        # request is still a follower of a later batch
+                        if any(e is entry for e in batch):
+                            led_own = True
+                        try:
+                            self._lead(batch)
+                        finally:
+                            turn.finish()
                     continue  # re-check: our entry is done (we led it)
                 self._cond.wait()
             if led_own:
@@ -361,7 +380,7 @@ class MicroBatcher:
         (_m_leader if led_own else _m_follower).inc()
         # credit the caller's pulse timeline with what this entry
         # actually experienced (error requests decompose too)
-        self._book_timeline(entry)
+        self._book_timeline(entry, current_timeline())
         if entry.error is not None:
             raise entry.error
         return entry.value if entry.value is not _UNSET else None
@@ -419,37 +438,44 @@ class MicroBatcher:
         with self._cond:
             try:
                 while True:
-                    while not self._pending and not self._closed:
-                        self._cond.wait()
-                    if not self._pending and self._closed:
-                        break
-                    if self._running:
-                        # a blocking leader beat us to the claim
-                        self._cond.wait()
-                        continue
-                    self._running = True
-                    batch = self._claim_locked()
-                    try:
-                        self._lead(batch)
-                    except Exception:
-                        # _lead's finally already completed the batch;
-                        # the dispatcher itself must survive (a dead
-                        # dispatcher would wedge every future submit)
-                        logger.exception("microbatch dispatcher error")
+                    turn = Turn()
+                    with timeline_scope(turn):
+                        with annotate("pio.turn.park"):
+                            # nothing pending, or a blocking leader
+                            # beat us to the claim and has the device
+                            while (self._running if self._pending
+                                   else not self._closed):
+                                self._cond.wait()
+                        if not self._pending:
+                            break   # closed and drained
+                        self._running = True
+                        with annotate("pio.turn.claim"):
+                            batch = self._claim_locked()
+                        try:
+                            self._lead(batch)
+                        except Exception:
+                            # _lead's finally already completed the
+                            # batch; the dispatcher itself must survive
+                            # (a dead dispatcher would wedge every
+                            # future submit)
+                            logger.exception("microbatch dispatcher error")
+                        finally:
+                            turn.finish()
             finally:
                 self._dispatcher_alive = False
                 self._cond.notify_all()
 
-    def _book_timeline(self, entry: _Entry) -> None:
-        """Book queue_wait/batch_wait/device from the entry stamps onto
-        the entry's attached timeline (continuous path) or the calling
-        thread's current one (blocking path).  Residual time inside the
-        covered region (condition wake latency, a solo retry after a
-        failed batch) is attributed to ``device`` by add_block, so the
-        timeline's segment sum still equals wall time."""
-        tl = entry.tl if entry.tl is not None else current_timeline()
+    def _book_timeline(self, entry: _Entry, tl) -> None:
+        """Book queue_wait/batch_wait/device from the entry stamps, and
+        the number of the turn that ran it, onto the request's
+        timeline: the entry's attached one (continuous path) or the
+        calling thread's current one (blocking path).  Residual time
+        inside the covered region (condition wake latency, a solo retry
+        after a failed batch) is attributed to ``device`` by add_block,
+        so the timeline's segment sum still equals wall time."""
         if tl is None:
             return
+        tl.turn = entry.turn
         parts = []
         if entry.t_claim is not None:
             parts.append(("queue_wait", entry.t_claim - entry.t_enq))
@@ -477,18 +503,19 @@ class MicroBatcher:
         completed = False
         live: list[_Entry] = []
         n_expired = 0
-        for e in batch:
-            if e.deadline is not None and e.deadline.expired:
-                e.error = DeadlineExceeded(
-                    f"query expired in the batch queue after "
-                    f"{time.perf_counter() - e.t_enq:.3f}s (budget "
-                    f"{e.deadline.budget_s:.3f}s); never dispatched"
-                )
-                n_expired += 1
-            else:
-                live.append(e)
-        if n_expired:
-            _m_adm_expired.inc(n_expired)
+        with annotate("pio.turn.claim"):
+            for e in batch:
+                if e.deadline is not None and e.deadline.expired:
+                    e.error = DeadlineExceeded(
+                        f"query expired in the batch queue after "
+                        f"{time.perf_counter() - e.t_enq:.3f}s (budget "
+                        f"{e.deadline.budget_s:.3f}s); never dispatched"
+                    )
+                    n_expired += 1
+                else:
+                    live.append(e)
+            if n_expired:
+                _m_adm_expired.inc(n_expired)
         try:
             if self.max_wait_s > 0 and live and len(live) < self.max_batch:
                 # optional accumulation window (off by default): give
@@ -497,21 +524,22 @@ class MicroBatcher:
                 # included) so nothing queued during the window is left
                 # behind for the next leader.
                 deadline = time.monotonic() + self.max_wait_s
-                while len(live) < self.max_batch:
-                    left = deadline - time.monotonic()
-                    if left <= 0:
-                        break
-                    self._cond.wait(left)
-                    take = self.max_batch - len(live)
-                    absorbed = self._pending[:take]
-                    del self._pending[:take]
-                    if absorbed:
-                        now = time.perf_counter()
-                        for e in absorbed:
-                            e.t_claim = now
-                        live += absorbed
-                        batch += absorbed
-                        _m_queue_depth.set(float(len(self._pending)))
+                with annotate("pio.turn.park"):
+                    while len(live) < self.max_batch:
+                        left = deadline - time.monotonic()
+                        if left <= 0:
+                            break
+                        self._cond.wait(left)
+                        take = self.max_batch - len(live)
+                        absorbed = self._pending[:take]
+                        del self._pending[:take]
+                        if absorbed:
+                            now = time.perf_counter()
+                            for e in absorbed:
+                                e.t_claim = now
+                            live += absorbed
+                            batch += absorbed
+                            _m_queue_depth.set(float(len(self._pending)))
             if live:
                 self._cond.release()
                 try:
@@ -597,7 +625,9 @@ class MicroBatcher:
         (measured: 2-tenant QPS@SLO dropped ~25% and p99 grew by a
         full group time).  Runs WITHOUT the lock held."""
         t0 = time.perf_counter()
-        for fn, entries in self._group(batch):
+        with annotate("pio.turn.claim"):
+            groups = self._group(batch)
+        for fn, entries in groups:
             self._exec_group(fn, entries)
             self._fire_callbacks(entries)
         self._turn_s = max(time.perf_counter() - t0, 0.0)
@@ -608,15 +638,17 @@ class MicroBatcher:
         so the leader's end-of-turn sweep can still answer anything a
         BaseException left unfired.  Must be called WITHOUT the lock —
         callbacks enqueue response bytes to the event loop."""
-        for e in entries:
-            if e.on_done is None or e.cb_fired:
-                continue
-            e.cb_fired = True
-            self._book_timeline(e)
-            try:
-                e.on_done(e)
-            except Exception:
-                logger.exception("microbatch completion callback failed")
+        with annotate("pio.turn.complete"):
+            for e in entries:
+                if e.on_done is None or e.cb_fired:
+                    continue
+                e.cb_fired = True
+                self._book_timeline(e, e.tl)
+                try:
+                    e.on_done(e)
+                except Exception:
+                    logger.exception(
+                        "microbatch completion callback failed")
 
     def _exec_group(self, fn: Callable, batch: list[_Entry]) -> None:
         """Run one device call; on failure, isolate the blast radius.
@@ -629,18 +661,25 @@ class MicroBatcher:
         serving, paid only on the rare failure path.
         """
         try:
-            items = [e.item for e in batch]
-            n = len(items)
-            if self.pad_batches and n > 1:
-                items = items + [items[-1]] * (_pad_size(n) - n)
-            t0 = time.perf_counter()
-            for e in batch:
-                e.t_run0 = t0
-            if batch[0].t_claim is not None:
-                # accumulation-window cost: first claim -> dispatch
-                _m_batch_wait.observe(max(t0 - batch[0].t_claim, 0.0))
-            _m_batch_size.observe(float(n))
-            with annotate(f"pio.device.batch{len(items)}"):
+            with annotate("pio.turn.claim"):
+                items = [e.item for e in batch]
+                n = len(items)
+                if self.pad_batches and n > 1:
+                    items = items + [items[-1]] * (_pad_size(n) - n)
+                turn = current_timeline()   # the leading thread's Turn
+                turn.rows += n
+                turn.padded += len(items)
+                t0 = time.perf_counter()
+                for e in batch:
+                    e.t_run0 = t0
+                    e.turn = turn.turn
+                if batch[0].t_claim is not None:
+                    # accumulation-window cost: first claim -> dispatch
+                    _m_batch_wait.observe(max(t0 - batch[0].t_claim, 0.0))
+                _m_batch_size.observe(float(n))
+            # all of fn is `fetch` unless it books finer steps of its own
+            # (templates/recommendation.py: prepare, dispatch, decode)
+            with annotate("pio.turn.fetch", rows=n, padded=len(items)):
                 results = fn(items)
             t1 = time.perf_counter()
             for e in batch:

@@ -52,6 +52,7 @@ from ..obs import (
     TRACE_HEADER,
     Histogram,
     current_trace_id,
+    gcpause,
     get_flight_recorder,
     get_tracer,
     new_trace_id,
@@ -511,6 +512,7 @@ class EngineServer(HTTPServerBase):
         # memory gauges fresh (registered like the breaker gauges above)
         xray.install()
         xray.start_sampler()
+        gcpause.install()
         # pio-scope: the always-on CPU sampler rides every serving
         # process (no-op when --no-profiler / PIO_TPU_SCOPE=0 opted out)
         scope.ensure_started()
@@ -1079,6 +1081,9 @@ class EngineServer(HTTPServerBase):
         }
         if ctx.foldin_seq:
             attrs["foldinSeq"] = ctx.foldin_seq
+        if tl.turn is not None:
+            # the dispatcher's turn that scored it (timeline.batch_turns)
+            attrs["batchTurn"] = tl.turn
         if lease is not None:
             # pio-hive: per-tenant latency histogram + online-eval
             # impression + trace/flight attribution (a slow query's

@@ -33,6 +33,7 @@ from ..controller import (
     WorkflowContext,
 )
 from ..models.als import ALSConfig, train_als
+from ..obs.timeline import annotate
 from ..ops.topk import (
     batch_topk_scores,  # noqa: F401 — public template API surface
     batch_topk_scores_t,
@@ -678,33 +679,36 @@ class ALSAlgorithm(Algorithm):
         out: list[PredictedResult] = [
             PredictedResult(item_scores=()) for _ in queries
         ]
-        uix = np.array(
-            [model.users.get(q.user) for q in queries], dtype=np.int64
-        )
-        nums = np.array([q.num for q in queries], dtype=np.int64)
-        valid = (uix >= 0) & (nums > 0)
-        if not valid.any():
-            return out
-        n_items = len(model.items)
-        k = min(pow2_ceil(int(nums[valid].max())), n_items)
-        uvecs = model.user_factors[np.where(valid, uix, 0)]
-        masks = [
-            self._allowed_mask(model, q) if v else None
-            for q, v in zip(queries, valid)
-        ]
-        if any(m is not None for m in masks):
-            zero = np.zeros(n_items, dtype=np.float32)
-            mask = np.stack([zero if m is None else m for m in masks])
-        else:
-            mask = None
-        rcfg = self._retrieval_config()
+        # the pio.turn.* scopes are the batch dispatcher's turn segments
+        # (obs/timeline.Turn); outside a turn they only name the step in
+        # a profiler trace
+        with annotate("pio.turn.prepare"):
+            uix = np.array(
+                [model.users.get(q.user) for q in queries], dtype=np.int64
+            )
+            nums = np.array([q.num for q in queries], dtype=np.int64)
+            valid = (uix >= 0) & (nums > 0)
+            if not valid.any():
+                return out
+            n_items = len(model.items)
+            k = min(pow2_ceil(int(nums[valid].max())), n_items)
+            uvecs = model.user_factors[np.where(valid, uix, 0)]
+            masks = [
+                self._allowed_mask(model, q) if v else None
+                for q, v in zip(queries, valid)
+            ]
+            if any(m is not None for m in masks):
+                zero = np.zeros(n_items, dtype=np.float32)
+                mask = np.stack([zero if m is None else m for m in masks])
+            else:
+                mask = None
+            rcfg = self._retrieval_config()
         if mask is None and getattr(self.params, "distributed_topk",
                                     False):
             # the micro-batched serving path rides the same parity-coded
             # ring as solo predict (the ring takes a [B, R] query block
             # natively); per-query masks keep the local scorer below
             vals, ixs = self._sharded_index(model)(uvecs, k)
-            vals, ixs = np.asarray(vals), np.asarray(ixs)
         elif mask is None and rcfg is not None:
             # pio-scout two-stage: the batched serving path is exactly
             # where the candidate stage pays — per-batch device work
@@ -713,20 +717,24 @@ class ALSAlgorithm(Algorithm):
             vals, ixs = model.device_ann_index(rcfg).search(
                 uvecs, k, model.device_item_factors(self._serve_dtype())
             )
-            vals, ixs = np.asarray(vals), np.asarray(ixs)
         else:
             # the pre-transposed [R, M] table: same math, ~5x the
             # batched-matmul GFLOPS on CPU (ops/topk.py)
-            vals, ixs = batch_topk_scores_t(
-                uvecs, model.device_item_factors_t(self._serve_dtype()),
-                k, mask=mask,
+            with annotate("pio.turn.dispatch"):
+                vals, ixs = batch_topk_scores_t(
+                    uvecs,
+                    model.device_item_factors_t(self._serve_dtype()),
+                    k, mask=mask,
+                )
+        with annotate("pio.turn.fetch"):
+            vals, ixs = jax.device_get((vals, ixs))
+        with annotate("pio.turn.decode"):
+            decoded = decode_batch_item_scores(
+                model.items, vals, ixs, [q.num for q in queries], valid, k
             )
-        decoded = decode_batch_item_scores(
-            model.items, vals, ixs, [q.num for q in queries], valid, k
-        )
-        return [
-            PredictedResult(item_scores=scores) for scores in decoded
-        ]
+            return [
+                PredictedResult(item_scores=scores) for scores in decoded
+            ]
 
     def predict_rating(self, model: ALSModel, user: str, item: str) -> float:
         """Point prediction for RMSE-style evaluation."""

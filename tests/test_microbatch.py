@@ -531,3 +531,163 @@ def test_mixed_blocking_and_continuous_coalesce():
     assert sizes == [1, 2]
     assert stats["followers"] == 2
     b.close()
+
+
+# -- the dispatcher's turn (obs/timeline.Turn) -------------------------------
+
+
+def _turn(number):
+    """The finished turn's record; a dispatcher finishes its turn after
+    the last completion callback has returned, so poll for it."""
+    from predictionio_tpu.obs.timeline import batch_turns
+
+    deadline = time.time() + 10
+    while True:
+        found = [t for t in batch_turns() if t["turn"] == number]
+        if found:
+            (rec,) = found
+            return rec
+        assert time.time() < deadline, f"turn {number} never finished"
+        time.sleep(0.002)
+
+
+def test_dispatcher_turn_is_recorded_with_rows_and_segments():
+    """One turn of the dispatcher: park until work arrives, claim and
+    pad, the whole of a plain batch_fn under `fetch`, the callbacks
+    under `complete`; the segments sum to the turn's wall time and the
+    entries name the turn."""
+    done = threading.Event()
+    entries = []
+
+    def batch_fn(xs):
+        time.sleep(0.03)
+        return list(xs)
+
+    def on_done(entry):
+        entries.append(entry)
+        time.sleep(0.005)
+        if len(entries) == 3:
+            done.set()
+
+    b = MicroBatcher(batch_fn, pad_batches=True)
+    with b._cond:   # all three are pending before the dispatcher claims
+        for x in range(3):
+            b.submit_nowait(x, on_done)
+    assert done.wait(10)
+    b.close()
+    (number,) = {e.turn for e in entries}
+    rec = _turn(number)
+    assert (rec["rows"], rec["padded"]) == (3, 4)
+    wall = rec["wall"]
+    assert {"park", "claim", "fetch", "complete"} <= set(wall)
+    assert wall["fetch"] >= 0.03 and wall["complete"] >= 0.015
+    assert all(v >= 0 for v in wall.values())
+    # sleeping is not computing: the thread's CPU time stays far below
+    assert rec["cpu"]["fetch"] < 0.5 * wall["fetch"]
+    assert set(rec["cpu"]) == set(wall)
+
+
+def test_finer_steps_of_the_engine_come_out_of_fetch():
+    """An engine that books `prepare` / `dispatch` / `decode` inside its
+    batch function leaves `fetch` only what it did not name."""
+    from predictionio_tpu.obs.timeline import annotate
+
+    def batch_fn(xs):
+        with annotate("pio.turn.prepare"):
+            time.sleep(0.02)
+        with annotate("pio.turn.dispatch"):
+            pass
+        time.sleep(0.01)    # unnamed: stays in the batcher's `fetch`
+        with annotate("pio.turn.decode"):
+            time.sleep(0.02)
+        return list(xs)
+
+    got = []
+    b = MicroBatcher(batch_fn)
+    b.submit_nowait(1, got.append)
+    deadline = time.time() + 10
+    while not got:
+        assert time.time() < deadline
+        time.sleep(0.002)
+    b.close()
+    wall = _turn(got[0].turn)["wall"]
+    assert wall["prepare"] >= 0.02 and wall["decode"] >= 0.02
+    assert 0.01 <= wall["fetch"] < 0.02
+    assert "dispatch" in wall
+
+
+def test_blocking_leader_turn_shadows_and_restores_the_serve_timeline():
+    from predictionio_tpu.obs.timeline import (
+        Timeline, current_timeline, timeline_scope,
+    )
+
+    def batch_fn(xs):
+        time.sleep(0.01)
+        return list(xs)
+
+    b = MicroBatcher(batch_fn)
+    tl = Timeline("serve")
+    with timeline_scope(tl):
+        assert b.submit(7) == 7
+        assert current_timeline() is tl
+    # the request's own timeline holds its own segments only, and the
+    # number of the turn that ran it
+    assert set(tl.segments) == {"queue_wait", "batch_wait", "device"}
+    rec = _turn(tl.turn)
+    assert rec["rows"] == 1 and rec["wall"]["fetch"] >= 0.01
+    assert "park" not in rec["wall"]    # a leader never waits for work
+
+
+def test_served_request_names_its_turn(storage_memory):
+    """Over HTTP on the continuous path: the request's `serve.query`
+    span (and its flight record) carries `batchTurn`, the number of a
+    turn in the deque whose rows include it."""
+    import json
+    import urllib.request
+
+    from predictionio_tpu.controller.base import (
+        Algorithm, DataSource, WorkflowContext,
+    )
+    from predictionio_tpu.controller.engine import SimpleEngine
+    from predictionio_tpu.obs import get_flight_recorder, get_tracer
+    from predictionio_tpu.server.serving import EngineServer, ServerConfig
+    from predictionio_tpu.workflow.train import run_train
+
+    class DS(DataSource):
+        def read_training(self, ctx):
+            return 1
+
+    class BatchedAlgo(Algorithm):
+        def train(self, ctx, data):
+            return {"w": 2}
+
+        def predict(self, model, query):
+            return {"y": model["w"] * query.get("x", 0)}
+
+        def batch_predict(self, model, queries):
+            return [self.predict(model, q) for q in queries]
+
+    ctx = WorkflowContext(storage=storage_memory)
+    engine = SimpleEngine(DS, BatchedAlgo)
+    ep = engine.params_from_variant({})
+    iid = run_train(engine, ep, ctx=ctx)
+    srv = EngineServer(engine, ep, iid, ctx=ctx, config=ServerConfig(port=0))
+    srv.start_background()
+    try:
+        tid = "t-turn-http"
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{srv.config.port}/queries.json",
+            data=b'{"x": 4}', method="POST",
+            headers={"Content-Type": "application/json",
+                     "X-PIO-Trace": tid},
+        )
+        with urllib.request.urlopen(req, timeout=15) as r:
+            assert json.loads(r.read().decode()) == {"y": 8}
+    finally:
+        srv.stop()
+    (span,) = get_tracer().spans(trace_id=tid, name="serve.query")
+    rec = _turn(span.attrs["batchTurn"])
+    assert rec["rows"] >= 1
+    flight = get_flight_recorder().record_for(tid)
+    if flight is not None:  # may be evicted by slower suite traffic
+        assert flight["attrs"]["batchTurn"] == span.attrs["batchTurn"]

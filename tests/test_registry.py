@@ -111,7 +111,7 @@ def test_shipped_env_template_parses_and_boots(tmp_path):
     # every PIO_* key in the template is one the code reads
     known = {
         "PIO_TPU_HOME", "PIO_TPU_SCAN_CACHE",
-        "PIO_TPU_VMEM_BYTES", "PIO_TPU_PROFILE", "PIO_TPU_BENCH_BUDGET_S",
+        "PIO_TPU_VMEM_BYTES", "PIO_TPU_BENCH_BUDGET_S",
     }
     for key in env:
         if key.startswith("PIO_TPU_"):

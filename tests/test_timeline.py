@@ -2,10 +2,13 @@
 accounting-identity property (segments are non-negative and sum to the
 measured end-to-end wall time), segment threading through predict_json
 / the HTTP handler / the micro-batcher / the event-server ingest route,
-flight-record decomposition attrs, the on-demand profiler capture, and
-the dashboard /pulse.html view."""
+flight-record decomposition attrs, the batch dispatcher's turn family
+and the `annotate` scopes that book it, the on-demand profiler capture,
+and the dashboard /pulse.html view."""
 
 import json
+import subprocess
+import sys
 import threading
 import time
 import urllib.error
@@ -16,12 +19,17 @@ import pytest
 
 from predictionio_tpu.obs import QUERY_LATENCY, get_tracer
 from predictionio_tpu.obs.timeline import (
+    BATCH_SEGMENTS,
+    BATCH_TURN_SECONDS,
     EVENT_SEGMENTS,
     EVENTS_SEGMENT_SECONDS,
     SERVE_SEGMENTS,
     SERVE_SEGMENT_SECONDS,
     ProfileBusy,
     Timeline,
+    Turn,
+    annotate,
+    batch_turns,
     capture_profile,
     current_timeline,
     mark,
@@ -125,6 +133,158 @@ def test_finish_observes_into_family():
     assert tl.snapshot_ms()["device"] == pytest.approx(
         segs["device"] * 1e3, abs=0.002
     )
+
+
+# -- the dispatcher's turn: segments booked by `annotate` ---------------------
+
+
+def _walk(rng, depth=0):
+    """Random nest of `pio.turn.*` scopes with busy time between them."""
+    for _ in range(rng.integers(1, 4)):
+        _busy(float(rng.uniform(0.05, 0.6)))
+        with annotate("pio.turn." + str(rng.choice(BATCH_SEGMENTS))):
+            _busy(float(rng.uniform(0.05, 0.6)))
+            if depth < 2 and rng.random() < 0.5:
+                _walk(rng, depth + 1)
+
+
+def test_turn_property_nested_scopes_sum_to_wall_time():
+    """Property: for ANY nest of scopes, each books its own time only,
+    and with the residual to `complete` a finished turn's segments sum
+    to its wall time; thread-CPU seconds never pass wall seconds."""
+    rng = np.random.default_rng(7)
+    for _ in range(20):
+        turn = Turn()
+        with timeline_scope(turn):
+            _walk(rng)
+        t_end = time.perf_counter()
+        segs = turn.finish()
+        assert set(segs) <= set(BATCH_SEGMENTS)
+        assert all(v >= -1e-9 for v in segs.values())
+        wall = sum(segs.values())
+        assert turn.t0 + wall >= t_end
+        assert wall == pytest.approx(t_end - turn.t0, abs=2e-4)
+        rec = batch_turns()[-1]
+        assert rec["turn"] == turn.turn and rec["t0"] == turn.t0
+        assert rec["wall"] == segs
+        # the walk spins, so it is on the CPU for nearly all of it
+        cpu = sum(rec["cpu"].values())
+        assert 0.5 * wall <= cpu <= wall + 1e-3
+
+
+def test_turn_numbers_rise_and_finish_observes_the_family():
+    before = {s: BATCH_TURN_SECONDS.labels(segment=s).snapshot()["count"]
+              for s in BATCH_SEGMENTS}
+    a, b = Turn(), Turn()
+    assert b.turn == a.turn + 1
+    with timeline_scope(a), annotate("pio.turn.fetch", rows=3, padded=4):
+        _busy(0.2)
+    a.rows, a.padded = 3, 4
+    a.finish()
+    after = {s: BATCH_TURN_SECONDS.labels(segment=s).snapshot()["count"]
+             for s in BATCH_SEGMENTS}
+    assert after["fetch"] == before["fetch"] + 1
+    assert after["complete"] == before["complete"] + 1
+    assert after["park"] == before["park"]
+    rec = batch_turns()[-1]
+    assert (rec["rows"], rec["padded"], rec["gcSec"]) == (3, 4, 0.0)
+
+
+def test_annotate_books_only_on_a_timeline_its_name_addresses():
+    """`pio.turn.*` under a request's serve timeline (a direct
+    predict, eval) and any other name under a turn book nothing."""
+    serve = Timeline("serve")
+    with timeline_scope(serve), annotate("pio.turn.prepare"):
+        pass
+    assert serve.segments == {}
+    turn = Turn()
+    with timeline_scope(turn), annotate("pio.serve.query"):
+        pass
+    assert turn.segments == {}
+    with annotate("pio.turn.decode"):   # no timeline in scope at all
+        pass
+
+
+def _host_events(trace_dir, prefix):
+    from jax.profiler import ProfileData
+
+    (path,) = trace_dir.rglob("*.xplane.pb")
+    found = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    found.append((ev.name, dict(ev.stats)))
+    return found
+
+
+def test_annotate_lands_in_a_profiler_session_it_did_not_start(tmp_path):
+    """The benchmark, `jax.profiler.start_server` or a notebook start
+    the profiler themselves; the program's scopes have to be in that
+    trace too, sizes as the event's stats and not in its name."""
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with annotate("pio.turn.fetch", rows=5, padded=8):
+            _busy(1.0)
+        with annotate("pio.turn.decode"):
+            _busy(1.0)
+    finally:
+        jax.profiler.stop_trace()
+    events = dict(_host_events(tmp_path, "pio.turn."))
+    assert set(events) == {"pio.turn.fetch", "pio.turn.decode"}
+    assert int(events["pio.turn.fetch"]["rows"]) == 5
+    assert int(events["pio.turn.fetch"]["padded"]) == 8
+
+
+def test_annotate_imports_no_jax_in_a_process_that_has_none():
+    code = (
+        "import sys\n"
+        "from predictionio_tpu.obs.timeline import Turn, annotate, "
+        "timeline_scope\n"
+        "turn = Turn()\n"
+        "with timeline_scope(turn), annotate('pio.turn.claim', rows=1):\n"
+        "    pass\n"
+        "assert 'claim' in turn.segments\n"
+        "assert not [m for m in sys.modules if m.split('.')[0] == 'jax']\n"
+    )
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
+
+
+# -- names for the kernels ----------------------------------------------------
+
+
+def test_lowered_scorer_and_half_iteration_hold_the_scope_names():
+    import jax.numpy as jnp
+    from predictionio_tpu.models import als
+    from predictionio_tpu.ops.topk import batch_topk_scores_t
+
+    text = batch_topk_scores_t.lower(
+        jnp.ones((4, 8)), jnp.ones((8, 64)), k=4
+    ).as_text(debug_info=True)
+    for name in ("topk.scores", "topk.select"):
+        assert f"/{name}/" in text, name
+
+    rng = np.random.default_rng(0)
+    u, i = rng.integers(0, 30, 400), rng.integers(0, 20, 400)
+    trainer = als.ALSTrainer(
+        (u, i, rng.uniform(1, 5, 400).astype(np.float32)), 30, 20,
+        als.ALSConfig(rank=4, num_iterations=1),
+    )
+    U, V = trainer.init_factors()
+    side = trainer._user_side
+    text = als._half_iteration.lower(
+        U, V, side["c_sorted"], side["v_sorted"], side["buckets"],
+        jnp.float32(0.1), jnp.float32(1.0), ks=side["ks"],
+        implicit=False, weighted_lambda=True, precision="highest",
+        solver=trainer.cfg.solver,
+    ).as_text(debug_info=True)
+    for name in ("als.positions", "als.gather", "als.gram", "als.solve",
+                 "als.scatter"):
+        assert f"/{name}/" in text, name
 
 
 # -- serving integration ----------------------------------------------------

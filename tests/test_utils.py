@@ -1,4 +1,4 @@
-"""utils subsystem: logging tiers, debug dumper, profiler hooks."""
+"""utils subsystem: logging tiers, debug dumper."""
 
 import dataclasses
 import logging
@@ -8,7 +8,6 @@ import numpy as np
 from predictionio_tpu.utils import (
     debug_string,
     modify_logging,
-    profile_trace,
     setup_logging,
 )
 
@@ -47,18 +46,3 @@ def test_debug_string_dataclass_and_truncation():
 
     s = debug_string(TD(id=3, vals=list(range(100))))
     assert s.startswith("TD(id=3") and "..." in s
-
-
-def test_profile_trace_disabled_is_noop(tmp_path, monkeypatch):
-    monkeypatch.delenv("PIO_TPU_PROFILE", raising=False)
-    with profile_trace("t") as out:
-        assert out is None
-
-
-def test_profile_trace_enabled_writes(tmp_path, monkeypatch):
-    monkeypatch.setenv("PIO_TPU_HOME", str(tmp_path))
-    import jax.numpy as jnp
-
-    with profile_trace("unit", enabled=True) as out:
-        (jnp.ones((8, 8)) @ jnp.ones((8, 8))).block_until_ready()
-    assert out is not None and any(out.rglob("*"))

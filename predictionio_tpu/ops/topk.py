@@ -3,21 +3,40 @@
 Replaces the reference's predict-time cosine scan over the
 ``productFeatures`` RDD (`/root/reference/examples/scala-parallel-
 recommendation/custom-query/src/main/scala/ALSAlgorithm.scala` predict) with
-one fused XLA matmul + ``lax.top_k`` per (batch of) queries — MXU work with
-a static ``k`` so the compiled executable is reused across requests.
+one device program per (batch of) queries and a static ``k``, so the
+compiled executable is reused across requests.  Small or masked calls
+are one XLA matmul + ``lax.top_k`` over the whole row (the dense path);
+an unmasked call over a long catalogue scores and selects in blocks of
+the item axis (the blocked path below) and never writes the ``[B, M]``
+score matrix.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..obs import xray
+from ..obs import get_registry, xray
+from .solve import pallas_interpret
 
 __all__ = ["topk_scores", "batch_topk_scores", "batch_topk_scores_t",
+           "ItemTables", "pack_rows", "patch_packed_rows", "rows_per_line",
+           "topk_path",
            "cosine_topk", "rerank_topk", "pow2_ceil"]
+
+TOPK_PATH = get_registry().counter(
+    "pio_topk_path_total",
+    "Calls of the serving scorers (topk_scores, batch_topk_scores, "
+    "batch_topk_scores_t) by the path their shapes chose: blocked "
+    "(block scan, top-k over block maxima, rescoring of the chosen "
+    "blocks) or dense (matmul + top-k over the whole row)",
+    labels=("path",),
+)
 
 
 def pow2_ceil(x: int) -> int:
@@ -29,12 +48,35 @@ def pow2_ceil(x: int) -> int:
     return 1 << (max(int(x), 1) - 1).bit_length()
 
 
+class _Counted:
+    """An instrumented scorer that also counts ``pio_topk_path_total``
+    once per call (``path_of`` takes the call's arguments; without one the
+    scorer is always dense).  Other attributes (``lower`` ...) are the
+    wrapped callable's."""
+
+    __slots__ = ("_fn", "_path_of", "__wrapped__")
+
+    def __init__(self, fn, path_of=None):
+        self._fn = fn
+        self._path_of = path_of
+        self.__wrapped__ = fn
+
+    def __call__(self, *args, **kwargs):
+        path = self._path_of(*args, **kwargs) if self._path_of else "dense"
+        TOPK_PATH.labels(path=path).inc()
+        return self._fn(*args, **kwargs)
+
+    def __getattr__(self, item):
+        return getattr(self._fn, item)
+
+
 # xray.instrument: these three are THE serving-path executables — a
 # mid-traffic recompile here (un-pow2'd k or batch) is precisely what
 # the /debug/xray recompile ring exists to catch.  Their named scopes
-# put `topk.scores` / `topk.select` into the HLO metadata of the product
-# and of the selection, so a profile finds both by name whatever XLA
-# lowers them to.
+# (`topk.scores` / `topk.select`; `topk.scan|blocks|rescore|select` on
+# the blocked path) go into the HLO metadata, so a profile finds each
+# stage by name whatever XLA lowers it to.
+@_Counted
 @xray.instrument("topk.topk_scores")
 @functools.partial(jax.jit, static_argnames=("k",))
 def topk_scores(query_vec: jax.Array, table: jax.Array, k: int,
@@ -48,6 +90,7 @@ def topk_scores(query_vec: jax.Array, table: jax.Array, k: int,
         return jax.lax.top_k(scores, k)
 
 
+@_Counted
 @xray.instrument("topk.batch_topk_scores")
 @functools.partial(jax.jit, static_argnames=("k",))
 def batch_topk_scores(query_vecs: jax.Array, table: jax.Array, k: int,
@@ -62,20 +105,312 @@ def batch_topk_scores(query_vecs: jax.Array, table: jax.Array, k: int,
         return jax.lax.top_k(scores, k)
 
 
+# -- the blocked path: exact top-k without the [B, M] score matrix -----------
+#
+# The item axis is cut into blocks; a scan keeps each block's best score
+# per query, lax.top_k picks the k best blocks, and only their items are
+# scored again and selected from.  Exact: an item of the true top-k scores
+# at least the k-th best score, so does its block's maximum, and a block
+# outside the k largest maxima is beaten by k items of k other blocks.
+#
+# A block is `blk` items 128 apart: block b of super-block s = b // 128
+# holds items s*blk*128 + (b % 128) + 128*g, g < blk.  That is what one
+# lane of a [B, blk*128] score tile sees, so the scan's reduction is an
+# elementwise maximum of vregs (no cross-lane work), and its output is
+# lane-dense.
+
+_LANES = 128
+_BLOCK_ITEMS = (64, 32, 16, 8)   # items to a block, largest first
+_RESCORE_BYTES = 16 << 20        # most bytes of candidate rows to gather
+_BLOCKS_PER_K = 8                # blocked only where M >= this * k * blk
+_TILE_BYTES = 4 << 20            # of the table, per grid step of the scan
+_PACK_ITEMS = 1 << 18            # items pack_rows re-lays at a time
+
+
+class ItemTables(NamedTuple):
+    """The two device layouts of one item table that the blocked path
+    reads, handed to :func:`batch_topk_scores_t` in place of the
+    transposed one alone: the scan streams ``t`` (``[R, M]``, items on the
+    lanes), the rescoring gathers item rows from ``packed``
+    (:func:`pack_rows`)."""
+
+    t: jax.Array
+    packed: jax.Array
+
+    @property
+    def shape(self):
+        return self.t.shape
+
+
+def rows_per_line(rank: int) -> int:
+    """Item rows to one 128-lane line of the packed table; 0 where the
+    rank neither divides nor is a multiple of the lane count."""
+    if rank % _LANES == 0:
+        return 1
+    return _LANES // rank if _LANES % rank == 0 else 0
+
+
+@jax.jit
+def pack_rows(table: jax.Array) -> jax.Array:
+    """``[M, R]`` -> ``[ceil(M / p), p * R]`` with ``p * R`` a multiple of
+    128: the row-major table with p consecutive items to a line, so that
+    a line is whole lanes and a gather of lines moves contiguous memory.
+    The TPU keeps a narrow ``[M, 64]`` array column-major (no lane
+    padding: the same bytes as its transpose), and a row gather from it,
+    like a column gather from ``[R, M]``, makes XLA re-lay the whole table
+    on every call.  Packing is that re-lay done once, `_PACK_ITEMS` items
+    at a time: in one piece its temporaries are four times the table."""
+    n_items, rank = table.shape
+    p = rows_per_line(rank)
+    if not p:
+        raise ValueError(f"rows of rank {rank} pack into no 128-lane line")
+    out = jnp.zeros((-(-n_items // p), p * rank), table.dtype)
+
+    def put(out, part, first_line):
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, part.reshape(part.shape[0] // p, p * rank), first_line, 0)
+
+    n_whole = n_items // _PACK_ITEMS
+    if n_whole:
+        out = jax.lax.fori_loop(
+            0, n_whole,
+            lambda i, out: put(out, jax.lax.dynamic_slice_in_dim(
+                table, i * _PACK_ITEMS, _PACK_ITEMS), i * (_PACK_ITEMS // p)),
+            out)
+    rest = table[n_whole * _PACK_ITEMS:]
+    if rest.shape[0]:
+        rest = jnp.pad(rest, ((0, -rest.shape[0] % p), (0, 0)))
+        out = put(out, rest, n_whole * (_PACK_ITEMS // p))
+    return out
+
+
+def patch_packed_rows(packed: jax.Array, n_items: int, ixs, rows,
+                      appended=None) -> jax.Array:
+    """The packed table of `n_items` items with rows `ixs` set to `rows`
+    and `appended` (``[A, R]`` or None) added as items ``n_items ..``:
+    a scatter of the delta's elements, no rebuild (pio-live)."""
+    rank = rows.shape[1]
+    p = packed.shape[1] // rank
+    ixs = jnp.asarray(ixs, jnp.int32)
+    rows = jnp.asarray(rows)
+    if appended is not None and len(appended):
+        total = n_items + len(appended)
+        packed = jnp.pad(
+            packed, ((0, -(-total // p) - packed.shape[0]), (0, 0)))
+        ixs = jnp.concatenate([ixs, jnp.arange(n_items, total,
+                                               dtype=jnp.int32)])
+        rows = jnp.concatenate([rows, jnp.asarray(appended)])
+    lanes = (ixs % p)[:, None] * rank + jnp.arange(rank, dtype=jnp.int32)
+    return packed.at[(ixs // p)[:, None], lanes].set(
+        rows.astype(packed.dtype))
+
+
+def block_items(batch: int, n_items: int, rank: int, k: int,
+                itemsize: int = 4) -> int:
+    """Items to a block for a ``[batch, rank] x [rank, n_items]`` top-k,
+    or 0 where the dense form is the right one: the largest block whose
+    ``batch * k`` chosen blocks gather within `_RESCORE_BYTES`, over a
+    catalogue long enough that the blocks outnumber k several times, at
+    a rank whose rows pack into whole lanes."""
+    if not rows_per_line(rank):
+        return 0
+    for blk in _BLOCK_ITEMS:
+        if batch * k * blk * rank * itemsize <= _RESCORE_BYTES:
+            return blk if n_items >= _BLOCKS_PER_K * k * blk else 0
+    return 0
+
+
+def topk_path(query_vecs, table_t, k: int, mask=None) -> str:
+    """``"blocked"`` or ``"dense"``: what :func:`batch_topk_scores_t`
+    does with these arguments, decided from their shapes alone."""
+    # the [B, M] additive mask needs the [B, M] scores; the rescoring
+    # needs the packed rows
+    if mask is None and isinstance(table_t, ItemTables) and block_items(
+            query_vecs.shape[0], *table_t.shape[::-1], k,
+            table_t.packed.dtype.itemsize):
+        return "blocked"
+    return "dense"
+
+
+def _mxu_operands() -> bool:
+    """Whether this backend's default matmul precision rounds float32
+    operands to bfloat16 (the TPU's does): the scan kernel and the
+    rescoring then round the same way, so both see one score per item."""
+    return jax.default_backend() == "tpu"
+
+
+def _block_max_kernel(q_ref, t_ref, out_ref, *, n_items: int, blk: int,
+                      to_bf16: bool):
+    """One tile of the transposed table: ``[B, R] x [R, TM]`` on the MXU,
+    then per super-block the elementwise maximum of its `blk` lane
+    groups.  The table's ragged tail (the tile's columns >= n_items hold
+    whatever the DMA left there) is masked here, on the scores."""
+    tm = t_ref.shape[1]
+    sb = blk * _LANES
+    op = jnp.bfloat16 if to_bf16 else jnp.float32
+    q = q_ref[...].astype(op)
+    tile0 = pl.program_id(0) * tm
+    for c in range(tm // sb):
+        scores = jnp.dot(q, t_ref[:, c * sb:(c + 1) * sb].astype(op),
+                         preferred_element_type=jnp.float32)
+        col0 = tile0 + c * sb
+
+        def put(s):
+            best = s[:, :_LANES]
+            for g in range(1, blk):
+                best = jnp.maximum(best, s[:, g * _LANES:(g + 1) * _LANES])
+            out_ref[:, c * _LANES:(c + 1) * _LANES] = best
+
+        @pl.when(col0 + sb <= n_items)
+        def _():
+            put(scores)
+
+        @pl.when(col0 + sb > n_items)
+        def _():
+            cols = col0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+            put(jnp.where(cols < n_items, scores, -jnp.inf))
+
+
+def block_maxima(query_vecs: jax.Array, table_t: jax.Array, blk: int,
+                 interpret: bool | None = None) -> jax.Array:
+    """The scan as one Pallas kernel: ``[B, n_blocks]`` float32, the best
+    score of each block, reading the table once and writing no score
+    matrix.  ``interpret=None`` follows :func:`ops.solve.pallas_interpret`."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    batch, rank = query_vecs.shape
+    n_items = table_t.shape[1]
+    sb = blk * _LANES
+    per_col = rank * jnp.dtype(table_t.dtype).itemsize
+    tm = sb * max(1, _TILE_BYTES // per_col // sb)
+    tm = min(tm, sb * pl.cdiv(n_items, sb))
+    n_tiles = pl.cdiv(n_items, tm)
+    rows = 8 * pl.cdiv(batch, 8)
+    q = jnp.pad(query_vecs, ((0, rows - batch), (0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_block_max_kernel, n_items=n_items, blk=blk,
+                          to_bf16=_mxu_operands()),
+        out_shape=jax.ShapeDtypeStruct((rows, n_tiles * tm // blk),
+                                       jnp.float32),
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec((rows, rank), lambda j: (0, 0)),
+                  pl.BlockSpec((rank, tm), lambda j: (0, j))],
+        out_specs=pl.BlockSpec((rows, tm // blk), lambda j: (0, j)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=64 << 20,
+        ),
+        name="pio_block_max",
+        interpret=interpret,
+    )(q, table_t)
+    return out[:batch]
+
+
+def block_maxima_jnp(query_vecs: jax.Array, table_t: jax.Array,
+                     blk: int) -> jax.Array:
+    """The same scan in plain ``jnp`` (the off-TPU form): the product is
+    written and read back once, but no full-width top-k walks it."""
+    batch = query_vecs.shape[0]
+    n_items = table_t.shape[1]
+    sb = blk * _LANES
+    n_sb = -(-n_items // sb)
+    scores = jnp.pad(query_vecs @ table_t,
+                     ((0, 0), (0, n_sb * sb - n_items)),
+                     constant_values=-jnp.inf)
+    return scores.reshape(batch, n_sb, blk, _LANES).max(axis=2).reshape(
+        batch, n_sb * _LANES)
+
+
+def _select_k(scores: jax.Array, ids: jax.Array, k: int):
+    """The k best of ``[B, P]`` candidates by (score descending, id
+    ascending), as k passes of a row maximum: P is k blocks, so this is
+    small, and it is neither a sort nor a ``top_k`` (the batch keeps ONE
+    `TopK` device op, which the benchmark's readers count)."""
+    gone = jnp.iinfo(jnp.int32).max
+
+    def step(carry, _):
+        s, ix = carry
+        best = s.max(axis=1, keepdims=True)
+        pick = jnp.where(s == best, ix, gone).min(axis=1, keepdims=True)
+        hit = ix == pick
+        return ((jnp.where(hit, -jnp.inf, s), jnp.where(hit, gone, ix)),
+                (best[:, 0], pick[:, 0]))
+
+    _, (vals, ixs) = jax.lax.scan(step, (scores, ids), None, length=k)
+    return vals.T, ixs.T
+
+
+def _blocked_topk(query_vecs, tables: ItemTables, k: int, blk: int):
+    rank, n_items = tables.t.shape
+    n_queries = query_vecs.shape[0]
+    # whole sublanes of queries: the kernel wants them, and XLA then
+    # lowers the top_k below to a `TopK` custom call of its own for a
+    # one-row batch too (it wraps that of a [1, n] operand in a fusion,
+    # whose device event carries another name)
+    batch = 8 * pl.cdiv(n_queries, 8)
+    query_vecs = jnp.pad(query_vecs, ((0, batch - n_queries), (0, 0)))
+    with jax.named_scope("topk.scan"):
+        if _mxu_operands():
+            maxima = block_maxima(query_vecs, tables.t, blk)
+        else:
+            maxima = block_maxima_jnp(query_vecs, tables.t, blk)
+    with jax.named_scope("topk.blocks"):
+        # ties go to the lower block index (lax.top_k is stable)
+        _, chosen = jax.lax.top_k(maxima, k)
+    with jax.named_scope("topk.rescore"):
+        first = (chosen // _LANES) * (blk * _LANES) + chosen % _LANES
+        ids = (first[:, :, None]
+               + _LANES * jnp.arange(blk, dtype=jnp.int32)[None, None, :]
+               ).reshape(batch, k * blk)
+        inside = ids < n_items
+        p = rows_per_line(rank)
+        safe = jnp.where(inside, ids, 0)
+        lines = tables.packed[safe // p].astype(jnp.float32)  # [B, P, p*R]
+        q = jnp.tile(query_vecs.astype(jnp.float32), (1, p))  # [B, p*R]
+        if _mxu_operands():
+            # what the MXU's default precision does to float32 operands,
+            # written as an op XLA may not drop; the products of two
+            # such values are exact in float32, like the MXU's
+            lines, q = (jax.lax.reduce_precision(x, 8, 7)
+                        for x in (lines, q))
+        prod = lines * q[:, None, :]
+        if p > 1:   # the lanes of a line that are this candidate's row
+            lane_row = jnp.arange(p * rank, dtype=jnp.int32) // rank
+            prod = jnp.where(lane_row == (safe % p)[:, :, None], prod, 0.0)
+        scores = prod.sum(axis=-1)
+        scores = jnp.where(inside, scores, -jnp.inf)
+    with jax.named_scope("topk.select"):
+        vals, ixs = _select_k(scores, ids, k)
+        return vals[:n_queries], ixs[:n_queries]
+
+
+@functools.partial(_Counted, path_of=topk_path)
 @xray.instrument("topk.batch_topk_scores_t")
 @functools.partial(jax.jit, static_argnames=("k",))
-def batch_topk_scores_t(query_vecs: jax.Array, table_t: jax.Array, k: int,
+def batch_topk_scores_t(query_vecs: jax.Array,
+                        table_t: jax.Array | ItemTables, k: int,
                         mask: jax.Array | None = None):
-    """[B, R] x [R, M] (PRE-TRANSPOSED table) -> top-k per row.
+    """[B, R] x [R, M] (PRE-TRANSPOSED table) -> top-k per row:
+    ``([B, k] float32 descending, [B, k] int32 item ids)``.
 
-    Identical math to :func:`batch_topk_scores`, radically different
-    lowering on CPU: with the contraction dim contiguous on BOTH
-    operands the batched matmul vectorizes along the M output axis —
-    measured 10.6 ms -> 2.1 ms for [16, 64] x [64, 100k] f32 on one
-    core (XLA's Eigen path pays a strided-RHS penalty ``@ table.T``
-    that the MXU never showed).  Serving keeps a transposed device
-    cache (``DeviceTableMixin.device_item_factors_t``) so the hot path
-    pays the transpose once per model advance, not per batch."""
+    The layout is the one the MXU streams: the scan reads ``[R, TM]``
+    tiles of the table as the matmul's right operand with the items on
+    the lanes.  Unmasked over a long catalogue (:func:`block_items`) the
+    call takes the blocked path: the table is read once, each block's
+    best score is kept, and the k chosen blocks' items are scored again at
+    the scan's precision; exact, because the true top-k can only lie in
+    the k blocks with the largest maxima.  With a mask (additive,
+    ``[B, M]``), a short catalogue or a large k it is the dense
+    ``query_vecs @ table_t`` + ``lax.top_k``.  Serving keeps the
+    transposed device copy (``DeviceTableMixin.device_item_factors_t``),
+    so the hot path pays the transpose once per model advance."""
+    if mask is None and isinstance(table_t, ItemTables):
+        blk = block_items(query_vecs.shape[0], *table_t.shape[::-1], k,
+                          table_t.packed.dtype.itemsize)
+        if blk:
+            return _blocked_topk(query_vecs, table_t, k, blk)
+    if isinstance(table_t, ItemTables):
+        table_t = table_t.t
     with jax.named_scope("topk.scores"):
         scores = query_vecs @ table_t
         if mask is not None:

@@ -83,7 +83,19 @@ class DeviceTableMixin:
                 np.linalg.norm(a, axis=-1, keepdims=True) + 1e-9
             )
 
+        from ..ops.topk import patch_packed_rows
+
+        # the host table is the patched one already (see above)
+        n_before = len(self.item_factors) - (
+            0 if app_np is None else len(app_np)
+        )
         for attr in list(vars(self)):
+            if attr.startswith("_dev_item_packed_"):
+                # the packed rows of `device_item_tables`: a scatter of
+                # the delta, like the row writes below
+                setattr(self, attr, patch_packed_rows(
+                    getattr(self, attr), n_before, ixs_d, rows_np, app_np
+                ))
             if not attr.startswith("_dev_item_factors_"):
                 continue
             normed = attr.startswith("_dev_item_factors_norm_")
@@ -120,11 +132,10 @@ class DeviceTableMixin:
 
     def device_item_factors_t(self, dtype: Optional[str] = None):
         """The item table PRE-TRANSPOSED to ``[R, M]`` (contiguous) —
-        the layout the batched serving matmul wants on CPU backends
-        (``ops.topk.batch_topk_scores_t``: contraction dim contiguous
-        on both operands, ~5x the GFLOPS of ``@ table.T`` through
-        XLA's Eigen path).  Cached per dtype; pio-live delta applies
-        patch it column-wise in place."""
+        the layout ``ops.topk.batch_topk_scores_t`` scores against: the
+        items lie on the lanes, so the blocked scan streams ``[R, TM]``
+        tiles of it as the matmul's right operand.  Cached per dtype;
+        pio-live delta applies patch it column-wise in place."""
         import jax.numpy as jnp
 
         key = f"_dev_item_factors_t_{dtype or 'native'}"
@@ -137,6 +148,25 @@ class DeviceTableMixin:
                 dev = dev.astype(jnp.dtype(dtype))
             setattr(self, key, dev)
         return dev
+
+    def device_item_tables(self, dtype: Optional[str] = None):
+        """What the batched scorer is handed
+        (``ops.topk.batch_topk_scores_t``): the transposed table for the
+        scan and the packed rows (``ops.topk.pack_rows``, cached per
+        dtype beside the tables) for rescoring the chosen blocks.  At a
+        rank whose rows pack into no line the scorer has no blocked
+        path, and gets the transposed table alone: no third copy."""
+        from ..ops.topk import ItemTables, pack_rows, rows_per_line
+
+        table_t = self.device_item_factors_t(dtype)
+        if not rows_per_line(table_t.shape[0]):
+            return table_t
+        key = f"_dev_item_packed_{dtype or 'native'}"
+        packed = getattr(self, key, None)
+        if packed is None:
+            packed = pack_rows(self.device_item_factors(dtype))
+            setattr(self, key, packed)
+        return ItemTables(table_t, packed)
 
     def device_ann_index(self, cfg):
         """Lazy per-config two-stage ANN retriever (pio-scout), cached
@@ -267,7 +297,9 @@ def warm_batched_topk(table, rank: int, n: int,
     def warm(vecs, k, mask=None):
         # warm the scorer the caller's batch path actually dispatches:
         # the transposed [R, M] one when a transposed table is given
-        # (recommendation), the classic [M, R] one otherwise
+        # (recommendation: its `device_item_tables`, so every rung
+        # compiles the path, blocked or dense, that its shapes will
+        # take under traffic), the classic [M, R] one otherwise
         if table_t is not None:
             batch_topk_scores_t(vecs, table_t, k, mask=mask)
         else:

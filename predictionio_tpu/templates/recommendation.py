@@ -38,6 +38,7 @@ from ..ops.topk import (
     batch_topk_scores,  # noqa: F401 — public template API surface
     batch_topk_scores_t,
     pow2_ceil,
+    topk_path,
     topk_scores,
 )
 from ..storage.columnar import Ratings
@@ -582,7 +583,7 @@ class ALSAlgorithm(Algorithm):
             topk_scores(vec, table, k, bias=bias)
         warm_batched_topk(
             table, rank, n, unmasked_too=True, max_batch=max_batch,
-            table_t=model.device_item_factors_t(self._serve_dtype()),
+            table_t=model.device_item_tables(self._serve_dtype()),
         )
         rcfg = self._retrieval_config()
         if rcfg is not None and not getattr(self.params,
@@ -718,14 +719,14 @@ class ALSAlgorithm(Algorithm):
                 uvecs, k, model.device_item_factors(self._serve_dtype())
             )
         else:
-            # the pre-transposed [R, M] table: same math, ~5x the
-            # batched-matmul GFLOPS on CPU (ops/topk.py)
-            with annotate("pio.turn.dispatch"):
-                vals, ixs = batch_topk_scores_t(
-                    uvecs,
-                    model.device_item_factors_t(self._serve_dtype()),
-                    k, mask=mask,
-                )
+            # the pre-transposed [R, M] table with the packed rows: an
+            # unmasked batch over a long catalogue is scored in blocks of
+            # the item axis (one read of the table, no [B, M] matrix;
+            # exact), anything else as one matmul + top-k (ops/topk.py)
+            tables = model.device_item_tables(self._serve_dtype())
+            with annotate("pio.turn.dispatch",
+                          path=topk_path(uvecs, tables, k, mask)):
+                vals, ixs = batch_topk_scores_t(uvecs, tables, k, mask=mask)
         with annotate("pio.turn.fetch"):
             vals, ixs = jax.device_get((vals, ixs))
         with annotate("pio.turn.decode"):

@@ -8,7 +8,11 @@ compiled executable is reused across requests.  Small or masked calls
 are one XLA matmul + ``lax.top_k`` over the whole row (the dense path);
 an unmasked call over a long catalogue scores and selects in blocks of
 the item axis (the blocked path below) and never writes the ``[B, M]``
-score matrix.
+score matrix.  A query's exclusions travel as item ids (``exclude``: one
+``[B, E]`` int32 array padded with -1) and are applied on the device, on
+the blocked path to the gathered candidates; only a filter that is no
+list of ids (``categories``, ``whiteList``) still needs the ``[B, M]``
+additive mask and with it the dense path.
 """
 
 from __future__ import annotations
@@ -26,7 +30,7 @@ from .solve import pallas_interpret
 
 __all__ = ["topk_scores", "batch_topk_scores", "batch_topk_scores_t",
            "ItemTables", "pack_rows", "patch_packed_rows", "rows_per_line",
-           "topk_path",
+           "topk_path", "EXCLUDE_LADDER", "exclude_width",
            "cosine_topk", "rerank_topk", "pow2_ceil"]
 
 TOPK_PATH = get_registry().counter(
@@ -34,7 +38,9 @@ TOPK_PATH = get_registry().counter(
     "Calls of the serving scorers (topk_scores, batch_topk_scores, "
     "batch_topk_scores_t) by the path their shapes chose: blocked "
     "(block scan, top-k over block maxima, rescoring of the chosen "
-    "blocks) or dense (matmul + top-k over the whole row)",
+    "blocks), blocked_ids (the same with excluded ids applied to the "
+    "candidates on the device) or dense (matmul + top-k over the "
+    "whole row, masked or not)",
     labels=("path",),
 )
 
@@ -126,20 +132,46 @@ _BLOCKS_PER_K = 8                # blocked only where M >= this * k * blk
 _TILE_BYTES = 4 << 20            # of the table, per grid step of the scan
 _PACK_ITEMS = 1 << 18            # items pack_rows re-lays at a time
 
+# ... with excluded ids: `TopK` over the maxima costs 4.6 times as much
+# an element at 32 chosen blocks as at 16 (v5e: [64, 584,704] 8.7 ms,
+# [16, 146,176] 0.41), a gathered line 8-11 ns, so larger blocks pay
+_RESCORE_BYTES_IDS = 128 << 20
+
+# Widths E of the ``[B, E]`` array of excluded ids, so that the compiled
+# programs stay (pow2 B) x (pow2 k) x (these).  One rung: every width is
+# one more program a rung of the warm-up ladder (with 4 and 16 a server
+# was 3 s later ready than with 16 alone; v5e).  A batch whose longest
+# list is longer takes a ``[B, M]`` mask.
+EXCLUDE_LADDER = (32,)
+
+
+def exclude_width(n_excluded: int) -> int:
+    """The ladder's rung for a batch whose longest list of excluded ids
+    has `n_excluded` entries; 0 where it has none or the ladder ends
+    below it."""
+    if n_excluded <= 0:
+        return 0
+    return next((e for e in EXCLUDE_LADDER if n_excluded <= e), 0)
+
 
 class ItemTables(NamedTuple):
     """The two device layouts of one item table that the blocked path
     reads, handed to :func:`batch_topk_scores_t` in place of the
     transposed one alone: the scan streams ``t`` (``[R, M]``, items on the
     lanes), the rescoring gathers item rows from ``packed``
-    (:func:`pack_rows`)."""
+    (:func:`pack_rows`).  At a rank of whole lines (128, 256) ``packed``
+    is the row-major ``[M, R]`` table itself and ``t`` is None: the scan
+    reads the rows, because the TPU keeps ``[128, M]`` float32 with the
+    short axis minor (the row-major table's own bytes) and would re-lay
+    all of it for the kernel on every call."""
 
-    t: jax.Array
+    t: jax.Array | None
     packed: jax.Array
 
     @property
     def shape(self):
-        return self.t.shape
+        """``(rank, n_items)``, as the transposed table's."""
+        return self.packed.shape[::-1] if self.t is None else self.t.shape
 
 
 def rows_per_line(rank: int) -> int:
@@ -206,30 +238,50 @@ def patch_packed_rows(packed: jax.Array, n_items: int, ixs, rows,
 
 
 def block_items(batch: int, n_items: int, rank: int, k: int,
-                itemsize: int = 4) -> int:
-    """Items to a block for a ``[batch, rank] x [rank, n_items]`` top-k,
-    or 0 where the dense form is the right one: the largest block whose
-    ``batch * k`` chosen blocks gather within `_RESCORE_BYTES`, over a
-    catalogue long enough that the blocks outnumber k several times, at
-    a rank whose rows pack into whole lanes."""
+                itemsize: int = 4, n_exclude: int = 0) -> int:
+    """Items to a block for a ``[batch, rank] x [rank, n_items]`` top-k
+    with up to `n_exclude` excluded ids a row, or 0 where the dense form
+    is the right one: the largest block whose ``batch * (k + n_exclude)``
+    chosen blocks gather within the budget (`_RESCORE_BYTES`, or
+    `_RESCORE_BYTES_IDS` with excluded ids), over a catalogue long
+    enough that the blocks outnumber those chosen several times, at a
+    rank whose rows pack into whole lanes."""
     if not rows_per_line(rank):
         return 0
+    chosen = k + n_exclude
+    budget = _RESCORE_BYTES_IDS if n_exclude else _RESCORE_BYTES
     for blk in _BLOCK_ITEMS:
-        if batch * k * blk * rank * itemsize <= _RESCORE_BYTES:
-            return blk if n_items >= _BLOCKS_PER_K * k * blk else 0
+        if batch * chosen * blk * rank * itemsize <= budget:
+            return blk if n_items >= _BLOCKS_PER_K * chosen * blk else 0
     return 0
 
 
-def topk_path(query_vecs, table_t, k: int, mask=None) -> str:
+def _blocked_items(query_vecs, table_t, k: int, mask, exclude) -> int:
+    """`block_items` of a call's arguments: 0 with a ``[B, M]`` mask
+    (it needs the ``[B, M]`` scores) or without the packed rows (the
+    rescoring gathers them)."""
+    if mask is not None or not isinstance(table_t, ItemTables):
+        return 0
+    return block_items(
+        query_vecs.shape[0], *table_t.shape[::-1], k,
+        table_t.packed.dtype.itemsize,
+        0 if exclude is None else exclude.shape[1])
+
+
+def topk_path(query_vecs, table_t, k: int, mask=None, exclude=None) -> str:
     """``"blocked"`` or ``"dense"``: what :func:`batch_topk_scores_t`
     does with these arguments, decided from their shapes alone."""
-    # the [B, M] additive mask needs the [B, M] scores; the rescoring
-    # needs the packed rows
-    if mask is None and isinstance(table_t, ItemTables) and block_items(
-            query_vecs.shape[0], *table_t.shape[::-1], k,
-            table_t.packed.dtype.itemsize):
-        return "blocked"
-    return "dense"
+    return ("blocked" if _blocked_items(query_vecs, table_t, k, mask,
+                                        exclude) else "dense")
+
+
+def _counted_path(query_vecs, table_t, k: int, mask=None,
+                  exclude=None) -> str:
+    """`pio_topk_path_total`'s label: the path, and on the blocked one
+    whether excluded ids rode along."""
+    path = topk_path(query_vecs, table_t, k, mask, exclude)
+    return "blocked_ids" if path == "blocked" and exclude is not None \
+        else path
 
 
 def _mxu_operands() -> bool:
@@ -240,19 +292,28 @@ def _mxu_operands() -> bool:
 
 
 def _block_max_kernel(q_ref, t_ref, out_ref, *, n_items: int, blk: int,
-                      to_bf16: bool):
+                      to_bf16: bool, row_major: bool = False):
     """One tile of the transposed table: ``[B, R] x [R, TM]`` on the MXU,
     then per super-block the elementwise maximum of its `blk` lane
     groups.  The table's ragged tail (the tile's columns >= n_items hold
-    whatever the DMA left there) is masked here, on the scores."""
-    tm = t_ref.shape[1]
+    whatever the DMA left there) is masked here, on the scores.  With
+    `row_major` the tile is ``[TM, R]`` of the row-major table and the
+    product contracts both operands' last axis (6.36 ms either way over
+    9.35 M x 128; v5e)."""
+    tm = t_ref.shape[0 if row_major else 1]
     sb = blk * _LANES
     op = jnp.bfloat16 if to_bf16 else jnp.float32
     q = q_ref[...].astype(op)
     tile0 = pl.program_id(0) * tm
     for c in range(tm // sb):
-        scores = jnp.dot(q, t_ref[:, c * sb:(c + 1) * sb].astype(op),
-                         preferred_element_type=jnp.float32)
+        if row_major:   # a [TM, R] tile of item rows: q x rows^T
+            scores = jax.lax.dot_general(
+                q, t_ref[c * sb:(c + 1) * sb, :].astype(op),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            scores = jnp.dot(q, t_ref[:, c * sb:(c + 1) * sb].astype(op),
+                             preferred_element_type=jnp.float32)
         col0 = tile0 + c * sb
 
         def put(s):
@@ -272,14 +333,16 @@ def _block_max_kernel(q_ref, t_ref, out_ref, *, n_items: int, blk: int,
 
 
 def block_maxima(query_vecs: jax.Array, table_t: jax.Array, blk: int,
-                 interpret: bool | None = None) -> jax.Array:
+                 interpret: bool | None = None,
+                 row_major: bool = False) -> jax.Array:
     """The scan as one Pallas kernel: ``[B, n_blocks]`` float32, the best
     score of each block, reading the table once and writing no score
-    matrix.  ``interpret=None`` follows :func:`ops.solve.pallas_interpret`."""
+    matrix.  ``interpret=None`` follows :func:`ops.solve.pallas_interpret`.
+    With `row_major` `table_t` is the ``[M, R]`` table itself."""
     if interpret is None:
         interpret = pallas_interpret()
     batch, rank = query_vecs.shape
-    n_items = table_t.shape[1]
+    n_items = table_t.shape[0 if row_major else 1]
     sb = blk * _LANES
     per_col = rank * jnp.dtype(table_t.dtype).itemsize
     tm = sb * max(1, _TILE_BYTES // per_col // sb)
@@ -287,14 +350,16 @@ def block_maxima(query_vecs: jax.Array, table_t: jax.Array, blk: int,
     n_tiles = pl.cdiv(n_items, tm)
     rows = 8 * pl.cdiv(batch, 8)
     q = jnp.pad(query_vecs, ((0, rows - batch), (0, 0)))
+    table_spec = (pl.BlockSpec((tm, rank), lambda j: (j, 0)) if row_major
+                  else pl.BlockSpec((rank, tm), lambda j: (0, j)))
     out = pl.pallas_call(
         functools.partial(_block_max_kernel, n_items=n_items, blk=blk,
-                          to_bf16=_mxu_operands()),
+                          to_bf16=_mxu_operands(), row_major=row_major),
         out_shape=jax.ShapeDtypeStruct((rows, n_tiles * tm // blk),
                                        jnp.float32),
         grid=(n_tiles,),
         in_specs=[pl.BlockSpec((rows, rank), lambda j: (0, 0)),
-                  pl.BlockSpec((rank, tm), lambda j: (0, j))],
+                  table_spec],
         out_specs=pl.BlockSpec((rows, tm // blk), lambda j: (0, j)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
@@ -306,11 +371,13 @@ def block_maxima(query_vecs: jax.Array, table_t: jax.Array, blk: int,
     return out[:batch]
 
 
-def block_maxima_jnp(query_vecs: jax.Array, table_t: jax.Array,
-                     blk: int) -> jax.Array:
+def block_maxima_jnp(query_vecs: jax.Array, table_t: jax.Array, blk: int,
+                     row_major: bool = False) -> jax.Array:
     """The same scan in plain ``jnp`` (the off-TPU form): the product is
     written and read back once, but no full-width top-k walks it."""
     batch = query_vecs.shape[0]
+    if row_major:
+        table_t = table_t.T
     n_items = table_t.shape[1]
     sb = blk * _LANES
     n_sb = -(-n_items // sb)
@@ -340,28 +407,47 @@ def _select_k(scores: jax.Array, ids: jax.Array, k: int):
     return vals.T, ixs.T
 
 
-def _blocked_topk(query_vecs, tables: ItemTables, k: int, blk: int):
-    rank, n_items = tables.t.shape
+def _excluded(ids: jax.Array, exclude: jax.Array) -> jax.Array:
+    """``[B, P]`` bool: which of a row's candidate ids are in its list
+    of excluded ids (``[B, E]``; the -1 padding equals no id)."""
+    return (ids[:, :, None] == exclude[:, None, :]).any(axis=-1)
+
+
+def _blocked_topk(query_vecs, tables: ItemTables, k: int, blk: int,
+                  exclude: jax.Array | None = None):
+    """Exact top-k of the allowed items.  A row with e excluded ids finds
+    its k best allowed items among the k + e blocks with the largest
+    UNMASKED maxima: a block that outranks the k-th allowed score and
+    holds none of the k has an excluded item as its maximum, and there
+    are at most e of those.  So the scan is the unfiltered one, ``k + E``
+    blocks are chosen, and the exclusions are applied to the gathered
+    candidates before the select."""
+    rank, n_items = tables.shape
     n_queries = query_vecs.shape[0]
+    n_blocks = k if exclude is None else k + exclude.shape[1]
+    # the table the scan streams: transposed, or the rows themselves
+    row_major = tables.t is None
+    scanned = tables.packed if row_major else tables.t
     # whole sublanes of queries: the kernel wants them, and XLA then
     # lowers the top_k below to a `TopK` custom call of its own for a
     # one-row batch too (it wraps that of a [1, n] operand in a fusion,
     # whose device event carries another name)
     batch = 8 * pl.cdiv(n_queries, 8)
     query_vecs = jnp.pad(query_vecs, ((0, batch - n_queries), (0, 0)))
+    if exclude is not None:
+        exclude = jnp.pad(exclude, ((0, batch - n_queries), (0, 0)),
+                          constant_values=-1)
     with jax.named_scope("topk.scan"):
-        if _mxu_operands():
-            maxima = block_maxima(query_vecs, tables.t, blk)
-        else:
-            maxima = block_maxima_jnp(query_vecs, tables.t, blk)
+        scan = block_maxima if _mxu_operands() else block_maxima_jnp
+        maxima = scan(query_vecs, scanned, blk, row_major=row_major)
     with jax.named_scope("topk.blocks"):
         # ties go to the lower block index (lax.top_k is stable)
-        _, chosen = jax.lax.top_k(maxima, k)
+        _, chosen = jax.lax.top_k(maxima, n_blocks)
     with jax.named_scope("topk.rescore"):
         first = (chosen // _LANES) * (blk * _LANES) + chosen % _LANES
         ids = (first[:, :, None]
                + _LANES * jnp.arange(blk, dtype=jnp.int32)[None, None, :]
-               ).reshape(batch, k * blk)
+               ).reshape(batch, n_blocks * blk)
         inside = ids < n_items
         p = rows_per_line(rank)
         safe = jnp.where(inside, ids, 0)
@@ -379,42 +465,53 @@ def _blocked_topk(query_vecs, tables: ItemTables, k: int, blk: int):
             prod = jnp.where(lane_row == (safe % p)[:, :, None], prod, 0.0)
         scores = prod.sum(axis=-1)
         scores = jnp.where(inside, scores, -jnp.inf)
+    if exclude is not None:
+        with jax.named_scope("topk.exclude"):
+            scores = jnp.where(_excluded(ids, exclude), -jnp.inf, scores)
     with jax.named_scope("topk.select"):
         vals, ixs = _select_k(scores, ids, k)
         return vals[:n_queries], ixs[:n_queries]
 
 
-@functools.partial(_Counted, path_of=topk_path)
+@functools.partial(_Counted, path_of=_counted_path)
 @xray.instrument("topk.batch_topk_scores_t")
 @functools.partial(jax.jit, static_argnames=("k",))
 def batch_topk_scores_t(query_vecs: jax.Array,
                         table_t: jax.Array | ItemTables, k: int,
-                        mask: jax.Array | None = None):
+                        mask: jax.Array | None = None,
+                        exclude: jax.Array | None = None):
     """[B, R] x [R, M] (PRE-TRANSPOSED table) -> top-k per row:
     ``([B, k] float32 descending, [B, k] int32 item ids)``.
 
     The layout is the one the MXU streams: the scan reads ``[R, TM]``
     tiles of the table as the matmul's right operand with the items on
-    the lanes.  Unmasked over a long catalogue (:func:`block_items`) the
-    call takes the blocked path: the table is read once, each block's
-    best score is kept, and the k chosen blocks' items are scored again at
-    the scan's precision; exact, because the true top-k can only lie in
-    the k blocks with the largest maxima.  With a mask (additive,
-    ``[B, M]``), a short catalogue or a large k it is the dense
-    ``query_vecs @ table_t`` + ``lax.top_k``.  Serving keeps the
-    transposed device copy (``DeviceTableMixin.device_item_factors_t``),
-    so the hot path pays the transpose once per model advance."""
-    if mask is None and isinstance(table_t, ItemTables):
-        blk = block_items(query_vecs.shape[0], *table_t.shape[::-1], k,
-                          table_t.packed.dtype.itemsize)
-        if blk:
-            return _blocked_topk(query_vecs, table_t, k, blk)
+    the lanes.  ``exclude`` (``[B, E]`` int32 item ids, -1 for none)
+    takes a row's listed items out of its answer.  Without a mask, over
+    a long catalogue (:func:`block_items`) the call takes the blocked
+    path: the table is read once, each block's best score is kept, and
+    the ``k + E`` chosen blocks' items are scored again at the scan's
+    precision and the excluded ones dropped; exact (:func:`_blocked_topk`).
+    With a mask (additive, ``[B, M]``), a short catalogue or a large k it
+    is the dense ``query_vecs @ table_t`` + ``lax.top_k``, the excluded
+    ids scattered into the scores.  Serving keeps the transposed device
+    copy (``DeviceTableMixin.device_item_factors_t``), so the hot path
+    pays the transpose once per model advance."""
+    blk = _blocked_items(query_vecs, table_t, k, mask, exclude)
+    if blk:   # from shapes alone  # piolint: disable=PIO104
+        return _blocked_topk(query_vecs, table_t, k, blk, exclude)
     if isinstance(table_t, ItemTables):
-        table_t = table_t.t
+        table_t = table_t.packed.T if table_t.t is None else table_t.t
     with jax.named_scope("topk.scores"):
         scores = query_vecs @ table_t
         if mask is not None:
             scores = scores + mask
+    if exclude is not None:
+        with jax.named_scope("topk.exclude"):
+            # -1 becomes an index past the row, which the scatter drops
+            rows = jnp.arange(scores.shape[0], dtype=jnp.int32)[:, None]
+            scores = scores.at[
+                rows, jnp.where(exclude < 0, scores.shape[1], exclude)
+            ].set(-jnp.inf, mode="drop")
     with jax.named_scope("topk.select"):
         return jax.lax.top_k(scores, k)
 

@@ -2,12 +2,39 @@
 
 from __future__ import annotations
 
-from typing import Optional
+import time
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
-__all__ = ["DeviceTableMixin", "filter_bias_mask", "normalize_rows",
-           "pow2_ladder", "warm_batched_topk"]
+from ..obs import get_registry, log_buckets
+from ..obs.timeline import annotate
+
+__all__ = ["BatchFilter", "DeviceTableMixin", "RowFilter", "batch_filter",
+           "filter_bias_mask", "normalize_rows", "pow2_ladder",
+           "warm_batched_topk"]
+
+_registry = get_registry()
+FILTER_ROWS = _registry.counter(
+    "pio_filter_rows_total",
+    "Rows of the batches the templates' batch_predict dispatched, by the "
+    "form their batch's filters took: none, ids (excluded item ids "
+    "applied on the device) or mask (a [B, M] additive mask built on "
+    "the host: categories, a whiteList, or a list past the ids' width)",
+    labels=("filter",),
+)
+FILTER_EXCLUDED_IDS = _registry.histogram(
+    "pio_filter_excluded_ids",
+    "Excluded item ids of one query (its seed items and blackList, as "
+    "resolved through the model's id map), in batches filtered by ids",
+    buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128),
+).child()
+FILTER_BUILD_SECONDS = _registry.histogram(
+    "pio_filter_build_seconds",
+    "Host time of one batch's `pio.filter.build` span: its queries' "
+    "filters resolved into the ids array or the mask",
+    buckets=log_buckets(1e-6, 10.0, per_decade=4),
+).child()
 
 
 def normalize_rows(table: np.ndarray) -> np.ndarray:
@@ -155,11 +182,17 @@ class DeviceTableMixin:
         scan and the packed rows (``ops.topk.pack_rows``, cached per
         dtype beside the tables) for rescoring the chosen blocks.  At a
         rank whose rows pack into no line the scorer has no blocked
-        path, and gets the transposed table alone: no third copy."""
+        path, and gets the transposed table alone: no third copy.  At a
+        rank of whole lines (128) it gets the row-major table alone."""
         from ..ops.topk import ItemTables, pack_rows, rows_per_line
 
+        p = rows_per_line(np.shape(self.item_factors)[1])
+        if p == 1:
+            # a row is whole lines: the row-major table is its own packed
+            # form and the scan reads it too (ItemTables): ONE copy
+            return ItemTables(None, self.device_item_factors(dtype))
         table_t = self.device_item_factors_t(dtype)
-        if not rows_per_line(table_t.shape[0]):
+        if not p:
             return table_t
         key = f"_dev_item_packed_{dtype or 'native'}"
         packed = getattr(self, key, None)
@@ -262,6 +295,90 @@ def filter_bias_mask(
     return np.where(allowed, 0.0, -np.inf).astype(np.float32)
 
 
+class RowFilter(NamedTuple):
+    """One query's filters, as the templates read them off the query:
+    the wire's lists of item ids, and `exclude_ix`, item indices the
+    engine itself takes out (a similar-items query's own seeds)."""
+
+    categories: Sequence[str] = ()
+    whitelist: Sequence[str] = ()
+    blacklist: Sequence[str] = ()
+    exclude_ix: Sequence[int] = ()
+
+
+class BatchFilter(NamedTuple):
+    """A batch's filters in the form the scorer takes
+    (``ops.topk.batch_topk_scores_t``): `kind` ``"none"``, ``"ids"``
+    (`exclude`: ``[B, E]`` int32 item indices, -1 for none) or ``"mask"``
+    (`mask`: ``[B, M]`` float32, additive)."""
+
+    kind: str
+    exclude: Optional[np.ndarray] = None
+    mask: Optional[np.ndarray] = None
+
+    def scorer_kwargs(self) -> dict:
+        """The scorer's keyword arguments: `mask` as its callers have
+        always passed it, `exclude` only where there are ids (a stand-in
+        for the scorer written before it took ids keeps working)."""
+        if self.exclude is None:
+            return {"mask": self.mask}
+        return {"mask": self.mask, "exclude": self.exclude}
+
+
+def batch_filter(items, item_props: Optional[dict],
+                 rows: Sequence[Optional[RowFilter]]) -> BatchFilter:
+    """Filters as data.  Each row's excluded items (its `exclude_ix` and
+    the `blacklist` ids the model knows, a hash lookup an id) go into one
+    ``[B, E]`` array for the device, E from ``ops.topk.EXCLUDE_LADDER``;
+    no array of the catalogue's length is built.  Only a batch that holds
+    a row with `categories` or a `whitelist`, or more excluded ids than
+    the ladder's last rung, takes the ``[B, M]`` mask
+    (:func:`filter_bias_mask` a row).  A row that is None (a query that
+    will not be answered) filters nothing."""
+    from ..ops.topk import exclude_width
+
+    t0 = time.perf_counter()
+    with annotate("pio.filter.build"):
+        lists, by_ids = [], True
+        for row in rows:
+            if row is None:
+                lists.append(())
+                continue
+            if row.categories or row.whitelist:
+                by_ids = False
+                break
+            found = (items.get(item_id) for item_id in row.blacklist or ())
+            lists.append(tuple(dict.fromkeys(
+                [*row.exclude_ix, *(ix for ix in found if ix >= 0)])))
+        longest = max(map(len, lists), default=0)
+        width = exclude_width(longest) if by_ids else 0
+        if by_ids and not longest:
+            out = BatchFilter("none")
+        elif width:
+            exclude = np.full((len(rows), width), -1, np.int32)
+            for bi, ex in enumerate(lists):
+                exclude[bi, :len(ex)] = ex
+            out = BatchFilter("ids", exclude=exclude)
+        else:
+            mask = np.zeros((len(rows), len(items)), np.float32)
+            for bi, row in enumerate(rows):
+                if row is not None:
+                    bias = filter_bias_mask(
+                        items, item_props, categories=row.categories,
+                        whitelist=row.whitelist,
+                        blacklist=row.blacklist or (),
+                        exclude_ix=row.exclude_ix, none_if_empty=True)
+                    if bias is not None:
+                        mask[bi] = bias
+            out = BatchFilter("mask", mask=mask)
+    FILTER_BUILD_SECONDS.observe(time.perf_counter() - t0)
+    FILTER_ROWS.labels(filter=out.kind).inc(len(rows))
+    if out.kind == "ids":
+        for ex in lists:
+            FILTER_EXCLUDED_IDS.observe(len(ex))
+    return out
+
+
 def pow2_ladder(max_batch: int) -> list[int]:
     """Every batch size the micro-batcher's pow2 padding can dispatch
     for a given ``max_batch`` — including the pow2 CEILING of a
@@ -277,42 +394,56 @@ def pow2_ladder(max_batch: int) -> list[int]:
 def warm_batched_topk(table, rank: int, n: int,
                       unmasked_too: bool = False,
                       max_batch: int = 64,
-                      table_t=None) -> None:
+                      table_t=None,
+                      solo_too: bool = False) -> None:
     """Pre-compile the pow2 batched top-k shapes the serving
     micro-batcher dispatches (server/microbatch.py pads batches to
     powers of two; templates round k to pow2): EVERY B in
-    ``pow2_ladder(max_batch)`` at the pow2-rounded default num, plus
-    the small-k shapes at B=1.  Every pow2 rung, not a subset — a size
-    the padding can produce but the warmup skipped compiles on first
-    exposure mid-traffic, which is exactly the p99 spike the padding
-    exists to avoid (ADVICE r4).  ``max_batch <= 0`` (no batcher: the
-    per-query predict path serves everything) skips the batched warms
-    entirely — they would compile executables nothing dispatches."""
-    from ..ops.topk import batch_topk_scores, batch_topk_scores_t, pow2_ceil
+    ``pow2_ladder(max_batch)`` at the pow2-rounded default num.  Every
+    pow2 rung, not a subset — a size the padding can produce but the
+    warmup skipped compiles on first exposure mid-traffic, which is
+    exactly the p99 spike the padding exists to avoid (ADVICE r4).
+    ``max_batch <= 0`` (no batcher: the per-query predict path serves
+    everything) skips the batched warms entirely — they would compile
+    executables nothing dispatches.
+
+    With `table_t` (what the caller's batch path hands
+    ``ops.topk.batch_topk_scores_t``: its ``device_item_tables``) the
+    filtered rungs carry excluded ids, at every width of
+    ``ops.topk.EXCLUDE_LADDER``; each rung compiles the path, blocked or
+    dense, that its shapes will take under traffic.  `solo_too` adds the
+    one-row rungs (the default num and the small k's) for an engine whose
+    lone request is a one-row batch (similarproduct); where a lone
+    request rides ``predict`` (recommendation: the batcher's `batch_fn`
+    sends a batch of one there) nothing dispatches them, and each is an
+    executable more to load before the server is ready.  The ``[B, M]`` masked form of that scorer (`categories`,
+    a `whiteList`) is not warmed: its rungs each shipped a ``[B, M]``
+    array of zeros, 2.4 GB at 64 rows over 9.4 M items, and set the
+    server's peak memory.  Without `table_t` it is the classic
+    ``[M, R]`` scorer under a ``[B, M]`` mask, for the templates whose
+    every batch is still masked (itemsimilarity, ecommerce)."""
+    from ..ops.topk import (
+        EXCLUDE_LADDER, batch_topk_scores, batch_topk_scores_t, pow2_ceil,
+    )
 
     ladder = pow2_ladder(max_batch)
     if not ladder:
         return
-
-    def warm(vecs, k, mask=None):
-        # warm the scorer the caller's batch path actually dispatches:
-        # the transposed [R, M] one when a transposed table is given
-        # (recommendation: its `device_item_tables`, so every rung
-        # compiles the path, blocked or dense, that its shapes will
-        # take under traffic), the classic [M, R] one otherwise
-        if table_t is not None:
-            batch_topk_scores_t(vecs, table_t, k, mask=mask)
-        else:
-            batch_topk_scores(vecs, table, k, mask=mask)
-
     k_default = min(pow2_ceil(10), n)
-    for b in ladder:
+    if table_t is None:
+        for b in ladder:
+            batch_topk_scores(np.zeros((b, rank), np.float32), table,
+                              k_default, mask=np.zeros((b, n), np.float32))
+        return
+    shapes = [(b, k_default) for b in ladder if b > 1 or solo_too]
+    if solo_too:
+        shapes += [(1, k) for k in {min(pow2_ceil(k), n) for k in (1, 4)}]
+    for b, k in shapes:
         vecs = np.zeros((b, rank), np.float32)
-        warm(vecs, k_default, mask=np.zeros((b, n), np.float32))
-        if unmasked_too:
-            warm(vecs, k_default)
-    for k in {min(pow2_ceil(k), n) for k in (1, 4)}:
-        vecs = np.zeros((1, rank), np.float32)
-        warm(vecs, k, mask=np.zeros((1, n), np.float32))
-        if unmasked_too:
-            warm(vecs, k)
+        # the keyword arguments as `batch_predict` passes them: they are
+        # part of the compiled call's key
+        filters = [BatchFilter("none")] if unmasked_too else []
+        filters += [BatchFilter("ids", np.full((b, width), -1, np.int32))
+                    for width in EXCLUDE_LADDER]
+        for flt in filters:
+            batch_topk_scores_t(vecs, table_t, k, **flt.scorer_kwargs())

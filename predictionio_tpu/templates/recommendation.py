@@ -44,6 +44,8 @@ from ..ops.topk import (
 from ..storage.columnar import Ratings
 from ._common import (
     DeviceTableMixin,
+    RowFilter,
+    batch_filter,
     filter_bias_mask,
     pow2_ladder,
     warm_batched_topk,
@@ -694,23 +696,23 @@ class ALSAlgorithm(Algorithm):
             n_items = len(model.items)
             k = min(pow2_ceil(int(nums[valid].max())), n_items)
             uvecs = model.user_factors[np.where(valid, uix, 0)]
-            masks = [
-                self._allowed_mask(model, q) if v else None
-                for q, v in zip(queries, valid)
-            ]
-            if any(m is not None for m in masks):
-                zero = np.zeros(n_items, dtype=np.float32)
-                mask = np.stack([zero if m is None else m for m in masks])
-            else:
-                mask = None
+            # filters as data: a blackList rides as item ids and is
+            # applied on the device; only `categories` or a `whiteList`
+            # make the batch's [B, M] mask (_common.batch_filter)
+            flt = batch_filter(model.items, model.item_props, [
+                RowFilter(q.categories, q.whitelist, q.blacklist)
+                if v and (q.categories or q.whitelist or q.blacklist)
+                else None for q, v in zip(queries, valid)
+            ])
+            unfiltered = flt.kind == "none"
             rcfg = self._retrieval_config()
-        if mask is None and getattr(self.params, "distributed_topk",
-                                    False):
+        if unfiltered and getattr(self.params, "distributed_topk",
+                                  False):
             # the micro-batched serving path rides the same parity-coded
             # ring as solo predict (the ring takes a [B, R] query block
             # natively); per-query masks keep the local scorer below
             vals, ixs = self._sharded_index(model)(uvecs, k)
-        elif mask is None and rcfg is not None:
+        elif unfiltered and rcfg is not None:
             # pio-scout two-stage: the batched serving path is exactly
             # where the candidate stage pays — per-batch device work
             # drops from O(M*R) f32 to a quantized shortlist scan +
@@ -719,14 +721,17 @@ class ALSAlgorithm(Algorithm):
                 uvecs, k, model.device_item_factors(self._serve_dtype())
             )
         else:
-            # the pre-transposed [R, M] table with the packed rows: an
-            # unmasked batch over a long catalogue is scored in blocks of
-            # the item axis (one read of the table, no [B, M] matrix;
-            # exact), anything else as one matmul + top-k (ops/topk.py)
+            # the pre-transposed [R, M] table with the packed rows: a
+            # batch over a long catalogue, with or without excluded ids,
+            # is scored in blocks of the item axis (one read of the
+            # table, no [B, M] matrix; exact), anything else as one
+            # matmul + top-k (ops/topk.py)
             tables = model.device_item_tables(self._serve_dtype())
-            with annotate("pio.turn.dispatch",
-                          path=topk_path(uvecs, tables, k, mask)):
-                vals, ixs = batch_topk_scores_t(uvecs, tables, k, mask=mask)
+            with annotate("pio.turn.dispatch", filter=flt.kind,
+                          path=topk_path(uvecs, tables, k, flt.mask,
+                                         flt.exclude)):
+                vals, ixs = batch_topk_scores_t(
+                    uvecs, tables, k, **flt.scorer_kwargs())
         with annotate("pio.turn.fetch"):
             vals, ixs = jax.device_get((vals, ixs))
         with annotate("pio.turn.decode"):

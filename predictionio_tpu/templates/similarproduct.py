@@ -16,6 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Optional
 
+import jax
 import numpy as np
 
 from ..controller import (
@@ -29,15 +30,15 @@ from ..controller import (
     WorkflowContext,
 )
 from ..models.als import ALSConfig, train_als
-from ..ops.topk import batch_topk_scores, pow2_ceil, topk_scores
+from ..obs.timeline import annotate
+from ..ops.topk import batch_topk_scores_t, pow2_ceil, topk_path
 
-from ._common import DeviceTableMixin, filter_bias_mask, \
+from ._common import DeviceTableMixin, RowFilter, batch_filter, \
     normalize_rows, warm_batched_topk
 from .recommendation import (
     PredictedResult,
     _resolve_app_id,
     decode_batch_item_scores,
-    decode_item_scores,
 )
 
 
@@ -278,88 +279,92 @@ class SimilarProductAlgorithm(Algorithm):
 
     # -- serving -----------------------------------------------------------
     def warmup(self, model: SimilarALSModel, max_batch: int = 64) -> None:
-        """Pre-compile the cosine top-k scorer for the common ``num``
-        values — single-query AND the pow2 batched shapes the serving
-        micro-batcher dispatches.  The table is train-time normalized,
-        so the plain device table serves cosine directly."""
+        """Pre-compile the cosine top-k scorer for the pow2 batched
+        shapes the serving micro-batcher dispatches and the small-k
+        one-row shapes of a lone request, each with excluded ids (every
+        query excludes its own seeds).  The table is train-time
+        normalized, so the plain device tables serve cosine directly."""
         n = len(model.items)
         if n == 0:
             return
-        tn = model.device_item_factors()
-        rank = model.item_factors.shape[1]
-        vec = np.zeros(rank, np.float32)
-        bias = np.zeros(n, np.float32)
-        for k in {min(k, n) for k in (1, 4, 10, 20)}:
-            topk_scores(vec, tn, k, bias=bias)
-        warm_batched_topk(tn, rank, n, max_batch=max_batch)
-
-    def _query_vec_and_mask(self, model: SimilarALSModel, query: Query):
-        """Per-query host work shared by predict/batch_predict: mean of
-        the known query-item rows (already unit-norm — the mean of
-        normalized rows is itemsimilarity's query semantics, which this
-        template now shares) re-normalized, + the filter mask.
-        Returns (None, None) for unanswerable queries."""
-        known = [model.items.get(i) for i in query.items]
-        known = [i for i in known if i >= 0]
-        if not known or query.num <= 0:
-            return None, None
-        qvec = model.item_factors[known].mean(axis=0)
-        qn = qvec / (np.linalg.norm(qvec) + 1e-9)
-        # exclude the query items themselves plus any filters
-        mask = filter_bias_mask(
-            model.items, model.item_props,
-            categories=query.categories, whitelist=query.whitelist,
-            blacklist=query.blacklist or (), exclude_ix=known,
+        warm_batched_topk(
+            None, model.item_factors.shape[1], n, max_batch=max_batch,
+            table_t=model.device_item_tables(), solo_too=True,
         )
-        return np.asarray(qn, np.float32), mask
+
+    @staticmethod
+    def _query_vecs(model: SimilarALSModel, known: list) -> np.ndarray:
+        """``[B, R]``: per query the mean of its known seed items' rows
+        (already unit-norm — the mean of normalized rows is
+        itemsimilarity's query semantics, which this template shares)
+        re-normalized; a zero row for a query with no known seed.  ONE
+        gather from the host table for the whole batch."""
+        flat = np.fromiter((ix for ixs in known for ix in ixs), np.int64)
+        rows = np.asarray(model.item_factors[flat], np.float32)
+        qvecs = np.zeros((len(known), rows.shape[1]), np.float32)
+        lo = 0
+        for bi, ixs in enumerate(known):
+            if ixs:
+                qvec = rows[lo:lo + len(ixs)].mean(axis=0)
+                qvecs[bi] = qvec / (np.linalg.norm(qvec) + 1e-9)
+                lo += len(ixs)
+        return qvecs
 
     def predict(self, model: SimilarALSModel, query: Query) -> PredictedResult:
-        qn, mask = self._query_vec_and_mask(model, query)
-        if qn is None:
-            return PredictedResult(item_scores=())
-        k = min(query.num, len(model.items))
-        # cosine: both sides normalized — the table at train time, the
-        # query vector per request
-        tn = model.device_item_factors()
-        vals, ixs = topk_scores(qn, tn, k, bias=mask)
-        return PredictedResult(
-            item_scores=decode_item_scores(model.items, vals, ixs)
-        )
+        """A lone request is a one-row batch: the same device program,
+        the same filters as data."""
+        return self.batch_predict(model, [query])[0]
 
     def batch_predict(self, model: SimilarALSModel, queries):
-        """Eval + micro-batched serving path: one batched cosine matmul
+        """Eval + micro-batched serving path: one batched cosine scoring
         for the whole query set.  Same shape-stability contract as the
         recommendation template: the device batch stays len(queries)
         (unanswerable queries score a zero vector, discarded on host)
-        and k rounds up to pow2, bounding the XLA executable key space."""
+        and k rounds up to pow2, bounding the XLA executable key space.
+
+        A query's own seed items and its `blackList` travel to the device
+        as item ids (``_common.batch_filter``): no array of the
+        catalogue's length is built for them.  Only `categories` and a
+        `whiteList` still make the batch's ``[B, M]`` mask."""
         out = [PredictedResult(item_scores=()) for _ in queries]
         n = len(model.items)
         if n == 0 or not queries:
             return out
-        rank = model.item_factors.shape[1]
-        qvecs = np.zeros((len(queries), rank), np.float32)
-        masks = np.zeros((len(queries), n), np.float32)
-        valid = np.zeros(len(queries), bool)
-        for bi, q in enumerate(queries):
-            qn, mask = self._query_vec_and_mask(model, q)
-            if qn is None:
-                continue
-            valid[bi] = True
-            qvecs[bi] = qn
-            masks[bi] = mask
-        if not valid.any():
-            return out
-        k = min(
-            pow2_ceil(max(q.num for q, v in zip(queries, valid) if v)), n
-        )
-        tn = model.device_item_factors()
-        vals, ixs = batch_topk_scores(qvecs, tn, k, mask=masks)
-        decoded = decode_batch_item_scores(
-            model.items, vals, ixs, [q.num for q in queries], valid, k
-        )
-        return [
-            PredictedResult(item_scores=scores) for scores in decoded
-        ]
+        with annotate("pio.turn.prepare"):
+            known = [
+                [ix for ix in map(model.items.get, q.items) if ix >= 0]
+                if q.num > 0 else [] for q in queries
+            ]
+            valid = np.array([bool(ixs) for ixs in known])
+            if not valid.any():
+                return out
+            # cosine: both sides normalized — the table at train time,
+            # the query vector per request
+            qvecs = self._query_vecs(model, known)
+            k = min(
+                pow2_ceil(max(q.num for q, v in zip(queries, valid) if v)),
+                n,
+            )
+            # exclude the query items themselves plus any filters
+            flt = batch_filter(model.items, model.item_props, [
+                RowFilter(q.categories, q.whitelist, q.blacklist, ixs)
+                if ixs else None for q, ixs in zip(queries, known)
+            ])
+            tables = model.device_item_tables()
+        with annotate("pio.turn.dispatch", filter=flt.kind,
+                      path=topk_path(qvecs, tables, k, flt.mask,
+                                     flt.exclude)):
+            vals, ixs = batch_topk_scores_t(
+                qvecs, tables, k, **flt.scorer_kwargs())
+        with annotate("pio.turn.fetch"):
+            vals, ixs = jax.device_get((vals, ixs))
+        with annotate("pio.turn.decode"):
+            decoded = decode_batch_item_scores(
+                model.items, vals, ixs, [q.num for q in queries], valid, k
+            )
+            return [
+                PredictedResult(item_scores=scores) for scores in decoded
+            ]
 
 
 def similarproduct_engine() -> Engine:

@@ -408,11 +408,11 @@ def test_similarproduct_batch_predict_matches_single(similar_ctx):
     model = models[0]
 
     shapes = []
-    real = smod.batch_topk_scores
+    real = smod.batch_topk_scores_t
 
-    def spy(vecs, table, k, mask=None):
+    def spy(vecs, tables, k, mask=None, exclude=None):
         shapes.append((vecs.shape[0], k))
-        return real(vecs, table, k, mask=mask)
+        return real(vecs, tables, k, mask=mask, exclude=exclude)
 
     import unittest.mock as mock
 
@@ -423,7 +423,7 @@ def test_similarproduct_batch_predict_matches_single(similar_ctx):
         smod.Query(items=("i2",), num=3, categories=("even",)),
         smod.Query(items=("i4",), num=0),            # unanswerable
     ]
-    with mock.patch.object(smod, "batch_topk_scores", spy):
+    with mock.patch.object(smod, "batch_topk_scores_t", spy):
         batch = algo.batch_predict(model, queries)
     assert shapes == [(5, 8)]  # full batch; k=5 -> pow2 8
     assert batch[1].item_scores == () and batch[4].item_scores == ()

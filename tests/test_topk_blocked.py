@@ -283,7 +283,10 @@ def test_packed_rows_follow_a_patched_model(m, r):
         if not p:   # no packed form, no third copy: the table alone
             np.testing.assert_array_equal(np.asarray(tables), host.T)
             return
-        np.testing.assert_array_equal(np.asarray(tables.t), host.T)
+        if p == 1:   # whole lines: the row-major table alone, scanned too
+            assert tables.t is None
+        else:
+            np.testing.assert_array_equal(np.asarray(tables.t), host.T)
         packed = np.asarray(tables.packed)
         assert packed.shape == (-(-len(host) // p), p * r)
         np.testing.assert_array_equal(
@@ -301,15 +304,17 @@ def test_packed_rows_follow_a_patched_model(m, r):
     check(host)
 
 
-@pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
-def test_batch_predict_takes_the_path_its_batch_allows(masked):
+@pytest.mark.parametrize("filtered", ["unmasked", "blacklist", "whitelist"])
+def test_batch_predict_takes_the_path_its_batch_allows(filtered):
     """`ALSAlgorithm.batch_predict` over a catalogue long enough for the
     blocked path: the same answers as `predict`, the path on the counter
-    and on the `pio.turn.dispatch` annotation."""
+    and, with the filter's form, on the `pio.turn.dispatch` annotation.
+    A blackList rides as ids on the blocked path; a whiteList still
+    takes the `[B, M]` mask and the dense form."""
     from predictionio_tpu.storage.bimap import StringIndex
     from predictionio_tpu.templates import recommendation as rmod
 
-    m, r = 20_000, 16
+    m, r = 40_000, 16
     rng = np.random.default_rng(3)
     model = rmod.ALSModel(
         user_factors=(rng.normal(size=(5, r)) / 4).astype(np.float32),
@@ -321,9 +326,14 @@ def test_batch_predict_takes_the_path_its_batch_allows(masked):
     algo = rmod.ALSAlgorithm()
     algo.params = rmod.ALSAlgorithmParams(rank=r)
     queries = [rmod.Query(user=f"u{i}", num=10) for i in range(4)]
-    if masked:
+    if filtered == "blacklist":
+        # the user's own best items, so that the list changes the answer
+        best = [s.item for s in algo.predict(model, queries[1]).item_scores]
         queries[1] = rmod.Query(user="u1", num=10,
-                                blacklist=("i7",))
+                                blacklist=(best[0], best[3], "unknown"))
+    if filtered == "whitelist":
+        queries[1] = rmod.Query(user="u1", num=10, whitelist=tuple(
+            f"i{i}" for i in range(0, m, 7)))
     seen = []
     real = rmod.annotate
 
@@ -331,13 +341,17 @@ def test_batch_predict_takes_the_path_its_batch_allows(masked):
         seen.append((name, meta))
         return real(name, **meta)
 
-    want = "dense" if masked else "blocked"
-    before = topk.TOPK_PATH.labels(path=want).value()
+    path, counted, kind = {
+        "unmasked": ("blocked", "blocked", "none"),
+        "blacklist": ("blocked", "blocked_ids", "ids"),
+        "whitelist": ("dense", "dense", "mask"),
+    }[filtered]
+    before = topk.TOPK_PATH.labels(path=counted).value()
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(rmod, "annotate", spy)
         got = algo.batch_predict(model, queries)
-    assert topk.TOPK_PATH.labels(path=want).value() == before + 1
-    assert ("pio.turn.dispatch", {"path": want}) in seen
+    assert topk.TOPK_PATH.labels(path=counted).value() == before + 1
+    assert ("pio.turn.dispatch", {"path": path, "filter": kind}) in seen
     for query, result in zip(queries, got):
         solo = algo.predict(model, query)
         assert [s.item for s in result.item_scores] == \

@@ -404,14 +404,13 @@ def _run_phase_probe(jax, trainer, U, V, cfg, emit) -> None:
     side = trainer._user_side
 
     @functools.partial(jax.jit, static_argnames=("ks", "stop_after"))
-    def probe(upd_tab, opp, c_sorted, v_sorted, buckets, lam, alpha, *,
-              ks, stop_after):
+    def probe(upd_tab, opp, buckets, lam, alpha, *, ks, stop_after):
         # upd_tab: the current factor table — subspace mode's "gram"
         # probe warm-starts its block sweep from it, so the measured
         # Gram phase includes the residual/prediction cache builds the
         # real sweep pays
         return _solve_buckets(
-            None, opp, c_sorted, v_sorted, buckets, lam, alpha,
+            None, opp, buckets, lam, alpha,
             ks=ks, implicit=cfg.implicit,
             weighted_lambda=cfg.weighted_lambda,
             precision=cfg.matmul_precision, solver=cfg.solver,
@@ -436,8 +435,7 @@ def _run_phase_probe(jax, trainer, U, V, cfg, emit) -> None:
         emit(
             f"user_half_probe_{stop}",
             timed(lambda: probe(
-                U, V, side["c_sorted"], side["v_sorted"],
-                side["buckets"], lam, alpha, ks=side["ks"],
+                U, V, side["buckets"], lam, alpha, ks=side["ks"],
                 stop_after=stop,
             )),
             **(
@@ -532,11 +530,11 @@ def run_fused_ab(args) -> None:
         if arm == "unfused":
 
             @functools.partial(jax.jit, static_argnames=("ks", "stop_after"))
-            def probe(upd_tab, opp, c_sorted, v_sorted, buckets, lam_t,
-                      alpha_t, *, ks, stop_after):
+            def probe(upd_tab, opp, buckets, lam_t, alpha_t, *, ks,
+                      stop_after):
                 return _solve_buckets(
-                    None, opp, c_sorted, v_sorted, buckets, lam_t,
-                    alpha_t, ks=ks, implicit=cfg.implicit,
+                    None, opp, buckets, lam_t, alpha_t, ks=ks,
+                    implicit=cfg.implicit,
                     weighted_lambda=cfg.weighted_lambda,
                     precision=cfg.matmul_precision, solver=cfg.solver,
                     gather_dtype=cfg.gather_dtype,
@@ -547,8 +545,7 @@ def run_fused_ab(args) -> None:
                 )
 
             dt = timed(lambda: probe(
-                U, V, side["c_sorted"], side["v_sorted"],
-                side["buckets"], lam, alpha, ks=side["ks"],
+                U, V, side["buckets"], lam, alpha, ks=side["ks"],
                 stop_after="gram",
             ))
             results[arm] = dt

@@ -33,7 +33,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from ..models.als import ALSConfig, _solve_buckets
+from ..models.als import ALSConfig, _solve_buckets, _valid_slots
 from ..obs import xray
 from ..ops.topk import pow2_ceil
 from .watermark import ScanBatch
@@ -69,18 +69,17 @@ def _jit_foldin():
     )
     def _foldin_solve(opp, ids, vals, counts, lam, alpha, *, k, implicit,
                       weighted_lambda, precision, solver):
-        b = ids.shape[0]
-        starts = jnp.arange(b, dtype=jnp.int32) * k
-        rows = jnp.arange(b, dtype=jnp.int32)
+        rows = jnp.arange(ids.shape[0], dtype=jnp.int32)
+        valid = _valid_slots(counts, k)
         # one fixed bucket through the SAME math as a training
-        # half-iteration; the write callback returns the solved [B, R]
-        # block instead of scattering into a donated table
+        # half-iteration (padding slots 0 / 0.0, as its staging lays
+        # them out); the write callback returns the solved [B, R] block
+        # instead of scattering into a donated table
         return _solve_buckets(
             lambda acc, r, x: x,
             opp,
-            ids.reshape(-1),
-            vals.reshape(-1),
-            ((rows, starts, counts),),
+            ((rows, jnp.where(valid, ids, 0), jnp.where(valid, vals, 0.0),
+              counts),),
             lam,
             alpha,
             ks=(k,),

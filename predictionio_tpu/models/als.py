@@ -12,18 +12,19 @@ HBM-resident and each half-iteration is ONE XLA computation:
   (ALX-style, arXiv 2112.02194): rows are keyed by next-pow2(rating count),
   so the device sees only static shapes.  Only the time-sorted COO arrays
   and tiny per-bucket ``(rows, starts, counts)`` vectors are transferred;
-  the padded ``[B, K]`` rating blocks are expanded **on device** inside the
-  compiled program (a gather from the sorted COO), which cuts host->HBM
-  traffic ~3x and keeps the expansion fused with the solves.
-* Per bucket, inside the same program: gather opposite factors
+  the padded ``[B, K]`` rating blocks are expanded **on device**, once,
+  by a staging program (``_expand_side``: a gather from the sorted COO),
+  which cuts host->HBM traffic ~3x; the blocks stay on the device and
+  the sorted columns are dropped.
+* Per bucket, inside one program a half: gather opposite factors
   ``[B, K, R]`` -> masked Gram matrices via einsum (MXU) -> batched
   Cholesky solve -> masked scatter into the factor table (OOB rows from
   batch padding are dropped).
 * The whole half-iteration is a single ``jit`` with the factor table
   donated, so a 20-iteration train is 40 dispatches and exactly 2 compiled
   executables (one per direction) regardless of bucket count.
-* Sharding: bucket batch dims are sharded over the mesh's ``data`` axis;
-  factor tables and the COO arrays are replicated, so gathers are local
+* Sharding: bucket batch dims (the padded blocks' too) are sharded over
+  the mesh's ``data`` axis; factor tables are replicated, so gathers are local
   and XLA inserts the collectives for the scatter from the shardings
   (no NCCL/MPI analogue needed).
 
@@ -74,7 +75,8 @@ __all__ = [
 ]
 
 # cap on B*K entries of a single bucket chunk: bounds the [B, K, R]
-# gathered intermediate (~1 GiB at rank 64, f32) regardless of dataset size
+# gathered intermediate (~1 GiB at rank 64, f32) regardless of dataset size,
+# and a staged [B, K] block of ids or of ratings at 16 MiB
 MAX_ENTRIES_PER_BUCKET = 4 << 20
 
 
@@ -282,7 +284,7 @@ class ALSFactors:
 
 # --------------------------------------------------------------------------
 # Host-side preprocessing: COO -> bucket layout (indices only; the padded
-# [B, K] blocks are expanded on device)
+# [B, K] blocks are expanded on device, once, at staging)
 # --------------------------------------------------------------------------
 
 
@@ -521,6 +523,47 @@ def _device_expand_sides(col_by_row, val_by_row, row_counts, val_scale):
     return c_row, v_row, c_opp, v_opp
 
 
+def _valid_slots(counts, k: int):
+    """``[B, K]`` mask of the slots that hold a rating."""
+    return jnp.arange(k, dtype=jnp.int32)[None, :] < counts[:, None]
+
+
+def _expand_bucket(c_sorted, v_sorted, starts, counts, k: int):
+    """One bucket's padded ``[B, K]`` opposite-side ids and ratings from
+    the row-grouped columns: row b's slots are ``c_sorted[starts[b] :
+    starts[b] + counts[b]]``, the padding slots 0 / 0.0.
+
+    Read as B slices of length K, not B*K addresses: the TPU's
+    per-element gather from a one-dimensional array costs 21 ns an
+    element (the Netflix-size user side: 7.0 s against 1.1 s; PERF.md,
+    PR 30).  The columns' tail is padded by K, so that no slice is
+    clamped at the end and shifted.
+    """
+    valid = _valid_slots(counts, k)
+
+    def block(column):
+        padded = jnp.pad(column, (0, k))
+        rows = jax.vmap(
+            lambda start: jax.lax.dynamic_slice(padded, (start,), (k,))
+        )(starts)
+        return jnp.where(valid, rows, 0)
+
+    return block(c_sorted), block(v_sorted)
+
+
+@xray.instrument("als.expand_side")
+@functools.partial(jax.jit, static_argnames=("ks",))
+def _expand_side(c_sorted, v_sorted, starts_counts, *, ks):
+    """Every bucket of one side expanded to its padded block, once, at
+    staging: the half-iterations read the blocks and hold no gather
+    from the ``[nnz]`` columns."""
+    with jax.named_scope("als.positions"):
+        return tuple(
+            _expand_bucket(c_sorted, v_sorted, starts, counts, k)
+            for (starts, counts), k in zip(starts_counts, ks)
+        )
+
+
 # --------------------------------------------------------------------------
 # Device-side: one jitted half-iteration per direction
 # --------------------------------------------------------------------------
@@ -578,9 +621,7 @@ def _spd_solve(A: jax.Array, b: jax.Array, solver: str,
 def _half_iteration_impl(
     upd: jax.Array,        # [N, R] factor table being solved (donated)
     opp: jax.Array,        # [M, R] opposite-side factor table
-    c_sorted: jax.Array,   # [nnz] int32
-    v_sorted: jax.Array,   # [nnz] f32
-    bucket_args: tuple,    # tuple of (rows, starts, counts) per bucket
+    bucket_args: tuple,    # tuple of (rows, idx, val, counts) per bucket
     lam: jax.Array,        # traced scalar: sweeping λ must not recompile
     alpha: jax.Array,      # traced scalar
     *,
@@ -603,7 +644,7 @@ def _half_iteration_impl(
         )
 
     out = _solve_buckets(
-        write, opp, c_sorted, v_sorted, bucket_args, lam, alpha,
+        write, opp, bucket_args, lam, alpha,
         ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
         precision=precision, solver=solver, gather_dtype=gather_dtype,
         gather_mode=gather_mode, solver_mode=solver_mode,
@@ -639,9 +680,9 @@ _half_iteration = xray.instrument("als.half_iteration")(
         "stop_after",
     ),
 )
-def _half_phase_probe(upd, opp, c_sorted, v_sorted, bucket_args, lam,
-                      alpha, *, ks, implicit, weighted_lambda, precision,
-                      solver, gather_dtype="float32", gather_mode="row",
+def _half_phase_probe(upd, opp, bucket_args, lam, alpha, *, ks, implicit,
+                      weighted_lambda, precision, solver,
+                      gather_dtype="float32", gather_mode="row",
                       solver_mode="full", subspace_size=0,
                       stop_after="gather"):
     """Truncated half-iteration for pio-obs phase tracing: the same
@@ -649,7 +690,7 @@ def _half_phase_probe(upd, opp, c_sorted, v_sorted, bucket_args, lam,
     gather+Gram), jitted WITHOUT donation — the real, donating half
     still consumes ``upd`` right after the probes run."""
     return _solve_buckets(
-        None, opp, c_sorted, v_sorted, bucket_args, lam, alpha,
+        None, opp, bucket_args, lam, alpha,
         ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
         precision=precision, solver=solver, gather_dtype=gather_dtype,
         gather_mode=gather_mode, solver_mode=solver_mode,
@@ -672,9 +713,7 @@ def _als_phase_trace_enabled() -> bool:
 def _solve_buckets(
     upd_write,             # callback(rows, x) -> new upd table/shard
     opp: jax.Array,        # [M, R] full opposite table (local or gathered)
-    c_sorted: jax.Array,
-    v_sorted: jax.Array,
-    bucket_args: tuple,
+    bucket_args: tuple,    # (rows, idx, val, counts) per bucket
     lam: jax.Array,
     alpha: jax.Array,
     *,
@@ -744,7 +783,6 @@ def _solve_buckets(
     from inside its own ``shard_map`` body and leaves it None.
     """
     r = opp.shape[-1]
-    nnz = c_sorted.shape[0]
     # B >= R degenerates to the full-solve branch VERBATIM (bitwise-
     # identical compiled program), per the ALSConfig contract
     sub = solver_mode == "subspace" and 0 < subspace_size < r
@@ -787,13 +825,9 @@ def _solve_buckets(
     out = None
     # the als.* scopes name each bucket's steps in the HLO metadata, so a
     # profile finds the kernels by name whatever XLA fuses them into
-    for (rows, starts, counts), k in zip(bucket_args, ks):
+    for (rows, idx, val, counts), k in zip(bucket_args, ks):
         with jax.named_scope("als.positions"):
-            iota = jnp.arange(k, dtype=jnp.int32)
-            pos = jnp.minimum(starts[:, None] + iota[None, :], nnz - 1)
-            valid = iota[None, :] < counts[:, None]          # [B, K]
-            idx = jnp.where(valid, c_sorted[pos], 0)
-            val = jnp.where(valid, v_sorted[pos], 0.0)       # f32, masked
+            valid = _valid_slots(counts, k)
             maskf = valid.astype(f32)
         if fused and fused_tile_plan(r, k) is not None:
             n_row = counts.astype(f32)
@@ -1069,9 +1103,15 @@ def build_sharded_half(
         me = jax.lax.axis_index(axis)
         shard_n = upd.shape[0]
         lo = (me * shard_n).astype(jnp.int32)
+        # the shard-local COO is expanded here, in every half; the
+        # replicated path's staging does it once (_expand_side)
+        triples = [
+            flat_buckets[i : i + 3] for i in range(0, len(flat_buckets), 3)
+        ]
         bucket_args = tuple(
-            tuple(flat_buckets[i : i + 3])
-            for i in range(0, len(flat_buckets), 3)
+            (rows, *_expand_bucket(c_sorted, v_sorted, starts, counts, k),
+             counts)
+            for (rows, starts, counts), k in zip(triples, ks)
         )
         # subspace mode warm-starts each row's block sweep from the
         # CURRENT factor value, but this device solves rows owned by
@@ -1095,7 +1135,7 @@ def build_sharded_half(
             return acc.at[safe].set(xg.astype(acc.dtype), mode="drop")
 
         out = _solve_buckets(
-            write, opp_full, c_sorted, v_sorted, bucket_args, lam, alpha,
+            write, opp_full, bucket_args, lam, alpha,
             ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
             precision=precision, solver=solver,
             gather_dtype=gather_dtype, gather_mode=gather_mode,
@@ -1321,26 +1361,36 @@ class ALSTrainer:
         if self.sharded:
             self._build_sharded_halves()
         self._init_loss(u, i, v)
+        sides = {"user": self._user_side, "item": self._item_side}
+
+        def per_side(key):
+            # 0 under sharded placement, which expands in every half
+            return {name: side.get(key, 0) for name, side in sides.items()}
+
         staged = {
             "solver": cfg.solver,
             "staging": self.staging,
             "placement": "sharded" if self.sharded else "replicated",
             "devices": n_dev,
             "devicesWithData": self.data_devices(),
+            "paddedEntries": per_side("padded_entries"),
+            "paddedBytes": per_side("padded_bytes"),
+            "expandSeconds": per_side("expand_s"),
         }
         logger.info("ALS staged: %s", staged)
         tower.note_event("als_staged", **staged)
 
     def data_devices(self) -> int:
-        """How many devices hold staged training data (the rating COO
-        and the per-bucket index vectors).  What a multi-chip bring-up
-        checks: code that put everything on device 0 reports 1."""
+        """How many devices hold staged training data: the per-bucket
+        rows, counts and padded blocks (sharded placement: the starts,
+        whose COO shards lie on the same devices).  What a multi-chip
+        bring-up checks: code that put everything on device 0 reports
+        1."""
         devices: set = set()
         for side in (self._user_side, self._item_side):
-            arrays = [side["c_sorted"], side["v_sorted"]]
-            arrays += [a for bucket in side["buckets"] for a in bucket]
-            for a in arrays:
-                devices.update(a.devices())
+            for bucket in side["buckets"]:
+                for a in bucket:
+                    devices.update(a.devices())
         return len(devices)
 
     # per-sweep loss sample cap: the watchdog needs a *trajectory*, not
@@ -1646,6 +1696,9 @@ class ALSTrainer:
         device the user side's grouping IS the transfer order (zero
         work), user ids are reconstructed from the per-row counts
         (``repeat``), and the item side is one argsort + gathers.
+        Each side's columns then go through :meth:`_stage_side`, which
+        expands them to the padded blocks the sweeps read and drops
+        them.
 
         The TPU lesson generalizes: host↔device bytes are the scarce
         resource (PCIe, or worse a DCN hop), device sort is cheap
@@ -1728,10 +1781,13 @@ class ALSTrainer:
         cs_u, vs_u, cs_i, vs_i = _device_expand_sides(
             i_dev, v_dev, counts_dev, scale
         )
-        return (
-            self._stage_side(cs_u, vs_u, buckets_u),
-            self._stage_side(cs_i, vs_i, buckets_i),
-        )
+        # each array goes as soon as its last reader has run: the blocks
+        # (8 bytes a padded entry) and both sides' columns together
+        # would be this path's peak
+        del i_dev, v_dev
+        user_side = self._stage_side(cs_u, vs_u, buckets_u)
+        del cs_u, vs_u
+        return user_side, self._stage_side(cs_i, vs_i, buckets_i)
 
     def _stage(self, layout: BucketLayout):
         """Transfer the sorted COO + bucket index vectors to the device."""
@@ -1740,7 +1796,9 @@ class ALSTrainer:
         )
 
     def _stage_side(self, c_sorted, v_sorted, buckets):
-        """Place one side's arrays; accepts host or already-device arrays."""
+        """Place one side's arrays (host or already on the device) and
+        expand every bucket to its padded block, once: the columns are
+        not read again and are dropped here."""
         if self.mesh is not None:
             rep = replicated(self.mesh)
             dp = NamedSharding(self.mesh, P(DATA_AXIS))
@@ -1748,14 +1806,28 @@ class ALSTrainer:
             put_dp = lambda x: jax.device_put(x, dp)    # noqa: E731
         else:
             put_rep = put_dp = jnp.asarray
+        ks = tuple(b.k for b in buckets)
+        counts = [put_dp(b.counts) for b in buckets]
+        columns = jax.block_until_ready(
+            (put_rep(c_sorted), put_rep(v_sorted))
+        )
+        t0 = time.perf_counter()
+        blocks = jax.block_until_ready(_expand_side(
+            *columns,
+            tuple((put_dp(b.starts), n) for b, n in zip(buckets, counts)),
+            ks=ks,
+        ))
+        expand_s = time.perf_counter() - t0
+        TRAIN_PHASE_SECONDS.labels(phase="als.expand").observe(expand_s)
         return {
-            "c_sorted": put_rep(c_sorted),
-            "v_sorted": put_rep(v_sorted),
-            "ks": tuple(b.k for b in buckets),
+            "ks": ks,
             "buckets": tuple(
-                (put_dp(b.rows), put_dp(b.starts), put_dp(b.counts))
-                for b in buckets
+                (put_dp(b.rows), put_dp(idx), put_dp(val), n)
+                for b, (idx, val), n in zip(buckets, blocks, counts)
             ),
+            "padded_entries": sum(idx.size for idx, _ in blocks),
+            "padded_bytes": sum(a.nbytes for blk in blocks for a in blk),
+            "expand_s": round(expand_s, 3),
         }
 
     def _stage_side_sharded(self, layout: BucketLayout, n_dev: int):
@@ -1875,8 +1947,7 @@ class ALSTrainer:
                 *flat,
             )
         return _half_iteration(
-            upd, opp, side["c_sorted"], side["v_sorted"], side["buckets"],
-            lam_t,
+            upd, opp, side["buckets"], lam_t,
             jnp.asarray(cfg.alpha, jnp.float32),
             ks=side["ks"],
             implicit=cfg.implicit,
@@ -1938,8 +2009,7 @@ class ALSTrainer:
 
         def probe(stop):
             return _half_phase_probe(
-                upd, opp, side["c_sorted"], side["v_sorted"],
-                side["buckets"], lam_t, alpha_t,
+                upd, opp, side["buckets"], lam_t, alpha_t,
                 ks=side["ks"], implicit=cfg.implicit,
                 weighted_lambda=cfg.weighted_lambda,
                 precision=cfg.matmul_precision, solver=cfg.solver,
@@ -2179,8 +2249,8 @@ def sweep_train_als(
     def make_half(side):
         def one(upd, opp, lam):
             return _half_iteration_impl(
-                upd, opp, side["c_sorted"], side["v_sorted"],
-                side["buckets"], lam, alpha, ks=side["ks"], **common,
+                upd, opp, side["buckets"], lam, alpha, ks=side["ks"],
+                **common,
             )
 
         return xray.instrument("als.sweep_half")(

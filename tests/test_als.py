@@ -133,6 +133,179 @@ def test_bucket_layout_covers_all_ratings():
             assert cnt == min(counts[rid], b.k)
 
 
+# -- the padded layout: expanded once, at staging -----------------------------
+
+
+@pytest.mark.parametrize("k", [8, 128, 1024])
+def test_expand_bucket_matches_plain_numpy(k):
+    """`_expand_bucket` against a loop over the rows: a row of count 0,
+    one of count K, and the row whose slice ends at nnz (its padding
+    slots lie past the column's end: the clamp)."""
+    from predictionio_tpu.models.als import _expand_bucket
+
+    rng = np.random.default_rng(k)
+    counts = np.array([k, 0, 1, k // 2, 0, k - 1, k, 3], np.int32)
+    starts = np.concatenate(([0], np.cumsum(counts)[:-1])).astype(np.int32)
+    nnz = int(counts.sum())
+    assert starts[-1] + counts[-1] == nnz and counts[-1] < k
+    c = rng.integers(1, 5000, nnz).astype(np.int32)
+    v = rng.uniform(1, 5, nnz).astype(np.float32)
+    # the rows in another order than the column's, as a bucket has them
+    order = rng.permutation(len(counts))
+    idx, val = _expand_bucket(
+        jnp.asarray(c), jnp.asarray(v), jnp.asarray(starts[order]),
+        jnp.asarray(counts[order]), k,
+    )
+    want_i = np.zeros((len(counts), k), np.int32)
+    want_v = np.zeros((len(counts), k), np.float32)
+    for b, row in enumerate(order):
+        s, n = starts[row], counts[row]
+        want_i[b, :n] = c[s : s + n]
+        want_v[b, :n] = v[s : s + n]
+    assert idx.dtype == jnp.int32 and val.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(idx), want_i)
+    np.testing.assert_array_equal(np.asarray(val), want_v)
+
+
+def _staged_columns(monkeypatch):
+    """Record what `_stage_side` is handed: the trainer keeps no copy."""
+    seen = []
+    stage_side = ALSTrainer._stage_side
+
+    def spy(self, c_sorted, v_sorted, buckets):
+        seen.append((np.asarray(c_sorted), np.asarray(v_sorted), buckets))
+        return stage_side(self, c_sorted, v_sorted, buckets)
+
+    monkeypatch.setattr(ALSTrainer, "_stage_side", spy)
+    return seen
+
+
+@pytest.mark.parametrize("implicit", [False, True])
+@pytest.mark.parametrize("staging", ["host", "device"])
+def test_staged_blocks_train_bitwise_as_expansion_in_the_sweep(
+    monkeypatch, staging, implicit
+):
+    """Two sweeps of `ALSTrainer.run` against a reference half that
+    expands every bucket inside the sweep, as the parent's did and the
+    sharded path does: the same tables, bit for bit."""
+    import functools
+
+    import jax
+
+    from predictionio_tpu.models import als
+
+    u, i, v, nu, ni = _toy(n_users=70, n_items=33, density=0.35, seed=5)
+    v = np.abs(v) + 1.0 if implicit else v
+    cfg = ALSConfig(rank=6, lam=0.05, implicit=implicit, alpha=2.0,
+                    min_bucket_k=4)
+    seen = _staged_columns(monkeypatch)
+    tr = ALSTrainer((u, i, v), nu, ni, cfg, staging=staging)
+    assert tr.staging == staging and len(seen) == 2
+    for side in (tr._user_side, tr._item_side):
+        assert "c_sorted" not in side and "v_sorted" not in side
+
+    @functools.partial(jax.jit, static_argnames=("ks",))
+    def reference_half(upd, opp, c, v_, flat, *, ks):
+        buckets = tuple(
+            (rows, *als._expand_bucket(c, v_, starts, counts, k), counts)
+            for (rows, starts, counts), k in zip(flat, ks)
+        )
+        return als._half_iteration_impl(
+            upd, opp, buckets, jnp.float32(cfg.lam),
+            jnp.float32(cfg.alpha), ks=ks, implicit=implicit,
+            weighted_lambda=True, precision="highest", solver="xla",
+        )
+
+    def ref_side(c, v_, buckets):
+        flat = tuple(
+            (jnp.asarray(b.rows), jnp.asarray(b.starts),
+             jnp.asarray(b.counts)) for b in buckets
+        )
+        ks = tuple(b.k for b in buckets)
+        return lambda upd, opp: reference_half(
+            upd, opp, jnp.asarray(c), jnp.asarray(v_), flat, ks=ks)
+
+    half_u, half_i = ref_side(*seen[0]), ref_side(*seen[1])
+    U0, V0 = tr.init_factors()
+    U, V = tr.run(U0, V0, 2)
+    Ur, Vr = U0, V0
+    for _ in range(2):
+        Ur = half_u(Ur, Vr)
+        Vr = half_i(Vr, Ur)
+    np.testing.assert_array_equal(np.asarray(U), np.asarray(Ur))
+    np.testing.assert_array_equal(np.asarray(V), np.asarray(Vr))
+
+
+def test_staged_blocks_are_padded_to_their_buckets_width():
+    """Every bucket arrives as `(rows, idx, val, counts)` with `idx`
+    int32 and `val` float32, both `[B, K]`, narrow or wide (the TPU
+    keeps a `[B, 8]` block with B minor, unpadded: no flat form)."""
+    u, i, v, nu, ni = _toy(n_users=300, n_items=40, density=0.5, seed=2)
+    tr = ALSTrainer((u, i, v), nu, ni, ALSConfig(rank=4, min_bucket_k=8))
+    widths = set()
+    for side in (tr._user_side, tr._item_side):
+        for (rows, idx, val, counts), k in zip(side["buckets"], side["ks"]):
+            assert idx.shape == val.shape == (len(rows), k)
+            assert idx.dtype == jnp.int32 and val.dtype == jnp.float32
+            assert counts.shape == rows.shape
+            assert int(jnp.sum(val != 0)) <= int(jnp.sum(counts))
+            widths.add(k)
+    assert min(widths) < 128 <= max(widths)
+
+
+def test_half_iteration_holds_no_gather_from_the_rating_columns():
+    """The lowered sweep reads padded blocks: no operand of it has the
+    `[nnz]` columns' length, and the staging program, which has, is
+    where the positions are reckoned."""
+    from predictionio_tpu.models import als
+
+    u, i, v, nu, ni = _toy(n_users=41, n_items=23, density=0.42, seed=11)
+    nnz = len(v)
+    tr = ALSTrainer((u, i, v), nu, ni, ALSConfig(rank=4))
+    layout = build_bucket_layout(u, i, v, nu)
+    padded = {len(b.rows) * b.k for b in layout.buckets}
+    assert nnz not in padded | {nu, ni} and nnz > 64
+    U, V = tr.init_factors()
+    side = tr._user_side
+    half = als._half_iteration.lower(
+        U, V, side["buckets"], jnp.float32(0.1), jnp.float32(1.0),
+        ks=side["ks"], implicit=False, weighted_lambda=True,
+        precision="highest", solver="xla",
+    ).as_text()
+    assert "gather" in half                      # the factor rows
+    assert f"tensor<{nnz}x" not in half
+    staging = als._expand_side.lower(
+        jnp.asarray(layout.col_sorted), jnp.asarray(layout.val_sorted),
+        tuple((jnp.asarray(b.starts), jnp.asarray(b.counts))
+              for b in layout.buckets),
+        ks=side["ks"],
+    ).as_text()
+    assert f"tensor<{nnz}xi32>" in staging and "gather" in staging
+
+
+def test_als_staged_event_counts_the_padded_layout(monkeypatch):
+    from predictionio_tpu.obs import TRAIN_PHASE_SECONDS, tower
+
+    events = []
+    monkeypatch.setattr(
+        tower, "note_event", lambda name, **f: events.append((name, f)))
+    u, i, v, nu, ni = _toy()
+    expand = TRAIN_PHASE_SECONDS.labels(phase="als.expand")
+    n0 = expand.snapshot()["count"]
+    tr = ALSTrainer((u, i, v), nu, ni, ALSConfig(rank=4, min_bucket_k=4))
+    assert expand.snapshot()["count"] == n0 + 2     # one a side
+    (name, staged), = events
+    assert name == "als_staged"
+    for which, side in (("user", tr._user_side), ("item", tr._item_side)):
+        entries = sum(
+            len(rows) * k
+            for (rows, *_), k in zip(side["buckets"], side["ks"])
+        )
+        assert staged["paddedEntries"][which] == entries >= len(v)
+        assert staged["paddedBytes"][which] == 8 * entries
+        assert staged["expandSeconds"][which] >= 0.0
+
+
 def test_bucket_layout_cap_truncates():
     u = np.zeros(100, dtype=np.int32)
     i = np.arange(100, dtype=np.int32)

@@ -186,6 +186,52 @@ def test_solver_matches_normal_equations_implicit():
     np.testing.assert_allclose(out[0], ref, rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("implicit", [False, True])
+def test_solved_block_equals_the_flattened_formulation_bitwise(implicit):
+    """The kernel hands its padded `[B, K]` ids and values to
+    `_solve_buckets` as they are.  Until PR 30 it flattened them and had
+    the sweep expand them again from `starts`: the same block, bit for
+    bit, for the same ids, vals and counts."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.models.als import _expand_bucket, _solve_buckets
+
+    rng = np.random.default_rng(3)
+    b, k, r = 8, 16, 6
+    opp = jnp.asarray(rng.normal(size=(1024, r)).astype(np.float32))
+    counts = np.array([16, 0, 3, 9, 1, 16, 0, 7], np.int32)
+    ids = np.zeros((b, k), np.int32)
+    vals = np.zeros((b, k), np.float32)
+    for j, n in enumerate(counts):
+        ids[j, :n] = rng.choice(1000, n, replace=False)
+        vals[j, :n] = rng.uniform(1, 5, n)
+    cfg = ALSConfig(rank=r, lam=0.07, implicit=implicit, alpha=2.0)
+    lam, alpha = jnp.float32(cfg.lam), jnp.float32(cfg.alpha)
+    kw = dict(implicit=implicit, weighted_lambda=True, precision="highest",
+              solver="xla")
+    got = FoldInSolver(cfg)._kernel(
+        opp, jnp.asarray(ids), jnp.asarray(vals), jnp.asarray(counts),
+        lam, alpha, k=k, **kw)
+
+    @functools.partial(jax.jit, static_argnames=("k",))
+    def flattened(opp, ids, vals, counts, lam, alpha, *, k):
+        starts = jnp.arange(ids.shape[0], dtype=jnp.int32) * k
+        rows = jnp.arange(ids.shape[0], dtype=jnp.int32)
+        idx, val = _expand_bucket(
+            ids.reshape(-1), vals.reshape(-1), starts, counts, k)
+        return _solve_buckets(
+            lambda acc, rows_, x: x, opp, ((rows, idx, val, counts),),
+            lam, alpha, ks=(k,), **kw)
+
+    want = flattened(opp, jnp.asarray(ids), jnp.asarray(vals),
+                     jnp.asarray(counts), lam, alpha, k=k)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.isfinite(np.asarray(got)).all()
+
+
 def test_solver_truncates_to_most_recent_when_over_capacity():
     rng = np.random.default_rng(2)
     Y = rng.normal(size=(64, 4)).astype(np.float32)

@@ -344,10 +344,9 @@ def test_gram_probe_runs_for_subspace():
     side = tr._user_side
 
     @functools.partial(jax.jit, static_argnames=("ks", "stop_after"))
-    def probe(upd, opp, c_sorted, v_sorted, buckets, lam, alpha, *, ks,
-              stop_after):
+    def probe(upd, opp, buckets, lam, alpha, *, ks, stop_after):
         return _solve_buckets(
-            None, opp, c_sorted, v_sorted, buckets, lam, alpha,
+            None, opp, buckets, lam, alpha,
             ks=ks, implicit=False, weighted_lambda=True,
             precision="highest", solver="xla",
             solver_mode="subspace", subspace_size=4, upd_table=upd,
@@ -357,7 +356,6 @@ def test_gram_probe_runs_for_subspace():
     lam = jnp.asarray(0.1, jnp.float32)
     alpha = jnp.asarray(1.0, jnp.float32)
     for stop in ("gather", "gram"):
-        out = probe(U0, V0, side["c_sorted"], side["v_sorted"],
-                    side["buckets"], lam, alpha, ks=side["ks"],
+        out = probe(U0, V0, side["buckets"], lam, alpha, ks=side["ks"],
                     stop_after=stop)
         assert np.isfinite(float(out))

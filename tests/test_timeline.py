@@ -277,14 +277,22 @@ def test_lowered_scorer_and_half_iteration_hold_the_scope_names():
     U, V = trainer.init_factors()
     side = trainer._user_side
     text = als._half_iteration.lower(
-        U, V, side["c_sorted"], side["v_sorted"], side["buckets"],
-        jnp.float32(0.1), jnp.float32(1.0), ks=side["ks"],
-        implicit=False, weighted_lambda=True, precision="highest",
-        solver=trainer.cfg.solver,
+        U, V, side["buckets"], jnp.float32(0.1), jnp.float32(1.0),
+        ks=side["ks"], implicit=False, weighted_lambda=True,
+        precision="highest", solver=trainer.cfg.solver,
     ).as_text(debug_info=True)
+    # als.positions: the mask alone here, the expansion at staging
     for name in ("als.positions", "als.gather", "als.gram", "als.solve",
                  "als.scatter"):
         assert f"/{name}/" in text, name
+    layout = als.build_bucket_layout(u, i, np.ones(400, np.float32), 30)
+    text = als._expand_side.lower(
+        jnp.asarray(layout.col_sorted), jnp.asarray(layout.val_sorted),
+        tuple((jnp.asarray(b.starts), jnp.asarray(b.counts))
+              for b in layout.buckets),
+        ks=tuple(b.k for b in layout.buckets),
+    ).as_text(debug_info=True)
+    assert "/als.positions/" in text
 
 
 # -- serving integration ----------------------------------------------------

@@ -83,11 +83,6 @@ def main() -> None:
                     default="process",
                     help="loadgen worker kind (process = no client "
                     "GIL, the honest default)")
-    ap.add_argument("--edge", choices=("eventloop", "threads"),
-                    default="eventloop",
-                    help="serving front end for --sweep/--concurrency "
-                    "(pio-surge A/B: eventloop = selector loop, "
-                    "threads = the pre-surge stdlib edge)")
     ap.add_argument("--arrival-rate", type=float, default=0.0,
                     metavar="QPS",
                     help="with --concurrency: open-loop Poisson "
@@ -421,14 +416,14 @@ def _prebuilt_engine(model, algo_params=None):
     return engine, ep, iid, ctx
 
 
-def _boot_server(engine, ep, iid, ctx, microbatch, edge="eventloop",
+def _boot_server(engine, ep, iid, ctx, microbatch,
                  tenants=None, slo_ms=None, shared_batcher=True,
                  microbatch_max=64):
     from predictionio_tpu.server.serving import EngineServer, ServerConfig
 
     srv = EngineServer(
         engine, ep, iid, ctx=ctx,
-        config=ServerConfig(port=0, microbatch=microbatch, edge=edge,
+        config=ServerConfig(port=0, microbatch=microbatch,
                             slo_ms=slo_ms,
                             shared_batcher=shared_batcher,
                             microbatch_max=microbatch_max),
@@ -625,7 +620,7 @@ def _bench_sweep(args, model, rng) -> None:
     # point also reads the error-budget burn rate the fleet alerting
     # would see (the 1m window covers a sweep point's duration)
     srv = _boot_server(engine, ep, iid, ctx, microbatch="auto",
-                       edge=args.edge, tenants=registry,
+                       tenants=registry,
                        slo_ms=args.slo_ms,
                        shared_batcher=(args.shared_batcher != "off"),
                        microbatch_max=args.microbatch_max)
@@ -765,7 +760,6 @@ def _bench_sweep(args, model, rng) -> None:
             "p50_ms": point["p50_ms"],
             "duration_s": args.duration_s,
             "loadgen_mode": args.loadgen_mode,
-            "edge": args.edge,
             "errors": res["errors"],
             "items": args.items,
             "rank": args.rank,
@@ -791,7 +785,6 @@ def _bench_sweep(args, model, rng) -> None:
         ),
         "slo_ms": args.slo_ms,
         "platform": platform,
-        "edge": args.edge,
         "items": args.items,
         "rank": args.rank,
         "retrieval": args.retrieval,
@@ -826,7 +819,6 @@ def _bench_sweep(args, model, rng) -> None:
             "sweep": [p["concurrency"] for p in points],
             "duration_s": args.duration_s,
             "loadgen_mode": args.loadgen_mode,
-            "edge": args.edge,
             "items": args.items,
             "rank": args.rank,
             **({"tenants": tenants_n} if tenants_n > 1 else {}),

@@ -579,7 +579,6 @@ def cmd_deploy(args, storage: Storage) -> int:
             breaker_failures=args.breaker_failures,
             breaker_reset_s=args.breaker_reset,
             foldin_poll_s=args.foldin_poll,
-            edge=args.edge,
             max_connections=args.max_connections,
             slo_ms=getattr(args, "slo_ms", None),
         ),
@@ -704,7 +703,6 @@ def _deploy_fleet(args) -> int:
         ("--engine-instance-id", args.engine_instance_id),
         ("--microbatch", args.microbatch),
         ("--shared-batcher", args.shared_batcher),
-        ("--edge", args.edge),
         # pio-hive: every replica hosts the same tenant manifest, so
         # the fleet multiplexes N tenants x N replicas
         ("--multi", getattr(args, "multi", None)),
@@ -1392,12 +1390,6 @@ def build_parser() -> argparse.ArgumentParser:
                    "in place (factor rows + top-k index, no "
                    "stop-the-world reload); pair with a `pio-tpu "
                    "foldin --watch` daemon")
-    d.add_argument("--edge", choices=("eventloop", "threads"),
-                   default="eventloop",
-                   help="serving front end (pio-surge): eventloop = "
-                   "one selector loop, no thread per connection "
-                   "(default); threads = the stdlib "
-                   "ThreadingHTTPServer edge")
     d.add_argument("--max-connections", type=int, default=512,
                    help="concurrent-connection cap; connection "
                    "attempts past it get a structured 503 and are "

@@ -1,8 +1,8 @@
 """Engine deployment server: answers ``/queries.json`` with predictions.
 
 Re-expression of reference `workflow/CreateServer.scala` (`ServerActor`
-routes `:433-612`, `MasterActor` lifecycle `:255-377`) on the stdlib
-threading HTTP server — no spray/akka.  Routes:
+routes `:433-612`, `MasterActor` lifecycle `:255-377`) on the selector
+event loop of `server/eventloop.py` — no spray/akka.  Routes:
 
 * ``GET  /``             — status JSON: engine info, request count, latency
   (``avgServingSec``/``lastServingSec`` parity, `CreateServer.scala:552-559`)
@@ -28,15 +28,10 @@ import time
 import urllib.parse
 import uuid
 from dataclasses import is_dataclass, asdict
-from http.server import ThreadingHTTPServer
 from typing import Any, Callable, Optional
 
 from ..controller.base import WorkflowContext
-from .http_base import (
-    HTTPServerBase,
-    JsonRequestHandler,
-    observability_response,
-)
+from .http_base import HTTPServerBase, observability_response
 from .eventloop import callback_scope
 from .microbatch import AdmissionRejected
 from ..controller.engine import Engine, EngineParams
@@ -100,24 +95,13 @@ class ServerConfig:
                  breaker_reset_s: float = 10.0,
                  retry_seed: Optional[int] = None,
                  foldin_poll_s: Optional[float] = None,
-                 edge: str = "eventloop",
                  max_connections: int = 512,
                  slo_ms: Optional[float] = None):
         self.host = host
         self.port = port
-        # pio-surge: which HTTP front end answers the port.
-        # "eventloop" (default) = ONE selector loop thread parses and
-        # routes every connection, device work rides the micro-batcher
-        # dispatcher, blocking routes ride a small aux pool — no thread
-        # per connection.  "threads" = the pre-surge stdlib
-        # ThreadingHTTPServer edge (kept for bitwise-compatible A/B
-        # benchmarking and as a fallback).
-        if edge not in ("eventloop", "threads"):
-            raise ValueError(f"edge must be eventloop|threads, got {edge!r}")
-        self.edge = edge
-        # concurrent-connection cap (both edges): connection attempts
-        # past it are answered a structured 503 and closed, so a
-        # slow-loris client can't pin unbounded threads/sockets
+        # concurrent-connection cap: connection attempts past it are
+        # answered a structured 503 and closed, so a slow-loris client
+        # can't pin unbounded sockets
         self.max_connections = max_connections
         self.feedback = feedback
         self.event_server_url = event_server_url
@@ -438,9 +422,9 @@ class EngineServer(HTTPServerBase):
             failure_threshold=max(self.config.breaker_failures * 4, 8),
             reset_timeout_s=min(self.config.breaker_reset_s, 1.0),
         )
-        # aux pool for the event-loop edge's blocking routes (status,
-        # reload, /debug/profile, fold-in apply, unbatched predicts);
-        # built lazily at first bind of the eventloop edge
+        # aux pool for the blocking routes (status, reload,
+        # /debug/profile, fold-in apply, unbatched predicts); built
+        # lazily at first bind
         self._aux_pool = None
         # pio-confluence: the process-wide shared batcher core (built
         # lazily by the first _make_batcher call that wants one) plus
@@ -478,7 +462,7 @@ class EngineServer(HTTPServerBase):
         self._latency = Histogram()
         self._m_latency = QUERY_LATENCY.child()
         # per-outcome query counters resolved once (.labels() is too
-        # hot for per-request use); shared by both edges
+        # hot for per-request use)
         self._m_queries = {
             s: QUERIES_TOTAL.labels(status=s)
             for s in ("ok", "bad_request", "timeout", "error", "rejected")
@@ -495,7 +479,7 @@ class EngineServer(HTTPServerBase):
             for s in ("ok", "bad_request", "timeout", "error",
                       "rejected", "quota", "shed")
         }
-        self._httpd: Optional[ThreadingHTTPServer] = None
+        self._httpd = None  # EventLoopHTTPServer once bound
         # pio-lens: --slo-ms arms the error-budget burn-rate gauges on
         # the process-wide latency histogram (the replica-side half of
         # the fleet's alert-ready signal; the router arms its own on
@@ -953,8 +937,8 @@ class EngineServer(HTTPServerBase):
         """Shared front half of a query on ANY edge: budget, decode,
         state snapshot, fault point, deadline-aware admission.  Marks
         the ``parse``/``auth`` timeline boundaries.  Runs on the
-        calling thread (event-loop thread or HTTP handler thread) and
-        never blocks."""
+        calling thread (the event loop's, or ``predict_json``'s caller)
+        and never blocks."""
         # the request's time budget: per-request override, else the
         # configured default, else unbounded (None costs nothing)
         budget = timeout_s if timeout_s is not None \
@@ -1111,18 +1095,12 @@ class EngineServer(HTTPServerBase):
 
     def predict_json(self, query_json: dict,
                      timeout_s: Optional[float] = None) -> Any:
-        """Blocking query path (threading edge, direct library callers,
-        benches).  The event-loop edge uses the same setup/finish
-        halves around a continuous ``submit_nowait`` instead."""
-        # pulse timeline: adopt the HTTP handler's (its t0 covers body
-        # read + JSON decode, and it adds the socket-write segment
-        # after the reply) or own a fresh one for direct callers
-        # (benches, tests) — either way the batcher finds it via the
-        # thread-local scope and credits queue/batch/device waits
-        tl = timeline.current_timeline()
-        owned = tl is None
-        if owned:
-            tl = timeline.Timeline("serve")
+        """The in-process entry (tests, library callers): the same
+        setup/finish halves as ``_el_query``, round a blocking
+        ``submit`` that parks until the dispatcher's turn has answered.
+        It owns the request's timeline; the batcher finds it through
+        the thread-local scope and credits queue/batch/device waits."""
+        tl = timeline.Timeline("serve")
         t0 = time.perf_counter()
         _m_inflight.inc()
         ctx = None
@@ -1162,14 +1140,11 @@ class EngineServer(HTTPServerBase):
             raise
         finally:
             _m_inflight.dec()
-        if owned:
-            tl.finish()
+        tl.finish()
         return out
 
     # -- event-loop edge (pio-surge) ---------------------------------------
     def _build_httpd(self):
-        if self.config.edge != "eventloop":
-            return super()._build_httpd()
         from .eventloop import EventLoopHTTPServer
 
         if self._aux_pool is None:
@@ -1508,10 +1483,10 @@ class EngineServer(HTTPServerBase):
 
     def _el_reply_error(self, e: BaseException, respond, hdrs,
                         lease=None) -> None:
-        """Map a query-path exception to the same structured replies
-        the threading edge produces (and the same counters).  A lease
-        passed here books the tenant outcome (idempotent — setup
-        failures were already completed inside ``_query_setup``)."""
+        """THE table of query-path exception -> structured reply and
+        counter.  A lease passed here books the tenant outcome
+        (idempotent — setup failures were already completed inside
+        ``_query_setup``)."""
         if lease is not None:
             lease.complete(_lease_status(e))
         self._book_engine_query(_lease_status(e))
@@ -1832,187 +1807,3 @@ class EngineServer(HTTPServerBase):
     @property
     def max_connections(self) -> int:
         return self.config.max_connections
-
-    def _make_handler(server: "EngineServer"):
-        # labeled counter children resolved ONCE: .labels() is a dict
-        # build + lock per call (~1.5 us), too hot for per-request use
-        m_ok = QUERIES_TOTAL.labels(status="ok")
-        m_bad = QUERIES_TOTAL.labels(status="bad_request")
-        m_timeout = QUERIES_TOTAL.labels(status="timeout")
-        m_err = QUERIES_TOTAL.labels(status="error")
-        m_rejected = QUERIES_TOTAL.labels(status="rejected")
-
-        class Handler(JsonRequestHandler):
-            server_logger = logger
-
-            def do_GET(self):
-                if self._serve_metrics():
-                    return
-                if self.path == "/" or self.path.startswith("/?"):
-                    # browsers get the HTML status page, everyone else the
-                    # JSON document (reference served Twirl HTML here)
-                    if "text/html" in self.headers.get("Accept", ""):
-                        self._reply(
-                            200, server.status_html().encode(),
-                            ctype="text/html; charset=utf-8",
-                        )
-                    else:
-                        self._reply(200, server.status_json())
-                elif self.path.startswith("/reload"):
-                    try:
-                        iid = server.reload()
-                        self._reply(200, {"reloaded": iid})
-                    except LookupError as e:
-                        self._reply(404, {"message": str(e)})
-                    except Exception as e:
-                        logger.exception("reload failed")
-                        self._reply(500, {"message": f"reload failed: {e}"})
-                elif self.path.startswith("/debug/tenants"):
-                    if server.tenants is None:
-                        self._reply(404, {"message": "tenancy is not "
-                                          "enabled (deploy --multi)"})
-                    else:
-                        self._reply(200, server.tenants.debug_payload())
-                elif self.path.startswith("/debug/experiments"):
-                    self._reply(*_experiments_response(server.tenants))
-                else:
-                    self._reply(404, {"message": "not found"})
-
-            def do_POST(self):
-                n = int(self.headers.get("Content-Length", 0))
-                raw = self.rfile.read(n) if n else b"{}"
-                if self.path.startswith("/queries.json"):
-                    # trace propagation: honor the client's X-PIO-Trace
-                    # or mint one; either way the id is bound to this
-                    # thread (spans inherit it, feedback delivery
-                    # forwards it) and echoed on the response.
-                    # extra_headers is (re)assigned per request — a
-                    # keep-alive connection reuses this handler.
-                    tid = self._trace_id() or new_trace_id()
-                    self.extra_headers = [(TRACE_HEADER, tid)]
-                    # the handler owns the pulse timeline: its t0
-                    # precedes JSON decode, and only the handler can
-                    # time the socket write of the reply
-                    tl = timeline.Timeline("serve")
-                    with trace_scope(tid), timeline.timeline_scope(tl):
-                        self._post_query(raw, tl)
-                elif self.path.startswith("/foldin/apply"):
-                    try:
-                        code, payload, _, _ = server._blocking_foldin_apply()
-                        self._reply(code, payload)
-                    except Exception as e:
-                        logger.exception("foldin apply failed")
-                        self._reply(500, {"message": str(e)})
-                elif self.path.startswith("/tenants/weights"):
-                    try:
-                        code, payload, _, _ = (
-                            server._blocking_set_weights(raw)
-                        )
-                        self._reply(code, payload)
-                    except Exception as e:
-                        logger.exception("weights update failed")
-                        self._reply(500, {"message": str(e)})
-                elif self.path.startswith("/admin/tenants"):
-                    try:
-                        code, payload, _, _ = (
-                            server._blocking_admin_tenants(raw)
-                        )
-                        self._reply(code, payload)
-                    except Exception as e:
-                        logger.exception("tenant admin failed")
-                        self._reply(500, {"message": str(e)})
-                elif self.path.startswith("/stop"):
-                    self._reply(200, {"message": "stopping"})
-                    threading.Thread(target=server.stop, daemon=True).start()
-                else:
-                    self._reply(404, {"message": "not found"})
-
-            def _post_query(self, raw: bytes, tl) -> None:
-                try:
-                    query_json = json.loads(raw.decode() or "{}")
-                except json.JSONDecodeError as e:
-                    m_bad.inc()
-                    self._reply(400, {"message": f"invalid JSON: {e}"})
-                    return
-                # optional per-request budget: /queries.json?timeout=0.5
-                timeout_s = None
-                tv = urllib.parse.parse_qs(
-                    urllib.parse.urlparse(self.path).query
-                ).get("timeout")
-                if tv:
-                    try:
-                        timeout_s = float(tv[0])
-                    except ValueError:
-                        m_bad.inc()
-                        self._reply(
-                            400, {"message": f"bad timeout: {tv[0]!r}"}
-                        )
-                        return
-                try:
-                    self._reply(200, server.predict_json(
-                        query_json, timeout_s=timeout_s))
-                    # close the timeline on the success path only:
-                    # error replies have no meaningful decomposition
-                    # and would pollute the per-segment histograms
-                    tl.mark("write")
-                    tl.finish()
-                    m_ok.inc()
-                except QuotaExceeded as e:
-                    # pio-hive: over the tenant's token bucket — the
-                    # client's rate problem, a structured 429
-                    m_rejected.inc()
-                    server._book_engine_query("quota")
-                    self.extra_headers.append(("Retry-After", "1"))
-                    self._reply(429, {
-                        "message": str(e),
-                        "error": "QuotaExceeded",
-                    })
-                except TenantUnavailable as e:
-                    m_rejected.inc()
-                    server._book_engine_query("shed")
-                    self.extra_headers.append(("Retry-After", "1"))
-                    self._reply(503, {
-                        "message": str(e),
-                        "error": "TenantUnavailable",
-                    })
-                except AdmissionRejected as e:
-                    # deadline-aware admission shed the request before
-                    # it queued (pio-surge): same structured 503, its
-                    # own counter
-                    m_rejected.inc()
-                    server._book_engine_query("rejected")
-                    self.extra_headers.append(("Retry-After", "1"))
-                    self._reply(503, {
-                        "message": str(e),
-                        "error": "AdmissionRejected",
-                    })
-                except DeadlineExceeded as e:
-                    # structured overload answer, not a hang: the
-                    # client can back off and retry
-                    m_timeout.inc()
-                    server._book_engine_query("timeout")
-                    self.extra_headers.append(("Retry-After", "1"))
-                    self._reply(503, {
-                        "message": str(e),
-                        "error": "DeadlineExceeded",
-                    })
-                except (KeyError, ValueError, TypeError) as e:
-                    m_bad.inc()
-                    server._book_engine_query("bad_request")
-                    self._reply(400, {"message": f"bad query: {e}"})
-                    server.remote_log(
-                        f"Query {raw.decode(errors='replace')} "
-                        f"is invalid: {e}"
-                    )
-                except Exception as e:
-                    m_err.inc()
-                    server._book_engine_query("error")
-                    logger.exception("query failed")
-                    self._reply(500, {"message": str(e)})
-                    server.remote_log(
-                        f"Query {raw.decode(errors='replace')} "
-                        f"failed: {e}"
-                    )
-
-        return Handler
-

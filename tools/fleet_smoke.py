@@ -185,7 +185,6 @@ def main(argv=None) -> int:
             procs.append(spawn_replica(
                 engine_json, i, coord, env=child_env,
                 extra_args=["--microbatch", "auto",
-                            "--edge", "eventloop",
                             "--slo-ms", "50"],
             ))
         replicas = []

@@ -133,8 +133,7 @@ def main(argv=None) -> int:
         scope.set_enabled(True)
         srv = EngineServer(
             engine, ep, iid, ctx=ctx,
-            config=ServerConfig(port=0, microbatch="on",
-                                edge="eventloop"),
+            config=ServerConfig(port=0, microbatch="on"),
             engine_variant="scope.json",
         )
         srv.start_background()

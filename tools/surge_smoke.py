@@ -173,7 +173,7 @@ def main(argv=None) -> int:
         for i in range(2):
             procs.append(spawn_replica(
                 engine_json, i, coord, env=child_env,
-                extra_args=["--microbatch", "auto", "--edge", "eventloop"],
+                extra_args=["--microbatch", "auto"],
             ))
         replicas = []
         for s in procs:

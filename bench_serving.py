@@ -22,7 +22,7 @@ additionally reports the SERVER's own histogram view
 
 Usage: python bench_serving.py [--items 100000] [--rank 64] [--n 200]
        [--threads 16]
-       [--tenants N] [--shared-batcher on|off] [--microbatch-max 64]
+       [--tenants N] [--microbatch-max 64]
 
 The ``--tenants N`` sweep serves N co-resident tenants through the
 pio-confluence shared batcher (suffix ``_mt`` on every record, tenant
@@ -116,12 +116,6 @@ def main() -> None:
                     "a few %% of batching efficiency for smaller turn "
                     "quanta — on a 1-core box the p99 tail is turn-"
                     "aligned, so capping the turn can buy back the SLO")
-    ap.add_argument("--shared-batcher", choices=("on", "off"),
-                    default="on",
-                    help="pio-confluence A/B: on (default) = ONE "
-                    "shared continuous batcher claims all tenants via "
-                    "weighted deficit round-robin; off = the "
-                    "pre-confluence private micro-batcher per tenant")
     ap.add_argument("--tenants", type=int, default=0, metavar="N",
                     help="pio-hive: stage N independent tenant models "
                     "in ONE multi-tenant server and drive the "
@@ -417,15 +411,13 @@ def _prebuilt_engine(model, algo_params=None):
 
 
 def _boot_server(engine, ep, iid, ctx, microbatch,
-                 tenants=None, slo_ms=None, shared_batcher=True,
-                 microbatch_max=64):
+                 tenants=None, slo_ms=None, microbatch_max=64):
     from predictionio_tpu.server.serving import EngineServer, ServerConfig
 
     srv = EngineServer(
         engine, ep, iid, ctx=ctx,
         config=ServerConfig(port=0, microbatch=microbatch,
                             slo_ms=slo_ms,
-                            shared_batcher=shared_batcher,
                             microbatch_max=microbatch_max),
         engine_variant="bench.json",
         tenants=tenants,
@@ -622,7 +614,6 @@ def _bench_sweep(args, model, rng) -> None:
     srv = _boot_server(engine, ep, iid, ctx, microbatch="auto",
                        tenants=registry,
                        slo_ms=args.slo_ms,
-                       shared_batcher=(args.shared_batcher != "off"),
                        microbatch_max=args.microbatch_max)
     # fenced-record keying (pio-scout satellite): the catalog size
     # rides the record's ``scale`` field — part of bench_gate's

@@ -573,7 +573,6 @@ def cmd_deploy(args, storage: Storage) -> int:
             log_url=args.log_url,
             log_prefix=args.log_prefix,
             microbatch=args.microbatch,
-            shared_batcher=(args.shared_batcher != "off"),
             query_timeout_s=args.query_timeout,
             feedback_capacity=args.feedback_capacity,
             breaker_failures=args.breaker_failures,
@@ -702,7 +701,6 @@ def _deploy_fleet(args) -> int:
         ("--engine-factory", args.engine_factory),
         ("--engine-instance-id", args.engine_instance_id),
         ("--microbatch", args.microbatch),
-        ("--shared-batcher", args.shared_batcher),
         # pio-hive: every replica hosts the same tenant manifest, so
         # the fleet multiplexes N tenants x N replicas
         ("--multi", getattr(args, "multi", None)),
@@ -1352,14 +1350,6 @@ def build_parser() -> argparse.ArgumentParser:
                    "device call (auto: when the algorithm batch-"
                    "predicts; off restores bitwise per-request "
                    "determinism)")
-    d.add_argument("--shared-batcher", choices=("on", "off"),
-                   default="on",
-                   help="pio-confluence: ONE shared continuous batcher "
-                   "per server — all tenants submit into a single "
-                   "queue claimed via weighted deficit round-robin, "
-                   "so cross-tenant traffic coalesces onto the "
-                   "device (off restores the private batcher per "
-                   "tenant)")
     d.add_argument("--query-timeout", type=float, default=None,
                    metavar="SEC",
                    help="per-request time budget: expiry answers a "

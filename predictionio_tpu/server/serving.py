@@ -84,7 +84,6 @@ class ServerConfig:
                  access_key: Optional[str] = None,
                  log_url: Optional[str] = None, log_prefix: str = "",
                  microbatch: str = "auto", microbatch_max: int = 64,
-                 shared_batcher: bool = True,
                  query_timeout_s: Optional[float] = None,
                  feedback_capacity: int = 1024,
                  delivery_attempts: int = 50,
@@ -115,14 +114,6 @@ class ServerConfig:
         # "on" forces it, "off" keeps per-request device dispatch
         self.microbatch = microbatch
         self.microbatch_max = microbatch_max
-        # pio-confluence: ONE shared continuous batcher per server —
-        # every tenant submits into a single pending queue whose
-        # dispatcher claims via weighted deficit round-robin across
-        # tenants, so cross-tenant concurrency coalesces onto the
-        # device instead of competing per-tenant dispatchers.  Off =
-        # the pre-confluence private-batcher-per-tenant layout (kept
-        # for A/B benchmarking and as an operator escape hatch).
-        self.shared_batcher = shared_batcher
         # per-request time budget (None = unbounded, the pre-resilience
         # behavior); expiry answers a structured 503 instead of queueing
         # device work for a client that already gave up
@@ -426,8 +417,8 @@ class EngineServer(HTTPServerBase):
         # /debug/profile, fold-in apply, unbatched predicts); built
         # lazily at first bind
         self._aux_pool = None
-        # pio-confluence: the process-wide shared batcher core (built
-        # lazily by the first _make_batcher call that wants one) plus
+        # the server's one shared batcher core (built lazily by the
+        # first _make_batcher call that batches at all) plus
         # the warmup-ladder signature set — co-shaped tenant models
         # share one compile per pow2 batch shape instead of re-warming
         # the full ladder per tenant
@@ -688,7 +679,7 @@ class EngineServer(HTTPServerBase):
         batches genuinely batched algorithms.
         """
         from ..controller.base import Algorithm
-        from .microbatch import MicroBatcher
+        from .microbatch import SharedBatcher, SharedBatcherView
 
         mode = self.config.microbatch
         if mode == "off":
@@ -720,22 +711,16 @@ class EngineServer(HTTPServerBase):
                 [pa[i] for pa in per_algo] for i in range(len(queries))
             ]
 
-        # pad_batches: predicts are pure per-item maps, and padding
-        # bounds the per-batch-size XLA executables to log2(max)+1
-        # instead of compiling mid-traffic for every new size
-        if not self.config.shared_batcher:
-            return MicroBatcher(
-                batch_fn, max_batch=self.config.microbatch_max,
-                pad_batches=True,
-            )
-        # pio-confluence: every tenant (and the anchor) gets a VIEW on
-        # one process-wide SharedBatcher — single pending queue, single
-        # dispatcher, claim-time weighted deficit round-robin across
-        # tenants.  The view carries this snapshot's batch_fn, so
-        # entries group by model identity inside a claim and in-flight
-        # queries survive a reload on the model they snapshotted.
-        from .microbatch import SharedBatcher, SharedBatcherView
-
+        # every tenant (and the anchor) gets a VIEW on the server's one
+        # SharedBatcher — single pending queue, single dispatcher,
+        # claim-time weighted deficit round-robin across tenants (plain
+        # FIFO while one tenant is pending).  The view carries this
+        # snapshot's batch_fn, so entries group by model identity
+        # inside a claim and in-flight queries survive a reload on the
+        # model they snapshotted.  pad_batches: predicts are pure
+        # per-item maps, and padding bounds the per-batch-size XLA
+        # executables to log2(max)+1 instead of compiling mid-traffic
+        # for every new size
         with self._shared_lock:
             if self._shared_core is None:
                 self._shared_core = SharedBatcher(
@@ -744,8 +729,7 @@ class EngineServer(HTTPServerBase):
                 )
             core = self._shared_core
         if tenant is None:
-            tenants = getattr(self, "tenants", None)
-            tenant = tenants.anchor_key if tenants is not None \
+            tenant = self.tenants.anchor_key if self.tenants is not None \
                 else "__anchor__"
         weight_fn = None
         if self.tenants is not None:

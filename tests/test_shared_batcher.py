@@ -29,7 +29,6 @@ import time
 import pytest
 
 from predictionio_tpu.server.microbatch import (
-    MicroBatcher,
     SharedBatcher,
     SharedBatcherView,
     _Entry,
@@ -375,10 +374,10 @@ def test_view_close_semantics_and_shared_stats():
 
 
 def test_engine_server_shared_batcher_wiring(storage_memory):
-    """The serving layer end of the chain: with shared_batcher on
-    (default) the anchor's batcher is a view on ONE process-wide core;
-    a reload swaps the view but keeps the core; opting out restores a
-    private MicroBatcher."""
+    """The serving layer end of the chain: the anchor's batcher is a
+    view on the server's ONE core; a reload swaps the view but keeps
+    the core; the only other layout is no batcher at all
+    (``microbatch="off"``), which builds no core."""
     from predictionio_tpu.controller.base import (
         Algorithm, DataSource, WorkflowContext,
     )
@@ -427,10 +426,11 @@ def test_engine_server_shared_batcher_wiring(storage_memory):
 
     srv = EngineServer(
         engine, ep, iid, ctx=ctx,
-        config=ServerConfig(port=0, shared_batcher=False),
+        config=ServerConfig(port=0, microbatch="off"),
     )
     try:
-        assert type(srv.batcher) is MicroBatcher
+        assert srv.batcher is None
         assert srv._shared_core is None
+        assert srv.predict_json({"x": 7}) == {"y": 14}
     finally:
         srv.stop()

@@ -24,10 +24,8 @@ the time* without any extra capture machinery.
 Concurrency saturation is first-class: ``pio_serve_inflight`` (requests
 between decode and reply), ``pio_microbatch_queue_depth`` (entries
 parked behind the in-flight batch), ``pio_microbatch_batch_size`` /
-``pio_microbatch_wait_seconds`` histograms and the
-``pio_microbatch_role_total{role}`` leader/follower split together
-answer "is the batcher widening concurrency or just queueing it" — the
-evidence layer the ROADMAP item-2 async front-end rework must beat.
+``pio_microbatch_wait_seconds`` histograms together answer "is the
+batcher widening concurrency or just queueing it".
 
 Accounting invariant: a finished timeline's segments SUM to the
 measured end-to-end wall time of the regions it covered (residual time
@@ -145,10 +143,9 @@ MICROBATCH_WAIT_SECONDS = _registry.histogram(
 )
 MICROBATCH_ROLE_TOTAL = _registry.counter(
     "pio_microbatch_role_total",
-    "Requests by batcher role: the leader ran the device call on its "
-    "own thread, a follower's result came from another thread's batch, "
-    "a dispatched request rode the continuous dispatcher (pio-surge "
-    "event-loop edge — no request thread involved)",
+    "Requests by batcher role: a dispatched request was admitted by "
+    "submit_nowait and completed by callback on the dispatcher (no "
+    "request thread parked for it)",
     labels=("role",),
 )
 MICROBATCH_ADMISSION_TOTAL = _registry.counter(
@@ -199,8 +196,6 @@ MICROBATCH_QUEUE_DEPTH.child()
 MICROBATCH_BATCH_SIZE.child()
 MICROBATCH_WAIT_SECONDS.child()
 MICROBATCH_TENANTS_PER_BATCH.child()
-MICROBATCH_ROLE_TOTAL.labels(role="leader")
-MICROBATCH_ROLE_TOTAL.labels(role="follower")
 MICROBATCH_ROLE_TOTAL.labels(role="dispatched")
 MICROBATCH_ADMISSION_TOTAL.labels(outcome="rejected")
 MICROBATCH_ADMISSION_TOTAL.labels(outcome="expired")

@@ -577,9 +577,8 @@ class DashboardServer(HTTPServerBase):
             "<tr><td>mean batch size</td><td>{:.2f}</td></tr>".format(
                 bs_snap["sum"] / bs_snap["count"]
                 if bs_snap["count"] else 0.0),
-            "<tr><td>leader / follower requests</td>"
-            "<td>{:g} / {:g}</td></tr>".format(
-                roles.get("leader", 0.0), roles.get("follower", 0.0)),
+            "<tr><td>dispatched requests</td>"
+            "<td>{:g}</td></tr>".format(roles.get("dispatched", 0.0)),
         ]
         sweep_html = "<p>(no sweep recorded yet — run "
         sweep_html += "<code>bench_serving.py --sweep 1,4,16</code>)</p>"

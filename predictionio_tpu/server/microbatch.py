@@ -12,22 +12,20 @@ unchanged QPS, bench_serving.py --threads).
 The TPU-shaped fix is to make concurrency *wider, not deeper*: coalesce
 the queries that arrive while a device call is in flight into ONE
 batched call (`Algorithm.batch_predict` — a [B, R] x [R, M] matmul
-costs barely more than the [R] x [R, M] one).  Two submission paths
-share one pending queue and one claim/run core:
+costs barely more than the [R] x [R, M] one).  ONE thread leads every
+turn: the lazily-started dispatcher claims whatever is pending the
+moment the device frees up, runs the batch, and completes its entries.
+Two ways in share its pending queue:
 
-* **Blocking** ``submit(x)`` — the original leader/follower pattern:
-  a request appends its query; if no batch is executing (and no
-  dispatcher owns the queue), it becomes the LEADER and runs the batch
-  on its own thread; requests arriving meanwhile park as FOLLOWERS.
-  Under no concurrency this degenerates to a direct call — no extra
-  thread, no timer, zero added latency.
-* **Continuous** ``submit_nowait(x, on_done, ...)`` (pio-surge) — the
-  event-loop edge admits requests *into the in-flight queue as they
-  arrive* and returns immediately; a lazily-started dispatcher thread
-  claims whatever is pending the moment the device frees up and fires
-  per-entry completion callbacks.  No thread ever parks per request:
-  the edge stays one loop thread + one dispatcher regardless of
-  concurrency.
+* **Continuous** ``submit_nowait(x, on_done, ...)`` — the event-loop
+  edge admits requests *into the queue as they arrive* and returns
+  immediately; the dispatcher fires per-entry completion callbacks.  No
+  thread ever parks per request: the edge stays one loop thread + one
+  dispatcher regardless of concurrency.
+* **Blocking** ``submit(x)`` — the same admission, then the calling
+  thread parks until the dispatcher has completed its entry and returns
+  the result (or raises) there.  The in-process callers' form
+  (``EngineServer.predict_json``, tests).
 
 Deadline-aware admission (pio-surge): entries may carry a
 ``resilience.policy.Deadline``.  A claimed entry already past its
@@ -39,8 +37,8 @@ queue+service time so the serving edge can reject a request that
 cannot make its SLO *up front* as a structured 503
 (:class:`AdmissionRejected`) rather than queue it to die.
 
-What the leading thread (the dispatcher, or a blocking leader) does
-between two device calls is one ``obs/timeline.Turn`` per claim: every
+What the dispatcher does between two device calls is one
+``obs/timeline.Turn`` per claim: every
 step below runs under an ``annotate("pio.turn.<segment>")`` (park, claim,
 fetch, complete; an engine's ``batch_predict`` may carve prepare /
 dispatch / decode out of fetch), which is at once a span in any
@@ -97,8 +95,6 @@ logger = logging.getLogger(__name__)
 _m_queue_depth = MICROBATCH_QUEUE_DEPTH.child()
 _m_batch_size = MICROBATCH_BATCH_SIZE.child()
 _m_batch_wait = MICROBATCH_WAIT_SECONDS.child()
-_m_leader = MICROBATCH_ROLE_TOTAL.labels(role="leader")
-_m_follower = MICROBATCH_ROLE_TOTAL.labels(role="follower")
 _m_dispatched = MICROBATCH_ROLE_TOTAL.labels(role="dispatched")
 _m_adm_rejected = MICROBATCH_ADMISSION_TOTAL.labels(outcome="rejected")
 _m_adm_expired = MICROBATCH_ADMISSION_TOTAL.labels(outcome="expired")
@@ -106,7 +102,7 @@ _m_tenants_per_batch = MICROBATCH_TENANTS_PER_BATCH.child()
 
 # distinguishes "no result produced" from a legitimate None result —
 # batch_fns whose valid outputs include None must not have them
-# clobbered by the leader-abort guard
+# clobbered by the aborted-turn guard
 _UNSET = object()
 
 
@@ -174,10 +170,10 @@ def dispatchable_sizes(max_batch: int) -> list[int]:
 class _Entry:
     # t_enq/t_claim/t_run0/t_run1 are the pulse timeline stamps: set by
     # whichever thread performs the transition (enqueue by the caller,
-    # claim by the leader/dispatcher, run bracketing by the executing
-    # thread) and read AFTER ``done`` — the condition variable's
-    # release/acquire (blocking path) or the dispatcher's post-batch
-    # callback (continuous path) orders the writes before the read
+    # claim and run bracketing by the dispatcher) and read AFTER
+    # ``done`` — the condition variable's release/acquire (blocking
+    # path) or the dispatcher's post-batch callback (continuous path)
+    # orders the writes before the read
     # tenant/fn are the pio-confluence fields: which tenant the entry
     # belongs to (the WDRR claim key) and which batch_fn executes it
     # (the group key — entries sharing a fn coalesce into ONE device
@@ -245,17 +241,19 @@ class MicroBatcher:
         # histogram is the direct queueing-for-the-batcher evidence
         self._cond = TimedCondition("microbatch")
         self._pending: list[_Entry] = []
-        self._running = False
         self._closed = False
         self._dispatcher_alive = False
+        # a claimed batch is executing: the admission estimate counts
+        # it as one batch ahead of a new arrival
+        self._in_turn = False
         # EWMA of recent device-batch service time: the admission
         # estimator's input.  Seeded 0 (= "no evidence, admit"), so a
         # cold batcher never sheds; mutated only under _cond.
         self._ewma = EwmaEstimator()
-        # full service time of the last dispatcher/leader turn (all
-        # execution groups back-to-back) — what the EWMA observes;
-        # written by _run_batch on the leading thread, read by _lead
-        # on the same thread under the re-acquired lock
+        # full service time of the last turn (all execution groups
+        # back-to-back) — what the EWMA observes; written by _run_batch
+        # on the dispatcher, read by _lead there under the re-acquired
+        # lock
         self._turn_s = 0.0
         # observability: how the batcher is actually coalescing.
         # Mutated only under _cond; read through stats() (bare reads
@@ -264,15 +262,12 @@ class MicroBatcher:
         self.batches = 0
         self.requests = 0
         self.max_seen = 0
-        self.leaders = 0
-        self.followers = 0
         self.dispatched = 0
         self.expired = 0
 
     def reset_stats(self) -> None:
         with self._cond:
             self.batches = self.requests = self.max_seen = 0
-            self.leaders = self.followers = 0
             self.dispatched = self.expired = 0
 
     def stats(self) -> dict:
@@ -284,8 +279,6 @@ class MicroBatcher:
                 "batches": self.batches,
                 "requests": self.requests,
                 "maxBatchSeen": self.max_seen,
-                "leaders": self.leaders,
-                "followers": self.followers,
                 "dispatched": self.dispatched,
                 "expired": self.expired,
                 "queueDepth": len(self._pending),
@@ -303,7 +296,7 @@ class MicroBatcher:
             ew = self._ewma.value
             if ew <= 0.0:
                 return 0.0
-            ahead = 1.0 if self._running else 0.0
+            ahead = 1.0 if self._in_turn else 0.0
             ahead += len(self._pending) / float(self.max_batch)
             return (ahead + 1.0) * ew
 
@@ -335,49 +328,18 @@ class MicroBatcher:
     def submit(self, item: Any,
                deadline: Optional[Deadline] = None,
                tenant=None, fn: Optional[Callable] = None) -> Any:
-        """Blocking submit: returns the result (or raises) on the
-        calling thread.  With no dispatcher running, the classic
-        leader/follower flow; with one, the caller parks as a follower
-        of the dispatcher's batches.  ``tenant``/``fn`` are the shared-
-        batcher routing fields (see :class:`SharedBatcherView`); plain
-        batchers leave them None."""
+        """Blocking submit: admits the entry as :meth:`submit_nowait`
+        does, parks the calling thread until the dispatcher's turn has
+        completed it, and returns the result (or raises) there.  It
+        keeps working after :meth:`close` — a reload swaps batchers
+        while in-flight queries still hold the old one.
+        ``tenant``/``fn`` are the shared-batcher routing fields (see
+        :class:`SharedBatcherView`); plain batchers leave them None."""
         entry = _Entry(item, deadline=deadline, tenant=tenant, fn=fn)
-        led_own = False
         with self._cond:
-            self._pending.append(entry)
-            _m_queue_depth.set(float(len(self._pending)))
-            # wake a leader/dispatcher sitting in its accumulation
-            # window (no-op for followers: they re-check and wait)
-            self._cond.notify_all()
-            while True:
-                if entry.done:
-                    break
-                if not self._running and not self._dispatcher_alive:
-                    # become the leader for everything pending now; the
-                    # turn shadows the caller's own serve timeline,
-                    # which _book_timeline below finds restored
-                    self._running = True
-                    turn = Turn()
-                    with timeline_scope(turn):
-                        with annotate("pio.turn.claim"):
-                            batch = self._claim_locked()
-                        # role bookkeeping: with > max_batch entries
-                        # ahead, the claimed batch may not include our
-                        # own entry — then we led for OTHERS and our
-                        # request is still a follower of a later batch
-                        if any(e is entry for e in batch):
-                            led_own = True
-                        try:
-                            self._lead(batch)
-                        finally:
-                            turn.finish()
-                    continue  # re-check: our entry is done (we led it)
+            self._admit_locked(entry)
+            while not entry.done:
                 self._cond.wait()
-            if led_own:
-                self.leaders += 1
-            else:
-                self.followers += 1
-        (_m_leader if led_own else _m_follower).inc()
         # credit the caller's pulse timeline with what this entry
         # actually experienced (error requests decompose too)
         self._book_timeline(entry, current_timeline())
@@ -392,35 +354,43 @@ class MicroBatcher:
         """Continuous (callback) submit: the entry is admitted into the
         pending queue immediately and ``on_done(entry)`` fires — on the
         dispatcher thread, after the entry's timeline is booked — once
-        ``entry.value``/``entry.error`` is set.  The lazily-started
-        dispatcher claims the next batch the moment the device frees
-        up, so arrivals ride the NEXT device call rather than waiting
-        out a batch boundary."""
+        ``entry.value``/``entry.error`` is set.  The dispatcher claims
+        the next batch the moment the device frees up, so arrivals ride
+        the NEXT device call rather than waiting out a batch
+        boundary."""
         entry = _Entry(item, deadline=deadline, tl=timeline,
                        on_done=on_done, tenant=tenant, fn=fn)
         with self._cond:
             if self._closed:
                 raise RuntimeError("batcher is closed")
-            if not self._dispatcher_alive:
-                self._dispatcher_alive = True
-                threading.Thread(
-                    target=self._dispatch_loop, daemon=True,
-                    name="microbatch-dispatch",
-                ).start()
-            self._pending.append(entry)
-            _m_queue_depth.set(float(len(self._pending)))
-            self._cond.notify_all()
+            self._admit_locked(entry)
+
+    def _admit_locked(self, entry: _Entry) -> None:
+        """Queue one entry and see that a dispatcher is there to claim
+        it: started lazily, and again by the first admission after one
+        has exited (closed and drained, or killed)."""
+        self._pending.append(entry)
+        _m_queue_depth.set(float(len(self._pending)))
+        self._ensure_dispatcher_locked()
+        # wake the dispatcher, parked or in its accumulation window
+        self._cond.notify_all()
+
+    def _ensure_dispatcher_locked(self) -> None:
+        if not self._dispatcher_alive:
+            self._dispatcher_alive = True
+            threading.Thread(
+                target=self._dispatch_loop, daemon=True,
+                name="microbatch-dispatch",
+            ).start()
 
     def close(self) -> None:
         """Stop accepting ``submit_nowait`` work and let the dispatcher
-        drain what is pending, then exit.  Blocking ``submit`` keeps
-        working (self-led) — a reload swaps batchers while in-flight
-        queries still hold the old one."""
+        drain what is pending, then exit."""
         with self._cond:
             self._closed = True
             self._cond.notify_all()
 
-    # -- claim/run core (shared by leaders and the dispatcher) -------------
+    # -- claim/run core (the dispatcher's turn) ----------------------------
     def _claim_locked(self) -> list[_Entry]:
         batch = self._pending[: self.max_batch]
         del self._pending[: len(batch)]
@@ -431,9 +401,8 @@ class MicroBatcher:
         return batch
 
     def _dispatch_loop(self) -> None:
-        """Standing leader for the continuous path: claims pending
-        entries whenever the device is free.  Blocking submitters
-        coalesce into its batches as followers."""
+        """The one thread that leads turns: claims pending entries
+        whenever the device is free, until closed and drained."""
         register_thread_role("microbatch_dispatcher")
         with self._cond:
             try:
@@ -441,14 +410,10 @@ class MicroBatcher:
                     turn = Turn()
                     with timeline_scope(turn):
                         with annotate("pio.turn.park"):
-                            # nothing pending, or a blocking leader
-                            # beat us to the claim and has the device
-                            while (self._running if self._pending
-                                   else not self._closed):
+                            while not self._pending and not self._closed:
                                 self._cond.wait()
                         if not self._pending:
                             break   # closed and drained
-                        self._running = True
                         with annotate("pio.turn.claim"):
                             batch = self._claim_locked()
                         try:
@@ -456,13 +421,16 @@ class MicroBatcher:
                         except Exception:
                             # _lead's finally already completed the
                             # batch; the dispatcher itself must survive
-                            # (a dead dispatcher would wedge every
-                            # future submit)
                             logger.exception("microbatch dispatcher error")
                         finally:
                             turn.finish()
             finally:
                 self._dispatcher_alive = False
+                if self._pending:
+                    # killed by a BaseException with entries still
+                    # queued: a successor claims them, or their callers
+                    # would wait for a submit that may never come
+                    self._ensure_dispatcher_locked()
                 self._cond.notify_all()
 
     def _book_timeline(self, entry: _Entry, tl) -> None:
@@ -486,20 +454,21 @@ class MicroBatcher:
         tl.add_block(parts, residual_to="device")
 
     def _lead(self, batch: list[_Entry]) -> None:
-        """Run one claimed batch on the calling thread.  Called with
-        the lock HELD; releases it around the device call (and around
+        """Run one claimed batch on the dispatcher.  Called with the
+        lock HELD; releases it around the device call (and around
         continuous-path callbacks) and re-acquires.
 
         Claim-time deadline enforcement happens here: entries already
         past their deadline are completed with ``DeadlineExceeded`` and
         never reach the device.
 
-        The ENTIRE leader turn — accumulation window included — sits
-        inside one try/finally: a BaseException landing anywhere in it
+        The ENTIRE turn — accumulation window included — sits inside
+        one try/finally: a BaseException landing anywhere in it
         (``Condition.wait`` re-acquires the lock before raising, so the
         lock state is consistent) must still mark every claimed entry
-        done and clear ``_running``, or the followers block forever and
-        every future ``submit`` hangs behind a leaderless batcher."""
+        done, or its blocking caller parks forever and its event-loop
+        request is never answered."""
+        self._in_turn = True
         completed = False
         live: list[_Entry] = []
         n_expired = 0
@@ -522,7 +491,7 @@ class MicroBatcher:
                 # near-simultaneous arrivals a chance to join this batch.
                 # Arrivals notify; absorb after EVERY wake (timeout
                 # included) so nothing queued during the window is left
-                # behind for the next leader.
+                # behind for the next turn.
                 deadline = time.monotonic() + self.max_wait_s
                 with annotate("pio.turn.park"):
                     while len(live) < self.max_batch:
@@ -551,16 +520,15 @@ class MicroBatcher:
             for e in batch:
                 if not completed and e.value is _UNSET and e.error is None:
                     # a BaseException (KeyboardInterrupt/SystemExit) tore
-                    # through the leader: _run_batch's except clause only
-                    # handles Exception, so coalesced followers would
+                    # through the turn: _exec_group's except clause only
+                    # handles Exception, so the batch's callers would
                     # otherwise wake with value=None and serve garbage.
-                    # The interrupt propagates to the leader's caller;
-                    # followers re-raise this instead.
+                    # The interrupt ends the dispatcher; they get this.
                     e.error = RuntimeError(
-                        "batch leader aborted before producing results"
+                        "batch turn aborted before producing results"
                     )
                 e.done = True
-            self._running = False
+            self._in_turn = False
             if live:
                 self.batches += 1
                 self.max_seen = max(self.max_seen, len(live))
@@ -571,8 +539,8 @@ class MicroBatcher:
                     self._turn_s = 0.0
             self.requests += len(batch)
             self.expired += n_expired
-            # continuous entries get the third role: the dispatcher ran
-            # the device call for them, no request thread led anything
+            # continuous entries: completed by callback on this thread,
+            # no request thread parked for them
             n_disp = sum(1 for e in batch if e.on_done is not None)
             if n_disp:
                 self.dispatched += n_disp
@@ -582,8 +550,8 @@ class MicroBatcher:
             # callbacks did NOT fire per-group in _run_batch: claim-time
             # deadline expiries (never executed) and anything a
             # BaseException tore past.  Inside the finally so even an
-            # aborted leader still answers every event-loop request
-            # (their entries carry the leader-abort error by now).
+            # aborted turn still answers every event-loop request
+            # (their entries carry the aborted-turn error by now).
             cbs = [e for e in batch
                    if e.on_done is not None and not e.cb_fired]
             if cbs:
@@ -635,7 +603,7 @@ class MicroBatcher:
     def _fire_callbacks(self, entries: list[_Entry]) -> None:
         """Book timelines and fire continuous-path callbacks for
         already-executed entries.  Idempotent per entry (``cb_fired``),
-        so the leader's end-of-turn sweep can still answer anything a
+        so the end-of-turn sweep can still answer anything a
         BaseException left unfired.  Must be called WITHOUT the lock —
         callbacks enqueue response bytes to the event loop."""
         with annotate("pio.turn.complete"):
@@ -666,7 +634,7 @@ class MicroBatcher:
                 n = len(items)
                 if self.pad_batches and n > 1:
                     items = items + [items[-1]] * (_pad_size(n) - n)
-                turn = current_timeline()   # the leading thread's Turn
+                turn = current_timeline()   # the dispatcher's Turn
                 turn.rows += n
                 turn.padded += len(items)
                 t0 = time.perf_counter()
@@ -704,15 +672,12 @@ class MicroBatcher:
 
 
 class SharedBatcher(MicroBatcher):
-    """ONE continuous batcher for the whole hive (pio-confluence).
+    """ONE continuous batcher for every tenant of a server.
 
-    The pio-hive design gave every tenant a private ``MicroBatcher``:
-    under mixed-tenant load, T tenants mean T dispatcher threads each
-    coalescing only 1/T of the traffic and competing for the single
-    device queue — measured as QPS@SLO(2 tenants) ~1/3 of the
-    single-tenant line on the same box.  This class keeps the exact
-    claim/run core (one pending queue, one lazily-started dispatcher,
-    leader/follower blocking path) and changes WHO gets claimed:
+    A dispatcher a tenant would mean T threads each coalescing only 1/T
+    of the traffic and competing for the single device queue.  This
+    class keeps :class:`MicroBatcher`'s claim/run core (one pending
+    queue, one lazily-started dispatcher) and changes WHO gets claimed:
 
     * **Claim-time weighted deficit round-robin across tenants.**  Each
       claim walks the tenants with pending entries in rotation order;
@@ -917,10 +882,10 @@ class SharedBatcher(MicroBatcher):
 
 
 class SharedBatcherView:
-    """One tenant's handle on the process-wide :class:`SharedBatcher`.
+    """One tenant's handle on the server's :class:`SharedBatcher`.
 
-    Exposes the exact surface the serving edges and benches already
-    use on a private ``MicroBatcher`` (``submit`` / ``submit_nowait`` /
+    Exposes the surface the serving edge and benches use on a
+    ``MicroBatcher`` (``submit`` / ``submit_nowait`` /
     ``check_admission`` / ``estimate_wait_s`` / ``stats`` /
     ``batch_fn`` / ``close``), stamping every entry with the tenant key
     and the tenant's own ``batch_fn``.  ``close()`` retires only THIS

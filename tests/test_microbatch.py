@@ -1,6 +1,6 @@
 """Serving micro-batcher (`server/microbatch.py`): correctness under
-concurrency, leader/follower coalescing, failure propagation, and the
-EngineServer auto-gating."""
+concurrency, coalescing into the dispatcher's turns, failure
+propagation, and the EngineServer auto-gating."""
 
 import concurrent.futures
 import threading
@@ -26,7 +26,7 @@ def test_concurrent_calls_coalesce():
     def batch_fn(xs):
         calls.append(len(xs))
         if len(calls) == 1:
-            gate.set()        # first (leader) batch entered
+            gate.set()        # first batch entered
             time.sleep(0.15)  # hold the "device" busy while others arrive
         return [x + 100 for x in xs]
 
@@ -100,12 +100,28 @@ def test_one_bad_item_does_not_poison_the_batch():
                 assert f.result(5) == f"ok:{x}"
 
 
+def _wait_pending(b, n, what="arrivals never queued"):
+    """Poll (deterministically) until exactly `n` entries are queued."""
+    deadline = time.time() + 10
+    while True:
+        with b._cond:
+            if len(b._pending) == n:
+                return
+        assert time.time() < deadline, what
+        time.sleep(0.002)
+
+
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
 def test_base_exception_fails_followers_not_none():
-    """A BaseException (KeyboardInterrupt) tearing through the leader
-    must surface as an ERROR to coalesced followers — not as a silent
-    value=None result that downstream serving would treat as a
-    prediction (ADVICE r4)."""
+    """A BaseException (KeyboardInterrupt) tearing through the
+    dispatcher's turn must surface as an ERROR to every caller whose
+    entry the turn had claimed — not as a silent value=None result that
+    downstream serving would treat as a prediction (ADVICE r4).  The
+    dispatcher dies of it; an entry still queued at that moment is
+    claimed by a successor, and the next submit works."""
     started, release = threading.Event(), threading.Event()
+    doomed_entered, doomed_release = threading.Event(), threading.Event()
     calls = []
 
     def batch_fn(xs):
@@ -113,8 +129,9 @@ def test_base_exception_fails_followers_not_none():
         if len(calls) == 1:  # hold the device so arrivals coalesce
             started.set()
             release.wait(5)
-            return [f"ok:{x}" for x in xs]
-        if len(calls) == 2:  # the coalesced batch's leader is killed
+        if len(calls) == 2:  # the coalesced batch's turn is killed
+            doomed_entered.set()
+            doomed_release.wait(5)
             raise KeyboardInterrupt
         return [f"ok:{x}" for x in xs]
 
@@ -123,17 +140,15 @@ def test_base_exception_fails_followers_not_none():
         f0 = ex.submit(b.submit, 0)
         assert started.wait(5)
         futs = [ex.submit(b.submit, i) for i in (1, 2)]
-        # wait (deterministically) until both are queued behind the
-        # in-flight batch, so one will lead the other as a follower
-        deadline = time.time() + 5
-        while True:
-            with b._cond:
-                if len(b._pending) == 2:
-                    break
-            assert time.time() < deadline, "arrivals never queued"
-            time.sleep(0.005)
+        # both queued behind the in-flight batch: ONE turn claims them
+        _wait_pending(b, 2)
         release.set()
         assert f0.result(5) == "ok:0"
+        # a third arrives while the doomed turn holds the device
+        assert doomed_entered.wait(5)
+        f3 = ex.submit(b.submit, 3)
+        _wait_pending(b, 1)
+        doomed_release.set()
         excs = []
         for f in futs:
             try:
@@ -141,14 +156,13 @@ def test_base_exception_fails_followers_not_none():
                 excs.append(None)
             except BaseException as e:  # noqa: BLE001 — the assertion
                 excs.append(e)
-    # the leader re-raises the interrupt; the follower gets a loud
-    # error, never a None result
-    assert None not in excs
-    kinds = {type(e) for e in excs}
-    assert KeyboardInterrupt in kinds
-    for e in excs:
-        if isinstance(e, RuntimeError):
-            assert "aborted" in str(e)
+        # stranded behind a dead dispatcher it would never return
+        assert f3.result(5) == "ok:3"
+    # the interrupt ends the dispatcher, on its own thread; both
+    # waiters get a loud error, never a None result
+    assert calls[1] == 2
+    assert [type(e) for e in excs] == [RuntimeError, RuntimeError]
+    assert all("aborted" in str(e) for e in excs)
     # the batcher recovers
     assert b.submit(9) == "ok:9"
 
@@ -162,7 +176,7 @@ def test_length_mismatch_is_an_error():
 
 
 def test_accumulation_window():
-    """The window must ABSORB arrivals into the leader's own batch (a
+    """The window must ABSORB arrivals into the open turn's batch (a
     previous version slept the full window and then dispatched without
     them — pure added latency)."""
     sizes = []
@@ -175,18 +189,18 @@ def test_accumulation_window():
     with concurrent.futures.ThreadPoolExecutor(8) as ex:
         assert sorted(ex.map(b.submit, range(8))) == list(range(8))
     # the FIRST batch (the only one whose window was open while the
-    # other submits raced in) picked up followers
+    # other submits raced in) picked up the arrivals
     assert sizes[0] > 1
     # a full batch short-circuits the window: all 8 in <= 2 batches
     assert len(sizes) <= 2
 
 
 def test_barrier_driven_coalescing_and_padded_slicing():
-    """Deterministic leader/follower drill (pio-pulse): the first
-    leader is parked on an event while 7 more submits queue behind it;
-    on release, exactly ONE follower-batch forms with all 7 entries,
-    the padding rounds it to 8, and every caller gets ITS OWN result
-    sliced back out of the padded batch."""
+    """Deterministic coalescing drill (pio-pulse): the dispatcher's
+    first turn is parked on an event while 7 more blocking submits
+    queue behind it; on release, exactly ONE more batch forms with all
+    7 entries, the padding rounds it to 8, and every caller gets ITS
+    OWN result sliced back out of the padded batch."""
     first_entered = threading.Event()
     release = threading.Event()
     seen_sizes = []
@@ -203,28 +217,23 @@ def test_barrier_driven_coalescing_and_padded_slicing():
         f0 = ex.submit(b.submit, 1)
         assert first_entered.wait(10)
         rest = [ex.submit(b.submit, x) for x in range(2, 9)]
-        # deterministic: wait until ALL 7 are parked behind the leader
-        deadline = time.time() + 10
-        while True:
-            with b._cond:
-                if len(b._pending) == 7:
-                    break
-            assert time.time() < deadline, "arrivals never queued"
-            time.sleep(0.002)
+        # deterministic: wait until ALL 7 are parked behind the turn
+        _wait_pending(b, 7)
         release.set()
         assert f0.result(10) == 10
         assert [f.result(10) for f in rest] == [
             x * 10 for x in range(2, 9)
         ]
-    # batch 1: the solo leader (no padding at n=1); batch 2: the 7
+    # batch 1: the lone first entry (no padding at n=1); batch 2: the 7
     # coalesced entries padded to 8 — results sliced back to 7
     assert seen_sizes == [1, 8]
     stats = b.stats()
     assert stats["batches"] == 2
     assert stats["requests"] == 8
     assert stats["maxBatchSeen"] == 7  # pre-padding coalesced size
-    assert stats["leaders"] == 2
-    assert stats["followers"] == 6
+    # blocking callers park; none of them is a callback entry
+    assert stats["dispatched"] == 0
+    assert stats["dispatcher"] is True
     assert stats["queueDepth"] == 0
 
 
@@ -251,7 +260,7 @@ def test_submit_books_timeline_segments():
 
 
 def test_stats_snapshot_is_consistent_under_concurrency():
-    """stats() reads under the lock: batches/requests/roles move
+    """stats() reads under the lock: batches/requests/dispatched move
     together — a torn read (requests advanced, batches not) can never
     be observed through the snapshot."""
     def batch_fn(xs):
@@ -261,33 +270,44 @@ def test_stats_snapshot_is_consistent_under_concurrency():
     b = MicroBatcher(batch_fn, max_batch=8)
     stop = threading.Event()
     torn = []
+    called_back = []
 
     def reader():
         while not stop.is_set():
             s = b.stats()
-            # every counted batch contributes >= 1 request, and roles
-            # are booked once per finished submit
+            # every counted batch contributes >= 1 request, and a
+            # callback entry is booked with its batch's requests
             if s["batches"] > s["requests"]:
                 torn.append(s)
-            if s["leaders"] + s["followers"] > s["requests"]:
+            if s["dispatched"] > s["requests"]:
                 torn.append(s)
 
     r = threading.Thread(target=reader)
     r.start()
     with concurrent.futures.ThreadPoolExecutor(8) as ex:
-        assert sorted(ex.map(b.submit, range(200))) == list(range(200))
+        # half blocking, half by callback, interleaved
+        blocking = [ex.submit(b.submit, x) for x in range(0, 200, 2)]
+        for x in range(1, 200, 2):
+            b.submit_nowait(x, lambda e: called_back.append(e.value))
+        assert [f.result(10) for f in blocking] == list(range(0, 200, 2))
+    deadline = time.time() + 10
+    while len(called_back) < 100 and time.time() < deadline:
+        time.sleep(0.002)
     stop.set()
     r.join(5)
+    assert not r.is_alive()
     assert torn == []
+    assert sorted(called_back) == list(range(1, 200, 2))
     final = b.stats()
     assert final["requests"] == 200
-    assert final["leaders"] + final["followers"] == 200
+    assert final["dispatched"] == 100
+    assert final["batches"] <= 200
 
 
 def test_engine_server_auto_gating(storage_memory):
     """"auto" batches only when every algorithm has a REAL
     batch_predict; the base-class fallback would serialize inside the
-    leader for no gain."""
+    turn for no gain."""
     from predictionio_tpu.controller.base import (
         Algorithm, DataSource, WorkflowContext,
     )
@@ -357,13 +377,7 @@ def test_mid_batch_admission_rides_next_device_call():
     # batch and form the NEXT one together
     b.submit_nowait(2, lambda e: done.append(("b", e.value)))
     b.submit_nowait(3, lambda e: done.append(("c", e.value)))
-    deadline = time.time() + 10
-    while True:
-        with b._cond:
-            if len(b._pending) == 2:
-                break
-        assert time.time() < deadline, "arrivals never queued"
-        time.sleep(0.002)
+    _wait_pending(b, 2)
     release.set()
     deadline = time.time() + 10
     while len(done) < 3 and time.time() < deadline:
@@ -488,16 +502,25 @@ def test_submit_nowait_after_close_raises_and_blocking_still_works():
     b.close()
     with pytest.raises(RuntimeError, match="closed"):
         b.submit_nowait(3, lambda e: None)
-    # blocking submit degrades to self-led batches after close
+    # a blocking submit still works after close (a reload swaps
+    # batchers under in-flight queries): the drained dispatcher has
+    # exited, the submit starts another, which answers and exits too
     deadline = time.time() + 10
     while b.stats()["dispatcher"] and time.time() < deadline:
         time.sleep(0.005)
+    assert b.stats()["dispatcher"] is False
     assert b.submit(9) == 10
+    assert b.submit(10) == 11
+    deadline = time.time() + 10
+    while b.stats()["dispatcher"] and time.time() < deadline:
+        time.sleep(0.005)
+    assert b.stats()["dispatcher"] is False
+    assert b.stats()["requests"] == 3
 
 
 def test_mixed_blocking_and_continuous_coalesce():
-    """Blocking submitters coalesce into the dispatcher's batches as
-    followers once a dispatcher owns the queue."""
+    """Blocking submitters and callback entries share the dispatcher's
+    batches."""
     first_entered = threading.Event()
     release = threading.Event()
     sizes = []
@@ -515,21 +538,16 @@ def test_mixed_blocking_and_continuous_coalesce():
     assert first_entered.wait(10)
     with concurrent.futures.ThreadPoolExecutor(2) as ex:
         blocking = [ex.submit(b.submit, x) for x in (2, 3)]
-        deadline = time.time() + 10
-        while True:
-            with b._cond:
-                if len(b._pending) == 2:
-                    break
-            assert time.time() < deadline
-            time.sleep(0.002)
+        _wait_pending(b, 2)
         release.set()
         assert sorted(f.result(10) for f in blocking) == [4, 6]
     assert async_done == [2]
     stats = b.stats()
     assert stats["requests"] == 3
+    assert stats["batches"] == 2
     # the two blocking entries ran inside the dispatcher's second batch
     assert sizes == [1, 2]
-    assert stats["followers"] == 2
+    assert stats["dispatched"] == 1   # the callback entry alone
     b.close()
 
 
@@ -616,7 +634,10 @@ def test_finer_steps_of_the_engine_come_out_of_fetch():
     assert "dispatch" in wall
 
 
-def test_blocking_leader_turn_shadows_and_restores_the_serve_timeline():
+def test_blocking_submit_timeline_is_booked_from_the_dispatchers_turn():
+    """A blocking submit's serve timeline holds its own segments only,
+    booked from the stamps of the dispatcher's turn, and names that
+    turn."""
     from predictionio_tpu.obs.timeline import (
         Timeline, current_timeline, timeline_scope,
     )
@@ -630,64 +651,38 @@ def test_blocking_leader_turn_shadows_and_restores_the_serve_timeline():
     with timeline_scope(tl):
         assert b.submit(7) == 7
         assert current_timeline() is tl
-    # the request's own timeline holds its own segments only, and the
-    # number of the turn that ran it
     assert set(tl.segments) == {"queue_wait", "batch_wait", "device"}
+    assert tl.segments["device"] >= 0.01
     rec = _turn(tl.turn)
     assert rec["rows"] == 1 and rec["wall"]["fetch"] >= 0.01
-    assert "park" not in rec["wall"]    # a leader never waits for work
+    assert "park" in rec["wall"]    # the dispatcher waited for the work
+    b.close()
 
 
-def test_served_request_names_its_turn(storage_memory):
-    """Over HTTP on the continuous path: the request's `serve.query`
-    span (and its flight record) carries `batchTurn`, the number of a
-    turn in the deque whose rows include it."""
-    import json
-    import urllib.request
+def test_blocking_submit_runs_on_the_dispatcher_thread():
+    """ONE thread leads turns: a blocking submit, alone or among
+    others, is executed on the thread named ``microbatch-dispatch`` and
+    never on a caller's (at the parent a caller with no dispatcher
+    alive led its own batch)."""
+    ran_on = []
 
-    from predictionio_tpu.controller.base import (
-        Algorithm, DataSource, WorkflowContext,
-    )
-    from predictionio_tpu.controller.engine import SimpleEngine
-    from predictionio_tpu.obs import get_flight_recorder, get_tracer
-    from predictionio_tpu.server.serving import EngineServer, ServerConfig
-    from predictionio_tpu.workflow.train import run_train
+    def batch_fn(xs):
+        ran_on.append(threading.current_thread())
+        time.sleep(0.002)
+        return [x + 1 for x in xs]
 
-    class DS(DataSource):
-        def read_training(self, ctx):
-            return 1
+    b = MicroBatcher(batch_fn, max_batch=4)
+    assert b.submit(0) == 1
+    callers = []
 
-    class BatchedAlgo(Algorithm):
-        def train(self, ctx, data):
-            return {"w": 2}
+    def call(x):
+        callers.append(threading.current_thread())
+        return b.submit(x)
 
-        def predict(self, model, query):
-            return {"y": model["w"] * query.get("x", 0)}
-
-        def batch_predict(self, model, queries):
-            return [self.predict(model, q) for q in queries]
-
-    ctx = WorkflowContext(storage=storage_memory)
-    engine = SimpleEngine(DS, BatchedAlgo)
-    ep = engine.params_from_variant({})
-    iid = run_train(engine, ep, ctx=ctx)
-    srv = EngineServer(engine, ep, iid, ctx=ctx, config=ServerConfig(port=0))
-    srv.start_background()
-    try:
-        tid = "t-turn-http"
-        req = urllib.request.Request(
-            f"http://127.0.0.1:{srv.config.port}/queries.json",
-            data=b'{"x": 4}', method="POST",
-            headers={"Content-Type": "application/json",
-                     "X-PIO-Trace": tid},
-        )
-        with urllib.request.urlopen(req, timeout=15) as r:
-            assert json.loads(r.read().decode()) == {"y": 8}
-    finally:
-        srv.stop()
-    (span,) = get_tracer().spans(trace_id=tid, name="serve.query")
-    rec = _turn(span.attrs["batchTurn"])
-    assert rec["rows"] >= 1
-    flight = get_flight_recorder().record_for(tid)
-    if flight is not None:  # may be evicted by slower suite traffic
-        assert flight["attrs"]["batchTurn"] == span.attrs["batchTurn"]
+    with concurrent.futures.ThreadPoolExecutor(8) as ex:
+        assert sorted(ex.map(call, range(32))) == list(range(1, 33))
+    assert {t.name for t in ran_on} == {"microbatch-dispatch"}
+    assert len(set(ran_on)) == 1    # and it is one thread throughout
+    assert not set(ran_on) & ({threading.current_thread()} | set(callers))
+    assert b.stats()["requests"] == 33
+    b.close()

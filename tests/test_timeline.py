@@ -418,8 +418,8 @@ def test_status_json_microbatch_uses_locked_snapshot(storage_memory):
     srv = _tiny_server(storage_memory)
     srv.predict_json({"x": 1})
     mb = srv.status_json()["microbatch"]
-    assert {"batches", "requests", "maxBatchSeen", "leaders",
-            "followers", "queueDepth"} <= set(mb)
+    assert {"batches", "requests", "maxBatchSeen", "dispatched",
+            "queueDepth"} <= set(mb)
     assert mb["requests"] >= 1
     assert mb["queueDepth"] == 0
 

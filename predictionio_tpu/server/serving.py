@@ -664,8 +664,9 @@ class EngineServer(HTTPServerBase):
                 logger.exception("autopilot tick failed")
 
     def _make_batcher(self, algorithms, models, tenant=None):
-        """Build the query micro-batcher for this (algorithms, models)
-        snapshot — or None when batching can't help.
+        """This (algorithms, models) snapshot's view on the server's
+        one shared batcher — or None when batching can't help: the one
+        batched path and the one unbatched.
 
         Concurrent requests each dispatching their own device call
         serialize on the single TPU execution queue (measured:
@@ -675,8 +676,11 @@ class EngineServer(HTTPServerBase):
         into one [B]-wide device call makes concurrency wider instead
         of deeper — see server/microbatch.py.  The base-class
         ``batch_predict`` just maps ``predict``, which would serialize
-        *inside* the leader's batch for no gain, so "auto" only
-        batches genuinely batched algorithms.
+        *inside* the dispatcher's turn for no gain, so "auto" only
+        batches genuinely batched algorithms.  Whatever the dispatcher
+        claims goes to ``batch_predict``, a lone request as a batch of
+        one (on the chip the one-row batch is the faster program:
+        3.3 ms against 11-12 for a ``[M]`` matvec and a full sort).
         """
         from ..controller.base import Algorithm
         from .microbatch import SharedBatcher, SharedBatcherView
@@ -691,18 +695,6 @@ class EngineServer(HTTPServerBase):
             return None
 
         def batch_fn(queries):
-            if len(queries) == 1:
-                # solo batches ride the scalar predict path: the [1, M]
-                # batched executable is measurably SLOWER than the [M]
-                # matvec one (CPU: 5.2 ms vs 1.5 ms at M=100k, R=64 —
-                # a batched row top-k pays layout overhead a vector
-                # top-k doesn't), and under no concurrency every batch
-                # is solo
-                q = queries[0]
-                return [[
-                    algo.predict(model, q)
-                    for algo, model in zip(algorithms, models)
-                ]]
             per_algo = [
                 algo.batch_predict(model, queries)
                 for algo, model in zip(algorithms, models)

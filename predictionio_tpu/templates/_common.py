@@ -394,52 +394,48 @@ def pow2_ladder(max_batch: int) -> list[int]:
 def warm_batched_topk(table, rank: int, n: int,
                       unmasked_too: bool = False,
                       max_batch: int = 64,
-                      table_t=None,
-                      solo_too: bool = False) -> None:
-    """Pre-compile the pow2 batched top-k shapes the serving
-    micro-batcher dispatches (server/microbatch.py pads batches to
-    powers of two; templates round k to pow2): EVERY B in
-    ``pow2_ladder(max_batch)`` at the pow2-rounded default num.  Every
-    pow2 rung, not a subset — a size the padding can produce but the
-    warmup skipped compiles on first exposure mid-traffic, which is
-    exactly the p99 spike the padding exists to avoid (ADVICE r4).
-    ``max_batch <= 0`` (no batcher: the per-query predict path serves
-    everything) skips the batched warms entirely — they would compile
-    executables nothing dispatches.
+                      table_t=None) -> None:
+    """Pre-compile the batched top-k shapes serving dispatches
+    (server/microbatch.py pads batches to powers of two; templates round
+    k to pow2): EVERY B in ``pow2_ladder(max_batch)`` at the
+    pow2-rounded default num, and one row at the small k's — a lone
+    request is a batch of one.  Every pow2 rung, not a subset — a size
+    the padding can produce but the warmup skipped compiles on first
+    exposure mid-traffic, which is exactly the p99 spike the padding
+    exists to avoid (ADVICE r4).
 
     With `table_t` (what the caller's batch path hands
     ``ops.topk.batch_topk_scores_t``: its ``device_item_tables``) the
     filtered rungs carry excluded ids, at every width of
     ``ops.topk.EXCLUDE_LADDER``; each rung compiles the path, blocked or
-    dense, that its shapes will take under traffic.  `solo_too` adds the
-    one-row rungs (the default num and the small k's) for an engine whose
-    lone request is a one-row batch (similarproduct); where a lone
-    request rides ``predict`` (recommendation: the batcher's `batch_fn`
-    sends a batch of one there) nothing dispatches them, and each is an
-    executable more to load before the server is ready.  The ``[B, M]`` masked form of that scorer (`categories`,
-    a `whiteList`) is not warmed: its rungs each shipped a ``[B, M]``
-    array of zeros, 2.4 GB at 64 rows over 9.4 M items, and set the
-    server's peak memory.  Without `table_t` it is the classic
-    ``[M, R]`` scorer under a ``[B, M]`` mask, for the templates whose
-    every batch is still masked (itemsimilarity, ecommerce)."""
+    dense, that its shapes will take under traffic.  These engines'
+    ``predict`` is a one-row ``batch_predict``, so ``max_batch <= 0``
+    (no batcher) still warms the one-row rungs.  The ``[B, M]`` masked
+    form of that scorer (`categories`, a `whiteList`) is not warmed: its
+    rungs each shipped a ``[B, M]`` array of zeros, 2.4 GB at 64 rows
+    over 9.4 M items, and set the server's peak memory.  Without
+    `table_t` it is the classic ``[M, R]`` scorer under a ``[B, M]``
+    mask, for the templates whose every batch is still masked and whose
+    ``predict`` is a scorer of its own (itemsimilarity, ecommerce): with
+    no batcher nothing dispatches these, and nothing is compiled."""
     from ..ops.topk import (
         EXCLUDE_LADDER, batch_topk_scores, batch_topk_scores_t, pow2_ceil,
     )
 
     ladder = pow2_ladder(max_batch)
     if not ladder:
-        return
+        if table_t is None:
+            return
+        ladder = [1]
     k_default = min(pow2_ceil(10), n)
-    if table_t is None:
-        for b in ladder:
-            batch_topk_scores(np.zeros((b, rank), np.float32), table,
-                              k_default, mask=np.zeros((b, n), np.float32))
-        return
-    shapes = [(b, k_default) for b in ladder if b > 1 or solo_too]
-    if solo_too:
-        shapes += [(1, k) for k in {min(pow2_ceil(k), n) for k in (1, 4)}]
+    shapes = [(b, k_default) for b in ladder]
+    shapes += [(1, k) for k in {min(pow2_ceil(k), n) for k in (1, 4)}]
     for b, k in shapes:
         vecs = np.zeros((b, rank), np.float32)
+        if table_t is None:
+            batch_topk_scores(vecs, table, k,
+                              mask=np.zeros((b, n), np.float32))
+            continue
         # the keyword arguments as `batch_predict` passes them: they are
         # part of the compiled call's key
         filters = [BatchFilter("none")] if unmasked_too else []

@@ -39,14 +39,12 @@ from ..ops.topk import (
     batch_topk_scores_t,
     pow2_ceil,
     topk_path,
-    topk_scores,
 )
 from ..storage.columnar import Ratings
 from ._common import (
     DeviceTableMixin,
     RowFilter,
     batch_filter,
-    filter_bias_mask,
     pow2_ladder,
     warm_batched_topk,
 )
@@ -550,25 +548,12 @@ class ALSAlgorithm(Algorithm):
         )
 
     # -- serving ----------------------------------------------------------
-    def _allowed_mask(self, model: ALSModel, query: Query) -> Optional[np.ndarray]:
-        """-inf additive mask for filtered-out items (filter-by-category /
-        whitelist / blacklist variants); None when the query has no
-        filters so the unbiased scorer executable is dispatched."""
-        return filter_bias_mask(
-            model.items, model.item_props,
-            categories=query.categories, whitelist=query.whitelist,
-            blacklist=query.blacklist or (), none_if_empty=True,
-        )
-
     def warmup(self, model: ALSModel, max_batch: int = 64) -> None:
-        """Compile the top-k scorers for the common ``num`` values (the
-        static k arg keys the executable) before the first real query.
-
-        Also pre-compiles BATCHED scorers: with the serving
-        micro-batcher on (the default), EVERY request — solo ones
-        included — routes through :meth:`batch_predict`, whose
+        """Compile the batched top-k scorers before the first real
+        query.  Every request routes through :meth:`batch_predict` — a
+        lone one as a batch of one, with or without a batcher — whose
         executable key space is bounded to (pow2 B) x (pow2 k) x
-        (masked?) by the shape-stability contract there.  This warms
+        (filter) by the shape-stability contract there.  This warms
         every pow2 B the batcher's padding can dispatch up to
         ``max_batch`` at the pow2-rounded default num (k=16) plus the
         small-k sizes at B=1; remaining shapes compile once under load
@@ -578,97 +563,45 @@ class ALSAlgorithm(Algorithm):
             return
         table = model.device_item_factors(self._serve_dtype())
         rank = model.item_factors.shape[1]
-        vec = np.zeros(rank, np.float32)
-        bias = np.zeros(n, np.float32)
-        for k in {min(k, n) for k in (1, 4, 10, 20)}:
-            topk_scores(vec, table, k)
-            topk_scores(vec, table, k, bias=bias)
         warm_batched_topk(
             table, rank, n, unmasked_too=True, max_batch=max_batch,
             table_t=model.device_item_tables(self._serve_dtype()),
         )
+        # the other two scorers `batch_predict` may choose warm the
+        # same shapes as warm_batched_topk: every pow2 batch at the
+        # default num, one row at the small k's (a size the padding can
+        # produce but the warmup skipped compiles mid-traffic, which is
+        # the p99 spike the ladder prevents)
+        ladder = pow2_ladder(max_batch) or [1]
+        k_default = min(pow2_ceil(10), n)
+        small_ks = {min(pow2_ceil(k), n) for k in (1, 4)}
         rcfg = self._retrieval_config()
         if rcfg is not None and not getattr(self.params,
                                             "distributed_topk", False):
-            # pio-scout: the two-stage path joins the warmup ladder —
-            # candidate + rerank executables for every pow2 batch the
-            # padded batcher can dispatch, plus the solo small-k
-            # shapes (same contract as warm_batched_topk: a size the
-            # padding can produce but the warmup skipped compiles
-            # mid-traffic, which is the p99 spike the ladder prevents)
+            # pio-scout: the candidate + rerank executables
             idx = model.device_ann_index(rcfg)
-            ladder = pow2_ladder(max_batch) or []
-            k_default = min(pow2_ceil(10), n)
-            idx.warm(k_default, ladder + [1], table)
-            for k in {min(pow2_ceil(kk), n) for kk in (1, 4)}:
+            idx.warm(k_default, ladder, table)
+            for k in small_ks:
                 idx.warm(k, [1], table)
         if getattr(self.params, "distributed_topk", False):
             # the ring index compiles BOTH variants (clean + parity-
             # coded; + the quantized candidate variant under
-            # retrieval != exact) per (batch, k).  Warm the two shapes
-            # serving dispatches, like the local ladder above: solo
-            # queries ride predict (the query's own k, batch 1),
-            # coalesced ones ride batch_predict (pow2 k, every pow2 B
-            # the padded batcher can dispatch) — so neither a first
+            # retrieval != exact) per (batch, k) — so neither a first
             # degradation nor a first burst pays a mid-request compile
             idx = self._sharded_index(model)
-            for k in {min(k, n) for k in (1, 4, 10, 20)}:
+            for b in ladder:
+                idx.warm(k_default, batch=b)
+            for k in small_ks:
                 idx.warm(k, batch=1)
-            for b in pow2_ladder(max_batch):
-                idx.warm(min(pow2_ceil(10), n), batch=b)
 
     def predict(self, model: ALSModel, query: Query) -> PredictedResult:
-        uix = model.users.get(query.user)
-        if uix < 0 or query.num <= 0:
-            return PredictedResult(item_scores=())
-        k = min(query.num, len(model.items))
-        mask = self._allowed_mask(model, query)
-        if (
-            mask is None
-            and getattr(self.params, "distributed_topk", False)
-        ):
-            # ring top-k over the mesh-sharded item table; the request
-            # Deadline in scope becomes the per-shard hop budget, and a
-            # late shard is served from parity (pio-armor)
-            vals2, ixs2 = self._sharded_index(model)(
-                np.asarray(model.user_factors[uix])[None, :], k
-            )
-            return PredictedResult(
-                item_scores=decode_item_scores(
-                    model.items, np.asarray(vals2)[0], np.asarray(ixs2)[0]
-                )
-            )
-        rcfg = self._retrieval_config()
-        if mask is None and rcfg is not None:
-            # pio-scout: quantized candidate shortlist -> exact f32
-            # rerank.  Filtered queries stay on the exact scorer above
-            # (a -inf mask over a shortlist can starve results below
-            # num; the exact path's mask contract is already right).
-            vals2, ixs2 = model.device_ann_index(rcfg).search(
-                np.asarray(model.user_factors[uix])[None, :], k,
-                model.device_item_factors(self._serve_dtype()),
-            )
-            return PredictedResult(
-                item_scores=decode_item_scores(
-                    model.items, np.asarray(vals2)[0], np.asarray(ixs2)[0]
-                )
-            )
-        table = model.device_item_factors(self._serve_dtype())
-        if mask is None:
-            vals, ixs = topk_scores(
-                np.asarray(model.user_factors[uix]), table, k
-            )
-        else:
-            vals, ixs = topk_scores(
-                np.asarray(model.user_factors[uix]), table, k, bias=mask,
-            )
-        return PredictedResult(
-            item_scores=decode_item_scores(model.items, vals, ixs)
-        )
+        """A lone request is a one-row batch: the same device program,
+        the same filters as data."""
+        return self.batch_predict(model, [query])[0]
 
     def batch_predict(self, model: ALSModel, queries: Sequence[Query]):
-        """Eval + micro-batched serving path: ONE batched matmul for all
-        queries, honoring the same per-query filters as :meth:`predict`.
+        """THE scoring path (serving, batched or lone, and eval): ONE
+        batched scorer call for all queries, each under its own filters.
 
         Shape stability contract: the device call's batch size is
         ``len(queries)`` regardless of how many queries are valid —
@@ -708,9 +641,8 @@ class ALSAlgorithm(Algorithm):
             rcfg = self._retrieval_config()
         if unfiltered and getattr(self.params, "distributed_topk",
                                   False):
-            # the micro-batched serving path rides the same parity-coded
-            # ring as solo predict (the ring takes a [B, R] query block
-            # natively); per-query masks keep the local scorer below
+            # the parity-coded ring takes a [B, R] query block
+            # natively; per-query filters keep the local scorer below
             vals, ixs = self._sharded_index(model)(uvecs, k)
         elif unfiltered and rcfg is not None:
             # pio-scout two-stage: the batched serving path is exactly

@@ -289,7 +289,7 @@ class SimilarProductAlgorithm(Algorithm):
             return
         warm_batched_topk(
             None, model.item_factors.shape[1], n, max_batch=max_batch,
-            table_t=model.device_item_tables(), solo_too=True,
+            table_t=model.device_item_tables(),
         )
 
     @staticmethod

@@ -78,6 +78,41 @@ def test_queries_json(deployed):
     assert scores == sorted(scores, reverse=True)
 
 
+def test_lone_query_is_a_batch_of_one(deployed, monkeypatch):
+    """One route for a lone request: over HTTP, with nothing else in
+    flight, it reaches `batch_predict` as a batch of one, on the
+    dispatcher's thread, and never `predict` (at the parent the
+    batcher's batch function sent a claimed batch of one there)."""
+    import threading
+
+    from predictionio_tpu.templates.recommendation import ALSAlgorithm
+
+    server, *_ = deployed
+    calls = []
+    real_batch = ALSAlgorithm.batch_predict
+
+    def batch_predict(self, model, queries):
+        calls.append(("batch_predict", len(queries),
+                      threading.current_thread().name))
+        return real_batch(self, model, queries)
+
+    def predict(self, model, query):
+        calls.append(("predict", 1, threading.current_thread().name))
+        raise AssertionError("a lone request must not reach predict")
+
+    monkeypatch.setattr(ALSAlgorithm, "batch_predict", batch_predict)
+    monkeypatch.setattr(ALSAlgorithm, "predict", predict)
+    base = f"http://127.0.0.1:{server.config.port}"
+    for num in (3, 1):
+        status, body = _post(f"{base}/queries.json",
+                             {"user": "u1", "num": num})
+        assert status == 200 and len(body["itemScores"]) == num
+    # an unknown user's empty answer takes the same route
+    status, body = _post(f"{base}/queries.json", {"user": "ghost"})
+    assert status == 200 and body["itemScores"] == []
+    assert calls == [("batch_predict", 1, "microbatch-dispatch")] * 3
+
+
 def test_unknown_user_empty_scores(deployed):
     server, *_ = deployed
     base = f"http://127.0.0.1:{server.config.port}"

@@ -209,8 +209,9 @@ def main(argv=None) -> int:
         # k is a static arg of the top-k scorers: num=2 then num=3
         # (pow2: k=2 -> 4) is the classic mid-traffic shape churn
         _code, before = _get(f"{base}/metrics")
+        scorer = "topk.batch_topk_scores_t"
         n_before = _metric_value(
-            before, "pio_jit_compiles_total", fn="topk.topk_scores"
+            before, "pio_jit_compiles_total", fn=scorer
         )
         for k in range(12):
             num = 2 if k < 6 else 3
@@ -221,7 +222,7 @@ def main(argv=None) -> int:
             assert code == 200 and len(body["itemScores"]) == num
         _code, after = _get(f"{base}/metrics")
         n_after = _metric_value(
-            after, "pio_jit_compiles_total", fn="topk.topk_scores"
+            after, "pio_jit_compiles_total", fn=scorer
         )
         invariants["metrics_compile_counter_incremented"] = (
             n_after >= n_before + 1
@@ -235,7 +236,7 @@ def main(argv=None) -> int:
         invariants["recompile_ring_parseable"] = isinstance(ring, list)
         forced = [
             e for e in ring
-            if e["fn"] == "topk.topk_scores" and e["kind"] == "recompile"
+            if e["fn"] == scorer and e["kind"] == "recompile"
         ]
         deltas_ok = False
         for e in forced:

@@ -172,6 +172,10 @@ CFG = AutopilotConfig(min_samples=50, max_step=0.1, min_weight=0.05)
 
 
 def _pilot(reg, tmp_path, cfg=CFG, **kw):
+    # the default guardrail input is the process-wide pio_slo_burn_rate
+    # gauge, which any server test of the same worker may have left
+    # burning: a test that is not about the guardrail reads a calm one
+    kw.setdefault("burn_rate_fn", lambda: 0.0)
     return AutoPilot(reg, config=cfg, manifest_id="t-pilot", **kw)
 
 

@@ -1077,7 +1077,6 @@ class EngineServer(HTTPServerBase):
         It owns the request's timeline; the batcher finds it through
         the thread-local scope and credits queue/batch/device waits."""
         tl = timeline.Timeline("serve")
-        t0 = time.perf_counter()
         _m_inflight.inc()
         ctx = None
         try:
@@ -1106,7 +1105,7 @@ class EngineServer(HTTPServerBase):
                         ]
                         tl.mark("device")
                     out = self._query_finish(
-                        ctx, predictions, tl, t0, query_json
+                        ctx, predictions, tl, tl.t0, query_json
                     )
         except BaseException as e:
             # _query_setup completes its own lease on setup failures;

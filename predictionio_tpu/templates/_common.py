@@ -12,7 +12,7 @@ from ..obs.timeline import annotate
 
 __all__ = ["BatchFilter", "DeviceTableMixin", "RowFilter", "batch_filter",
            "filter_bias_mask", "normalize_rows", "pow2_ladder",
-           "warm_batched_topk"]
+           "warm_batched_topk", "warm_shapes"]
 
 _registry = get_registry()
 FILTER_ROWS = _registry.counter(
@@ -391,46 +391,50 @@ def pow2_ladder(max_batch: int) -> list[int]:
     return dispatchable_sizes(max_batch)
 
 
+def warm_shapes(max_batch: int, n: int) -> list:
+    """The ``(B, k)`` shapes a warm-up compiles: EVERY B in
+    ``pow2_ladder(max_batch)`` at the pow2-rounded default num — every
+    rung, not a subset: a size the padding can produce but the warmup
+    skipped compiles on first exposure mid-traffic, which is exactly
+    the p99 spike the padding exists to avoid (ADVICE r4) — and one row
+    at the small k's, for a lone request, which is a batch of one.
+    ``max_batch <= 0`` (no batcher) leaves the one-row shapes: what an
+    engine whose ``predict`` is a one-row ``batch_predict`` still
+    dispatches."""
+    from ..ops.topk import pow2_ceil
+
+    ladder = pow2_ladder(max_batch) or [1]
+    shapes = [(b, min(pow2_ceil(10), n)) for b in ladder]
+    return shapes + [(1, k) for k in {min(pow2_ceil(k), n) for k in (1, 4)}]
+
+
 def warm_batched_topk(table, rank: int, n: int,
                       unmasked_too: bool = False,
                       max_batch: int = 64,
                       table_t=None) -> None:
-    """Pre-compile the batched top-k shapes serving dispatches
-    (server/microbatch.py pads batches to powers of two; templates round
-    k to pow2): EVERY B in ``pow2_ladder(max_batch)`` at the
-    pow2-rounded default num, and one row at the small k's — a lone
-    request is a batch of one.  Every pow2 rung, not a subset — a size
-    the padding can produce but the warmup skipped compiles on first
-    exposure mid-traffic, which is exactly the p99 spike the padding
-    exists to avoid (ADVICE r4).
+    """Pre-compile the batched top-k scorer at the shapes serving
+    dispatches (:func:`warm_shapes`: server/microbatch.py pads batches
+    to powers of two; templates round k to pow2).
 
     With `table_t` (what the caller's batch path hands
     ``ops.topk.batch_topk_scores_t``: its ``device_item_tables``) the
     filtered rungs carry excluded ids, at every width of
     ``ops.topk.EXCLUDE_LADDER``; each rung compiles the path, blocked or
-    dense, that its shapes will take under traffic.  These engines'
-    ``predict`` is a one-row ``batch_predict``, so ``max_batch <= 0``
-    (no batcher) still warms the one-row rungs.  The ``[B, M]`` masked
-    form of that scorer (`categories`, a `whiteList`) is not warmed: its
-    rungs each shipped a ``[B, M]`` array of zeros, 2.4 GB at 64 rows
-    over 9.4 M items, and set the server's peak memory.  Without
+    dense, that its shapes will take under traffic.  The ``[B, M]``
+    masked form of that scorer (`categories`, a `whiteList`) is not
+    warmed: its rungs each shipped a ``[B, M]`` array of zeros, 2.4 GB
+    at 64 rows over 9.4 M items, and set the server's peak memory.  Without
     `table_t` it is the classic ``[M, R]`` scorer under a ``[B, M]``
     mask, for the templates whose every batch is still masked and whose
     ``predict`` is a scorer of its own (itemsimilarity, ecommerce): with
-    no batcher nothing dispatches these, and nothing is compiled."""
+    no batcher nothing dispatches it, and nothing is compiled."""
     from ..ops.topk import (
-        EXCLUDE_LADDER, batch_topk_scores, batch_topk_scores_t, pow2_ceil,
+        EXCLUDE_LADDER, batch_topk_scores, batch_topk_scores_t,
     )
 
-    ladder = pow2_ladder(max_batch)
-    if not ladder:
-        if table_t is None:
-            return
-        ladder = [1]
-    k_default = min(pow2_ceil(10), n)
-    shapes = [(b, k_default) for b in ladder]
-    shapes += [(1, k) for k in {min(pow2_ceil(k), n) for k in (1, 4)}]
-    for b, k in shapes:
+    if table_t is None and max_batch <= 0:
+        return
+    for b, k in warm_shapes(max_batch, n):
         vecs = np.zeros((b, rank), np.float32)
         if table_t is None:
             batch_topk_scores(vecs, table, k,

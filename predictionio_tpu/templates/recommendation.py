@@ -45,8 +45,8 @@ from ._common import (
     DeviceTableMixin,
     RowFilter,
     batch_filter,
-    pow2_ladder,
     warm_batched_topk,
+    warm_shapes,
 )
 from ..storage.levents import EventStore
 
@@ -567,32 +567,24 @@ class ALSAlgorithm(Algorithm):
             table, rank, n, unmasked_too=True, max_batch=max_batch,
             table_t=model.device_item_tables(self._serve_dtype()),
         )
-        # the other two scorers `batch_predict` may choose warm the
-        # same shapes as warm_batched_topk: every pow2 batch at the
-        # default num, one row at the small k's (a size the padding can
-        # produce but the warmup skipped compiles mid-traffic, which is
-        # the p99 spike the ladder prevents)
-        ladder = pow2_ladder(max_batch) or [1]
-        k_default = min(pow2_ceil(10), n)
-        small_ks = {min(pow2_ceil(k), n) for k in (1, 4)}
+        # the other two scorers `batch_predict` may choose, at the
+        # same shapes
+        shapes = warm_shapes(max_batch, n)
         rcfg = self._retrieval_config()
         if rcfg is not None and not getattr(self.params,
                                             "distributed_topk", False):
             # pio-scout: the candidate + rerank executables
             idx = model.device_ann_index(rcfg)
-            idx.warm(k_default, ladder, table)
-            for k in small_ks:
-                idx.warm(k, [1], table)
+            for b, k in shapes:
+                idx.warm(k, [b], table)
         if getattr(self.params, "distributed_topk", False):
             # the ring index compiles BOTH variants (clean + parity-
             # coded; + the quantized candidate variant under
             # retrieval != exact) per (batch, k) — so neither a first
             # degradation nor a first burst pays a mid-request compile
             idx = self._sharded_index(model)
-            for b in ladder:
-                idx.warm(k_default, batch=b)
-            for k in small_ks:
-                idx.warm(k, batch=1)
+            for b, k in shapes:
+                idx.warm(k, batch=b)
 
     def predict(self, model: ALSModel, query: Query) -> PredictedResult:
         """A lone request is a one-row batch: the same device program,

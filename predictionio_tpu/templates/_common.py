@@ -396,16 +396,17 @@ def warm_shapes(max_batch: int, n: int) -> list:
     ``pow2_ladder(max_batch)`` at the pow2-rounded default num — every
     rung, not a subset: a size the padding can produce but the warmup
     skipped compiles on first exposure mid-traffic, which is exactly
-    the p99 spike the padding exists to avoid (ADVICE r4) — and one row
-    at the small k's, for a lone request, which is a batch of one.
-    ``max_batch <= 0`` (no batcher) leaves the one-row shapes: what an
-    engine whose ``predict`` is a one-row ``batch_predict`` still
-    dispatches."""
+    the p99 spike the padding exists to avoid (ADVICE r4).  A lone
+    request is the one-row rung; ``max_batch <= 0`` (no batcher) leaves
+    that rung alone: what an engine whose ``predict`` is a one-row
+    ``batch_predict`` still dispatches.  No other k: on the chip every
+    rung is an executable to load, 0.22-0.28 s of each server's start
+    over a 9.39 M-item table (PERF.md, PR 31), and a k no rung holds
+    compiles once and lands in the persistent compilation cache."""
     from ..ops.topk import pow2_ceil
 
-    ladder = pow2_ladder(max_batch) or [1]
-    shapes = [(b, min(pow2_ceil(10), n)) for b in ladder]
-    return shapes + [(1, k) for k in {min(pow2_ceil(k), n) for k in (1, 4)}]
+    k_default = min(pow2_ceil(10), n)
+    return [(b, k_default) for b in pow2_ladder(max_batch) or [1]]
 
 
 def warm_batched_topk(table, rank: int, n: int,

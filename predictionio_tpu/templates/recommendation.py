@@ -555,9 +555,9 @@ class ALSAlgorithm(Algorithm):
         executable key space is bounded to (pow2 B) x (pow2 k) x
         (filter) by the shape-stability contract there.  This warms
         every pow2 B the batcher's padding can dispatch up to
-        ``max_batch`` at the pow2-rounded default num (k=16) plus the
-        small-k sizes at B=1; remaining shapes compile once under load
-        and land in the persistent compilation cache."""
+        ``max_batch`` at the pow2-rounded default num (k=16); remaining
+        shapes compile once under load and land in the persistent
+        compilation cache."""
         n = len(model.items)
         if n == 0:
             return

@@ -280,9 +280,9 @@ class SimilarProductAlgorithm(Algorithm):
     # -- serving -----------------------------------------------------------
     def warmup(self, model: SimilarALSModel, max_batch: int = 64) -> None:
         """Pre-compile the cosine top-k scorer for the pow2 batched
-        shapes the serving micro-batcher dispatches and the small-k
-        one-row shapes of a lone request, each with excluded ids (every
-        query excludes its own seeds).  The table is train-time
+        shapes the serving micro-batcher dispatches, a lone request's
+        one row among them, each with excluded ids (every query
+        excludes its own seeds).  The table is train-time
         normalized, so the plain device tables serve cosine directly."""
         n = len(model.items)
         if n == 0:

@@ -391,7 +391,7 @@ def pow2_ladder(max_batch: int) -> list[int]:
     return dispatchable_sizes(max_batch)
 
 
-def warm_shapes(max_batch: int, n: int) -> list:
+def warm_shapes(max_batch: int, n: int, lone_nums=()) -> list:
     """The ``(B, k)`` shapes a warm-up compiles: EVERY B in
     ``pow2_ladder(max_batch)`` at the pow2-rounded default num — every
     rung, not a subset: a size the padding can produce but the warmup
@@ -399,23 +399,29 @@ def warm_shapes(max_batch: int, n: int) -> list:
     the p99 spike the padding exists to avoid (ADVICE r4).  A lone
     request is the one-row rung; ``max_batch <= 0`` (no batcher) leaves
     that rung alone: what an engine whose ``predict`` is a one-row
-    ``batch_predict`` still dispatches.  No other k: on the chip every
-    rung is an executable to load, 0.22-0.28 s of each server's start
-    over a 9.39 M-item table (PERF.md, PR 31), and a k no rung holds
-    compiles once and lands in the persistent compilation cache."""
+    ``batch_predict`` still dispatches.  `lone_nums` adds the one-row
+    rung at each of these nums' pow2 k (a lone "three similar items"
+    under a product page).  On the chip every rung is an executable to
+    load, 0.18-0.31 s of each server's start over a 9.39 M-item table
+    (PERF.md, PR 31), so the caller names what its traffic asks for; a
+    k no rung holds compiles once and lands in the persistent
+    compilation cache."""
     from ..ops.topk import pow2_ceil
 
     k_default = min(pow2_ceil(10), n)
-    return [(b, k_default) for b in pow2_ladder(max_batch) or [1]]
+    lone_ks = {min(pow2_ceil(num), n) for num in lone_nums} - {k_default}
+    return ([(b, k_default) for b in pow2_ladder(max_batch) or [1]]
+            + [(1, k) for k in sorted(lone_ks)])
 
 
 def warm_batched_topk(table, rank: int, n: int,
                       unmasked_too: bool = False,
                       max_batch: int = 64,
-                      table_t=None) -> None:
+                      table_t=None, lone_nums=()) -> None:
     """Pre-compile the batched top-k scorer at the shapes serving
-    dispatches (:func:`warm_shapes`: server/microbatch.py pads batches
-    to powers of two; templates round k to pow2).
+    dispatches (:func:`warm_shapes`, which reads `max_batch` and
+    `lone_nums`: server/microbatch.py pads batches to powers of two;
+    templates round k to pow2).
 
     With `table_t` (what the caller's batch path hands
     ``ops.topk.batch_topk_scores_t``: its ``device_item_tables``) the
@@ -435,7 +441,7 @@ def warm_batched_topk(table, rank: int, n: int,
 
     if table_t is None and max_batch <= 0:
         return
-    for b, k in warm_shapes(max_batch, n):
+    for b, k in warm_shapes(max_batch, n, lone_nums):
         vecs = np.zeros((b, rank), np.float32)
         if table_t is None:
             batch_topk_scores(vecs, table, k,

@@ -280,16 +280,16 @@ class SimilarProductAlgorithm(Algorithm):
     # -- serving -----------------------------------------------------------
     def warmup(self, model: SimilarALSModel, max_batch: int = 64) -> None:
         """Pre-compile the cosine top-k scorer for the pow2 batched
-        shapes the serving micro-batcher dispatches, a lone request's
-        one row among them, each with excluded ids (every query
-        excludes its own seeds).  The table is train-time
+        shapes the serving micro-batcher dispatches and the small-k
+        one-row shapes of a lone request, each with excluded ids (every
+        query excludes its own seeds).  The table is train-time
         normalized, so the plain device tables serve cosine directly."""
         n = len(model.items)
         if n == 0:
             return
         warm_batched_topk(
             None, model.item_factors.shape[1], n, max_batch=max_batch,
-            table_t=model.device_item_tables(),
+            table_t=model.device_item_tables(), lone_nums=(1, 4),
         )
 
     @staticmethod

@@ -659,6 +659,61 @@ def test_blocking_submit_timeline_is_booked_from_the_dispatchers_turn():
     b.close()
 
 
+def test_served_request_names_its_turn(storage_memory):
+    """Over HTTP on the continuous path: the request's `serve.query`
+    span (and its flight record) carries `batchTurn`, the number of a
+    turn in the deque whose rows include it."""
+    import json
+    import urllib.request
+
+    from predictionio_tpu.controller.base import (
+        Algorithm, DataSource, WorkflowContext,
+    )
+    from predictionio_tpu.controller.engine import SimpleEngine
+    from predictionio_tpu.obs import get_flight_recorder, get_tracer
+    from predictionio_tpu.server.serving import EngineServer, ServerConfig
+    from predictionio_tpu.workflow.train import run_train
+
+    class DS(DataSource):
+        def read_training(self, ctx):
+            return 1
+
+    class BatchedAlgo(Algorithm):
+        def train(self, ctx, data):
+            return {"w": 2}
+
+        def predict(self, model, query):
+            return {"y": model["w"] * query.get("x", 0)}
+
+        def batch_predict(self, model, queries):
+            return [self.predict(model, q) for q in queries]
+
+    ctx = WorkflowContext(storage=storage_memory)
+    engine = SimpleEngine(DS, BatchedAlgo)
+    ep = engine.params_from_variant({})
+    iid = run_train(engine, ep, ctx=ctx)
+    srv = EngineServer(engine, ep, iid, ctx=ctx, config=ServerConfig(port=0))
+    srv.start_background()
+    try:
+        tid = "t-turn-http"
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{srv.config.port}/queries.json",
+            data=b'{"x": 4}', method="POST",
+            headers={"Content-Type": "application/json",
+                     "X-PIO-Trace": tid},
+        )
+        with urllib.request.urlopen(req, timeout=15) as r:
+            assert json.loads(r.read().decode()) == {"y": 8}
+    finally:
+        srv.stop()
+    (span,) = get_tracer().spans(trace_id=tid, name="serve.query")
+    rec = _turn(span.attrs["batchTurn"])
+    assert rec["rows"] >= 1
+    flight = get_flight_recorder().record_for(tid)
+    if flight is not None:  # may be evicted by slower suite traffic
+        assert flight["attrs"]["batchTurn"] == span.attrs["batchTurn"]
+
+
 def test_blocking_submit_runs_on_the_dispatcher_thread():
     """ONE thread leads turns: a blocking submit, alone or among
     others, is executed on the thread named ``microbatch-dispatch`` and

@@ -346,9 +346,8 @@ def test_similarproduct_serves_by_ids_and_warms_what_it_dispatches(
     alone = algo.predict(model, queries[1])
     again = algo.batch_predict(model, [queries[1]])[0]
     assert alone == again
-    assert xray.total_backend_compiles() == compiled
-    # another num is another k: its one compile lands in the cache
     assert algo.predict(model, queries[3]).item_scores[0].item != "i2"
+    assert xray.total_backend_compiles() == compiled
     assert topk.TOPK_PATH.labels(path="blocked_ids").value() == ids_calls + 6
 
 

@@ -184,6 +184,8 @@ class DeviceTableMixin:
         rank whose rows pack into no line the scorer has no blocked
         path, and gets the transposed table alone: no third copy.  At a
         rank of whole lines (128) it gets the row-major table alone."""
+        import jax
+
         from ..ops.topk import ItemTables, pack_rows, rows_per_line
 
         p = rows_per_line(np.shape(self.item_factors)[1])
@@ -191,13 +193,19 @@ class DeviceTableMixin:
             # a row is whole lines: the row-major table is its own packed
             # form and the scan reads it too (ItemTables): ONE copy
             return ItemTables(None, self.device_item_factors(dtype))
-        table_t = self.device_item_factors_t(dtype)
         if not p:
-            return table_t
+            return self.device_item_factors_t(dtype)
+        # the [M, R] table up before the transposed one's upload is
+        # issued: with both in flight at once the device held neither
+        # until seconds after the warm-up had returned, and a
+        # warm-started server's first batch waited that long in `fetch`
+        # (PERF.md, PR 31)
+        table = jax.block_until_ready(self.device_item_factors(dtype))
+        table_t = self.device_item_factors_t(dtype)
         key = f"_dev_item_packed_{dtype or 'native'}"
         packed = getattr(self, key, None)
         if packed is None:
-            packed = pack_rows(self.device_item_factors(dtype))
+            packed = pack_rows(table)
             setattr(self, key, packed)
         return ItemTables(table_t, packed)
 

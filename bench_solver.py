@@ -1,9 +1,9 @@
 """Batched-SPD-solver benchmark: XLA (cholesky + triangular_solve) vs the
-Pallas kernel (`ops/solve.py`) vs the iALS++ subspace sweep's solve
-phase, on the default accelerator.
+Pallas Cholesky kernel (`ops/solve.py`, the batch on the lanes) vs the
+iALS++ subspace sweep's solve phase, on the default accelerator.
 
-VERDICT r1 item 3: the crossover must be MEASURED on the real chip, not
-promised in a docstring.  Run with the TPU reachable:
+The crossover is MEASURED on the real chip, not promised in a
+docstring.  Run with the TPU reachable:
 
     python bench_solver.py                 # full grid, prints a table
     python bench_solver.py --rank 64 --batch 32768   # one cell
@@ -19,10 +19,10 @@ half-iteration in place of one batched R×R solve:
   {"metric": "spd_solve_subspace_ms", "rank": R, "batch": B,
    "block": Bk, "n_blocks": ..., "sweep_xla_ms": ...,
    "sweep_pallas_ms": ..., "solve_speedup_vs_full": ...}
-and a final summary line recommending full-solve vs subspace per rank.
-Results should be recorded in docs/ARCHITECTURE.md ("Measured
-performance") and, if a mode wins at the north-star rank, the
-`ALSConfig` defaults flipped.
+and a final summary line naming the fastest per rank.  `[32768, 64, 64]`
+on one v5e chip read 264 ms (xla) and 8.0 ms (the kernel) in PR 32
+(PERF.md, Findings); `ALSConfig.solver="auto"` takes the kernel on a TPU
+for that reason.
 """
 
 from __future__ import annotations

@@ -14,9 +14,11 @@ chip its children need.
      takes the device-staging path an ML-20M user gets.  Depth is cut
      (3 iterations); widths are not.
   2. `app new`, `import`, `train` on an engine.json from `template get
-     recommendation` (default solver, xla).
+     recommendation` (default solver, "auto": on the chip the
+     `ops/solve.py` Cholesky kernel, which the summary's `solve_path`
+     must say).
   3. two more `train`s, `"solver": "pallas"` and `"solver": "fused"`:
-     the Gauss-Jordan kernel and the fused gather+Gram+solve kernel at
+     the same kernel forced and the fused gather+Gram+solve kernel at
      rank 64, compiled, not interpreted, not degraded.
   4. `deploy --port 0 --port-file`: single queries, one filtered query
      (`blackList`, the exact-scan branch), one concurrent burst wide
@@ -250,7 +252,8 @@ class Smoke:
             "name": name, "instance": iid,
             "platform": header["platform"], "kind": header["deviceKind"],
             "devices": header["nDevices"],
-            "solver": staged["solver"], "staging": staged["staging"],
+            "solver": staged["solver"], "solve_path": staged["solvePath"],
+            "staging": staged["staging"],
             "placement": staged["placement"],
             "devices_with_data": staged["devicesWithData"],
             "sweeps": final["sweeps"],
@@ -434,12 +437,12 @@ class Smoke:
         if imported < self.size["ratings"]:
             raise SmokeFailure(f"imported {imported} events")
 
-        xla = self.engine_dir("xla", {})
-        iid = self.train("xla", xla, device)
+        auto = self.engine_dir("auto", {})
+        iid = self.train("auto", auto, device)
         for solver in ("pallas", "fused"):
             self.train(solver, self.engine_dir(solver, {"solver": solver}),
                        device)
-        self.serve("xla", xla, iid, device)
+        self.serve("auto", auto, iid, device)
         if device["count"] > 1:
             # more than one chip: sharded ALS and the ring top-k, once
             ring = self.engine_dir("sharded", {
@@ -449,9 +452,12 @@ class Smoke:
                 raise SmokeFailure(f"sharded train: {self.trains[-1]}")
             self.serve("sharded", ring, ring_iid, device)
         self.check_logs()
-        for want, got in zip(("xla", "pallas", "fused"), self.trains):
+        for want, got in zip(("auto", "pallas", "fused"), self.trains):
             if got["solver"] != want:
                 raise SmokeFailure(f"train {want} ran solver {got}")
+        on_chip = device["platform"] == "tpu"
+        if self.trains[0]["solve_path"] != ("kernel" if on_chip else "lax"):
+            raise SmokeFailure(f"default train solved by {self.trains[0]}")
         # the same library the children built under this PIO_TPU_HOME
         os.environ["PIO_TPU_HOME"] = self.env["PIO_TPU_HOME"]
         from predictionio_tpu.native import native_available

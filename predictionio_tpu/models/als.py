@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..obs import TRAIN_PHASE_SECONDS, tower, xray
+from ..obs import ALS_SOLVE_SYSTEMS_TOTAL, TRAIN_PHASE_SECONDS, tower, xray
 from ..obs.timeline import annotate
 from ..parallel.mesh import DATA_AXIS, pad_to_multiple, replicated
 from ..storage.columnar import Ratings
@@ -102,13 +102,17 @@ class ALSConfig:
     # "default" (bf16).  RMSE parity wants "highest"; ranking-only workloads
     # can trade down.
     matmul_precision: str = "highest"
-    # batched SPD solver: "xla" (lax.linalg), "pallas" (ops/solve.py
-    # Gauss-Jordan kernel for the solves alone), or "fused"
-    # (ops/fused_als.py single-pass gather+Gram+solve kernel; buckets
-    # too wide for its SMEM index block keep the xla path).  A kernel
-    # the backend's compiler rejects fails the first half-iteration
-    # with the compiler's message; nothing is substituted for it
-    solver: str = "xla"
+    # batched SPD solver.  "auto" (the default) resolves from what the
+    # code can observe (`_solve_path`): the ops/solve.py Cholesky kernel
+    # for float32 systems of R <= 128 on a TPU backend, lax.linalg
+    # everywhere else.  "xla" (lax.linalg) and "pallas" (the kernel,
+    # through the interpreter on the CPU backend) force a path for
+    # tests and A/B; "fused" is ops/fused_als.py's single-pass
+    # gather+Gram+solve kernel (buckets too wide for its SMEM index
+    # block keep the lax path).  A kernel the backend's compiler
+    # rejects fails the first half-iteration with the compiler's
+    # message; nothing is substituted for it
+    solver: str = "auto"
     # rank-sweep strategy: "full" solves the complete R×R normal
     # equations per row (today's behavior, the default); "subspace"
     # (iALS++, arXiv 2110.14044) sweeps the rank dimension in blocks of
@@ -173,9 +177,9 @@ class ALSConfig:
                 "solver='fused' (the fused kernel gathers in-kernel); "
                 "pick one"
             )
-        if self.solver not in ("xla", "pallas", "fused"):
+        if self.solver not in ("auto", "xla", "pallas", "fused"):
             raise ValueError(
-                f"solver must be 'xla', 'pallas' or 'fused', "
+                f"solver must be 'auto', 'xla', 'pallas' or 'fused', "
                 f"got {self.solver!r}"
             )
         if self.gather_dtype == "bfloat16" and self.solver == "fused":
@@ -591,18 +595,49 @@ def _per_device(fn, mesh: Optional[Mesh], in_specs, out_specs):
     return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
+def _block_sweeps(solver_mode: str, subspace_size: int, r: int) -> bool:
+    """Whether a half sweeps rank blocks (iALS++): ``subspace_size >= r``
+    is the full solve VERBATIM, per the ALSConfig contract."""
+    return solver_mode == "subspace" and 0 < subspace_size < r
+
+
+# the widest system the ops/solve.py kernel is sized for: a 128-lane
+# tile of [128, 128] float32 systems is 8 MiB of its VMEM budget
+_KERNEL_MAX_R = 128
+
+
+def _solve_path(solver: str, r: int, dtype=jnp.float32) -> str:
+    """Which implementation solves a batch of ``r`` x ``r`` systems of
+    ``dtype`` under ``ALSConfig.solver``: ``"kernel"`` (`ops/solve.py`)
+    or ``"lax"`` (``lax.linalg``).
+
+    ``"pallas"`` and ``"xla"`` force one; ``"auto"`` takes the kernel
+    where it is the faster (a TPU backend, float32 systems no wider than
+    a tile holds) and ``lax`` elsewhere: on the CPU backend the kernel
+    would run through the Pallas interpreter.  ``"fused"`` buckets that
+    reach the plain solve keep ``lax``.  The vmapped sweep
+    (`sweep_train_als`) resolves ``"auto"`` to ``"xla"`` itself: a
+    Pallas grid does not batch under ``vmap``.
+    """
+    if solver == "pallas":
+        return "kernel"
+    if (solver == "auto" and jax.default_backend() == "tpu"
+            and dtype == jnp.float32 and r <= _KERNEL_MAX_R):
+        return "kernel"
+    return "lax"
+
+
 def _spd_solve(A: jax.Array, b: jax.Array, solver: str,
                mesh: Optional[Mesh] = None) -> jax.Array:
     """Batched SPD solve ``A[i] x[i] = b[i]`` via the configured backend.
 
     One routing point for BOTH the full R×R systems and the subspace
-    mode's B×B subsystems: ``"pallas"`` runs the Gauss-Jordan kernel
-    (`ops/solve.py` — smaller systems pack more rows per VMEM tile, so
-    the kernel gets FASTER per system as B shrinks), anything else the
-    XLA Cholesky + two triangular solves.  ``mesh``: see
+    mode's B×B subsystems (:func:`_solve_path`): the Cholesky kernel
+    (`ops/solve.py` — smaller systems pack more of the batch per VMEM
+    tile) or the XLA Cholesky + two triangular solves.  ``mesh``: see
     :func:`_per_device`.
     """
-    if solver == "pallas":
+    if _solve_path(solver, A.shape[-1], A.dtype) == "kernel":
         from ..ops.solve import cholesky_solve_batched
 
         return _per_device(
@@ -783,9 +818,7 @@ def _solve_buckets(
     from inside its own ``shard_map`` body and leaves it None.
     """
     r = opp.shape[-1]
-    # B >= R degenerates to the full-solve branch VERBATIM (bitwise-
-    # identical compiled program), per the ALSConfig contract
-    sub = solver_mode == "subspace" and 0 < subspace_size < r
+    sub = _block_sweeps(solver_mode, subspace_size, r)
     if sub and upd_table is None:
         raise ValueError(
             "solver_mode='subspace' requires the current factor table "
@@ -1120,7 +1153,7 @@ def build_sharded_half(
         # operand).  One extra [N, R] all-gather per half-iteration;
         # stays zero-cost when the mode is off or degenerate.
         upd_full = None
-        if solver_mode == "subspace" and 0 < subspace_size < upd.shape[-1]:
+        if _block_sweeps(solver_mode, subspace_size, upd.shape[-1]):
             upd_full = jax.lax.all_gather(upd, axis, axis=0, tiled=True)
 
         def write(acc, rows, x):
@@ -1367,8 +1400,11 @@ class ALSTrainer:
             # 0 under sharded placement, which expands in every half
             return {name: side.get(key, 0) for name, side in sides.items()}
 
+        self._plan_solves()
         staged = {
             "solver": cfg.solver,
+            "solvePath": self.solve_path,
+            "solveSystems": self.solve_systems,
             "staging": self.staging,
             "placement": "sharded" if self.sharded else "replicated",
             "devices": n_dev,
@@ -1379,6 +1415,30 @@ class ALSTrainer:
         }
         logger.info("ALS staged: %s", staged)
         tower.note_event("als_staged", **staged)
+
+    def _plan_solves(self) -> None:
+        """What `_spd_solve` will be handed in every half, and by which
+        path (``solve_path``, ``solve_systems`` a side): known on the
+        host from the staged shapes alone.  A system for each row of
+        each bucket, batch padding included, times the rank blocks of a
+        subspace sweep; the buckets the fused kernel takes whole never
+        reach the solve."""
+        cfg = self.cfg
+        sub = _block_sweeps(cfg.solver_mode, cfg.subspace_size, cfg.rank)
+        width = cfg.subspace_size if sub else cfg.rank
+        self.solve_path = _solve_path(cfg.solver, width)
+        fused = cfg.solver == "fused"
+        if fused:
+            from ..ops.fused_als import fused_tile_plan
+        self.solve_systems = {
+            name: -(-cfg.rank // width) * sum(
+                int(bucket[0].shape[0])
+                for bucket, k in zip(side["buckets"], side["ks"])
+                if not (fused and fused_tile_plan(cfg.rank, k) is not None)
+            )
+            for name, side in (("user", self._user_side),
+                               ("item", self._item_side))
+        }
 
     def data_devices(self) -> int:
         """How many devices hold staged training data: the per-bucket
@@ -1570,6 +1630,7 @@ class ALSTrainer:
             n_dev, device_proc, exchange_dir, f"{tag}-item", timeout,
         )
         self._build_sharded_halves()
+        self._plan_solves()
         # distributed staging holds only LOCAL triples; a global
         # training loss is not computable from one process
         self.loss_every = 0
@@ -2096,6 +2157,9 @@ class ALSTrainer:
                 TRAIN_PHASE_SECONDS.labels(phase="als.item_half").observe(
                     phases["item_half"]
                 )
+            ALS_SOLVE_SYSTEMS_TOTAL.labels(path=self.solve_path).inc(
+                self.solve_systems["user"] + self.solve_systems["item"]
+            )
             if faults.fired("train.nan"):
                 # poison the iterates the way an exploding sweep would;
                 # the convergence watchdog must catch it THIS sweep
@@ -2229,9 +2293,10 @@ def sweep_train_als(
             U, V = trainer.run(U0, V0, cfg.num_iterations, lam=float(lam))
             out.append(trainer._factors(U, V))
         return out
-    if cfg.solver != "xla":
+    if cfg.solver not in ("auto", "xla"):
         raise ValueError(
-            "sweep_train_als (vmapped form) requires solver='xla'"
+            "sweep_train_als (vmapped form) requires solver='auto' or "
+            "'xla': a Pallas grid does not batch under vmap"
         )
     trainer = ALSTrainer(ratings, n_users, n_items, cfg, mesh=mesh)
     side_u, side_i = trainer._user_side, trainer._item_side
@@ -2241,7 +2306,7 @@ def sweep_train_als(
 
     common = dict(
         implicit=cfg.implicit, weighted_lambda=cfg.weighted_lambda,
-        precision=cfg.matmul_precision, solver=cfg.solver,
+        precision=cfg.matmul_precision, solver="xla",
         gather_dtype=cfg.gather_dtype, gather_mode=cfg.gather_mode,
         solver_mode=cfg.solver_mode, subspace_size=cfg.subspace_size,
     )

@@ -211,6 +211,13 @@ TRAIN_PHASE_SECONDS = _registry.histogram(
     labels=("phase",),
     buckets=log_buckets(1e-4, 10000.0, per_decade=4),
 )
+ALS_SOLVE_SYSTEMS_TOTAL = _registry.counter(
+    "pio_als_solve_systems_total",
+    "Normal-equation systems an ALS half handed the batched SPD solve, "
+    "by the path that solved them (kernel = ops/solve.py, lax = "
+    "lax.linalg)",
+    labels=("path",),
+)
 
 # pio-live (incremental fold-in) families: the daemon side books cycles
 # / scanned events / produced rows + per-phase timings; the serving side

@@ -19,8 +19,9 @@ one-row slice of a bf16 ref is not tile-aligned).
 
 Per chunk: the row copies, then ``A += (cw·rows)ᵀ rows`` on the MXU and
 ``b += Σ bw·rows`` on the VPU, accumulated in fp32 VMEM scratch; on the
-last chunk the kernel regularizes and solves in place with the same
-augmented Gauss-Jordan as ``ops/solve.py``, writing only ``x[TB, R]``.
+last chunk the kernel regularizes and solves in place by augmented
+Gauss-Jordan (its tile has the batch on the major axis; ``ops/solve.py``'s
+Cholesky wants it on the lanes), writing only ``x[TB, R]``.
 
 ``models/als._solve_buckets`` routes a bucket through the kernel when
 ``fused_tile_plan`` finds a tile for its width; wider buckets keep the
@@ -45,7 +46,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..obs import xray
 from .solve import (
-    _EPS,
     pallas_interpret,
     solver_smem_budget,
     solver_vmem_budget,
@@ -55,6 +55,9 @@ __all__ = [
     "fused_gather_gram_solve",
     "fused_tile_plan",
 ]
+
+# the smallest pivot the elimination divides by
+_EPS = 1e-20
 
 
 def _pad8(n: int) -> int:
@@ -80,9 +83,9 @@ def fused_tile_plan(r: int, k: int):
     one batch tile's scalar-prefetched ``[TB, Kpad]`` int32 index block.
     The table itself stays in HBM, so its height does not enter.
 
-    Like ``ops/solve._tile_rows`` the plan fills only half the VMEM
-    budget: the chunk's weighted copy and the MXU operands Mosaic
-    stages are stack temporaries of the same order as the landing pad
+    The plan fills only half the VMEM budget: the chunk's weighted
+    copy and the MXU operands Mosaic stages are stack temporaries of
+    the same order as the landing pad
     (v5e: a plan at 13 of 16 MiB compiled to 18.7 MiB and was refused).
 
     Returns ``None`` when no tile fits (the caller keeps the XLA path
@@ -110,8 +113,8 @@ def fused_tile_plan(r: int, k: int):
 def _gj_solve_writeback(a_scr, b_scr, m_scr, reg_ref, x_ref):
     """Regularize + augmented Gauss-Jordan in place; write x[TB, R].
 
-    The same no-pivot elimination as ``ops/solve.py`` (safe: ALS always
-    solves ``Gram + reg·I ≻ 0``), on the fp32 accumulators.
+    No pivoting (safe: ALS always solves ``Gram + reg·I ≻ 0``), on the
+    fp32 accumulators.
     """
     tb, r, _ = a_scr.shape
     w = r + 1

@@ -1,39 +1,51 @@
-"""Batched SPD solve as a Pallas TPU kernel (augmented Gauss-Jordan).
+"""Batched SPD solve as a Pallas TPU kernel (Cholesky, batch on the lanes).
 
 The ALS hot loop solves hundreds of thousands of small (R<=128) SPD
 normal-equation systems per half-iteration (`models/als.py`).  XLA lowers
 ``lax.linalg.cholesky`` + two ``triangular_solve`` calls on TPU to
-loop-heavy code (the 2026-07-30 phase split in docs/ARCHITECTURE.md put
-it at ~13 GFLOP/s; not re-measured on the current toolchain).  This
-kernel instead keeps a tile of systems resident in
-VMEM and runs **augmented Gauss-Jordan elimination** lock-step across the
-batch:
+column loops over whole ``[B, R, R]`` arrays in HBM: 264 ms for
+``[32768, 64, 64]`` on a v5e, 12-13 GFLOP/s.  This kernel keeps a tile of
+systems resident in VMEM, factors ``A = U^T U``, substitutes forward and
+backward in the same pass and writes only ``x [B, R]``: 8 ms for the same
+batch alone, 5.3 ms a call inside a sweep (PERF.md, PR 32).
 
-* the augmented matrix ``[A | b]`` lives in one ``[TB, R, R+1]`` VMEM
-  scratch (the +1 column is free: Mosaic pads the lane dimension to 128
-  anyway for R <= 127);
-* each of the R pivot steps is a handful of `[TB, R]`/`[TB, R, W]`
-  vector ops (one-hot row/column extraction via broadcasted-iota masks,
-  one fused rank-1 update) — no substitution phases, no dynamic slicing,
-  only ops Mosaic lowers everywhere;
-* after R steps the b-column IS the solution.
+* **The batch is the lane axis.**  The tile lives as ``S[i, j, b]`` in a
+  ``[R, R+8, TB]`` scratch, TB a multiple of 128: element ``(i, j)`` of
+  128 systems is one lane vector, row ``i`` of 128 systems is ``R/8``
+  vector registers with ``j`` on the sublanes.  Every step of the
+  factorisation is then an elementwise multiply / subtract / ``rsqrt``
+  over lane vectors: no masks over the matrix, no one-hot extraction, no
+  cross-lane reduction, and what a lane computes never reaches another
+  lane (a ragged last tile's padding lanes hold garbage that is never
+  written back).
+* **``A`` arrives as ``[B, R, R]``** (what the Gram einsum emits; no
+  second copy in HBM) in blocks of 8 rows of every system of the tile;
+  each row ``[TB, R]`` is transposed to ``[R, TB]`` in VMEM as it
+  arrives, and the 8 rows are factored while the next block is in
+  flight.
+* **Row-wise (left-looking) Cholesky**: row ``k`` of ``U`` is
+  ``(A[k, :] - sum_{m<k} U[m, k] * U[m, :]) / sqrt(pivot)``, zeroed left
+  of the diagonal.  ``b`` rides along as column ``R`` of the same slab,
+  so the forward substitution ``y = U^-T b`` is the same dot products
+  and costs no pass of its own.  The products of 8 earlier rows are summed as a tree
+  before they leave the accumulator: half the rounding error of a
+  running subtraction (float64 comparison in `tests/test_solve.py`),
+  which is what keeps the tables inside the benchmark's limits against
+  XLA's own Cholesky.
+* **Back substitution** reads the rows of ``U`` in reverse; the dot of
+  a row with the solved tail is a sum over sublane blocks and one
+  sublane reduction.
 
-Gauss-Jordan without pivoting is numerically safe here because ALS always
-solves ``A = Gram + reg·I`` with ``reg > 0`` — symmetric positive definite
-and diagonally loaded, the textbook no-pivot case.  A previous revision
-factorized via lock-step Cholesky + masked substitutions; Jordan
-elimination does the same O(R^3) work per system but needs no
-back-substitution passes, which both halves the step count and removes
-the row-extraction traffic the substitutions paid.
+No pivoting: ALS always solves ``A = Gram + reg*I`` with ``reg > 0``.
 
-Used by ``ALSConfig(solver="pallas")`` — for the full R×R normal
-equations in ``solver_mode="full"`` AND for the B×B subsystems of the
-iALS++ subspace sweep (``solver_mode="subspace"``, `models/als.py
-_subspace_sweep`): the tile sizing (`_tile_rows`) packs MORE systems
-per VMEM tile as R shrinks, so the kernel gets faster per system at
-block sizes, not bypassed.  ``interpret=True`` runs the same kernel
-through the Pallas interpreter; :func:`pallas_interpret` selects it only
-for a process the operator put on the CPU (the test suite, dry runs).
+Used by ``ALSConfig.solver`` ``"auto"`` on a TPU backend and
+``"pallas"`` everywhere — for the full R×R normal equations in
+``solver_mode="full"`` AND for the B×B subsystems of the iALS++ subspace
+sweep (``solver_mode="subspace"``, `models/als.py _subspace_sweep`): the
+tile sizing (`_tile_rows`) packs MORE systems per VMEM tile as R
+shrinks.  ``interpret=True`` runs the same kernel through the Pallas
+interpreter; :func:`pallas_interpret` selects it only for a process the
+operator put on the CPU (the test suite, dry runs).
 """
 
 from __future__ import annotations
@@ -55,7 +67,8 @@ __all__ = [
     "solver_tile_footprint",
 ]
 
-_EPS = 1e-20
+_LANES = 128
+_SUB = 8   # float32 sublanes of a vector register; rows of A a grid step
 
 
 def pallas_interpret() -> bool:
@@ -68,31 +81,86 @@ def pallas_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _gj_kernel(a_ref, b_ref, x_ref, m_scr):
-    """One batch tile: augmented Gauss-Jordan over [A | b] in VMEM."""
-    R = a_ref.shape[-1]
-    W = R + 1
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)   # [1, W]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)    # [1, R]
-    m_scr[:, :, :R] = a_ref[:]
-    m_scr[:, :, R:W] = b_ref[:][:, :, None]
+def _tree_sum(terms):
+    """Pairwise sum of equal-shaped arrays (a balanced tree)."""
+    while len(terms) > 1:
+        terms = [
+            terms[i] + terms[i + 1] if i + 1 < len(terms) else terms[i]
+            for i in range(0, len(terms), 2)
+        ]
+    return terms[0]
 
-    def gj_step(j, _):
-        M = m_scr[:]                                   # [TB, R, W]
-        ohr = (rows == j).astype(M.dtype)              # [1, R] pivot row
-        ohc = (lanes == j).astype(M.dtype)             # [1, W] pivot col
-        pr = jnp.sum(M * ohr[:, :, None], axis=1)      # [TB, W] row j
-        d = jnp.sum(pr * ohc, axis=-1)                 # [TB] pivot value
-        prn = pr / jnp.where(jnp.abs(d) > _EPS, d, _EPS)[:, None]
-        col = jnp.sum(M * ohc[:, None, :], axis=-1)    # [TB, R] col j
-        colz = jnp.where(rows == j, 0.0, col)          # zero at pivot row
-        # fused: eliminate col j everywhere else + normalize the pivot row
-        upd = M - colz[:, :, None] * prn[:, None, :]
-        m_scr[:] = jnp.where(ohr[:, :, None] > 0, prn[:, None, :], upd)
-        return 0
 
-    jax.lax.fori_loop(0, R, gj_step, 0)
-    x_ref[:] = m_scr[:, :, R]
+def _cholesky_kernel(a_ref, b_ref, x_ref, s_ref, d_ref):
+    """Grid step (tile t, block-row c): rows 8c..8c+7 of the tile's
+    systems arrive in ``a_ref [TB, 8, R]``, are laid into ``s_ref[i, j,
+    b]`` and factored; the last block-row's step substitutes back and
+    fills ``x_ref [R, TB]``.  ``d_ref [R, TB]`` keeps 1/U[k, k].
+
+    One body serves every block-row (``c`` is a loop bound and an
+    offset, never a Python value): a row's slab is worked at its full
+    width, zeros left of the diagonal included.  That is twice the
+    multiplies of the triangle, and a kernel of some 150 operations
+    instead of 1,000: each of an ALS half's dozens of bucket shapes
+    traces and lowers its own copy in every process, compile cache or
+    not (PERF.md, PR 32).
+    """
+    tb, _, r = a_ref.shape
+    c = pl.program_id(1)
+    c0 = pl.multiple_of(c * _SUB, _SUB)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (_SUB, tb), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (r + _SUB, tb), 0)
+
+    for i in range(_SUB):
+        s_ref[c0 + i, :r, :] = a_ref[:, i, :].T
+        s_ref[c0 + i, r:, :] = jnp.where(
+            sub == 0, b_ref[pl.ds(c0 + i, 1), :], 0.0
+        )
+
+    def row(i, carry):
+        k = c0 + i
+
+        def products(m, live=None):
+            u_mk = s_ref[m, pl.ds(k, 1), :]                   # [1, TB]
+            if live is not None:
+                u_mk = jnp.where(live, u_mk, 0.0)
+            return u_mk * s_ref[m]                            # [R + 8, TB]
+
+        def earlier_block(g, acc):
+            return acc - _tree_sum(
+                [products(g * _SUB + u) for u in range(_SUB)]
+            )
+
+        acc = jax.lax.fori_loop(0, c, earlier_block, s_ref[k])
+        # this block's rows above k; the rows from k down still hold A
+        acc = acc - _tree_sum(
+            [products(c0 + u, u < i) for u in range(_SUB - 1)]
+        )
+        s_ref[k] = acc
+        pivot = s_ref[k, pl.ds(k, 1), :]
+        dinv = jax.lax.rsqrt(pivot)
+        # one Newton step: the hardware's rsqrt is an approximation
+        dinv = dinv * (1.5 - 0.5 * pivot * dinv * dinv)
+        d_ref[pl.ds(k, 1), :] = dinv
+        # the columns left of the diagonal are zeros of U
+        s_ref[k] = jnp.where(col >= k, acc * dinv, 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, _SUB, row, 0)
+
+    @pl.when(c == r // _SUB - 1)
+    def _():
+        x_ref[...] = jnp.zeros_like(x_ref)
+
+        def row_up(j, carry):
+            k = r - 1 - j
+            # x is still zero at k and above it, U zero left of k
+            dot = jnp.sum(s_ref[k, :r, :] * x_ref[...], axis=0, keepdims=True)
+            y_k = s_ref[k, r:r + 1, :]
+            x_ref[pl.ds(k, 1), :] = (y_k - dot) * d_ref[pl.ds(k, 1), :]
+            return carry
+
+        jax.lax.fori_loop(0, r, row_up, 0)
 
 
 def solver_vmem_budget() -> int:
@@ -100,7 +168,7 @@ def solver_vmem_budget() -> int:
 
     There is no public query API for scoped VMEM; Mosaic's default
     scoped limit is 16 MiB per core, and the tiles `_tile_rows` derives
-    from it compile on v5e at ranks 10/16/64/128 (CHANGES.md, PR 21).
+    from it compile on v5e at ranks 10/16/64/128 (PERF.md, PR 32).
     ``PIO_TPU_VMEM_BYTES`` overrides for a future generation or a
     deliberately tighter/looser budget.
     """
@@ -127,57 +195,68 @@ def solver_smem_budget() -> int:
     return 256 << 10
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def solver_tile_footprint(tb: int, r: int) -> int:
-    """Worst-case VMEM bytes the kernel occupies for a ``tb``-row tile.
+    """VMEM bytes the kernel occupies for a tile of ``tb`` systems.
 
     Counts the PADDED footprints (Mosaic tiles f32 values to (8, 128) on
     the trailing two dims) of everything resident at once: the
-    ``[TB, R, R+1]`` augmented scratch, the ``[TB, R, R]`` input A block
-    and ``[TB, R]`` b block (double-buffered by the pipeline), and the
-    ``[TB, R]`` output block (also double-buffered).
+    ``[R, R+8, TB]`` scratch of the tile, the ``[R, TB]`` scratch of the
+    pivots, the ``[TB, 8, R]`` block of A (its lanes padded to 128;
+    double-buffered by the pipeline) and the ``[R, TB]`` blocks of b and
+    x (also double-buffered).
     """
-    r8 = max(-(-r // 8) * 8, 8)
-    r128 = max(-(-r // 128) * 128, 128)
-    w128 = max(-(-(r + 1) // 128) * 128, 128)
-    scratch = tb * r8 * w128 * 4
-    a_blk = tb * r8 * r128 * 4
-    vec_blk = max(-(-tb // 8) * 8, 8) * r128 * 4  # [TB, R] b/x blocks
-    return scratch + 2 * a_blk + 4 * vec_blk
+    r = _round_up(r, _SUB)
+    tile = r * (r + _SUB) * tb * 4
+    vec = r * tb * 4
+    a_blk = tb * _SUB * _round_up(r, _LANES) * 4
+    return tile + vec + 2 * a_blk + 4 * vec
 
 
 def _tile_rows(r: int) -> int:
-    """Largest power-of-two batch tile whose total footprint fits in half
-    the VMEM budget (headroom for Mosaic's own temporaries): a 64-row
-    tile at R=64, 16 rows at R=128."""
-    budget = solver_vmem_budget() // 2
-    tb = 512
-    while tb > 8 and solver_tile_footprint(tb, r) > budget:
+    """Systems a tile: the largest of 512, 256 and 128 lanes whose
+    footprint fits three quarters of the VMEM budget (the rest is
+    Mosaic's own temporaries): 256 at R=64, 128 at R=128.  Never under
+    one register's 128 lanes."""
+    budget = solver_vmem_budget() * 3 // 4
+    tb = 4 * _LANES
+    while tb > _LANES and solver_tile_footprint(tb, r) > budget:
         tb //= 2
     return tb
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _solve_padded(A, b, *, interpret: bool):
-    B, R, _ = A.shape
-    tb = _tile_rows(R)
-    grid = (pl.cdiv(B, tb),)
-    return pl.pallas_call(
-        _gj_kernel,
-        out_shape=jax.ShapeDtypeStruct((B, R), A.dtype),
-        grid=grid,
+@functools.partial(jax.jit, static_argnames=("tb", "interpret"))
+def _solve(A, b, *, tb: int, interpret: bool):
+    B, r0, _ = A.shape
+    r = _round_up(r0, _SUB)
+    if r != r0:
+        # pad to whole sublane blocks with identity rows: their x is 0
+        A = jnp.pad(A, ((0, 0), (0, r - r0), (0, r - r0)))
+        A = A + jnp.diag(jnp.arange(r) >= r0).astype(A.dtype)
+    n_tiles = pl.cdiv(B, tb)
+    bt = jnp.pad(b.T, ((0, r - r0), (0, n_tiles * tb - B)))
+    xt = pl.pallas_call(
+        _cholesky_kernel,
+        out_shape=jax.ShapeDtypeStruct((r, n_tiles * tb), A.dtype),
+        grid=(n_tiles, r // _SUB),
         in_specs=[
-            pl.BlockSpec((tb, R, R), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((tb, R), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((tb, _SUB, r), lambda t, c: (t, c, 0)),
+            pl.BlockSpec((r, tb), lambda t, c: (0, t)),
         ],
-        out_specs=pl.BlockSpec((tb, R), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
+        out_specs=pl.BlockSpec((r, tb), lambda t, c: (0, t)),
         scratch_shapes=[
-            pltpu.VMEM((tb, R, R + 1), jnp.float32),
+            pltpu.VMEM((r, r + _SUB, tb), jnp.float32),
+            pltpu.VMEM((r, tb), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
         interpret=interpret,
-    )(A, b)
+    )(A, bt)
+    return xt[:r0, :B].T
 
 
 def spd_solve_batched(A, b, interpret: bool | None = None):
@@ -190,20 +269,15 @@ def spd_solve_batched(A, b, interpret: bool | None = None):
         interpret = pallas_interpret()
     B = A.shape[0]
     tb = _tile_rows(A.shape[-1])
-    pad = (-B) % tb
-    if pad:
-        # padded systems are identity/zero -> solution 0, sliced away
-        eye = jnp.broadcast_to(
-            jnp.eye(A.shape[-1], dtype=A.dtype), (pad, *A.shape[1:])
-        )
-        A = jnp.concatenate([A, eye], axis=0)
-        b = jnp.concatenate(
-            [b, jnp.zeros((pad, b.shape[-1]), b.dtype)], axis=0
-        )
-    x = _solve_padded(A, b, interpret=bool(interpret))
-    return x[:B]
+    if B < tb:
+        # a block may not be wider than its array, and every batch under
+        # one tile then shares one traced and lowered kernel (an ALS half
+        # has a dozen such buckets); a ragged LAST tile of a larger batch
+        # needs no padding: its lanes are not written back
+        A = jnp.pad(A, ((0, tb - B), (0, 0), (0, 0)))
+        b = jnp.pad(b, ((0, tb - B), (0, 0)))
+    return _solve(A, b, tb=tb, interpret=bool(interpret))[:B]
 
 
-# historical name (the first revision of this kernel factorized via
-# Cholesky); ALSConfig docs and tests may refer to either
+# the name `models/als.py` and the tests call it by
 cholesky_solve_batched = spd_solve_batched

@@ -57,7 +57,7 @@ class ItemSimilarityParams(Params):
     lam: float = 0.01
     alpha: float = 1.0
     seed: int = 3
-    solver: str = "xla"
+    solver: str = "auto"
     factor_placement: str = "replicated"
     # pio-scout two-stage cosine (the point of this engine): "ivf" is
     # the catalog-scale default; "exact" restores the brute-force scan

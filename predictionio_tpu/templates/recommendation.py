@@ -352,9 +352,10 @@ class ALSAlgorithmParams(Params):
     # gather access pattern: "row" | "grouped" (tile-aligned slab
     # gather — models/als.py ALSConfig.gather_mode)
     gather_mode: str = "row"
-    # batched SPD solver: "xla" | "pallas" | "fused" (a kernel that
+    # batched SPD solver: "auto" (the ops/solve.py kernel on a TPU,
+    # lax.linalg elsewhere) | "xla" | "pallas" | "fused" (a kernel that
     # does not compile on this backend fails the train)
-    solver: str = "xla"
+    solver: str = "auto"
     # rank-sweep strategy: "full" (R×R solve per row) | "subspace"
     # (iALS++ block sweep — engine.json keys solverMode/subspaceSize;
     # models/als.py ALSConfig.solver_mode)

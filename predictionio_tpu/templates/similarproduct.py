@@ -187,11 +187,12 @@ class SimilarALSParams(Params):
     lam: float = 0.01
     alpha: float = 1.0
     seed: int = 3
-    # scaling knobs (models/als.py): "fused"/"pallas" kernels fail
-    # the train if they do not compile;
+    # scaling knobs (models/als.py): solver "auto" takes the
+    # ops/solve.py kernel on a TPU and lax.linalg elsewhere; kernels
+    # fail the train if they do not compile;
     # "sharded" placement shards factor tables AND the rating COO
     # over the mesh
-    solver: str = "xla"
+    solver: str = "auto"
     solver_mode: str = "full"    # "subspace" = iALS++ block sweep
     subspace_size: int = 16
     factor_placement: str = "replicated"

@@ -410,12 +410,15 @@ def test_chip_smoke_cpu_dry_run_checks_the_plumbing(tmp_path):
     assert rec["claim"] is None
     assert rec["device"] == json.loads(result)["device"]
     assert [(t["solver"], t["placement"]) for t in rec["trains"]] == [
-        ("xla", "replicated"), ("pallas", "replicated"),
-        ("fused", "replicated"), ("xla", "sharded")]
+        ("auto", "replicated"), ("pallas", "replicated"),
+        ("fused", "replicated"), ("auto", "sharded")]
+    # the default resolves from the backend: lax here, the kernel on a chip
+    assert [t["solve_path"] for t in rec["trains"]] == [
+        "lax", "kernel", "lax", "lax"]
     assert all(t["platform"] == "cpu" and t["devices_with_data"] == 4
                and len(t["sweep_seconds"]) == t["sweeps"] == 2
                for t in rec["trains"])
-    assert [s["name"] for s in rec["serving"]] == ["xla", "sharded"]
+    assert [s["name"] for s in rec["serving"]] == ["auto", "sharded"]
     for serve in rec["serving"]:
         assert serve["compiles_after_warmup"] == 0
         assert serve["max_batch_seen"] >= 2
@@ -426,7 +429,7 @@ def test_chip_smoke_cpu_dry_run_checks_the_plumbing(tmp_path):
     assert rec["compile_cache"]["hit"] + rec["compile_cache"]["miss"] > 0
     assert set(rec["versions"]) == {"jax", "jaxlib", "libtpu"}
     # nothing left behind: every child stopped, the work dir removed
-    assert not list(Path("/tmp").glob("pio-chip-smoke-*/deploy-xla.log"))
+    assert not list(Path("/tmp").glob("pio-chip-smoke-*/deploy-auto.log"))
 
 
 def test_chip_smoke_event_recipe(tmp_path):

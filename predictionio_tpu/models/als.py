@@ -50,7 +50,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..obs import ALS_SOLVE_SYSTEMS_TOTAL, TRAIN_PHASE_SECONDS, tower, xray
+from ..obs import (
+    ALS_EXCHANGE_BYTES_TOTAL, ALS_SOLVE_SYSTEMS_TOTAL, TRAIN_PHASE_SECONDS,
+    tower, xray,
+)
 from ..obs.timeline import annotate
 from ..parallel.mesh import DATA_AXIS, pad_to_multiple, replicated
 from ..storage.columnar import Ratings
@@ -78,6 +81,47 @@ __all__ = [
 # gathered intermediate (~1 GiB at rank 64, f32) regardless of dataset size,
 # and a staged [B, K] block of ids or of ratings at 16 MiB
 MAX_ENTRIES_PER_BUCKET = 4 << 20
+
+# share of one device's memory that a bucket chunk's [B, R, R] float32
+# Gram may take: the entry cap alone lets a K=8 chunk hold 524,288 rows,
+# whose Gram at rank 128 is 34 GB
+_GRAM_MEMORY_SHARE = 16
+
+
+def _device_memory_bytes() -> int:
+    """Bytes of one device's memory as the backend reports them
+    (``bytes_limit``); a backend that keeps no statistics, as the CPU's,
+    counts as 16 GiB, a v5e chip's."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit", 16 << 30))
+
+
+def _rows_in_memory_share(row_bytes: int) -> int:
+    """How many rows of ``row_bytes`` fit a sixteenth of one device's
+    memory, rounded down to a power of two, so that a few bytes more or
+    less of ``bytes_limit`` stage the same shapes."""
+    rows = _device_memory_bytes() // _GRAM_MEMORY_SHARE // row_bytes
+    return 1 << max(rows, 1).bit_length() - 1
+
+
+def gram_chunk_rows(rank: int, n_dev: int = 1) -> int:
+    """Most rows a bucket chunk may hold over ``n_dev`` devices, so that
+    each device's ``[B / n_dev, R, R]`` float32 Gram stays under a
+    sixteenth of its memory (16 GB: 32,768 rows a device at rank 64,
+    8,192 at rank 128)."""
+    return _rows_in_memory_share(4 * rank * rank) * n_dev
+
+
+def exchange_chunk_entries(rank: int, n_dev: int) -> int:
+    """Most entries (B*K) a bucket chunk may hold under SHARDED
+    placement: a device looks up every device's ids of the chunk, so it
+    holds ``n_dev + 1`` times its own ``[B / n_dev, K, R]`` float32 rows
+    (the partial answers and, after the reduce-scatter, its own), and
+    those stay under the same sixteenth of its memory (16 GB at rank
+    128: 262,144 entries a device).  Never more than
+    ``MAX_ENTRIES_PER_BUCKET``."""
+    return min(MAX_ENTRIES_PER_BUCKET,
+               _rows_in_memory_share((n_dev + 1) * 4 * rank) * n_dev)
 
 
 @dataclass(frozen=True)
@@ -318,13 +362,15 @@ def build_bucket_layout(
     batch_multiple: int = 1,
     max_entries: Optional[int] = None,
     starts_dtype: type = np.int32,
+    max_rows: Optional[int] = None,
 ) -> BucketLayout:
     """Group rows by padded rating-count so the device solves static shapes.
 
     Rows with zero ratings are excluded (their factors stay at init, like
     MLlib which simply never solves them).  Oversized buckets are split so
-    ``B*K <= max_entries``; batch dims are padded to ``batch_multiple``
-    (the mesh size) for even sharding.
+    ``B*K <= max_entries`` and ``B <= max_rows`` (:func:`gram_chunk_rows`:
+    the bytes of the chunk's Gram); batch dims are padded to
+    ``batch_multiple`` (the mesh size) for even sharding.
 
     ``starts_dtype``: the replicated-COO path keeps int32 (those offsets
     are gathered on device) and rejects COOs past the int32 range; the
@@ -353,7 +399,7 @@ def build_bucket_layout(
     )
     layout.buckets = _assemble_buckets(
         counts, starts, n_rows, min_k, max_per_row, batch_multiple,
-        max_entries, starts_dtype=starts_dtype,
+        max_entries, starts_dtype=starts_dtype, max_rows=max_rows,
     )
     return layout
 
@@ -367,6 +413,7 @@ def _assemble_buckets(
     batch_multiple: int = 1,
     max_entries: Optional[int] = None,
     starts_dtype: type = np.int32,
+    max_rows: Optional[int] = None,
 ) -> list[Bucket]:
     """Bucket plan from per-row (counts, starts) alone.
 
@@ -394,10 +441,10 @@ def _assemble_buckets(
     for k in np.unique(k_active):
         k = int(k)
         rows_k = active[k_active == k].astype(np.int32)
-        b_cap = max(
-            batch_multiple,
-            (max_entries // k) // batch_multiple * batch_multiple,
-        )
+        b_cap = max_entries // k
+        if max_rows is not None:
+            b_cap = min(b_cap, max_rows)
+        b_cap = max(batch_multiple, b_cap // batch_multiple * batch_multiple)
         for s in range(0, len(rows_k), b_cap):
             rows = rows_k[s : s + b_cap]
             B = len(rows)
@@ -527,29 +574,48 @@ def _device_expand_sides(col_by_row, val_by_row, row_counts, val_scale):
     return c_row, v_row, c_opp, v_opp
 
 
+# a row's slice is 2.4 us, an element of a gather 21 ns: slices from here up
+_SLICE_MIN_K = 128
+
+
 def _valid_slots(counts, k: int):
     """``[B, K]`` mask of the slots that hold a rating."""
     return jnp.arange(k, dtype=jnp.int32)[None, :] < counts[:, None]
 
 
-def _expand_bucket(c_sorted, v_sorted, starts, counts, k: int):
+def _expand_bucket(c_sorted, v_sorted, starts, counts, k: int,
+                   tail: int = 0):
     """One bucket's padded ``[B, K]`` opposite-side ids and ratings from
     the row-grouped columns: row b's slots are ``c_sorted[starts[b] :
     starts[b] + counts[b]]``, the padding slots 0 / 0.0.
 
-    Read as B slices of length K, not B*K addresses: the TPU's
-    per-element gather from a one-dimensional array costs 21 ns an
-    element (the Netflix-size user side: 7.0 s against 1.1 s; PERF.md,
-    PR 30).  The columns' tail is padded by K, so that no slice is
-    clamped at the end and shifted.
+    From ``_SLICE_MIN_K`` entries a row, read as B slices of length K,
+    not B*K addresses: the TPU's per-element gather from a
+    one-dimensional array costs 21 ns an element (the Netflix-size user
+    side: 7.0 s against 1.1 s; PERF.md, PR 30).  Below it, as K single
+    elements a row: a slice costs its 2.4 us whatever its width, and
+    20 M rows of K = 8 were 27 s of a 40 s sweep (four chips, PR 34);
+    the block's bits are the same either way.  The columns' tail is
+    padded by K, so that no slice is clamped at the end and shifted;
+    ``tail`` is the padding the caller's columns already carry (a loop
+    over chunks pads once, outside it).
     """
     valid = _valid_slots(counts, k)
 
-    def block(column):
-        padded = jnp.pad(column, (0, k))
-        rows = jax.vmap(
+    def slices(padded):
+        return jax.vmap(
             lambda start: jax.lax.dynamic_slice(padded, (start,), (k,))
         )(starts)
+
+    def elements(padded):
+        return padded[starts[:, None]
+                      + jnp.arange(k, dtype=starts.dtype)[None, :]]
+
+    # k is a pad width, never a traced value
+    read = slices if k >= _SLICE_MIN_K else elements  # piolint: disable=PIO104
+
+    def block(column):
+        rows = read(jnp.pad(column, (0, max(k - tail, 0))))
         return jnp.where(valid, rows, 0)
 
     return block(c_sorted), block(v_sorted)
@@ -651,6 +717,36 @@ def _spd_solve(A: jax.Array, b: jax.Array, solver: str,
     return jax.lax.linalg.triangular_solve(
         L, y, left_side=True, lower=True, transpose_a=True
     )[..., 0]
+
+
+# rows of one partial sum of a table's Gram (`_table_gram`)
+_GRAM_BLOCK_ROWS = 4096
+
+
+def _table_gram(table: jax.Array, prec) -> jax.Array:
+    """``Y^T Y`` of a tall ``[M, R]`` table in float32, summed in blocks
+    of ``_GRAM_BLOCK_ROWS`` rows whose partial Grams are then added
+    together.  ONE contraction over millions of rows adds each row's
+    squares to a running sum thousands of times their size, and what the
+    sum's last bit drops is digits of the result (the four-chip cell,
+    PR 34: 2.4e-5 of the implicit half's solution, as much as computing
+    at ``high``); a block's sum stays within 2^12 of its terms."""
+    f32 = jnp.float32
+    n, r = table.shape
+    blocks = n // _GRAM_BLOCK_ROWS
+
+    def gram(rows):
+        return jnp.einsum("mr,ms->rs", rows, rows, precision=prec,
+                          preferred_element_type=f32)
+
+    total = jnp.zeros((r, r), f32)
+    if blocks:
+        head = table[: blocks * _GRAM_BLOCK_ROWS].reshape(
+            blocks, _GRAM_BLOCK_ROWS, r)
+        total = jax.lax.map(gram, head).sum(axis=0)
+    if n % _GRAM_BLOCK_ROWS:
+        total = total + gram(table[blocks * _GRAM_BLOCK_ROWS:])
+    return total
 
 
 def _half_iteration_impl(
@@ -765,6 +861,7 @@ def _solve_buckets(
     gram: Optional[jax.Array] = None,
     stop_after: Optional[str] = None,
     mesh: Optional[Mesh] = None,
+    exchange=None,
 ):
     """Shared bucket-solve math for the replicated and sharded paths
     (and the pio-live fold-in: `live/foldin.py` routes its
@@ -816,6 +913,12 @@ def _solve_buckets(
     (whose bucket batches arrive data-sharded): the Pallas kernels then
     run per device (:func:`_per_device`).  The sharded path calls this
     from inside its own ``shard_map`` body and leaves it None.
+
+    ``exchange`` (`parallel/collectives.ShardedRows`; the sharded path)
+    says that ``opp`` is this device's ``[M/d, R]`` shard and the
+    buckets' ids are global: the gather below then looks up all
+    devices' ids in the shard and a reduce-scatter returns this
+    device's rows, the same bits the gather from a whole table reads.
     """
     r = opp.shape[-1]
     sub = _block_sweeps(solver_mode, subspace_size, r)
@@ -828,7 +931,7 @@ def _solve_buckets(
         {"highest": "highest", "high": "high", "default": "default"}[precision]
     )
     if implicit and gram is None:
-        gram = jnp.einsum("mr,ms->rs", opp, opp, precision=prec)
+        gram = _table_gram(opp, prec)
     opp_g = (
         opp.astype(jnp.bfloat16)
         if gather_dtype == "bfloat16" and opp.dtype != jnp.bfloat16
@@ -889,6 +992,10 @@ def _solve_buckets(
                 out = upd_write(out, rows, x)
             continue
         with jax.named_scope("als.gather"):
+            if exchange is not None:
+                idx, valid_g = exchange.spread(idx, valid)
+            else:
+                valid_g = valid
             if opp_grp is not None:
                 # slab gather + in-slab select: exact same rows as the
                 # row gather, but every HBM read is a full memory tile.
@@ -922,11 +1029,13 @@ def _solve_buckets(
                         ],
                         axis=0,
                     )
-                Vm = Vm * valid[..., None].astype(Vm.dtype)
+                Vm = Vm * valid_g[..., None].astype(Vm.dtype)
             else:
-                Vm = opp_g[idx] * valid[..., None].astype(
+                Vm = opp_g[idx] * valid_g[..., None].astype(
                     opp_g.dtype
                 )                                            # [B, K, R]
+            if exchange is not None:
+                Vm = exchange.collect(Vm)
         if stop_after == "gather":
             out = (0.0 if out is None else out) + Vm.astype(f32).sum()
             continue
@@ -1072,6 +1181,36 @@ def _subspace_sweep(
     return acc if gram_probe else x0
 
 
+def _chunk_groups(buckets: list) -> list:
+    """Runs of consecutive bucket chunks of one shape (pad width and
+    batch): ``[[0, 1, 2], [3], ...]``.  A run is staged as ONE stacked
+    array and solved as one loop; `_assemble_buckets` emits a bucket's
+    full chunks first and its remainder last, so a pad width makes two
+    runs at most."""
+    def shape(b):
+        return b.k, len(b.rows)
+
+    groups: list = []
+    for j, b in enumerate(buckets):
+        if groups and shape(buckets[groups[-1][0]]) == shape(b):
+            groups[-1].append(j)
+        else:
+            groups.append([j])
+    return groups
+
+
+def _each_chunk(step, carry, chunks: tuple):
+    """``carry = step(carry, chunk)`` over the leading axis of
+    ``chunks`` (arrays ``[n, ...]``): a `lax.scan`, so n chunks of one
+    shape trace and lower ``step`` once; a lone chunk is the call
+    itself."""
+    if chunks[0].shape[0] == 1:
+        return step(carry, tuple(a[0] for a in chunks))
+    return jax.lax.scan(
+        lambda c, chunk: (step(c, chunk), None), carry, chunks
+    )[0]
+
+
 def build_sharded_half(
     mesh: Mesh,
     *,
@@ -1094,19 +1233,33 @@ def build_sharded_half(
 
     * Both factor tables live **sharded** ``P('data', None)`` at rest, so
       model capacity scales with total mesh HBM instead of one chip's.
-    * Per half-iteration, each device all-gathers the opposite table over
-      ICI (transient), solves its shard of every bucket's batch, then
-      all-gathers the small solved blocks ``[B, R]`` and writes only the
-      rows its own factor shard owns — updates never cross devices.
+    * No device ever holds the opposite table, or more of it than its
+      own shard: a chunk's opposite rows come through
+      `parallel/collectives.ShardedRows` (the chunk's ids all-gathered,
+      each device's look-up in its own shard, a reduce-scatter of the
+      ``[d*B, K, R]`` partial answers), the same bits a gather from the
+      whole table reads.  Each device then solves its shard of the
+      chunk, all-gathers the small solved blocks ``[B, R]`` and writes
+      only the rows its own factor shard owns.
     * Rating COO arrays are SHARDED ``P('data')``: each device holds only
       the slices of the bucket rows it solves, in shard-local order with
       shard-local starts (``_plan_shard_layout``) — rating capacity
       scales with mesh HBM like MLlib's co-partitioned rating blocks,
       and the int32-offset ceiling applies per shard.
+    * ``ks[g]`` is the pad width of chunk group ``g``, whose arrays are
+      ``[n, B]``: n chunks of one shape (``_chunk_groups``), run as ONE
+      loop, so a table of 600 chunks traces, lowers and compiles one
+      chunk's program a shape.
+
+    Two modes keep a whole-table gather, because what they run reads a
+    whole table: ``solver="fused"`` (the kernel fetches rows by DMA from
+    a table in HBM) all-gathers the opposite table, and the subspace
+    sweep all-gathers the table being UPDATED for its warm start.
 
     ``coded=True`` (coded-ALS, arXiv 2105.03631; `parallel/coded.py`)
-    builds the straggler-tolerant variant.  Signature grows two inputs
-    and one output::
+    builds the straggler-tolerant variant, which reconstructs a late
+    shard's block inside the gathered table and so keeps the all-gather.
+    Signature grows two inputs and one output::
 
         fn(upd, opp, opp_parity, ok_mask, c, v, lam, alpha, *buckets)
           -> (new_upd, new_upd_parity)
@@ -1125,27 +1278,25 @@ def build_sharded_half(
     padding rows carry ids >= the padded row count, so they drop out of
     every shard's scatter window.
     """
-    from ..parallel.collectives import shard_map
+    from ..parallel.collectives import (
+        EXCHANGE_SCOPE, ShardedRows, shard_map,
+    )
 
     axis = DATA_AXIS
     d = mesh.shape[axis]
     f32 = jnp.float32
+    tail = max(ks, default=0)
 
-    def solve_core(upd, opp_full, gram, c_sorted, v_sorted, lam, alpha,
-                   flat_buckets):
+    def solve_core(upd, opp, gram, c_sorted, v_sorted, lam, alpha,
+                   flat_buckets, exchange=None):
         me = jax.lax.axis_index(axis)
         shard_n = upd.shape[0]
         lo = (me * shard_n).astype(jnp.int32)
-        # the shard-local COO is expanded here, in every half; the
-        # replicated path's staging does it once (_expand_side)
-        triples = [
-            flat_buckets[i : i + 3] for i in range(0, len(flat_buckets), 3)
-        ]
-        bucket_args = tuple(
-            (rows, *_expand_bucket(c_sorted, v_sorted, starts, counts, k),
-             counts)
-            for (rows, starts, counts), k in zip(triples, ks)
-        )
+        # the shard-local COO is expanded here, in every half, a chunk
+        # at a time; the replicated path's staging does it once
+        # (_expand_side).  The columns are padded once for every chunk
+        c_sorted = jnp.pad(c_sorted, (0, tail))
+        v_sorted = jnp.pad(v_sorted, (0, tail))
         # subspace mode warm-starts each row's block sweep from the
         # CURRENT factor value, but this device solves rows owned by
         # OTHER shards — gather the full updating table transiently
@@ -1156,26 +1307,50 @@ def build_sharded_half(
         if _block_sweeps(solver_mode, subspace_size, upd.shape[-1]):
             upd_full = jax.lax.all_gather(upd, axis, axis=0, tiled=True)
 
-        def write(acc, rows, x):
-            acc = upd if acc is None else acc
-            xg = jax.lax.all_gather(x, axis, axis=0, tiled=True)   # [B, R]
-            rg = jax.lax.all_gather(rows, axis, axis=0, tiled=True)
-            local = rg - lo
-            inside = (local >= 0) & (local < shard_n)
-            # OOB sentinel: shard_n is out of range -> dropped by the
-            # scatter (covers other shards' rows AND bucket padding)
-            safe = jnp.where(inside, local, shard_n)
-            return acc.at[safe].set(xg.astype(acc.dtype), mode="drop")
+        def chunk_step(k):
+            def step(table, chunk):
+                rows, starts, counts = chunk
 
-        out = _solve_buckets(
-            write, opp_full, bucket_args, lam, alpha,
-            ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
-            precision=precision, solver=solver,
-            gather_dtype=gather_dtype, gather_mode=gather_mode,
-            solver_mode=solver_mode, subspace_size=subspace_size,
-            upd_table=upd_full, gram=gram,
-        )
-        return upd if out is None else out
+                def write(acc, rows, x):
+                    acc = table if acc is None else acc
+                    with jax.named_scope(EXCHANGE_SCOPE):
+                        xg = jax.lax.all_gather(x, axis, axis=0, tiled=True)
+                        rg = jax.lax.all_gather(
+                            rows, axis, axis=0, tiled=True)
+                    local = rg - lo
+                    inside = (local >= 0) & (local < shard_n)
+                    # OOB sentinel: shard_n is out of range -> dropped by
+                    # the scatter (covers other shards' rows AND bucket
+                    # padding)
+                    safe = jnp.where(inside, local, shard_n)
+                    return acc.at[safe].set(
+                        xg.astype(acc.dtype), mode="drop")
+
+                bucket = (
+                    rows,
+                    *_expand_bucket(c_sorted, v_sorted, starts, counts, k,
+                                    tail=tail),
+                    counts,
+                )
+                out = _solve_buckets(
+                    write, opp, (bucket,), lam, alpha,
+                    ks=(k,), implicit=implicit,
+                    weighted_lambda=weighted_lambda,
+                    precision=precision, solver=solver,
+                    gather_dtype=gather_dtype, gather_mode=gather_mode,
+                    solver_mode=solver_mode, subspace_size=subspace_size,
+                    upd_table=upd_full, gram=gram, exchange=exchange,
+                )
+                return table if out is None else out
+
+            return step
+
+        table = upd
+        for g, k in enumerate(ks):
+            table = _each_chunk(
+                chunk_step(k), table, flat_buckets[3 * g : 3 * g + 3]
+            )
+        return table
 
     def _prec():
         return jax.lax.Precision(
@@ -1187,34 +1362,35 @@ def build_sharded_half(
     P_ = P
     sharded2 = P_(axis, None)
     rep = P_()
-    bucket_specs = (P_(axis),) * (3 * len(ks))
+    bucket_specs = (P_(None, axis),) * (3 * len(ks))
 
     if not coded:
 
         def body(upd, opp, c_sorted, v_sorted, lam, alpha, *flat_buckets):
             # upd/opp arrive as local shards [Np/d, R] / [Mp/d, R]
-            # cast BEFORE the all-gather so bf16 mode also halves ICI
-            # traffic
-            opp_send = (
-                opp.astype(jnp.bfloat16)
-                if gather_dtype == "bfloat16"
-                else opp
-            )
-            opp_full = jax.lax.all_gather(
-                opp_send, axis, axis=0, tiled=True
-            )
             gram = None
             if implicit:
                 # YtY from the LOCAL shard + psum: identical [R, R]
                 # result at 1/d the FLOPs of redoing the full einsum on
                 # every device
-                gram = jax.lax.psum(
-                    jnp.einsum("mr,ms->rs", opp, opp, precision=_prec()),
-                    axis,
+                with jax.named_scope(EXCHANGE_SCOPE):
+                    gram = jax.lax.psum(_table_gram(opp, _prec()), axis)
+            if solver == "fused":
+                # the kernel reads a whole table in HBM; cast BEFORE
+                # the all-gather so bf16 mode also halves ICI traffic
+                opp_send = (
+                    opp.astype(jnp.bfloat16)
+                    if gather_dtype == "bfloat16"
+                    else opp
+                )
+                return solve_core(
+                    upd, jax.lax.all_gather(opp_send, axis, axis=0,
+                                            tiled=True),
+                    gram, c_sorted, v_sorted, lam, alpha, flat_buckets,
                 )
             return solve_core(
-                upd, opp_full, gram, c_sorted, v_sorted, lam, alpha,
-                flat_buckets,
+                upd, opp, gram, c_sorted, v_sorted, lam, alpha,
+                flat_buckets, exchange=ShardedRows(axis, opp.shape[0]),
             )
 
         in_specs = (
@@ -1324,6 +1500,7 @@ class ALSTrainer:
         self.sharded = (
             cfg.factor_placement == "sharded" and self.mesh is not None
         )
+        caps = self._chunk_caps(n_dev)
         # single-device "sharded" degenerates to replicated, and coded
         # parity with it (there is no ring to straggle)
         self.coded = False
@@ -1355,7 +1532,7 @@ class ALSTrainer:
                 build_bucket_layout(
                     u, i, v, nu, cfg.min_bucket_k,
                     cfg.max_ratings_per_row, batch_multiple=n_dev,
-                    starts_dtype=np.int64,
+                    starts_dtype=np.int64, **caps,
                 ),
                 n_dev,
             )
@@ -1363,7 +1540,7 @@ class ALSTrainer:
                 build_bucket_layout(
                     i, u, v, ni, cfg.min_bucket_k,
                     cfg.max_ratings_per_row, batch_multiple=n_dev,
-                    starts_dtype=np.int64,
+                    starts_dtype=np.int64, **caps,
                 ),
                 n_dev,
             )
@@ -1383,12 +1560,14 @@ class ALSTrainer:
                     build_bucket_layout(
                         u, i, v, nu, cfg.min_bucket_k,
                         cfg.max_ratings_per_row, batch_multiple=n_dev,
+                        **caps,
                     )
                 )
                 self._item_side = self._stage(
                     build_bucket_layout(
                         i, u, v, ni, cfg.min_bucket_k,
                         cfg.max_ratings_per_row, batch_multiple=n_dev,
+                        **caps,
                     )
                 )
         if self.sharded:
@@ -1401,12 +1580,18 @@ class ALSTrainer:
             return {name: side.get(key, 0) for name, side in sides.items()}
 
         self._plan_solves()
+        self._plan_exchange()
         staged = {
             "solver": cfg.solver,
             "solvePath": self.solve_path,
             "solveSystems": self.solve_systems,
             "staging": self.staging,
             "placement": "sharded" if self.sharded else "replicated",
+            "shards": n_dev if self.sharded else 1,
+            "oppTransientBytes": self.opp_transient_bytes,
+            "gramChunkBytes": self.gram_chunk_bytes,
+            "chunksLooped": self.chunks_looped,
+            "exchangeBytes": self.exchange_bytes,
             "devices": n_dev,
             "devicesWithData": self.data_devices(),
             "paddedEntries": per_side("padded_entries"),
@@ -1415,6 +1600,19 @@ class ALSTrainer:
         }
         logger.info("ALS staged: %s", staged)
         tower.note_event("als_staged", **staged)
+
+    def _chunk_caps(self, n_dev: int) -> dict:
+        """The bounds every staging path hands `_assemble_buckets`: a
+        chunk's rows by the bytes of its Gram and, under sharded
+        placement, its entries by the bytes of the exchanged rows (else
+        ``MAX_ENTRIES_PER_BUCKET``)."""
+        return {
+            "max_rows": gram_chunk_rows(self.cfg.rank, n_dev),
+            "max_entries": (
+                exchange_chunk_entries(self.cfg.rank, n_dev)
+                if self.sharded else None
+            ),
+        }
 
     def _plan_solves(self) -> None:
         """What `_spd_solve` will be handed in every half, and by which
@@ -1432,13 +1630,71 @@ class ALSTrainer:
             from ..ops.fused_als import fused_tile_plan
         self.solve_systems = {
             name: -(-cfg.rank // width) * sum(
-                int(bucket[0].shape[0])
+                int(bucket[0].size)
                 for bucket, k in zip(side["buckets"], side["ks"])
                 if not (fused and fused_tile_plan(cfg.rank, k) is not None)
             )
             for name, side in (("user", self._user_side),
                                ("item", self._item_side))
         }
+
+    def _plan_exchange(self) -> None:
+        """What a half moves between devices and holds in their place,
+        from the staged shapes alone, a side (the side being solved):
+
+        * ``exchange_bytes``: bytes ONE device receives in a half
+          (sharded placement: each chunk's ids all-gathered, the
+          reduce-scatter of its ``[d*B, K, R]`` partial rows, the solved
+          ``[B, R]`` blocks and their row ids all-gathered, the implicit
+          YtY all-reduced; the whole table where a mode all-gathers it).
+        * ``opp_transient_bytes``: the most a device holds in the
+          opposite table's place at once (the partial rows and the
+          chunk's own; the whole table where a mode all-gathers it).
+        * ``gram_chunk_bytes``: the largest chunk's ``[B, R, R]`` float32
+          Gram on one device.
+        * ``chunks_looped``: chunks that run inside a loop over chunks
+          of their shape (0 under replicated placement, which unrolls).
+        """
+        cfg = self.cfg
+        r = cfg.rank
+        d = self.mesh.size if self.mesh is not None else 1
+        row_bytes = r * jnp.dtype(cfg.gather_dtype).itemsize
+        table_rows = {"user": self._pad_items, "item": self._pad_users}
+        whole_opp = self.coded or cfg.solver == "fused"
+        sub = _block_sweeps(cfg.solver_mode, cfg.subspace_size, r)
+        self.exchange_bytes, self.opp_transient_bytes = {}, {}
+        self.chunks_looped = {}
+        gram_rows = 0
+        for name, side in (("user", self._user_side),
+                           ("item", self._item_side)):
+            received = transient = looped = 0
+            for bucket, k in zip(side["buckets"], side["ks"]):
+                rows = bucket[0]
+                n, b = (rows.shape[0], rows.shape[1] // d) \
+                    if self.sharded else (1, rows.shape[0] // d)
+                gram_rows = max(gram_rows, b)
+                if not self.sharded:
+                    continue
+                looped += n if n > 1 else 0
+                received += n * (d - 1) * b * (r * 4 + 4)
+                if not whole_opp:
+                    received += n * (d - 1) * b * k * (4 + row_bytes)
+                    transient = max(transient, (d + 1) * b * k * row_bytes)
+            if self.sharded:
+                shard = table_rows[name] // d * row_bytes
+                if whole_opp:
+                    received += (d - 1) * shard
+                    transient = d * shard
+                if sub:
+                    upd_rows = {"user": self._pad_users,
+                                "item": self._pad_items}[name]
+                    received += (d - 1) * upd_rows // d * r * 4
+                if cfg.implicit:
+                    received += 2 * (d - 1) * r * r * 4 // d
+            self.exchange_bytes[name] = int(received)
+            self.opp_transient_bytes[name] = int(transient)
+            self.chunks_looped[name] = int(looped)
+        self.gram_chunk_bytes = int(gram_rows * r * r * 4)
 
     def data_devices(self) -> int:
         """How many devices hold staged training data: the per-bucket
@@ -1631,6 +1887,7 @@ class ALSTrainer:
         )
         self._build_sharded_halves()
         self._plan_solves()
+        self._plan_exchange()
         # distributed staging holds only LOCAL triples; a global
         # training loss is not computable from one process
         self.loss_every = 0
@@ -1660,7 +1917,7 @@ class ALSTrainer:
         buckets = _assemble_buckets(
             counts, starts, n_rows_pad, cfg.min_bucket_k,
             cfg.max_ratings_per_row, batch_multiple=n_dev,
-            starts_dtype=np.int64,
+            starts_dtype=np.int64, **self._chunk_caps(n_dev),
         )
         _, local_starts, L = _plan_shard_layout(
             buckets, n_dev, build_perm=False
@@ -1724,21 +1981,13 @@ class ALSTrainer:
         v_g = jax.make_array_from_single_device_arrays(
             (n_dev * L,), sh, v_parts
         )
-        from ..parallel.mesh import shard_put
-
+        ks, groups = self._put_chunk_groups(buckets, local_starts)
         return {
             "c_sorted": c_g,
             "v_sorted": v_g,
             "shard_len": L,
-            "ks": tuple(b.k for b in buckets),
-            "buckets": tuple(
-                (
-                    shard_put(b.rows, self.mesh, P(DATA_AXIS)),
-                    shard_put(ls, self.mesh, P(DATA_AXIS)),
-                    shard_put(b.counts, self.mesh, P(DATA_AXIS)),
-                )
-                for b, ls in zip(buckets, local_starts)
-            ),
+            "ks": ks,
+            "buckets": groups,
         }
 
     def _stage_device(self, u, i, v, nu, ni, n_dev):
@@ -1800,14 +2049,15 @@ class ALSTrainer:
             ([0], np.cumsum(counts_i)[:-1])
         ).astype(np.int32)
         cfg = self.cfg
+        caps = self._chunk_caps(n_dev)
         buckets_u = _assemble_buckets(
             np.asarray(counts_u, np.int32), np.asarray(starts_u, np.int32),
             nu, cfg.min_bucket_k, cfg.max_ratings_per_row,
-            batch_multiple=n_dev,
+            batch_multiple=n_dev, **caps,
         )
         buckets_i = _assemble_buckets(
             counts_i, starts_i, ni, cfg.min_bucket_k,
-            cfg.max_ratings_per_row, batch_multiple=n_dev,
+            cfg.max_ratings_per_row, batch_multiple=n_dev, **caps,
         )
 
         def compact_ids(x, n):
@@ -1908,16 +2158,37 @@ class ALSTrainer:
         # after a replicated-path read); device_put would reject the
         # non-addressable devices
         put_dp = lambda x: shard_put(x, self.mesh, P(DATA_AXIS))  # noqa: E731
+        ks, groups = self._put_chunk_groups(layout.buckets, local_starts)
         return {
             "c_sorted": put_dp(c_sh),
             "v_sorted": put_dp(v_sh),
             "shard_len": L,
-            "ks": tuple(b.k for b in layout.buckets),
-            "buckets": tuple(
-                (put_dp(b.rows), put_dp(ls), put_dp(b.counts))
-                for b, ls in zip(layout.buckets, local_starts)
-            ),
+            "ks": ks,
+            "buckets": groups,
         }
+
+    def _put_chunk_groups(self, buckets, local_starts) -> tuple:
+        """``(ks, groups)`` of a sharded side: the chunks of one shape
+        (:func:`_chunk_groups`) stacked as ``[n, B]`` arrays of rows,
+        shard-local starts and counts, each chunk's batch split over
+        the mesh."""
+        from ..parallel.mesh import shard_put
+
+        runs = _chunk_groups(buckets)
+
+        def put(arrays):
+            return shard_put(np.stack(arrays), self.mesh,
+                             P(None, DATA_AXIS))
+
+        return (
+            tuple(buckets[run[0]].k for run in runs),
+            tuple(
+                (put([buckets[j].rows for j in run]),
+                 put([local_starts[j] for j in run]),
+                 put([buckets[j].counts for j in run]))
+                for run in runs
+            ),
+        )
 
     @property
     def coo_shard_entries(self) -> Optional[int]:
@@ -2099,12 +2370,19 @@ class ALSTrainer:
         V: jax.Array,
         num_iterations: int,
         lam: Optional[float] = None,
+        donate: bool = False,
     ) -> tuple[jax.Array, jax.Array]:
         """Iterate; treats U/V functionally (the caller's arrays survive).
 
         The half-iterations donate their working buffers, so copy the
         inputs once up front — two [N, R] copies are noise next to one
         half-iteration, and callers keep usable arrays for warm restarts.
+        ``donate=True`` is for the caller that gives its arrays up (a
+        loop of ``U, V = run(U, V, 1, donate=True)``): the halves then
+        work on the caller's own buffers, which are gone when this
+        returns, and no second copy of the tables ever exists — the
+        difference between fitting and not where the tables are most
+        of the mesh's memory.
 
         ``lam`` overrides the config's regularization for THIS run: λ is
         a traced scalar, so sweeping it reuses the compiled executables
@@ -2113,8 +2391,9 @@ class ALSTrainer:
         """
         from ..resilience import faults
 
-        U = jnp.array(U, copy=True)
-        V = jnp.array(V, copy=True)
+        if not donate:
+            U = jnp.array(U, copy=True)
+            V = jnp.array(V, copy=True)
         if self.coded:
             # fresh iterates mean the cached parity blocks are stale:
             # recompute lazily from THESE tables on first use
@@ -2160,6 +2439,10 @@ class ALSTrainer:
             ALS_SOLVE_SYSTEMS_TOTAL.labels(path=self.solve_path).inc(
                 self.solve_systems["user"] + self.solve_systems["item"]
             )
+            for side_name, received in self.exchange_bytes.items():
+                if received:
+                    ALS_EXCHANGE_BYTES_TOTAL.labels(side=side_name).inc(
+                        received)
             if faults.fired("train.nan"):
                 # poison the iterates the way an exploding sweep would;
                 # the convergence watchdog must catch it THIS sweep
@@ -2202,7 +2485,7 @@ class ALSTrainer:
         U, V = self.init_factors()
         if checkpointer is None:
             # one call keeps the 2*num_iterations dispatches async
-            U, V = self.run(U, V, self.cfg.num_iterations)
+            U, V = self.run(U, V, self.cfg.num_iterations, donate=True)
             return self._factors(U, V)
         start = 0
         if resume:
@@ -2215,7 +2498,7 @@ class ALSTrainer:
         it = start
         while it < self.cfg.num_iterations:
             chunk = min(checkpoint_every, self.cfg.num_iterations - it)
-            U, V = self.run(U, V, chunk)
+            U, V = self.run(U, V, chunk, donate=True)
             it += chunk
             checkpointer.save(it, {"U": U, "V": V})
         return self._factors(U, V)

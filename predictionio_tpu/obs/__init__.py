@@ -218,6 +218,13 @@ ALS_SOLVE_SYSTEMS_TOTAL = _registry.counter(
     "lax.linalg)",
     labels=("path",),
 )
+ALS_EXCHANGE_BYTES_TOTAL = _registry.counter(
+    "pio_als_exchange_bytes_total",
+    "Bytes ONE device receives from the others in the sharded ALS "
+    "halves (ids, partial rows, solved blocks, YtY), by the side being "
+    "solved; counted from the staged shapes, once a sweep",
+    labels=("side",),
+)
 
 # pio-live (incremental fold-in) families: the daemon side books cycles
 # / scanned events / produced rows + per-phase timings; the serving side

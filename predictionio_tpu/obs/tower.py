@@ -341,6 +341,7 @@ class TowerSession:
         self._sweep = 0
         self._phase_totals: dict[str, float] = {}
         self._sweep_seconds_total = 0.0
+        self._bookkeeping_seconds = 0.0
         self._loss_history: list[tuple[int, float]] = []
         self._pending_events: list[dict] = []
         self._events_total = 0
@@ -444,6 +445,11 @@ class TowerSession:
             self.manifest.sweep(i, round(seconds, 6), phases, **extras)
         if self._publisher is not None:
             self._publisher.publish()
+        # this call's own time lies between two sweeps, inside neither:
+        # booked, so that the run still decomposes when a collector
+        # pause or a slow disk lands here
+        with self._lock:
+            self._bookkeeping_seconds += time.perf_counter() - mark
         try:
             self.watchdog.check(i, seconds, loss, factors_finite)
         except ConvergenceError as e:
@@ -464,7 +470,8 @@ class TowerSession:
         """The workflow layer reports the ``train.run`` span's wall
         time (read + prepare + staging + sweeps).  With the sweep
         marks this decomposes the whole span in the final record:
-        setup (span start -> first sweep) + sweeps + tail (last sweep
+        setup (span start -> first sweep) + sweeps + the sweeps'
+        bookkeeping (:meth:`record_sweep`'s own time) + tail (last sweep
         -> span end) — the cross-layer reconciliation
         ``tools/train_obs_smoke.py`` asserts to 2%."""
         with self._lock:
@@ -509,6 +516,9 @@ class TowerSession:
                         max(end - self._last_sweep_end, 0.0), 6))
             fields.setdefault(
                 "sweepSecondsTotal", round(self._sweep_seconds_total, 6)
+            )
+            fields.setdefault(
+                "bookkeepingSeconds", round(self._bookkeeping_seconds, 6)
             )
         if self._aggregator is not None and self.manifest is not None:
             try:

@@ -29,12 +29,57 @@ shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 __all__ = [
+    "ShardedRows",
     "all_reduce_sum",
     "all_gather_blocks",
     "all_to_all_blocks",
     "reduce_scatter_sum",
     "ring_shift",
 ]
+
+
+# names every collective of the sharded ALS half in the HLO metadata
+EXCHANGE_SCOPE = "als.exchange"
+
+
+class ShardedRows:
+    """Rows of a block-sharded ``[M, R]`` table by GLOBAL row id, from
+    inside a ``shard_map`` body, without any device ever holding more of
+    the table than its own ``[M/d, R]`` shard (ALX's sharded gather,
+    arXiv 2112.02194).
+
+    Every device asks for the rows of its own ``[B, K]`` ids.  The ids
+    of all devices are all-gathered (4 bytes an entry); each device
+    looks up, in its shard alone, the ids that fall in its block of
+    rows and leaves zeros for the rest (:meth:`spread`, then the
+    caller's own gather of ``[d*B, K, R]``); a reduce-scatter sums the
+    d partial answers and hands each device the ``[B, K, R]`` rows it
+    asked for (:meth:`collect`).  Exactly one of the d terms of each
+    sum is non-zero, so the rows are the table's own bits.
+    """
+
+    def __init__(self, axis: str, shard_rows: int):
+        self.axis = axis
+        self.shard_rows = shard_rows
+
+    def spread(self, idx: jax.Array, valid: jax.Array):
+        """``[B, K]`` global ids and their validity -> ``[d*B, K]`` ids
+        local to this device's shard and the mask of those it owns."""
+        with jax.named_scope(EXCHANGE_SCOPE):
+            asked = jax.lax.all_gather(
+                jnp.where(valid, idx, -1), self.axis, axis=0, tiled=True
+            )
+        local = asked - jax.lax.axis_index(self.axis) * self.shard_rows
+        # an invalid slot asks for -1, which no device owns
+        mine = (local >= 0) & (local < self.shard_rows)
+        return jnp.where(mine, local, 0), mine
+
+    def collect(self, rows: jax.Array) -> jax.Array:
+        """``[d*B, K, R]`` partial answers -> this device's ``[B, K, R]``."""
+        with jax.named_scope(EXCHANGE_SCOPE):
+            return jax.lax.psum_scatter(
+                rows, self.axis, scatter_dimension=0, tiled=True
+            )
 
 
 def all_reduce_sum(x: jax.Array, mesh: Mesh, axis: str = DATA_AXIS):

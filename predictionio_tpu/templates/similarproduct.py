@@ -222,21 +222,20 @@ class SimilarProductAlgorithm(Algorithm):
     params_class = SimilarALSParams
     placement = ModelPlacement.DEVICE_SHARDED
 
-    def train(self, ctx: WorkflowContext, data: SimilarTrainingData):
-        p = self.params
-        factors = train_als(
-            data.ratings,
-            cfg=ALSConfig(
-                rank=p.rank, num_iterations=p.num_iterations, lam=p.lam,
-                implicit=True, alpha=p.alpha, seed=p.seed,
-                solver=p.solver, factor_placement=p.factor_placement,
-                solver_mode=p.solver_mode,
-                subspace_size=p.subspace_size,
-                gather_dtype=p.gather_dtype,
-                gather_mode=p.gather_mode,
-            ),
-            mesh=ctx.mesh,
+    def _config(self) -> ALSConfig:
+        p: SimilarALSParams = self.params
+        return ALSConfig(
+            rank=p.rank, num_iterations=p.num_iterations, lam=p.lam,
+            implicit=True, alpha=p.alpha, seed=p.seed,
+            solver=p.solver, factor_placement=p.factor_placement,
+            solver_mode=p.solver_mode,
+            subspace_size=p.subspace_size,
+            gather_dtype=p.gather_dtype,
+            gather_mode=p.gather_mode,
         )
+
+    def train(self, ctx: WorkflowContext, data: SimilarTrainingData):
+        factors = train_als(data.ratings, cfg=self._config(), mesh=ctx.mesh)
         return SimilarALSModel(
             item_factors=normalize_rows(factors.item_factors),
             items=data.ratings.items,

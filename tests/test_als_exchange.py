@@ -1,0 +1,390 @@
+"""The sharded ALS half that never holds the opposite table, the bucket
+chunks bounded by the bytes of their Gram, and the chunks of one shape
+run as one loop: on the CPU's virtual devices (`tests/conftest.py`
+forces 8)."""
+
+import importlib.util
+import re
+from pathlib import Path
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from predictionio_tpu.models import als
+from predictionio_tpu.models.als import (
+    ALSConfig, ALSTrainer, _assemble_buckets, _chunk_groups,
+    exchange_chunk_entries, gram_chunk_rows,
+)
+from predictionio_tpu.parallel import make_mesh
+from predictionio_tpu.parallel.collectives import ShardedRows, shard_map
+
+ROOT = Path(__file__).resolve().parent.parent
+
+
+def _ratings(n_users=70, n_items=33, density=0.35, seed=5, positive=False):
+    rng = np.random.default_rng(seed)
+    mask = rng.random((n_users, n_items)) < density
+    u, i = np.nonzero(mask)
+    v = rng.normal(size=len(u)).astype(np.float32)
+    if positive:
+        v = np.abs(v) + 1.0
+    return u.astype(np.int32), i.astype(np.int32), v, n_users, n_items
+
+
+def _sweeps(cfg, data, mesh=None, n=2, **run_kw):
+    u, i, v, nu, ni = data
+    tr = ALSTrainer((u, i, v), nu, ni, cfg, mesh=mesh)
+    U, V = tr.init_factors()
+    U, V = tr.run(U, V, n, **run_kw)
+    return tr, np.asarray(U)[:nu], np.asarray(V)[:ni]
+
+
+# -- (a) the bounded half equals the replicated half ------------------------
+
+
+@pytest.mark.parametrize("shards", [4, 8])
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_sharded_half_matches_replicated(implicit, shards):
+    """70 users and 33 items divide by neither 4 nor 8: the tables are
+    padded with zero rows, which no device may count."""
+    data = _ratings(positive=implicit)
+    base = dict(rank=6, lam=0.05, implicit=implicit, alpha=2.0,
+                min_bucket_k=4)
+    _, U0, V0 = _sweeps(ALSConfig(**base), data)
+    tr, U1, V1 = _sweeps(
+        ALSConfig(**base, factor_placement="sharded"), data,
+        mesh=make_mesh(shards),
+    )
+    assert tr.sharded and tr.mesh.size == shards
+    np.testing.assert_allclose(U1, U0, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(V1, V0, rtol=1e-5, atol=1e-5)
+
+
+def test_sharded_rows_are_the_tables_own_bits():
+    """`ShardedRows`: every device asks for rows by global id and gets
+    the bits `table[idx]` reads, with zeros in the invalid slots."""
+    mesh = make_mesh(4)
+    rng = np.random.default_rng(0)
+    table = rng.normal(size=(24, 5)).astype(np.float32)
+    idx = rng.integers(0, 24, size=(16, 3)).astype(np.int32)
+    valid = rng.random((16, 3)) < 0.7
+
+    def body(shard, idx, valid):
+        rows = ShardedRows("data", shard.shape[0])
+        local, mine = rows.spread(idx, valid)
+        return rows.collect(shard[local] * mine[..., None])
+
+    got = shard_map(
+        body, mesh=mesh, in_specs=(P("data", None), P("data"), P("data")),
+        out_specs=P("data"),
+    )(table, idx, valid)
+    np.testing.assert_array_equal(
+        np.asarray(got), table[idx] * valid[..., None])
+
+
+def test_table_gram_sums_blocks_and_tail(monkeypatch):
+    """`_table_gram`: whole blocks through the loop, the tail beside
+    them, the same Y^T Y; and a table under one block is the one
+    contraction it always was."""
+    monkeypatch.setattr(als, "_GRAM_BLOCK_ROWS", 16)
+    import jax
+
+    rng = np.random.default_rng(2)
+    for rows in (7, 16, 50, 64):
+        table = rng.normal(size=(rows, 5)).astype(np.float32)
+        got = als._table_gram(jnp.asarray(table), jax.lax.Precision.HIGHEST)
+        want = table.astype(np.float64).T @ table.astype(np.float64)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("k", [8, 64, 128, 256])
+def test_expand_bucket_gathers_narrow_rows_and_slices_wide_ones(k):
+    """Under `_SLICE_MIN_K` entries a row the block is one gather, from
+    there up B slices: the same block either way."""
+    rng = np.random.default_rng(k)
+    counts = rng.integers(0, k + 1, size=20).astype(np.int32)
+    starts = np.concatenate(([0], np.cumsum(counts)[:-1])).astype(np.int32)
+    col = rng.integers(0, 1000, size=int(counts.sum())).astype(np.int32)
+    val = rng.normal(size=len(col)).astype(np.float32)
+    idx, v = als._expand_bucket(jnp.asarray(col), jnp.asarray(val),
+                                jnp.asarray(starts), jnp.asarray(counts), k)
+    for b in range(20):
+        n, s0 = counts[b], starts[b]
+        np.testing.assert_array_equal(np.asarray(idx)[b, :n], col[s0:s0 + n])
+        np.testing.assert_array_equal(np.asarray(v)[b, :n], val[s0:s0 + n])
+        assert not np.asarray(idx)[b, n:].any()
+        assert not np.asarray(v)[b, n:].any()
+    import jax
+
+    text = jax.jit(als._expand_bucket, static_argnums=4).lower(
+        jnp.asarray(col), jnp.asarray(val), jnp.asarray(starts),
+        jnp.asarray(counts), k).as_text()
+    # a gather of whole K-wide slices, or of single elements
+    assert (f"slice_sizes = array<i64: {k}>" in text) == (
+        k >= als._SLICE_MIN_K)
+    assert ("slice_sizes = array<i64: 1>" in text) == (
+        k < als._SLICE_MIN_K)
+
+
+# -- (b) no device holds the whole opposite table ---------------------------
+
+
+def _compiled_half_text(tr, side_name):
+    side = tr._user_side if side_name == "user" else tr._item_side
+    fn = (tr._sharded_user_half if side_name == "user"
+          else tr._sharded_item_half)
+    U, V = tr.init_factors()
+    upd, opp = (U, V) if side_name == "user" else (V, U)
+    flat = [a for b in side["buckets"] for a in b]
+    args = [upd, opp]
+    if tr.coded:
+        args += [tr._coded_parity("opp", opp),
+                 jnp.ones(tr.mesh.size, jnp.float32)]
+    args += [side["c_sorted"], side["v_sorted"], jnp.float32(0.1),
+             jnp.float32(1.0), *flat]
+    return fn.lower(*args).compile().as_text()
+
+
+def _dims(hlo_text):
+    """Every array dimension that appears in a compiled module."""
+    return {
+        int(n)
+        for shape in re.findall(r"[a-z]\d*\[([\d,]+)\]", hlo_text)
+        for n in shape.split(",")
+    }
+
+
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_no_op_of_a_sharded_half_has_the_opposite_tables_rows(implicit):
+    """The property the exchange exists for: in the compiled, per-device
+    module of a half, no operation's result (or operand) has the
+    opposite table's row count, padded or not.  1,003 users and 517
+    items on 4 devices: 1,004 and 520 rows, 251 and 130 a shard; no
+    bucket dimension comes near them."""
+    nu, ni = 1003, 517
+    data = _ratings(nu, ni, density=0.02, seed=3, positive=implicit)
+    cfg = ALSConfig(rank=6, implicit=implicit, min_bucket_k=4,
+                    factor_placement="sharded")
+    tr = ALSTrainer(data[:3], nu, ni, cfg, mesh=make_mesh(4))
+    for side, opp_rows, shard_rows in (("user", (517, 520), 130),
+                                       ("item", (1003, 1004), 251)):
+        dims = _dims(_compiled_half_text(tr, side))
+        assert shard_rows in dims          # the check reads real shapes
+        assert not dims & set(opp_rows), (side, sorted(dims))
+
+
+def test_the_check_sees_a_whole_table_where_a_half_gathers_one():
+    """Positive control: the coded half reconstructs a late shard inside
+    the gathered table, keeps the all-gather, and the same reading of
+    the compiled module finds the opposite table's rows in it."""
+    nu, ni = 1003, 517
+    data = _ratings(nu, ni, density=0.02, seed=3)
+    cfg = ALSConfig(rank=6, min_bucket_k=4, factor_placement="sharded",
+                    coded_shards=True)
+    tr = ALSTrainer(data[:3], nu, ni, cfg, mesh=make_mesh(4))
+    assert 1004 in _dims(_compiled_half_text(tr, "item"))
+    assert tr.opp_transient_bytes["item"] == 1004 * 6 * 4
+
+
+# -- (c) chunks bounded by the bytes of their Gram --------------------------
+
+
+def _shapes(buckets):
+    return [(b.k, len(b.rows)) for b in buckets]
+
+
+def test_no_chunks_gram_exceeds_the_bound_at_rank_128(monkeypatch):
+    monkeypatch.setattr(als, "_device_memory_bytes", lambda: int(16.9e9))
+    rows = gram_chunk_rows(128, 4)
+    assert rows == 4 * 8192
+    entries = exchange_chunk_entries(128, 4)
+    assert entries == 4 * 262144
+    rng = np.random.default_rng(1)
+    counts = rng.integers(1, 9, size=300_000).astype(np.int64)
+    counts[:700] = rng.integers(200, 5000, size=700)
+    starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+    buckets = _assemble_buckets(counts, starts, len(counts), 8, 0, 4,
+                                entries, starts_dtype=np.int64,
+                                max_rows=rows)
+    bound = int(16.9e9) // 16
+    for b in buckets:
+        per_dev = len(b.rows) // 4
+        assert per_dev * 128 * 128 * 4 <= bound
+        assert (4 + 1) * per_dev * b.k * 128 * 4 <= bound
+    assert sum(int((b.rows < len(counts)).sum()) for b in buckets) \
+        == len(counts)
+    # the entry cap alone would have staged one K=8 chunk of 34 GB of Gram
+    unbounded = _assemble_buckets(counts, starts, len(counts), 8, 0, 4,
+                                  starts_dtype=np.int64)
+    assert max(len(b.rows) for b in unbounded) > 8 * rows
+
+
+def test_the_bound_leaves_small_ranks_and_one_device_alone():
+    assert gram_chunk_rows(10, 1) >= als.MAX_ENTRIES_PER_BUCKET // 8
+    assert exchange_chunk_entries(10, 8) == als.MAX_ENTRIES_PER_BUCKET
+    assert gram_chunk_rows(64, 1) in (32768, 65536)
+
+
+def _netflix_degrees():
+    spec = importlib.util.spec_from_file_location(
+        "train_sweeps_for_degrees",
+        ROOT / "perfbench" / "drivers" / "train_sweeps.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    import json
+
+    cfg = json.loads(
+        (ROOT / "perfbench" / "configs" / "rec-netflix-r64.json").read_text())
+    n = cfg["n_ratings"]
+    return (
+        module.capped_power_law(cfg["n_users"], cfg["user_exponent"], n,
+                                cfg["user_max_ratings"]),
+        module.capped_power_law(cfg["n_items"], cfg["item_exponent"], n,
+                                cfg["item_max_ratings"]),
+    )
+
+
+# what `rec-netflix-r64.train` staged at commit 0dd1ecb (PR 32), from its
+# configuration's own degrees: (pad width, rows) of every bucket chunk
+NETFLIX_USER_SHAPES = (
+    [(128, 32768)] * 8 + [(128, 28276)] + [(256, 16384)] * 7 + [(256, 4386)]
+    + [(512, 8192)] * 5 + [(512, 3436)] + [(1024, 4096)] * 4 + [(1024, 138)]
+    + [(2048, 2048)] * 3 + [(4096, 1024)] * 2 + [(4096, 235)]
+    + [(8192, 512), (8192, 337), (16384, 256), (16384, 59), (32768, 128),
+       (32768, 58)]
+)
+
+
+def test_netflix_degrees_stage_the_shapes_they_staged(monkeypatch):
+    """`rec-netflix-r64.train` must not move: from the configuration's
+    own degrees, at its full size, the bound cuts no chunk (on a 16.9 GB
+    v5e and on a backend that reports nothing), and the user side's
+    shapes are the pinned ones."""
+    counts_u, counts_i = _netflix_degrees()
+    for memory in (int(16.9e9), 16 << 30):
+        monkeypatch.setattr(als, "_device_memory_bytes", lambda m=memory: m)
+        for counts in (counts_u, counts_i):
+            starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+            old = _assemble_buckets(counts, starts, len(counts), 8, 0, 1)
+            new = _assemble_buckets(counts, starts, len(counts), 8, 0, 1,
+                                    max_rows=gram_chunk_rows(64, 1))
+            assert _shapes(new) == _shapes(old)
+    starts = np.concatenate(([0], np.cumsum(counts_u)[:-1]))
+    staged = _assemble_buckets(counts_u, starts, len(counts_u), 8, 0, 1,
+                               max_rows=gram_chunk_rows(64, 1))
+    assert _shapes(staged) == NETFLIX_USER_SHAPES
+
+
+# -- (d) the looped chunks give bitwise what the unrolled ones gave ---------
+
+
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_looped_chunks_bitwise_as_unrolled(monkeypatch, implicit):
+    monkeypatch.setattr(als, "MAX_ENTRIES_PER_BUCKET", 64)
+    data = _ratings(positive=implicit)
+    cfg = ALSConfig(rank=6, lam=0.05, implicit=implicit, alpha=2.0,
+                    min_bucket_k=4, factor_placement="sharded")
+    mesh = make_mesh(4)
+    looped, U1, V1 = _sweeps(cfg, data, mesh=mesh)
+    assert looped.chunks_looped["user"] >= 4
+    assert looped.chunks_looped["item"] >= 2
+    assert any(b[0].shape[0] > 1 for b in looped._user_side["buckets"])
+    monkeypatch.setattr(
+        als, "_chunk_groups", lambda buckets: [[j] for j in range(len(buckets))])
+    unrolled, U2, V2 = _sweeps(cfg, data, mesh=mesh)
+    assert unrolled.chunks_looped == {"user": 0, "item": 0}
+    assert all(b[0].shape[0] == 1 for b in unrolled._user_side["buckets"])
+    np.testing.assert_array_equal(U1, U2)
+    np.testing.assert_array_equal(V1, V2)
+
+
+def test_chunk_groups_are_runs_of_one_shape():
+    counts = np.array([3] * 20 + [30] * 3, np.int64)
+    starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+    buckets = _assemble_buckets(counts, starts, len(counts), 4, 0, 2,
+                                max_entries=32)
+    assert _shapes(buckets) == [(4, 8), (4, 8), (4, 4), (32, 2), (32, 2)]
+    assert _chunk_groups(buckets) == [[0, 1], [2], [3, 4]]
+
+
+# -- (e) counters, the staged event, and the caller's arrays ----------------
+
+
+def test_the_tracing_carries_the_exchange(monkeypatch):
+    from predictionio_tpu.obs import ALS_EXCHANGE_BYTES_TOTAL, tower
+
+    events = []
+    monkeypatch.setattr(
+        tower, "note_event", lambda name, **f: events.append((name, f)))
+    monkeypatch.setattr(als, "MAX_ENTRIES_PER_BUCKET", 64)
+    data = _ratings(positive=True)
+    u, i, v, nu, ni = data
+    cfg = ALSConfig(rank=6, implicit=True, min_bucket_k=4,
+                    factor_placement="sharded")
+    tr = ALSTrainer((u, i, v), nu, ni, cfg, mesh=make_mesh(4))
+    (name, staged), = events
+    assert name == "als_staged"
+    assert staged["placement"] == "sharded" and staged["shards"] == 4
+    d, r = 4, 6
+    for which, side in (("user", tr._user_side), ("item", tr._item_side)):
+        want = 2 * (d - 1) * r * r * 4 // d          # YtY, all-reduced
+        transient = 0
+        for (rows, _, _), k in zip(side["buckets"], side["ks"]):
+            n, b = rows.shape[0], rows.shape[1] // d
+            # ids + partial rows in, solved rows + their ids back
+            want += n * (d - 1) * b * (k * (4 + r * 4) + r * 4 + 4)
+            transient = max(transient, (d + 1) * b * k * r * 4)
+        assert staged["exchangeBytes"][which] == want > 0
+        assert staged["oppTransientBytes"][which] == transient
+    widest = max(b[0].shape[1] // d for side in (tr._user_side, tr._item_side)
+                 for b in side["buckets"])
+    assert staged["gramChunkBytes"] == widest * r * r * 4
+    assert staged["chunksLooped"] == tr.chunks_looped
+    assert staged["chunksLooped"]["user"] > 0
+    counters = {s: ALS_EXCHANGE_BYTES_TOTAL.labels(side=s)
+                for s in ("user", "item")}
+    before = {s: c.value() for s, c in counters.items()}
+    U, V = tr.init_factors()
+    tr.run(U, V, 3)
+    for s, c in counters.items():
+        assert c.value() - before[s] == 3 * staged["exchangeBytes"][s]
+
+
+def test_replicated_placement_reports_no_exchange(monkeypatch):
+    from predictionio_tpu.obs import tower
+
+    events = []
+    monkeypatch.setattr(
+        tower, "note_event", lambda name, **f: events.append((name, f)))
+    u, i, v, nu, ni = _ratings()
+    ALSTrainer((u, i, v), nu, ni, ALSConfig(rank=6, min_bucket_k=4))
+    (_, staged), = events
+    assert staged["placement"] == "replicated" and staged["shards"] == 1
+    assert staged["exchangeBytes"] == {"user": 0, "item": 0}
+    assert staged["oppTransientBytes"] == {"user": 0, "item": 0}
+    assert staged["chunksLooped"] == {"user": 0, "item": 0}
+    assert staged["gramChunkBytes"] > 0
+
+
+@pytest.mark.parametrize("placement", ["replicated", "sharded"])
+def test_a_donating_run_consumes_the_callers_tables(placement):
+    """`run(..., donate=True)`: no second copy of the tables, the same
+    sweep, and the caller's arrays are gone; without it they survive."""
+    data = _ratings()
+    u, i, v, nu, ni = data
+    mesh = make_mesh(4) if placement == "sharded" else None
+    cfg = ALSConfig(rank=6, min_bucket_k=4, factor_placement=placement)
+    tr = ALSTrainer((u, i, v), nu, ni, cfg, mesh=mesh)
+    U0, V0 = tr.init_factors()
+    U1, V1 = tr.run(U0, V0, 1)
+    assert not U0.is_deleted() and not V0.is_deleted()
+    U2, V2 = tr.run(U0, V0, 1, donate=True)
+    assert U0.is_deleted() and V0.is_deleted()
+    np.testing.assert_array_equal(np.asarray(U1), np.asarray(U2))
+    np.testing.assert_array_equal(np.asarray(V1), np.asarray(V2))

@@ -322,6 +322,22 @@ def test_sweep_telemetry_manifest_complete():
     assert runlog.summarize(view)["sweepsPlanned"] == 4
 
 
+def test_record_sweep_books_its_own_time(monkeypatch):
+    """What `record_sweep` itself takes lies between two sweeps: the
+    final record carries it as `bookkeepingSeconds`, so a pause that
+    lands there (here: a slow device sampling) is still accounted."""
+    import time
+
+    def slow_sampling():
+        time.sleep(0.05)
+
+    monkeypatch.setattr(tower, "_device_high_water", slow_sampling)
+    view = _train(iid="bookkeeping")
+    final = view["final"]
+    assert final["bookkeepingSeconds"] >= 4 * 0.05
+    assert final["bookkeepingSeconds"] < final["wallSeconds"]
+
+
 def test_sweep_loss_cadence_and_off():
     from predictionio_tpu.models.als import ALSConfig
 

@@ -139,7 +139,7 @@ def main(argv=None) -> int:
         run_s = final["trainRunSeconds"]
         accounted = (
             final["setupSeconds"] + final["sweepSecondsTotal"]
-            + final["tailSeconds"]
+            + final["bookkeepingSeconds"] + final["tailSeconds"]
         )
         run_gap = abs(accounted - run_s) / run_s
         invariants["sweep_phase_sums_within_2pct"] = worst_sweep <= 0.02

@@ -51,8 +51,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import (
-    ALS_EXCHANGE_BYTES_TOTAL, ALS_SOLVE_SYSTEMS_TOTAL, TRAIN_PHASE_SECONDS,
-    tower, xray,
+    ALS_EXCHANGE_BYTES_TOTAL, ALS_GRAM_ENTRIES_TOTAL, ALS_SOLVE_SYSTEMS_TOTAL,
+    TRAIN_PHASE_SECONDS, tower, xray,
 )
 from ..obs.timeline import annotate
 from ..parallel.mesh import DATA_AXIS, pad_to_multiple, replicated
@@ -96,11 +96,12 @@ def _device_memory_bytes() -> int:
     return int(stats.get("bytes_limit", 16 << 30))
 
 
-def _rows_in_memory_share(row_bytes: int) -> int:
-    """How many rows of ``row_bytes`` fit a sixteenth of one device's
-    memory, rounded down to a power of two, so that a few bytes more or
-    less of ``bytes_limit`` stage the same shapes."""
-    rows = _device_memory_bytes() // _GRAM_MEMORY_SHARE // row_bytes
+def _rows_in_memory_share(row_bytes: int,
+                          share: int = _GRAM_MEMORY_SHARE) -> int:
+    """How many rows of ``row_bytes`` fit one ``share``-th (a sixteenth)
+    of one device's memory, rounded down to a power of two, so that a
+    few bytes more or less of ``bytes_limit`` stage the same shapes."""
+    rows = _device_memory_bytes() // share // row_bytes
     return 1 << max(rows, 1).bit_length() - 1
 
 
@@ -122,6 +123,69 @@ def exchange_chunk_entries(rank: int, n_dev: int) -> int:
     ``MAX_ENTRIES_PER_BUCKET``."""
     return min(MAX_ENTRIES_PER_BUCKET,
                _rows_in_memory_share((n_dev + 1) * 4 * rank) * n_dev)
+
+
+# pad width that marks a DENSE bucket chunk: its rows hold so large a
+# share of the opposite table that their normal equations are a blocked
+# matmul over that whole table (`_dense_normal_equations`), not a gather
+DENSE_K = 0
+
+# opposite rows of one block of a dense chunk: a partial Gram sums at
+# most this many outer products before it is added to the others (one
+# chip, PR 35: 1,024 rows against 480,189 at rank 64 take 72.3 ms in
+# blocks of 4,096 and 81.1 ms in blocks of 8,192)
+_DENSE_BLOCK_ROWS = 4096
+
+# `dense_min_count`'s two constants, from one v5e chip at rank 64
+# (PR 35).  A gathered chunk of 4,194,304 padded entries with its Gram
+# takes 48.9 ms from a 480,189-row table (11.7 ns an entry; 46.0 ms of it
+# the gather) and 14.3 ms from a 17,770-row one (3.4 ns; 11.3); the dense
+# form of 1,024 rows against 480,189 takes 72.3 ms, of 1,408 against
+# 17,770 5.0 ms: 0.15 to 0.17 ns a (row, opposite row) pair where the
+# counts are whole numbers (three bf16 passes hold them; twice that for
+# the implicit form's float32 weights).  A bucket's padded entries are
+# 1.44 times its ratings, so the dense form wins from 1/115 of a large
+# opposite table and from 1/29 of a small one: one share for both, the
+# careful one.  At the floor a row's whole gather is 20 to 70 us.
+_DENSE_SHARE_AT_RANK_64 = 1 / 32
+_DENSE_MIN_COUNT = 4096
+
+# share of one device's memory that one side's dense blocks may take,
+# and the bytes of a slot: a float32 rating and a count that is int32
+# where int8 does not hold it
+_DENSE_MEMORY_SHARE = 4
+_DENSE_SLOT_BYTES = 8
+
+
+def dense_min_count(n_opposite: int, rank: int) -> Optional[int]:
+    """Fewest ratings with which a row is staged DENSE against an
+    opposite table of ``n_opposite`` rows, or None where no row is.
+
+    The gather costs by the padded entry, whatever the rank; the dense
+    form by the opposite row times R^2.  So a row is dense from a share
+    of the opposite table that grows with (rank / 64)^2, and never once
+    that share reaches the whole table; below ``_DENSE_MIN_COUNT``
+    ratings a row's whole gather is tens of microseconds and the block's
+    fixed cost wins nothing, which keeps every small table (the CPU
+    tests', fold-in's, a catalogue's) on the gathered path."""
+    share = _DENSE_SHARE_AT_RANK_64 * (rank / 64) ** 2
+    if share >= 1:
+        return None
+    return max(_DENSE_MIN_COUNT, math.ceil(share * n_opposite))
+
+
+def dense_blocks(n_opposite: int) -> int:
+    """Blocks of ``_DENSE_BLOCK_ROWS`` opposite rows in a dense chunk."""
+    return -(-n_opposite // _DENSE_BLOCK_ROWS)
+
+
+def dense_budget_rows(n_opposite: int) -> int:
+    """Most rows one side may stage dense: their ``[J, n_opposite]``
+    slots stay under a quarter of ONE device's memory (16 GB against
+    480,189 opposite rows: 1,024 rows), because one device builds a
+    chunk's block whole before a mesh splits its rows."""
+    row_bytes = dense_blocks(n_opposite) * _DENSE_BLOCK_ROWS * _DENSE_SLOT_BYTES
+    return _rows_in_memory_share(row_bytes, _DENSE_MEMORY_SHARE)
 
 
 @dataclass(frozen=True)
@@ -170,6 +234,9 @@ class ALSConfig:
     # block width B of the subspace sweep (ALX-friendly: smaller B×B
     # systems pack MORE rows per VMEM tile in the Pallas GJ kernel)
     subspace_size: int = 16
+    # gather_dtype and gather_mode concern the GATHERED buckets only: a
+    # row staged dense (`dense_min_count`) reads the float32 table in
+    # order and gathers nothing.
     # dtype the opposite factor table is GATHERED in: "float32" (exact,
     # default) or "bfloat16" — the Gram einsums are gather-bandwidth-bound
     # (see docs/ARCHITECTURE.md cost model), so a bf16 table halves the
@@ -338,7 +405,7 @@ class ALSFactors:
 
 @dataclass
 class Bucket:
-    k: int             # static pad width (power of two)
+    k: int             # static pad width (power of two); DENSE_K: dense
     rows: np.ndarray   # [Bp] row ids; padding = n_rows (OOB -> dropped)
     starts: np.ndarray  # [Bp] offset of each row's slice in the sorted COO
     counts: np.ndarray  # [Bp] true rating count (<= k); 0 for padding
@@ -363,6 +430,8 @@ def build_bucket_layout(
     max_entries: Optional[int] = None,
     starts_dtype: type = np.int32,
     max_rows: Optional[int] = None,
+    dense_min: Optional[int] = None,
+    dense_rows: int = 0,
 ) -> BucketLayout:
     """Group rows by padded rating-count so the device solves static shapes.
 
@@ -400,6 +469,7 @@ def build_bucket_layout(
     layout.buckets = _assemble_buckets(
         counts, starts, n_rows, min_k, max_per_row, batch_multiple,
         max_entries, starts_dtype=starts_dtype, max_rows=max_rows,
+        dense_min=dense_min, dense_rows=dense_rows,
     )
     return layout
 
@@ -414,12 +484,19 @@ def _assemble_buckets(
     max_entries: Optional[int] = None,
     starts_dtype: type = np.int32,
     max_rows: Optional[int] = None,
+    dense_min: Optional[int] = None,
+    dense_rows: int = 0,
 ) -> list[Bucket]:
     """Bucket plan from per-row (counts, starts) alone.
 
     Shared by the host path (counts/starts from the counting sort) and the
     device-staging path (counts from ``np.bincount`` on the raw COO, starts
     from its cumsum — the big sorted arrays never touch the host there).
+
+    Rows with ``dense_min`` ratings or more (:func:`dense_min_count`;
+    None: no row) leave the K buckets for DENSE chunks (``k == DENSE_K``,
+    after the others), the widest ``dense_rows`` of them
+    (:func:`dense_budget_rows`) where more qualify.
     """
     if max_entries is None:
         max_entries = MAX_ENTRIES_PER_BUCKET
@@ -434,14 +511,19 @@ def _assemble_buckets(
     k_of_row = np.maximum(
         min_k, 1 << np.ceil(np.log2(safe)).astype(np.int64)
     )
+    if dense_min is not None:
+        wide = np.flatnonzero(eff_counts >= dense_min)
+        wide = wide[np.argsort(-eff_counts[wide], kind="stable")[:dense_rows]]
+        k_of_row[wide] = DENSE_K
     active = np.nonzero(counts)[0]
     k_active = k_of_row[active]
 
     buckets: list[Bucket] = []
-    for k in np.unique(k_active):
+    keys = np.unique(k_active)
+    for k in (*keys[keys != DENSE_K], *keys[keys == DENSE_K]):
         k = int(k)
         rows_k = active[k_active == k].astype(np.int32)
-        b_cap = max_entries // k
+        b_cap = dense_rows if k == DENSE_K else max_entries // k
         if max_rows is not None:
             b_cap = min(b_cap, max_rows)
         b_cap = max(batch_multiple, b_cap // batch_multiple * batch_multiple)
@@ -462,6 +544,15 @@ def _assemble_buckets(
                 Bucket(k=k, rows=rows_p, starts=starts_p, counts=counts_p)
             )
     return buckets
+
+
+def _gram_entries(buckets: list[Bucket]) -> dict:
+    """A side's real ratings by the path their Gram takes."""
+    entries = {"gathered": 0, "dense": 0}
+    for b in buckets:
+        entries["dense" if b.k == DENSE_K else "gathered"] += int(
+            b.counts.sum())
+    return entries
 
 
 def _plan_shard_layout(
@@ -632,6 +723,112 @@ def _expand_side(c_sorted, v_sorted, starts_counts, *, ks):
             _expand_bucket(c_sorted, v_sorted, starts, counts, k)
             for (starts, counts), k in zip(starts_counts, ks)
         )
+
+
+# entries of one slice of a dense row's ratings (`_dense_pieces`)
+_DENSE_PIECE = 4096
+
+
+def _dense_pieces(bucket: Bucket) -> tuple:
+    """A dense chunk's ratings as slices of ``_DENSE_PIECE`` entries of
+    the row-grouped columns: ``(row, start, length)`` of each slice, host
+    arrays, ``row`` the row's place in the chunk.  The rows' widths
+    differ sixteen-fold, so one pad width for all would be mostly
+    padding; whole slices are what `_expand_bucket` reads cheaply."""
+    counts = bucket.counts.astype(np.int64)
+    per_row = -(-counts // _DENSE_PIECE)
+    row = np.repeat(np.arange(len(counts)), per_row)
+    within = (np.arange(len(row)) - np.repeat(np.cumsum(per_row) - per_row,
+                                              per_row)) * _DENSE_PIECE
+    return (
+        row.astype(np.int32),
+        (bucket.starts[row] + within).astype(bucket.starts.dtype),
+        np.minimum(counts[row] - within, _DENSE_PIECE).astype(np.int32),
+    )
+
+
+@xray.instrument("als.dense_block")
+@functools.partial(jax.jit, static_argnames=("rows", "blocks", "count_dtype"))
+def _dense_block(c_sorted, v_sorted, piece_row, piece_start, piece_len, *,
+                 rows: int, blocks: int, count_dtype):
+    """One dense chunk's resident ``[blocks, rows, _DENSE_BLOCK_ROWS]``
+    arrays, built once at staging: how many ratings row j holds of
+    opposite row u (0 or 1 in a table without repeated pairs) and their
+    sum, with its float32 bits, at ``[u // block, j, u % block]``."""
+    idx, val = _expand_bucket(c_sorted, v_sorted, piece_start, piece_len,
+                              _DENSE_PIECE)
+    # a padding slot's block lies past the last: the scatter drops it
+    block = jnp.where(_valid_slots(piece_len, _DENSE_PIECE),
+                      idx // _DENSE_BLOCK_ROWS, blocks)
+    at = (block, piece_row[:, None], idx % _DENSE_BLOCK_ROWS)
+    shape = (blocks, rows, _DENSE_BLOCK_ROWS)
+    return (
+        jnp.zeros(shape, count_dtype).at[at].add(1, mode="drop"),
+        jnp.zeros(shape, jnp.float32).at[at].add(val, mode="drop"),
+    )
+
+
+def _dense_chunk(columns, bucket: Bucket, blocks: int, put) -> tuple:
+    """``(count, rating)`` of one dense chunk (`_dense_block`), the
+    counts as int8 unless a pair held 128 times or more wrapped one:
+    the counts then no longer add up to the chunk's ratings, and they
+    are built again as int32."""
+    pieces = [put(a) for a in _dense_pieces(bucket)]
+    for count_dtype in (jnp.int8, jnp.int32):
+        count, rating = _dense_block(
+            *columns, *pieces, rows=len(bucket.rows), blocks=blocks,
+            count_dtype=count_dtype,
+        )
+        if int(count.sum(dtype=jnp.int32)) == int(bucket.counts.sum()):
+            break
+    return count, rating
+
+
+def _dense_normal_equations(opp: jax.Array, count: jax.Array,
+                            rating: jax.Array, alpha: jax.Array,
+                            implicit: bool, prec) -> tuple:
+    """``(A [J, R, R], b [J, R])`` of a dense chunk's J rows, without
+    ``reg`` and the implicit ``YtY``: ``A = W @ Z`` and ``b = Bw @ opp``
+    over ALL opposite rows, read in order, where ``Z[u, r*R + s] =
+    opp[u, r] * opp[u, s]``, ``W`` is ``count`` (explicit) or ``alpha``
+    x ``rating`` (implicit) and ``Bw`` is ``rating`` (explicit) or
+    ``count`` + ``alpha`` x ``rating`` (implicit): the sums the gathered
+    einsums take over a row's own entries, a repeated pair counted as
+    often as it is held.  ``opp`` is the table itself: ``gather_dtype``
+    and ``gather_mode`` concern the gathered buckets.
+
+    Summed block by block in float32, the partial Grams added: ONE
+    contraction over a popular row's 232,944 outer products would lose
+    digits to its own running sum (`_table_gram`)."""
+    f32 = jnp.float32
+    blocks, j, block_rows = count.shape
+    n, r = opp.shape
+    opp_blocks = jnp.pad(
+        opp.astype(f32), ((0, blocks * block_rows - n), (0, 0))
+    ).reshape(blocks, block_rows, r)
+
+    def dot(w, x):
+        return jnp.dot(w, x, precision=prec, preferred_element_type=f32)
+
+    def add_block(sums, block):
+        c, v, o = block
+        c, v = c.astype(f32), v.astype(f32)
+        z = (o[:, :, None] * o[:, None, :]).reshape(block_rows, r * r)
+        # alpha scales the sums below, so the weights here are the
+        # resident blocks as they are (whole-number counts take the MXU
+        # three bf16 passes at `highest`, not six); the explicit form
+        # has no use for the third sum, a 64th of the first one's work
+        terms = (dot(v if implicit else c, z), dot(v, o), dot(c, o))
+        return tuple(s + t for s, t in zip(sums, terms)), None
+
+    (A, b, held), _ = jax.lax.scan(
+        add_block,
+        (jnp.zeros((j, r * r), f32),) + (jnp.zeros((j, r), f32),) * 2,
+        (count, rating, opp_blocks),
+    )
+    if implicit:
+        A, b = alpha.astype(f32) * A, held + alpha.astype(f32) * b
+    return A.reshape(j, r, r), b
 
 
 # --------------------------------------------------------------------------
@@ -914,6 +1111,14 @@ def _solve_buckets(
     run per device (:func:`_per_device`).  The sharded path calls this
     from inside its own ``shard_map`` body and leaves it None.
 
+    A DENSE bucket (``k == DENSE_K``; staged under replicated placement
+    with the full solve and no fused kernel, by `ALSTrainer._dense_caps`)
+    carries its ``[blocks, J, block_rows]`` counts and ratings in the
+    place of ``idx`` and ``val``: its normal equations are a blocked
+    matmul over ALL of ``opp`` (`_dense_normal_equations`), then the
+    same ``reg``, solve and scatter as any bucket's.  Replicated
+    placement over a mesh shards its J rows as it shards B.
+
     ``exchange`` (`parallel/collectives.ShardedRows`; the sharded path)
     says that ``opp`` is this device's ``[M/d, R]`` shard and the
     buckets' ids are global: the gather below then looks up all
@@ -959,15 +1164,43 @@ def _solve_buckets(
             fused_gather_gram_solve, fused_tile_plan,
         )
     out = None
+
+    def regularisation(counts):
+        n_row = counts.astype(f32)                       # [B]
+        lam_t = lam.astype(f32)
+        if weighted_lambda:
+            return lam_t * jnp.maximum(n_row, 1.0)       # ALS-WR: λ·n_row
+        return jnp.broadcast_to(lam_t, n_row.shape)
+
+    def solve_and_write(out, rows, A, b, reg):
+        with jax.named_scope("als.gram"):
+            A = A + reg[:, None, None] * jnp.eye(r, dtype=A.dtype)
+        if stop_after == "gram":
+            return (0.0 if out is None else out) + A.sum() + b.sum()
+        with jax.named_scope("als.solve"):
+            x = _spd_solve(A, b, solver, mesh)
+        with jax.named_scope("als.scatter"):
+            return upd_write(out, rows, x)
+
     # the als.* scopes name each bucket's steps in the HLO metadata, so a
     # profile finds the kernels by name whatever XLA fuses them into
     for (rows, idx, val, counts), k in zip(bucket_args, ks):
+        # k is a static pad width, never a traced value
+        if k == DENSE_K:  # piolint: disable=PIO104
+            if stop_after == "gather":
+                continue        # a dense bucket gathers nothing
+            with jax.named_scope("als.dense_gram"):
+                A, b = _dense_normal_equations(opp, idx, val, alpha,
+                                               implicit, prec)
+                if implicit:
+                    A = gram + A
+            out = solve_and_write(out, rows, A, b, regularisation(counts))
+            continue
         with jax.named_scope("als.positions"):
             valid = _valid_slots(counts, k)
             maskf = valid.astype(f32)
+        reg = regularisation(counts)
         if fused and fused_tile_plan(r, k) is not None:
-            n_row = counts.astype(f32)
-            lam_t = lam.astype(f32)
             if implicit:
                 cwk = alpha.astype(f32) * val * maskf
                 bwk = (1.0 + cwk) * maskf
@@ -976,10 +1209,6 @@ def _solve_buckets(
                 cwk = maskf
                 bwk = val * maskf
                 g0 = None
-            if weighted_lambda:
-                reg = lam_t * jnp.maximum(n_row, 1.0)
-            else:
-                reg = jnp.broadcast_to(lam_t, n_row.shape)
             if g0 is None:
                 g0 = jnp.zeros((r, r), f32)
             x = _per_device(
@@ -1039,12 +1268,6 @@ def _solve_buckets(
         if stop_after == "gather":
             out = (0.0 if out is None else out) + Vm.astype(f32).sum()
             continue
-        n_row = counts.astype(f32)                       # [B]
-        lam_t = lam.astype(f32)
-        if weighted_lambda:
-            reg = lam_t * jnp.maximum(n_row, 1.0)        # ALS-WR: λ·n_row
-        else:
-            reg = jnp.broadcast_to(lam_t, n_row.shape)
         if sub:
             # iALS++ block sweep: warm-start from the current factor
             # rows (batch-padding ids are OOB -> fill 0; their output
@@ -1085,14 +1308,7 @@ def _solve_buckets(
                     "bk,bkr->br", (val * maskf).astype(Vm.dtype), Vm,
                     precision=prec, preferred_element_type=f32,
                 )
-            A = A + reg[:, None, None] * jnp.eye(r, dtype=A.dtype)
-        if stop_after == "gram":
-            out = (0.0 if out is None else out) + A.sum() + b.sum()
-            continue
-        with jax.named_scope("als.solve"):
-            x = _spd_solve(A, b, solver, mesh)
-        with jax.named_scope("als.scatter"):
-            out = upd_write(out, rows, x)
+        out = solve_and_write(out, rows, A, b, reg)
     return out
 
 
@@ -1500,7 +1716,6 @@ class ALSTrainer:
         self.sharded = (
             cfg.factor_placement == "sharded" and self.mesh is not None
         )
-        caps = self._chunk_caps(n_dev)
         # single-device "sharded" degenerates to replicated, and coded
         # parity with it (there is no ring to straggle)
         self.coded = False
@@ -1508,6 +1723,7 @@ class ALSTrainer:
         self._pad_items = pad_to_multiple(n_items, n_dev)
         nu = self._pad_users if self.sharded else n_users
         ni = self._pad_items if self.sharded else n_items
+        caps = self._chunk_caps(n_dev)
         if staging not in ("auto", "host", "device"):
             raise ValueError(
                 f"staging must be 'auto', 'host' or 'device', got {staging!r}"
@@ -1560,15 +1776,17 @@ class ALSTrainer:
                     build_bucket_layout(
                         u, i, v, nu, cfg.min_bucket_k,
                         cfg.max_ratings_per_row, batch_multiple=n_dev,
-                        **caps,
-                    )
+                        **caps, **self._dense_caps(ni),
+                    ),
+                    ni,
                 )
                 self._item_side = self._stage(
                     build_bucket_layout(
                         i, u, v, ni, cfg.min_bucket_k,
                         cfg.max_ratings_per_row, batch_multiple=n_dev,
-                        **caps,
-                    )
+                        **caps, **self._dense_caps(nu),
+                    ),
+                    nu,
                 )
         if self.sharded:
             self._build_sharded_halves()
@@ -1581,6 +1799,7 @@ class ALSTrainer:
 
         self._plan_solves()
         self._plan_exchange()
+        self._plan_gram_counters()
         staged = {
             "solver": cfg.solver,
             "solvePath": self.solve_path,
@@ -1597,6 +1816,11 @@ class ALSTrainer:
             "paddedEntries": per_side("padded_entries"),
             "paddedBytes": per_side("padded_bytes"),
             "expandSeconds": per_side("expand_s"),
+            "denseRows": per_side("dense_rows"),
+            "denseEntries": {name: side["entries"]["dense"]
+                             for name, side in sides.items()},
+            "denseBytes": per_side("dense_bytes"),
+            "denseChunks": per_side("dense_chunks"),
         }
         logger.info("ALS staged: %s", staged)
         tower.note_event("als_staged", **staged)
@@ -1612,6 +1836,23 @@ class ALSTrainer:
                 exchange_chunk_entries(self.cfg.rank, n_dev)
                 if self.sharded else None
             ),
+        }
+
+    def _dense_caps(self, n_opposite: int) -> dict:
+        """What the replicated staging paths hand `_assemble_buckets`
+        besides, for a side whose opposite table has ``n_opposite``
+        rows: from how many ratings a row is staged dense, and how many
+        rows may be.  Nothing, so no dense row, where the gathered rows
+        themselves are consumed: the subspace sweep and the fused
+        kernel.  (The sharded staging paths never ask: the opposite
+        table is a shard there, and the dense form would be a partial
+        Gram and a ``psum``, which is not built.)"""
+        cfg = self.cfg
+        if cfg.solver_mode == "subspace" or cfg.solver == "fused":
+            return {}
+        return {
+            "dense_min": dense_min_count(n_opposite, cfg.rank),
+            "dense_rows": dense_budget_rows(n_opposite),
         }
 
     def _plan_solves(self) -> None:
@@ -1637,6 +1878,19 @@ class ALSTrainer:
             for name, side in (("user", self._user_side),
                                ("item", self._item_side))
         }
+
+    def _plan_gram_counters(self) -> None:
+        """``pio_als_gram_entries_total``'s children with what `run`
+        adds to each once a sweep (the real ratings whose Gram a half
+        builds by each path), looked up here and not in every sweep: a
+        small table's sweep is a millisecond, and its record reconciles
+        the phases with the whole to 2 %."""
+        self._gram_entry_counters = [
+            (ALS_GRAM_ENTRIES_TOTAL.labels(path=path, side=name), entries)
+            for name, side in (("user", self._user_side),
+                               ("item", self._item_side))
+            for path, entries in side["entries"].items() if entries
+        ]
 
     def _plan_exchange(self) -> None:
         """What a half moves between devices and holds in their place,
@@ -1888,6 +2142,7 @@ class ALSTrainer:
         self._build_sharded_halves()
         self._plan_solves()
         self._plan_exchange()
+        self._plan_gram_counters()
         # distributed staging holds only LOCAL triples; a global
         # training loss is not computable from one process
         self.loss_every = 0
@@ -1988,6 +2243,7 @@ class ALSTrainer:
             "shard_len": L,
             "ks": ks,
             "buckets": groups,
+            "entries": _gram_entries(buckets),
         }
 
     def _stage_device(self, u, i, v, nu, ni, n_dev):
@@ -2053,11 +2309,12 @@ class ALSTrainer:
         buckets_u = _assemble_buckets(
             np.asarray(counts_u, np.int32), np.asarray(starts_u, np.int32),
             nu, cfg.min_bucket_k, cfg.max_ratings_per_row,
-            batch_multiple=n_dev, **caps,
+            batch_multiple=n_dev, **caps, **self._dense_caps(ni),
         )
         buckets_i = _assemble_buckets(
             counts_i, starts_i, ni, cfg.min_bucket_k,
             cfg.max_ratings_per_row, batch_multiple=n_dev, **caps,
+            **self._dense_caps(nu),
         )
 
         def compact_ids(x, n):
@@ -2096,49 +2353,65 @@ class ALSTrainer:
         # (8 bytes a padded entry) and both sides' columns together
         # would be this path's peak
         del i_dev, v_dev
-        user_side = self._stage_side(cs_u, vs_u, buckets_u)
+        user_side = self._stage_side(cs_u, vs_u, buckets_u, ni)
         del cs_u, vs_u
-        return user_side, self._stage_side(cs_i, vs_i, buckets_i)
+        return user_side, self._stage_side(cs_i, vs_i, buckets_i, nu)
 
-    def _stage(self, layout: BucketLayout):
+    def _stage(self, layout: BucketLayout, n_opposite: int):
         """Transfer the sorted COO + bucket index vectors to the device."""
         return self._stage_side(
-            layout.col_sorted, layout.val_sorted, layout.buckets
+            layout.col_sorted, layout.val_sorted, layout.buckets, n_opposite
         )
 
-    def _stage_side(self, c_sorted, v_sorted, buckets):
+    def _stage_side(self, c_sorted, v_sorted, buckets, n_opposite):
         """Place one side's arrays (host or already on the device) and
-        expand every bucket to its padded block, once: the columns are
+        expand every bucket, once, to what the sweeps read: a gathered
+        bucket to its padded block, a dense one to its counts and
+        ratings over all ``n_opposite`` opposite rows.  The columns are
         not read again and are dropped here."""
         if self.mesh is not None:
-            rep = replicated(self.mesh)
-            dp = NamedSharding(self.mesh, P(DATA_AXIS))
-            put_rep = lambda x: jax.device_put(x, rep)  # noqa: E731
-            put_dp = lambda x: jax.device_put(x, dp)    # noqa: E731
+            def put(*spec):
+                sharding = NamedSharding(self.mesh, P(*spec))
+                return lambda x: jax.device_put(x, sharding)
+
+            put_rep, put_dp = put(), put(DATA_AXIS)
+            put_rows_dp = put(None, DATA_AXIS, None)
         else:
-            put_rep = put_dp = jnp.asarray
-        ks = tuple(b.k for b in buckets)
+            put_rep = put_dp = put_rows_dp = jnp.asarray
+        # `_assemble_buckets` puts the dense chunks last
+        gathered = [b for b in buckets if b.k != DENSE_K]
+        dense = buckets[len(gathered):]
         counts = [put_dp(b.counts) for b in buckets]
         columns = jax.block_until_ready(
             (put_rep(c_sorted), put_rep(v_sorted))
         )
         t0 = time.perf_counter()
-        blocks = jax.block_until_ready(_expand_side(
+        padded = jax.block_until_ready(_expand_side(
             *columns,
-            tuple((put_dp(b.starts), n) for b, n in zip(buckets, counts)),
-            ks=ks,
+            tuple((put_dp(b.starts), n) for b, n in zip(gathered, counts)),
+            ks=tuple(b.k for b in gathered),
         ))
+        resident = jax.block_until_ready([
+            _dense_chunk(columns, b, dense_blocks(n_opposite), put_rep)
+            for b in dense
+        ])
         expand_s = time.perf_counter() - t0
         TRAIN_PHASE_SECONDS.labels(phase="als.expand").observe(expand_s)
+        blocks = [tuple(map(put_dp, blk)) for blk in padded] \
+            + [tuple(map(put_rows_dp, blk)) for blk in resident]
         return {
-            "ks": ks,
+            "ks": tuple(b.k for b in buckets),
             "buckets": tuple(
-                (put_dp(b.rows), put_dp(idx), put_dp(val), n)
+                (put_dp(b.rows), idx, val, n)
                 for b, (idx, val), n in zip(buckets, blocks, counts)
             ),
-            "padded_entries": sum(idx.size for idx, _ in blocks),
-            "padded_bytes": sum(a.nbytes for blk in blocks for a in blk),
+            "padded_entries": sum(idx.size for idx, _ in padded),
+            "padded_bytes": sum(a.nbytes for blk in padded for a in blk),
             "expand_s": round(expand_s, 3),
+            "entries": _gram_entries(buckets),
+            "dense_rows": sum(int((b.counts > 0).sum()) for b in dense),
+            "dense_bytes": sum(a.nbytes for blk in resident for a in blk),
+            "dense_chunks": len(dense),
         }
 
     def _stage_side_sharded(self, layout: BucketLayout, n_dev: int):
@@ -2165,6 +2438,7 @@ class ALSTrainer:
             "shard_len": L,
             "ks": ks,
             "buckets": groups,
+            "entries": _gram_entries(layout.buckets),
         }
 
     def _put_chunk_groups(self, buckets, local_starts) -> tuple:
@@ -2443,6 +2717,8 @@ class ALSTrainer:
                 if received:
                     ALS_EXCHANGE_BYTES_TOTAL.labels(side=side_name).inc(
                         received)
+            for counter, entries in self._gram_entry_counters:
+                counter.inc(entries)
             if faults.fired("train.nan"):
                 # poison the iterates the way an exploding sweep would;
                 # the convergence watchdog must catch it THIS sweep

@@ -218,6 +218,14 @@ ALS_SOLVE_SYSTEMS_TOTAL = _registry.counter(
     "lax.linalg)",
     labels=("path",),
 )
+ALS_GRAM_ENTRIES_TOTAL = _registry.counter(
+    "pio_als_gram_entries_total",
+    "Real ratings whose normal equations an ALS half built, by the side "
+    "being solved and the path they took (gathered = the opposite rows "
+    "fetched into a padded bucket, dense = a blocked matmul over the "
+    "whole opposite table); counted from the staged plan, once a sweep",
+    labels=("path", "side"),
+)
 ALS_EXCHANGE_BYTES_TOTAL = _registry.counter(
     "pio_als_exchange_bytes_total",
     "Bytes ONE device receives from the others in the sharded ALS "

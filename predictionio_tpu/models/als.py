@@ -51,8 +51,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import (
-    ALS_EXCHANGE_BYTES_TOTAL, ALS_GRAM_ENTRIES_TOTAL, ALS_SOLVE_SYSTEMS_TOTAL,
-    TRAIN_PHASE_SECONDS, tower, xray,
+    ALS_EXCHANGE_BYTES_TOTAL, ALS_GATHER_BYTES_TOTAL, ALS_GRAM_ENTRIES_TOTAL,
+    ALS_SOLVE_SYSTEMS_TOTAL, TRAIN_PHASE_SECONDS, tower, xray,
 )
 from ..obs.timeline import annotate
 from ..parallel.mesh import DATA_AXIS, pad_to_multiple, replicated
@@ -111,6 +111,28 @@ def gram_chunk_rows(rank: int, n_dev: int = 1) -> int:
     sixteenth of its memory (16 GB: 32,768 rows a device at rank 64,
     8,192 at rank 128)."""
     return _rows_in_memory_share(4 * rank * rank) * n_dev
+
+
+# share of one device's memory that a bucket chunk's gathered rows
+# [B, K, R] may take under replicated placement.  A quarter: the largest
+# chunk the entry cap ever allowed, rank 128 in float32, is 2.1 GB, an
+# eighth of a v5e's memory, and the power of two below a quarter's rows
+# keeps it (and so every shape staged at a rank of 128 or less)
+_GATHER_MEMORY_SHARE = 4
+
+
+def gather_chunk_entries(rank: int, n_dev: int = 1, itemsize: int = 4) -> int:
+    """Most entries (B*K) a bucket chunk may hold under REPLICATED
+    placement over ``n_dev`` devices, so that each device's gathered
+    rows ``[B / n_dev, K, R]`` of ``itemsize`` bytes an element stay
+    under a quarter of its memory at ANY rank (16 GB, float32: 262,144
+    entries, 2.1 GB, at rank 2,048, where ``MAX_ENTRIES_PER_BUCKET``
+    alone would gather 34 GB).  Never more than ``MAX_ENTRIES_PER_BUCKET``,
+    which binds up to rank 128.  A row wider than the bound is a chunk of
+    its own: a row's entries are not split."""
+    return min(MAX_ENTRIES_PER_BUCKET,
+               _rows_in_memory_share(rank * itemsize, _GATHER_MEMORY_SHARE)
+               * n_dev)
 
 
 def exchange_chunk_entries(rank: int, n_dev: int) -> int:
@@ -916,8 +938,10 @@ def _spd_solve(A: jax.Array, b: jax.Array, solver: str,
     )[..., 0]
 
 
-# rows of one partial sum of a table's Gram (`_table_gram`)
+# rows of one partial sum of a table's Gram (`_table_gram`), and the
+# bytes of partial Grams it holds at once
 _GRAM_BLOCK_ROWS = 4096
+_GRAM_STACK_BYTES = 256 << 20
 
 
 def _table_gram(table: jax.Array, prec) -> jax.Array:
@@ -936,11 +960,28 @@ def _table_gram(table: jax.Array, prec) -> jax.Array:
         return jnp.einsum("mr,ms->rs", rows, rows, precision=prec,
                           preferred_element_type=f32)
 
+    def summed(head):
+        return jax.lax.map(gram, head).sum(axis=0)
+
+    # partial Grams held at once: 4,096 at rank 128, 16 at rank 2,048
+    # (571,355 rows' 139 would be 2.3 GB); the groups' sums are added in
+    # turn, a few tens of terms of one size
+    held = max(1, _GRAM_STACK_BYTES // (4 * r * r))
     total = jnp.zeros((r, r), f32)
     if blocks:
         head = table[: blocks * _GRAM_BLOCK_ROWS].reshape(
             blocks, _GRAM_BLOCK_ROWS, r)
-        total = jax.lax.map(gram, head).sum(axis=0)
+        if blocks <= held:
+            total = summed(head)
+        else:
+            groups = blocks // held
+            total = jax.lax.scan(
+                lambda acc, group: (acc + summed(group), None), total,
+                head[: groups * held].reshape(
+                    groups, held, _GRAM_BLOCK_ROWS, r),
+            )[0]
+            if blocks % held:
+                total = total + summed(head[groups * held:])
     if n % _GRAM_BLOCK_ROWS:
         total = total + gram(table[blocks * _GRAM_BLOCK_ROWS:])
     return total
@@ -971,12 +1012,12 @@ def _half_iteration_impl(
             x.astype(acc.dtype), mode="drop", unique_indices=True
         )
 
-    out = _solve_buckets(
-        write, opp, bucket_args, lam, alpha,
+    out = _solve_staged(
+        write, upd, opp, bucket_args, lam, alpha,
         ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
         precision=precision, solver=solver, gather_dtype=gather_dtype,
         gather_mode=gather_mode, solver_mode=solver_mode,
-        subspace_size=subspace_size, upd_table=upd, mesh=mesh,
+        subspace_size=subspace_size, mesh=mesh,
     )
     return upd if out is None else out
 
@@ -1017,14 +1058,27 @@ def _half_phase_probe(upd, opp, bucket_args, lam, alpha, *, ks, implicit,
     kernel prefix ``tools/breakdown_matrix.py`` probes (gather only /
     gather+Gram), jitted WITHOUT donation — the real, donating half
     still consumes ``upd`` right after the probes run."""
-    return _solve_buckets(
-        None, opp, bucket_args, lam, alpha,
+    return _solve_staged(
+        None, upd, opp, bucket_args, lam, alpha,
         ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
         precision=precision, solver=solver, gather_dtype=gather_dtype,
         gather_mode=gather_mode, solver_mode=solver_mode,
-        subspace_size=subspace_size, upd_table=upd,
-        stop_after=stop_after,
+        subspace_size=subspace_size, stop_after=stop_after,
     )
+
+
+def _solve_staged(upd_write, upd, opp, bucket_args, lam, alpha, *,
+                  solver_mode: str, subspace_size: int, **how):
+    """A replicated half over the buckets as `ALSTrainer._stage_side`
+    staged them: the block sweep's runs of chunks as loops
+    (`_block_sweep_half`), the full solve's buckets one unrolled step
+    each (`_solve_buckets`)."""
+    how.update(solver_mode=solver_mode, subspace_size=subspace_size)
+    if _block_sweeps(solver_mode, subspace_size, upd.shape[-1]):
+        return _block_sweep_half(upd_write, upd, opp, bucket_args, lam,
+                                 alpha, **how)
+    return _solve_buckets(upd_write, opp, bucket_args, lam, alpha,
+                          upd_table=upd, **how)
 
 
 def _als_phase_trace_enabled() -> bool:
@@ -1312,6 +1366,30 @@ def _solve_buckets(
     return out
 
 
+def _sum_over_entries(spec: str, *operands, prec) -> jax.Array:
+    """``jnp.einsum(spec, *operands)`` in float32 where the spec sums
+    over a bucket's K axis (``k``, the operands' second axis, ``b``
+    their first): summed in blocks of ``_GRAM_BLOCK_ROWS`` entries whose
+    partial sums are then added, for the reason `_table_gram` is.  A
+    row of the full solve this wide is staged dense; the block sweep
+    gathers it, 131,072 entries to a contraction."""
+    f32 = jnp.float32
+    k = operands[0].shape[1]
+    if k <= _GRAM_BLOCK_ROWS:
+        return jnp.einsum(spec, *operands, precision=prec,
+                          preferred_element_type=f32)
+    # a pad width is a power of two
+    parts = k // _GRAM_BLOCK_ROWS
+    ins, out = spec.split("->")
+    blocked = ",".join(t.replace("bk", "bck") for t in ins.split(","))
+    return jnp.einsum(
+        f"{blocked}->bc{out[1:]}",
+        *(a.reshape(a.shape[0], parts, _GRAM_BLOCK_ROWS, *a.shape[2:])
+          for a in operands),
+        precision=prec, preferred_element_type=f32,
+    ).sum(axis=1)
+
+
 def _subspace_sweep(
     Vm: jax.Array,          # [B, K, R] gathered+masked opposite rows
     val: jax.Array,         # [B, K] masked ratings, f32
@@ -1352,49 +1430,111 @@ def _subspace_sweep(
         "bkr,br->bk", Vm, x0.astype(Vm.dtype),
         precision=prec, preferred_element_type=f32,
     )
-    e = q = None
-    if cw is None:
-        e = pred - val
-    else:
-        q = jnp.einsum("bs,sr->br", x0, gram, precision=prec)
-    acc = jnp.zeros((), f32)
-    for s in range(0, r, block):
-        w = min(block, r - s)
-        Vs = jax.lax.slice_in_dim(Vm, s, s + w, axis=2)   # [B, K, w]
-        xs = jax.lax.slice_in_dim(x0, s, s + w, axis=1)   # [B, w]
-        if cw is None:
-            H = jnp.einsum("bks,bkt->bst", Vs, Vs, precision=prec,
-                           preferred_element_type=f32)
-            g = jnp.einsum("bk,bks->bs", e.astype(Vs.dtype), Vs,
-                           precision=prec, preferred_element_type=f32)
-        else:
-            H = gram[s:s + w, s:s + w] + jnp.einsum(
-                "bk,bks,bkt->bst", cw.astype(Vs.dtype), Vs, Vs,
-                precision=prec, preferred_element_type=f32,
-            )
-            # (c-1)·p - c on rated items: cw is masked, so c·mask is
-            # maskf + cw
-            coef = cw * pred - maskf - cw
-            g = q[:, s:s + w] + jnp.einsum(
-                "bk,bks->bs", coef.astype(Vs.dtype), Vs,
-                precision=prec, preferred_element_type=f32,
-            )
-        H = H + reg[:, None, None] * jnp.eye(w, dtype=H.dtype)
-        g = g + reg[:, None] * xs
+    # the cache a block's gradient reads and its update advances: the
+    # residual (explicit) or the prediction (implicit, with q = x·YtY)
+    implicit = cw is not None
+    cache = pred if implicit else pred - val
+    q = jnp.einsum("bs,sr->br", x0, gram, precision=prec) if implicit \
+        else None
+
+    def block_step(state, s, w: int):
+        """The block of ``w`` rank coordinates from ``s`` on (a Python
+        int, or the loop's traced offset)."""
+        x, cache, q, acc = state
+        with jax.named_scope("als.block_gram"):
+            Vs = jax.lax.dynamic_slice_in_dim(Vm, s, w, axis=2)  # [B, K, w]
+            xs = jax.lax.dynamic_slice_in_dim(x, s, w, axis=1)   # [B, w]
+            if implicit:
+                gram_rows = jax.lax.dynamic_slice_in_dim(gram, s, w, axis=0)
+                H = jax.lax.dynamic_slice_in_dim(
+                    gram_rows, s, w, axis=1
+                ) + _sum_over_entries(
+                    "bk,bks,bkt->bst", cw.astype(Vs.dtype), Vs, Vs,
+                    prec=prec,
+                )
+                # (c-1)·p - c on rated items: cw is masked, so c·mask is
+                # maskf + cw
+                coef = cw * cache - maskf - cw
+                g = jax.lax.dynamic_slice_in_dim(q, s, w, axis=1) \
+                    + _sum_over_entries(
+                        "bk,bks->bs", coef.astype(Vs.dtype), Vs, prec=prec)
+            else:
+                H = _sum_over_entries("bks,bkt->bst", Vs, Vs, prec=prec)
+                g = _sum_over_entries(
+                    "bk,bks->bs", cache.astype(Vs.dtype), Vs, prec=prec)
+            H = H + reg[:, None, None] * jnp.eye(w, dtype=H.dtype)
+            g = g + reg[:, None] * xs
         if gram_probe:
-            acc = acc + H.sum() + g.sum()
-            continue
-        d = -_spd_solve(H, g, solver, mesh)              # [B, w]
-        x0 = jax.lax.dynamic_update_slice_in_dim(x0, xs + d, s, axis=1)
-        dp = jnp.einsum("bks,bs->bk", Vs, d.astype(Vs.dtype),
-                        precision=prec, preferred_element_type=f32)
-        if cw is None:
-            e = e + dp
-        else:
-            pred = pred + dp
-            q = q + jnp.einsum("bs,sr->br", d, gram[s:s + w, :],
-                               precision=prec)
-    return acc if gram_probe else x0
+            return x, cache, q, acc + H.sum() + g.sum()
+        with jax.named_scope("als.block_solve"):
+            d = -_spd_solve(H, g, solver, mesh)              # [B, w]
+        with jax.named_scope("als.block_update"):
+            x = jax.lax.dynamic_update_slice_in_dim(x, xs + d, s, axis=1)
+            cache = cache + jnp.einsum(
+                "bks,bs->bk", Vs, d.astype(Vs.dtype),
+                precision=prec, preferred_element_type=f32)
+            if implicit:
+                q = q + jnp.einsum("bs,sr->br", d, gram_rows,
+                                   precision=prec)
+        return x, cache, q, acc
+
+    # the whole blocks are ONE loop: one traced body, and one lowering of
+    # the solve kernel, whatever the rank (16 blocks at rank 2,048 would
+    # be 16 copies of the body in every bucket shape's program); a
+    # narrower last block follows it
+    state = (x0, cache, q, jnp.zeros((), f32))
+    whole = r // block
+    if whole > 1:
+        state = jax.lax.fori_loop(
+            0, whole, lambda j, st: block_step(st, j * block, block), state)
+    else:
+        state = block_step(state, 0, block)
+    if r % block:
+        state = block_step(state, whole * block, r % block)
+    return state[3] if gram_probe else state[0]
+
+
+def _block_sweep_half(upd_write, upd, opp, bucket_args, lam, alpha, *,
+                      ks: tuple, implicit: bool, precision: str,
+                      stop_after: Optional[str] = None, **how):
+    """The block sweep's half under replicated placement: every staged
+    bucket in turn, a run of chunks of one shape (arrays ``[n, B, ...]``,
+    `ALSTrainer._stage_side`) as ONE loop, so that the hundreds of
+    chunks a high rank makes (`gather_chunk_entries`) trace and lower
+    one body a shape.
+
+    A chunk's warm start is read from the table AS IT STANDS, the
+    loop's carry, and its solved rows are written into the same: a row
+    is solved once a half, so these are the bits the table held when
+    the half began, and no second copy of the table being updated
+    lives beside the first (4.7 GB at 571,355 x 2,048).  ``stop_after``:
+    the phase probe's sums in the table's place; ``upd_write`` is then
+    None."""
+    gram = None
+    if implicit:
+        gram = _table_gram(opp, jax.lax.Precision(precision))
+    probe = stop_after is not None
+    carry = jnp.zeros((), jnp.float32) if probe else upd
+    for bucket, k in zip(bucket_args, ks):
+        def step(carry, chunk, k=k):
+            table = upd if probe else carry
+
+            def write(acc, rows, x):
+                return upd_write(table if acc is None else acc, rows, x)
+
+            out = _solve_buckets(
+                None if probe else write, opp, (chunk,), lam, alpha,
+                ks=(k,), implicit=implicit, precision=precision,
+                upd_table=table, gram=gram, stop_after=stop_after, **how,
+            )
+            if probe:
+                return carry if out is None else carry + out
+            return table if out is None else out
+
+        # a lone chunk is staged [B, ...], a run of n chunks [n, B, ...]
+        carry = step(carry, bucket) if bucket[0].ndim == 1 \
+            else _each_chunk(step, carry, bucket)
+    return carry
 
 
 def _chunk_groups(buckets: list) -> list:
@@ -1809,7 +1949,12 @@ class ALSTrainer:
             "shards": n_dev if self.sharded else 1,
             "oppTransientBytes": self.opp_transient_bytes,
             "gramChunkBytes": self.gram_chunk_bytes,
+            "gatherChunkBytes": self.gather_chunk_bytes,
+            "gatherBytes": self.gather_bytes,
             "chunksLooped": self.chunks_looped,
+            "solverMode": cfg.solver_mode,
+            "subspaceSize": cfg.subspace_size,
+            "rankBlocks": -(-cfg.rank // self.system_width),
             "exchangeBytes": self.exchange_bytes,
             "devices": n_dev,
             "devicesWithData": self.data_devices(),
@@ -1827,16 +1972,31 @@ class ALSTrainer:
 
     def _chunk_caps(self, n_dev: int) -> dict:
         """The bounds every staging path hands `_assemble_buckets`: a
-        chunk's rows by the bytes of its Gram and, under sharded
-        placement, its entries by the bytes of the exchanged rows (else
-        ``MAX_ENTRIES_PER_BUCKET``)."""
+        chunk's rows by the bytes of its Gram (``[B, w, w]`` where a
+        half sweeps rank blocks of width w, ``[B, R, R]`` else) and its
+        entries by the bytes of its rows: the exchanged ones under
+        sharded placement, the gathered ones else."""
+        cfg = self.cfg
         return {
-            "max_rows": gram_chunk_rows(self.cfg.rank, n_dev),
+            "max_rows": gram_chunk_rows(self.system_width, n_dev),
             "max_entries": (
-                exchange_chunk_entries(self.cfg.rank, n_dev)
-                if self.sharded else None
+                exchange_chunk_entries(cfg.rank, n_dev) if self.sharded
+                else gather_chunk_entries(
+                    cfg.rank, n_dev, jnp.dtype(cfg.gather_dtype).itemsize)
             ),
         }
+
+    @property
+    def system_width(self) -> int:
+        """Width of the systems a half solves: the rank block's where it
+        sweeps blocks (`_block_sweeps`), the rank's else."""
+        cfg = self.cfg
+        return cfg.subspace_size if self.sweeps_blocks else cfg.rank
+
+    @property
+    def sweeps_blocks(self) -> bool:
+        cfg = self.cfg
+        return _block_sweeps(cfg.solver_mode, cfg.subspace_size, cfg.rank)
 
     def _dense_caps(self, n_opposite: int) -> dict:
         """What the replicated staging paths hand `_assemble_buckets`
@@ -1863,8 +2023,7 @@ class ALSTrainer:
         subspace sweep; the buckets the fused kernel takes whole never
         reach the solve."""
         cfg = self.cfg
-        sub = _block_sweeps(cfg.solver_mode, cfg.subspace_size, cfg.rank)
-        width = cfg.subspace_size if sub else cfg.rank
+        width = self.system_width
         self.solve_path = _solve_path(cfg.solver, width)
         fused = cfg.solver == "fused"
         if fused:
@@ -1904,10 +2063,15 @@ class ALSTrainer:
         * ``opp_transient_bytes``: the most a device holds in the
           opposite table's place at once (the partial rows and the
           chunk's own; the whole table where a mode all-gathers it).
-        * ``gram_chunk_bytes``: the largest chunk's ``[B, R, R]`` float32
-          Gram on one device.
+        * ``gram_chunk_bytes``: the largest chunk's float32 Gram on one
+          device, ``[B, w, w]`` for systems of width w (`system_width`).
+        * ``gather_chunk_bytes``: the largest chunk's gathered rows
+          ``[B, K, R]`` on one device, in the gather dtype; and
+          ``gather_bytes``, all that a half gathers over the devices
+          together, padding included (a dense chunk gathers nothing).
         * ``chunks_looped``: chunks that run inside a loop over chunks
-          of their shape (0 under replicated placement, which unrolls).
+          of their shape (under replicated placement the block sweep's
+          alone: the full solve unrolls).
         """
         cfg = self.cfg
         r = cfg.rank
@@ -1915,21 +2079,24 @@ class ALSTrainer:
         row_bytes = r * jnp.dtype(cfg.gather_dtype).itemsize
         table_rows = {"user": self._pad_items, "item": self._pad_users}
         whole_opp = self.coded or cfg.solver == "fused"
-        sub = _block_sweeps(cfg.solver_mode, cfg.subspace_size, r)
+        sub = self.sweeps_blocks
         self.exchange_bytes, self.opp_transient_bytes = {}, {}
-        self.chunks_looped = {}
-        gram_rows = 0
+        self.chunks_looped, self.gather_bytes = {}, {}
+        gram_rows = gather_entries = 0
         for name, side in (("user", self._user_side),
                            ("item", self._item_side)):
-            received = transient = looped = 0
+            received = transient = looped = gathered = 0
             for bucket, k in zip(side["buckets"], side["ks"]):
                 rows = bucket[0]
-                n, b = (rows.shape[0], rows.shape[1] // d) \
-                    if self.sharded else (1, rows.shape[0] // d)
+                # a run of n chunks of one shape is staged [n, B]
+                n = rows.shape[0] if rows.ndim == 2 else 1
+                b = rows.shape[-1] // d
                 gram_rows = max(gram_rows, b)
+                gather_entries = max(gather_entries, b * k)
+                gathered += rows.size * k * row_bytes
+                looped += n if n > 1 else 0
                 if not self.sharded:
                     continue
-                looped += n if n > 1 else 0
                 received += n * (d - 1) * b * (r * 4 + 4)
                 if not whole_opp:
                     received += n * (d - 1) * b * k * (4 + row_bytes)
@@ -1948,7 +2115,9 @@ class ALSTrainer:
             self.exchange_bytes[name] = int(received)
             self.opp_transient_bytes[name] = int(transient)
             self.chunks_looped[name] = int(looped)
-        self.gram_chunk_bytes = int(gram_rows * r * r * 4)
+            self.gather_bytes[name] = int(gathered)
+        self.gram_chunk_bytes = int(gram_rows * self.system_width ** 2 * 4)
+        self.gather_chunk_bytes = int(gather_entries * row_bytes)
 
     def data_devices(self) -> int:
         """How many devices hold staged training data: the per-bucket
@@ -2376,8 +2545,27 @@ class ALSTrainer:
 
             put_rep, put_dp = put(), put(DATA_AXIS)
             put_rows_dp = put(None, DATA_AXIS, None)
+            put_chunks_dp = put(None, DATA_AXIS)
         else:
             put_rep = put_dp = put_rows_dp = jnp.asarray
+        if self.sweeps_blocks:
+            # the block sweep's chunks are bounded by the bytes they
+            # gather, hundreds of them at a high rank: a run of n chunks
+            # of one shape is staged as ONE bucket of n * B rows, expanded
+            # in one piece and laid out [n, B, ...], which
+            # `_block_sweep_half` solves as one loop; the full solve's
+            # buckets stay as they are, one unrolled step each
+            runs = _chunk_groups(buckets)
+            buckets = [
+                Bucket(k=buckets[run[0]].k, **{
+                    field: np.concatenate(
+                        [getattr(buckets[j], field) for j in run])
+                    for field in ("rows", "starts", "counts")})
+                for run in runs
+            ]
+            chunks = [len(run) for run in runs]
+        else:
+            chunks = [1] * len(buckets)
         # `_assemble_buckets` puts the dense chunks last
         gathered = [b for b in buckets if b.k != DENSE_K]
         dense = buckets[len(gathered):]
@@ -2399,11 +2587,19 @@ class ALSTrainer:
         TRAIN_PHASE_SECONDS.labels(phase="als.expand").observe(expand_s)
         blocks = [tuple(map(put_dp, blk)) for blk in padded] \
             + [tuple(map(put_rows_dp, blk)) for blk in resident]
+
+        def by_chunk(a, n):
+            if n == 1:
+                return a
+            a = a.reshape(n, a.shape[0] // n, *a.shape[1:])
+            return put_chunks_dp(a) if self.mesh is not None else a
+
         return {
             "ks": tuple(b.k for b in buckets),
             "buckets": tuple(
-                (put_dp(b.rows), idx, val, n)
-                for b, (idx, val), n in zip(buckets, blocks, counts)
+                tuple(by_chunk(a, n) for a in (put_dp(b.rows), idx, val, m))
+                for b, (idx, val), m, n in zip(buckets, blocks, counts,
+                                               chunks)
             ),
             "padded_entries": sum(idx.size for idx, _ in padded),
             "padded_bytes": sum(a.nbytes for blk in padded for a in blk),
@@ -2717,6 +2913,10 @@ class ALSTrainer:
                 if received:
                     ALS_EXCHANGE_BYTES_TOTAL.labels(side=side_name).inc(
                         received)
+            for side_name, gathered in self.gather_bytes.items():
+                if gathered:
+                    ALS_GATHER_BYTES_TOTAL.labels(side=side_name).inc(
+                        gathered)
             for counter, entries in self._gram_entry_counters:
                 counter.inc(entries)
             if faults.fired("train.nan"):

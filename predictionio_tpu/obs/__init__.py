@@ -233,6 +233,13 @@ ALS_EXCHANGE_BYTES_TOTAL = _registry.counter(
     "solved; counted from the staged shapes, once a sweep",
     labels=("side",),
 )
+ALS_GATHER_BYTES_TOTAL = _registry.counter(
+    "pio_als_gather_bytes_total",
+    "Bytes of opposite rows an ALS half gathers into its padded bucket "
+    "chunks ([B, K, R] in the gather dtype, padding included), by the "
+    "side being solved; counted from the staged shapes, once a sweep",
+    labels=("side",),
+)
 
 # pio-live (incremental fold-in) families: the daemon side books cycles
 # / scanned events / produced rows + per-phase timings; the serving side

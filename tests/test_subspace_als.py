@@ -359,3 +359,397 @@ def test_gram_probe_runs_for_subspace():
         out = probe(U0, V0, side["buckets"], lam, alpha, ks=side["ks"],
                     stop_after=stop)
         assert np.isfinite(float(out))
+
+
+# --------------------------------------------------------------------------
+# PR 36: the blocks as ONE loop, chunks bounded by the bytes they gather
+# and looped, against the benchmark's plain reference
+# --------------------------------------------------------------------------
+
+
+def _unrolled_sweep(Vm, val, maskf, x0, reg, cw, gram, prec, solver, block):
+    """The block sweep as it stood before the loop (one Python iteration a
+    block, static slices): what the looped sweep has to give bit for bit."""
+    import jax
+
+    from predictionio_tpu.models.als import _spd_solve
+
+    f32 = jnp.float32
+    r = Vm.shape[-1]
+    pred = jnp.einsum("bkr,br->bk", Vm, x0.astype(Vm.dtype),
+                      precision=prec, preferred_element_type=f32)
+    e = q = None
+    if cw is None:
+        e = pred - val
+    else:
+        q = jnp.einsum("bs,sr->br", x0, gram, precision=prec)
+    for s in range(0, r, block):
+        w = min(block, r - s)
+        Vs = jax.lax.slice_in_dim(Vm, s, s + w, axis=2)
+        xs = jax.lax.slice_in_dim(x0, s, s + w, axis=1)
+        if cw is None:
+            H = jnp.einsum("bks,bkt->bst", Vs, Vs, precision=prec,
+                           preferred_element_type=f32)
+            g = jnp.einsum("bk,bks->bs", e.astype(Vs.dtype), Vs,
+                           precision=prec, preferred_element_type=f32)
+        else:
+            H = gram[s:s + w, s:s + w] + jnp.einsum(
+                "bk,bks,bkt->bst", cw.astype(Vs.dtype), Vs, Vs,
+                precision=prec, preferred_element_type=f32)
+            coef = cw * pred - maskf - cw
+            g = q[:, s:s + w] + jnp.einsum(
+                "bk,bks->bs", coef.astype(Vs.dtype), Vs,
+                precision=prec, preferred_element_type=f32)
+        H = H + reg[:, None, None] * jnp.eye(w, dtype=H.dtype)
+        g = g + reg[:, None] * xs
+        d = -_spd_solve(H, g, solver)
+        x0 = jax.lax.dynamic_update_slice_in_dim(x0, xs + d, s, axis=1)
+        dp = jnp.einsum("bks,bs->bk", Vs, d.astype(Vs.dtype),
+                        precision=prec, preferred_element_type=f32)
+        if cw is None:
+            e = e + dp
+        else:
+            pred = pred + dp
+            q = q + jnp.einsum("bs,sr->br", d, gram[s:s + w, :],
+                               precision=prec)
+    return x0
+
+
+@pytest.mark.parametrize("implicit", [False, True])
+@pytest.mark.parametrize("solver", ["xla", "pallas"])
+@pytest.mark.parametrize("shape", [(7, 16, 32, 8), (5, 8, 20, 8),
+                                   (33, 64, 12, 8)])
+def test_looped_blocks_bitwise_as_unrolled(implicit, solver, shape):
+    """The whole blocks are one `fori_loop` (one traced body, one lowering
+    of the solve kernel) and a narrower last block follows it: 32 = 4 x 8,
+    20 = 2 x 8 + 4, 12 = 8 + 4 (no loop at all)."""
+    import jax
+
+    from predictionio_tpu.models.als import _subspace_sweep
+
+    B, K, R, block = shape
+    rng = np.random.default_rng(B)
+    mask = jnp.asarray((rng.random((B, K)) < 0.7).astype(np.float32))
+    Vm = jnp.asarray(rng.standard_normal((B, K, R)).astype(np.float32)) \
+        * mask[..., None]
+    val = jnp.asarray(rng.random((B, K)).astype(np.float32)) * mask
+    x0 = jnp.asarray(rng.standard_normal((B, R)).astype(np.float32))
+    reg = jnp.asarray(rng.random(B).astype(np.float32)) + 0.1
+    Y = rng.standard_normal((100, R)).astype(np.float32)
+    gram = jnp.asarray(Y.T @ Y) if implicit else None
+    cw = 2.0 * val * mask if implicit else None
+    prec = jax.lax.Precision.HIGHEST
+    args = (Vm, val, mask, x0, reg, cw, gram)
+    looped = jax.jit(
+        lambda *a: _subspace_sweep(*a, prec, solver, block))(*args)
+    unrolled = jax.jit(
+        lambda *a: _unrolled_sweep(*a, prec, solver, block))(*args)
+    assert np.array_equal(np.asarray(looped), np.asarray(unrolled))
+    assert not np.array_equal(np.asarray(looped), np.asarray(x0))
+
+
+def test_the_loop_traces_the_solve_once_whatever_the_rank(monkeypatch):
+    """16 blocks, one trace of the body: what bounds the lowering of the
+    kernel at rank 2,048 (and `warmup_s` with it)."""
+    import jax
+
+    from predictionio_tpu.models import als
+
+    calls = []
+    real = als._spd_solve
+    monkeypatch.setattr(
+        als, "_spd_solve",
+        lambda *a, **k: calls.append(a[0].shape) or real(*a, **k))
+    rng = np.random.default_rng(0)
+    B, K, R = 4, 8, 64
+    Vm = jnp.asarray(rng.standard_normal((B, K, R)).astype(np.float32))
+    ones = jnp.ones((B, K), jnp.float32)
+    x0 = jnp.asarray(rng.standard_normal((B, R)).astype(np.float32))
+    jax.jit(lambda *a: als._subspace_sweep(
+        *a, jax.lax.Precision.HIGHEST, "xla", 4))(
+        Vm, ones, ones, x0, jnp.ones(B), None, None)
+    assert calls == [(B, 4, 4)]
+
+
+@pytest.mark.parametrize("rank,block,alpha", [(32, 8, 1.0), (20, 8, 2.5)])
+def test_implicit_sweep_row_for_row_as_the_benchmarks_reference(
+        rank, block, alpha):
+    """`perfbench/reference/ials_subspace_ref.py` forms each row's whole
+    normal equations and recomputes the gradient at every block; the
+    program keeps caches and never forms them: the same rows."""
+    from perfbench.reference import ials_subspace_ref as ref
+
+    u, i, v, nu, ni = _toy_implicit(n_users=60, n_items=40, density=0.4)
+    cfg = ALSConfig(rank=rank, num_iterations=1, lam=0.05, implicit=True,
+                    alpha=alpha, solver_mode="subspace", subspace_size=block)
+    U0, V0, U1 = _one_user_half(cfg, u, i, v, nu, ni)
+    order = np.argsort(u, kind="stable")
+    counts = np.bincount(u, minlength=nu)
+    starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+    want = ref.sweep_rows(ref.gram([V0]), V0[i[order]], v[order], starts,
+                          counts, U0, 0.05, alpha, block)
+    rated = counts > 0
+    gap = np.linalg.norm(U1[rated] - want[rated], axis=1) \
+        / np.linalg.norm(want[rated], axis=1)
+    assert gap.max() < 5e-6
+    assert np.array_equal(U1[~rated], U0[~rated])
+
+
+@pytest.mark.parametrize("rank,block", [(32, 8)])
+def test_explicit_sweep_row_for_row_at_rank_32(rank, block):
+    u, i, v, nu, ni = _toy(n_users=60, n_items=40)
+    cfg = ALSConfig(rank=rank, num_iterations=1, lam=0.1,
+                    solver_mode="subspace", subspace_size=block)
+    U0, V0, U1 = _one_user_half(cfg, u, i, v, nu, ni)
+    want = _np_subspace_half_explicit(U0, V0, u, i, v, 0.1, block)
+    np.testing.assert_allclose(U1, want, rtol=2e-4, atol=2e-4)
+
+
+def test_a_block_as_wide_as_rank_32_is_bitwise_the_full_solve():
+    u, i, v, nu, ni = _toy_implicit(n_users=60, n_items=40)
+    kw = dict(rank=32, num_iterations=2, lam=0.05, implicit=True)
+    full = train_als((u, i, v), nu, ni, ALSConfig(**kw))
+    for size in (32, 128):
+        deg = train_als((u, i, v), nu, ni, ALSConfig(
+            solver_mode="subspace", subspace_size=size, **kw))
+        assert np.array_equal(full.user_factors, deg.user_factors)
+        assert np.array_equal(full.item_factors, deg.item_factors)
+
+
+@pytest.mark.parametrize("memory", [16 << 30, int(16.9e9)])
+def test_gathered_rows_are_bounded_by_bytes_at_any_rank(memory, monkeypatch):
+    """Up to rank 128 the entry cap binds, so those ranks stage the
+    shapes they staged (a CPU's 16 GiB and a v5e's bytes_limit alike);
+    at rank 2,048 a chunk's [B, K, R] rows stay under a quarter of the
+    device's memory where the entry cap alone would gather 34 GB."""
+    from predictionio_tpu.models import als
+
+    monkeypatch.setattr(als, "_device_memory_bytes", lambda: memory)
+    cap = als.MAX_ENTRIES_PER_BUCKET
+    for rank in (8, 64, 100, 128):
+        assert als.gather_chunk_entries(rank) == cap
+        assert als.gather_chunk_entries(rank, n_dev=4) == cap
+    assert als.gather_chunk_entries(256, itemsize=2) == cap
+    entries = als.gather_chunk_entries(2048)
+    # the power of two under a quarter: the quarter itself at 16 GiB
+    assert entries == (524_288 if memory == 16 << 30 else 262_144)
+    assert cap * 2048 * 4 > 34e9
+    assert entries * 2048 * 4 <= memory // 4
+    assert als.gather_chunk_entries(2048, n_dev=4) == 4 * entries
+    assert als.gather_chunk_entries(2048, itemsize=2) == 2 * entries
+    assert als.gather_chunk_entries(4096) == entries // 2
+
+
+def test_chunk_caps_follow_the_width_of_the_systems(monkeypatch):
+    """A half that sweeps blocks of 128 holds [B, 128, 128] Hessians, not
+    [B, 2048, 2048] Grams: its chunks may hold 8,192 rows where the full
+    solve's bound at that rank would allow 32."""
+    from predictionio_tpu.models import als
+
+    monkeypatch.setattr(als, "_device_memory_bytes", lambda: int(16.9e9))
+    u, i, v, nu, ni = _toy_implicit()
+    sub = ALSTrainer((u, i, v), nu, ni, ALSConfig(
+        rank=2048, implicit=True, solver_mode="subspace", subspace_size=128))
+    assert sub.system_width == 128
+    assert sub._chunk_caps(1) == {"max_rows": 8192, "max_entries": 262_144}
+    assert als.gram_chunk_rows(2048) == 32
+    full = ALSTrainer((u, i, v), nu, ni, ALSConfig(rank=64))
+    assert full.system_width == 64
+    assert full._chunk_caps(1) == {
+        "max_rows": 32768, "max_entries": als.MAX_ENTRIES_PER_BUCKET}
+
+
+def _small_chunks(monkeypatch, entries=64):
+    from predictionio_tpu.models import als
+
+    monkeypatch.setattr(als, "MAX_ENTRIES_PER_BUCKET", entries)
+
+
+@pytest.mark.parametrize("implicit", [False, True])
+def test_runs_of_chunks_are_staged_stacked_and_looped_bitwise(
+        implicit, monkeypatch):
+    """Under the block sweep a run of chunks of one shape is ONE stacked
+    array and one loop; the result is bit for bit what the same chunks
+    give one unrolled step each."""
+    from predictionio_tpu.models import als
+
+    _small_chunks(monkeypatch)
+    u, i, v, nu, ni = (_toy_implicit if implicit else _toy)(
+        n_users=90, n_items=40, density=0.3)
+    cfg = ALSConfig(rank=16, num_iterations=2, lam=0.05, implicit=implicit,
+                    solver_mode="subspace", subspace_size=4)
+    looped = ALSTrainer((u, i, v), nu, ni, cfg)
+    assert looped.chunks_looped["user"] > 1
+    assert any(b[0].ndim == 2 and b[0].shape[0] > 1
+               for b in looped._user_side["buckets"])
+    assert any(b[0].ndim == 1 for b in looped._user_side["buckets"])
+    for side in (looped._user_side, looped._item_side):
+        for (rows, idx, val, counts), k in zip(side["buckets"], side["ks"]):
+            assert idx.shape == rows.shape + (k,) == val.shape + ()
+            assert counts.shape == rows.shape
+    monkeypatch.setattr(als, "_chunk_groups",
+                        lambda buckets: [[j] for j in range(len(buckets))])
+    unrolled = ALSTrainer((u, i, v), nu, ni, cfg)
+    assert unrolled.chunks_looped == {"user": 0, "item": 0}
+    assert looped.solve_systems == unrolled.solve_systems
+    assert looped.gather_bytes == unrolled.gather_bytes
+    U0, V0 = looped.init_factors()
+    a = looped.run(U0, V0, 2)
+    b = unrolled.run(U0, V0, 2)
+    for x, y in zip(a, b):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+    # and the full solve's buckets stay one unrolled step each
+    full = ALSTrainer((u, i, v), nu, ni, ALSConfig(
+        rank=16, implicit=implicit))
+    assert all(b[0].ndim == 1 for b in full._user_side["buckets"])
+    assert full.chunks_looped == {"user": 0, "item": 0}
+
+
+def test_looped_chunks_match_numpy_and_the_phase_probe_runs(monkeypatch):
+    _small_chunks(monkeypatch)
+    u, i, v, nu, ni = _toy(n_users=90, n_items=40, density=0.3)
+    cfg = ALSConfig(rank=10, num_iterations=1, lam=0.1,
+                    solver_mode="subspace", subspace_size=4)
+    U0, V0, U1 = _one_user_half(cfg, u, i, v, nu, ni)
+    want = _np_subspace_half_explicit(U0, V0, u, i, v, 0.1, 4)
+    np.testing.assert_allclose(U1, want, rtol=2e-4, atol=2e-4)
+    from predictionio_tpu.models.als import _half_phase_probe
+
+    tr = ALSTrainer((u, i, v), nu, ni, cfg)
+    side = tr._user_side
+    assert tr.chunks_looped["user"] > 1
+    sums = {}
+    for stop in ("gather", "gram"):
+        sums[stop] = float(_half_phase_probe(
+            jnp.asarray(U0), jnp.asarray(V0), side["buckets"],
+            jnp.float32(0.1), jnp.float32(1.0), ks=side["ks"],
+            implicit=False, weighted_lambda=True, precision="highest",
+            solver="xla", solver_mode="subspace", subspace_size=4,
+            stop_after=stop))
+        assert np.isfinite(sums[stop])
+    # the probe's sum over the looped chunks is the gathered rows' sum
+    gathered = sum(V0[i[u == row]].sum() for row in range(nu))
+    assert sums["gather"] == pytest.approx(float(gathered), rel=1e-4)
+
+
+def test_the_half_holds_no_second_copy_of_the_table_it_updates(monkeypatch):
+    """A chunk's warm start is read from the loop's carry, so the donated
+    table is updated in place: the compiled half's temporaries stay under
+    the table's own bytes (a second copy is what at 571,355 x 2,048 would
+    not fit beside the first)."""
+    from predictionio_tpu.models import als
+
+    _small_chunks(monkeypatch, entries=4096)
+    rng = np.random.default_rng(0)
+    nu, ni, nnz = 20000, 50, 60000
+    u = rng.integers(0, nu, nnz).astype(np.int32)
+    i = rng.integers(0, ni, nnz).astype(np.int32)
+    v = np.ones(nnz, np.float32)
+    cfg = ALSConfig(rank=64, implicit=True, solver_mode="subspace",
+                    subspace_size=16)
+    tr = ALSTrainer((u, i, v), nu, ni, cfg)
+    assert tr.chunks_looped["user"] > 1
+    U0, V0 = tr.init_factors()
+    side = tr._user_side
+    lowered = als._half_iteration.lower(
+        U0, V0, side["buckets"], jnp.float32(0.01), jnp.float32(1.0),
+        ks=side["ks"], implicit=True, weighted_lambda=True,
+        precision="highest", solver="xla", solver_mode="subspace",
+        subspace_size=16)
+    memory = lowered.compile().memory_analysis()
+    table = nu * 64 * 4
+    assert memory.temp_size_in_bytes < table, memory
+
+
+def test_table_gram_holds_a_bounded_stack_of_partial_grams(monkeypatch):
+    """At rank 2,048 the 139 partial Grams of 571,355 rows would be
+    2.3 GB at once: they are summed a bounded group at a time; a table
+    whose partial Grams fit the bound takes the sum it took."""
+    import jax
+
+    from predictionio_tpu.models import als
+
+    monkeypatch.setattr(als, "_GRAM_BLOCK_ROWS", 16)
+    rng = np.random.default_rng(1)
+    table = jnp.asarray(rng.standard_normal((16 * 11 + 5, 8)), jnp.float32)
+    prec = jax.lax.Precision.HIGHEST
+    whole = np.asarray(als._table_gram(table, prec))
+    monkeypatch.setattr(als, "_GRAM_STACK_BYTES", 3 * 4 * 8 * 8)
+    grouped = np.asarray(als._table_gram(table, prec))
+    want = np.asarray(table, np.float64).T @ np.asarray(table, np.float64)
+    np.testing.assert_allclose(grouped, want, rtol=1e-6, atol=1e-5)
+    np.testing.assert_allclose(grouped, whole, rtol=1e-6, atol=1e-5)
+    hlo = jax.jit(lambda t: als._table_gram(t, prec)).lower(table).as_text()
+    assert "3x16x8" in hlo and "11x8x8" not in hlo
+
+
+def test_wide_rows_are_summed_in_blocks_of_entries(monkeypatch):
+    import jax
+
+    from predictionio_tpu.models import als
+
+    rng = np.random.default_rng(2)
+    V = jnp.asarray(rng.standard_normal((3, 256, 8)), jnp.float32)
+    c = jnp.asarray(rng.random((3, 256)), jnp.float32)
+    prec = jax.lax.Precision.HIGHEST
+    plain = als._sum_over_entries("bk,bks,bkt->bst", c, V, V, prec=prec)
+    assert np.array_equal(
+        np.asarray(plain),
+        np.asarray(jnp.einsum("bk,bks,bkt->bst", c, V, V, precision=prec,
+                              preferred_element_type=jnp.float32)))
+    monkeypatch.setattr(als, "_GRAM_BLOCK_ROWS", 64)
+    for spec, ops in (("bk,bks,bkt->bst", (c, V, V)), ("bk,bks->bs", (c, V)),
+                      ("bks,bkt->bst", (V, V))):
+        blocked = als._sum_over_entries(spec, *ops, prec=prec)
+        want = np.einsum(spec, *(np.asarray(a, np.float64) for a in ops))
+        np.testing.assert_allclose(np.asarray(blocked), want, rtol=2e-6,
+                                   atol=2e-5)
+    hlo = jax.jit(lambda c, V: als._sum_over_entries(
+        "bk,bks->bs", c, V, prec=prec)).lower(c, V).as_text()
+    assert "3x4x64x8" in hlo
+
+
+def test_the_tracing_carries_the_block_sweep(monkeypatch):
+    from predictionio_tpu.obs import (
+        ALS_GATHER_BYTES_TOTAL, ALS_SOLVE_SYSTEMS_TOTAL, tower,
+    )
+
+    events = []
+    monkeypatch.setattr(
+        tower, "note_event", lambda name, **f: events.append((name, f)))
+    _small_chunks(monkeypatch)
+    u, i, v, nu, ni = _toy_implicit(n_users=90, n_items=40)
+    cfg = ALSConfig(rank=16, implicit=True, solver_mode="subspace",
+                    subspace_size=4)
+    tr = ALSTrainer((u, i, v), nu, ni, cfg)
+    (name, staged), = events
+    assert name == "als_staged"
+    assert (staged["solverMode"], staged["subspaceSize"],
+            staged["rankBlocks"]) == ("subspace", 4, 4)
+    padded = {name: sum(int(b[1].size) for b in side["buckets"])
+              for name, side in (("user", tr._user_side),
+                                 ("item", tr._item_side))}
+    assert staged["gatherBytes"] == {k: n * 16 * 4 for k, n in padded.items()}
+    assert staged["gatherChunkBytes"] <= 64 * 16 * 4
+    assert staged["gramChunkBytes"] % (4 * 4 * 4) == 0
+    assert staged["chunksLooped"]["user"] > 1
+    # a system a row a rank block, batch padding included
+    rows = {name: sum(int(b[0].size) for b in side["buckets"])
+            for name, side in (("user", tr._user_side),
+                               ("item", tr._item_side))}
+    assert staged["solveSystems"] == {k: 4 * n for k, n in rows.items()}
+    gathered = {s: ALS_GATHER_BYTES_TOTAL.labels(side=s)
+                for s in ("user", "item")}
+    solved = ALS_SOLVE_SYSTEMS_TOTAL.labels(path="lax")
+    before = {s: c.value() for s, c in gathered.items()}, solved.value()
+    U, V = tr.init_factors()
+    tr.run(U, V, 3)
+    for s, c in gathered.items():
+        assert c.value() - before[0][s] == 3 * staged["gatherBytes"][s]
+    assert solved.value() - before[1] == 3 * 4 * sum(rows.values())
+    # the full solve names one block and gathers the same way
+    events.clear()
+    ALSTrainer((u, i, v), nu, ni, ALSConfig(rank=16, implicit=True))
+    assert events[0][1]["rankBlocks"] == 1
+    assert events[0][1]["solverMode"] == "full"
+    assert events[0][1]["gatherBytes"]["user"] > 0

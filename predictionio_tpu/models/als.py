@@ -43,7 +43,7 @@ import logging
 import math
 import time
 from dataclasses import dataclass, field
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -987,6 +987,164 @@ def _table_gram(table: jax.Array, prec) -> jax.Array:
     return total
 
 
+# widest pad width, as a share of the rank, whose rows are solved in
+# the K x K form (`_lowrank_form`): measured on one v5e chip (PERF.md,
+# PR 37), one bucket of each K alone, a chunk with its gather, the full
+# form against this one: rank 128, 8,192 rows, K = 32 11.7 against 7.5
+# ms and K = 64 8.1 against 8.2; rank 64, 32,768 rows, K = 16 15.6
+# against 12.6 and K = 32 23.8 against 26.1
+_LOWRANK_RANK_SHARE = 4
+
+
+def _lowrank_form(k: int, r: int, implicit: bool, solver_mode: str,
+                  subspace_size: int, solver: str) -> bool:
+    """Whether a bucket of static pad width ``k`` at rank ``r`` is solved
+    in its K x K form against the shared base (`_lowrank_solve`) and not
+    as ``[B, R, R]`` normal equations.
+
+    Implicit buckets alone have a base every row shares (``YtY``); the
+    block sweep consumes the gathered rows by block, the fused kernel
+    takes its buckets whole, a dense bucket (``DENSE_K``) gathers
+    nothing; and the form wins only while a row's ``k`` rotated rows and
+    its K x K system cost less than an R x R Gram and factorisation:
+    ``k`` no more than a quarter of the rank."""
+    return (
+        implicit and solver != "fused" and k != DENSE_K
+        and not _block_sweeps(solver_mode, subspace_size, r)
+        and _LOWRANK_RANK_SHARE * k <= r
+    )
+
+
+class _GramBase(NamedTuple):
+    """``YtY`` in a basis that makes ``(YtY + reg I)^-1`` a scale by row:
+    ``q^T YtY q = diag(lam) + rest`` with ``q`` orthonormal to float32's
+    last bits and ``rest`` the little that float32's ``eigh`` leaves off
+    the diagonal."""
+    q: jax.Array      # [R, R]
+    lam: jax.Array    # [R]
+    rest: jax.Array   # [R, R]
+
+
+def _matmul_t_compensated(a: jax.Array, b: jax.Array) -> tuple:
+    """``a^T b`` of two small float32 matrices ``[n, p]``, ``[n, q]`` as
+    a pair (sum, what the sum's roundings dropped): the n products of
+    every entry are added one at a time, each addition's error kept
+    (Knuth's two-sum).  A plain float32 contraction over 128 terms is
+    good to 3e-7 of its largest partial sum, which `_gram_base` cannot
+    afford: its products are every row's system."""
+    def step(carry, ab):
+        s, c = carry
+        p = ab[0][:, None] * ab[1][None, :]
+        t = s + p
+        bp = t - s
+        return (t, c + ((s - (t - bp)) + (p - bp))), None
+
+    zero = jnp.zeros((a.shape[1], b.shape[1]), jnp.float32)
+    return jax.lax.scan(step, (zero, zero), (a, b))[0]
+
+
+def _gram_base(gram: jax.Array) -> _GramBase:
+    """Once a half, from the ``YtY`` the half already has: float32's
+    eigenvectors, made orthonormal to 1e-7 by a Newton-Schulz step on a
+    compensated ``q^T q - I`` (``eigh`` leaves 2e-6 on the CPU and 6e-6
+    on a v5e, and 1e-5 of ``YtY`` off the diagonal there), and
+    ``q^T YtY q`` by compensated products, so that ``lam`` and ``rest``
+    describe the ``YtY`` in hand to 5e-8 of it and not to the 3e-7 of a
+    plain float32 product.  Four products of 128 steps over [R, R]
+    arrays: 1.6 ms a half on a v5e (PERF.md, PR 37)."""
+    hi = jax.lax.Precision.HIGHEST
+    _, q = jnp.linalg.eigh(gram)
+    qq, low = _matmul_t_compensated(q, q)
+    eye = jnp.eye(gram.shape[-1], dtype=gram.dtype)
+    q = q - 0.5 * jnp.matmul(q, (qq - eye) + low, precision=hi)
+    gq, gq_low = _matmul_t_compensated(gram, q)      # YtY is symmetric
+    t, low = _matmul_t_compensated(q, gq)
+    low = low + jnp.matmul(q.T, gq_low, precision=hi)
+    # YtY has no negative eigenvalue; a rounded one may read as such
+    lam = jnp.maximum(jnp.diagonal(t), 0.0)
+    rest = (t - jnp.diag(lam)) + low
+    return _GramBase(q, lam, 0.5 * (rest + rest.T))
+
+
+def _lowrank_solve(Vm, val, maskf, reg, alpha, base: _GramBase, prec,
+                   solver: str, mesh, *, probe: bool = False):
+    """An implicit bucket's rows ``[B, R]`` from its gathered, masked
+    opposite rows ``Vm [B, K, R]`` where K is far under R, without the
+    ``[B, R, R]`` normal equations.
+
+    With ``G = YtY``, ``B_n = G + reg_n I`` and ``W = diag(sqrt(c - 1))
+    Vm`` a row's system is ``(B_n + W^T W) x = b``, and (Woodbury)
+
+        x = B_n^-1 b - B_n^-1 W^T (I_K + W B_n^-1 W^T)^-1 W B_n^-1 b
+
+    the same system over the same entries and the whole ``YtY``, an
+    identity.  In the basis of `_gram_base` ``B_n^-1`` is the scale
+    ``1 / (lam + reg_n)`` by row, whatever the row's count, so the work
+    is one rotation of the gathered rows, a K x K system (SPD whatever
+    the row holds: padded slots are identity rows of it), and a rotation
+    back.  What ``eigh`` left off the diagonal (``rest``) is taken in by
+    one step of refinement against the exact rotated system, a
+    ``[B, R] @ [R, R]`` product.  Takes ``c >= 1`` (``alpha * rating >=
+    0``, Hu-Koren-Volinsky's confidence): a negative weight has no
+    square root and reads NaN, which the sweep's watchdog reports.
+
+    ``probe``: the sum of the K x K systems and right-hand sides in the
+    rows' place (the phase probe's ``stop_after="gram"``)."""
+    f32 = jnp.float32
+    k = Vm.shape[1]
+    Vm = Vm.astype(f32)
+
+    def over_slots(coef, rows):
+        """``sum_k coef[b, k] rows[b, k, :]``."""
+        return jnp.einsum("bk,bkr->br", coef, rows, precision=prec)
+
+    def along_rank(rows, vec):
+        """``sum_r rows[b, k, r] vec[b, r]``."""
+        return jnp.einsum("bkr,br->bk", rows, vec, precision=prec)
+
+    with jax.named_scope("als.lowrank_rotate"):
+        Vt = jnp.einsum("bkr,rs->bks", Vm, base.q,
+                        precision=prec, preferred_element_type=f32)
+    with jax.named_scope("als.lowrank_system"):
+        cw = alpha.astype(f32) * val * maskf             # (c - 1), f32
+        d = 1.0 / (base.lam[None, :] + reg[:, None])     # [B, R]
+        sw = jnp.sqrt(cw)
+        Wt = sw[..., None] * Vt                          # [B, K, R]
+        S = jnp.eye(k, dtype=f32) + jnp.einsum(
+            "bkr,bjr->bkj", Wt * d[:, None, :], Wt, precision=prec)
+        # b = Vm^T cb, and the answer is B_n^-1 Vm^T coef with
+        # coef = cb - sqrt(c-1) S^-1 W B_n^-1 b: where a prediction is
+        # near 1 (alpha 40, a trained table) that difference cancels
+        # digits.  On the slots with a weight, sqrt(c-1) S^-1 (cb /
+        # sqrt(c-1)) is the same number with nothing to cancel; a real
+        # entry whose rating is 0 has no weight and keeps its cb
+        has_weight = cw > 0
+        cb = (1.0 + cw) * maskf
+        beta = jnp.where(has_weight, cb / jnp.where(has_weight, sw, 1.0), 0.0)
+        cb0 = jnp.where(has_weight, 0.0, cb)
+        rhs = beta - along_rank(Wt, d * over_slots(cb0, Vt))
+    if probe:
+        return S.sum() + rhs.sum()
+    with jax.named_scope("als.lowrank_solve"):
+        coef = sw * _spd_solve(S, rhs, solver, mesh) + cb0
+        wt = over_slots(coef, Vt)
+        # what eigh left off the diagonal: one step of refinement
+        # against the exact rotated system (diag(lam) + rest + reg +
+        # Wt^T Wt) xt = bt, whose residual at xt = d * wt is -xt @ rest
+        rt = jnp.einsum("br,rs->bs", d * wt, base.rest, precision=prec)
+        z = _spd_solve(S, along_rank(Wt, d * rt), solver, mesh)
+        wt = wt - rt + over_slots(z, Wt)
+        w = over_slots(coef + sw * z, Vm)
+    with jax.named_scope("als.lowrank_rotate"):
+        # B_n^-1 = mid I + q diag(d - mid) q^T with mid the least of d:
+        # what all directions share needs no rotation, so a base near a
+        # multiple of the identity (a seed's tables) leaves the rotation
+        # back little to round, and no term is a difference
+        mid = d.min(axis=1)[:, None]
+        return mid * w + jnp.einsum(
+            "bs,rs->br", (d - mid) * wt - mid * rt, base.q, precision=prec)
+
+
 def _half_iteration_impl(
     upd: jax.Array,        # [N, R] factor table being solved (donated)
     opp: jax.Array,        # [M, R] opposite-side factor table
@@ -1110,6 +1268,7 @@ def _solve_buckets(
     subspace_size: int = 0,
     upd_table: Optional[jax.Array] = None,
     gram: Optional[jax.Array] = None,
+    base: Optional[_GramBase] = None,
     stop_after: Optional[str] = None,
     mesh: Optional[Mesh] = None,
     exchange=None,
@@ -1173,6 +1332,13 @@ def _solve_buckets(
     same ``reg``, solve and scatter as any bucket's.  Replicated
     placement over a mesh shards its J rows as it shards B.
 
+    An implicit bucket whose pad width is small against the rank
+    (`_lowrank_form`, from the static ``k`` and ``r``) builds no
+    ``[B, R, R]``: its rows are solved in their K x K form against the
+    shared ``YtY`` base (`_lowrank_solve`).  ``base`` is that base's
+    decomposition (`_gram_base`), from a caller that comes here once a
+    chunk and so computes it once a half itself.
+
     ``exchange`` (`parallel/collectives.ShardedRows`; the sharded path)
     says that ``opp`` is this device's ``[M/d, R]`` shard and the
     buckets' ids are global: the gather below then looks up all
@@ -1191,6 +1357,14 @@ def _solve_buckets(
     )
     if implicit and gram is None:
         gram = _table_gram(opp, prec)
+    lowrank = [
+        stop_after != "gather" and _lowrank_form(
+            k, r, implicit, solver_mode, subspace_size, solver)
+        for k in ks
+    ]
+    # static, as every k is
+    if base is None and any(lowrank):  # piolint: disable=PIO104
+        base = _gram_base(gram)
     opp_g = (
         opp.astype(jnp.bfloat16)
         if gather_dtype == "bfloat16" and opp.dtype != jnp.bfloat16
@@ -1238,7 +1412,7 @@ def _solve_buckets(
 
     # the als.* scopes name each bucket's steps in the HLO metadata, so a
     # profile finds the kernels by name whatever XLA fuses them into
-    for (rows, idx, val, counts), k in zip(bucket_args, ks):
+    for (rows, idx, val, counts), k, low in zip(bucket_args, ks, lowrank):
         # k is a static pad width, never a traced value
         if k == DENSE_K:  # piolint: disable=PIO104
             if stop_after == "gather":
@@ -1250,6 +1424,14 @@ def _solve_buckets(
                     A = gram + A
             out = solve_and_write(out, rows, A, b, regularisation(counts))
             continue
+        if low and out is not None:  # piolint: disable=PIO104
+            # a replicated half unrolls its chunks, and nothing but the
+            # table orders them: without a [B, R, R] to weigh on it the
+            # TPU's scheduler starts hundreds of chunks' gathers and
+            # rotations at once (41 GB of temporaries for 640 chunks of
+            # 8,192 rows at rank 128, compiled for a v5e; PR 37).  This
+            # chunk's ids wait for the last chunk's scatter
+            out, idx = jax.lax.optimization_barrier((out, idx))
         with jax.named_scope("als.positions"):
             valid = _valid_slots(counts, k)
             maskf = valid.astype(f32)
@@ -1318,23 +1500,39 @@ def _solve_buckets(
                     opp_g.dtype
                 )                                            # [B, K, R]
             if exchange is not None:
-                Vm = exchange.collect(Vm)
+                if low:  # piolint: disable=PIO104
+                    # as [d*B*K, R]: the K x K form's consumers make the
+                    # TPU's compiler lay a [B, K, R] operand out K-major,
+                    # whose rows a reduce-scatter cannot scatter, and the
+                    # exchange became an all-reduce of all four devices'
+                    # rows (`als_exchange_s.x4` 2.50 -> 3.20; PR 37)
+                    Vm = exchange.collect(
+                        Vm.reshape(-1, r)).reshape(-1, k, r)
+                else:
+                    Vm = exchange.collect(Vm)
         if stop_after == "gather":
             out = (0.0 if out is None else out) + Vm.astype(f32).sum()
             continue
-        if sub:
-            # iALS++ block sweep: warm-start from the current factor
-            # rows (batch-padding ids are OOB -> fill 0; their output
-            # is dropped by the scatter anyway)
-            x0 = upd_table.at[rows].get(
-                mode="fill", fill_value=0.0
-            ).astype(f32)
-            cw_b = (alpha.astype(f32) * val * maskf) if implicit else None
-            res = _subspace_sweep(
-                Vm, val, maskf, x0, reg, cw_b, gram, prec, solver,
-                subspace_size, gram_probe=stop_after == "gram",
-                mesh=mesh,
-            )
+        # low and sub are static (the rule, the mode), never traced
+        if low or sub:  # piolint: disable=PIO104
+            if low:  # piolint: disable=PIO104
+                res = _lowrank_solve(
+                    Vm, val, maskf, reg, alpha, base, prec, solver, mesh,
+                    probe=stop_after == "gram")
+            else:
+                # iALS++ block sweep: warm-start from the current factor
+                # rows (batch-padding ids are OOB -> fill 0; their output
+                # is dropped by the scatter anyway)
+                x0 = upd_table.at[rows].get(
+                    mode="fill", fill_value=0.0
+                ).astype(f32)
+                cw_b = (alpha.astype(f32) * val * maskf) if implicit \
+                    else None
+                res = _subspace_sweep(
+                    Vm, val, maskf, x0, reg, cw_b, gram, prec, solver,
+                    subspace_size, gram_probe=stop_after == "gram",
+                    mesh=mesh,
+                )
             if stop_after == "gram":
                 out = (0.0 if out is None else out) + res
             else:
@@ -1662,6 +1860,12 @@ def build_sharded_half(
         upd_full = None
         if _block_sweeps(solver_mode, subspace_size, upd.shape[-1]):
             upd_full = jax.lax.all_gather(upd, axis, axis=0, tiled=True)
+        # the K x K form's base, once a half and not once a chunk: every
+        # device decomposes the same psum'd YtY
+        base = None
+        if any(_lowrank_form(k, upd.shape[-1], implicit, solver_mode,
+                             subspace_size, solver) for k in ks):
+            base = _gram_base(gram)
 
         def chunk_step(k):
             def step(table, chunk):
@@ -1695,7 +1899,8 @@ def build_sharded_half(
                     precision=precision, solver=solver,
                     gather_dtype=gather_dtype, gather_mode=gather_mode,
                     solver_mode=solver_mode, subspace_size=subspace_size,
-                    upd_table=upd_full, gram=gram, exchange=exchange,
+                    upd_table=upd_full, gram=gram, base=base,
+                    exchange=exchange,
                 )
                 return table if out is None else out
 
@@ -1944,6 +2149,15 @@ class ALSTrainer:
             "solver": cfg.solver,
             "solvePath": self.solve_path,
             "solveSystems": self.solve_systems,
+            "solveForms": {
+                name: {"lowrank": lowrank, "full": systems - lowrank}
+                for name, systems in self.solve_systems.items()
+                for lowrank in [sum(self.lowrank_systems[name].values())]
+            },
+            "lowrankWidths": {
+                name: {str(k): rows for k, rows in sorted(by_width.items())}
+                for name, by_width in self.lowrank_systems.items()
+            },
             "staging": self.staging,
             "placement": "sharded" if self.sharded else "replicated",
             "shards": n_dev if self.sharded else 1,
@@ -2021,22 +2235,44 @@ class ALSTrainer:
         host from the staged shapes alone.  A system for each row of
         each bucket, batch padding included, times the rank blocks of a
         subspace sweep; the buckets the fused kernel takes whole never
-        reach the solve."""
+        reach the solve.  Beside them the split by form: the rows whose
+        system is solved K x K against the shared base
+        (``lowrank_systems`` a side, by pad width; `_lowrank_form`),
+        which ``solve_systems`` counts too; the others are solved at
+        ``system_width``.  And ``pio_als_solve_systems_total``'s
+        children with what `run` adds to each once a sweep: the K x K
+        rows under ``path="lowrank"``, the rest under ``solve_path``."""
         cfg = self.cfg
         width = self.system_width
         self.solve_path = _solve_path(cfg.solver, width)
         fused = cfg.solver == "fused"
         if fused:
             from ..ops.fused_als import fused_tile_plan
+        sides = (("user", self._user_side), ("item", self._item_side))
         self.solve_systems = {
             name: -(-cfg.rank // width) * sum(
                 int(bucket[0].size)
                 for bucket, k in zip(side["buckets"], side["ks"])
                 if not (fused and fused_tile_plan(cfg.rank, k) is not None)
             )
-            for name, side in (("user", self._user_side),
-                               ("item", self._item_side))
+            for name, side in sides
         }
+        self.lowrank_systems = {name: {} for name, _ in sides}
+        for name, side in sides:
+            by_width = self.lowrank_systems[name]
+            for bucket, k in zip(side["buckets"], side["ks"]):
+                if _lowrank_form(k, cfg.rank, cfg.implicit, cfg.solver_mode,
+                                 cfg.subspace_size, cfg.solver):
+                    by_width[k] = by_width.get(k, 0) + int(bucket[0].size)
+        lowrank = sum(sum(by_width.values())
+                      for by_width in self.lowrank_systems.values())
+        self._solve_counters = [
+            (ALS_SOLVE_SYSTEMS_TOTAL.labels(path=path), systems)
+            for path, systems in (
+                (self.solve_path, sum(self.solve_systems.values()) - lowrank),
+                ("lowrank", lowrank),
+            ) if systems
+        ]
 
     def _plan_gram_counters(self) -> None:
         """``pio_als_gram_entries_total``'s children with what `run`
@@ -2906,9 +3142,8 @@ class ALSTrainer:
                 TRAIN_PHASE_SECONDS.labels(phase="als.item_half").observe(
                     phases["item_half"]
                 )
-            ALS_SOLVE_SYSTEMS_TOTAL.labels(path=self.solve_path).inc(
-                self.solve_systems["user"] + self.solve_systems["item"]
-            )
+            for counter, systems in self._solve_counters:
+                counter.inc(systems)
             for side_name, received in self.exchange_bytes.items():
                 if received:
                     ALS_EXCHANGE_BYTES_TOTAL.labels(side=side_name).inc(

@@ -213,9 +213,11 @@ TRAIN_PHASE_SECONDS = _registry.histogram(
 )
 ALS_SOLVE_SYSTEMS_TOTAL = _registry.counter(
     "pio_als_solve_systems_total",
-    "Normal-equation systems an ALS half handed the batched SPD solve, "
-    "by the path that solved them (kernel = ops/solve.py, lax = "
-    "lax.linalg)",
+    "Normal-equation systems an ALS half solved, by the path that solved "
+    "them at the half's system width (kernel = ops/solve.py, lax = "
+    "lax.linalg) or lowrank = rows of far fewer ratings than the rank, "
+    "solved K x K against the shared YtY base; from the staged plan, once "
+    "a sweep",
     labels=("path",),
 )
 ALS_GRAM_ENTRIES_TOTAL = _registry.counter(

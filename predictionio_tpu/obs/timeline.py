@@ -41,7 +41,19 @@ whose segments ``park -> claim -> prepare -> dispatch -> fetch ->
 decode -> complete`` say what the batcher's thread did between two
 device calls, in wall and in thread-CPU seconds.  Finished turns stay
 in a bounded in-memory deque (:func:`batch_turns`); each served
-request's ``serve.query`` span names its turn (``batchTurn``).
+request's ``serve.query`` span names its turn (``batchTurn``).  Inside
+``complete`` a turn also keeps ``parts``: seconds summed over its
+requests by what the completion callback does for each
+(:func:`mark_part`: ``book -> serve -> observe -> encode -> handoff``).
+
+The event loop's thread has the ``loop`` family (:class:`LoopRecord`,
+``pio_loop_seconds_total{server,phase}``): cumulative wall seconds by
+what the thread does between two returns of ``select`` (``poll ->
+accept | read | drain | write -> sweep``), its thread-CPU seconds and
+those it spent inside ``select``, the answers it wrote, and how long
+answers finished on other threads waited for it (hand-off wait).  About
+ten times a second the sums are copied as one *beat* into a bounded
+deque (:func:`loop_beats`); a reader subtracts two beats.
 
 Profiler bridge: :class:`annotate` enters a
 ``jax.profiler.TraceAnnotation`` in every process that has imported
@@ -71,15 +83,20 @@ from . import get_registry, log_buckets, telemetry_home
 __all__ = [
     "BATCH_SEGMENTS",
     "EVENT_SEGMENTS",
+    "LOOP_PHASES",
+    "LoopRecord",
     "ProfileBusy",
     "SERVE_SEGMENTS",
+    "TURN_PARTS",
     "Timeline",
     "Turn",
     "annotate",
     "batch_turns",
     "capture_profile",
     "current_timeline",
+    "loop_beats",
     "mark",
+    "mark_part",
     "profiles_dir",
     "register_segment_family",
     "timeline_scope",
@@ -98,6 +115,10 @@ EVENT_SEGMENTS = ("parse", "auth", "store_write", "reply")
 BATCH_SEGMENTS = (
     "park", "claim", "prepare", "dispatch", "fetch", "decode", "complete",
 )
+# what a turn's completion callback does for one request, inside `complete`
+TURN_PARTS = ("book", "serve", "observe", "encode", "handoff")
+# what the event loop's thread does between two returns of select
+LOOP_PHASES = ("poll", "accept", "read", "drain", "write", "sweep")
 
 SERVE_SEGMENT_SECONDS = _registry.histogram(
     "pio_serve_segment_seconds",
@@ -118,6 +139,41 @@ BATCH_TURN_SECONDS = _registry.histogram(
     "segment (park/claim/prepare/dispatch/fetch/decode/complete); a "
     "turn's segments sum to its wall time, claim to claim",
     labels=("segment",),
+)
+LOOP_SECONDS_TOTAL = _registry.counter(
+    "pio_loop_seconds_total",
+    "Wall seconds of an event loop's thread by phase (poll = inside "
+    "select; accept/read/drain/write = the events handled; sweep = the "
+    "loop's own housekeeping); the phases sum to the thread's wall "
+    "time, so 1 - rate(poll) is the loop's busy share",
+    labels=("server", "phase"),
+)
+LOOP_CPU_SECONDS_TOTAL = _registry.counter(
+    "pio_loop_cpu_seconds_total",
+    "Thread-CPU seconds of an event loop's thread, select included: "
+    "less pio_loop_poll_cpu_seconds_total and over the non-poll wall "
+    "seconds, the share of its work time the thread was on a CPU and "
+    "not runnable behind the interpreter lock",
+    labels=("server",),
+)
+LOOP_POLL_CPU_SECONDS_TOTAL = _registry.counter(
+    "pio_loop_poll_cpu_seconds_total",
+    "Thread-CPU seconds an event loop's thread spent inside select (a "
+    "blocking system call is not free), estimated from one select in "
+    "POLL_CPU_EVERY",
+    labels=("server",),
+)
+LOOP_HANDOFF_WAIT_SECONDS_TOTAL = _registry.counter(
+    "pio_loop_handoff_wait_seconds_total",
+    "Seconds finished answers waited between being queued off the "
+    "loop's thread and the loop taking them up; over "
+    "pio_loop_handoffs_total the mean hand-off wait",
+    labels=("server",),
+)
+LOOP_HANDOFFS_TOTAL = _registry.counter(
+    "pio_loop_handoffs_total",
+    "Answers queued to an event loop from another thread",
+    labels=("server",),
 )
 SERVE_INFLIGHT = _registry.gauge(
     "pio_serve_inflight",
@@ -310,10 +366,11 @@ def mark(segment: str) -> None:
 
 # -- the dispatcher's turn ---------------------------------------------------
 
-# finished turns, newest last: over two minutes at 30 turns/s.  Appended
-# by the thread that ran the turn and copied whole by readers; both are
-# one call into the deque under the interpreter lock.
-_TURNS: collections.deque = collections.deque(maxlen=4096)
+# finished turns, newest last: two minutes at the 130 turns/s of a
+# lightly loaded server (7.6 ms a turn).  Appended by the thread that
+# ran the turn and copied whole by readers; both are one call into the
+# deque under the interpreter lock.
+_TURNS: collections.deque = collections.deque(maxlen=16384)
 _turn_numbers = itertools.count(1)
 
 
@@ -321,7 +378,9 @@ def batch_turns() -> list:
     """The finished turns still in memory, oldest first: per turn its
     number ``turn``, ``t0`` (``perf_counter``), ``rows`` and ``padded``
     rows sent to the device, per segment ``wall`` and thread-``cpu``
-    seconds, and ``gcSec`` the collector took on the turn's thread."""
+    seconds, ``gcSec`` the collector took on the turn's thread, the
+    ``requests`` its completion callbacks answered and ``parts``, the
+    wall seconds of ``complete`` summed over them by what was done."""
     return list(_TURNS)
 
 
@@ -335,9 +394,17 @@ class Turn(Timeline):
     thread-CPU seconds: wall minus CPU of a segment that does not wait
     by design is time the thread sat runnable but off the CPU.
     :meth:`finish` books what no scope covered to ``complete``, so the
-    segments still sum to the turn's wall time."""
+    segments still sum to the turn's wall time.
 
-    __slots__ = ("cpu", "rows", "padded", "gc_s", "_c0", "_wall", "_cpu")
+    ``parts`` is a second dictionary, not segments: wall seconds inside
+    ``complete`` by what the completion callback does for one request,
+    summed over the turn's ``requests`` (:meth:`open_part` before each,
+    :func:`mark_part` at each step's end, the names :data:`TURN_PARTS`).
+    Their sum stays under ``complete``; what is left is the batcher's
+    own loop."""
+
+    __slots__ = ("cpu", "rows", "padded", "gc_s", "parts", "requests",
+                 "_c0", "_wall", "_cpu", "_part")
 
     PREFIX = "pio.turn."    # + segment: the scopes that book themselves
 
@@ -347,8 +414,16 @@ class Turn(Timeline):
         self.cpu: dict[str, float] = {}
         self.rows = self.padded = 0
         self.gc_s = 0.0
+        self.parts = dict.fromkeys(TURN_PARTS, 0.0)
+        self.requests = 0
         self._c0 = time.thread_time()
         self._wall = self._cpu = 0.0   # booked so far, all segments
+        self._part = self.t0           # where the open part began
+
+    def open_part(self) -> None:
+        """One more request's completion begins here."""
+        self.requests += 1
+        self._part = time.perf_counter()
 
     def book(self, segment: str, wall: float, cpu: float) -> None:
         self.segments[segment] = self.segments.get(segment, 0.0) + wall
@@ -365,8 +440,112 @@ class Turn(Timeline):
             "turn": self.turn, "t0": self.t0, "rows": self.rows,
             "padded": self.padded, "wall": dict(self.segments),
             "cpu": dict(self.cpu), "gcSec": self.gc_s,
+            "requests": self.requests, "parts": dict(self.parts),
         })
         return super().finish()
+
+
+def mark_part(name: str) -> None:
+    """Close the open part of the dispatcher's turn under ``name`` (one
+    of :data:`TURN_PARTS`) and open the next; a no-op unless this
+    thread's current timeline is a :class:`Turn` (a blocking caller's
+    thread, the aux pool, tests)."""
+    tl = getattr(_local, "tl", None)
+    if isinstance(tl, Turn):
+        now = time.perf_counter()
+        tl.parts[name] += now - tl._part
+        tl._part = now
+
+
+# -- the event loop's thread ---------------------------------------------------
+
+# beats of every loop in the process, newest last: 13 minutes of one busy
+# loop (ten a second); an idle loop beats once a second
+_BEATS: collections.deque = collections.deque(maxlen=8192)
+_loop_numbers = itertools.count(1)
+BEAT_PERIOD_S = 0.1
+# one select in so many has the thread's CPU clock read round it: odd, so
+# that a loop whose iterations alternate (a read, a drain) has both read
+POLL_CPU_EVERY = 7
+
+
+def loop_beats() -> list:
+    """The beats still in memory, oldest first.  A beat is a copy of one
+    loop's CUMULATIVE sums at ``t`` (``perf_counter``): ``loop`` (the
+    record's number: two servers of one name are two loops) and
+    ``server``, ``wall`` seconds by phase, ``cpu`` seconds of the thread
+    and ``pollCpu`` those of them inside ``select``, ``responses``
+    flushed, ``handoffs`` and ``handoffWaitSec``.  Subtract two beats of
+    one loop."""
+    return list(_BEATS)
+
+
+class LoopRecord:
+    """The sums one event loop keeps of its own thread, touched by that
+    thread alone (no lock): the loop adds to them at the boundaries it
+    already has and calls :meth:`beat` at an iteration's end once
+    ``BEAT_PERIOD_S`` has passed; only there are the sums copied into
+    :func:`loop_beats` and brought to ``/metrics``.
+
+    ``time.thread_time()`` is a system call of 6 us on the chip's host
+    (0.2 us where the kernel answers it in user space), so the thread's
+    CPU clock is read at the beat alone.  What that holds besides the
+    loop's work is ``select`` itself, which burns 100 us of thread-CPU
+    there each time it blocks: ``poll_cpu`` estimates it from a pair of
+    readings round one ``select`` in ``POLL_CPU_EVERY``, scaled by that
+    count, so ``cpu - poll_cpu`` is the CPU of the loop's work at 1.9 us
+    an iteration, and exact where ``select`` seldom blocks."""
+
+    __slots__ = ("loop", "server", "wall", "cpu", "poll_cpu", "responses",
+                 "handoffs", "handoff_wait", "t_beat", "_cpu_read",
+                 "_counters", "_sent")
+
+    def __init__(self, server: str):
+        self.loop = next(_loop_numbers)
+        self.server = server
+        self.wall = dict.fromkeys(LOOP_PHASES, 0.0)
+        self.cpu = self.poll_cpu = self.handoff_wait = 0.0
+        self.responses = self.handoffs = 0
+        self.t_beat = self._cpu_read = 0.0
+        # children made here, at the server's start-up, like
+        # _SEGMENT_CHILDREN: the schema is whole from the first scrape
+        self._counters = {
+            p: LOOP_SECONDS_TOTAL.labels(server=server, phase=p)
+            for p in LOOP_PHASES
+        }
+        self._counters["cpu"] = LOOP_CPU_SECONDS_TOTAL.labels(server=server)
+        self._counters["pollCpu"] = (
+            LOOP_POLL_CPU_SECONDS_TOTAL.labels(server=server))
+        self._counters["handoffWaitSec"] = (
+            LOOP_HANDOFF_WAIT_SECONDS_TOTAL.labels(server=server))
+        self._counters["handoffs"] = LOOP_HANDOFFS_TOTAL.labels(server=server)
+        self._sent = dict.fromkeys(self._counters, 0.0)
+
+    def start(self) -> float:
+        """The loop's thread begins (or takes up again) here."""
+        self._cpu_read = time.thread_time()
+        self.t_beat = time.perf_counter()
+        return self.t_beat
+
+    def beat(self, now: float) -> None:
+        self.t_beat = now
+        cpu_read = time.thread_time()
+        self.cpu += cpu_read - self._cpu_read
+        self._cpu_read = cpu_read
+        wall = dict(self.wall)
+        _BEATS.append({
+            "loop": self.loop, "server": self.server, "t": now, "wall": wall,
+            "cpu": self.cpu, "pollCpu": self.poll_cpu,
+            "responses": self.responses, "handoffs": self.handoffs,
+            "handoffWaitSec": self.handoff_wait,
+        })
+        sums = dict(wall, cpu=self.cpu, pollCpu=self.poll_cpu,
+                    handoffWaitSec=self.handoff_wait,
+                    handoffs=float(self.handoffs))
+        sent = self._sent
+        for key, child in self._counters.items():
+            child.inc(sums[key] - sent[key])
+            sent[key] = sums[key]
 
 
 # -- jax.profiler bridge ------------------------------------------------------

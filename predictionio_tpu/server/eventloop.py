@@ -24,6 +24,12 @@ Interface parity: the class exposes the ``server_address`` /
 through one lifecycle (bind-in-caller, ephemeral-port re-read,
 EADDRINUSE retry, stop-handshake semantics all unchanged).
 
+The loop's thread keeps a record of itself (``obs/timeline.py``, the
+``loop`` family): wall seconds by what it does between two returns of
+``select``, its thread-CPU seconds, the answers it wrote, and how long
+answers finished on other threads waited for it; see
+:class:`LoopRecord`.
+
 Deliberate non-features: no chunked transfer encoding (every client in
 this system sends Content-Length), no TLS, no HTTP/2 — a reverse proxy
 owns those concerns in production; this edge owns the query hot path.
@@ -40,6 +46,12 @@ import time
 from typing import Callable, Optional
 
 from ..obs import HTTP_CONN_REJECTED, HTTP_OPEN_CONNECTIONS
+from ..obs.timeline import (
+    BEAT_PERIOD_S,
+    POLL_CPU_EVERY,
+    LoopRecord,
+    mark_part,
+)
 
 __all__ = [
     "EventLoopHTTPServer",
@@ -118,7 +130,9 @@ class Responder:
             else json.dumps(payload).encode()
         )
         data = self._server._render(code, body, ctype, extra_headers, close)
+        mark_part("encode")
         self._server._complete(self._conn, data, tl, close)
+        mark_part("handoff")
 
 
 _REASONS = {
@@ -177,7 +191,9 @@ class EventLoopHTTPServer:
         self._wake_r.setblocking(False)
         self._conns: set[_Conn] = set()
         self._pending_lock = threading.Lock()
-        self._pending: list[tuple[_Conn, bytes, object, bool]] = []
+        # (conn, bytes, timeline, close, perf_counter when queued)
+        self._pending: list[tuple[_Conn, bytes, object, bool, float]] = []
+        self._rec = LoopRecord(name)
         self._stop = threading.Event()
         self._stopped = threading.Event()
         self._loop_thread: Optional[threading.Thread] = None
@@ -195,26 +211,60 @@ class EventLoopHTTPServer:
         self._loop_thread = threading.current_thread()
         self._sel.register(self._lsock, selectors.EVENT_READ, "accept")
         self._sel.register(self._wake_r, selectors.EVENT_READ, "wake")
-        last_sweep = time.monotonic()
+        # the thread's wall time is tiled by consecutive stamps, each
+        # interval booked to the phase that ended at it; the thread's CPU
+        # clock is read at the beat, and round one select in
+        # POLL_CPU_EVERY for what select itself burns
+        rec = self._rec
+        wall = rec.wall
+        clock, cpu_clock = time.perf_counter, time.thread_time
+        t = last_sweep = rec.start()
+        skip = 0
         try:
             while not self._stop.is_set():
-                events = self._sel.select(timeout=1.0)
+                if skip:
+                    skip -= 1
+                    events = self._sel.select(timeout=1.0)
+                else:
+                    skip = POLL_CPU_EVERY - 1
+                    cpu0 = cpu_clock()
+                    events = self._sel.select(timeout=1.0)
+                    rec.poll_cpu += POLL_CPU_EVERY * (cpu_clock() - cpu0)
+                now = clock()
+                wall["poll"] += now - t
+                t = now
                 for key, mask in events:
-                    if key.data == "accept":
+                    what = key.data
+                    if what == "accept":
                         self._accept()
-                    elif key.data == "wake":
+                        phase = "accept"
+                    elif what == "wake":
                         self._drain_wakeups()
+                        phase = "drain"
                     else:
-                        conn = key.data
                         if mask & selectors.EVENT_READ:
-                            self._readable(conn)
-                        if mask & selectors.EVENT_WRITE:
-                            self._writable(conn)
-                now = time.monotonic()
-                if now - last_sweep >= 5.0:
-                    last_sweep = now
-                    self._sweep_idle(now)
+                            self._readable(what)
+                            now = clock()
+                            wall["read"] += now - t
+                            t = now
+                        if not mask & selectors.EVENT_WRITE:
+                            continue
+                        self._writable(what)
+                        phase = "write"
+                    now = clock()
+                    wall[phase] += now - t
+                    t = now
+                if t - last_sweep >= 5.0:
+                    last_sweep = t
+                    self._sweep_idle(time.monotonic())
+                now = clock()
+                wall["sweep"] += now - t
+                t = now
+                if t - rec.t_beat >= BEAT_PERIOD_S:
+                    rec.beat(t)
         finally:
+            if t > rec.t_beat:      # the closing sums, once
+                rec.beat(t)
             self._stopped.set()
 
     def shutdown(self) -> None:
@@ -250,12 +300,17 @@ class EventLoopHTTPServer:
             pass
         with self._pending_lock:
             pending, self._pending = self._pending, []
-        for conn, data, tl, close in pending:
+        now = time.perf_counter()
+        waited = 0.0
+        for conn, data, tl, close, queued in pending:
+            waited += now - queued
             if conn in self._conns:
                 conn.tl = tl
                 conn.closing = conn.closing or close
                 conn.wbuf.append(data)
                 self._writable(conn)
+        self._rec.handoffs += len(pending)
+        self._rec.handoff_wait += waited
 
     def _accept(self) -> None:
         while True:
@@ -452,8 +507,9 @@ class EventLoopHTTPServer:
                 conn.wbuf.append(data)
                 self._writable(conn)
             return
+        queued = time.perf_counter()
         with self._pending_lock:
-            self._pending.append((conn, data, tl, close))
+            self._pending.append((conn, data, tl, close, queued))
         self._wake()
 
     def _writable(self, conn: _Conn) -> None:
@@ -482,6 +538,7 @@ class EventLoopHTTPServer:
         # write segment ends at the last successful send) and either
         # close the connection or look for the next pipelined request
         self._set_interest(conn, selectors.EVENT_READ)
+        self._rec.responses += 1
         if conn.tl is not None:
             tl, conn.tl = conn.tl, None
             tl.mark("write")

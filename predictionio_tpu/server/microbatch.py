@@ -44,6 +44,9 @@ fetch, complete; an engine's ``batch_predict`` may carve prepare /
 dispatch / decode out of fetch), which is at once a span in any
 profiler session and a wall + thread-CPU segment of the turn's record
 (``timeline.batch_turns()``, ``pio_batch_turn_seconds{segment}``).
+Inside ``complete`` the turn's ``parts`` say what the callbacks did for
+each request (``timeline.mark_part``: book here, serve / observe in
+``serving.py``, encode / handoff in the edge's ``Responder``).
 
 Batch size therefore adapts to the arrival rate with no tuning knob
 doing latency/throughput trades behind the operator's back
@@ -74,6 +77,7 @@ from ..obs.timeline import (
     Turn,
     annotate,
     current_timeline,
+    mark_part,
     timeline_scope,
 )
 from ..resilience.policy import Deadline, DeadlineExceeded
@@ -606,12 +610,15 @@ class MicroBatcher:
         so the end-of-turn sweep can still answer anything a
         BaseException left unfired.  Must be called WITHOUT the lock —
         callbacks enqueue response bytes to the event loop."""
+        turn = current_timeline()   # the dispatcher's Turn
         with annotate("pio.turn.complete"):
             for e in entries:
                 if e.on_done is None or e.cb_fired:
                     continue
                 e.cb_fired = True
+                turn.open_part()
                 self._book_timeline(e, e.tl)
+                mark_part("book")
                 try:
                     e.on_done(e)
                 except Exception:

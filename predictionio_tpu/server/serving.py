@@ -1015,6 +1015,7 @@ class EngineServer(HTTPServerBase):
             # attribution loop online eval closes
             out = {**out, "variant": lease.variant}
         tl.mark("serialize")
+        timeline.mark_part("serve")
         self._admission_breaker.record_success()
         dt = time.perf_counter() - t0
         with self._lock:
@@ -1431,6 +1432,7 @@ class EngineServer(HTTPServerBase):
                 self._el_reply_error(err, respond, hdrs, lease=ctx.lease)
                 return
             self._m_queries["ok"].inc()
+            timeline.mark_part("observe")
             respond(200, out, extra_headers=hdrs, tl=tl)
 
         try:

@@ -23,12 +23,6 @@ FILTER_ROWS = _registry.counter(
     "the host: categories, a whiteList, or a list past the ids' width)",
     labels=("filter",),
 )
-FILTER_EXCLUDED_IDS = _registry.histogram(
-    "pio_filter_excluded_ids",
-    "Excluded item ids of one query (its seed items and blackList, as "
-    "resolved through the model's id map), in batches filtered by ids",
-    buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128),
-).child()
 FILTER_BUILD_SECONDS = _registry.histogram(
     "pio_filter_build_seconds",
     "Host time of one batch's `pio.filter.build` span: its queries' "
@@ -381,9 +375,6 @@ def batch_filter(items, item_props: Optional[dict],
             out = BatchFilter("mask", mask=mask)
     FILTER_BUILD_SECONDS.observe(time.perf_counter() - t0)
     FILTER_ROWS.labels(filter=out.kind).inc(len(rows))
-    if out.kind == "ids":
-        for ex in lists:
-            FILTER_EXCLUDED_IDS.observe(len(ex))
     return out
 
 

@@ -5,12 +5,14 @@ implicitly rides now that the EngineServer defaults to this edge."""
 
 import http.client
 import json
+import queue
 import socket
 import threading
 import time
 
 import pytest
 
+from predictionio_tpu.obs import timeline
 from predictionio_tpu.server.eventloop import EventLoopHTTPServer
 
 
@@ -252,3 +254,135 @@ def test_handler_exception_answers_500():
     finally:
         srv.shutdown()
         srv.server_close()
+
+
+# -- the loop's record of its own thread (obs/timeline.py, `loop` family) ----
+
+
+def _beats_of(name):
+    return [b for b in timeline.loop_beats() if b["server"] == name]
+
+
+def _between(b0, b1):
+    assert b0["loop"] == b1["loop"]
+    out = {k: b1[k] - b0[k] for k in b1
+           if k not in ("loop", "server", "wall")}
+    out["wall"] = {p: b1["wall"][p] - b0["wall"][p] for p in b1["wall"]}
+    return out
+
+
+def test_loop_beats_account_for_the_threads_time_and_the_handoffs():
+    """A few hundred keep-alive requests, each answered from another
+    thread: between two beats the phases tile the elapsed time, and the
+    counts are those of the answers queued and written."""
+    inbox = queue.Queue()
+
+    def handler(req, respond):
+        if req.path == "/ping":
+            respond(200, {"pong": True})    # on the loop: no hand-off
+        else:
+            inbox.put(respond)
+
+    def answer():
+        while True:
+            respond = inbox.get()
+            if respond is None:
+                return
+            respond(200, {"ok": True})
+
+    worker = threading.Thread(target=answer, daemon=True)
+    worker.start()
+    srv = _boot(handler, name="beats")
+    try:
+        conns = [_conn(srv) for _ in range(3)]
+        # an iteration that ends a beat's period or more after the last
+        # beat makes one: the mark the counts below are subtracted from
+        time.sleep(0.15)
+        conns[0].request("GET", "/ping", None)
+        assert conns[0].getresponse().read()
+        b0 = _beats_of("beats")[-1]
+        assert b0["responses"] == 1 and b0["handoffs"] == 0
+        n = 300
+        for i in range(n):
+            c = conns[i % 3]
+            c.request("POST", "/q", b'{"i": 1}')
+            r = c.getresponse()
+            assert r.status == 200 and r.read()
+        for c in conns:
+            c.close()
+    finally:
+        inbox.put(None)
+        srv.shutdown()      # the loop's last act is a beat
+        srv.server_close()
+    worker.join(5.0)
+    assert not worker.is_alive()
+    beats = _beats_of("beats")
+    assert [b["t"] for b in beats] == sorted(b["t"] for b in beats)
+    d = _between(b0, beats[-1])
+    assert set(d["wall"]) == set(timeline.LOOP_PHASES)
+    assert all(v >= 0 for v in d["wall"].values())
+    assert sum(d["wall"].values()) == pytest.approx(d["t"], rel=0.01)
+    assert d["responses"] == n and d["handoffs"] == n
+    assert 0 < d["handoffWaitSec"] < d["t"] * n
+    assert d["wall"]["read"] > 0 and d["wall"]["drain"] > 0
+    # the thread's CPU is read at the beat, select's share of it round
+    # one select in POLL_CPU_EVERY: the rest is the work's, inside the
+    # work's wall time (a tick of the coarsest thread clock allowed)
+    assert 0 <= d["pollCpu"] <= d["cpu"] + 0.011
+    assert 0 < d["cpu"] <= d["t"] + 0.011
+    assert d["cpu"] - d["pollCpu"] <= d["t"] - d["wall"]["poll"] + 0.011
+    assert set(beats[-1]) == {
+        "loop", "server", "t", "wall", "cpu", "pollCpu", "responses",
+        "handoffs", "handoffWaitSec"}
+    # the same sums, on /metrics since the last beat
+    fam = timeline.LOOP_SECONDS_TOTAL
+    assert fam.labels(server="beats", phase="read").value() == \
+        pytest.approx(beats[-1]["wall"]["read"])
+    assert timeline.LOOP_HANDOFFS_TOTAL.labels(
+        server="beats").value() == n
+    assert timeline.LOOP_CPU_SECONDS_TOTAL.labels(
+        server="beats").value() == pytest.approx(beats[-1]["cpu"])
+    assert timeline.LOOP_POLL_CPU_SECONDS_TOTAL.labels(
+        server="beats").value() == pytest.approx(beats[-1]["pollCpu"])
+    assert timeline.LOOP_HANDOFF_WAIT_SECONDS_TOTAL.labels(
+        server="beats").value() == pytest.approx(
+            beats[-1]["handoffWaitSec"])
+
+
+def test_an_idle_loop_beats_once_a_second_and_books_it_to_poll():
+    srv = _boot(_echo_handler, name="idle-beats")
+    try:
+        time.sleep(2.4)
+    finally:
+        srv.shutdown()
+        srv.server_close()
+    beats = _beats_of("idle-beats")
+    # select's timeout wakes the loop once a second; the last beat is
+    # the one the loop makes as it stops
+    assert len(beats) == 3
+    assert beats[1]["t"] - beats[0]["t"] == pytest.approx(1.0, abs=0.2)
+    d = _between(beats[0], beats[-1])
+    assert d["wall"]["poll"] >= 0.99 * d["t"]
+    assert sum(d["wall"].values()) == pytest.approx(d["t"], rel=0.01)
+    assert d["responses"] == d["handoffs"] == 0
+    assert d["handoffWaitSec"] == 0.0
+    assert 0 <= d["cpu"] < 0.1 * d["t"]
+
+
+def test_two_loops_of_one_name_beat_under_their_own_numbers():
+    """Every `EngineServer` names its loop "serving": two of them in one
+    process (tenants) must not be subtracted one from the other."""
+    a = _boot(_echo_handler, name="twins")
+    b = _boot(_echo_handler, name="twins")
+    try:
+        time.sleep(0.3)
+    finally:
+        for srv in (a, b):
+            srv.shutdown()
+            srv.server_close()
+    beats = _beats_of("twins")
+    assert {b["loop"] for b in beats} == {a._rec.loop, b._rec.loop}
+    assert a._rec.loop != b._rec.loop
+    for loop in (a._rec.loop, b._rec.loop):
+        polls = [b["wall"]["poll"] for b in beats if b["loop"] == loop]
+        assert polls == sorted(polls)

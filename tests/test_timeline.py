@@ -18,12 +18,14 @@ import numpy as np
 import pytest
 
 from predictionio_tpu.obs import QUERY_LATENCY, get_tracer
+from predictionio_tpu.obs import timeline as timeline_module
 from predictionio_tpu.obs.timeline import (
     BATCH_SEGMENTS,
     BATCH_TURN_SECONDS,
     EVENT_SEGMENTS,
     EVENTS_SEGMENT_SECONDS,
     SERVE_SEGMENTS,
+    TURN_PARTS,
     SERVE_SEGMENT_SECONDS,
     ProfileBusy,
     Timeline,
@@ -33,6 +35,7 @@ from predictionio_tpu.obs.timeline import (
     capture_profile,
     current_timeline,
     mark,
+    mark_part,
     timeline_scope,
 )
 
@@ -193,6 +196,63 @@ def test_turn_numbers_rise_and_finish_observes_the_family():
     assert after["park"] == before["park"]
     rec = batch_turns()[-1]
     assert (rec["rows"], rec["padded"], rec["gcSec"]) == (3, 4, 0.0)
+
+
+PARTS = ("book", "serve", "observe", "encode", "handoff")
+
+
+def test_turn_parts_are_one_tuple_and_a_name_outside_it_raises():
+    """The parts are spelled once, in `TURN_PARTS`; the call sites in
+    microbatch, serving and the edge cannot drift from it silently: a
+    turn holds every part from its start and a misspelt one is a
+    KeyError where it is marked, not a part that reads 0."""
+    assert TURN_PARTS == PARTS
+    turn = Turn()
+    assert turn.parts == dict.fromkeys(PARTS, 0.0)
+    with timeline_scope(turn):
+        turn.open_part()
+        with pytest.raises(KeyError):
+            mark_part("encoding")
+    with timeline_scope(None):
+        mark_part("encoding")     # off a turn: still a no-op
+
+
+def test_mark_part_books_parts_inside_complete_and_leaves_the_segments():
+    """Parts are a second dictionary: they sum to no more than
+    `complete`, count their requests, and the identity that the
+    segments sum to the turn's wall time stands as it was."""
+    turn = Turn()
+    with timeline_scope(turn):
+        with annotate("pio.turn.fetch"):
+            _busy(0.3)
+        with annotate("pio.turn.complete"):
+            for _ in range(3):
+                _busy(0.05)     # the batcher's own loop: in no part
+                turn.open_part()
+                for name in PARTS:
+                    _busy(0.1)
+                    mark_part(name)
+    t_end = time.perf_counter()
+    segs = turn.finish()
+    assert sum(segs.values()) == pytest.approx(t_end - turn.t0, abs=2e-4)
+    assert set(segs) == {"fetch", "complete"}
+    rec = batch_turns()[-1]
+    assert rec["turn"] == turn.turn and rec["requests"] == 3
+    assert tuple(rec["parts"]) == PARTS
+    assert all(v >= 3 * 1e-4 for v in rec["parts"].values())
+    assert sum(rec["parts"].values()) <= rec["wall"]["complete"] - 3 * 5e-5
+    assert timeline_module._TURNS.maxlen == 16384
+
+
+@pytest.mark.parametrize("scope", ["request", "none"])
+def test_mark_part_is_a_no_op_off_a_turn(scope):
+    """Under a request's own timeline (a blocking `predict_json`, the aux
+    pool) and under none (the loop's thread, a library call)."""
+    serve = Timeline("serve")
+    with timeline_scope(serve if scope == "request" else None):
+        mark_part("serve")
+        mark_part("encode")
+    assert serve.segments == {} and not hasattr(serve, "parts")
 
 
 def test_annotate_books_only_on_a_timeline_its_name_addresses():

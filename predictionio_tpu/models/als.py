@@ -696,11 +696,12 @@ def _valid_slots(counts, k: int):
     return jnp.arange(k, dtype=jnp.int32)[None, :] < counts[:, None]
 
 
-def _expand_bucket(c_sorted, v_sorted, starts, counts, k: int,
-                   tail: int = 0):
+def _expand_bucket(c_sorted, v_sorted, starts, counts, k: int):
     """One bucket's padded ``[B, K]`` opposite-side ids and ratings from
     the row-grouped columns: row b's slots are ``c_sorted[starts[b] :
-    starts[b] + counts[b]]``, the padding slots 0 / 0.0.
+    starts[b] + counts[b]]``, the padding slots 0 / 0.0.  Staging's own
+    (`_expand_side`, `_expand_side_sharded`, `_dense_block`): no sweep
+    reads the columns.
 
     From ``_SLICE_MIN_K`` entries a row, read as B slices of length K,
     not B*K addresses: the TPU's per-element gather from a
@@ -709,9 +710,7 @@ def _expand_bucket(c_sorted, v_sorted, starts, counts, k: int,
     elements a row: a slice costs its 2.4 us whatever its width, and
     20 M rows of K = 8 were 27 s of a 40 s sweep (four chips, PR 34);
     the block's bits are the same either way.  The columns' tail is
-    padded by K, so that no slice is clamped at the end and shifted;
-    ``tail`` is the padding the caller's columns already carry (a loop
-    over chunks pads once, outside it).
+    padded by K, so that no slice is clamped at the end and shifted.
     """
     valid = _valid_slots(counts, k)
 
@@ -728,7 +727,7 @@ def _expand_bucket(c_sorted, v_sorted, starts, counts, k: int,
     read = slices if k >= _SLICE_MIN_K else elements  # piolint: disable=PIO104
 
     def block(column):
-        rows = read(jnp.pad(column, (0, max(k - tail, 0))))
+        rows = read(jnp.pad(column, (0, k)))
         return jnp.where(valid, rows, 0)
 
     return block(c_sorted), block(v_sorted)
@@ -745,6 +744,54 @@ def _expand_side(c_sorted, v_sorted, starts_counts, *, ks):
             _expand_bucket(c_sorted, v_sorted, starts, counts, k)
             for (starts, counts), k in zip(starts_counts, ks)
         )
+
+
+@xray.instrument("als.expand_side_sharded")
+@functools.partial(jax.jit, static_argnames=("mesh", "ks"))
+def _expand_side_sharded(c_sorted, v_sorted, starts_counts, *, mesh, ks):
+    """`_expand_side` under sharded placement: every chunk group of one
+    side (``[n, B]`` shard-local starts and counts, `_chunk_groups`)
+    expanded to its ``[n, B, K]`` ids and ratings, once, at staging,
+    each device reading its own ``[n, B/d]`` rows out of its own shard
+    of the columns (``P('data')``; `_plan_shard_layout`).  The blocks
+    stay where they are made, ``P(None, 'data', None)``; the sharded
+    halves read them and hold no gather from the shard's COO.
+
+    A chunk at a time: the TPU pads a ``[rows, 8]`` temporary to 128
+    lanes, and a group's 5 M rows at once are 2.6 GB of positions."""
+    from ..parallel.collectives import shard_map
+
+    def body(c_shard, v_shard, *flat):
+        with jax.named_scope("als.positions"):
+            blocks = []
+            for g, k in enumerate(ks):
+                blocks += jax.lax.map(
+                    lambda chunk, k=k: _expand_bucket(
+                        c_shard, v_shard, *chunk, k),
+                    (flat[2 * g], flat[2 * g + 1]),
+                )
+            return tuple(blocks)
+
+    column, chunks = P(DATA_AXIS), P(None, DATA_AXIS)
+    blocks = P(None, DATA_AXIS, None)
+    flat = shard_map(
+        body, mesh=mesh,
+        in_specs=(column, column) + (chunks,) * (2 * len(ks)),
+        out_specs=(blocks,) * (2 * len(ks)),
+    )(c_sorted, v_sorted, *(a for pair in starts_counts for a in pair))
+    return tuple(zip(flat[0::2], flat[1::2]))
+
+
+def _expansion_report(padded, expand_s: float) -> dict:
+    """What a staged side says of its padded blocks (``(idx, val)`` a
+    bucket or chunk group), under either placement; the ``als.expand``
+    phase is observed here, once a side."""
+    TRAIN_PHASE_SECONDS.labels(phase="als.expand").observe(expand_s)
+    return {
+        "padded_entries": sum(idx.size for idx, _ in padded),
+        "padded_bytes": sum(a.nbytes for blk in padded for a in blk),
+        "expand_s": round(expand_s, 6),
+    }
 
 
 # entries of one slice of a dense row's ratings (`_dense_pieces`)
@@ -1795,15 +1842,21 @@ def build_sharded_half(
       whole table reads.  Each device then solves its shard of the
       chunk, all-gathers the small solved blocks ``[B, R]`` and writes
       only the rows its own factor shard owns.
-    * Rating COO arrays are SHARDED ``P('data')``: each device holds only
-      the slices of the bucket rows it solves, in shard-local order with
-      shard-local starts (``_plan_shard_layout``) — rating capacity
-      scales with mesh HBM like MLlib's co-partitioned rating blocks,
-      and the int32-offset ceiling applies per shard.
+    * The ratings are SHARDED with the rows that read them: staging
+      lays each device's rating slices out in shard-local order
+      (``_plan_shard_layout``; the int32-offset ceiling applies per
+      shard) and expands them there, once, to the padded blocks
+      (`_expand_side_sharded`), so a device holds the ids and ratings
+      of the bucket rows it solves and no other — rating capacity
+      scales with mesh HBM like MLlib's co-partitioned rating blocks.
+      A half reads the blocks: it takes no argument of the shard's COO
+      length and gathers nothing from a one-dimensional array.
     * ``ks[g]`` is the pad width of chunk group ``g``, whose arrays are
-      ``[n, B]``: n chunks of one shape (``_chunk_groups``), run as ONE
-      loop, so a table of 600 chunks traces, lowers and compiles one
-      chunk's program a shape.
+      the replicated path's own tuple laid out by chunk, ``(rows [n, B],
+      idx [n, B, K], val [n, B, K], counts [n, B])``, the batch split
+      over the mesh: n chunks of one shape (``_chunk_groups``), run as
+      ONE loop, so a table of 600 chunks traces, lowers and compiles
+      one chunk's program a shape.
 
     Two modes keep a whole-table gather, because what they run reads a
     whole table: ``solver="fused"`` (the kernel fetches rows by DMA from
@@ -1815,7 +1868,7 @@ def build_sharded_half(
     shard's block inside the gathered table and so keeps the all-gather.
     Signature grows two inputs and one output::
 
-        fn(upd, opp, opp_parity, ok_mask, c, v, lam, alpha, *buckets)
+        fn(upd, opp, opp_parity, ok_mask, lam, alpha, *buckets)
           -> (new_upd, new_upd_parity)
 
     ``opp_parity`` is the replicated ``[M/d, R]`` f32 block sum of the
@@ -1839,18 +1892,11 @@ def build_sharded_half(
     axis = DATA_AXIS
     d = mesh.shape[axis]
     f32 = jnp.float32
-    tail = max(ks, default=0)
 
-    def solve_core(upd, opp, gram, c_sorted, v_sorted, lam, alpha,
-                   flat_buckets, exchange=None):
+    def solve_core(upd, opp, gram, lam, alpha, flat_buckets, exchange=None):
         me = jax.lax.axis_index(axis)
         shard_n = upd.shape[0]
         lo = (me * shard_n).astype(jnp.int32)
-        # the shard-local COO is expanded here, in every half, a chunk
-        # at a time; the replicated path's staging does it once
-        # (_expand_side).  The columns are padded once for every chunk
-        c_sorted = jnp.pad(c_sorted, (0, tail))
-        v_sorted = jnp.pad(v_sorted, (0, tail))
         # subspace mode warm-starts each row's block sweep from the
         # CURRENT factor value, but this device solves rows owned by
         # OTHER shards — gather the full updating table transiently
@@ -1869,8 +1915,6 @@ def build_sharded_half(
 
         def chunk_step(k):
             def step(table, chunk):
-                rows, starts, counts = chunk
-
                 def write(acc, rows, x):
                     acc = table if acc is None else acc
                     with jax.named_scope(EXCHANGE_SCOPE):
@@ -1886,14 +1930,8 @@ def build_sharded_half(
                     return acc.at[safe].set(
                         xg.astype(acc.dtype), mode="drop")
 
-                bucket = (
-                    rows,
-                    *_expand_bucket(c_sorted, v_sorted, starts, counts, k,
-                                    tail=tail),
-                    counts,
-                )
                 out = _solve_buckets(
-                    write, opp, (bucket,), lam, alpha,
+                    write, opp, (chunk,), lam, alpha,
                     ks=(k,), implicit=implicit,
                     weighted_lambda=weighted_lambda,
                     precision=precision, solver=solver,
@@ -1909,7 +1947,7 @@ def build_sharded_half(
         table = upd
         for g, k in enumerate(ks):
             table = _each_chunk(
-                chunk_step(k), table, flat_buckets[3 * g : 3 * g + 3]
+                chunk_step(k), table, flat_buckets[4 * g : 4 * g + 4]
             )
         return table
 
@@ -1923,11 +1961,15 @@ def build_sharded_half(
     P_ = P
     sharded2 = P_(axis, None)
     rep = P_()
-    bucket_specs = (P_(None, axis),) * (3 * len(ks))
+    # a group's (rows, idx, val, counts), as staged
+    bucket_specs = (
+        P_(None, axis), P_(None, axis, None), P_(None, axis, None),
+        P_(None, axis),
+    ) * len(ks)
 
     if not coded:
 
-        def body(upd, opp, c_sorted, v_sorted, lam, alpha, *flat_buckets):
+        def body(upd, opp, lam, alpha, *flat_buckets):
             # upd/opp arrive as local shards [Np/d, R] / [Mp/d, R]
             gram = None
             if implicit:
@@ -1947,16 +1989,14 @@ def build_sharded_half(
                 return solve_core(
                     upd, jax.lax.all_gather(opp_send, axis, axis=0,
                                             tiled=True),
-                    gram, c_sorted, v_sorted, lam, alpha, flat_buckets,
+                    gram, lam, alpha, flat_buckets,
                 )
             return solve_core(
-                upd, opp, gram, c_sorted, v_sorted, lam, alpha,
-                flat_buckets, exchange=ShardedRows(axis, opp.shape[0]),
+                upd, opp, gram, lam, alpha, flat_buckets,
+                exchange=ShardedRows(axis, opp.shape[0]),
             )
 
-        in_specs = (
-            sharded2, sharded2, P_(axis), P_(axis), rep, rep,
-        ) + bucket_specs
+        in_specs = (sharded2, sharded2, rep, rep) + bucket_specs
         mapped = shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=sharded2,
         )
@@ -1964,8 +2004,7 @@ def build_sharded_half(
             jax.jit(mapped, donate_argnums=(0,))
         )
 
-    def coded_body(upd, opp, opp_parity, ok, c_sorted, v_sorted, lam,
-                   alpha, *flat_buckets):
+    def coded_body(upd, opp, opp_parity, ok, lam, alpha, *flat_buckets):
         me = jax.lax.axis_index(axis)
         opp_send = (
             opp.astype(jnp.bfloat16)
@@ -2003,10 +2042,7 @@ def build_sharded_half(
             gram = alive_gram + jnp.einsum(
                 "mr,ms->rs", recon, recon, precision=_prec(),
             ) * (1.0 - jnp.min(ok))
-        out = solve_core(
-            upd, opp_full, gram, c_sorted, v_sorted, lam, alpha,
-            flat_buckets,
-        )
+        out = solve_core(upd, opp_full, gram, lam, alpha, flat_buckets)
         # a degraded shard wrote nothing this half: freeze its rows
         okw = okm.astype(out.dtype)
         out = out * okw + upd.astype(out.dtype) * (1.0 - okw)
@@ -2014,9 +2050,7 @@ def build_sharded_half(
         new_parity = jax.lax.psum(out.astype(f32), axis)
         return out, new_parity
 
-    in_specs = (
-        sharded2, sharded2, rep, rep, P_(axis), P_(axis), rep, rep,
-    ) + bucket_specs
+    in_specs = (sharded2, sharded2, rep, rep, rep, rep) + bucket_specs
     mapped = shard_map(
         coded_body, mesh=mesh, in_specs=in_specs,
         out_specs=(sharded2, rep),
@@ -2139,7 +2173,7 @@ class ALSTrainer:
         sides = {"user": self._user_side, "item": self._item_side}
 
         def per_side(key):
-            # 0 under sharded placement, which expands in every half
+            # the dense keys are the replicated staging's alone
             return {name: side.get(key, 0) for name, side in sides.items()}
 
         self._plan_solves()
@@ -2357,10 +2391,9 @@ class ALSTrainer:
 
     def data_devices(self) -> int:
         """How many devices hold staged training data: the per-bucket
-        rows, counts and padded blocks (sharded placement: the starts,
-        whose COO shards lie on the same devices).  What a multi-chip
-        bring-up checks: code that put everything on device 0 reports
-        1."""
+        rows, counts and padded blocks, under either placement.  What a
+        multi-chip bring-up checks: code that put everything on device
+        0 reports 1."""
         devices: set = set()
         for side in (self._user_side, self._item_side):
             for bucket in side["buckets"]:
@@ -2641,15 +2674,7 @@ class ALSTrainer:
         v_g = jax.make_array_from_single_device_arrays(
             (n_dev * L,), sh, v_parts
         )
-        ks, groups = self._put_chunk_groups(buckets, local_starts)
-        return {
-            "c_sorted": c_g,
-            "v_sorted": v_g,
-            "shard_len": L,
-            "ks": ks,
-            "buckets": groups,
-            "entries": _gram_entries(buckets),
-        }
+        return self._stage_chunk_groups((c_g, v_g), L, buckets, local_starts)
 
     def _stage_device(self, u, i, v, nu, ni, n_dev):
         """Compact-transfer staging: host counting-sort once, expand the
@@ -2820,7 +2845,6 @@ class ALSTrainer:
             for b in dense
         ])
         expand_s = time.perf_counter() - t0
-        TRAIN_PHASE_SECONDS.labels(phase="als.expand").observe(expand_s)
         blocks = [tuple(map(put_dp, blk)) for blk in padded] \
             + [tuple(map(put_rows_dp, blk)) for blk in resident]
 
@@ -2837,9 +2861,7 @@ class ALSTrainer:
                 for b, (idx, val), m, n in zip(buckets, blocks, counts,
                                                chunks)
             ),
-            "padded_entries": sum(idx.size for idx, _ in padded),
-            "padded_bytes": sum(a.nbytes for blk in padded for a in blk),
-            "expand_s": round(expand_s, 3),
+            **_expansion_report(padded, expand_s),
             "entries": _gram_entries(buckets),
             "dense_rows": sum(int((b.counts > 0).sum()) for b in dense),
             "dense_bytes": sum(a.nbytes for blk in resident for a in blk),
@@ -2850,7 +2872,8 @@ class ALSTrainer:
         """Place one side's COO SHARDED: device ``d`` receives only the
         rating slices of the bucket rows it solves, in shard-local order
         (``_plan_shard_layout``).  The per-bucket starts arrays are the
-        shard-LOCAL offsets, so the device gather indexes its own shard.
+        shard-LOCAL offsets, so the expansion that follows
+        (:meth:`_stage_chunk_groups`) indexes each device's own shard.
         """
         perm, local_starts, L = _plan_shard_layout(layout.buckets, n_dev)
         flat = perm.reshape(-1)
@@ -2863,21 +2886,20 @@ class ALSTrainer:
         # after a replicated-path read); device_put would reject the
         # non-addressable devices
         put_dp = lambda x: shard_put(x, self.mesh, P(DATA_AXIS))  # noqa: E731
-        ks, groups = self._put_chunk_groups(layout.buckets, local_starts)
-        return {
-            "c_sorted": put_dp(c_sh),
-            "v_sorted": put_dp(v_sh),
-            "shard_len": L,
-            "ks": ks,
-            "buckets": groups,
-            "entries": _gram_entries(layout.buckets),
-        }
+        return self._stage_chunk_groups(
+            (put_dp(c_sh), put_dp(v_sh)), L, layout.buckets, local_starts)
 
-    def _put_chunk_groups(self, buckets, local_starts) -> tuple:
-        """``(ks, groups)`` of a sharded side: the chunks of one shape
-        (:func:`_chunk_groups`) stacked as ``[n, B]`` arrays of rows,
-        shard-local starts and counts, each chunk's batch split over
-        the mesh."""
+    def _stage_chunk_groups(self, columns, shard_len: int, buckets,
+                            local_starts) -> dict:
+        """A sharded side from its columns on the mesh (``P('data')``,
+        ``shard_len`` entries a device): the chunks of one shape
+        (:func:`_chunk_groups`) stacked as ``[n, B]`` arrays of rows and
+        counts, each chunk's batch split over the mesh, and every group
+        expanded, once, on the devices that hold the shards, to the
+        ``[n, B, K]`` ids and ratings the halves read
+        (`_expand_side_sharded`).  A group is the replicated path's own
+        ``(rows, idx, val, counts)``; the columns and the shard-local
+        starts are not read again and are dropped here."""
         from ..parallel.mesh import shard_put
 
         runs = _chunk_groups(buckets)
@@ -2886,15 +2908,26 @@ class ALSTrainer:
             return shard_put(np.stack(arrays), self.mesh,
                              P(None, DATA_AXIS))
 
-        return (
-            tuple(buckets[run[0]].k for run in runs),
-            tuple(
-                (put([buckets[j].rows for j in run]),
-                 put([local_starts[j] for j in run]),
-                 put([buckets[j].counts for j in run]))
-                for run in runs
+        ks = tuple(buckets[run[0]].k for run in runs)
+        rows = [put([buckets[j].rows for j in run]) for run in runs]
+        counts = [put([buckets[j].counts for j in run]) for run in runs]
+        starts = [put([local_starts[j] for j in run]) for run in runs]
+        columns = jax.block_until_ready(columns)
+        t0 = time.perf_counter()
+        padded = jax.block_until_ready(_expand_side_sharded(
+            *columns, tuple(zip(starts, counts)), mesh=self.mesh, ks=ks,
+        ))
+        expand_s = time.perf_counter() - t0
+        return {
+            "shard_len": shard_len,
+            "ks": ks,
+            "buckets": tuple(
+                (r, idx, val, m)
+                for r, (idx, val), m in zip(rows, padded, counts)
             ),
-        )
+            **_expansion_report(padded, expand_s),
+            "entries": _gram_entries(buckets),
+        }
 
     @property
     def coo_shard_entries(self) -> Optional[int]:
@@ -2971,7 +3004,6 @@ class ALSTrainer:
                     upd, opp,
                     self._coded_parity(opp_name, opp),
                     jnp.asarray(ok, jnp.float32),
-                    side["c_sorted"], side["v_sorted"],
                     lam_t,
                     jnp.asarray(cfg.alpha, jnp.float32),
                     *flat,
@@ -2979,10 +3011,7 @@ class ALSTrainer:
                 self._parity_state[upd_name] = new_par
                 return new
             return fn(
-                upd, opp, side["c_sorted"], side["v_sorted"],
-                lam_t,
-                jnp.asarray(cfg.alpha, jnp.float32),
-                *flat,
+                upd, opp, lam_t, jnp.asarray(cfg.alpha, jnp.float32), *flat,
             )
         return _half_iteration(
             upd, opp, side["buckets"], lam_t,

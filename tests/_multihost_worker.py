@@ -110,11 +110,11 @@ def _run_sharded_trainer(pid: int, db: str, exch: str, out: str,
         app_id=1, event_names=["rate"],
     )
     assert tr.staging == "sharded-distributed", tr.staging
-    # rating bytes THIS process holds on its devices (the scaling claim)
-    local_nnz = sum(
-        s.data.shape[0]
-        for s in tr._user_side["c_sorted"].addressable_shards
-    )
+    # rating slots THIS process holds on its devices (the scaling
+    # claim): a shard's COO length for each device that holds a shard
+    # of the staged blocks, which were expanded from it
+    _rows, idx, _val, _counts = tr._user_side["buckets"][0]
+    local_nnz = len(idx.addressable_shards) * tr._user_side["shard_len"]
     factors = tr.train()
     np.savez(
         out,

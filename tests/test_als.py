@@ -923,10 +923,12 @@ def test_sweep_train_implicit_mode():
 
 
 def test_sharded_coo_is_actually_sharded():
-    """factor_placement='sharded' must shard the RATING COO too (round-3
-    verdict item 3): each device's shard holds ~1/d of the total rating
-    bytes, not a full replica — the property that lets nnz scale with
-    mesh HBM."""
+    """factor_placement='sharded' must shard the RATINGS too (round-3
+    verdict item 3): each device holds the padded blocks of the bucket
+    rows it solves, ~1/d of the total rating bytes and not a full
+    replica — the property that lets nnz scale with mesh HBM.  The
+    blocks are what is staged: the shard-local columns they were
+    expanded from are gone."""
     from predictionio_tpu.parallel import make_mesh
 
     u, i, v, nu, ni = _toy(n_users=200, n_items=80, density=0.3, seed=9)
@@ -937,18 +939,30 @@ def test_sharded_coo_is_actually_sharded():
     assert tr.staging == "sharded"
     nnz = len(v)
     for side in (tr._user_side, tr._item_side):
-        cs = side["c_sorted"]
-        shard_sizes = [s.data.shape[0] for s in cs.addressable_shards]
-        assert len(shard_sizes) == 8
-        # every device holds the same (padded) shard length L, and the
-        # total padded size stays close to nnz — not 8x nnz
+        assert "c_sorted" not in side and "v_sorted" not in side
+        # the columns' padded shard length: close to nnz / 8, not nnz
         L = side["shard_len"]
-        assert set(shard_sizes) == {L}
         assert 8 * L < 1.5 * nnz, (8 * L, nnz)
         assert L < 0.3 * nnz  # one shard is nowhere near a full replica
-        # shard-local starts stay int32 (the per-shard offset contract)
-        for _rows, starts, _counts in side["buckets"]:
-            assert starts.dtype == np.int32
+        held = np.zeros(8, np.int64)
+        for (rows, idx, val, counts), k in zip(side["buckets"], side["ks"]):
+            n, b = rows.shape
+            assert idx.shape == val.shape == (n, b, k)
+            assert idx.dtype == jnp.int32 and val.dtype == jnp.float32
+            for block in (idx, val):
+                shards = block.addressable_shards
+                assert len(shards) == 8
+                # every device holds its own [n, B/8] rows of the block
+                assert {s.data.shape for s in shards} == {(n, b // 8, k)}
+                assert sorted(s.index[1].start or 0 for s in shards) \
+                    == [d * (b // 8) for d in range(8)]
+            for d, s in enumerate(idx.addressable_shards):
+                held[d] += s.data.size
+        # the devices' blocks add up to the padded entries, not to 8x
+        # them, and no device holds more than its eighth
+        assert held.sum() == side["padded_entries"]
+        assert set(held) == {side["padded_entries"] // 8}
+        assert side["padded_bytes"] == 8 * side["padded_entries"]
 
 
 def test_sharded_coo_slices_land_on_owning_device():

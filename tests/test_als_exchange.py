@@ -1,7 +1,8 @@
 """The sharded ALS half that never holds the opposite table, the bucket
 chunks bounded by the bytes of their Gram, and the chunks of one shape
-run as one loop: on the CPU's virtual devices (`tests/conftest.py`
-forces 8)."""
+run as one loop, and the padded blocks expanded once at staging on the
+devices that hold the shards: on the CPU's virtual devices
+(`tests/conftest.py` forces 8)."""
 
 import importlib.util
 import re
@@ -128,12 +129,17 @@ def test_expand_bucket_gathers_narrow_rows_and_slices_wide_ones(k):
         k >= als._SLICE_MIN_K)
     assert ("slice_sizes = array<i64: 1>" in text) == (
         k < als._SLICE_MIN_K)
+    # what `_gathers_from_a_column` looks for in a half, found here
+    assert _gathers_from_a_column(text)
 
 
 # -- (b) no device holds the whole opposite table ---------------------------
 
 
-def _compiled_half_text(tr, side_name):
+def _lowered_half(tr, side_name):
+    """A sharded half lowered on what `ALSTrainer._half` hands it: the
+    tables, the coded half's parity and mask, lambda, alpha and every
+    group's staged `(rows, idx, val, counts)`."""
     side = tr._user_side if side_name == "user" else tr._item_side
     fn = (tr._sharded_user_half if side_name == "user"
           else tr._sharded_item_half)
@@ -142,11 +148,31 @@ def _compiled_half_text(tr, side_name):
     flat = [a for b in side["buckets"] for a in b]
     args = [upd, opp]
     if tr.coded:
-        args += [tr._coded_parity("opp", opp),
+        args += [tr._parity_fn(opp),
                  jnp.ones(tr.mesh.size, jnp.float32)]
-    args += [side["c_sorted"], side["v_sorted"], jnp.float32(0.1),
-             jnp.float32(1.0), *flat]
-    return fn.lower(*args).compile().as_text()
+    args += [jnp.float32(0.1), jnp.float32(1.0), *flat]
+    return fn.lower(*args)
+
+
+def _compiled_half_text(tr, side_name):
+    return _lowered_half(tr, side_name).compile().as_text()
+
+
+def _tensor_dims(stablehlo_text):
+    """Every array dimension that appears in a lowered module."""
+    return {
+        int(n)
+        for shape in re.findall(r"tensor<((?:\d+x)+)", stablehlo_text)
+        for n in shape.split("x") if n
+    }
+
+
+def _gathers_from_a_column(stablehlo_text):
+    """The gathers of a lowered module whose operand is a
+    one-dimensional array: how `_expand_bucket` reads the ratings'
+    columns, by element or by slice."""
+    return re.findall(
+        r'"stablehlo\.gather"[^\n]*: \(tensor<\d+x[a-z]\w*>, ', stablehlo_text)
 
 
 def _dims(hlo_text):
@@ -189,6 +215,143 @@ def test_the_check_sees_a_whole_table_where_a_half_gathers_one():
     tr = ALSTrainer(data[:3], nu, ni, cfg, mesh=make_mesh(4))
     assert 1004 in _dims(_compiled_half_text(tr, "item"))
     assert tr.opp_transient_bytes["item"] == 1004 * 6 * 4
+
+
+# -- (b') the padded blocks: expanded once, where the shards lie ------------
+
+
+def _staged_shards(monkeypatch):
+    """Record what `_stage_chunk_groups` is handed: the shard-local
+    columns, which the trainer drops, the chunks and their shard-local
+    starts."""
+    seen = []
+    stage = ALSTrainer._stage_chunk_groups
+
+    def spy(self, columns, shard_len, buckets, local_starts):
+        seen.append(([np.asarray(c) for c in columns], shard_len, buckets,
+                     local_starts))
+        return stage(self, columns, shard_len, buckets, local_starts)
+
+    monkeypatch.setattr(ALSTrainer, "_stage_chunk_groups", spy)
+    return seen
+
+
+@pytest.mark.parametrize("shards", [4, 8])
+@pytest.mark.parametrize("implicit", [False, True],
+                         ids=["explicit", "implicit"])
+def test_staged_blocks_are_each_shards_own_expansion(monkeypatch, implicit,
+                                                     shards):
+    """Every staged group's `idx` / `val` are, bit for bit,
+    `_expand_bucket` of the owning device's shard-local columns at the
+    chunk's shard-local starts, read by element under `_SLICE_MIN_K`
+    and by slice from it up; a device's shard of a block is its own
+    `[n, B/d]` rows, and the devices' shards add up to the padded
+    entries, not to d times them."""
+    monkeypatch.setattr(als, "_SLICE_MIN_K", 16)
+    monkeypatch.setattr(als, "MAX_ENTRIES_PER_BUCKET", 256)
+    seen = _staged_shards(monkeypatch)
+    data = _ratings(positive=implicit)
+    u, i, v, nu, ni = data
+    cfg = ALSConfig(rank=6, implicit=implicit, min_bucket_k=4,
+                    factor_placement="sharded")
+    tr = ALSTrainer((u, i, v), nu, ni, cfg, mesh=make_mesh(shards))
+    assert len(seen) == 2
+    widths = set()
+    for side, (columns, L, buckets, local_starts) in zip(
+            (tr._user_side, tr._item_side), seen):
+        assert "c_sorted" not in side and "v_sorted" not in side
+        assert side["shard_len"] == L
+        c_sh, v_sh = (col.reshape(shards, L) for col in columns)
+        runs = _chunk_groups(buckets)
+        assert len(runs) == len(side["buckets"])
+        held = 0
+        for run, (rows, idx, val, counts), k in zip(
+                runs, side["buckets"], side["ks"]):
+            n, b = rows.shape
+            assert (n, k) == (len(run), buckets[run[0]].k)
+            assert idx.shape == val.shape == (n, b, k)
+            assert idx.dtype == jnp.int32 and val.dtype == jnp.float32
+            widths.add(k)
+            per = b // shards
+            for block in (idx, val):
+                assert len(block.addressable_shards) == shards
+                for s in block.addressable_shards:
+                    assert s.data.shape == (n, per, k)
+                    assert s.index[1].stop - (s.index[1].start or 0) == per
+            held += sum(s.data.size for s in idx.addressable_shards)
+            got_i, got_v = np.asarray(idx), np.asarray(val)
+            for c, j in enumerate(run):
+                np.testing.assert_array_equal(
+                    np.asarray(rows)[c], buckets[j].rows)
+                np.testing.assert_array_equal(
+                    np.asarray(counts)[c], buckets[j].counts)
+                for dev in range(shards):
+                    mine = slice(dev * per, (dev + 1) * per)
+                    want_i, want_v = als._expand_bucket(
+                        jnp.asarray(c_sh[dev]), jnp.asarray(v_sh[dev]),
+                        jnp.asarray(local_starts[j][mine]),
+                        jnp.asarray(buckets[j].counts[mine]), k)
+                    assert got_i[c, mine].tobytes() \
+                        == np.asarray(want_i).tobytes()
+                    assert got_v[c, mine].tobytes() \
+                        == np.asarray(want_v).tobytes()
+        assert held == side["padded_entries"] == sum(
+            len(b.rows) * b.k for b in buckets)
+        assert side["padded_bytes"] == 8 * held
+        assert side["expand_s"] >= 0
+        # every rating is in a block, once
+        assert sum(int(np.count_nonzero(np.asarray(b[2])))
+                   for b in side["buckets"]) == len(v)
+    assert min(widths) < als._SLICE_MIN_K <= max(widths)
+
+
+HALF_MODES = {
+    "full": dict(rank=6),
+    "lowrank": dict(rank=32, implicit=True),
+    "block_sweep": dict(rank=6, solver_mode="subspace", subspace_size=2),
+    "coded": dict(rank=6, coded_shards=True),
+}
+
+
+@pytest.mark.parametrize("mode", sorted(HALF_MODES))
+def test_no_sharded_half_reads_the_shards_coo(mode):
+    """The lowered half, whatever its mode, is handed the staged blocks
+    and nothing of the shard's COO: no argument and no operand has the
+    columns' length (a shard's L, the whole d * L), and no gather reads
+    a one-dimensional array.  The staging program is where both are."""
+    nu, ni = 1003, 517
+    cfg = ALSConfig(min_bucket_k=4, factor_placement="sharded",
+                    **HALF_MODES[mode])
+    data = _ratings(nu, ni, density=0.02, seed=3, positive=cfg.implicit)
+    d = 4
+    tr = ALSTrainer(data[:3], nu, ni, cfg, mesh=make_mesh(d))
+    assert tr.coded == (mode == "coded")
+    assert tr.sweeps_blocks == (mode == "block_sweep")
+    assert bool(sum(tr.lowrank_systems["user"].values())) \
+        == (mode == "lowrank")
+    for name, side in (("user", tr._user_side), ("item", tr._item_side)):
+        L = side["shard_len"]
+        lowered = _lowered_half(tr, name)
+        coo = {L, d * L} | {L + k for k in side["ks"]}
+        flat = [a for b in side["buckets"] for a in b]
+        assert len(flat) == 4 * len(side["ks"])
+        avals = lowered.in_avals[0]
+        assert len(avals) == (6 if tr.coded else 4) + len(flat)
+        assert not any(set(a.shape) & coo for a in avals)
+        text = lowered.as_text()
+        dims = _tensor_dims(text)
+        assert dims and not dims & coo, (name, sorted(dims & coo))
+        assert "stablehlo.gather" in text           # the factor rows
+        assert not _gathers_from_a_column(text)
+    # positive control: the staging program holds the columns and reads
+    # them as the half no longer does
+    staging = als._expand_side_sharded.lower(
+        jnp.zeros(d * L, jnp.int32), jnp.zeros(d * L, jnp.float32),
+        tuple((b[0], b[3]) for b in side["buckets"]),
+        mesh=tr.mesh, ks=side["ks"],
+    ).as_text()
+    assert f"tensor<{d * L}xi32>" in staging and f"tensor<{L}xi32>" in staging
+    assert _gathers_from_a_column(staging)
 
 
 # -- (c) chunks bounded by the bytes of their Gram --------------------------
@@ -335,7 +498,7 @@ def test_the_tracing_carries_the_exchange(monkeypatch):
     for which, side in (("user", tr._user_side), ("item", tr._item_side)):
         want = 2 * (d - 1) * r * r * 4 // d          # YtY, all-reduced
         transient = 0
-        for (rows, _, _), k in zip(side["buckets"], side["ks"]):
+        for (rows, *_), k in zip(side["buckets"], side["ks"]):
             n, b = rows.shape[0], rows.shape[1] // d
             # ids + partial rows in, solved rows + their ids back
             want += n * (d - 1) * b * (k * (4 + r * 4) + r * 4 + 4)

@@ -300,18 +300,23 @@ def test_a_dense_bucket_keeps_its_own_form(monkeypatch):
 @pytest.mark.parametrize("placement", ["replicated", "sharded"])
 def test_the_counter_and_the_event_add_up_to_the_staged_rows(
         placement, monkeypatch):
-    from predictionio_tpu.obs import ALS_SOLVE_SYSTEMS_TOTAL, tower
+    from predictionio_tpu.obs import (
+        ALS_SOLVE_SYSTEMS_TOTAL, TRAIN_PHASE_SECONDS, tower,
+    )
 
     events = []
     monkeypatch.setattr(
         tower, "note_event", lambda name, **f: events.append((name, f)))
     u, i, v, nu, ni = _ratings()
     sharded = placement == "sharded"
+    expand = TRAIN_PHASE_SECONDS.labels(phase="als.expand")
+    expansions = expand.snapshot()["count"]
     tr = ALSTrainer(
         (u, i, v), nu, ni,
         ALSConfig(rank=32, implicit=True, factor_placement=placement),
         mesh=make_mesh(4) if sharded else None,
     )
+    assert expand.snapshot()["count"] == expansions + 2     # one a side
     (name, staged), = events
     assert name == "als_staged"
     assert isinstance(tr.solve_path, str)
@@ -328,6 +333,14 @@ def test_the_counter_and_the_event_add_up_to_the_staged_rows(
         assert forms["lowrank"] == rows.get(8, 0) > 0
         assert staged["lowrankWidths"][side_name] == {"8": rows[8]}
     assert staged["solveSystems"] == tr.solve_systems
+    # either placement expands its blocks once, at staging, and says so
+    assert staged["placement"] == placement
+    for side_name, side in (("user", tr._user_side),
+                            ("item", tr._item_side)):
+        entries = staged["paddedEntries"][side_name]
+        assert entries == sum(int(b[1].size) for b in side["buckets"]) > 0
+        assert staged["paddedBytes"][side_name] == 8 * entries
+        assert staged["expandSeconds"][side_name] > 0
     counters = {p: ALS_SOLVE_SYSTEMS_TOTAL.labels(path=p)
                 for p in ("lowrank", tr.solve_path)}
     before = {p: c.value() for p, c in counters.items()}

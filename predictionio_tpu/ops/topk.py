@@ -10,9 +10,11 @@ an unmasked call over a long catalogue scores and selects in blocks of
 the item axis (the blocked path below) and never writes the ``[B, M]``
 score matrix.  A query's exclusions travel as item ids (``exclude``: one
 ``[B, E]`` int32 array padded with -1) and are applied on the device, on
-the blocked path to the gathered candidates; only a filter that is no
-list of ids (``categories``, ``whiteList``) still needs the ``[B, M]``
-additive mask and with it the dense path.
+the blocked path to the gathered candidates (a short list) or to the
+maxima of the listed ids' own blocks before the blocks are chosen (a
+long one: a shopper's whole history); only a filter that is no list of
+ids (``categories``, ``whiteList``) still needs the ``[B, M]`` additive
+mask and with it the dense path.
 """
 
 from __future__ import annotations
@@ -22,6 +24,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -30,7 +33,8 @@ from .solve import pallas_interpret
 
 __all__ = ["topk_scores", "batch_topk_scores", "batch_topk_scores_t",
            "ItemTables", "pack_rows", "patch_packed_rows", "rows_per_line",
-           "topk_path", "EXCLUDE_LADDER", "exclude_width",
+           "topk_path", "EXCLUDE_LADDER", "exclude_width", "listed_order",
+           "exclude_layout",
            "cosine_topk", "rerank_topk", "pow2_ceil"]
 
 TOPK_PATH = get_registry().counter(
@@ -138,11 +142,21 @@ _PACK_ITEMS = 1 << 18            # items pack_rows re-lays at a time
 _RESCORE_BYTES_IDS = 128 << 20
 
 # Widths E of the ``[B, E]`` array of excluded ids, so that the compiled
-# programs stay (pow2 B) x (pow2 k) x (these).  One rung: every width is
-# one more program a rung of the warm-up ladder (with 4 and 16 a server
-# was 3 s later ready than with 16 alone; v5e).  A batch whose longest
-# list is longer takes a ``[B, M]`` mask.
-EXCLUDE_LADDER = (32,)
+# programs stay (pow2 B) x (pow2 k) x (these).  Every width is one more
+# program a rung of the warm-up ladder (with 4 and 16 a server was 3 s
+# later ready than with 16 alone; v5e), so a server warms the rungs its
+# engine names (``_common.warm_batched_topk(exclude_widths=)``): the
+# first alone for a blackList, all of them where a user's whole history
+# is excluded.  A batch pays for its longest row's rung; one whose
+# longest list is longer than the last takes a ``[B, M]`` mask.
+EXCLUDE_LADDER = (32, 128, 512, 2048, 4224)
+
+# Up to the first width `k + E` blocks are chosen and every candidate is
+# compared with every id (`_blocked_topk`); past it the listed ids' own
+# blocks are re-reduced and k blocks chosen (`_blocked_topk_listed`)
+_PAIRWISE_EXCLUDE = EXCLUDE_LADDER[0]
+# ... whose blocks' items are the bits of one uint32 a listed id
+_BLOCK_ITEMS_LISTED = (32, 16, 8)
 
 
 def exclude_width(n_excluded: int) -> int:
@@ -248,12 +262,33 @@ def block_items(batch: int, n_items: int, rank: int, k: int,
     rank whose rows pack into whole lanes."""
     if not rows_per_line(rank):
         return 0
+    if n_exclude > _PAIRWISE_EXCLUDE:
+        return _block_items_listed(batch, n_items, rank, k, itemsize,
+                                   n_exclude)
     chosen = k + n_exclude
     budget = _RESCORE_BYTES_IDS if n_exclude else _RESCORE_BYTES
     for blk in _BLOCK_ITEMS:
         if batch * chosen * blk * rank * itemsize <= budget:
             return blk if n_items >= _BLOCKS_PER_K * chosen * blk else 0
     return 0
+
+
+def _block_items_listed(batch: int, n_items: int, rank: int, k: int,
+                        itemsize: int, n_exclude: int) -> int:
+    """`block_items` for a list too long to compare pairwise: k blocks
+    are chosen, and what is gathered besides is every listed id's own
+    block.  The largest block within `_RESCORE_BYTES_IDS`; where not even
+    the smallest is, the smallest as long as its gather stays under a
+    quarter of the table the scan reads anyway (the dense form writes and
+    reads ``batch`` times a twenty-eighth of it and sorts whole rows)."""
+    gathered = batch * (k + n_exclude) * rank * itemsize
+    blk = next((b for b in _BLOCK_ITEMS_LISTED
+                if gathered * b <= _RESCORE_BYTES_IDS),
+               _BLOCK_ITEMS_LISTED[-1])
+    if gathered * blk > max(_RESCORE_BYTES_IDS,
+                            n_items * rank * itemsize // 4):
+        return 0
+    return blk if n_items >= _BLOCKS_PER_K * k * blk else 0
 
 
 def _blocked_items(query_vecs, table_t, k: int, mask, exclude) -> int:
@@ -413,6 +448,62 @@ def _excluded(ids: jax.Array, exclude: jax.Array) -> jax.Array:
     return (ids[:, :, None] == exclude[:, None, :]).any(axis=-1)
 
 
+def _padded_queries(query_vecs, exclude):
+    """Whole sublanes of queries: the kernel wants them, and XLA then
+    lowers the top_k over the maxima to a `TopK` custom call of its own
+    for a one-row batch too (it wraps that of a [1, n] operand in a
+    fusion, whose device event carries another name)."""
+    n_queries = query_vecs.shape[0]
+    batch = 8 * pl.cdiv(n_queries, 8)
+    query_vecs = jnp.pad(query_vecs, ((0, batch - n_queries), (0, 0)))
+    if exclude is not None:
+        exclude = jnp.pad(exclude, ((0, batch - n_queries), (0, 0)),
+                          constant_values=-1)
+    return query_vecs, exclude
+
+
+def _scan_maxima(query_vecs, tables: ItemTables, blk: int):
+    """``[B, n_blocks]`` block maxima of the unfiltered scores."""
+    # the table the scan streams: transposed, or the rows themselves
+    row_major = tables.t is None
+    scanned = tables.packed if row_major else tables.t
+    with jax.named_scope("topk.scan"):
+        scan = block_maxima if _mxu_operands() else block_maxima_jnp
+        return scan(query_vecs, scanned, blk, row_major=row_major)
+
+
+def _block_item_ids(blocks, blk: int):
+    """``[B, P * blk]`` item ids of the ``[B, P]`` blocks, a block's
+    `blk` items side by side."""
+    first = (blocks // _LANES) * (blk * _LANES) + blocks % _LANES
+    return (first[:, :, None]
+            + _LANES * jnp.arange(blk, dtype=jnp.int32)[None, None, :]
+            ).reshape(blocks.shape[0], blocks.shape[1] * blk)
+
+
+def _rescore(query_vecs, tables: ItemTables, ids):
+    """``[B, P]`` scores of the items `ids` at the scan's precision, from
+    their gathered rows; -inf for an id past the catalogue."""
+    rank, n_items = tables.shape
+    inside = ids < n_items
+    p = rows_per_line(rank)
+    safe = jnp.where(inside, ids, 0)
+    lines = tables.packed[safe // p].astype(jnp.float32)  # [B, P, p*R]
+    q = jnp.tile(query_vecs.astype(jnp.float32), (1, p))  # [B, p*R]
+    if _mxu_operands():
+        # what the MXU's default precision does to float32 operands,
+        # written as an op XLA may not drop; the products of two
+        # such values are exact in float32, like the MXU's
+        lines, q = (jax.lax.reduce_precision(x, 8, 7)
+                    for x in (lines, q))
+    prod = lines * q[:, None, :]
+    if p > 1:   # the lanes of a line that are this candidate's row
+        lane_row = jnp.arange(p * rank, dtype=jnp.int32) // rank
+        prod = jnp.where(lane_row == (safe % p)[:, :, None], prod, 0.0)
+    scores = prod.sum(axis=-1)
+    return jnp.where(inside, scores, -jnp.inf)
+
+
 def _blocked_topk(query_vecs, tables: ItemTables, k: int, blk: int,
                   exclude: jax.Array | None = None):
     """Exact top-k of the allowed items.  A row with e excluded ids finds
@@ -422,54 +513,166 @@ def _blocked_topk(query_vecs, tables: ItemTables, k: int, blk: int,
     are at most e of those.  So the scan is the unfiltered one, ``k + E``
     blocks are chosen, and the exclusions are applied to the gathered
     candidates before the select."""
-    rank, n_items = tables.shape
     n_queries = query_vecs.shape[0]
     n_blocks = k if exclude is None else k + exclude.shape[1]
-    # the table the scan streams: transposed, or the rows themselves
-    row_major = tables.t is None
-    scanned = tables.packed if row_major else tables.t
-    # whole sublanes of queries: the kernel wants them, and XLA then
-    # lowers the top_k below to a `TopK` custom call of its own for a
-    # one-row batch too (it wraps that of a [1, n] operand in a fusion,
-    # whose device event carries another name)
-    batch = 8 * pl.cdiv(n_queries, 8)
-    query_vecs = jnp.pad(query_vecs, ((0, batch - n_queries), (0, 0)))
-    if exclude is not None:
-        exclude = jnp.pad(exclude, ((0, batch - n_queries), (0, 0)),
-                          constant_values=-1)
-    with jax.named_scope("topk.scan"):
-        scan = block_maxima if _mxu_operands() else block_maxima_jnp
-        maxima = scan(query_vecs, scanned, blk, row_major=row_major)
+    query_vecs, exclude = _padded_queries(query_vecs, exclude)
+    maxima = _scan_maxima(query_vecs, tables, blk)
     with jax.named_scope("topk.blocks"):
         # ties go to the lower block index (lax.top_k is stable)
         _, chosen = jax.lax.top_k(maxima, n_blocks)
     with jax.named_scope("topk.rescore"):
-        first = (chosen // _LANES) * (blk * _LANES) + chosen % _LANES
-        ids = (first[:, :, None]
-               + _LANES * jnp.arange(blk, dtype=jnp.int32)[None, None, :]
-               ).reshape(batch, n_blocks * blk)
-        inside = ids < n_items
-        p = rows_per_line(rank)
-        safe = jnp.where(inside, ids, 0)
-        lines = tables.packed[safe // p].astype(jnp.float32)  # [B, P, p*R]
-        q = jnp.tile(query_vecs.astype(jnp.float32), (1, p))  # [B, p*R]
-        if _mxu_operands():
-            # what the MXU's default precision does to float32 operands,
-            # written as an op XLA may not drop; the products of two
-            # such values are exact in float32, like the MXU's
-            lines, q = (jax.lax.reduce_precision(x, 8, 7)
-                        for x in (lines, q))
-        prod = lines * q[:, None, :]
-        if p > 1:   # the lanes of a line that are this candidate's row
-            lane_row = jnp.arange(p * rank, dtype=jnp.int32) // rank
-            prod = jnp.where(lane_row == (safe % p)[:, :, None], prod, 0.0)
-        scores = prod.sum(axis=-1)
-        scores = jnp.where(inside, scores, -jnp.inf)
+        ids = _block_item_ids(chosen, blk)
+        scores = _rescore(query_vecs, tables, ids)
     if exclude is not None:
         with jax.named_scope("topk.exclude"):
             scores = jnp.where(_excluded(ids, exclude), -jnp.inf, scores)
     with jax.named_scope("topk.select"):
         vals, ixs = _select_k(scores, ids, k)
+        return vals[:n_queries], ixs[:n_queries]
+
+
+_LISTED_LANE = 1 << 24    # a listed id's place: lane * this + id // 128
+
+
+def listed_order(ids) -> np.ndarray:
+    """The distinct ids of a list (numpy, on the host) in the order the
+    listed form reads them: by lane (``id % 128``), then by ``id // 128``.
+    In that order the ids of one block lie together whatever the block's
+    size, so the device sorts nothing (:func:`exclude_layout`)."""
+    ids = np.asarray(ids, np.int64)
+    key = np.unique((ids % _LANES) * _LISTED_LANE + ids // _LANES)
+    return ((key % _LISTED_LANE) * _LANES + key // _LISTED_LANE).astype(
+        np.int32)
+
+
+def exclude_layout(ids, width: int):
+    """A row's excluded ids as the form that reads an ids array of
+    `width` wants them: as they come up to the first rung (every
+    candidate is compared with every id), in :func:`listed_order` past
+    it."""
+    return listed_order(ids) if width > _PAIRWISE_EXCLUDE else ids
+
+
+def _listed_blocks(exclude, blk: int, n_blocks: int):
+    """The listed ids by block.  ``([B, E] block, [B, E] uint32 bits,
+    [B] ordered)``: each listed id's block (`n_blocks`, a block past the
+    last, for the -1 padding); one bit an item of that block, which of
+    its items the row lists (the OR over the run of the list that the
+    block is: at most `blk` entries, side by side in
+    :func:`listed_order`); and whether the row IS in that order, distinct,
+    its padding last."""
+    listed = exclude >= 0
+    e = jnp.where(listed, exclude, 0)
+    q, lane = e // _LANES, e % _LANES
+    key = jnp.where(listed, lane * _LISTED_LANE + q,
+                    jnp.iinfo(jnp.int32).max)
+    ordered = ((key[:, 1:] > key[:, :-1]) | ~listed[:, 1:]).all(axis=1)
+    block = jnp.where(listed, (q // blk) * _LANES + lane, n_blocks)
+    bit = jnp.where(listed, jnp.uint32(1) << (q % blk).astype(jnp.uint32),
+                    jnp.uint32(0))
+    bits = bit
+    for d in range(1, blk):
+        same = block[:, d:] == block[:, :-d]
+        none = jnp.zeros((block.shape[0], d), jnp.uint32)
+        bits = (bits
+                | jnp.concatenate(
+                    [jnp.where(same, bit[:, d:], jnp.uint32(0)), none], 1)
+                | jnp.concatenate(
+                    [none, jnp.where(same, bit[:, :-d], jnp.uint32(0))], 1))
+    return block, bits, ordered
+
+
+_SCATTER_UPDATES = 1 << 14   # a scatter of more compiles for 8-10 s (v5e)
+
+
+def _put_maxima(maxima, block, values):
+    """``[B, n_blocks / 128, 128]``: `maxima` by lines of 128 blocks, with
+    ``[row, block[row, j]] = values[row, j]`` (a block past the last is
+    dropped; the entries of one block carry one value).  Scattered into
+    the FLAT array and never laid out ``[B, n_blocks]`` again: the TPU
+    compiler turns a scatter into a wide 2-D operand into one over its
+    flat form and back, row by row (14.7 ms for ``[16, 1.17 M]``; v5e),
+    where flat and by lines are the same bytes.  In runs of at most
+    `_SCATTER_UPDATES` updates, one scatter body in a loop: it takes
+    8-10 s to compile a larger one."""
+    batch, n_blocks = maxima.shape
+    width = block.shape[1]
+    rows = jnp.arange(batch, dtype=jnp.int32)[:, None]
+    at = jnp.where(block < n_blocks, rows * n_blocks + block,
+                   batch * n_blocks)
+    chunk = max(c for c in range(1, width + 1)
+                if width % c == 0 and batch * c <= _SCATTER_UPDATES)
+
+    def put(flat, part):
+        return flat.at[part[0]].set(part[1], mode="drop"), None
+
+    by_chunk = tuple(
+        x.reshape(batch, width // chunk, chunk).swapaxes(0, 1).reshape(
+            width // chunk, batch * chunk) for x in (at, values))
+    flat = jax.lax.scan(put, maxima.reshape(-1), by_chunk)[0]
+    return flat.reshape(batch, n_blocks // _LANES, _LANES)
+
+
+def _best_blocks(lines, k: int):
+    """``[B, k]``: the k blocks with the largest maxima, ties to the lower
+    block, from the maxima by lines of 128 (`_put_maxima`): ONE `TopK`,
+    of the k best lines by their own maxima (a block of the k best lies
+    in one of them), then the k best of their ``k * 128`` blocks."""
+    batch, n_lines, _ = lines.shape
+    _, best = jax.lax.top_k(lines.max(axis=-1), min(k, n_lines))
+    maxima = jnp.take_along_axis(lines, best[:, :, None], axis=1)
+    blocks = best[:, :, None] * _LANES + jnp.arange(_LANES, dtype=jnp.int32)
+    return _select_k(maxima.reshape(batch, -1), blocks.reshape(batch, -1),
+                     k)[1]
+
+
+def _listed_items(bits, blk: int):
+    """``[B, P * blk]`` bool from ``[B, P]`` bits: item by item of each
+    block, whether its bit is set."""
+    item = jnp.arange(blk, dtype=jnp.uint32)[None, None, :]
+    return ((bits[:, :, None] >> item) & 1).astype(bool).reshape(
+        bits.shape[0], bits.shape[1] * blk)
+
+
+def _blocked_topk_listed(query_vecs, tables: ItemTables, k: int, blk: int,
+                         exclude: jax.Array):
+    """Exact top-k of the allowed items for lists of any length: the
+    scan is the unfiltered one; then every listed id's own block is
+    gathered and reduced again without its listed items, and that maximum
+    takes the scan's place; so every block's maximum is that of its
+    ALLOWED items, k blocks are chosen as without a filter, and the
+    chosen blocks' listed items are dropped before the select.  No
+    candidate is compared with every id: the list comes ordered by block
+    (:func:`listed_order`), a block's listed items are bits
+    (`_listed_blocks`), and a chosen block finds its bits by its number.
+    A row whose list is not in that order answers NaN scores (which the
+    templates' decode drops: nothing), never a listed item."""
+    n_queries = query_vecs.shape[0]
+    query_vecs, exclude = _padded_queries(query_vecs, exclude)
+    maxima = _scan_maxima(query_vecs, tables, blk)
+    with jax.named_scope("topk.exclude_bits"):
+        block, bits, ordered = _listed_blocks(exclude, blk, maxima.shape[1])
+    with jax.named_scope("topk.exclude_blocks"):
+        # the padding's block is past the last: its rows are item 0's,
+        # its maximum is dropped by the scatter
+        scores = _rescore(query_vecs, tables, _block_item_ids(
+            jnp.minimum(block, maxima.shape[1] - 1), blk))
+        allowed = jnp.where(_listed_items(bits, blk), -jnp.inf, scores)
+        lines = _put_maxima(
+            maxima, block, allowed.reshape(*block.shape, blk).max(axis=-1))
+    with jax.named_scope("topk.blocks"):
+        chosen = _best_blocks(lines, k)
+    with jax.named_scope("topk.rescore"):
+        ids = _block_item_ids(chosen, blk)
+        scores = _rescore(query_vecs, tables, ids)
+    with jax.named_scope("topk.exclude"):
+        # every id of a listed block carries the block's whole bits
+        mine = jnp.where(chosen[:, :, None] == block[:, None, :],
+                         bits[:, None, :], jnp.uint32(0)).max(axis=-1)
+        scores = jnp.where(_listed_items(mine, blk), -jnp.inf, scores)
+    with jax.named_scope("topk.select"):
+        vals, ixs = _select_k(scores, ids, k)
+        vals = jnp.where(ordered[:, None], vals, jnp.nan)
         return vals[:n_queries], ixs[:n_queries]
 
 
@@ -491,6 +694,9 @@ def batch_topk_scores_t(query_vecs: jax.Array,
     path: the table is read once, each block's best score is kept, and
     the ``k + E`` chosen blocks' items are scored again at the scan's
     precision and the excluded ones dropped; exact (:func:`_blocked_topk`).
+    A list wider than 32 ids has its own blocks reduced again instead and
+    k blocks chosen (:func:`_blocked_topk_listed`), exact too; on that
+    path each row's ids come in :func:`listed_order`.
     With a mask (additive, ``[B, M]``), a short catalogue or a large k it
     is the dense ``query_vecs @ table_t`` + ``lax.top_k``, the excluded
     ids scattered into the scores.  Serving keeps the transposed device
@@ -498,6 +704,8 @@ def batch_topk_scores_t(query_vecs: jax.Array,
     pays the transpose once per model advance."""
     blk = _blocked_items(query_vecs, table_t, k, mask, exclude)
     if blk:   # from shapes alone  # piolint: disable=PIO104
+        if exclude is not None and exclude.shape[1] > _PAIRWISE_EXCLUDE:
+            return _blocked_topk_listed(query_vecs, table_t, k, blk, exclude)
         return _blocked_topk(query_vecs, table_t, k, blk, exclude)
     if isinstance(table_t, ItemTables):
         table_t = table_t.packed.T if table_t.t is None else table_t.t

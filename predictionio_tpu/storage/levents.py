@@ -222,6 +222,38 @@ class EventStore(abc.ABC):
             )
         return frame
 
+    # -- target ids by entity (the read a `predict` makes) -----------------
+    def find_target_ids(
+        self,
+        app_id: int,
+        entity_type: str,
+        entity_ids: Sequence[str],
+        event_names: Optional[Sequence[str]] = None,
+        channel_id: int = 0,
+    ) -> list[list[str]]:
+        """For each of `entity_ids`, the target entity ids of its events
+        (those named in `event_names`; every event without), an id once
+        an event, in no promised order: :meth:`find` by entity with the
+        targets alone, for the serving path that reads a batch's users'
+        histories inside the turn (reference ``LEventStore.findByEntity``
+        in ``ECommAlgorithm.predict``).  An event acknowledged by
+        :meth:`insert` before the call is in the answer.
+
+        Generic implementation on :meth:`find`, one scan an entity;
+        backends that index by entity override it and build no
+        :class:`Event` (memory: its entity index; sqlite: one SELECT of
+        two columns over the ``entity`` index)."""
+        names = None if event_names is None else list(event_names)
+        return [
+            [e.target_entity_id
+             for e in self.find(
+                 app_id=app_id, channel_id=channel_id,
+                 entity_type=entity_type, entity_id=entity_id,
+                 event_names=names)
+             if e.target_entity_id]
+            for entity_id in entity_ids
+        ]
+
     # -- aggregation (built on find, like the reference) ------------------
     def aggregate_properties_of(
         self,
@@ -333,17 +365,22 @@ def _match(
 
 
 class MemoryEventStore(EventStore):
-    """Hermetic in-memory backend (list per (app, channel), lock-guarded)."""
+    """Hermetic in-memory backend (dict per (app, channel) by event id,
+    and beside it the same events by entity, as the sqlite backend's
+    ``entity`` index has them; lock-guarded)."""
 
     def __init__(self, config=None):
         self._lock = threading.RLock()
         self._tables: dict[tuple[int, int], dict[str, Event]] = {}
+        # (app, channel) -> (entity_type, entity_id) -> event id -> Event
+        self._by_entity: dict[tuple[int, int], dict[tuple, dict]] = {}
 
     def _table(self, app_id: int, channel_id: int) -> dict[str, Event]:
         key = (app_id, channel_id)
         with self._lock:
             if key not in self._tables:
                 self._tables[key] = {}
+                self._by_entity[key] = {}
             return self._tables[key]
 
     def init_channel(self, app_id: int, channel_id: int = 0) -> bool:
@@ -352,6 +389,7 @@ class MemoryEventStore(EventStore):
 
     def remove_channel(self, app_id: int, channel_id: int = 0) -> bool:
         with self._lock:
+            self._by_entity.pop((app_id, channel_id), None)
             return self._tables.pop((app_id, channel_id), None) is not None
 
     def insert(self, event: Event, app_id: int, channel_id: int = 0,
@@ -359,9 +397,26 @@ class MemoryEventStore(EventStore):
         if validate:
             validate_event(event)
         eid = event.event_id or new_event_id()
+        if event.event_id != eid:
+            event = event.with_id(eid)
         with self._lock:
-            self._table(app_id, channel_id)[eid] = event.with_id(eid)
+            table = self._table(app_id, channel_id)
+            old = table.get(eid)
+            if old is not None:     # an id written again moves entity
+                self._unindex(app_id, channel_id, old)
+            table[eid] = event
+            self._by_entity[app_id, channel_id].setdefault(
+                (event.entity_type, event.entity_id), {})[eid] = event
         return eid
+
+    def _unindex(self, app_id: int, channel_id: int, event: Event) -> None:
+        entities = self._by_entity[app_id, channel_id]
+        key = (event.entity_type, event.entity_id)
+        mine = entities.get(key)
+        if mine is not None:
+            mine.pop(event.event_id, None)
+            if not mine:
+                del entities[key]
 
     def get(self, event_id: str, app_id: int, channel_id: int = 0) -> Optional[Event]:
         with self._lock:
@@ -369,7 +424,24 @@ class MemoryEventStore(EventStore):
 
     def delete(self, event_id: str, app_id: int, channel_id: int = 0) -> bool:
         with self._lock:
-            return self._table(app_id, channel_id).pop(event_id, None) is not None
+            old = self._table(app_id, channel_id).pop(event_id, None)
+            if old is not None:
+                self._unindex(app_id, channel_id, old)
+            return old is not None
+
+    def find_target_ids(self, app_id: int, entity_type: str, entity_ids,
+                        event_names=None, channel_id: int = 0):
+        names = None if event_names is None else set(event_names)
+        with self._lock:
+            self._table(app_id, channel_id)
+            entities = self._by_entity[app_id, channel_id]
+            return [
+                [e.target_entity_id
+                 for e in entities.get((entity_type, entity_id), {}).values()
+                 if e.target_entity_id
+                 and (names is None or e.event in names)]
+                for entity_id in entity_ids
+            ]
 
     def find(
         self,
@@ -386,7 +458,12 @@ class MemoryEventStore(EventStore):
         reversed: bool = False,
     ) -> Iterator[Event]:
         with self._lock:
-            evs = list(self._table(app_id, channel_id).values())
+            table = self._table(app_id, channel_id)
+            if entity_type is not None and entity_id is not None:
+                # one entity's events: by the index, not a pass over all
+                table = self._by_entity[app_id, channel_id].get(
+                    (entity_type, entity_id), {})
+            evs = list(table.values())
         evs.sort(key=lambda e: (e.event_time, e.event_id or ""), reverse=reversed)
         it = (
             e

@@ -712,6 +712,29 @@ class SQLiteEventStore(EventStore):
         cur = self._conn.execute(sql, params)
         return (self._event_from_row(r) for r in iter(cur.fetchone, None))
 
+    def find_target_ids(self, app_id: int, entity_type: str, entity_ids,
+                        event_names=None, channel_id: int = 0):
+        """:meth:`EventStore.find_target_ids` as one SELECT of two
+        columns over the ``entity`` index: no :class:`Event`, no JSON."""
+        check_deadline("event store scan")
+        entity_ids = list(entity_ids)
+        out: dict = {entity_id: [] for entity_id in entity_ids}
+        if not out:
+            return []
+        t = self._ensure_table(app_id, channel_id)
+        sql = (f"SELECT entity_id, target_entity_id FROM {t} "
+               f"WHERE entity_type = ? AND entity_id IN "
+               f"({','.join('?' * len(out))}) "
+               f"AND target_entity_id IS NOT NULL")
+        params = [entity_type, *out]
+        if event_names is not None:
+            sql += f" AND event IN ({','.join('?' * len(event_names))})"
+            params.extend(event_names)
+        for entity_id, target in self._conn.execute(sql, params):
+            if target:
+                out[entity_id].append(target)
+        return [out[entity_id] for entity_id in entity_ids]
+
     # -- fused training read (scan + encode in C) -------------------------
     def find_ratings(
         self,

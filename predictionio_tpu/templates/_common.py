@@ -23,6 +23,18 @@ FILTER_ROWS = _registry.counter(
     "the host: categories, a whiteList, or a list past the ids' width)",
     labels=("filter",),
 )
+FILTER_EXCLUDE_WIDTH = _registry.counter(
+    "pio_filter_exclude_width_total",
+    "Batches dispatched with excluded ids, by the width of their ids "
+    "array: the rung of ops.topk.EXCLUDE_LADDER that the batch's longest "
+    "list took",
+    labels=("width",),
+)
+FILTER_EXCLUDED_IDS = _registry.counter(
+    "pio_filter_excluded_ids_total",
+    "Excluded item ids dispatched to the device (the ids arrays' real "
+    "entries, their -1 padding left out)",
+).child()
 FILTER_BUILD_SECONDS = _registry.histogram(
     "pio_filter_build_seconds",
     "Host time of one batch's `pio.filter.build` span: its queries' "
@@ -300,7 +312,8 @@ def filter_bias_mask(
 class RowFilter(NamedTuple):
     """One query's filters, as the templates read them off the query:
     the wire's lists of item ids, and `exclude_ix`, item indices the
-    engine itself takes out (a similar-items query's own seeds)."""
+    engine itself takes out (a similar-items query's own seeds; a
+    shopper's seen items, as one int32 array of distinct indices)."""
 
     categories: Sequence[str] = ()
     whitelist: Sequence[str] = ()
@@ -318,6 +331,11 @@ class BatchFilter(NamedTuple):
     exclude: Optional[np.ndarray] = None
     mask: Optional[np.ndarray] = None
 
+    @property
+    def width(self) -> int:
+        """The ids array's width (its rung of the ladder); 0 without."""
+        return 0 if self.exclude is None else self.exclude.shape[1]
+
     def scorer_kwargs(self) -> dict:
         """The scorer's keyword arguments: `mask` as its callers have
         always passed it, `exclude` only where there are ids (a stand-in
@@ -331,13 +349,14 @@ def batch_filter(items, item_props: Optional[dict],
                  rows: Sequence[Optional[RowFilter]]) -> BatchFilter:
     """Filters as data.  Each row's excluded items (its `exclude_ix` and
     the `blacklist` ids the model knows, a hash lookup an id) go into one
-    ``[B, E]`` array for the device, E from ``ops.topk.EXCLUDE_LADDER``;
-    no array of the catalogue's length is built.  Only a batch that holds
+    ``[B, E]`` array for the device, E the rung of
+    ``ops.topk.EXCLUDE_LADDER`` that holds the batch's longest list; no
+    array of the catalogue's length is built.  Only a batch that holds
     a row with `categories` or a `whitelist`, or more excluded ids than
     the ladder's last rung, takes the ``[B, M]`` mask
     (:func:`filter_bias_mask` a row).  A row that is None (a query that
     will not be answered) filters nothing."""
-    from ..ops.topk import exclude_width
+    from ..ops.topk import exclude_layout, exclude_width
 
     t0 = time.perf_counter()
     with annotate("pio.filter.build"):
@@ -349,9 +368,16 @@ def batch_filter(items, item_props: Optional[dict],
             if row.categories or row.whitelist:
                 by_ids = False
                 break
-            found = (items.get(item_id) for item_id in row.blacklist or ())
-            lists.append(tuple(dict.fromkeys(
-                [*row.exclude_ix, *(ix for ix in found if ix >= 0)])))
+            found = [ix for ix in map(items.get, row.blacklist or ())
+                     if ix >= 0]
+            if isinstance(row.exclude_ix, np.ndarray):
+                # distinct already; an id the blackList repeats only
+                # lengthens the list
+                lists.append(np.concatenate([row.exclude_ix, found])
+                             if found else row.exclude_ix)
+            else:
+                lists.append(tuple(dict.fromkeys(
+                    [*row.exclude_ix, *found])))
         longest = max(map(len, lists), default=0)
         width = exclude_width(longest) if by_ids else 0
         if by_ids and not longest:
@@ -359,7 +385,9 @@ def batch_filter(items, item_props: Optional[dict],
         elif width:
             exclude = np.full((len(rows), width), -1, np.int32)
             for bi, ex in enumerate(lists):
-                exclude[bi, :len(ex)] = ex
+                if len(ex):     # in the order this width's form reads
+                    ex = exclude_layout(ex, width)
+                    exclude[bi, :len(ex)] = ex
             out = BatchFilter("ids", exclude=exclude)
         else:
             mask = np.zeros((len(rows), len(items)), np.float32)
@@ -375,6 +403,9 @@ def batch_filter(items, item_props: Optional[dict],
             out = BatchFilter("mask", mask=mask)
     FILTER_BUILD_SECONDS.observe(time.perf_counter() - t0)
     FILTER_ROWS.labels(filter=out.kind).inc(len(rows))
+    if out.kind == "ids":
+        FILTER_EXCLUDE_WIDTH.labels(width=str(width)).inc()
+        FILTER_EXCLUDED_IDS.inc(int((out.exclude >= 0).sum()))
     return out
 
 
@@ -416,7 +447,8 @@ def warm_shapes(max_batch: int, n: int, lone_nums=()) -> list:
 def warm_batched_topk(table, rank: int, n: int,
                       unmasked_too: bool = False,
                       max_batch: int = 64,
-                      table_t=None, lone_nums=()) -> None:
+                      table_t=None, lone_nums=(),
+                      exclude_widths=None) -> None:
     """Pre-compile the batched top-k scorer at the shapes serving
     dispatches (:func:`warm_shapes`, which reads `max_batch` and
     `lone_nums`: server/microbatch.py pads batches to powers of two;
@@ -424,15 +456,18 @@ def warm_batched_topk(table, rank: int, n: int,
 
     With `table_t` (what the caller's batch path hands
     ``ops.topk.batch_topk_scores_t``: its ``device_item_tables``) the
-    filtered rungs carry excluded ids, at every width of
-    ``ops.topk.EXCLUDE_LADDER``; each rung compiles the path, blocked or
-    dense, that its shapes will take under traffic.  The ``[B, M]``
+    filtered rungs carry excluded ids, at each of `exclude_widths`: the
+    rungs of ``ops.topk.EXCLUDE_LADDER`` that the engine's queries can
+    take, the first alone unless it names more (a blackList; an engine
+    that excludes a user's whole history names them all); each rung
+    compiles the path, blocked or dense, that its shapes will take under
+    traffic.  The ``[B, M]``
     masked form of that scorer (`categories`, a `whiteList`) is not
     warmed: its rungs each shipped a ``[B, M]`` array of zeros, 2.4 GB
     at 64 rows over 9.4 M items, and set the server's peak memory.  Without
     `table_t` it is the classic ``[M, R]`` scorer under a ``[B, M]``
-    mask, for the templates whose every batch is still masked and whose
-    ``predict`` is a scorer of its own (itemsimilarity, ecommerce): with
+    mask, for the template whose every batch is still masked and whose
+    ``predict`` is a scorer of its own (itemsimilarity): with
     no batcher nothing dispatches it, and nothing is compiled."""
     from ..ops.topk import (
         EXCLUDE_LADDER, batch_topk_scores, batch_topk_scores_t,
@@ -440,6 +475,8 @@ def warm_batched_topk(table, rank: int, n: int,
 
     if table_t is None and max_batch <= 0:
         return
+    if exclude_widths is None:
+        exclude_widths = EXCLUDE_LADDER[:1]
     for b, k in warm_shapes(max_batch, n, lone_nums):
         vecs = np.zeros((b, rank), np.float32)
         if table_t is None:
@@ -450,6 +487,6 @@ def warm_batched_topk(table, rank: int, n: int,
         # part of the compiled call's key
         filters = [BatchFilter("none")] if unmasked_too else []
         filters += [BatchFilter("ids", np.full((b, width), -1, np.int32))
-                    for width in EXCLUDE_LADDER]
+                    for width in exclude_widths]
         for flt in filters:
             batch_topk_scores_t(vecs, table_t, k, **flt.scorer_kwargs())

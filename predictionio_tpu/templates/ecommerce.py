@@ -3,24 +3,43 @@
 Capability parity with `/root/reference/examples/scala-parallel-
 ecommercerecommendation/` (``ECommAlgorithm``): implicit ALS over view
 (+ optional buy/rate) events, with **predict-time event-store reads** —
-the serving path consults the live event store for
+the serving path consults the live event store, inside the turn, for
 
-* the user's already-seen items (``unseen_only`` + ``seen_events`` params,
-  reference `ALSAlgorithm.scala:160-192`), and
+* the batch's users' already-seen items (``unseen_only`` +
+  ``seen_events`` params, reference `ALSAlgorithm.scala:160-192`): ONE
+  read a batch through ``EventStore.find_target_ids``, nothing cached
+  from one request to the next, so a ``buy`` acknowledged before a query
+  is received is out of that query's answer, and
 * the latest ``$set`` on the ``constraint``/``unavailableItems`` entity
-  (reference `:194-215`),
+  (reference `:194-215`), once a batch.
 
-then merges both with the query blacklist before the top-k matmul.  This is
-the template that demonstrates low-latency `LEventStore` access from
-``predict`` (SURVEY §2.6).
+Both, with the query's ``blackList``, travel to the device as item ids
+(``_common.batch_filter`` -> ``ops.topk.batch_topk_scores_t(exclude=)``):
+one ``[B, E]`` int32 array a batch, E the rung of
+``ops.topk.EXCLUDE_LADDER`` that holds the batch's longest list (32 to
+4,224 ids), taken out inside the exact blocked top-k.  No array of the
+catalogue's length is built on the host; only ``categories`` and a
+``whiteList`` still make the ``[B, M]`` mask.
+
+What ``unseenOnly`` costs a batch, by the longest history in it (one
+v5e chip, 9.35 M items at rank 128, 16 rows; builder's chip runs,
+PR 40; PERF.md, section 5): the read of the store 0.06 ms and the
+filter's build 0.4 ms on the host; on the device 6.7 ms with no ids,
+7.3 ms up to 128 ids, 8.0 up to 512, 10.3 up to 2,048, 14.0 up to
+4,224 (6.3-6.5 of each is the one read of the table), and a longer
+list sends the batch to the host's mask.  This is the template that
+demonstrates low-latency `LEventStore` access from ``predict``
+(SURVEY §2.6).
 """
 
 from __future__ import annotations
 
 import logging
+import time
 from dataclasses import dataclass
-from typing import Any, Optional
+from typing import Any, Optional, Sequence
 
+import jax
 import numpy as np
 
 from ..controller import (
@@ -34,18 +53,41 @@ from ..controller import (
     WorkflowContext,
 )
 from ..models.als import ALSConfig, train_als
-from ..ops.topk import batch_topk_scores, pow2_ceil, topk_scores
+from ..obs import get_registry, log_buckets
+from ..obs.timeline import annotate
+from ..ops.topk import (
+    EXCLUDE_LADDER, batch_topk_scores_t, pow2_ceil, topk_path,
+)
 
-from ._common import DeviceTableMixin, filter_bias_mask, warm_batched_topk
+from ._common import (
+    DeviceTableMixin, RowFilter, batch_filter, warm_batched_topk,
+)
 from .recommendation import (
     PredictedResult,
     Query,
     _resolve_app_id,
     decode_batch_item_scores,
-    decode_item_scores,
 )
 
 logger = logging.getLogger(__name__)
+
+_registry = get_registry()
+SEEN_READ_SECONDS = _registry.histogram(
+    "pio_seen_read_seconds",
+    "Host time of one batch's `pio.seen.read` span: its users' seen "
+    "items read from the event store inside the turn",
+    buckets=log_buckets(1e-6, 10.0, per_decade=4),
+).child()
+SEEN_EVENTS = _registry.counter(
+    "pio_seen_events_total",
+    "Target ids of seen events that the e-commerce engine read from the "
+    "event store at query time",
+).child()
+SEEN_READ_FAILURES = _registry.counter(
+    "pio_seen_read_failures_total",
+    "Reads of a batch's seen events that failed or timed out: logged and "
+    "answered as if the users had seen nothing, as upstream does",
+).child()
 
 
 @dataclass(frozen=True)
@@ -176,22 +218,28 @@ class ECommAlgorithm(Algorithm):
             return get_storage().get_event_store()
         return ctx.storage.get_event_store()
 
-    def _seen_items(self, model: ECommModel, user: str) -> set[str]:
-        """The user's already-seen items (reference `:160-192`)."""
-        p = self.params
-        try:
-            events = self._event_store().find(
-                app_id=model.app_id,
-                entity_type="user",
-                entity_id=user,
-                event_names=list(p.seen_events),
-            )
-            return {
-                e.target_entity_id for e in events if e.target_entity_id
-            }
-        except Exception as e:
-            logger.error("error reading seen events: %s", e)
-            return set()
+    def _seen_items(self, model: ECommModel,
+                    users: Sequence[str]) -> list:
+        """Each user's already-seen items (reference `:160-192`), one
+        list of item ids a user: ONE read of the store for the batch,
+        inside the turn.  A failed read is logged, counted, and answers
+        as if nothing had been seen."""
+        t0 = time.perf_counter()
+        with annotate("pio.seen.read"):
+            try:
+                seen = self._event_store().find_target_ids(
+                    app_id=model.app_id,
+                    entity_type="user",
+                    entity_ids=users,
+                    event_names=list(self.params.seen_events),
+                )
+            except Exception as e:
+                logger.error("error reading seen events: %s", e)
+                SEEN_READ_FAILURES.inc()
+                seen = [[] for _ in users]
+        SEEN_READ_SECONDS.observe(time.perf_counter() - t0)
+        SEEN_EVENTS.inc(sum(map(len, seen)))
+        return seen
 
     def _unavailable_items(self, model: ECommModel) -> set[str]:
         """Latest constraint/unavailableItems $set (reference `:194-215`)."""
@@ -209,92 +257,89 @@ class ECommAlgorithm(Algorithm):
             return set()
 
     def warmup(self, model: ECommModel, max_batch: int = 64) -> None:
-        """Pre-compile the biased top-k scorer for the common ``num``
-        values (every e-comm query carries a filter mask), single-query
-        AND the pow2 batched shapes the serving micro-batcher
-        dispatches."""
+        """Pre-compile the batched top-k scorer at the pow2 shapes the
+        serving micro-batcher dispatches (a lone request is the one-row
+        rung), with excluded ids at the widths this engine's queries can
+        take: with ``unseen_only`` a user's whole history, every rung of
+        the ladder; without, a blackList and the unavailable items, the
+        first.  No small-k rungs: they would be one more program a
+        width, and a shelf asks for ten."""
         n = len(model.items)
         if n == 0:
             return
-        table = model.device_item_factors()
-        rank = model.item_factors.shape[1]
-        vec = np.zeros(rank, np.float32)
-        bias = np.zeros(n, np.float32)
-        for k in {min(k, n) for k in (1, 4, 10, 20)}:
-            topk_scores(vec, table, k, bias=bias)
-        warm_batched_topk(table, rank, n, max_batch=max_batch)
-
-    def _query_mask(self, model: ECommModel, query: Query,
-                    unavailable: Optional[set] = None):
-        """Serve-time filter for one query: blacklist + (optionally)
-        the user's SEEN events read from the live event store + the
-        unavailable-items constraint — the reference's predict-time
-        LEventStore reads (`ECommAlgorithm.scala` predict).
-
-        ``unavailable`` lets batch_predict read the batch-invariant
-        constraint entity ONCE instead of once per coalesced query."""
-        black = set(query.blacklist or ())
-        if self.params.unseen_only:
-            black |= self._seen_items(model, query.user)
-        black |= (
-            self._unavailable_items(model)
-            if unavailable is None else unavailable
+        warm_batched_topk(
+            None, model.item_factors.shape[1], n, unmasked_too=True,
+            max_batch=max_batch, table_t=model.device_item_tables(),
+            exclude_widths=EXCLUDE_LADDER if self.params.unseen_only
+            else None,
         )
-        return filter_bias_mask(
-            model.items, model.item_props,
-            categories=query.categories, whitelist=query.whitelist,
-            blacklist=black,
-        )
+
+    def _excluded_items(self, model: ECommModel, users: Sequence[str]):
+        """For each user, the distinct item indices (int32) the engine
+        itself takes out: the user's seen items (``unseen_only``) and
+        the unavailable ones, both read from the live event store, the
+        constraint entity ONCE for the batch."""
+        gone = model.items.encode(sorted(self._unavailable_items(model)))
+        if not self.params.unseen_only:
+            return [np.unique(gone[gone >= 0])] * len(users)
+        out = []
+        for seen in self._seen_items(model, users):
+            ixs = np.concatenate([model.items.encode(seen), gone])
+            out.append(np.unique(ixs[ixs >= 0]))
+        return out
 
     def predict(self, model: ECommModel, query: Query) -> PredictedResult:
-        uix = model.users.get(query.user)
-        if uix < 0 or query.num <= 0:
-            return PredictedResult(item_scores=())
-        mask = self._query_mask(model, query)
-        k = min(query.num, len(model.items))
-        vals, ixs = topk_scores(
-            np.asarray(model.user_factors[uix], np.float32),
-            model.device_item_factors(), k, bias=mask,
-        )
-        return PredictedResult(
-            item_scores=decode_item_scores(model.items, vals, ixs)
-        )
+        """A lone request is a one-row batch: the same device program,
+        the same filters as data."""
+        return self.batch_predict(model, [query])[0]
 
     def batch_predict(self, model: ECommModel, queries):
-        """Micro-batched serving + eval path: the per-query event-store
-        reads (seen/unavailable) stay host work, the scoring collapses
-        to one batched masked matmul under the same shape-stability
-        contract as the other templates (device batch = len(queries),
-        k rounded to pow2)."""
+        """THE scoring path (serving, batched or lone, and eval): one
+        batched scorer call under the same shape-stability contract as
+        the other templates (device batch = len(queries), k rounded to
+        pow2).  Each row's blackList, seen items and the unavailable
+        items travel to the device as item ids
+        (``_common.batch_filter``); only `categories` and a `whiteList`
+        still make the batch's ``[B, M]`` mask."""
         out = [PredictedResult(item_scores=()) for _ in queries]
         n = len(model.items)
         if n == 0 or not queries:
             return out
-        uix = np.array(
-            [model.users.get(q.user) for q in queries], dtype=np.int64
-        )
-        nums = np.array([q.num for q in queries], dtype=np.int64)
-        valid = (uix >= 0) & (nums > 0)
-        if not valid.any():
-            return out
-        masks = np.zeros((len(queries), n), np.float32)
-        unavailable = self._unavailable_items(model)  # batch-invariant
-        for bi, q in enumerate(queries):
-            if valid[bi]:
-                masks[bi] = self._query_mask(model, q, unavailable)
-        k = min(pow2_ceil(int(nums[valid].max())), n)
-        uvecs = np.asarray(
-            model.user_factors[np.where(valid, uix, 0)], np.float32
-        )
-        vals, ixs = batch_topk_scores(
-            uvecs, model.device_item_factors(), k, mask=masks
-        )
-        decoded = decode_batch_item_scores(
-            model.items, vals, ixs, [q.num for q in queries], valid, k
-        )
-        return [
-            PredictedResult(item_scores=scores) for scores in decoded
-        ]
+        with annotate("pio.turn.prepare"):
+            uix = np.array(
+                [model.users.get(q.user) for q in queries], dtype=np.int64
+            )
+            nums = np.array([q.num for q in queries], dtype=np.int64)
+            valid = (uix >= 0) & (nums > 0)
+            if not valid.any():
+                return out
+            k = min(pow2_ceil(int(nums[valid].max())), n)
+            uvecs = np.asarray(
+                model.user_factors[np.where(valid, uix, 0)], np.float32
+            )
+            asked = [q for q, v in zip(queries, valid) if v]
+            gone = iter(self._excluded_items(
+                model, [q.user for q in asked]))
+            flt = batch_filter(model.items, model.item_props, [
+                RowFilter(q.categories, q.whitelist, q.blacklist, next(gone))
+                if v else None for q, v in zip(queries, valid)
+            ])
+            tables = model.device_item_tables()
+        with annotate("pio.turn.dispatch", filter=flt.kind,
+                      path=topk_path(uvecs, tables, k, flt.mask,
+                                     flt.exclude),
+                      exclude_width=flt.width):
+            vals, ixs = batch_topk_scores_t(
+                uvecs, tables, k, **flt.scorer_kwargs())
+        with annotate("pio.turn.fetch"):
+            vals, ixs = jax.device_get((vals, ixs))
+        with annotate("pio.turn.decode"):
+            decoded = decode_batch_item_scores(
+                model.items, vals, ixs, [q.num for q in queries], valid, k
+            )
+            return [
+                PredictedResult(item_scores=scores) for scores in decoded
+            ]
 
 
 def ecommerce_engine() -> Engine:

@@ -654,7 +654,8 @@ class ALSAlgorithm(Algorithm):
             tables = model.device_item_tables(self._serve_dtype())
             with annotate("pio.turn.dispatch", filter=flt.kind,
                           path=topk_path(uvecs, tables, k, flt.mask,
-                                         flt.exclude)):
+                                         flt.exclude),
+                          exclude_width=flt.width):
                 vals, ixs = batch_topk_scores_t(
                     uvecs, tables, k, **flt.scorer_kwargs())
         with annotate("pio.turn.fetch"):

@@ -353,7 +353,8 @@ class SimilarProductAlgorithm(Algorithm):
             tables = model.device_item_tables()
         with annotate("pio.turn.dispatch", filter=flt.kind,
                       path=topk_path(qvecs, tables, k, flt.mask,
-                                     flt.exclude)):
+                                     flt.exclude),
+                      exclude_width=flt.width):
             vals, ixs = batch_topk_scores_t(
                 qvecs, tables, k, **flt.scorer_kwargs())
         with annotate("pio.turn.fetch"):

@@ -443,9 +443,10 @@ def test_similarproduct_batch_predict_matches_single(similar_ctx):
 
 
 def test_ecommerce_batch_predict_matches_single(ecomm_ctx):
-    """Ecommerce batch_predict: per-query event-store filters stay host
-    work (seen/unavailable read per query), scoring collapses to one
-    shape-stable batched matmul; results match per-query predict."""
+    """Ecommerce batch_predict: the event-store reads stay host work
+    (seen items ONE read a batch, unavailable once a batch), their ids
+    ride to the device, scoring is one shape-stable batched call of
+    `batch_topk_scores_t`; results match per-query predict."""
     from predictionio_tpu.templates import ecommerce as emod
 
     ctx, app_id = ecomm_ctx
@@ -461,11 +462,12 @@ def test_ecommerce_batch_predict_matches_single(ecomm_ctx):
     model = models[0]
 
     shapes = []
-    real = emod.batch_topk_scores
+    real = emod.batch_topk_scores_t
 
-    def spy(vecs, table, k, mask=None):
+    def spy(vecs, tables, k, mask=None, exclude=None):
         shapes.append((vecs.shape[0], k))
-        return real(vecs, table, k, mask=mask)
+        assert mask is None and exclude is not None, "filters ride as ids"
+        return real(vecs, tables, k, mask=mask, exclude=exclude)
 
     import unittest.mock as mock
 
@@ -477,7 +479,7 @@ def test_ecommerce_batch_predict_matches_single(ecomm_ctx):
         Query(user="u1", num=5),
         Query(user="u2", num=3, blacklist=("i0", "i2")),
     ]
-    with mock.patch.object(emod, "batch_topk_scores", spy):
+    with mock.patch.object(emod, "batch_topk_scores_t", spy):
         batch = algo.batch_predict(model, queries)
     assert shapes == [(4, 8)]  # full batch, k=5 -> pow2 8
     assert batch[1].item_scores == ()
@@ -488,7 +490,7 @@ def test_ecommerce_batch_predict_matches_single(ecomm_ctx):
         ], q
     # unseen-only honored in the batched path: u0 viewed items never
     # come back
-    seen = algo._seen_items(model, "u0")
+    seen = set(algo._seen_items(model, ["u0"])[0])
     assert seen and not (
         {s.item for s in batch[0].item_scores} & seen
     )

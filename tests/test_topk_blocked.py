@@ -341,17 +341,19 @@ def test_batch_predict_takes_the_path_its_batch_allows(filtered):
         seen.append((name, meta))
         return real(name, **meta)
 
-    path, counted, kind = {
-        "unmasked": ("blocked", "blocked", "none"),
-        "blacklist": ("blocked", "blocked_ids", "ids"),
-        "whitelist": ("dense", "dense", "mask"),
+    path, counted, kind, width = {
+        "unmasked": ("blocked", "blocked", "none", 0),
+        "blacklist": ("blocked", "blocked_ids", "ids",
+                      topk.EXCLUDE_LADDER[0]),
+        "whitelist": ("dense", "dense", "mask", 0),
     }[filtered]
     before = topk.TOPK_PATH.labels(path=counted).value()
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(rmod, "annotate", spy)
         got = algo.batch_predict(model, queries)
     assert topk.TOPK_PATH.labels(path=counted).value() == before + 1
-    assert ("pio.turn.dispatch", {"path": path, "filter": kind}) in seen
+    assert ("pio.turn.dispatch", {"path": path, "filter": kind,
+                                  "exclude_width": width}) in seen
     for query, result in zip(queries, got):
         solo = algo.predict(model, query)
         assert [s.item for s in result.item_scores] == \

@@ -1,4 +1,6 @@
-"""Excluded ids on the device (`ops.topk.batch_topk_scores_t(exclude=)`):
+"""Excluded ids on the device (`ops.topk.batch_topk_scores_t(exclude=)`),
+at the ladder's first rung (a blackList; the wider rungs, a whole history,
+are `tests/test_topk_listed.py`'s):
 the blocked path with `k + E` chosen blocks and the exclusions applied to
 the gathered candidates equals the dense masked top-k id for id; what the
 shapes do not allow stays dense, with the ids scattered into the scores;
@@ -16,7 +18,8 @@ from predictionio_tpu.ops import topk
 from predictionio_tpu.templates import _common
 
 M = 100_003
-WIDTH = topk.EXCLUDE_LADDER[-1]
+WIDTH = topk.EXCLUDE_LADDER[0]
+LAST = topk.EXCLUDE_LADDER[-1]
 
 
 def _unit_rows(m, r, seed=0):
@@ -160,7 +163,10 @@ def test_block_size_with_excluded_ids(b, k, rank, width, want):
 def test_ladder_of_widths_and_the_counters_label():
     assert topk.exclude_width(0) == 0
     assert topk.exclude_width(1) == topk.exclude_width(WIDTH) == WIDTH
-    assert topk.exclude_width(WIDTH + 1) == 0
+    assert topk.exclude_width(WIDTH + 1) == topk.EXCLUDE_LADDER[1]
+    assert topk.exclude_width(LAST) == LAST
+    assert topk.exclude_width(LAST + 1) == 0
+    assert list(topk.EXCLUDE_LADDER) == sorted(set(topk.EXCLUDE_LADDER))
     rows = _unit_rows(M, 128)
     q, tables = rows[:4], _tables(rows)
 
@@ -246,7 +252,7 @@ def test_batch_filter_resolves_ids_by_lookup_and_builds_no_wide_array(
 def test_batch_filter_kinds_and_counters():
     from predictionio_tpu.storage.bimap import StringIndex
 
-    items = StringIndex([f"i{j}" for j in range(50)])
+    items = StringIndex([f"i{j}" for j in range(LAST + 50)])
     rows_of = _common.FILTER_ROWS.labels
 
     def rows(kind):
@@ -260,14 +266,18 @@ def test_batch_filter_kinds_and_counters():
     ids = _common.batch_filter(items, {}, [
         _common.RowFilter(blacklist=("i1",)), None, None])
     assert ids.kind == "ids"
-    wide = _common.batch_filter(items, {}, [_common.RowFilter(
+    assert ids.width == WIDTH and none.width == 0
+    listed = _common.batch_filter(items, {}, [_common.RowFilter(
         blacklist=tuple(f"i{j}" for j in range(WIDTH + 1)))])
-    assert wide.kind == "mask" and wide.exclude is None
-    assert np.isneginf(wide.mask[0, :WIDTH + 1]).all()
-    assert (wide.mask[0, WIDTH + 1:] == 0).all()
+    assert listed.kind == "ids" and listed.width == topk.EXCLUDE_LADDER[1]
+    wide = _common.batch_filter(items, {}, [_common.RowFilter(
+        blacklist=tuple(f"i{j}" for j in range(LAST + 1)))])
+    assert wide.kind == "mask" and wide.exclude is None and wide.width == 0
+    assert np.isneginf(wide.mask[0, :LAST + 1]).all()
+    assert (wide.mask[0, LAST + 1:] == 0).all()
     assert {kind: rows(kind) - n for kind, n in before.items()} == {
-        "none": 2, "ids": 3, "mask": 1}
-    assert _common.FILTER_BUILD_SECONDS.snapshot()["count"] == built + 3
+        "none": 2, "ids": 4, "mask": 1}
+    assert _common.FILTER_BUILD_SECONDS.snapshot()["count"] == built + 4
 
 
 # -- the templates: warm-up, one path for one row, no wide host array --------
@@ -352,8 +362,8 @@ def test_similarproduct_serves_by_ids_and_warms_what_it_dispatches(
 @pytest.mark.parametrize("kind", ["categories", "whitelist", "both",
                                   "long_blacklist"])
 def test_similarproduct_wide_filters_keep_their_answers(kind):
-    """`categories`, a `whiteList` and a list past the ids' width still
-    take the `[B, M]` mask; the answers are the contract's."""
+    """`categories`, a `whiteList` and a list past the ladder's last rung
+    still take the `[B, M]` mask; the answers are the contract's."""
     from predictionio_tpu.templates import similarproduct as smod
 
     model = _similar_model()
@@ -366,7 +376,7 @@ def test_similarproduct_wide_filters_keep_their_answers(kind):
                            categories=("even",), blacklist=("i0", "i42")),
         "long_blacklist": smod.Query(
             items=("i1",), num=8,
-            blacklist=tuple(f"i{j}" for j in range(2, 2 + WIDTH))),
+            blacklist=tuple(f"i{j}" for j in range(2, 2 + LAST))),
     }[kind]
     mask_rows = _common.FILTER_ROWS.labels(filter="mask").value()
     plain = smod.Query(items=("i3",), num=8)

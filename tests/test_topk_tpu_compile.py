@@ -1,0 +1,118 @@
+"""The serving scorer's programs with excluded ids compiled at the
+e-commerce cell's size for a DESCRIBED v5e chip (no chip attached; the TPU's
+compiler is installed here): what the interpreter cannot show, a kernel or
+a gather the chip's compiler refuses and a rung that does not fit the
+device's memory, costs no chip time; and that the shapes by which
+`exclude_device_ms.unseen` finds the exclusions' operations in a trace are
+those of the operations under the exclusions' named scopes, at every rung the
+cell's batches take.  Nothing runs; a compile that passes is not a chip run.  One file, the topology described inside a fixture
+(`on-chip-measurement`, section 2)."""
+
+import json
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from predictionio_tpu.ops import topk
+
+M, R, K = 9_350_000, 128, 16
+# `exclude_device_ms.unseen` finds the exclusions' operations in a trace by
+# the SHAPES in their HLO text; the trace names an operation by that text
+PATTERN = re.compile(json.loads(
+    (Path(__file__).resolve().parents[1]
+     / "perfbench/metrics/exclude_device_ms.unseen.json").read_text()
+)["args"]["pattern"])
+NO_EVENT = ("parameter(", "get-tuple-element(", "bitcast(", "constant(",
+            "tuple(")
+
+
+def operations_by_scope(text: str):
+    """`(scope, instruction)` of the instructions a trace would show as
+    events: those outside fused computations that do any work, each with
+    the innermost `topk.*` named scope of its `op_name`."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    inside = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            inside = head.group(1)
+        elif line.startswith("}"):
+            inside = None
+        elif inside is not None and inside not in fused:
+            ins = line.strip().removeprefix("ROOT ")
+            body = ins.split(", metadata=")[0]
+            if ins.startswith("%") and not any(k in body for k in NO_EVENT):
+                name = re.search(r'op_name="([^"]*)"', ins)
+                scopes = re.findall(r"topk\.[a-z_]+", name.group(1)) \
+                    if name else []
+                yield (scopes[-1] if scopes else "", ins)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def as_on_the_chip(monkeypatch):
+    """The branches the chip takes: the scan kernel compiled (not
+    interpreted) and bfloat16 operands; and no persistent cache, which
+    could not read such a program back without a chip."""
+    monkeypatch.setattr(topk, "_mxu_operands", lambda: True)
+    monkeypatch.setattr(topk, "pallas_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.mark.parametrize("batch,width", [
+    (64, topk.EXCLUDE_LADDER[-1]), (16, 512), (1, 128), (64, 32),
+    (64, 128), (1, 512), (64, 2048), (16, topk.EXCLUDE_LADDER[-1])])
+def test_a_rung_compiles_for_the_chip_and_fits_it(one_chip, as_on_the_chip,
+                                                  batch, width):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scorer = topk.batch_topk_scores_t.__wrapped__.__wrapped__
+    compiled = jax.jit(scorer, static_argnames=("k",)).lower(
+        sds((batch, R), jnp.float32),
+        topk.ItemTables(None, sds((M, R), jnp.float32)), k=K, mask=None,
+        exclude=sds((batch, width), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the scan is the Pallas kernel"
+    assert text.count('custom_call_target="TopK"') == 1
+    memory = compiled.memory_analysis()
+    # beside the 4.79 GB table: the widest rung's gathered lines and the
+    # block maxima, 1.1-1.2 GB; a [B, M] matrix would be 2.4 GB
+    assert memory.temp_size_in_bytes < 1.5e9
+    assert memory.argument_size_in_bytes < 4.9e9
+    if width == topk.EXCLUDE_LADDER[0]:
+        return      # the pairwise form: no batch of the e-commerce cell
+    # the listed form, every rung the cell's batches take: what the metric
+    # reads as the exclusions' own lies under their named scopes (and the
+    # lines' maxima, by which this form alone chooses its blocks), never
+    # in the scan, the chosen blocks' scores or the k passes; and it holds
+    # the gathered lines of the listed ids' blocks, the widest of it
+    read = [(scope, ins) for scope, ins in operations_by_scope(text)
+            if PATTERN.search(ins)]
+    assert {scope for scope, _ in read} <= {
+        "topk.exclude_bits", "topk.exclude_blocks", "topk.exclude",
+        "topk.blocks"}, read
+    for scope, ins in read:
+        if scope == "topk.blocks":
+            assert re.match(r"%[\w.\-]+ = f32\[\d+,(4568|9136)\]", ins), ins
+    lines = [ins for scope, ins in operations_by_scope(text)
+             if scope == "topk.exclude_blocks" and "(%table_t_packed" in ins]
+    assert lines and all(PATTERN.search(ins) for ins in lines), lines

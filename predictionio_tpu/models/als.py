@@ -52,7 +52,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import (
     ALS_EXCHANGE_BYTES_TOTAL, ALS_GATHER_BYTES_TOTAL, ALS_GRAM_ENTRIES_TOTAL,
-    ALS_SOLVE_SYSTEMS_TOTAL, TRAIN_PHASE_SECONDS, tower, xray,
+    ALS_SOLVE_SYSTEMS_TOTAL, ALS_WRITE_ROWS_TOTAL, TRAIN_PHASE_SECONDS,
+    tower, xray,
 )
 from ..obs.timeline import annotate
 from ..parallel.mesh import DATA_AXIS, pad_to_multiple, replicated
@@ -454,6 +455,7 @@ def build_bucket_layout(
     max_rows: Optional[int] = None,
     dense_min: Optional[int] = None,
     dense_rows: int = 0,
+    owners: int = 1,
 ) -> BucketLayout:
     """Group rows by padded rating-count so the device solves static shapes.
 
@@ -491,9 +493,22 @@ def build_bucket_layout(
     layout.buckets = _assemble_buckets(
         counts, starts, n_rows, min_k, max_per_row, batch_multiple,
         max_entries, starts_dtype=starts_dtype, max_rows=max_rows,
-        dense_min=dense_min, dense_rows=dense_rows,
+        dense_min=dense_min, dense_rows=dense_rows, owners=owners,
     )
     return layout
+
+
+def _deal_order(owner: np.ndarray) -> np.ndarray:
+    """An order of a bucket's rows, from the shard that owns each
+    (``owner``, non-decreasing as the ids ascend), in which every run of
+    consecutive rows holds each owner's rows in proportion to its share
+    of the bucket, to a row: owner o's j-th row of c stands at
+    ``(j + 1/2) / c`` of the way.  Rows of one owner keep their order,
+    and a bucket with one owner keeps its own."""
+    held = np.bincount(owner)
+    within = np.arange(len(owner)) - np.repeat(np.cumsum(held) - held, held)
+    place = (within + 0.5) / np.repeat(held, held)
+    return np.argsort(place, kind="stable")
 
 
 def _assemble_buckets(
@@ -508,6 +523,7 @@ def _assemble_buckets(
     max_rows: Optional[int] = None,
     dense_min: Optional[int] = None,
     dense_rows: int = 0,
+    owners: int = 1,
 ) -> list[Bucket]:
     """Bucket plan from per-row (counts, starts) alone.
 
@@ -519,6 +535,14 @@ def _assemble_buckets(
     None: no row) leave the K buckets for DENSE chunks (``k == DENSE_K``,
     after the others), the widest ``dense_rows`` of them
     (:func:`dense_budget_rows`) where more qualify.
+
+    ``owners`` (sharded placement: the shards of the table of ``n_rows``
+    rows, which they divide) above 1 DEALS a pad width's rows over its
+    chunks (:func:`_deal_order`), so that every chunk holds each shard's
+    rows in the shard's share of the bucket and no shard writes back a
+    whole chunk while the others wait (`_owner_lists`).  At 1 the rows
+    ascend, as the replicated halves' staged shapes and programs have
+    them.
     """
     if max_entries is None:
         max_entries = MAX_ENTRIES_PER_BUCKET
@@ -545,6 +569,8 @@ def _assemble_buckets(
     for k in (*keys[keys != DENSE_K], *keys[keys == DENSE_K]):
         k = int(k)
         rows_k = active[k_active == k].astype(np.int32)
+        if owners > 1:
+            rows_k = rows_k[_deal_order(rows_k // (n_rows // owners))]
         b_cap = dense_rows if k == DENSE_K else max_entries // k
         if max_rows is not None:
             b_cap = min(b_cap, max_rows)
@@ -655,6 +681,37 @@ def _plan_shard_layout(
             )
             perm[d, :total] = np.arange(total, dtype=np.int64) + base
     return perm, [ls.astype(np.int32) for ls in local_starts], max(L, 1)
+
+
+def _owner_lists(rows: np.ndarray, shard_n: int, n_dev: int) -> tuple:
+    """Which solved rows of a chunk each shard writes back, for a group
+    of chunks ``rows [n, B]`` (global ids; batch padding, at or past the
+    table's ``n_dev * shard_n`` rows, is nobody's): ``(own_pos, own_row)``,
+    both int32 ``[n, n_dev, cap]``.  ``own_pos[c, s]`` are the places in
+    chunk c's all-gathered ``[B]`` order of the rows shard s owns, and
+    ``own_row[c, s]`` those rows' ids inside the shard.  ``cap`` is the
+    most rows any shard owns of any one chunk, rounded up to the mesh:
+    about B / n_dev for rows dealt over the chunks (:func:`_deal_order`),
+    B for a group whose rows live in one shard.  Past a shard's count the
+    places point at row 0 and the ids are distinct and out of range
+    (``shard_n + slot``), so the scatter drops them and
+    ``unique_indices=True`` stays honest, as for the replicated path's
+    batch padding."""
+    b = rows.shape[1]
+    owner = np.minimum(rows // shard_n, n_dev).astype(np.int16)
+    by_owner = np.argsort(owner, axis=1, kind="stable")
+    held = np.stack([(owner == s).sum(axis=1) for s in range(n_dev)], axis=1)
+    first = np.cumsum(held, axis=1) - held
+    cap = pad_to_multiple(max(int(held.max()), 1), n_dev)
+    slot = np.arange(cap)
+    mine = slot < held[:, :, None]
+    pos = np.take_along_axis(
+        by_owner[:, None, :],
+        np.minimum(first[:, :, None] + slot, b - 1), axis=2)
+    local = (np.take_along_axis(rows[:, None, :], pos, axis=2)
+             - (np.arange(n_dev) * shard_n)[:, None])
+    return (np.where(mine, pos, 0).astype(np.int32),
+            np.where(mine, local, shard_n + slot).astype(np.int32))
 
 
 @xray.instrument("als.expand_sides")
@@ -1841,7 +1898,12 @@ def build_sharded_half(
       ``[d*B, K, R]`` partial answers), the same bits a gather from the
       whole table reads.  Each device then solves its shard of the
       chunk, all-gathers the small solved blocks ``[B, R]`` and writes
-      only the rows its own factor shard owns.
+      only the rows its own factor shard owns, from a list laid out at
+      staging (`_owner_lists`): the TPU's row scatter costs by the row
+      it is handed (some 70 ns at rank 128), dropped or not, and a pad
+      width's rows are dealt over its chunks (`_deal_order`) so that a
+      chunk's rows are about a quarter each shard's and no shard
+      scatters a whole chunk while the others wait for it.
     * The ratings are SHARDED with the rows that read them: staging
       lays each device's rating slices out in shard-local order
       (``_plan_shard_layout``; the int32-offset ceiling applies per
@@ -1854,9 +1916,10 @@ def build_sharded_half(
     * ``ks[g]`` is the pad width of chunk group ``g``, whose arrays are
       the replicated path's own tuple laid out by chunk, ``(rows [n, B],
       idx [n, B, K], val [n, B, K], counts [n, B])``, the batch split
-      over the mesh: n chunks of one shape (``_chunk_groups``), run as
-      ONE loop, so a table of 600 chunks traces, lowers and compiles
-      one chunk's program a shape.
+      over the mesh, and the owners' lists ``(own_pos, own_row)``, both
+      ``[n, d, cap]``, a shard its own: n chunks of one shape
+      (``_chunk_groups``), run as ONE loop, so a table of 600 chunks
+      traces, lowers and compiles one chunk's program a shape.
 
     Two modes keep a whole-table gather, because what they run reads a
     whole table: ``solver="fused"`` (the kernel fetches rows by DMA from
@@ -1881,9 +1944,10 @@ def build_sharded_half(
     parity is already fresh.  With an all-ones mask the math reduces to
     the plain path (reconstruction multiplies by zero).
 
-    Requires row counts padded to a multiple of the mesh size; bucket
-    padding rows carry ids >= the padded row count, so they drop out of
-    every shard's scatter window.
+    Requires row counts padded to a multiple of the mesh size.  Bucket
+    padding rows carry ids >= the padded row count and are in no
+    shard's list; a list's own padding carries distinct ids past the
+    shard's rows, which the scatter drops.
     """
     from ..parallel.collectives import (
         EXCHANGE_SCOPE, ShardedRows, shard_map,
@@ -1894,9 +1958,6 @@ def build_sharded_half(
     f32 = jnp.float32
 
     def solve_core(upd, opp, gram, lam, alpha, flat_buckets, exchange=None):
-        me = jax.lax.axis_index(axis)
-        shard_n = upd.shape[0]
-        lo = (me * shard_n).astype(jnp.int32)
         # subspace mode warm-starts each row's block sweep from the
         # CURRENT factor value, but this device solves rows owned by
         # OTHER shards — gather the full updating table transiently
@@ -1915,23 +1976,21 @@ def build_sharded_half(
 
         def chunk_step(k):
             def step(table, chunk):
+                *bucket, own_pos, own_row = chunk
+
                 def write(acc, rows, x):
                     acc = table if acc is None else acc
                     with jax.named_scope(EXCHANGE_SCOPE):
                         xg = jax.lax.all_gather(x, axis, axis=0, tiled=True)
-                        rg = jax.lax.all_gather(
-                            rows, axis, axis=0, tiled=True)
-                    local = rg - lo
-                    inside = (local >= 0) & (local < shard_n)
-                    # OOB sentinel: shard_n is out of range -> dropped by
-                    # the scatter (covers other shards' rows AND bucket
-                    # padding)
-                    safe = jnp.where(inside, local, shard_n)
-                    return acc.at[safe].set(
-                        xg.astype(acc.dtype), mode="drop")
+                    # this shard's own rows of the chunk, by the staged
+                    # list ([1, cap] here): the scatter costs by the row
+                    # it is handed, dropped or not
+                    return acc.at[own_row[0]].set(
+                        xg[own_pos[0]].astype(acc.dtype), mode="drop",
+                        unique_indices=True)
 
                 out = _solve_buckets(
-                    write, opp, (chunk,), lam, alpha,
+                    write, opp, (tuple(bucket),), lam, alpha,
                     ks=(k,), implicit=implicit,
                     weighted_lambda=weighted_lambda,
                     precision=precision, solver=solver,
@@ -1947,7 +2006,7 @@ def build_sharded_half(
         table = upd
         for g, k in enumerate(ks):
             table = _each_chunk(
-                chunk_step(k), table, flat_buckets[4 * g : 4 * g + 4]
+                chunk_step(k), table, flat_buckets[6 * g : 6 * g + 6]
             )
         return table
 
@@ -1961,10 +2020,10 @@ def build_sharded_half(
     P_ = P
     sharded2 = P_(axis, None)
     rep = P_()
-    # a group's (rows, idx, val, counts), as staged
+    # a group's (rows, idx, val, counts) and (own_pos, own_row), as staged
     bucket_specs = (
         P_(None, axis), P_(None, axis, None), P_(None, axis, None),
-        P_(None, axis),
+        P_(None, axis), P_(None, axis, None), P_(None, axis, None),
     ) * len(ks)
 
     if not coded:
@@ -2204,6 +2263,8 @@ class ALSTrainer:
             "subspaceSize": cfg.subspace_size,
             "rankBlocks": -(-cfg.rank // self.system_width),
             "exchangeBytes": self.exchange_bytes,
+            "writeRows": self.write_rows,
+            "writeCaps": self.write_caps,
             "devices": n_dev,
             "devicesWithData": self.data_devices(),
             "paddedEntries": per_side("padded_entries"),
@@ -2223,9 +2284,10 @@ class ALSTrainer:
         chunk's rows by the bytes of its Gram (``[B, w, w]`` where a
         half sweeps rank blocks of width w, ``[B, R, R]`` else) and its
         entries by the bytes of its rows: the exchanged ones under
-        sharded placement, the gathered ones else."""
+        sharded placement, the gathered ones else; and, under sharded
+        placement, the shards that own the rows."""
         cfg = self.cfg
-        return {
+        caps = {
             "max_rows": gram_chunk_rows(self.system_width, n_dev),
             "max_entries": (
                 exchange_chunk_entries(cfg.rank, n_dev) if self.sharded
@@ -2233,6 +2295,10 @@ class ALSTrainer:
                     cfg.rank, n_dev, jnp.dtype(cfg.gather_dtype).itemsize)
             ),
         }
+        if self.sharded:
+            # a pad width's rows dealt over its chunks by owning shard
+            caps["owners"] = n_dev
+        return caps
 
     @property
     def system_width(self) -> int:
@@ -2328,8 +2394,14 @@ class ALSTrainer:
         * ``exchange_bytes``: bytes ONE device receives in a half
           (sharded placement: each chunk's ids all-gathered, the
           reduce-scatter of its ``[d*B, K, R]`` partial rows, the solved
-          ``[B, R]`` blocks and their row ids all-gathered, the implicit
-          YtY all-reduced; the whole table where a mode all-gathers it).
+          ``[B, R]`` blocks all-gathered, the implicit YtY all-reduced;
+          the whole table where a mode all-gathers it).
+        * ``write_rows``: rows ONE device hands its scatter in a half
+          (sharded placement: every chunk's list, ``cap`` rows, padding
+          included: a quarter of the side's padded rows on four devices
+          where the rows are dealt over the chunks, all of them for a
+          group that one shard owns), and ``write_caps``, each group's
+          ``[cap, B]``.
         * ``opp_transient_bytes``: the most a device holds in the
           opposite table's place at once (the partial rows and the
           chunk's own; the whole table where a mode all-gathers it).
@@ -2352,6 +2424,7 @@ class ALSTrainer:
         sub = self.sweeps_blocks
         self.exchange_bytes, self.opp_transient_bytes = {}, {}
         self.chunks_looped, self.gather_bytes = {}, {}
+        self.write_rows, self.write_caps = {}, {}
         gram_rows = gather_entries = 0
         for name, side in (("user", self._user_side),
                            ("item", self._item_side)):
@@ -2367,7 +2440,7 @@ class ALSTrainer:
                 looped += n if n > 1 else 0
                 if not self.sharded:
                     continue
-                received += n * (d - 1) * b * (r * 4 + 4)
+                received += n * (d - 1) * b * r * 4
                 if not whole_opp:
                     received += n * (d - 1) * b * k * (4 + row_bytes)
                     transient = max(transient, (d + 1) * b * k * row_bytes)
@@ -2382,6 +2455,11 @@ class ALSTrainer:
                     received += (d - 1) * upd_rows // d * r * 4
                 if cfg.implicit:
                     received += 2 * (d - 1) * r * r * 4 // d
+            lists = [own_pos.shape for own_pos, _ in side.get("owners", ())]
+            self.write_rows[name] = sum(n * cap for n, _, cap in lists)
+            self.write_caps[name] = [
+                [cap, bucket[0].shape[-1]]
+                for (_, _, cap), bucket in zip(lists, side["buckets"])]
             self.exchange_bytes[name] = int(received)
             self.opp_transient_bytes[name] = int(transient)
             self.chunks_looped[name] = int(looped)
@@ -2674,7 +2752,8 @@ class ALSTrainer:
         v_g = jax.make_array_from_single_device_arrays(
             (n_dev * L,), sh, v_parts
         )
-        return self._stage_chunk_groups((c_g, v_g), L, buckets, local_starts)
+        return self._stage_chunk_groups(
+            (c_g, v_g), L, buckets, local_starts, n_rows_pad)
 
     def _stage_device(self, u, i, v, nu, ni, n_dev):
         """Compact-transfer staging: host counting-sort once, expand the
@@ -2887,10 +2966,11 @@ class ALSTrainer:
         # non-addressable devices
         put_dp = lambda x: shard_put(x, self.mesh, P(DATA_AXIS))  # noqa: E731
         return self._stage_chunk_groups(
-            (put_dp(c_sh), put_dp(v_sh)), L, layout.buckets, local_starts)
+            (put_dp(c_sh), put_dp(v_sh)), L, layout.buckets, local_starts,
+            layout.n_rows)
 
     def _stage_chunk_groups(self, columns, shard_len: int, buckets,
-                            local_starts) -> dict:
+                            local_starts, table_rows: int) -> dict:
         """A sharded side from its columns on the mesh (``P('data')``,
         ``shard_len`` entries a device): the chunks of one shape
         (:func:`_chunk_groups`) stacked as ``[n, B]`` arrays of rows and
@@ -2899,19 +2979,31 @@ class ALSTrainer:
         ``[n, B, K]`` ids and ratings the halves read
         (`_expand_side_sharded`).  A group is the replicated path's own
         ``(rows, idx, val, counts)``; the columns and the shard-local
-        starts are not read again and are dropped here."""
+        starts are not read again and are dropped here.  Beside each
+        group, ``owners``: the lists by which a shard of the table of
+        ``table_rows`` rows writes back its own solved rows
+        (`_owner_lists`), ``[n, d, cap]``, a shard its own."""
         from ..parallel.mesh import shard_put
 
         runs = _chunk_groups(buckets)
+        n_dev = self.mesh.size
 
-        def put(arrays):
-            return shard_put(np.stack(arrays), self.mesh,
-                             P(None, DATA_AXIS))
+        def put(array, spec=P(None, DATA_AXIS)):
+            return shard_put(array, self.mesh, spec)
+
+        def by_run(per_chunk):
+            return [np.stack([per_chunk[j] for j in run]) for run in runs]
 
         ks = tuple(buckets[run[0]].k for run in runs)
-        rows = [put([buckets[j].rows for j in run]) for run in runs]
-        counts = [put([buckets[j].counts for j in run]) for run in runs]
-        starts = [put([local_starts[j] for j in run]) for run in runs]
+        ids = by_run([b.rows for b in buckets])
+        owners = tuple(
+            tuple(put(a, P(None, DATA_AXIS, None))
+                  for a in _owner_lists(group, table_rows // n_dev, n_dev))
+            for group in ids
+        )
+        rows = [put(group) for group in ids]
+        counts = [put(g) for g in by_run([b.counts for b in buckets])]
+        starts = [put(g) for g in by_run(local_starts)]
         columns = jax.block_until_ready(columns)
         t0 = time.perf_counter()
         padded = jax.block_until_ready(_expand_side_sharded(
@@ -2925,6 +3017,7 @@ class ALSTrainer:
                 (r, idx, val, m)
                 for r, (idx, val), m in zip(rows, padded, counts)
             ),
+            "owners": owners,
             **_expansion_report(padded, expand_s),
             "entries": _gram_entries(buckets),
         }
@@ -2981,6 +3074,14 @@ class ALSTrainer:
             self._parity_state[name] = p
         return p
 
+    @staticmethod
+    def _sharded_operands(side: dict) -> list:
+        """What a sharded half is handed after its tables and scalars:
+        group by group, the staged ``(rows, idx, val, counts)`` and the
+        owners' lists ``(own_pos, own_row)``."""
+        return [a for group in zip(side["buckets"], side["owners"])
+                for part in group for a in part]
+
     def _half(self, upd, opp, side, lam: Optional[float] = None) -> jax.Array:
         cfg = self.cfg
         lam_t = jnp.asarray(cfg.lam if lam is None else lam, jnp.float32)
@@ -2990,7 +3091,7 @@ class ALSTrainer:
                 if side is self._user_side
                 else self._sharded_item_half
             )
-            flat = [a for b in side["buckets"] for a in b]
+            flat = self._sharded_operands(side)
             if self.coded:
                 upd_name = (
                     "user" if side is self._user_side else "item"
@@ -3177,6 +3278,9 @@ class ALSTrainer:
                 if received:
                     ALS_EXCHANGE_BYTES_TOTAL.labels(side=side_name).inc(
                         received)
+            for side_name, written in self.write_rows.items():
+                if written:
+                    ALS_WRITE_ROWS_TOTAL.labels(side=side_name).inc(written)
             for side_name, gathered in self.gather_bytes.items():
                 if gathered:
                     ALS_GATHER_BYTES_TOTAL.labels(side=side_name).inc(

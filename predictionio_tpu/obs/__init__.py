@@ -235,6 +235,14 @@ ALS_EXCHANGE_BYTES_TOTAL = _registry.counter(
     "solved; counted from the staged shapes, once a sweep",
     labels=("side",),
 )
+ALS_WRITE_ROWS_TOTAL = _registry.counter(
+    "pio_als_write_rows_total",
+    "Solved rows ONE device hands its scatter in the sharded ALS halves "
+    "(each bucket chunk's list of the rows its shard owns, padding "
+    "included), by the side being solved; counted from the staged "
+    "lists, once a sweep",
+    labels=("side",),
+)
 ALS_GATHER_BYTES_TOTAL = _registry.counter(
     "pio_als_gather_bytes_total",
     "Bytes of opposite rows an ALS half gathers into its padded bucket "

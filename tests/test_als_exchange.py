@@ -139,13 +139,13 @@ def test_expand_bucket_gathers_narrow_rows_and_slices_wide_ones(k):
 def _lowered_half(tr, side_name):
     """A sharded half lowered on what `ALSTrainer._half` hands it: the
     tables, the coded half's parity and mask, lambda, alpha and every
-    group's staged `(rows, idx, val, counts)`."""
+    group's staged `(rows, idx, val, counts)` and owners' lists."""
     side = tr._user_side if side_name == "user" else tr._item_side
     fn = (tr._sharded_user_half if side_name == "user"
           else tr._sharded_item_half)
     U, V = tr.init_factors()
     upd, opp = (U, V) if side_name == "user" else (V, U)
-    flat = [a for b in side["buckets"] for a in b]
+    flat = tr._sharded_operands(side)
     args = [upd, opp]
     if tr.coded:
         args += [tr._parity_fn(opp),
@@ -227,10 +227,11 @@ def _staged_shards(monkeypatch):
     seen = []
     stage = ALSTrainer._stage_chunk_groups
 
-    def spy(self, columns, shard_len, buckets, local_starts):
+    def spy(self, columns, shard_len, buckets, local_starts, table_rows):
         seen.append(([np.asarray(c) for c in columns], shard_len, buckets,
                      local_starts))
-        return stage(self, columns, shard_len, buckets, local_starts)
+        return stage(self, columns, shard_len, buckets, local_starts,
+                     table_rows)
 
     monkeypatch.setattr(ALSTrainer, "_stage_chunk_groups", spy)
     return seen
@@ -333,8 +334,8 @@ def test_no_sharded_half_reads_the_shards_coo(mode):
         L = side["shard_len"]
         lowered = _lowered_half(tr, name)
         coo = {L, d * L} | {L + k for k in side["ks"]}
-        flat = [a for b in side["buckets"] for a in b]
-        assert len(flat) == 4 * len(side["ks"])
+        flat = tr._sharded_operands(side)
+        assert len(flat) == 6 * len(side["ks"])
         avals = lowered.in_avals[0]
         assert len(avals) == (6 if tr.coded else 4) + len(flat)
         assert not any(set(a.shape) & coo for a in avals)
@@ -352,6 +353,339 @@ def test_no_sharded_half_reads_the_shards_coo(mode):
     ).as_text()
     assert f"tensor<{d * L}xi32>" in staging and f"tensor<{L}xi32>" in staging
     assert _gathers_from_a_column(staging)
+
+
+# -- (b'') the write-back: a shard scatters the rows it owns, by a list ------
+
+
+def _parents_half(tr, side):
+    """The sharded half with the PARENT's write-back, kept here as a
+    plain reference: a chunk's solved rows and their ids all-gathered,
+    every one of the B rows handed to every shard's scatter, the other
+    owners' (and the batch padding) sent to the sentinel and dropped.
+    Chunks unrolled; everything before the write is `_solve_buckets`."""
+    import jax
+
+    cfg, ks = tr.cfg, side["ks"]
+    prec = jax.lax.Precision(cfg.matmul_precision)
+
+    def body(upd, opp, lam, alpha, *flat):
+        shard_n = upd.shape[0]
+        lo = (jax.lax.axis_index("data") * shard_n).astype(jnp.int32)
+        gram = None
+        if cfg.implicit:
+            gram = jax.lax.psum(als._table_gram(opp, prec), "data")
+        table = upd
+        for g, k in enumerate(ks):
+            group = flat[4 * g: 4 * g + 4]
+            for c in range(group[0].shape[0]):
+
+                def write(acc, rows, x, table=table):
+                    acc = table if acc is None else acc
+                    xg = jax.lax.all_gather(x, "data", axis=0, tiled=True)
+                    rg = jax.lax.all_gather(rows, "data", axis=0, tiled=True)
+                    local = rg - lo
+                    inside = (local >= 0) & (local < shard_n)
+                    return acc.at[jnp.where(inside, local, shard_n)].set(
+                        xg.astype(acc.dtype), mode="drop")
+
+                table = als._solve_buckets(
+                    write, opp, (tuple(a[c] for a in group),), lam, alpha,
+                    ks=(k,), implicit=cfg.implicit,
+                    weighted_lambda=cfg.weighted_lambda,
+                    precision=cfg.matmul_precision, solver=cfg.solver,
+                    gram=gram, exchange=ShardedRows("data", opp.shape[0]))
+        return table
+
+    table, rep = P("data", None), P()
+    group = (P(None, "data"), P(None, "data", None), P(None, "data", None),
+             P(None, "data"))
+    half = jax.jit(shard_map(
+        body, mesh=tr.mesh, in_specs=(table, table, rep, rep) + group * len(ks),
+        out_specs=table))
+    flat = [a for b in side["buckets"] for a in b]
+    return lambda upd, opp: half(
+        upd, opp, jnp.float32(cfg.lam), jnp.float32(cfg.alpha), *flat)
+
+
+WRITE_MODES = {
+    "explicit": dict(rank=6),
+    "implicit": dict(rank=6, implicit=True),
+    "lowrank": dict(rank=32, implicit=True),
+}
+
+
+@pytest.mark.parametrize("mode", sorted(WRITE_MODES))
+def test_dealt_rows_written_by_list_bitwise_as_the_parents_write(
+        monkeypatch, mode):
+    """A row's solution depends neither on the chunk it shares nor on
+    who scatters it: two sweeps over dealt buckets, written by the
+    owners' lists, are bit for bit the same buckets in id order through
+    the parent's write."""
+    monkeypatch.setattr(als, "MAX_ENTRIES_PER_BUCKET", 128)
+    cfg = ALSConfig(lam=0.05, alpha=2.0, min_bucket_k=4,
+                    factor_placement="sharded", **WRITE_MODES[mode])
+    data = _ratings(210, 45, density=0.1, positive=cfg.implicit)
+    u, i, v, nu, ni = data
+    mesh = make_mesh(4)
+    dealt, U1, V1 = _sweeps(cfg, data, mesh=mesh)
+    assert bool(sum(dealt.lowrank_systems["user"].values())) \
+        == (mode == "lowrank")
+    # the dealing engaged: a looped group's chunks hold every shard's rows
+    caps = dealt.write_caps["user"]
+    assert min(cap / b for cap, b in caps) < 0.5
+    # staged as the parent staged: a pad width's rows in id order
+    monkeypatch.setattr(als, "_deal_order",
+                        lambda owner: np.arange(len(owner)))
+    plain = ALSTrainer((u, i, v), nu, ni, cfg, mesh=mesh)
+    assert [b[0].shape for b in plain._user_side["buckets"]] \
+        == [b[0].shape for b in dealt._user_side["buckets"]]
+    assert any(
+        not np.array_equal(np.asarray(a[0]), np.asarray(b[0]))
+        for a, b in zip(plain._user_side["buckets"],
+                        dealt._user_side["buckets"]))
+    user_half = _parents_half(plain, plain._user_side)
+    item_half = _parents_half(plain, plain._item_side)
+    U, V = plain.init_factors()
+    for _ in range(2):
+        U = user_half(U, V)
+        V = item_half(V, U)
+    np.testing.assert_array_equal(U1, np.asarray(U)[:nu])
+    np.testing.assert_array_equal(V1, np.asarray(V)[:ni])
+
+
+def _check_lists(rows, own_pos, own_row, shard_n, d):
+    """Every real row of the group is in exactly one owner's list,
+    exactly once, and it is its owner's; a list's padding carries
+    distinct ids past the shard's rows; `cap` is the most any shard
+    owns of any chunk, rounded up to the mesh."""
+    n, b = rows.shape
+    assert own_pos.shape == own_row.shape and own_pos.shape[:2] == (n, d)
+    cap = own_pos.shape[2]
+    most = 0
+    for c in range(n):
+        seen = np.zeros(b, np.int64)
+        for s in range(d):
+            real = own_row[c, s] < shard_n
+            most = max(most, int(real.sum()))
+            pos = own_pos[c, s][real]
+            seen[pos] += 1
+            # the place's row is this shard's, under its local id
+            np.testing.assert_array_equal(
+                rows[c, pos], own_row[c, s][real] + s * shard_n)
+            pad = own_row[c, s][~real]
+            assert len(set(pad.tolist())) == len(pad)
+            assert (pad >= shard_n).all()
+            assert len(set(own_row[c, s].tolist())) == cap
+            assert ((own_pos[c, s] >= 0) & (own_pos[c, s] < b)).all()
+        np.testing.assert_array_equal(seen, rows[c] < d * shard_n)
+    assert cap == -(-max(most, 1) // d) * d
+    return cap
+
+
+@pytest.mark.parametrize("shards", [4, 8])
+@pytest.mark.parametrize("layout", ["dealt", "one_shard", "id_order"])
+def test_owner_lists_hold_every_real_row_once(layout, shards):
+    rng = np.random.default_rng(shards)
+    shard_n, b, n = 1000, 64, 5
+    table_rows = shards * shard_n
+    if layout == "one_shard":
+        ids = np.sort(rng.choice(shard_n, n * b - 9, replace=False)) + shard_n
+    else:
+        ids = np.sort(rng.choice(table_rows, n * b - 9, replace=False))
+    if layout == "dealt":
+        ids = ids[als._deal_order(ids // shard_n)]
+    rows = table_rows + np.tile(np.arange(b), n)        # batch padding
+    rows[: len(ids)] = ids
+    rows = rows.reshape(n, b).astype(np.int32)
+    own_pos, own_row = als._owner_lists(rows, shard_n, shards)
+    assert own_pos.dtype == own_row.dtype == np.int32
+    cap = _check_lists(rows, own_pos, own_row, shard_n, shards)
+    if layout == "dealt":
+        assert cap <= b // shards + shards
+    if layout == "one_shard":
+        assert cap == b
+
+
+@pytest.mark.parametrize("shards", [4, 8])
+def test_deal_order_gives_every_chunk_each_owners_share(shards):
+    """Every run of B consecutive rows in the dealt order holds each
+    owner's rows in its share of the bucket, to a row or two, whatever
+    the shares; an owner's rows keep their order."""
+    rng = np.random.default_rng(7)
+    held = rng.integers(0, 4000, size=shards)
+    held[1] = 0                                  # a shard with no row
+    owner = np.repeat(np.arange(shards), held)
+    order = als._deal_order(owner)
+    assert sorted(order.tolist()) == list(range(len(owner)))
+    dealt = owner[order]
+    for s in range(shards):
+        assert (np.diff(order[dealt == s]) > 0).all()
+    b = 256
+    for lo in range(0, len(owner) - b, b):
+        got = np.bincount(dealt[lo: lo + b], minlength=shards)
+        assert (np.abs(got - b * held / held.sum()) <= 2).all()
+    np.testing.assert_array_equal(
+        als._deal_order(np.zeros(50, np.int64)), np.arange(50))
+
+
+@pytest.mark.parametrize("shards", [4, 8])
+def test_staged_lists_are_the_groups_owners(monkeypatch, shards):
+    """What `_stage_chunk_groups` stages beside every group: the lists
+    of THAT group's rows, a shard its own `[n, 1, cap]`."""
+    monkeypatch.setattr(als, "MAX_ENTRIES_PER_BUCKET", 64)
+    u, i, v, nu, ni = _ratings()
+    cfg = ALSConfig(rank=6, min_bucket_k=4, factor_placement="sharded")
+    tr = ALSTrainer((u, i, v), nu, ni, cfg, mesh=make_mesh(shards))
+    for side, pad in ((tr._user_side, tr._pad_users),
+                      (tr._item_side, tr._pad_items)):
+        assert len(side["owners"]) == len(side["buckets"])
+        caps = []
+        for (rows, *_), (own_pos, own_row) in zip(side["buckets"],
+                                                  side["owners"]):
+            for a in (own_pos, own_row):
+                assert {s.data.shape for s in a.addressable_shards} \
+                    == {(a.shape[0], 1, a.shape[2])}
+            caps.append([_check_lists(
+                np.asarray(rows), np.asarray(own_pos), np.asarray(own_row),
+                pad // shards, shards), rows.shape[1]])
+        name = "user" if side is tr._user_side else "item"
+        assert tr.write_caps[name] == caps
+        assert tr.write_rows[name] == sum(
+            b[0].shape[0] * cap for b, (cap, _) in zip(side["buckets"], caps))
+
+
+def test_a_group_one_shard_owns_is_written_whole_and_solved(monkeypatch):
+    """Users 0-29 (all in shard 0 of 4 x 30) rate 9-16 items, the other
+    90 rate 1-4: the K = 16 group's rows all live in one shard, its
+    `cap` is B, and every one of its rows is solved as the replicated
+    half solves it."""
+    rng = np.random.default_rng(11)
+    nu, ni = 120, 40
+    per_user = np.concatenate([rng.integers(9, 17, 30),
+                               rng.integers(1, 5, 90)])
+    u = np.repeat(np.arange(nu), per_user).astype(np.int32)
+    i = np.concatenate([rng.choice(ni, c, replace=False)
+                        for c in per_user]).astype(np.int32)
+    v = rng.normal(size=len(u)).astype(np.float32)
+    data = (u, i, v, nu, ni)
+    base = dict(rank=6, lam=0.05, min_bucket_k=4)
+    _, U0, V0 = _sweeps(ALSConfig(**base), data)
+    tr, U1, V1 = _sweeps(ALSConfig(**base, factor_placement="sharded"),
+                         data, mesh=make_mesh(4))
+    by_k = dict(zip(tr._user_side["ks"], tr.write_caps["user"]))
+    assert by_k[16][0] == by_k[16][1]             # cap == B: one owner
+    assert by_k[4][0] <= by_k[4][1] // 3 + 4      # dealt over three owners
+    np.testing.assert_allclose(U1, U0, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(V1, V0, rtol=1e-5, atol=1e-5)
+    U_init = np.asarray(tr.init_factors()[0])[:nu]
+    assert (np.abs(U1 - U_init).max(axis=1) > 0).all()
+
+
+def test_the_coded_half_still_freezes_a_masked_shards_rows():
+    """The coded half runs the same `solve_core` and write: with shard 2
+    masked, that shard's own rows stay as they were, and the others'
+    are the rows the unmasked half writes from the reconstructed
+    table (parity is current, so the reconstruction is exact)."""
+    u, i, v, nu, ni = _ratings()
+    cfg = ALSConfig(rank=6, min_bucket_k=4, factor_placement="sharded",
+                    coded_shards=True)
+    tr = ALSTrainer((u, i, v), nu, ni, cfg, mesh=make_mesh(4))
+    assert tr.coded
+    U, V = tr.init_factors()
+    flat = tr._sharded_operands(tr._user_side)
+
+    def half(ok):
+        return tr._sharded_user_half(
+            jnp.array(U), V, tr._parity_fn(V), jnp.asarray(ok, jnp.float32),
+            jnp.float32(0.1), jnp.float32(1.0), *flat)[0]
+
+    clean = np.asarray(half([1, 1, 1, 1]))
+    masked = np.asarray(half([1, 1, 0, 1]))
+    shard_n = tr._pad_users // 4
+    mine = slice(2 * shard_n, 3 * shard_n)
+    np.testing.assert_array_equal(masked[mine], np.asarray(U)[mine])
+    assert np.abs(clean[mine] - np.asarray(U)[mine]).max() > 0
+    others = np.r_[0: 2 * shard_n, 3 * shard_n: 4 * shard_n]
+    np.testing.assert_allclose(masked[others], clean[others],
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_write_rows_read_a_quarter_of_a_dealt_sides_rows(monkeypatch):
+    """`writeRows`, `writeCaps` and `pio_als_write_rows_total{side}`: on
+    four shards a chip scatters a quarter (and the lists' padding) of a
+    side whose rows are dealt, where the parent scattered all of them."""
+    from predictionio_tpu.obs import ALS_WRITE_ROWS_TOTAL, tower
+
+    events = []
+    monkeypatch.setattr(
+        tower, "note_event", lambda name, **f: events.append((name, f)))
+    monkeypatch.setattr(als, "MAX_ENTRIES_PER_BUCKET", 1024)
+    rng = np.random.default_rng(4)
+    nu, ni = 4000, 3000
+    per_user = rng.integers(1, 4, nu)
+    u = np.repeat(np.arange(nu), per_user).astype(np.int32)
+    i = rng.integers(0, ni, len(u)).astype(np.int32)
+    v = rng.normal(size=len(u)).astype(np.float32)
+    cfg = ALSConfig(rank=4, min_bucket_k=4, factor_placement="sharded")
+    tr = ALSTrainer((u, i, v), nu, ni, cfg, mesh=make_mesh(4))
+    (_, staged), = events
+    assert staged["writeRows"] == tr.write_rows
+    assert staged["writeCaps"] == tr.write_caps
+    for name, side in (("user", tr._user_side), ("item", tr._item_side)):
+        padded = sum(int(b[0].size) for b in side["buckets"])
+        assert 0.25 <= staged["writeRows"][name] / padded < 0.3, name
+        for (cap, b), (rows, *_) in zip(staged["writeCaps"][name],
+                                        side["buckets"]):
+            assert b == rows.shape[1]
+            if rows.shape[0] > 1:                 # a looped, dealt group
+                assert b // 4 <= cap <= b // 4 + 8
+    counters = {s: ALS_WRITE_ROWS_TOTAL.labels(side=s)
+                for s in ("user", "item")}
+    before = {s: c.value() for s, c in counters.items()}
+    U, V = tr.init_factors()
+    tr.run(U, V, 2)
+    for s, c in counters.items():
+        assert c.value() - before[s] == 2 * staged["writeRows"][s] > 0
+
+
+@pytest.mark.parametrize("mesh_size", [None, 4])
+def test_replicated_placement_stages_rows_in_id_order(monkeypatch, mesh_size):
+    """The dealing is the sharded staging's alone: a replicated trainer,
+    on one device or over a mesh, never asks for it, its pad widths'
+    rows ascend through their chunks as they did, and it stages no
+    owners' lists and scatters by none."""
+    def never(owner):
+        raise AssertionError("replicated placement dealt a bucket's rows")
+
+    monkeypatch.setattr(als, "_deal_order", never)
+    monkeypatch.setattr(als, "MAX_ENTRIES_PER_BUCKET", 64)
+    u, i, v, nu, ni = _ratings()
+    mesh = make_mesh(mesh_size) if mesh_size else None
+    tr = ALSTrainer((u, i, v), nu, ni, ALSConfig(rank=6, min_bucket_k=4),
+                    mesh=mesh)
+    assert "owners" not in tr._chunk_caps(mesh_size or 1)
+    counts = np.bincount(u, minlength=nu)
+    starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+    for side, n in ((tr._user_side, nu), (tr._item_side, ni)):
+        assert "owners" not in side
+        last = {}
+        for (rows, *_), k in zip(side["buckets"], side["ks"]):
+            real = np.asarray(rows)
+            real = real[real < n]
+            assert (np.diff(real) > 0).all()
+            assert real[0] > last.get(k, -1)
+            last[k] = real[-1]
+    assert tr.write_rows == {"user": 0, "item": 0}
+    assert tr.write_caps == {"user": [], "item": []}
+    want = _assemble_buckets(counts, starts, nu, 4, 0, mesh_size or 1,
+                             **tr._chunk_caps(mesh_size or 1))
+    assert _shapes(want) == [
+        (k, rows.shape[0])
+        for (rows, *_), k in zip(tr._user_side["buckets"],
+                                 tr._user_side["ks"])]
+    for b, (rows, *_) in zip(want, tr._user_side["buckets"]):
+        np.testing.assert_array_equal(b.rows, np.asarray(rows))
 
 
 # -- (c) chunks bounded by the bytes of their Gram --------------------------
@@ -500,8 +834,9 @@ def test_the_tracing_carries_the_exchange(monkeypatch):
         transient = 0
         for (rows, *_), k in zip(side["buckets"], side["ks"]):
             n, b = rows.shape[0], rows.shape[1] // d
-            # ids + partial rows in, solved rows + their ids back
-            want += n * (d - 1) * b * (k * (4 + r * 4) + r * 4 + 4)
+            # ids + partial rows in, solved rows back (their ids are
+            # staged: the owners' lists)
+            want += n * (d - 1) * b * (k * (4 + r * 4) + r * 4)
             transient = max(transient, (d + 1) * b * k * r * 4)
         assert staged["exchangeBytes"][which] == want > 0
         assert staged["oppTransientBytes"][which] == transient

@@ -12,9 +12,14 @@ score matrix.  A query's exclusions travel as item ids (``exclude``: one
 ``[B, E]`` int32 array padded with -1) and are applied on the device, on
 the blocked path to the gathered candidates (a short list) or to the
 maxima of the listed ids' own blocks before the blocks are chosen (a
-long one: a shopper's whole history); only a filter that is no list of
-ids (``categories``, ``whiteList``) still needs the ``[B, M]`` additive
-mask and with it the dense path.
+long one: a shopper's whole history).  A query's ``categories`` travel
+as category NUMBERS (``allow``: ``[B, C]`` int32 beside the model's
+resident bit rows, one bit an item a category): the device ORs a row's
+categories into one bit an item and the scan tests it on the scores
+before a block's maximum is taken, and again on the chosen blocks' items
+before the select.  Only a ``whiteList`` (and what the ladders do not
+hold) still needs the ``[B, M]`` additive mask and with it the dense
+path.
 """
 
 from __future__ import annotations
@@ -34,7 +39,8 @@ from .solve import pallas_interpret
 __all__ = ["topk_scores", "batch_topk_scores", "batch_topk_scores_t",
            "ItemTables", "pack_rows", "patch_packed_rows", "rows_per_line",
            "topk_path", "EXCLUDE_LADDER", "exclude_width", "listed_order",
-           "exclude_layout",
+           "exclude_layout", "Allowed", "CATEGORY_SLOTS", "allow_words",
+           "category_bit_rows", "grow_category_rows",
            "cosine_topk", "rerank_topk", "pow2_ceil"]
 
 TOPK_PATH = get_registry().counter(
@@ -43,8 +49,9 @@ TOPK_PATH = get_registry().counter(
     "batch_topk_scores_t) by the path their shapes chose: blocked "
     "(block scan, top-k over block maxima, rescoring of the chosen "
     "blocks), blocked_ids (the same with excluded ids applied to the "
-    "candidates on the device) or dense (matmul + top-k over the "
-    "whole row, masked or not)",
+    "candidates on the device), blocked_cats (the same with a row's "
+    "allowed items tested as bits inside the scan) or dense (matmul + "
+    "top-k over the whole row, masked or not)",
     labels=("path",),
 )
 
@@ -159,6 +166,23 @@ _PAIRWISE_EXCLUDE = EXCLUDE_LADDER[0]
 _BLOCK_ITEMS_LISTED = (32, 16, 8)
 
 
+# The width of the ``[B, C]`` array of a batch's category numbers: one
+# program a (B, k), so one width until a second is measured; a query that
+# names more categories takes the ``[B, M]`` mask.  A row costs the
+# device one resident bit row read a slot (`_allowed_words`), named or not.
+CATEGORY_SLOTS = 4
+
+# A row's allowed items are bits: bit g of the word at lane l of line
+# group w is item ``(32 w + g) * 128 + l``.  The scan's score tile keeps
+# items 128 apart on one lane, so the kernel tests a lane group's 128
+# scores with ONE shift of a ``[B, 128]`` vector of words it holds
+# anyway, whatever the block size (a block is `blk` consecutive bits of
+# a lane's words).
+_WORD_BITS = 32
+_WORD_ITEMS = _WORD_BITS * _LANES
+_WORD_LINES = 8     # lines of 128 words to one (8, 128) tile of a bit row
+
+
 def exclude_width(n_excluded: int) -> int:
     """The ladder's rung for a batch whose longest list of excluded ids
     has `n_excluded` entries; 0 where it has none or the ladder ends
@@ -166,6 +190,63 @@ def exclude_width(n_excluded: int) -> int:
     if n_excluded <= 0:
         return 0
     return next((e for e in EXCLUDE_LADDER if n_excluded <= e), 0)
+
+
+def allow_words(n_items: int) -> int:
+    """uint32 words to a row of allowed bits over `n_items` items: whole
+    tiles of 8 lines of 128 words, 4,096 items a line."""
+    return -(-n_items // (_WORD_ITEMS * _WORD_LINES)) * _WORD_LINES * _LANES
+
+
+def category_bit_rows(offsets, members, n_items: int, lo: int,
+                      hi: int) -> np.ndarray:
+    """``[hi - lo, allow_words(n_items)]`` uint32 (numpy, on the host):
+    the bit rows of categories ``lo .. hi - 1`` of a category-major index
+    (`members[offsets[c]:offsets[c + 1]]` the distinct item indices of
+    category c), in the layout the scan tests."""
+    n_words = allow_words(n_items)
+    ids = np.asarray(members[offsets[lo]:offsets[hi]], np.int64)
+    row = np.repeat(np.arange(hi - lo, dtype=np.int64),
+                    np.diff(offsets[lo:hi + 1]))
+    line = ids // _LANES
+    words = np.zeros((hi - lo) * n_words, np.uint32)
+    np.bitwise_or.at(
+        words, row * n_words + (line // _WORD_BITS) * _LANES + ids % _LANES,
+        np.uint32(1) << (line % _WORD_BITS).astype(np.uint32))
+    return words.reshape(hi - lo, n_words)
+
+
+def grow_category_rows(rows: jax.Array, n_items: int) -> jax.Array:
+    """The resident bit rows (:class:`Allowed`'s `rows`) after a table has
+    grown to `n_items` items: widened by whole tiles to
+    ``allow_words(n_items)`` words, zero bits for every category (an
+    appended item is in none) and set ones for the last row, every item.
+    The same array where it is wide enough already."""
+    tiles = allow_words(n_items) // (_WORD_LINES * _LANES) - rows.shape[1]
+    if tiles <= 0:
+        return rows
+    more = np.zeros((rows.shape[0], tiles, _WORD_LINES, _LANES), np.uint32)
+    more[-1] = ~np.uint32(0)
+    return jnp.concatenate([rows, jnp.asarray(more)], axis=1)
+
+
+class Allowed(NamedTuple):
+    """A batch's categories in the form the scorer takes
+    (``batch_topk_scores_t(allow=)``): `numbers` ``[B, C]`` int32, the
+    category numbers a row names (-1 for an empty slot; a row of -1 alone
+    allows every item), and `rows` ``[n + 2, allow_words(M) / 1024, 8,
+    128]`` uint32, the model's resident bit rows (`category_bit_rows`) of
+    its n categories, then a row of no item (number n: a name the model
+    does not know) and a row of every item (every bit set, the words'
+    padding past the catalogue too: the scan and the rescoring drop an id
+    past the table whatever its bit).  A row's words by whole
+    (8, 128) tiles, so that on the device a category's row is ONE
+    contiguous piece whatever layout the TPU would choose for a wide
+    2-D or 3-D array (it lays ``[n, W / 128, 128]`` out with the
+    categories on the sublanes)."""
+
+    numbers: jax.Array
+    rows: jax.Array
 
 
 class ItemTables(NamedTuple):
@@ -291,32 +372,41 @@ def _block_items_listed(batch: int, n_items: int, rank: int, k: int,
     return blk if n_items >= _BLOCKS_PER_K * k * blk else 0
 
 
-def _blocked_items(query_vecs, table_t, k: int, mask, exclude) -> int:
+def _blocked_items(query_vecs, table_t, k: int, mask, exclude,
+                   allow=None) -> int:
     """`block_items` of a call's arguments: 0 with a ``[B, M]`` mask
-    (it needs the ``[B, M]`` scores) or without the packed rows (the
-    rescoring gathers them)."""
+    (it needs the ``[B, M]`` scores), without the packed rows (the
+    rescoring gathers them), or with categories beside a list too long to
+    compare pairwise (the listed form re-reduces its blocks without the
+    allowed bits)."""
     if mask is not None or not isinstance(table_t, ItemTables):
+        return 0
+    width = 0 if exclude is None else exclude.shape[1]
+    if allow is not None and width > _PAIRWISE_EXCLUDE:
         return 0
     return block_items(
         query_vecs.shape[0], *table_t.shape[::-1], k,
-        table_t.packed.dtype.itemsize,
-        0 if exclude is None else exclude.shape[1])
+        table_t.packed.dtype.itemsize, width)
 
 
-def topk_path(query_vecs, table_t, k: int, mask=None, exclude=None) -> str:
+def topk_path(query_vecs, table_t, k: int, mask=None, exclude=None,
+              allow=None) -> str:
     """``"blocked"`` or ``"dense"``: what :func:`batch_topk_scores_t`
     does with these arguments, decided from their shapes alone."""
     return ("blocked" if _blocked_items(query_vecs, table_t, k, mask,
-                                        exclude) else "dense")
+                                        exclude, allow) else "dense")
 
 
 def _counted_path(query_vecs, table_t, k: int, mask=None,
-                  exclude=None) -> str:
+                  exclude=None, allow=None) -> str:
     """`pio_topk_path_total`'s label: the path, and on the blocked one
-    whether excluded ids rode along."""
-    path = topk_path(query_vecs, table_t, k, mask, exclude)
-    return "blocked_ids" if path == "blocked" and exclude is not None \
-        else path
+    whether categories or excluded ids rode along."""
+    path = topk_path(query_vecs, table_t, k, mask, exclude, allow)
+    if path != "blocked":
+        return path
+    if allow is not None:
+        return "blocked_cats"
+    return "blocked_ids" if exclude is not None else path
 
 
 def _mxu_operands() -> bool:
@@ -327,14 +417,19 @@ def _mxu_operands() -> bool:
 
 
 def _block_max_kernel(q_ref, t_ref, out_ref, *, n_items: int, blk: int,
-                      to_bf16: bool, row_major: bool = False):
+                      to_bf16: bool, row_major: bool = False,
+                      allow_ref=None):
     """One tile of the transposed table: ``[B, R] x [R, TM]`` on the MXU,
     then per super-block the elementwise maximum of its `blk` lane
     groups.  The table's ragged tail (the tile's columns >= n_items hold
     whatever the DMA left there) is masked here, on the scores.  With
     `row_major` the tile is ``[TM, R]`` of the row-major table and the
     product contracts both operands' last axis (6.36 ms either way over
-    9.35 M x 128; v5e)."""
+    9.35 M x 128; v5e).  With `allow_ref` (``[B, TM / 32]`` int32: the
+    tile's allowed bits, a tile begins on a word) a lane group's scores
+    are kept where their bit is set and -inf elsewhere, before the
+    maximum: one shift that brings the group's bit to the sign, one
+    compare, one select."""
     tm = t_ref.shape[0 if row_major else 1]
     sb = blk * _LANES
     op = jnp.bfloat16 if to_bf16 else jnp.float32
@@ -352,9 +447,21 @@ def _block_max_kernel(q_ref, t_ref, out_ref, *, n_items: int, blk: int,
         col0 = tile0 + c * sb
 
         def put(s):
-            best = s[:, :_LANES]
+            words = {}
+
+            def group(g):
+                x = s[:, g * _LANES:(g + 1) * _LANES]
+                if allow_ref is None:
+                    return x
+                w, bit = divmod(c * blk + g, _WORD_BITS)
+                if w not in words:
+                    words[w] = allow_ref[:, w * _LANES:(w + 1) * _LANES]
+                return jnp.where(
+                    (words[w] << (_WORD_BITS - 1 - bit)) < 0, x, -jnp.inf)
+
+            best = group(0)
             for g in range(1, blk):
-                best = jnp.maximum(best, s[:, g * _LANES:(g + 1) * _LANES])
+                best = jnp.maximum(best, group(g))
             out_ref[:, c * _LANES:(c + 1) * _LANES] = best
 
         @pl.when(col0 + sb <= n_items)
@@ -369,32 +476,53 @@ def _block_max_kernel(q_ref, t_ref, out_ref, *, n_items: int, blk: int,
 
 def block_maxima(query_vecs: jax.Array, table_t: jax.Array, blk: int,
                  interpret: bool | None = None,
-                 row_major: bool = False) -> jax.Array:
+                 row_major: bool = False,
+                 allow: jax.Array | None = None) -> jax.Array:
     """The scan as one Pallas kernel: ``[B, n_blocks]`` float32, the best
     score of each block, reading the table once and writing no score
     matrix.  ``interpret=None`` follows :func:`ops.solve.pallas_interpret`.
-    With `row_major` `table_t` is the ``[M, R]`` table itself."""
+    With `row_major` `table_t` is the ``[M, R]`` table itself.  With
+    `allow` (``[B, allow_words(M)]`` uint32) a block's best score is that
+    of its ALLOWED items, -inf where it holds none: the kernel reads a
+    tile's 1/32 word an item beside the tile."""
     if interpret is None:
         interpret = pallas_interpret()
     batch, rank = query_vecs.shape
     n_items = table_t.shape[0 if row_major else 1]
     sb = blk * _LANES
+    # with allowed bits a tile begins on a word of them
+    unit = sb if allow is None else max(sb, _WORD_ITEMS)
     per_col = rank * jnp.dtype(table_t.dtype).itemsize
-    tm = sb * max(1, _TILE_BYTES // per_col // sb)
-    tm = min(tm, sb * pl.cdiv(n_items, sb))
+    tm = unit * max(1, _TILE_BYTES // per_col // unit)
+    tm = min(tm, unit * pl.cdiv(n_items, unit))
     n_tiles = pl.cdiv(n_items, tm)
     rows = 8 * pl.cdiv(batch, 8)
     q = jnp.pad(query_vecs, ((0, rows - batch), (0, 0)))
     table_spec = (pl.BlockSpec((tm, rank), lambda j: (j, 0)) if row_major
                   else pl.BlockSpec((rank, tm), lambda j: (0, j)))
+    kernel = functools.partial(_block_max_kernel, n_items=n_items, blk=blk,
+                               to_bf16=_mxu_operands(), row_major=row_major)
+    operands = [q, table_t]
+    in_specs = [pl.BlockSpec((rows, rank), lambda j: (0, 0)), table_spec]
+    if allow is not None:
+        scores_alone = kernel
+
+        def kernel(q_ref, t_ref, allow_ref, out_ref):
+            scores_alone(q_ref, t_ref, out_ref, allow_ref=allow_ref)
+
+        # a last tile past the words' end reads what the DMA left there:
+        # those items lie past the catalogue and the kernel's tail mask
+        # drops them whatever their bits say
+        operands.append(jax.lax.bitcast_convert_type(
+            jnp.pad(allow, ((0, rows - batch), (0, 0))), jnp.int32))
+        in_specs.append(pl.BlockSpec((rows, tm // _WORD_BITS),
+                                     lambda j: (0, j)))
     out = pl.pallas_call(
-        functools.partial(_block_max_kernel, n_items=n_items, blk=blk,
-                          to_bf16=_mxu_operands(), row_major=row_major),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((rows, n_tiles * tm // blk),
                                        jnp.float32),
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((rows, rank), lambda j: (0, 0)),
-                  table_spec],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((rows, tm // blk), lambda j: (0, j)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
@@ -402,12 +530,27 @@ def block_maxima(query_vecs: jax.Array, table_t: jax.Array, blk: int,
         ),
         name="pio_block_max",
         interpret=interpret,
-    )(q, table_t)
+    )(*operands)
     return out[:batch]
 
 
+def _allowed_items(words: jax.Array, ids: jax.Array) -> jax.Array:
+    """Bool, of `ids`' shape: whether each item's bit is set in its row
+    of `words` (``[B, W]`` uint32; `ids` ``[n]`` for every row alike or
+    ``[B, n]``); False for an id past the words."""
+    line = ids // _LANES
+    at = (line // _WORD_BITS) * _LANES + ids % _LANES
+    inside = at < words.shape[1]
+    at = jnp.broadcast_to(jnp.where(inside, at, 0),
+                          (words.shape[0], ids.shape[-1]))
+    bit = (jnp.take_along_axis(words, at, axis=1)
+           >> (line % _WORD_BITS).astype(jnp.uint32)) & 1
+    return inside & bit.astype(bool)
+
+
 def block_maxima_jnp(query_vecs: jax.Array, table_t: jax.Array, blk: int,
-                     row_major: bool = False) -> jax.Array:
+                     row_major: bool = False,
+                     allow: jax.Array | None = None) -> jax.Array:
     """The same scan in plain ``jnp`` (the off-TPU form): the product is
     written and read back once, but no full-width top-k walks it."""
     batch = query_vecs.shape[0]
@@ -416,7 +559,12 @@ def block_maxima_jnp(query_vecs: jax.Array, table_t: jax.Array, blk: int,
     n_items = table_t.shape[1]
     sb = blk * _LANES
     n_sb = -(-n_items // sb)
-    scores = jnp.pad(query_vecs @ table_t,
+    scores = query_vecs @ table_t
+    if allow is not None:
+        scores = jnp.where(
+            _allowed_items(allow, jnp.arange(n_items, dtype=jnp.int32)),
+            scores, -jnp.inf)
+    scores = jnp.pad(scores,
                      ((0, 0), (0, n_sb * sb - n_items)),
                      constant_values=-jnp.inf)
     return scores.reshape(batch, n_sb, blk, _LANES).max(axis=2).reshape(
@@ -462,14 +610,78 @@ def _padded_queries(query_vecs, exclude):
     return query_vecs, exclude
 
 
-def _scan_maxima(query_vecs, tables: ItemTables, blk: int):
-    """``[B, n_blocks]`` block maxima of the unfiltered scores."""
+def _allowed_words(allow: Allowed, batch: int) -> jax.Array:
+    """``[batch, W]`` uint32: each row's allowed items, the OR of the
+    resident bit rows of the categories it names (its named slots come
+    first); the rows past the batch's own (the sublane padding) and a
+    row that names none allow every item.  One row of the batch at a
+    time, each a read of the resident rows it names and one write of its
+    own words, each one contiguous piece; then ONE re-lay of the
+    batch's words to rows on the sublanes, as the scan reads them.  A
+    gather of ``[B, C]`` rows this wide makes the TPU compiler copy the
+    whole resident array (its plan for a described v5e: 4.3 GB of
+    temporaries against 0.3 MB), and a row sliced out of a 2-D
+    ``[n, W]`` array moves in 512-byte pieces, 10 ns each (51 us a row
+    read and written; v5e)."""
+    numbers, rows = allow
+    numbers = jnp.pad(numbers, ((0, batch - numbers.shape[0]), (0, 0)),
+                      constant_values=-1)
+    nothing = rows.shape[0] - 2
+    named = numbers >= 0
+    at = jnp.where(named, numbers, nothing)
+    at = at.at[:, 0].set(jnp.where(named[:, 0], at[:, 0], nothing + 1))
+    several = named[:, 1:].any(axis=1)
+
+    def read(number):
+        return jax.lax.dynamic_index_in_dim(rows, number, 0, keepdims=True)
+
+    def one(mine):
+        return read(mine[0])
+
+    def every(mine):
+        return functools.reduce(jnp.bitwise_or, [
+            read(mine[slot]) for slot in range(1, mine.shape[0])],
+            read(mine[0]))
+
+    def put(row, words):
+        return jax.lax.dynamic_update_slice_in_dim(
+            words, jax.lax.cond(several[row], every, one, at[row]), row, 0)
+
+    words = jax.lax.fori_loop(
+        0, batch, put, jnp.zeros((batch, *rows.shape[1:]), rows.dtype))
+    return words.reshape(batch, -1)
+
+
+def _allowed_in_blocks(words, blocks, blk: int):
+    """``[B, P * blk]`` bool: item by item of the ``[B, P]`` blocks
+    (`_block_item_ids`' order), whether its bit is set in the row's
+    `words`.  A block's items are consecutive bits of its lane's words,
+    so a block reads one word (two at 64 items), not one an item."""
+    line0 = (blocks // _LANES) * blk            # the block's first line
+    n_words = max(1, blk // _WORD_BITS)
+    at = ((line0 // _WORD_BITS)[:, :, None]
+          + jnp.arange(n_words, dtype=jnp.int32)) * _LANES \
+        + (blocks % _LANES)[:, :, None]
+    got = jnp.take_along_axis(
+        words, jnp.minimum(at, words.shape[1] - 1).reshape(
+            blocks.shape[0], -1), axis=1).reshape(*blocks.shape, n_words)
+    line = line0[:, :, None] + jnp.arange(blk, dtype=jnp.int32)
+    word = jnp.repeat(got, blk // n_words, axis=2)
+    bit = (word >> (line % _WORD_BITS).astype(jnp.uint32)) & 1
+    return bit.astype(bool).reshape(blocks.shape[0], -1)
+
+
+def _scan_maxima(query_vecs, tables: ItemTables, blk: int, allow=None):
+    """``[B, n_blocks]`` block maxima of the scores: the unfiltered ones,
+    or with `allow` (``[B, W]`` words) those of each block's allowed
+    items."""
     # the table the scan streams: transposed, or the rows themselves
     row_major = tables.t is None
     scanned = tables.packed if row_major else tables.t
     with jax.named_scope("topk.scan"):
         scan = block_maxima if _mxu_operands() else block_maxima_jnp
-        return scan(query_vecs, scanned, blk, row_major=row_major)
+        return scan(query_vecs, scanned, blk, row_major=row_major,
+                    allow=allow)
 
 
 def _block_item_ids(blocks, blk: int):
@@ -505,24 +717,37 @@ def _rescore(query_vecs, tables: ItemTables, ids):
 
 
 def _blocked_topk(query_vecs, tables: ItemTables, k: int, blk: int,
-                  exclude: jax.Array | None = None):
+                  exclude: jax.Array | None = None,
+                  allow: Allowed | None = None):
     """Exact top-k of the allowed items.  A row with e excluded ids finds
     its k best allowed items among the k + e blocks with the largest
     UNMASKED maxima: a block that outranks the k-th allowed score and
     holds none of the k has an excluded item as its maximum, and there
     are at most e of those.  So the scan is the unfiltered one, ``k + E``
     blocks are chosen, and the exclusions are applied to the gathered
-    candidates before the select."""
+    candidates before the select.  With `allow` (a row's categories) a
+    block's maximum is that of the items the categories allow, tested as
+    bits inside the scan, and the same argument holds among those items;
+    the chosen blocks' other items are dropped by the same bits before
+    the select, so a row that allows fewer than k answers fewer."""
     n_queries = query_vecs.shape[0]
     n_blocks = k if exclude is None else k + exclude.shape[1]
     query_vecs, exclude = _padded_queries(query_vecs, exclude)
-    maxima = _scan_maxima(query_vecs, tables, blk)
+    words = None
+    if allow is not None:
+        with jax.named_scope("topk.allow_bits"):
+            words = _allowed_words(allow, query_vecs.shape[0])
+    maxima = _scan_maxima(query_vecs, tables, blk, words)
     with jax.named_scope("topk.blocks"):
         # ties go to the lower block index (lax.top_k is stable)
         _, chosen = jax.lax.top_k(maxima, n_blocks)
     with jax.named_scope("topk.rescore"):
         ids = _block_item_ids(chosen, blk)
         scores = _rescore(query_vecs, tables, ids)
+    if words is not None:
+        with jax.named_scope("topk.allow"):
+            scores = jnp.where(_allowed_in_blocks(words, chosen, blk),
+                               scores, -jnp.inf)
     if exclude is not None:
         with jax.named_scope("topk.exclude"):
             scores = jnp.where(_excluded(ids, exclude), -jnp.inf, scores)
@@ -682,7 +907,8 @@ def _blocked_topk_listed(query_vecs, tables: ItemTables, k: int, blk: int,
 def batch_topk_scores_t(query_vecs: jax.Array,
                         table_t: jax.Array | ItemTables, k: int,
                         mask: jax.Array | None = None,
-                        exclude: jax.Array | None = None):
+                        exclude: jax.Array | None = None,
+                        allow: Allowed | None = None):
     """[B, R] x [R, M] (PRE-TRANSPOSED table) -> top-k per row:
     ``([B, k] float32 descending, [B, k] int32 item ids)``.
 
@@ -696,23 +922,42 @@ def batch_topk_scores_t(query_vecs: jax.Array,
     precision and the excluded ones dropped; exact (:func:`_blocked_topk`).
     A list wider than 32 ids has its own blocks reduced again instead and
     k blocks chosen (:func:`_blocked_topk_listed`), exact too; on that
-    path each row's ids come in :func:`listed_order`.
+    path each row's ids come in :func:`listed_order`.  ``allow``
+    (:class:`Allowed`: a row's category numbers beside the model's
+    resident bit rows) keeps a row's answer to the items that carry one
+    of its categories, tested as bits inside the scan and on the chosen
+    blocks, with up to 32 excluded ids beside them.
     With a mask (additive, ``[B, M]``), a short catalogue or a large k it
     is the dense ``query_vecs @ table_t`` + ``lax.top_k``, the excluded
     ids scattered into the scores.  Serving keeps the transposed device
     copy (``DeviceTableMixin.device_item_factors_t``), so the hot path
     pays the transpose once per model advance."""
-    blk = _blocked_items(query_vecs, table_t, k, mask, exclude)
+    if allow is not None:
+        n_items = table_t.shape[1]
+        covered = allow.rows.shape[1] * _WORD_LINES * _WORD_ITEMS
+        if covered < n_items:
+            raise ValueError(
+                f"the category rows cover {covered} items of the table's "
+                f"{n_items}: they have not followed it")
+    blk = _blocked_items(query_vecs, table_t, k, mask, exclude, allow)
     if blk:   # from shapes alone  # piolint: disable=PIO104
         if exclude is not None and exclude.shape[1] > _PAIRWISE_EXCLUDE:
             return _blocked_topk_listed(query_vecs, table_t, k, blk, exclude)
-        return _blocked_topk(query_vecs, table_t, k, blk, exclude)
+        return _blocked_topk(query_vecs, table_t, k, blk, exclude, allow)
     if isinstance(table_t, ItemTables):
         table_t = table_t.packed.T if table_t.t is None else table_t.t
     with jax.named_scope("topk.scores"):
         scores = query_vecs @ table_t
         if mask is not None:
             scores = scores + mask
+    if allow is not None:
+        with jax.named_scope("topk.allow_bits"):
+            words = _allowed_words(allow, scores.shape[0])
+        with jax.named_scope("topk.allow"):
+            scores = jnp.where(
+                _allowed_items(words, jnp.arange(scores.shape[1],
+                                                 dtype=jnp.int32)),
+                scores, -jnp.inf)
     if exclude is not None:
         with jax.named_scope("topk.exclude"):
             # -1 becomes an index past the row, which the scatter drops

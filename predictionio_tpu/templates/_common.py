@@ -2,27 +2,41 @@
 
 from __future__ import annotations
 
+import logging
 import time
 from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
-from ..obs import get_registry, log_buckets
+from ..obs import get_registry, log_buckets, tower
 from ..obs.timeline import annotate
 
-__all__ = ["BatchFilter", "DeviceTableMixin", "RowFilter", "batch_filter",
-           "filter_bias_mask", "normalize_rows", "pow2_ladder",
-           "warm_batched_topk", "warm_shapes"]
+__all__ = ["BatchFilter", "CategoryIndex", "DeviceTableMixin", "RowFilter",
+           "batch_filter", "filter_bias_mask", "normalize_rows",
+           "pow2_ladder", "props_without_categories", "warm_batched_topk",
+           "warm_shapes"]
+
+logger = logging.getLogger(__name__)
 
 _registry = get_registry()
 FILTER_ROWS = _registry.counter(
     "pio_filter_rows_total",
     "Rows of the batches the templates' batch_predict dispatched, by the "
     "form their batch's filters took: none, ids (excluded item ids "
-    "applied on the device) or mask (a [B, M] additive mask built on "
-    "the host: categories, a whiteList, or a list past the ids' width)",
+    "applied on the device), cats (category numbers tested on the device "
+    "against the model's resident category index, with up to 32 excluded "
+    "ids) or mask (a [B, M] additive mask built on the host: a "
+    "whiteList, categories of a model whose index is not on the device "
+    "or beside a list of more than 32 ids or more than 4 names, "
+    "or a list past the ids' width)",
     labels=("filter",),
 )
+FILTER_CATEGORY_IDS = _registry.counter(
+    "pio_filter_category_ids_total",
+    "Category numbers dispatched to the device (the numbers arrays' real "
+    "entries, a name the model does not know among them, their -1 "
+    "padding left out)",
+).child()
 FILTER_EXCLUDE_WIDTH = _registry.counter(
     "pio_filter_exclude_width_total",
     "Batches dispatched with excluded ids, by the width of their ids "
@@ -51,6 +65,157 @@ def normalize_rows(table: np.ndarray) -> np.ndarray:
     retriever serve cosine with no per-query normalization."""
     t = np.asarray(table, np.float32)
     return t / (np.linalg.norm(t, axis=-1, keepdims=True) + 1e-9)
+
+
+class CategoryIndex:
+    """The items' ``categories`` property as arrays, category-major: a
+    vocabulary of names (a category's number is its place in `names`) and
+    each category's distinct item indices, ascending
+    (``members[offsets[c]:offsets[c + 1]]``).  The train's snapshot of the
+    items' properties, 4 bytes a membership on the host; the ONE owner of
+    what serving reads of the items' ``categories``, empty where no item
+    has any.  It holds no length of the catalogue: an item a live fold-in
+    appends is in no category, and the callers say how many items there
+    are now.  On the device it is one bit row a category
+    (:meth:`DeviceTableMixin.device_category_rows`)."""
+
+    def __init__(self, names=(), offsets=(0,), members=()):
+        self.names = np.asarray(names, dtype=str)
+        self.offsets = np.asarray(offsets, np.int64)
+        self.members = np.asarray(members, np.int32)
+        # cleared by `DeviceTableMixin.device_category_rows` where the
+        # device has no room for the bit rows: `batch_filter` then sends
+        # `categories` to the mask, which reads the lists here
+        self.resident = True
+        self._number = {name: j for j, name in enumerate(self.names.tolist())}
+
+    @classmethod
+    def from_memberships(cls, names, category, item) -> "CategoryIndex":
+        """From parallel arrays: membership j says item `item[j]` carries
+        category number `category[j]` (a pair given twice counts once)."""
+        item = np.asarray(item, np.int64)
+        span = int(item.max()) + 1 if len(item) else 1
+        key = np.unique(np.asarray(category, np.int64) * span + item)
+        offsets = np.searchsorted(key // span, np.arange(len(names) + 1))
+        return cls(names, offsets, key % span)
+
+    @classmethod
+    def from_props(cls, items, item_props) -> "CategoryIndex":
+        """From the items' property dicts, one pass; empty where no item
+        the model knows carries a category."""
+        number: dict = {}
+        category, item = [], []
+        for item_id, props in (item_props or {}).items():
+            ix = items.get(item_id)
+            if ix < 0:
+                continue
+            for name in props.get("categories") or ():
+                category.append(number.setdefault(str(name), len(number)))
+                item.append(ix)
+        return cls.from_memberships(list(number), category, item)
+
+    def __len__(self) -> int:
+        return len(self.names)
+
+    @property
+    def memberships(self) -> int:
+        return len(self.members)
+
+    @property
+    def nbytes(self) -> int:
+        return self.names.nbytes + self.offsets.nbytes + self.members.nbytes
+
+    def number(self, name: str) -> int:
+        """A category's number; ``len(self)``, the number of no item, for
+        a name the model does not know."""
+        return self._number.get(name, len(self))
+
+    def allowed(self, names, n_items: int) -> np.ndarray:
+        """``[n_items]`` bool: the items that carry one of `names`, of a
+        catalogue that holds `n_items` now."""
+        out = np.zeros(n_items, bool)
+        for c in {self.number(name) for name in names} - {len(self)}:
+            out[self.members[self.offsets[c]:self.offsets[c + 1]]] = True
+        return out
+
+    def arrays(self) -> dict:
+        """What persists the index beside a model's tables (``np.savez``)."""
+        return {"category_names": self.names,
+                "category_offsets": self.offsets,
+                "category_members": self.members}
+
+    @classmethod
+    def from_arrays(cls, data) -> Optional["CategoryIndex"]:
+        """The index `arrays` wrote; None for a file from before it."""
+        if "category_names" not in data:
+            return None
+        return cls(data["category_names"], data["category_offsets"],
+                   data["category_members"])
+
+
+def props_without_categories(item_props) -> dict:
+    """The items' property dicts with ``categories`` taken out, and no
+    entry for an item that has nothing else: what a model keeps beside
+    its :class:`CategoryIndex`, which owns the categories."""
+    return {item_id: rest for item_id, fields in (item_props or {}).items()
+            if (rest := {k: v for k, v in fields.items()
+                         if k != "categories"})}
+
+
+_CATEGORY_ROWS_PIECE = 256 << 20   # bytes of bit rows built and sent at once
+# The share of the device's memory that the bit rows leave free beside
+# what is resident when they are built (the item table): 2.1 GB of a
+# v5e's 16.9, for one piece in flight (0.27 GB) and the temporaries of the
+# widest program a server warms (0.3 GB a 64-row category batch over
+# 9.35 M items, 1.1 GB the 4,224-id rung; PERF.md, PR 40 and 42).
+_CATEGORY_ROWS_SPARE = 1 / 8
+_NO_CATEGORIES = CategoryIndex()
+
+
+def _device_free_bytes() -> Optional[tuple]:
+    """``(free, limit)`` bytes of the first local device's memory; None
+    where the backend keeps no such count (the CPU)."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return None
+    return (stats["bytes_limit"] - stats.get("bytes_in_use", 0),
+            stats["bytes_limit"])
+
+
+def _category_rows_on_device(index: CategoryIndex, n_items: int):
+    """`DeviceTableMixin.device_category_rows`' array: `index`'s bit rows
+    over `n_items` items, built from its lists a piece at a time on the
+    host and written into place on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.topk import allow_words, category_bit_rows
+
+    n, width = len(index), allow_words(n_items)
+    step = min(n + 2, max(2, _CATEGORY_ROWS_PIECE // (4 * width)))
+    put = jax.jit(
+        lambda rows, piece, lo: jax.lax.dynamic_update_slice_in_dim(
+            rows, piece, lo, 0), donate_argnums=0)
+    tiled = (width // 1024, 8, 128)      # a row by (8, 128) tiles
+    dev = jnp.zeros((n + 2, *tiled), jnp.uint32)
+    for lo in range(0, n + 2, step):
+        lo = min(lo, n + 2 - step)     # pieces of one shape
+        piece = np.zeros((step, width), np.uint32)
+        named = min(lo + step, n)
+        if named > lo:
+            piece[:named - lo] = category_bit_rows(
+                index.offsets, index.members, n_items, lo, named)
+        if lo + step == n + 2:
+            # every item, and the words' padding past the catalogue: the
+            # scan drops what lies past the table whatever its bit
+            piece[-1] = ~np.uint32(0)
+        # a piece at a time ON the device too: uploads queued ahead of
+        # their writes held up to 2 GB more than the rows (v5e)
+        dev = jax.block_until_ready(
+            put(dev, piece.reshape(step, *tiled), lo))
+    return dev
 
 
 class DeviceTableMixin:
@@ -116,8 +281,16 @@ class DeviceTableMixin:
                 np.linalg.norm(a, axis=-1, keepdims=True) + 1e-9
             )
 
-        from ..ops.topk import patch_packed_rows
+        from ..ops.topk import grow_category_rows, patch_packed_rows
 
+        if app_np is not None and \
+                vars(self).get("_dev_category_rows") is not None:
+            # appended items are in no category: the resident rows follow
+            # the table's length (zero bits; the row of every item grows
+            # by set ones), BEFORE the tables grow: a concurrent scorer
+            # may meet rows wider than its table, never narrower
+            self._dev_category_rows = grow_category_rows(
+                self._dev_category_rows, len(self.item_factors))
         # the host table is the patched one already (see above)
         n_before = len(self.item_factors) - (
             0 if app_np is None else len(app_np)
@@ -215,6 +388,66 @@ class DeviceTableMixin:
             setattr(self, key, packed)
         return ItemTables(table_t, packed)
 
+    # the train's snapshot of the items' `categories`; a model class
+    # declares it as a field, and `categories` fills it where it is None
+    category_index = None
+
+    def categories(self) -> CategoryIndex:
+        """The model's :class:`CategoryIndex`.  A model that came without
+        one (built by hand; a file from before the index) gets it here,
+        once, from its items' property dicts: empty where they name no
+        category."""
+        if self.category_index is None:
+            self.category_index = CategoryIndex.from_props(
+                self.items, getattr(self, "item_props", None))
+        return self.category_index
+
+    def serving_categories(self) -> CategoryIndex:
+        """:meth:`categories` as :func:`batch_filter` takes it in a turn:
+        its bit rows on the device, or found not to fit and the index
+        marked so (`resident`)."""
+        self.device_category_rows()
+        return self.categories()
+
+    def device_category_rows(self):
+        """The model's category index resident on the device beside the
+        item table: ``[n + 2, allow_words(M) / 1024, 8, 128]`` uint32, one
+        bit an item a category in the layout the blocked scan tests
+        (``ops.topk.category_bit_rows``), then a row of no item and a row
+        of every item (``ops.topk.Allowed``); M / 8 bytes a category, M
+        the item table's length (:meth:`patch_device_item_rows` keeps it
+        so).  Built once a model (re)load.  None where the index is
+        empty, or where the rows would not leave `_CATEGORY_ROWS_SPARE`
+        of the device's memory free beside what it holds: the index then
+        stays on the host, marked not `resident`, and `categories` take
+        :func:`batch_filter`'s counted mask, which reads its lists."""
+        from ..ops.topk import allow_words
+
+        if "_dev_category_rows" in vars(self):
+            return self._dev_category_rows
+        index = self.categories()
+        t0 = time.perf_counter()
+        n, n_items = len(index), len(self.item_factors)
+        facts = {"categories": n, "memberships": index.memberships,
+                 "categoryIndexBytes": 4 * (n + 2) * allow_words(n_items)}
+        dev, room = None, _device_free_bytes() if n else None
+        if room is not None and facts["categoryIndexBytes"] \
+                > room[0] - _CATEGORY_ROWS_SPARE * room[1]:
+            logger.warning(
+                "category index kept on the host: its bit rows (%d bytes) "
+                "do not fit the device (%d bytes free of %d); `categories` "
+                "take the [B, M] mask", facts["categoryIndexBytes"], *room)
+            facts["categoryIndexBytes"] = 0
+        elif n:
+            dev = _category_rows_on_device(index, n_items)
+        self._dev_category_rows, index.resident = dev, dev is not None
+        if n:
+            tower.note_event("category_index", **facts)
+            logger.info("category index %s in %.1fs: %s",
+                        "on the host" if dev is None else "resident",
+                        time.perf_counter() - t0, facts)
+        return dev
+
     def device_ann_index(self, cfg):
         """Lazy per-config two-stage ANN retriever (pio-scout), cached
         on the model like the device tables: int8 table + scale (+
@@ -268,7 +501,7 @@ class DeviceTableMixin:
 
 def filter_bias_mask(
     items,
-    item_props: Optional[dict] = None,
+    index=None,
     *,
     categories=None,
     whitelist=None,
@@ -279,8 +512,11 @@ def filter_bias_mask(
     """Additive -inf bias over the item table for query-side filtering —
     the shared core of the filter-by-category / whitelist / blacklist
     template variants (plus query-item exclusion for similar-item
-    queries).  ``none_if_empty=True`` returns None when no filter is
-    active so callers can dispatch the cheaper unbiased scorer.
+    queries).  `index` is the model's :class:`CategoryIndex`, whose lists
+    name a category's items; without one no item carries a category.
+    ``none_if_empty=True`` returns None
+    when no filter is active so callers can dispatch the cheaper
+    unbiased scorer.
     """
     import numpy as np
 
@@ -296,13 +532,7 @@ def filter_bias_mask(
         allowed &= np.isin(items.ids.astype(str),
                            np.array(sorted(whitelist), dtype=str))
     if categories:
-        cats = set(categories)
-        has = np.zeros(n, dtype=bool)
-        for item_id, props in (item_props or {}).items():
-            ix = items.get(item_id)
-            if ix >= 0 and cats & set(props.get("categories", [])):
-                has[ix] = True
-        allowed &= has
+        allowed &= (index or _NO_CATEGORIES).allowed(categories, n)
     if blacklist:
         allowed &= ~np.isin(items.ids.astype(str),
                             np.array(sorted(blacklist), dtype=str))
@@ -324,50 +554,78 @@ class RowFilter(NamedTuple):
 class BatchFilter(NamedTuple):
     """A batch's filters in the form the scorer takes
     (``ops.topk.batch_topk_scores_t``): `kind` ``"none"``, ``"ids"``
-    (`exclude`: ``[B, E]`` int32 item indices, -1 for none) or ``"mask"``
-    (`mask`: ``[B, M]`` float32, additive)."""
+    (`exclude`: ``[B, E]`` int32 item indices, -1 for none), ``"cats"``
+    (`categories`: ``[B, CATEGORY_SLOTS]`` int32 category numbers, a row's
+    named slots first, -1 for none, beside an `exclude` of the first rung) or
+    ``"mask"`` (`mask`: ``[B, M]`` float32, additive)."""
 
     kind: str
     exclude: Optional[np.ndarray] = None
     mask: Optional[np.ndarray] = None
+    categories: Optional[np.ndarray] = None
 
     @property
     def width(self) -> int:
         """The ids array's width (its rung of the ladder); 0 without."""
         return 0 if self.exclude is None else self.exclude.shape[1]
 
-    def scorer_kwargs(self) -> dict:
+    @property
+    def category_rows(self) -> int:
+        """Rows of the batch that name a category; 0 without."""
+        return 0 if self.categories is None \
+            else int((self.categories[:, 0] >= 0).sum())
+
+    def scorer_kwargs(self, model=None) -> dict:
         """The scorer's keyword arguments: `mask` as its callers have
         always passed it, `exclude` only where there are ids (a stand-in
-        for the scorer written before it took ids keeps working)."""
+        for the scorer written before it took ids keeps working),
+        `allow` only where there are categories: their numbers beside
+        `model`'s resident bit rows."""
+        from ..ops.topk import Allowed
+
         if self.exclude is None:
             return {"mask": self.mask}
-        return {"mask": self.mask, "exclude": self.exclude}
+        if self.categories is None:
+            return {"mask": self.mask, "exclude": self.exclude}
+        return {"mask": self.mask, "exclude": self.exclude,
+                "allow": Allowed(self.categories,
+                                 model.device_category_rows())}
 
 
-def batch_filter(items, item_props: Optional[dict],
+def batch_filter(items, index,
                  rows: Sequence[Optional[RowFilter]]) -> BatchFilter:
     """Filters as data.  Each row's excluded items (its `exclude_ix` and
     the `blacklist` ids the model knows, a hash lookup an id) go into one
     ``[B, E]`` array for the device, E the rung of
     ``ops.topk.EXCLUDE_LADDER`` that holds the batch's longest list; no
-    array of the catalogue's length is built.  Only a batch that holds
-    a row with `categories` or a `whitelist`, or more excluded ids than
-    the ladder's last rung, takes the ``[B, M]`` mask
-    (:func:`filter_bias_mask` a row).  A row that is None (a query that
-    will not be answered) filters nothing."""
-    from ..ops.topk import exclude_layout, exclude_width
+    array of the catalogue's length is built.  `index` is the model's
+    :class:`CategoryIndex` (none, or an empty one: no item carries a
+    category).  Where its bit rows are `resident` on the device
+    (:meth:`DeviceTableMixin.serving_categories`), a row's `categories`
+    go as category NUMBERS (a hash lookup a name) into one
+    ``[B, CATEGORY_SLOTS]`` array beside the ids, for the device to test
+    against them.  The ``[B, M]`` mask (:func:`filter_bias_mask` a row,
+    which reads the index's lists) is left for a batch that holds a row
+    with a `whitelist`; `categories` without resident rows, beside more
+    excluded ids than the ladder's first rung, or more of them than
+    ``ops.topk.CATEGORY_SLOTS``; or more excluded ids than the ladder's
+    last rung.  A row that is None (a query that will not be answered)
+    filters nothing."""
+    from ..ops.topk import CATEGORY_SLOTS, exclude_layout, exclude_width
 
+    index = index or _NO_CATEGORIES
+    resident = index.resident and len(index) > 0
     t0 = time.perf_counter()
     with annotate("pio.filter.build"):
-        lists, by_ids = [], True
+        lists, by_ids, named = [], True, 0
         for row in rows:
             if row is None:
                 lists.append(())
                 continue
-            if row.categories or row.whitelist:
+            if row.whitelist or (row.categories and not resident):
                 by_ids = False
                 break
+            named = max(named, len(row.categories or ()))
             found = [ix for ix in map(items.get, row.blacklist or ())
                      if ix >= 0]
             if isinstance(row.exclude_ix, np.ndarray):
@@ -379,8 +637,16 @@ def batch_filter(items, item_props: Optional[dict],
                 lists.append(tuple(dict.fromkeys(
                     [*row.exclude_ix, *found])))
         longest = max(map(len, lists), default=0)
-        width = exclude_width(longest) if by_ids else 0
-        if by_ids and not longest:
+        if not by_ids:
+            width = 0
+        elif named:
+            # categories ride with the pairwise ids alone: the first rung
+            width = exclude_width(1)
+            width = width if named <= CATEGORY_SLOTS and longest <= width \
+                else 0
+        else:
+            width = exclude_width(longest)
+        if by_ids and not longest and not named:
             out = BatchFilter("none")
         elif width:
             exclude = np.full((len(rows), width), -1, np.int32)
@@ -388,13 +654,22 @@ def batch_filter(items, item_props: Optional[dict],
                 if len(ex):     # in the order this width's form reads
                     ex = exclude_layout(ex, width)
                     exclude[bi, :len(ex)] = ex
-            out = BatchFilter("ids", exclude=exclude)
+            numbers = None
+            if named:
+                numbers = np.full((len(rows), CATEGORY_SLOTS), -1, np.int32)
+                for bi, row in enumerate(rows):
+                    if row is not None and row.categories:
+                        mine = list(dict.fromkeys(
+                            map(index.number, row.categories)))
+                        numbers[bi, :len(mine)] = mine
+            out = BatchFilter("cats" if named else "ids", exclude=exclude,
+                              categories=numbers)
         else:
             mask = np.zeros((len(rows), len(items)), np.float32)
             for bi, row in enumerate(rows):
                 if row is not None:
                     bias = filter_bias_mask(
-                        items, item_props, categories=row.categories,
+                        items, index, categories=row.categories,
                         whitelist=row.whitelist,
                         blacklist=row.blacklist or (),
                         exclude_ix=row.exclude_ix, none_if_empty=True)
@@ -403,9 +678,11 @@ def batch_filter(items, item_props: Optional[dict],
             out = BatchFilter("mask", mask=mask)
     FILTER_BUILD_SECONDS.observe(time.perf_counter() - t0)
     FILTER_ROWS.labels(filter=out.kind).inc(len(rows))
-    if out.kind == "ids":
+    if out.exclude is not None:
         FILTER_EXCLUDE_WIDTH.labels(width=str(width)).inc()
         FILTER_EXCLUDED_IDS.inc(int((out.exclude >= 0).sum()))
+    if out.categories is not None:
+        FILTER_CATEGORY_IDS.inc(int((out.categories >= 0).sum()))
     return out
 
 
@@ -448,7 +725,7 @@ def warm_batched_topk(table, rank: int, n: int,
                       unmasked_too: bool = False,
                       max_batch: int = 64,
                       table_t=None, lone_nums=(),
-                      exclude_widths=None) -> None:
+                      exclude_widths=None, category_model=None) -> None:
     """Pre-compile the batched top-k scorer at the shapes serving
     dispatches (:func:`warm_shapes`, which reads `max_batch` and
     `lone_nums`: server/microbatch.py pads batches to powers of two;
@@ -461,16 +738,19 @@ def warm_batched_topk(table, rank: int, n: int,
     take, the first alone unless it names more (a blackList; an engine
     that excludes a user's whole history names them all); each rung
     compiles the path, blocked or dense, that its shapes will take under
-    traffic.  The ``[B, M]``
-    masked form of that scorer (`categories`, a `whiteList`) is not
-    warmed: its rungs each shipped a ``[B, M]`` array of zeros, 2.4 GB
+    traffic.  Where `category_model` (the engine's model) keeps a
+    category index resident on the device, each shape is also warmed with
+    category numbers against its rows, so a server's first `categories`
+    query compiles nothing.  The ``[B, M]`` masked form of that scorer (a
+    `whiteList`) is not warmed: its rungs each shipped a ``[B, M]`` array of zeros, 2.4 GB
     at 64 rows over 9.4 M items, and set the server's peak memory.  Without
     `table_t` it is the classic ``[M, R]`` scorer under a ``[B, M]``
     mask, for the template whose every batch is still masked and whose
     ``predict`` is a scorer of its own (itemsimilarity): with
     no batcher nothing dispatches it, and nothing is compiled."""
     from ..ops.topk import (
-        EXCLUDE_LADDER, batch_topk_scores, batch_topk_scores_t,
+        CATEGORY_SLOTS, EXCLUDE_LADDER, batch_topk_scores,
+        batch_topk_scores_t,
     )
 
     if table_t is None and max_batch <= 0:
@@ -488,5 +768,11 @@ def warm_batched_topk(table, rank: int, n: int,
         filters = [BatchFilter("none")] if unmasked_too else []
         filters += [BatchFilter("ids", np.full((b, width), -1, np.int32))
                     for width in exclude_widths]
+        if category_model is not None and \
+                category_model.device_category_rows() is not None:
+            filters.append(BatchFilter(
+                "cats", np.full((b, EXCLUDE_LADDER[0]), -1, np.int32),
+                categories=np.full((b, CATEGORY_SLOTS), -1, np.int32)))
         for flt in filters:
-            batch_topk_scores_t(vecs, table_t, k, **flt.scorer_kwargs())
+            batch_topk_scores_t(vecs, table_t, k,
+                                **flt.scorer_kwargs(category_model))

@@ -17,9 +17,13 @@ Both, with the query's ``blackList``, travel to the device as item ids
 (``_common.batch_filter`` -> ``ops.topk.batch_topk_scores_t(exclude=)``):
 one ``[B, E]`` int32 array a batch, E the rung of
 ``ops.topk.EXCLUDE_LADDER`` that holds the batch's longest list (32 to
-4,224 ids), taken out inside the exact blocked top-k.  No array of the
-catalogue's length is built on the host; only ``categories`` and a
-``whiteList`` still make the ``[B, M]`` mask.
+4,224 ids), taken out inside the exact blocked top-k.  A query's
+``categories`` travel as numbers of the model's category index and are
+tested on the device where the row's list holds at most 32 ids (a
+shopper's whole history is usually longer: such a batch takes the mask,
+counted).  No array of the catalogue's length is built on the host for
+the ids; a ``whiteList``, and ``categories`` beside a longer list, still
+make the ``[B, M]`` mask.
 
 What ``unseenOnly`` costs a batch, by the longest history in it (one
 v5e chip, 9.35 M items at rank 128, 16 rows; builder's chip runs,
@@ -60,7 +64,8 @@ from ..ops.topk import (
 )
 
 from ._common import (
-    DeviceTableMixin, RowFilter, batch_filter, warm_batched_topk,
+    CategoryIndex, DeviceTableMixin, RowFilter, batch_filter,
+    warm_batched_topk,
 )
 from .recommendation import (
     PredictedResult,
@@ -177,6 +182,10 @@ class ECommModel(DeviceTableMixin):
     items: Any
     item_props: dict[str, dict]
     app_id: int
+    # the train's snapshot of the items' `categories` as arrays; a model
+    # made without one gets it from `item_props` at first use
+    # (`categories()`)
+    category_index: Optional[CategoryIndex] = None
 
 
 class ECommAlgorithm(Algorithm):
@@ -207,6 +216,8 @@ class ECommAlgorithm(Algorithm):
             items=data.ratings.items,
             item_props=data.items,
             app_id=data.app_id,
+            category_index=CategoryIndex.from_props(
+                data.ratings.items, data.items),
         )
 
     # -- predict-time event store reads ------------------------------------
@@ -272,6 +283,7 @@ class ECommAlgorithm(Algorithm):
             max_batch=max_batch, table_t=model.device_item_tables(),
             exclude_widths=EXCLUDE_LADDER if self.params.unseen_only
             else None,
+            category_model=model,
         )
 
     def _excluded_items(self, model: ECommModel, users: Sequence[str]):
@@ -299,8 +311,10 @@ class ECommAlgorithm(Algorithm):
         the other templates (device batch = len(queries), k rounded to
         pow2).  Each row's blackList, seen items and the unavailable
         items travel to the device as item ids
-        (``_common.batch_filter``); only `categories` and a `whiteList`
-        still make the batch's ``[B, M]`` mask."""
+        (``_common.batch_filter``), `categories` as category numbers
+        where the batch's longest list holds at most 32 ids; a
+        `whiteList`, and `categories` beside a longer list, still make
+        the batch's ``[B, M]`` mask."""
         out = [PredictedResult(item_scores=()) for _ in queries]
         n = len(model.items)
         if n == 0 or not queries:
@@ -320,17 +334,18 @@ class ECommAlgorithm(Algorithm):
             asked = [q for q, v in zip(queries, valid) if v]
             gone = iter(self._excluded_items(
                 model, [q.user for q in asked]))
-            flt = batch_filter(model.items, model.item_props, [
+            flt = batch_filter(model.items, model.serving_categories(), [
                 RowFilter(q.categories, q.whitelist, q.blacklist, next(gone))
                 if v else None for q, v in zip(queries, valid)
             ])
             tables = model.device_item_tables()
         with annotate("pio.turn.dispatch", filter=flt.kind,
                       path=topk_path(uvecs, tables, k, flt.mask,
-                                     flt.exclude),
-                      exclude_width=flt.width):
+                                     flt.exclude, flt.categories),
+                      exclude_width=flt.width,
+                      categories=flt.category_rows):
             vals, ixs = batch_topk_scores_t(
-                uvecs, tables, k, **flt.scorer_kwargs())
+                uvecs, tables, k, **flt.scorer_kwargs(model))
         with annotate("pio.turn.fetch"):
             vals, ixs = jax.device_get((vals, ixs))
         with annotate("pio.turn.decode"):

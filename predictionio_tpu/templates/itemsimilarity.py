@@ -37,7 +37,7 @@ from ..controller import (
 )
 from ..models.als import ALSConfig, train_als
 from ..ops.topk import batch_topk_scores, pow2_ceil, topk_scores
-from ._common import DeviceTableMixin, filter_bias_mask, \
+from ._common import CategoryIndex, DeviceTableMixin, filter_bias_mask, \
     normalize_rows, pow2_ladder, warm_batched_topk
 from .recommendation import (
     ItemScore,
@@ -95,6 +95,9 @@ class ItemSimilarityModel(DeviceTableMixin):
     item_factors: np.ndarray
     items: Any  # StringIndex
     item_props: dict[str, dict]
+    # the train's snapshot of the items' `categories`, which the mask of
+    # a filtered query reads (`_common.filter_bias_mask`)
+    category_index: Optional[CategoryIndex] = None
 
     def sanity_check(self) -> None:
         if not np.isfinite(self.item_factors).all():
@@ -122,6 +125,8 @@ class ItemSimilarityAlgorithm(Algorithm):
             item_factors=normalize_rows(factors.item_factors),
             items=data.ratings.items,
             item_props=data.items,
+            category_index=CategoryIndex.from_props(
+                data.ratings.items, data.items),
         )
 
     def _retrieval_config(self):
@@ -174,7 +179,7 @@ class ItemSimilarityAlgorithm(Algorithm):
 
     def _exact_mask(self, model, query, known):
         return filter_bias_mask(
-            model.items, model.item_props,
+            model.items, model.categories(),
             categories=query.categories, whitelist=query.whitelist,
             blacklist=query.blacklist or (), exclude_ix=known,
         )

@@ -42,6 +42,7 @@ from ..ops.topk import (
 )
 from ..storage.columnar import Ratings
 from ._common import (
+    CategoryIndex,
     DeviceTableMixin,
     RowFilter,
     batch_filter,
@@ -430,6 +431,10 @@ class ALSModel(DeviceTableMixin):
     users: Any   # StringIndex
     items: Any   # StringIndex
     item_props: dict[str, dict]
+    # the train's snapshot of the items' `categories` as arrays; a model
+    # made without one gets it from `item_props` at first use
+    # (`categories()`)
+    category_index: Optional[CategoryIndex] = None
 
     def sanity_check(self) -> None:
         if not np.isfinite(self.user_factors).all():
@@ -546,6 +551,8 @@ class ALSAlgorithm(Algorithm):
             users=data.ratings.users,
             items=data.ratings.items,
             item_props=data.items,
+            category_index=CategoryIndex.from_props(
+                data.ratings.items, data.items),
         )
 
     # -- serving ----------------------------------------------------------
@@ -567,6 +574,7 @@ class ALSAlgorithm(Algorithm):
         warm_batched_topk(
             table, rank, n, unmasked_too=True, max_batch=max_batch,
             table_t=model.device_item_tables(self._serve_dtype()),
+            category_model=model,
         )
         # the other two scorers `batch_predict` may choose, at the
         # same shapes
@@ -622,10 +630,11 @@ class ALSAlgorithm(Algorithm):
             n_items = len(model.items)
             k = min(pow2_ceil(int(nums[valid].max())), n_items)
             uvecs = model.user_factors[np.where(valid, uix, 0)]
-            # filters as data: a blackList rides as item ids and is
-            # applied on the device; only `categories` or a `whiteList`
-            # make the batch's [B, M] mask (_common.batch_filter)
-            flt = batch_filter(model.items, model.item_props, [
+            # filters as data: a blackList rides as item ids, `categories`
+            # as numbers of the model's category index, both applied on
+            # the device; only a `whiteList` makes the batch's [B, M]
+            # mask (_common.batch_filter)
+            flt = batch_filter(model.items, model.serving_categories(), [
                 RowFilter(q.categories, q.whitelist, q.blacklist)
                 if v and (q.categories or q.whitelist or q.blacklist)
                 else None for q, v in zip(queries, valid)
@@ -654,10 +663,11 @@ class ALSAlgorithm(Algorithm):
             tables = model.device_item_tables(self._serve_dtype())
             with annotate("pio.turn.dispatch", filter=flt.kind,
                           path=topk_path(uvecs, tables, k, flt.mask,
-                                         flt.exclude),
-                          exclude_width=flt.width):
+                                         flt.exclude, flt.categories),
+                          exclude_width=flt.width,
+                          categories=flt.category_rows):
                 vals, ixs = batch_topk_scores_t(
-                    uvecs, tables, k, **flt.scorer_kwargs())
+                    uvecs, tables, k, **flt.scorer_kwargs(model))
         with annotate("pio.turn.fetch"):
             vals, ixs = jax.device_get((vals, ixs))
         with annotate("pio.turn.decode"):

@@ -9,6 +9,19 @@ with one fused cosine matmul + top-k.
 The custom model persistence demonstrates the `PersistentModel` contract
 (reference `multi/src/main/scala/ALSAlgorithm.scala:25-66` saves factor
 RDDs with ``saveAsObjectFile``; here: one ``.npz``).
+
+The documented query, ``{"items": [...], "num": 4, "categories": ["c4",
+"c3"], "whiteList": [...], "blackList": [...]}``, at catalogue scale:
+``train`` builds a category index from the items' ``categories`` property
+(``_common.CategoryIndex``: names -> numbers and each category's items,
+saved with the model as arrays), ``deploy`` keeps it on the device as one
+bit row a category, and a query's ``categories`` travel in the turn as
+numbers and are tested inside the blocked top-k, beside its seeds and
+blackList as item ids: exact over the allowed items, no array of the
+catalogue's length a query (9.35 M items x 4,096 categories: 4.8 GB
+resident, 75 MB of bits for 64 rows; ``PERF.md``,
+``simcat-amazon14-r128``).  A ``whiteList`` still takes the ``[B, M]``
+mask.
 """
 
 from __future__ import annotations
@@ -33,8 +46,9 @@ from ..models.als import ALSConfig, train_als
 from ..obs.timeline import annotate
 from ..ops.topk import batch_topk_scores_t, pow2_ceil, topk_path
 
-from ._common import DeviceTableMixin, RowFilter, batch_filter, \
-    normalize_rows, warm_batched_topk
+from ._common import CategoryIndex, DeviceTableMixin, RowFilter, \
+    batch_filter, normalize_rows, props_without_categories, \
+    warm_batched_topk
 from .recommendation import (
     PredictedResult,
     _resolve_app_id,
@@ -208,11 +222,22 @@ class SimilarALSModel(DeviceTableMixin):
     scoring needs no per-query table normalization and the table is
     directly servable by the two-stage int8/IVF retriever.  Legacy
     ``.npz`` models saved by the pre-migration template (raw factors)
-    are normalized once at load."""
+    are normalized once at load.
+
+    ``category_index`` is the train's snapshot of the items'
+    ``categories`` property (`_common.CategoryIndex`): what a query's
+    `categories` are looked up in and, as bit rows resident on the device
+    beside the table, tested against inside the blocked top-k.  The
+    categories live THERE ONLY: ``item_props`` holds the items' other
+    properties (a custom Serving may read them; the algorithm does not)
+    and no entry for an item that has none, on a trained model and on a
+    deployed one alike.  A model built by hand without an index gets one
+    from its ``item_props`` at first use (``categories()``)."""
 
     item_factors: np.ndarray
     items: Any  # StringIndex
     item_props: dict[str, dict]
+    category_index: Optional[CategoryIndex] = None
 
 
 class SimilarProductAlgorithm(Algorithm):
@@ -239,7 +264,9 @@ class SimilarProductAlgorithm(Algorithm):
         return SimilarALSModel(
             item_factors=normalize_rows(factors.item_factors),
             items=data.ratings.items,
-            item_props=data.items,
+            item_props=props_without_categories(data.items),
+            category_index=CategoryIndex.from_props(
+                data.ratings.items, data.items),
         )
 
     # -- custom persistence (PersistentModel demo) -------------------------
@@ -254,11 +281,17 @@ class SimilarProductAlgorithm(Algorithm):
             # files (saved raw by the pre-migration template) exactly
             # once, and leaves stamped files alone
             normalized=np.array(True),
+            # the categories as arrays (4 bytes a membership), not as one
+            # dict an item
+            **model.categories().arrays(),
         )
         import json as _json
 
+        # what the index holds is not written twice: the JSON keeps the
+        # items' OTHER properties, and no entry for an item without any
         props_path = base_dir / f"{model_id}-props.json"
-        props_path.write_text(_json.dumps(model.item_props))
+        props_path.write_text(_json.dumps(
+            props_without_categories(model.item_props)))
         return {"npz": path.name, "props": props_path.name}
 
     def load_model(self, ctx, model_id, manifest, base_dir):
@@ -271,10 +304,15 @@ class SimilarProductAlgorithm(Algorithm):
         factors = data["item_factors"]
         if "normalized" not in data.files or not bool(data["normalized"]):
             factors = normalize_rows(factors)
+        items = StringIndex(list(data["item_ids"]))
+        index = CategoryIndex.from_arrays(data)
+        if index is None:
+            # a file from before the index: the categories are in the JSON
+            index = CategoryIndex.from_props(items, props)
         return SimilarALSModel(
-            item_factors=factors,
-            items=StringIndex(list(data["item_ids"])),
-            item_props=props,
+            item_factors=factors, items=items,
+            item_props=props_without_categories(props),
+            category_index=index,
         )
 
     # -- serving -----------------------------------------------------------
@@ -282,14 +320,17 @@ class SimilarProductAlgorithm(Algorithm):
         """Pre-compile the cosine top-k scorer for the pow2 batched
         shapes the serving micro-batcher dispatches and the small-k
         one-row shapes of a lone request, each with excluded ids (every
-        query excludes its own seeds).  The table is train-time
-        normalized, so the plain device tables serve cosine directly."""
+        query excludes its own seeds) and, where the model holds a
+        category index, with category numbers beside them.  The table is
+        train-time normalized, so the plain device tables serve cosine
+        directly."""
         n = len(model.items)
         if n == 0:
             return
         warm_batched_topk(
             None, model.item_factors.shape[1], n, max_batch=max_batch,
             table_t=model.device_item_tables(), lone_nums=(1, 4),
+            category_model=model,
         )
 
     @staticmethod
@@ -323,9 +364,13 @@ class SimilarProductAlgorithm(Algorithm):
         and k rounds up to pow2, bounding the XLA executable key space.
 
         A query's own seed items and its `blackList` travel to the device
-        as item ids (``_common.batch_filter``): no array of the
-        catalogue's length is built for them.  Only `categories` and a
-        `whiteList` still make the batch's ``[B, M]`` mask."""
+        as item ids, its `categories` as category numbers looked up in
+        the model's index (``_common.batch_filter``): no array of the
+        catalogue's length is built for them, on the host or on the
+        device, at any catalogue size (a 9.35 M-item catalogue with 4,096
+        categories: 75 MB of allowed bits for 64 rows, formed on the
+        device from the resident index).  Only a `whiteList` still makes
+        the batch's ``[B, M]`` mask."""
         out = [PredictedResult(item_scores=()) for _ in queries]
         n = len(model.items)
         if n == 0 or not queries:
@@ -346,17 +391,18 @@ class SimilarProductAlgorithm(Algorithm):
                 n,
             )
             # exclude the query items themselves plus any filters
-            flt = batch_filter(model.items, model.item_props, [
+            flt = batch_filter(model.items, model.serving_categories(), [
                 RowFilter(q.categories, q.whitelist, q.blacklist, ixs)
                 if ixs else None for q, ixs in zip(queries, known)
             ])
             tables = model.device_item_tables()
         with annotate("pio.turn.dispatch", filter=flt.kind,
                       path=topk_path(qvecs, tables, k, flt.mask,
-                                     flt.exclude),
-                      exclude_width=flt.width):
+                                     flt.exclude, flt.categories),
+                      exclude_width=flt.width,
+                      categories=flt.category_rows):
             vals, ixs = batch_topk_scores_t(
-                qvecs, tables, k, **flt.scorer_kwargs())
+                qvecs, tables, k, **flt.scorer_kwargs(model))
         with annotate("pio.turn.fetch"):
             vals, ixs = jax.device_get((vals, ixs))
         with annotate("pio.turn.decode"):

@@ -266,7 +266,8 @@ def test_without_unseen_only_the_store_is_not_read_for_users(model):
 
 
 @pytest.mark.parametrize("kind", ["categories", "whitelist"])
-def test_categories_and_a_whitelist_still_take_the_mask(model, kind):
+def test_categories_ride_as_numbers_and_a_whitelist_takes_the_mask(
+        model, kind):
     store = _make_store("memory")
     best = _best(model, "u0", 3)
     store.insert_batch([_buy("u0", item) for item in best], APP)
@@ -275,9 +276,10 @@ def test_categories_and_a_whitelist_still_take_the_mask(model, kind):
     query = (Query(user="u0", num=5, categories=("even",))
              if kind == "categories" else
              Query(user="u0", num=5, whitelist=white))
-    masks = _common.FILTER_ROWS.labels(filter="mask").value()
+    form = "cats" if kind == "categories" else "mask"
+    rows = _common.FILTER_ROWS.labels(filter=form).value()
     got = [s.item for s in algo.predict(model, query).item_scores]
-    assert _common.FILTER_ROWS.labels(filter="mask").value() == masks + 1
+    assert _common.FILTER_ROWS.labels(filter=form).value() == rows + 1
     scores = model.item_factors @ model.user_factors[0]
     allowed = np.zeros(M, bool)
     if kind == "categories":
@@ -350,7 +352,7 @@ def test_the_dispatch_span_carries_the_rung(model, monkeypatch):
                      "pio.turn.dispatch", "pio.turn.fetch",
                      "pio.turn.decode"]
     assert seen[2][1] == {"filter": "ids", "path": "blocked",
-                          "exclude_width": LADDER[1]}
+                          "exclude_width": LADDER[1], "categories": 0}
 
 
 # -- the store's read by entity ------------------------------------------------

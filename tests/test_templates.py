@@ -96,7 +96,18 @@ def test_similarproduct_custom_persistence_roundtrip(similar_ctx, tmp_path):
     m = models[0]
     assert m.item_factors.dtype == np.float32
     assert len(m.items) == 10
-    assert m.item_props["i0"]["categories"] == ["even"]
+    # the categories persist as the index's arrays, not as a dict an item
+    index = m.category_index
+    assert sorted(index.names.tolist()) == ["even", "odd"]
+    assert index.memberships == 10
+    assert m.items.decode(np.flatnonzero(index.allowed(["even"], 10))
+                          ).tolist() == ["i0", "i2", "i4", "i6", "i8"]
+    # the index is the one owner of the categories, on the trained model
+    # as on the deployed one
+    trained = e.train(similar_ctx, ep)[0]
+    assert m.item_props == trained.item_props == {}, \
+        "nothing but `categories` was set"
+    assert trained.category_index.names.tolist() == index.names.tolist()
     # model dir contains the npz, not a pickle
     mdir = similar_ctx.storage.model_data_dir() / iid
     assert any(p.suffix == ".npz" for p in mdir.iterdir())
@@ -410,9 +421,12 @@ def test_similarproduct_batch_predict_matches_single(similar_ctx):
     shapes = []
     real = smod.batch_topk_scores_t
 
-    def spy(vecs, tables, k, mask=None, exclude=None):
+    def spy(vecs, tables, k, **filters):
         shapes.append((vecs.shape[0], k))
-        return real(vecs, tables, k, mask=mask, exclude=exclude)
+        # a trained model holds a category index: the batch's categories
+        # ride as numbers beside its ids
+        assert filters["allow"].numbers.shape == (5, 4)
+        return real(vecs, tables, k, **filters)
 
     import unittest.mock as mock
 

@@ -353,7 +353,8 @@ def test_batch_predict_takes_the_path_its_batch_allows(filtered):
         got = algo.batch_predict(model, queries)
     assert topk.TOPK_PATH.labels(path=counted).value() == before + 1
     assert ("pio.turn.dispatch", {"path": path, "filter": kind,
-                                  "exclude_width": width}) in seen
+                                  "exclude_width": width,
+                                  "categories": 0}) in seen
     for query, result in zip(queries, got):
         solo = algo.predict(model, query)
         assert [s.item for s in result.item_scores] == \

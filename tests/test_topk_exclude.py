@@ -362,8 +362,10 @@ def test_similarproduct_serves_by_ids_and_warms_what_it_dispatches(
 @pytest.mark.parametrize("kind", ["categories", "whitelist", "both",
                                   "long_blacklist"])
 def test_similarproduct_wide_filters_keep_their_answers(kind):
-    """`categories`, a `whiteList` and a list past the ladder's last rung
-    still take the `[B, M]` mask; the answers are the contract's."""
+    """A `whiteList` and a list past the ladder's last rung still take the
+    `[B, M]` mask; `categories` alone ride as numbers of the index that a
+    model built from property dicts gets at first use; the answers are the
+    contract's."""
     from predictionio_tpu.templates import similarproduct as smod
 
     model = _similar_model()
@@ -378,10 +380,11 @@ def test_similarproduct_wide_filters_keep_their_answers(kind):
             items=("i1",), num=8,
             blacklist=tuple(f"i{j}" for j in range(2, 2 + LAST))),
     }[kind]
-    mask_rows = _common.FILTER_ROWS.labels(filter="mask").value()
+    form = "cats" if kind == "categories" else "mask"
+    rows = _common.FILTER_ROWS.labels(filter=form).value()
     plain = smod.Query(items=("i3",), num=8)
     got = algo.batch_predict(model, [plain, query])
-    assert _common.FILTER_ROWS.labels(filter="mask").value() == mask_rows + 2
+    assert _common.FILTER_ROWS.labels(filter=form).value() == rows + 2
     assert [s.item for s in got[0].item_scores] == _brute_force(model, plain)
     assert [s.item for s in got[1].item_scores] == _brute_force(model, query)
     alone = algo.predict(model, query)     # a batch of one: ulps apart

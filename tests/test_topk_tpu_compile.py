@@ -5,9 +5,14 @@ a gather the chip's compiler refuses and a rung that does not fit the
 device's memory, costs no chip time; and that the shapes by which
 `exclude_device_ms.unseen` finds the exclusions' operations in a trace are
 those of the operations under the exclusions' named scopes, at every rung the
-cell's batches take.  Nothing runs; a compile that passes is not a chip run.  One file, the topology described inside a fixture
+cell's batches take.  Since PR 42 also the category form (a row's allowed
+items as bits, tested inside the scan kernel and on the chosen blocks) at the
+category cell's size beside its resident index, the pattern of
+`allow_device_ms.cats` held to ITS scopes, and the programs of a model without
+an index held to the parent commit's, lowered for the chip.  Nothing runs; a compile that passes is not a chip run.  One file, the topology described inside a fixture
 (`on-chip-measurement`, section 2)."""
 
+import hashlib
 import json
 import re
 from pathlib import Path
@@ -116,3 +121,95 @@ def test_a_rung_compiles_for_the_chip_and_fits_it(one_chip, as_on_the_chip,
     lines = [ins for scope, ins in operations_by_scope(text)
              if scope == "topk.exclude_blocks" and "(%table_t_packed" in ins]
     assert lines and all(PATTERN.search(ins) for ins in lines), lines
+
+
+# -- PR 42: the category form ---------------------------------------------------
+
+CATEGORIES = 4096
+ALLOW_PATTERN = re.compile(json.loads(
+    (Path(__file__).resolve().parents[1]
+     / "perfbench/metrics/allow_device_ms.cats.json").read_text()
+)["args"]["pattern"])
+
+
+def _category_form(one_chip, batch, width):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scorer = topk.batch_topk_scores_t.__wrapped__.__wrapped__
+    return jax.jit(scorer, static_argnames=("k",)).lower(
+        sds((batch, R), jnp.float32),
+        topk.ItemTables(None, sds((M, R), jnp.float32)), k=K, mask=None,
+        exclude=sds((batch, width), jnp.int32) if width else None,
+        allow=topk.Allowed(
+            sds((batch, topk.CATEGORY_SLOTS), jnp.int32),
+            sds((CATEGORIES + 2, topk.allow_words(M) // 1024, 8, 128),
+                jnp.uint32)))
+
+
+@pytest.mark.parametrize("batch", [1, 8, 64])
+@pytest.mark.parametrize("width", [0, topk.EXCLUDE_LADDER[0]])
+def test_the_category_form_compiles_for_the_chip_and_fits_it(
+        one_chip, as_on_the_chip, batch, width):
+    compiled = _category_form(one_chip, batch, width).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the scan is the Pallas kernel"
+    assert text.count('custom_call_target="TopK"') == 1
+    memory = compiled.memory_analysis()
+    # the 4.79 GB table and the 4.80 GB of bit rows are arguments; beside
+    # them the batch's words (75 MB at 64 rows), its block maxima and the
+    # chosen blocks' gathered rows: no copy of the resident rows (a
+    # gather of `[B, C]` rows from them made one: 4.3 GB of temporaries)
+    assert memory.temp_size_in_bytes < 0.3e9
+    assert 9.5e9 < memory.argument_size_in_bytes < 9.7e9
+    assert (memory.temp_size_in_bytes + memory.argument_size_in_bytes
+            + memory.output_size_in_bytes) < 15.75e9, "one v5e chip"
+    # what `allow_device_ms.cats` reads lies under the allowed bits' own
+    # named scopes, never in the scan, the chosen blocks' scores, the
+    # excluded ids' drop or the k passes; and it holds each row's read of
+    # the resident rows and the re-lay of the batch's words
+    read = [(scope, ins) for scope, ins in operations_by_scope(text)
+            if ALLOW_PATTERN.search(ins)]
+    assert {scope for scope, _ in read} <= {"topk.allow_bits",
+                                            "topk.allow"}, read
+    words = topk.allow_words(M)
+    rows = 8 * -(-batch // 8)
+    assert any(f"u32[1,{words // 1024},8,128]" in ins.split(" fusion(")[0]
+               for _, ins in read), "a row's read of the resident rows"
+    assert any(ins.split(" = ")[1].startswith(f"u32[{rows},{words}]")
+               for _, ins in read), "the words re-laid, rows on the sublanes"
+    whiles = [ins for _, ins in operations_by_scope(text) if " while(" in ins]
+    assert whiles and not any(ALLOW_PATTERN.search(ins) for ins in whiles), \
+        "the row loop itself is not read a second time beside its body"
+
+
+# the parent commit's programs (97c4c5b), lowered for the described chip with
+# the Mosaic body's payload (which holds source positions) and the locations
+# taken out: what an engine whose model holds no index dispatches
+PARENT_PROGRAMS = {
+    (1, 0): "eb72f05cc71fceb6", (1, 32): "c09c940daa280e4c",
+    (16, 0): "06bf32043c62902f", (16, 32): "053aaac2a60731fa",
+    (64, 0): "25ffa214e596ade3", (64, 32): "9e79ffd84d4adc78",
+}
+
+
+@pytest.mark.parametrize("batch,width", sorted(PARENT_PROGRAMS))
+def test_programs_without_an_index_are_the_parents(one_chip, as_on_the_chip,
+                                                   batch, width):
+    """`sim-amazon14-r128`, `ecomm-amazon14-r128` and `rec-yambda-r64` run
+    what they ran: without `allow` the lowered program is the parent's,
+    text for text, the Mosaic body's source positions aside."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scorer = topk.batch_topk_scores_t.__wrapped__.__wrapped__
+    text = jax.jit(scorer, static_argnames=("k",)).lower(
+        sds((batch, R), jnp.float32),
+        topk.ItemTables(None, sds((M, R), jnp.float32)), k=K, mask=None,
+        exclude=sds((batch, width), jnp.int32) if width else None).as_text()
+    assert len(re.findall(r'backend_config = "', text)) == 1
+    text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
+                  'backend_config = ""', text)
+    text = re.sub(r"loc\([^)]*\)", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PARENT_PROGRAMS[batch, width]

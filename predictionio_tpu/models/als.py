@@ -2251,6 +2251,7 @@ class ALSTrainer:
                 name: {str(k): rows for k, rows in sorted(by_width.items())}
                 for name, by_width in self.lowrank_systems.items()
             },
+            "solveSlabWork": self.slab_work,
             "staging": self.staging,
             "placement": "sharded" if self.sharded else "replicated",
             "shards": n_dev if self.sharded else 1,
@@ -2339,7 +2340,9 @@ class ALSTrainer:
         system is solved K x K against the shared base
         (``lowrank_systems`` a side, by pad width; `_lowrank_form`),
         which ``solve_systems`` counts too; the others are solved at
-        ``system_width``.  And ``pio_als_solve_systems_total``'s
+        ``system_width``.  Where the kernel solves them, the share of
+        its full-width slab products it does at each width
+        (``slab_work`` a side).  And ``pio_als_solve_systems_total``'s
         children with what `run` adds to each once a sweep: the K x K
         rows under ``path="lowrank"``, the rest under ``solve_path``."""
         cfg = self.cfg
@@ -2366,6 +2369,22 @@ class ALSTrainer:
                     by_width[k] = by_width.get(k, 0) + int(bucket[0].size)
         lowrank = sum(sum(by_width.values())
                       for by_width in self.lowrank_systems.values())
+        # the share of the full-width slab products the kernel does at
+        # each width it solves (`ops/solve.slab_work_share`)
+        self.slab_work = {name: {} for name, _ in sides}
+        if self.solve_path == "kernel":
+            from ..ops.solve import slab_work_share
+
+            for name, _ in sides:
+                widths = set(self.lowrank_systems[name])
+                if self.solve_systems[name] > sum(
+                        self.lowrank_systems[name].values()):
+                    widths.add(width)
+                    if self.sweeps_blocks and cfg.rank % width:
+                        widths.add(cfg.rank % width)
+                self.slab_work[name] = {
+                    str(w): slab_work_share(w) for w in sorted(widths)
+                }
         self._solve_counters = [
             (ALS_SOLVE_SYSTEMS_TOTAL.labels(path=path), systems)
             for path, systems in (

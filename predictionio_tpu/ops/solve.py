@@ -32,6 +32,14 @@ batch alone, 5.3 ms a call inside a sweep (PERF.md, PR 32).
   running subtraction (float64 comparison in `tests/test_solve.py`),
   which is what keeps the tables inside the benchmark's limits against
   XLA's own Cholesky.
+* **Width classes** (`_slab_classes`, from the width alone): at R = 128
+  the block-rows fall in four classes, and a row works only the columns
+  from its class's first on, half the products of the full ``[R+8,
+  TB]`` slab, with ``x`` the same to the bit: ``[4096, 128, 128]`` 4.00
+  -> 2.74 ms a call on a v5e, for 0.27 s more lowering a shape (PERF.md,
+  PR 43).  Under 128 one class, the full width: there the kernel is a
+  few % of a sweep and every one of dozens of bucket shapes lowers it
+  in every process.
 * **Back substitution** reads the rows of ``U`` in reverse; the dot of
   a row with the solved tail is a sum over sublane blocks and one
   sublane reduction.
@@ -61,6 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = [
     "spd_solve_batched",
     "cholesky_solve_batched",
+    "slab_work_share",
     "pallas_interpret",
     "solver_smem_budget",
     "solver_vmem_budget",
@@ -91,25 +100,51 @@ def _tree_sum(terms):
     return terms[0]
 
 
-def _cholesky_kernel(a_ref, b_ref, x_ref, s_ref, d_ref):
+def _slab_classes(r: int) -> tuple[int, ...]:
+    """The block-rows at which the kernel's width classes start, for
+    systems of padded width ``r``: one class, today's full-width body,
+    under 128; four at 128, where the products are most of the kernel
+    (PERF.md, PR 43).  A class from block-row ``c`` works the columns
+    from ``8c`` on."""
+    if r < 128:
+        return (0,)
+    return tuple(r // _SUB * j // 4 for j in range(4))
+
+
+def slab_work_share(r: int) -> float:
+    """The share of the full-width slab products the kernel does for
+    systems of width ``r``: the products of row ``k`` are ``k`` times
+    the columns its class works."""
+    r = _round_up(r, _SUB)
+    starts = _slab_classes(r)
+    work = sum(
+        k * (r + _SUB - _SUB * max(s for s in starts if s <= k // _SUB))
+        for k in range(r)
+    )
+    return work / (r * (r - 1) // 2 * (r + _SUB))
+
+
+def _cholesky_kernel(a_ref, b_ref, x_ref, s_ref, d_ref, *, starts):
     """Grid step (tile t, block-row c): rows 8c..8c+7 of the tile's
     systems arrive in ``a_ref [TB, 8, R]``, are laid into ``s_ref[i, j,
     b]`` and factored; the last block-row's step substitutes back and
     fills ``x_ref [R, TB]``.  ``d_ref [R, TB]`` keeps 1/U[k, k].
 
-    One body serves every block-row (``c`` is a loop bound and an
-    offset, never a Python value): a row's slab is worked at its full
-    width, zeros left of the diagonal included.  That is twice the
-    multiplies of the triangle, and a kernel of some 150 operations
-    instead of 1,000: each of an ALS half's dozens of bucket shapes
-    traces and lowers its own copy in every process, compile cache or
-    not (PERF.md, PR 32).
+    A row of block-row ``c`` is worked from column ``lo``, the first
+    of its width class (``starts``, `_slab_classes`), to ``b`` in
+    column R: the columns left of ``lo`` are zeros of U no later row
+    reads, since a later row's ``lo`` is no less and ``U[m, k]`` sits
+    at ``k >= lo``.  Every column that is worked sees the operations of
+    the full-width body in its order, so ``x`` is the same to the bit.
+    Each class is a copy of the row body (about 150 operations) that
+    every bucket shape traces and lowers in every process, compile
+    cache or not (PERF.md, PR 32): one class, the full width, under
+    R = 128.
     """
     tb, _, r = a_ref.shape
     c = pl.program_id(1)
     c0 = pl.multiple_of(c * _SUB, _SUB)
     sub = jax.lax.broadcasted_iota(jnp.int32, (_SUB, tb), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (r + _SUB, tb), 0)
 
     for i in range(_SUB):
         s_ref[c0 + i, :r, :] = a_ref[:, i, :].T
@@ -117,36 +152,46 @@ def _cholesky_kernel(a_ref, b_ref, x_ref, s_ref, d_ref):
             sub == 0, b_ref[pl.ds(c0 + i, 1), :], 0.0
         )
 
-    def row(i, carry):
-        k = c0 + i
+    def factor(lo):
+        col = lo + jax.lax.broadcasted_iota(jnp.int32, (r + _SUB - lo, tb), 0)
 
-        def products(m, live=None):
-            u_mk = s_ref[m, pl.ds(k, 1), :]                   # [1, TB]
-            if live is not None:
-                u_mk = jnp.where(live, u_mk, 0.0)
-            return u_mk * s_ref[m]                            # [R + 8, TB]
+        def row(i, carry):
+            k = c0 + i
 
-        def earlier_block(g, acc):
-            return acc - _tree_sum(
-                [products(g * _SUB + u) for u in range(_SUB)]
+            def products(m, live=None):
+                u_mk = s_ref[m, pl.ds(k, 1), :]               # [1, TB]
+                if live is not None:
+                    u_mk = jnp.where(live, u_mk, 0.0)
+                return u_mk * s_ref[m, lo:, :]                # [R + 8 - lo, TB]
+
+            def earlier_block(g, acc):
+                return acc - _tree_sum(
+                    [products(g * _SUB + u) for u in range(_SUB)]
+                )
+
+            acc = jax.lax.fori_loop(0, c, earlier_block, s_ref[k, lo:, :])
+            # this block's rows above k; the rows from k down still hold A
+            acc = acc - _tree_sum(
+                [products(c0 + u, u < i) for u in range(_SUB - 1)]
             )
+            s_ref[k, lo:, :] = acc
+            pivot = s_ref[k, pl.ds(k, 1), :]
+            dinv = jax.lax.rsqrt(pivot)
+            # one Newton step: the hardware's rsqrt is an approximation
+            dinv = dinv * (1.5 - 0.5 * pivot * dinv * dinv)
+            d_ref[pl.ds(k, 1), :] = dinv
+            # the columns left of the diagonal are zeros of U
+            s_ref[k, lo:, :] = jnp.where(col >= k, acc * dinv, 0.0)
+            return carry
 
-        acc = jax.lax.fori_loop(0, c, earlier_block, s_ref[k])
-        # this block's rows above k; the rows from k down still hold A
-        acc = acc - _tree_sum(
-            [products(c0 + u, u < i) for u in range(_SUB - 1)]
-        )
-        s_ref[k] = acc
-        pivot = s_ref[k, pl.ds(k, 1), :]
-        dinv = jax.lax.rsqrt(pivot)
-        # one Newton step: the hardware's rsqrt is an approximation
-        dinv = dinv * (1.5 - 0.5 * pivot * dinv * dinv)
-        d_ref[pl.ds(k, 1), :] = dinv
-        # the columns left of the diagonal are zeros of U
-        s_ref[k] = jnp.where(col >= k, acc * dinv, 0.0)
-        return carry
+        jax.lax.fori_loop(0, _SUB, row, 0)
 
-    jax.lax.fori_loop(0, _SUB, row, 0)
+    if len(starts) == 1:
+        factor(0)
+    else:
+        for first, end in zip(starts, starts[1:] + (r // _SUB,)):
+            pl.when((c >= first) & (c < end))(
+                functools.partial(factor, _SUB * first))
 
     @pl.when(c == r // _SUB - 1)
     def _():
@@ -154,7 +199,9 @@ def _cholesky_kernel(a_ref, b_ref, x_ref, s_ref, d_ref):
 
         def row_up(j, carry):
             k = r - 1 - j
-            # x is still zero at k and above it, U zero left of k
+            # x is still zero at k and above it, so the columns left of
+            # k (U's zeros, or A's entries left of a class's first
+            # column) add nothing
             dot = jnp.sum(s_ref[k, :r, :] * x_ref[...], axis=0, keepdims=True)
             y_k = s_ref[k, r:r + 1, :]
             x_ref[pl.ds(k, 1), :] = (y_k - dot) * d_ref[pl.ds(k, 1), :]
@@ -228,8 +275,8 @@ def _tile_rows(r: int) -> int:
     return tb
 
 
-@functools.partial(jax.jit, static_argnames=("tb", "interpret"))
-def _solve(A, b, *, tb: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("tb", "starts", "interpret"))
+def _solve(A, b, *, tb: int, starts: tuple[int, ...], interpret: bool):
     B, r0, _ = A.shape
     r = _round_up(r0, _SUB)
     if r != r0:
@@ -239,7 +286,7 @@ def _solve(A, b, *, tb: int, interpret: bool):
     n_tiles = pl.cdiv(B, tb)
     bt = jnp.pad(b.T, ((0, r - r0), (0, n_tiles * tb - B)))
     xt = pl.pallas_call(
-        _cholesky_kernel,
+        functools.partial(_cholesky_kernel, starts=starts),
         out_shape=jax.ShapeDtypeStruct((r, n_tiles * tb), A.dtype),
         grid=(n_tiles, r // _SUB),
         in_specs=[
@@ -276,7 +323,9 @@ def spd_solve_batched(A, b, interpret: bool | None = None):
         # needs no padding: its lanes are not written back
         A = jnp.pad(A, ((0, tb - B), (0, 0), (0, 0)))
         b = jnp.pad(b, ((0, tb - B), (0, 0)))
-    return _solve(A, b, tb=tb, interpret=bool(interpret))[:B]
+    starts = _slab_classes(_round_up(A.shape[-1], _SUB))
+    return _solve(A, b, tb=tb, starts=starts,
+                  interpret=bool(interpret))[:B]
 
 
 # the name `models/als.py` and the tests call it by

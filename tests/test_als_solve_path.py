@@ -138,3 +138,25 @@ def test_the_tracing_carries_the_path_and_the_systems(
     assert counters[path].value() - before[path] == 2 * (
         want["user"] + want["item"])
     assert counters[other].value() == before[other]
+
+
+@pytest.mark.parametrize("rank,block,solver,want", [
+    # whole blocks of 128: four width classes, half the slab's products
+    (256, 128, "pallas", {"128": 551_424 / 1_105_408}),
+    # blocks of 64 and a last one of 8: today's full-width body
+    (200, 64, "pallas", {"8": 1.0, "64": 1.0}),
+    # lax.linalg solves: no kernel, no slab
+    (256, 128, "xla", {}),
+], ids=["engages", "full-width", "lax"])
+def test_the_staged_event_carries_the_kernels_slab_work(
+        rank, block, solver, want, monkeypatch):
+    from predictionio_tpu.obs import tower
+
+    events = []
+    monkeypatch.setattr(
+        tower, "note_event", lambda name, **f: events.append((name, f)))
+    u, i, v, nu, ni = _ratings()
+    ALSTrainer((u, i, v), nu, ni, ALSConfig(
+        rank=rank, solver=solver, solver_mode="subspace", subspace_size=block))
+    (name, staged), = events
+    assert staged["solveSlabWork"] == {"user": want, "item": want}

@@ -190,3 +190,45 @@ def test_error_against_float64_in_units_of_eps_cond():
                  / np.linalg.norm(ref, axis=1)).max()
         assert fro < 0.4 * eps * cond, (n, fro / (eps * cond))
         assert worst < 0.6 * eps * cond, (n, worst / (eps * cond))
+
+
+def _classes(r, n):
+    """``n`` width classes over the ``r / 8`` block-rows, at any width
+    (the rule engages at 128 alone); ``n = 0`` the exact triangle."""
+    rows = r // 8
+    n = n or rows
+    return tuple(sorted({rows * j // n for j in range(n)}))
+
+
+@pytest.mark.parametrize("n", [4, 0], ids=["four", "triangle"])
+@pytest.mark.parametrize("B", [1, 129, 300])
+@pytest.mark.parametrize("R", [16, 64, 100, 128])
+def test_width_classes_solve_to_the_bit_of_the_full_width_body(
+        R, B, n, monkeypatch):
+    """A row worked from its class's first column gives the x of the
+    one full-width body to the bit: the same products, tree groups and
+    subtractions in every column that is worked.  Ragged last tiles
+    (129, 300) and identity-padded ranks (100) included."""
+    from predictionio_tpu.ops import solve as solve_mod
+
+    A, b = _spd_batch(B, R, seed=R + B)
+    monkeypatch.setattr(solve_mod, "_slab_classes", lambda r: (0,))
+    full = np.asarray(cholesky_solve_batched(A, b))
+    monkeypatch.setattr(solve_mod, "_slab_classes", lambda r: _classes(r, n))
+    trimmed = np.asarray(cholesky_solve_batched(A, b))
+    assert np.isfinite(full).all()
+    assert np.array_equal(trimmed, full)
+
+
+def test_the_classes_follow_the_width_alone():
+    """Four classes at 128, where the products are most of the kernel;
+    today's one body under it.  The slab work is the share of the
+    full-width products the kernel does."""
+    from predictionio_tpu.ops import solve as solve_mod
+
+    assert solve_mod._slab_classes(128) == (0, 4, 8, 12)
+    for r in (8, 16, 64, 104, 120):
+        assert solve_mod._slab_classes(r) == (0,)
+        assert solve_mod.slab_work_share(r) == 1.0
+    # 551,424 of 1,105,408 products a system (ISSUE 43's table)
+    assert solve_mod.slab_work_share(128) == 551_424 / 1_105_408

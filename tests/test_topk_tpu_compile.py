@@ -9,7 +9,10 @@ cell's batches take.  Since PR 42 also the category form (a row's allowed
 items as bits, tested inside the scan kernel and on the chosen blocks) at the
 category cell's size beside its resident index, the pattern of
 `allow_device_ms.cats` held to ITS scopes, and the programs of a model without
-an index held to the parent commit's, lowered for the chip.  Nothing runs; a compile that passes is not a chip run.  One file, the topology described inside a fixture
+an index held to the parent commit's, lowered for the chip.  Since PR 43
+the ALS solve kernel (`ops/solve.py`) at widths on both sides of its rule
+for width classes.
+Nothing runs; a compile that passes is not a chip run.  One file, the topology described inside a fixture
 (`on-chip-measurement`, section 2)."""
 
 import hashlib
@@ -213,3 +216,26 @@ def test_programs_without_an_index_are_the_parents(one_chip, as_on_the_chip,
     text = re.sub(r"loc\([^)]*\)", "", text)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PARENT_PROGRAMS[batch, width]
+
+
+# -- PR 43: the ALS solve kernel in its width classes ---------------------------
+
+
+@pytest.mark.parametrize("batch,width,bodies", [
+    (4096, 128, 4),      # the block sweep's systems: four width classes
+    (32768, 64, 1),      # the netflix cell's: the one full-width body
+    (1000, 8, 1),        # the four-chip cell's K x K: one block-row
+])
+def test_the_solve_kernel_compiles_for_the_chip_in_its_width_classes(
+        one_chip, as_on_the_chip, batch, width, bodies):
+    from predictionio_tpu.ops import solve
+
+    starts = solve._slab_classes(width)
+    assert len(starts) == bodies
+    text = solve._solve.lower(
+        jax.ShapeDtypeStruct((batch, width, width), jnp.float32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((batch, width), jnp.float32, sharding=one_chip),
+        tb=solve._tile_rows(width), starts=starts, interpret=False,
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1, "the solve is the kernel"

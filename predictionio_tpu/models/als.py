@@ -159,42 +159,79 @@ DENSE_K = 0
 # blocks of 4,096 and 81.1 ms in blocks of 8,192)
 _DENSE_BLOCK_ROWS = 4096
 
-# `dense_min_count`'s two constants, from one v5e chip at rank 64
-# (PR 35).  A gathered chunk of 4,194,304 padded entries with its Gram
-# takes 48.9 ms from a 480,189-row table (11.7 ns an entry; 46.0 ms of it
-# the gather) and 14.3 ms from a 17,770-row one (3.4 ns; 11.3); the dense
-# form of 1,024 rows against 480,189 takes 72.3 ms, of 1,408 against
-# 17,770 5.0 ms: 0.15 to 0.17 ns a (row, opposite row) pair where the
-# counts are whole numbers (three bf16 passes hold them; twice that for
-# the implicit form's float32 weights).  A bucket's padded entries are
-# 1.44 times its ratings, so the dense form wins from 1/115 of a large
-# opposite table and from 1/29 of a small one: one share for both, the
-# careful one.  At the floor a row's whole gather is 20 to 70 us.
-_DENSE_SHARE_AT_RANK_64 = 1 / 32
+# `dense_min_count`'s costs, from one v5e chip at rank 64.  A gathered
+# chunk of 4,194,304 padded entries, gathered and its Grams built, takes
+# 55.5 ms from the netflix table's 480,189 user rows at every pad width
+# from 2,048 to 16,384 (13.2 ns an entry, 11.7 of it the gather) and
+# 14.3 ms from a 17,770-row table (3.4 ns); `_gather_entry_ns` between
+# them.  The dense form costs by the (row, opposite row) pair where its
+# weights are whole numbers (three bf16 passes hold them): against
+# 480,189 rows 929 rows in 66.6 ms, 1,859 in 126.9, 3,717 in 247.3
+# (0.149, 0.142, 0.139 ns); twice that for the implicit form's float32
+# weights.  So against the netflix user table a K = 4,096 row gathers in
+# 54 us and would take 67 dense, a K = 8,192 one 108 against 67.
+# Below ``_DENSE_MIN_COUNT`` ratings a row's whole gather is tens of
+# microseconds and the block's fixed cost wins nothing, which keeps
+# every small table (the CPU tests', fold-in's, a catalogue's) on the
+# gathered path.
+_GATHER_ENTRY_NS = ((17_770, 3.4), (480_189, 13.2))
+_DENSE_PAIR_NS = 0.14
 _DENSE_MIN_COUNT = 4096
 
-# share of one device's memory that one side's dense blocks may take,
-# and the bytes of a slot: a float32 rating and a count that is int32
-# where int8 does not hold it
+# most bytes of one block's outer products, ``[_DENSE_BLOCK_ROWS, R^2]``
+# float32 (`_dense_normal_equations`): no dense form from rank 363
+_DENSE_OUTER_BYTES = 2 << 30
+
+# share of one device's memory that one side's dense blocks may take
 _DENSE_MEMORY_SHARE = 4
-_DENSE_SLOT_BYTES = 8
 
 
-def dense_min_count(n_opposite: int, rank: int) -> Optional[int]:
+def _gather_entry_ns(n_opposite: int) -> float:
+    """ns a padded entry of a gathered chunk costs against a table of
+    ``n_opposite`` rows: `_GATHER_ENTRY_NS` at the two tables measured,
+    interpolated by the logarithm of the rows between them.  Outside
+    them the nearer reading is held: a guess, measured at no such
+    table (a sharded table, the largest, never asks)."""
+    (n0, ns0), (n1, ns1) = _GATHER_ENTRY_NS
+    at = math.log(max(n_opposite, 1) / n0) / math.log(n1 / n0)
+    return ns0 + min(max(at, 0.0), 1.0) * (ns1 - ns0)
+
+
+def dense_min_count(n_opposite: int, rank: int,
+                    float_weights: bool) -> Optional[int]:
     """Fewest ratings with which a row is staged DENSE against an
     opposite table of ``n_opposite`` rows, or None where no row is.
 
-    The gather costs by the padded entry, whatever the rank; the dense
-    form by the opposite row times R^2.  So a row is dense from a share
-    of the opposite table that grows with (rank / 64)^2, and never once
-    that share reaches the whole table; below ``_DENSE_MIN_COUNT``
-    ratings a row's whole gather is tens of microseconds and the block's
-    fixed cost wins nothing, which keeps every small table (the CPU
-    tests', fold-in's, a catalogue's) on the gathered path."""
-    share = _DENSE_SHARE_AT_RANK_64 * (rank / 64) ** 2
-    if share >= 1:
+    A gathered row costs its pad width K (the next power of two of its
+    ratings) times `_gather_entry_ns`, whatever the rank; a dense one
+    ``n_opposite`` times `_DENSE_PAIR_NS` x (rank / 64)^2, twice that
+    where its weights are float32 (``float_weights``: the implicit form
+    over ratings that are not whole numbers, `dense_slots`).  So a row
+    is dense from the least K that pays, and never once that K would
+    be the whole table or a block's outer products pass
+    ``_DENSE_OUTER_BYTES``; and from ``_DENSE_MIN_COUNT`` ratings."""
+    share = (_DENSE_PAIR_NS * (rank / 64) ** 2 * (2 if float_weights else 1)
+             / _gather_entry_ns(n_opposite))
+    if share >= 1 or _DENSE_BLOCK_ROWS * 4 * rank ** 2 > _DENSE_OUTER_BYTES:
         return None
-    return max(_DENSE_MIN_COUNT, math.ceil(share * n_opposite))
+    least_k = 1 << (math.ceil(share * n_opposite) - 1).bit_length()
+    return max(_DENSE_MIN_COUNT, least_k // 2 + 1)
+
+
+def dense_slots(widest_pair: int, whole: bool, least: float,
+                most: float) -> tuple:
+    """``(count dtype, rating dtype)`` of a dense block's slots: the
+    narrowest that hold every slot's sum exactly, from the ratings
+    (``whole`` numbers, ``least`` to ``most``) and the most times one
+    pair is held (``widest_pair``).  The count is int8 while a pair is
+    held at most 127 times; the rating sum uint8 where the ratings are
+    whole numbers from 0 and ``widest_pair`` x ``most`` stays under 256
+    (the netflix table's stars: pairs held up to 45 times, sums up to
+    167), float32 else, fractional ratings among them.  No slot is
+    checked after it is built: these bounds are what let it hold."""
+    count = np.dtype(np.int8) if widest_pair <= 127 else np.dtype(np.int32)
+    narrow = whole and least >= 0 and widest_pair * most <= 255
+    return count, np.dtype(np.uint8 if narrow else np.float32)
 
 
 def dense_blocks(n_opposite: int) -> int:
@@ -202,12 +239,13 @@ def dense_blocks(n_opposite: int) -> int:
     return -(-n_opposite // _DENSE_BLOCK_ROWS)
 
 
-def dense_budget_rows(n_opposite: int) -> int:
+def dense_budget_rows(n_opposite: int, slot_bytes: int) -> int:
     """Most rows one side may stage dense: their ``[J, n_opposite]``
-    slots stay under a quarter of ONE device's memory (16 GB against
-    480,189 opposite rows: 1,024 rows), because one device builds a
-    chunk's block whole before a mesh splits its rows."""
-    row_bytes = dense_blocks(n_opposite) * _DENSE_BLOCK_ROWS * _DENSE_SLOT_BYTES
+    slots of ``slot_bytes`` (`dense_slots`) stay under a quarter of ONE
+    device's memory (16 GB against 480,189 opposite rows: 4,096 rows at
+    two bytes, 1,024 at five), because one device builds a chunk's
+    block whole before a mesh splits its rows."""
+    row_bytes = dense_blocks(n_opposite) * _DENSE_BLOCK_ROWS * slot_bytes
     return _rows_in_memory_share(row_bytes, _DENSE_MEMORY_SHARE)
 
 
@@ -873,14 +911,82 @@ def _dense_pieces(bucket: Bucket) -> tuple:
     )
 
 
+# `_slot_stats` counts one pair's repeats up to this many (2^8): more
+# would change no slot of `dense_slots`
+_WIDEST_PAIR_SEEN = 256
+
+
+@jax.jit
+def _slot_stats(col, val, starts):
+    """What `dense_slots` decides from, of a COO grouped by row with the
+    opposite ids ascending inside a row (``starts``: each row's first
+    place): the most times one pair is held (``_WIDEST_PAIR_SEEN`` where
+    it is that or more), whether every rating is a whole number, the
+    least rating and the greatest.
+
+    A pair held L times is a run of L - 1 places that each hold the pair
+    of the place before (``again``).  No prefix scan finds the longest:
+    compiled for a v5e, one over the netflix table's 100 M ratings takes
+    20 to 32 s, this form 3.  Runs of 2^j are found by doubling, the longest by
+    binary lifting: the widest run that still extends it, widest
+    first."""
+    n = col.shape[0]
+    first = jnp.zeros(n, bool).at[starts].set(True, mode="drop")
+    again = (col == jnp.roll(col, 1)) & ~first
+    steps = _WIDEST_PAIR_SEEN.bit_length() - 1
+
+    def back(a, s):
+        """``a[p - s]`` at p, False before the first place"""
+        pad = _WIDEST_PAIR_SEEN
+        return jax.lax.dynamic_slice(jnp.pad(a, (pad, 0)), (pad - s,), (n,))
+
+    # levels[j][p]: the 2^j places up to p all hold again
+    levels = [again]
+    for j in range(steps - 1):
+        levels.append(levels[j] & back(levels[j], 1 << j))
+    run, length = jnp.ones(n, bool), jnp.int32(0)
+    for j in reversed(range(steps)):
+        longer = run & back(levels[j], length)
+        found = longer.any()
+        run = jnp.where(found, longer, run)
+        length += jnp.where(found, 1 << j, 0)
+    return (length + 1, jnp.all(val == jnp.round(val)), jnp.min(val),
+            jnp.max(val))
+
+
+def _slot_sums(at, x, shape, dtype):
+    """``x`` summed into ``zeros(shape, dtype)`` at ``at`` (indices past
+    the first axis's end dropped).  One-byte slots are summed four to a
+    uint32 word, byte k of word w holding slot ``k * shape[-1] / 4 + w``,
+    and the bytes then laid out in slot order: on a v5e a scatter of
+    values into one-byte slots compiled for 75 to 108 s at the netflix
+    item side's shapes, this form builds cold in 17 s (an int8 count
+    and a float32 sum in 20).  A sum that left its byte would spill
+    into the next slot; `dense_slots` sizes the slots so that none
+    does."""
+    if np.dtype(dtype).itemsize != 1:
+        return jnp.zeros(shape, dtype).at[at].add(x.astype(dtype),
+                                                  mode="drop")
+    *lead, slot = at
+    quarter = shape[-1] // 4
+    shift = (8 * (slot // quarter)).astype(jnp.uint32)
+    words = jnp.zeros((*shape[:-1], quarter), jnp.uint32).at[
+        (*lead, slot % quarter)].add(x.astype(jnp.uint32) << shift,
+                                     mode="drop")
+    return jnp.concatenate([(words >> 8 * k) & 0xFF for k in range(4)],
+                           axis=-1).astype(dtype)
+
+
 @xray.instrument("als.dense_block")
-@functools.partial(jax.jit, static_argnames=("rows", "blocks", "count_dtype"))
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "blocks", "count_dtype",
+                                    "rating_dtype"))
 def _dense_block(c_sorted, v_sorted, piece_row, piece_start, piece_len, *,
-                 rows: int, blocks: int, count_dtype):
+                 rows: int, blocks: int, count_dtype, rating_dtype):
     """One dense chunk's resident ``[blocks, rows, _DENSE_BLOCK_ROWS]``
     arrays, built once at staging: how many ratings row j holds of
     opposite row u (0 or 1 in a table without repeated pairs) and their
-    sum, with its float32 bits, at ``[u // block, j, u % block]``."""
+    sum, at ``[u // block, j, u % block]``."""
     idx, val = _expand_bucket(c_sorted, v_sorted, piece_start, piece_len,
                               _DENSE_PIECE)
     # a padding slot's block lies past the last: the scatter drops it
@@ -888,26 +994,20 @@ def _dense_block(c_sorted, v_sorted, piece_row, piece_start, piece_len, *,
                       idx // _DENSE_BLOCK_ROWS, blocks)
     at = (block, piece_row[:, None], idx % _DENSE_BLOCK_ROWS)
     shape = (blocks, rows, _DENSE_BLOCK_ROWS)
-    return (
-        jnp.zeros(shape, count_dtype).at[at].add(1, mode="drop"),
-        jnp.zeros(shape, jnp.float32).at[at].add(val, mode="drop"),
+    return (_slot_sums(at, jnp.ones_like(idx), shape, count_dtype),
+            _slot_sums(at, val, shape, rating_dtype))
+
+
+def _dense_chunk(columns, bucket: Bucket, blocks: int, put,
+                 slots: tuple) -> tuple:
+    """``(count, rating)`` of one dense chunk (`_dense_block`) in the
+    ``slots`` `dense_slots` chose."""
+    count_dtype, rating_dtype = slots
+    return _dense_block(
+        *columns, *(put(a) for a in _dense_pieces(bucket)),
+        rows=len(bucket.rows), blocks=blocks, count_dtype=count_dtype,
+        rating_dtype=rating_dtype,
     )
-
-
-def _dense_chunk(columns, bucket: Bucket, blocks: int, put) -> tuple:
-    """``(count, rating)`` of one dense chunk (`_dense_block`), the
-    counts as int8 unless a pair held 128 times or more wrapped one:
-    the counts then no longer add up to the chunk's ratings, and they
-    are built again as int32."""
-    pieces = [put(a) for a in _dense_pieces(bucket)]
-    for count_dtype in (jnp.int8, jnp.int32):
-        count, rating = _dense_block(
-            *columns, *pieces, rows=len(bucket.rows), blocks=blocks,
-            count_dtype=count_dtype,
-        )
-        if int(count.sum(dtype=jnp.int32)) == int(bucket.counts.sum()):
-            break
-    return count, rating
 
 
 def _dense_normal_equations(opp: jax.Array, count: jax.Array,
@@ -941,9 +1041,10 @@ def _dense_normal_equations(opp: jax.Array, count: jax.Array,
         c, v = c.astype(f32), v.astype(f32)
         z = (o[:, :, None] * o[:, None, :]).reshape(block_rows, r * r)
         # alpha scales the sums below, so the weights here are the
-        # resident blocks as they are (whole-number counts take the MXU
-        # three bf16 passes at `highest`, not six); the explicit form
-        # has no use for the third sum, a 64th of the first one's work
+        # resident blocks as they are (whole numbers from integer slots
+        # take the MXU three bf16 passes at `highest`, not six); the
+        # explicit form has no use for the third sum, a 64th of the
+        # first one's work
         terms = (dot(v if implicit else c, z), dot(v, o), dot(c, o))
         return tuple(s + t for s, t in zip(sums, terms)), None
 
@@ -1429,7 +1530,7 @@ def _solve_buckets(
     from inside its own ``shard_map`` body and leaves it None.
 
     A DENSE bucket (``k == DENSE_K``; staged under replicated placement
-    with the full solve and no fused kernel, by `ALSTrainer._dense_caps`)
+    with the full solve and no fused kernel, by `ALSTrainer._dense_slots`)
     carries its ``[blocks, J, block_rows]`` counts and ratings in the
     place of ``idx`` and ``val``: its normal equations are a blocked
     matmul over ALL of ``opp`` (`_dense_normal_equations`), then the
@@ -2210,21 +2311,31 @@ class ALSTrainer:
                 sides = self._stage_device(u, i, v, nu, ni, n_dev)
                 self._user_side, self._item_side = sides
             else:
+                u, i = np.asarray(u), np.asarray(i)
+                counts_u = np.bincount(u, minlength=nu)
+
+                def by_pair():
+                    order = np.lexsort((i, u))
+                    return (i[order], np.asarray(v, np.float32)[order],
+                            np.cumsum(counts_u) - counts_u)
+
+                slots = self._dense_slots(
+                    counts_u, np.bincount(i, minlength=ni), by_pair)
                 self._user_side = self._stage(
                     build_bucket_layout(
                         u, i, v, nu, cfg.min_bucket_k,
                         cfg.max_ratings_per_row, batch_multiple=n_dev,
-                        **caps, **self._dense_caps(ni),
+                        **caps, **self._dense_caps(ni, slots),
                     ),
-                    ni,
+                    ni, slots,
                 )
                 self._item_side = self._stage(
                     build_bucket_layout(
                         i, u, v, ni, cfg.min_bucket_k,
                         cfg.max_ratings_per_row, batch_multiple=n_dev,
-                        **caps, **self._dense_caps(nu),
+                        **caps, **self._dense_caps(nu, slots),
                     ),
-                    nu,
+                    nu, slots,
                 )
         if self.sharded:
             self._build_sharded_halves()
@@ -2276,6 +2387,8 @@ class ALSTrainer:
                              for name, side in sides.items()},
             "denseBytes": per_side("dense_bytes"),
             "denseChunks": per_side("dense_chunks"),
+            "denseRatingDtype": {name: side.get("dense_rating_dtype")
+                                 for name, side in sides.items()},
         }
         logger.info("ALS staged: %s", staged)
         tower.note_event("als_staged", **staged)
@@ -2313,21 +2426,45 @@ class ALSTrainer:
         cfg = self.cfg
         return _block_sweeps(cfg.solver_mode, cfg.subspace_size, cfg.rank)
 
-    def _dense_caps(self, n_opposite: int) -> dict:
-        """What the replicated staging paths hand `_assemble_buckets`
-        besides, for a side whose opposite table has ``n_opposite``
-        rows: from how many ratings a row is staged dense, and how many
-        rows may be.  Nothing, so no dense row, where the gathered rows
+    def _dense_slots(self, counts_u, counts_i, grouped) -> Optional[tuple]:
+        """The slots of the replicated staging's dense blocks
+        (`dense_slots`, from `_slot_stats` of the COO that ``grouped()``
+        returns), or None where no row can be staged dense: no row of
+        either side (``counts_u``, ``counts_i``) holds the ratings
+        `dense_min_count` asks at the least, or the gathered rows
         themselves are consumed: the subspace sweep and the fused
         kernel.  (The sharded staging paths never ask: the opposite
         table is a shard there, and the dense form would be a partial
         Gram and a ``psum``, which is not built.)"""
         cfg = self.cfg
         if cfg.solver_mode == "subspace" or cfg.solver == "fused":
+            return None
+        if not any(
+            least is not None and counts.max(initial=0) >= least
+            for counts, n_opposite in ((counts_u, self.n_items),
+                                       (counts_i, self.n_users))
+            for least in [dense_min_count(n_opposite, cfg.rank, False)]
+        ):
+            return None
+        widest, whole, least, most = _slot_stats(*grouped())
+        return dense_slots(int(widest), bool(whole), float(least),
+                           float(most))
+
+    def _dense_caps(self, n_opposite: int, slots: Optional[tuple]) -> dict:
+        """What the replicated staging paths hand `_assemble_buckets`
+        besides, for a side whose opposite table has ``n_opposite``
+        rows: from how many ratings a row is staged dense, and how many
+        rows may be, in the ``slots`` of `_dense_slots`; nothing, so no
+        dense row, where those are None."""
+        if slots is None:
             return {}
+        cfg = self.cfg
+        count, rating = slots
         return {
-            "dense_min": dense_min_count(n_opposite, cfg.rank),
-            "dense_rows": dense_budget_rows(n_opposite),
+            "dense_min": dense_min_count(
+                n_opposite, cfg.rank, cfg.implicit and rating == np.float32),
+            "dense_rows": dense_budget_rows(
+                n_opposite, count.itemsize + rating.itemsize),
         }
 
     def _plan_solves(self) -> None:
@@ -2832,18 +2969,6 @@ class ALSTrainer:
         starts_i = np.concatenate(
             ([0], np.cumsum(counts_i)[:-1])
         ).astype(np.int32)
-        cfg = self.cfg
-        caps = self._chunk_caps(n_dev)
-        buckets_u = _assemble_buckets(
-            np.asarray(counts_u, np.int32), np.asarray(starts_u, np.int32),
-            nu, cfg.min_bucket_k, cfg.max_ratings_per_row,
-            batch_multiple=n_dev, **caps, **self._dense_caps(ni),
-        )
-        buckets_i = _assemble_buckets(
-            counts_i, starts_i, ni, cfg.min_bucket_k,
-            cfg.max_ratings_per_row, batch_multiple=n_dev, **caps,
-            **self._dense_caps(nu),
-        )
 
         def compact_ids(x, n):
             return x.astype(np.uint16) if n <= (1 << 16) else \
@@ -2881,22 +3006,40 @@ class ALSTrainer:
         # (8 bytes a padded entry) and both sides' columns together
         # would be this path's peak
         del i_dev, v_dev
-        user_side = self._stage_side(cs_u, vs_u, buckets_u, ni)
+        # the item side's columns hold each item's users in ascending
+        # order (a stable sort of the user-ordered COO)
+        slots = self._dense_slots(
+            counts_u, counts_i, lambda: (cs_i, vs_i, put(starts_i)))
+        cfg = self.cfg
+        caps = self._chunk_caps(n_dev)
+        buckets_u = _assemble_buckets(
+            np.asarray(counts_u, np.int32), np.asarray(starts_u, np.int32),
+            nu, cfg.min_bucket_k, cfg.max_ratings_per_row,
+            batch_multiple=n_dev, **caps, **self._dense_caps(ni, slots),
+        )
+        buckets_i = _assemble_buckets(
+            counts_i, starts_i, ni, cfg.min_bucket_k,
+            cfg.max_ratings_per_row, batch_multiple=n_dev, **caps,
+            **self._dense_caps(nu, slots),
+        )
+        user_side = self._stage_side(cs_u, vs_u, buckets_u, ni, slots)
         del cs_u, vs_u
-        return user_side, self._stage_side(cs_i, vs_i, buckets_i, nu)
+        return user_side, self._stage_side(cs_i, vs_i, buckets_i, nu, slots)
 
-    def _stage(self, layout: BucketLayout, n_opposite: int):
+    def _stage(self, layout: BucketLayout, n_opposite: int, slots):
         """Transfer the sorted COO + bucket index vectors to the device."""
         return self._stage_side(
-            layout.col_sorted, layout.val_sorted, layout.buckets, n_opposite
+            layout.col_sorted, layout.val_sorted, layout.buckets, n_opposite,
+            slots,
         )
 
-    def _stage_side(self, c_sorted, v_sorted, buckets, n_opposite):
+    def _stage_side(self, c_sorted, v_sorted, buckets, n_opposite, slots):
         """Place one side's arrays (host or already on the device) and
         expand every bucket, once, to what the sweeps read: a gathered
         bucket to its padded block, a dense one to its counts and
-        ratings over all ``n_opposite`` opposite rows.  The columns are
-        not read again and are dropped here."""
+        ratings over all ``n_opposite`` opposite rows in ``slots``
+        (`_dense_slots`).  The columns are not read again and are
+        dropped here."""
         if self.mesh is not None:
             def put(*spec):
                 sharding = NamedSharding(self.mesh, P(*spec))
@@ -2939,7 +3082,8 @@ class ALSTrainer:
             ks=tuple(b.k for b in gathered),
         ))
         resident = jax.block_until_ready([
-            _dense_chunk(columns, b, dense_blocks(n_opposite), put_rep)
+            _dense_chunk(columns, b, dense_blocks(n_opposite), put_rep,
+                         slots)
             for b in dense
         ])
         expand_s = time.perf_counter() - t0
@@ -2964,6 +3108,8 @@ class ALSTrainer:
             "dense_rows": sum(int((b.counts > 0).sum()) for b in dense),
             "dense_bytes": sum(a.nbytes for blk in resident for a in blk),
             "dense_chunks": len(dense),
+            "dense_rating_dtype": (str(resident[0][1].dtype) if resident
+                                   else None),
         }
 
     def _stage_side_sharded(self, layout: BucketLayout, n_dev: int):

@@ -172,9 +172,9 @@ def _staged_columns(monkeypatch):
     seen = []
     stage_side = ALSTrainer._stage_side
 
-    def spy(self, c_sorted, v_sorted, buckets, n_opposite):
+    def spy(self, c_sorted, v_sorted, buckets, *rest):
         seen.append((np.asarray(c_sorted), np.asarray(v_sorted), buckets))
-        return stage_side(self, c_sorted, v_sorted, buckets, n_opposite)
+        return stage_side(self, c_sorted, v_sorted, buckets, *rest)
 
     monkeypatch.setattr(ALSTrainer, "_stage_side", spy)
     return seen

@@ -271,7 +271,8 @@ def test_a_dense_bucket_keeps_its_own_form(monkeypatch):
     """A dense chunk beside K = 8 buckets that take the K x K form: the
     dense rows go through `_dense_normal_equations`, every other row's
     bucket by the rule."""
-    monkeypatch.setattr(als, "dense_min_count", lambda n, rank: 100)
+    monkeypatch.setattr(als, "dense_min_count",
+                        lambda n, rank, float_weights: 100)
     seen = _spy(monkeypatch)
     u, i, v, nu, ni = _ratings(n_users=120)
     # one user who holds 150 of the 400 items

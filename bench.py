@@ -136,12 +136,6 @@ def _parse_args(argv=None):
         "the JSON line carries rmse_holdout next to train_rmse (north "
         "star: RMSE parity, not just speed).  0 disables",
     )
-    ap.add_argument("--gather-dtype", default=None,
-                    choices=("float32", "bfloat16"),
-                    help="ALS opposite-table gather dtype; A/B the "
-                    "bandwidth optimization.  Unset = float32, except "
-                    "the orchestrated attempt chain may try bfloat16 "
-                    "first; an EXPLICIT value pins every attempt")
     ap.add_argument("--gather-mode", default=None,
                     choices=("row", "grouped"),
                     help="ALS gather form: plain row take vs tile-"
@@ -153,10 +147,9 @@ def _parse_args(argv=None):
                     "transfer + on-device sort (auto: device at this "
                     "bench's full scale)")
     ap.add_argument("--solver", default=None,
-                    choices=("xla", "pallas", "fused"),
+                    choices=("xla", "pallas"),
                     help="batched SPD solver override (default: "
-                    "ALSConfig default); 'fused' = single-pass "
-                    "gather+Gram+solve kernel")
+                    "ALSConfig default)")
     ap.add_argument("--solver-mode", default=None,
                     choices=("full", "subspace"),
                     help="rank-sweep strategy: 'full' = R×R solve per "
@@ -225,15 +218,6 @@ def _parse_args(argv=None):
         "to localize the per-iteration cost",
     )
     ap.add_argument(
-        "--fused-ab",
-        action="store_true",
-        help="fenced fused-vs-unfused A/B on the user half's "
-        "gather+Gram wall: times the unfused gather+Gram phase and the "
-        "fused full half on identical staged data and appends BOTH as "
-        "canonical BENCH_HISTORY.jsonl records so tools/bench_gate.py "
-        "gates the Gram phase; implies --inner semantics",
-    )
-    ap.add_argument(
         "--straggler-ab",
         action="store_true",
         help="fenced clean-vs-straggler A/B of the coded sharded "
@@ -283,8 +267,7 @@ def _prepare(args):
         extra["subspace_size"] = args.subspace_block
     cfg = ALSConfig(
         rank=args.rank, num_iterations=args.iters, lam=0.01,
-        seed=args.seed, gather_dtype=args.gather_dtype or "float32",
-        gather_mode=args.gather_mode or "row",
+        seed=args.seed, gather_mode=args.gather_mode or "row",
         **extra,
     )
     return jax, (u, i, v, n_users, n_items), mesh, cfg
@@ -390,7 +373,7 @@ def _run_phase_probe(jax, trainer, U, V, cfg, emit) -> None:
     ``full_half`` is the real `_half` including solves AND the
     factor-table scatter.  The truncations run the REAL kernel
     (`models/als._solve_buckets` with ``stop_after``), so implicit mode,
-    weighted-λ, precision, gather dtype, and solver choice are all
+    weighted-λ, precision, gather mode, and solver choice are all
     whatever the trainer is configured with — the deltas attribute the
     per-iteration time to gather vs MXU vs solver vs scatter, the
     decision data for docs/ARCHITECTURE.md 'Measured performance'.
@@ -414,8 +397,7 @@ def _run_phase_probe(jax, trainer, U, V, cfg, emit) -> None:
             ks=ks, implicit=cfg.implicit,
             weighted_lambda=cfg.weighted_lambda,
             precision=cfg.matmul_precision, solver=cfg.solver,
-            gather_dtype=cfg.gather_dtype, gather_mode=cfg.gather_mode,
-            solver_mode=cfg.solver_mode,
+            gather_mode=cfg.gather_mode, solver_mode=cfg.solver_mode,
             subspace_size=cfg.subspace_size,
             upd_table=upd_tab, stop_after=stop_after,
         )
@@ -450,137 +432,6 @@ def _run_phase_probe(jax, trainer, U, V, cfg, emit) -> None:
         timed(lambda: trainer._half(jnp.array(U, copy=True), V,
                                     trainer._user_side)),
     )
-
-
-def run_fused_ab(args) -> None:
-    """Fenced fused-vs-unfused A/B on the gather+Gram wall.
-
-    Stages ONE dataset, then times — all fenced, warm-first, identical
-    bucket layout — (a) the unfused user half truncated after
-    gather+Gram (``stop_after="gram"``: the 303 + 793 ms wall the fused
-    kernel exists to kill) and (b) the FULL fused user half (the fused
-    kernel is single-pass, so its gather+Gram cannot be timed apart
-    from its in-kernel solve — the comparison is therefore conservative
-    against the fused arm: it carries its solve and the factor scatter
-    while the unfused arm carries neither).  Both measurements append
-    to BENCH_HISTORY.jsonl as canonical fenced records
-    (``als_user_half_unfused_gather_gram_seconds`` /
-    ``als_user_half_fused_seconds``) so ``tools/bench_gate.py`` gates
-    the Gram phase like any other trajectory metric, keyed per
-    (metric, platform, scale).
-
-    A fused kernel that does not compile raises in the trainer, so
-    both arms measure the solver they name.
-    """
-    import dataclasses
-    import functools
-
-    jax, (u, i, v, n_users, n_items), mesh, cfg0 = _prepare(args)
-    import jax.numpy as jnp
-
-    from predictionio_tpu.models.als import (
-        ALSConfig, ALSTrainer, _solve_buckets,
-    )
-
-    # the two arms: identical data/layout knobs, only the solver path
-    # differs.  The unfused baseline pins solver="xla".
-    base = {
-        f.name: getattr(cfg0, f.name) for f in dataclasses.fields(cfg0)
-    }
-    cfg_un = ALSConfig(**{**base, "solver": "xla"})
-    cfg_fu = ALSConfig(**{**base, "solver": "fused"})
-
-    reps = 3
-    platform = str(jax.default_backend())
-
-    def emit_and_record(rec, summary_key):
-        print(json.dumps(rec), flush=True)
-        try:
-            gate = _bench_gate()
-            gate.append_history(HISTORY_PATH, rec)
-            # the fused-path record also rides BENCH_PR<k>.json, nested
-            # so it never clobbers the orchestrated train record at the
-            # top level
-            gate.write_pr_summary(rec, key=summary_key)
-        except Exception as e:  # noqa: BLE001 — the print already landed
-            print(f"# WARNING: could not record fused A/B: {e}",
-                  file=sys.stderr, flush=True)
-
-    def timed(fn):
-        jax.block_until_ready(fn())  # warm: compile outside the span
-        t0 = time.time()
-        for _ in range(reps):
-            out = fn()
-        jax.block_until_ready(out)
-        return (time.time() - t0) / reps
-
-    results = {}
-    for arm, cfg in (("unfused", cfg_un), ("fused", cfg_fu)):
-        trainer = ALSTrainer((u, i, v), n_users, n_items, cfg, mesh=mesh,
-                             staging=args.staging)
-        U, V = trainer.init_factors()
-        side = trainer._user_side
-        lam = jnp.asarray(cfg.lam, jnp.float32)
-        alpha = jnp.asarray(cfg.alpha, jnp.float32)
-        common = dict(
-            unit="s", platform=platform, scale=args.scale, fenced=True,
-            rank=cfg.rank, gather_dtype=cfg.gather_dtype,
-            precision=cfg.matmul_precision, n_ratings=int(len(v)),
-        )
-        if arm == "unfused":
-
-            @functools.partial(jax.jit, static_argnames=("ks", "stop_after"))
-            def probe(upd_tab, opp, buckets, lam_t, alpha_t, *, ks,
-                      stop_after):
-                return _solve_buckets(
-                    None, opp, buckets, lam_t, alpha_t, ks=ks,
-                    implicit=cfg.implicit,
-                    weighted_lambda=cfg.weighted_lambda,
-                    precision=cfg.matmul_precision, solver=cfg.solver,
-                    gather_dtype=cfg.gather_dtype,
-                    gather_mode=cfg.gather_mode,
-                    solver_mode=cfg.solver_mode,
-                    subspace_size=cfg.subspace_size, upd_table=upd_tab,
-                    stop_after=stop_after,
-                )
-
-            dt = timed(lambda: probe(
-                U, V, side["buckets"], lam, alpha, ks=side["ks"],
-                stop_after="gram",
-            ))
-            results[arm] = dt
-            emit_and_record({
-                "metric": "als_user_half_unfused_gather_gram_seconds",
-                "value": round(dt, 5), "solver": cfg.solver,
-                **common,
-            }, "fused_ab_unfused")
-        else:
-            # the fused kernel is one pass: time the FULL half (its
-            # gather+Gram carries the in-kernel solve + the scatter)
-            dt = timed(
-                lambda: trainer._half(jnp.array(U, copy=True), V, side)
-            )
-            results[arm] = dt
-            emit_and_record({
-                "metric": "als_user_half_fused_seconds",
-                "value": round(dt, 5),
-                "solver": cfg.solver,
-                **common,
-            }, "fused_ab_fused")
-        del trainer, U, V
-
-    # derived headline (not a history record: a ratio of two gated
-    # metrics would double-judge the same movement); conservative by
-    # construction — the fused arm's time includes its solve + scatter
-    print(json.dumps({
-        "metric": "fused_vs_unfused_gather_gram_speedup",
-        "value": round(results["unfused"] / results["fused"], 3)
-        if results.get("fused") else None,
-        "note": "unfused gather+Gram phase over the FULL fused half "
-                "(fused includes solve+scatter); >= 1 means the fused "
-                "kernel beats the wall it replaces",
-        "platform": platform, "scale": args.scale,
-    }), flush=True)
 
 
 def run_straggler_ab(args) -> None:
@@ -791,7 +642,6 @@ def run_inner(args) -> None:
                     if cfg.solver_mode == "subspace" else {}
                 ),
                 "precision": cfg.matmul_precision,
-                "gather_dtype": cfg.gather_dtype,
                 "gather_mode": cfg.gather_mode,
                 # the timed train covers the (1-holdout) split; recorded
                 # so the workload identity is explicit in every artifact
@@ -1245,9 +1095,6 @@ def main() -> None:
     if args.pipeline:
         run_pipeline(args)
         return
-    if args.fused_ab:
-        run_fused_ab(args)
-        return
     if args.straggler_ab:
         run_straggler_ab(args)
         return
@@ -1265,9 +1112,7 @@ def main() -> None:
         "--scale", str(args.scale), "--rank", str(args.rank),
         "--iters", str(args.iters), "--seed", str(args.seed),
         "--staging", args.staging, "--holdout", str(args.holdout),
-    ] + (["--gather-dtype", args.gather_dtype]
-         if args.gather_dtype else []) \
-      + (["--gather-mode", args.gather_mode]
+    ] + (["--gather-mode", args.gather_mode]
          if args.gather_mode else []) \
       + (["--solver", args.solver] if args.solver else []) \
       + (["--solver-mode", args.solver_mode] if args.solver_mode else []) \
@@ -1299,20 +1144,13 @@ def main() -> None:
         print(f"# ERROR: no accelerator ({probe_err}); nothing measured",
               file=sys.stderr, flush=True)
         sys.exit(1)
-    # attempt the best configuration first — Gauss-Jordan Pallas solves
-    # + bf16 gather + bf16x3 Gram — then the conservative all-XLA/f32
-    # config.  Explicit --solver/--precision/--gather-dtype flags pin a
-    # single attempt.
+    # attempt the best configuration first — Pallas solves + bf16x3
+    # Gram — then the conservative all-XLA config.  Explicit
+    # --solver/--precision flags pin a single attempt.
     attempts = [common]
-    if (
-        args.solver is None
-        and args.precision is None
-        and args.gather_dtype is None  # explicit dtype pins attempts
-    ):
+    if args.solver is None and args.precision is None:
         attempts.insert(
-            0, common + ["--solver", "pallas", "--precision", "high",
-                         "--gather-dtype", "bfloat16"]
-        )
+            0, common + ["--solver", "pallas", "--precision", "high"])
     errs = []
     # progress-aware supervision: a slow-but-advancing attempt keeps its
     # slot until the budget genuinely runs out, while a stalled attempt

@@ -17,8 +17,7 @@ chip its children need.
      recommendation` (default solver, "auto": on the chip the
      `ops/solve.py` Cholesky kernel, which the summary's `solve_path`
      must say).
-  3. two more `train`s, `"solver": "pallas"` and `"solver": "fused"`:
-     the same kernel forced and the fused gather+Gram+solve kernel at
+  3. one more `train`, `"solver": "pallas"`: the same kernel forced at
      rank 64, compiled, not interpreted, not degraded.
   4. `deploy --port 0 --port-file`: single queries, one filtered query
      (`blackList`, the exact-scan branch), one concurrent burst wide
@@ -439,9 +438,8 @@ class Smoke:
 
         auto = self.engine_dir("auto", {})
         iid = self.train("auto", auto, device)
-        for solver in ("pallas", "fused"):
-            self.train(solver, self.engine_dir(solver, {"solver": solver}),
-                       device)
+        self.train("pallas", self.engine_dir("pallas", {"solver": "pallas"}),
+                   device)
         self.serve("auto", auto, iid, device)
         if device["count"] > 1:
             # more than one chip: sharded ALS and the ring top-k, once
@@ -452,7 +450,7 @@ class Smoke:
                 raise SmokeFailure(f"sharded train: {self.trains[-1]}")
             self.serve("sharded", ring, ring_iid, device)
         self.check_logs()
-        for want, got in zip(("auto", "pallas", "fused"), self.trains):
+        for want, got in zip(("auto", "pallas"), self.trains):
             if got["solver"] != want:
                 raise SmokeFailure(f"train {want} ran solver {got}")
         on_chip = device["platform"] == "tpu"

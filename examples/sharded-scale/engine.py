@@ -10,10 +10,7 @@ both the factor matrices AND the rating blocks across the cluster
   capacity scales with total HBM — ALX-style, arXiv 2112.02194),
 * the rating COO is co-partitioned with the bucket rows each device
   solves (`models/als._plan_shard_layout`) so DATA capacity scales with
-  total HBM too, and the int32-offset ceiling applies per shard,
-* ``solver="fused"`` additionally runs each bucket's
-  gather+Gram+solve as one Pallas kernel where a tile plan exists
-  (a kernel that does not compile fails the train).
+  total HBM too, and the int32-offset ceiling applies per shard.
 
 Multi-host, the same layout extends across processes (datasource
 ``coo: "local"`` + `ALSTrainer.distributed`): rating triples travel
@@ -63,8 +60,7 @@ def main() -> None:
     )
     sharded = ALSTrainer(
         (u, i, v), n_users, n_items,
-        ALSConfig(rank=8, num_iterations=4, factor_placement="sharded",
-                  solver="fused"),
+        ALSConfig(rank=8, num_iterations=4, factor_placement="sharded"),
         mesh=mesh,
     )
     L = sharded.coo_shard_entries

@@ -113,21 +113,39 @@ def extract_params(
     camelCase keys (and reserved words like ``lambda``): camelCase is
     auto-converted to snake_case, and classes may declare
     ``__param_aliases__ = {"lambda": "lam"}`` for the rest.
+
+    ``__retired_params__ = {"key": value}`` names keys a class no longer
+    has, which stored instance records still hold: a retired key that
+    carries ``value`` is dropped, any other value is refused.
+    ``__retired_values__ = {"key": {"old": "new"}}`` reads a string value
+    a kept key no longer accepts as the value that replaced it.
     """
     if not dataclasses.is_dataclass(cls):
         raise ParamsError(f"{cls!r} is not a params dataclass")
     json_dict = dict(json_dict or {})
     aliases = getattr(cls, "__param_aliases__", {})
+    retired = getattr(cls, "__retired_params__", {})
     field_names = {f.name for f in dataclasses.fields(cls) if f.init}
     renamed = {}
     for k, v in json_dict.items():
         if k in aliases:
             k = aliases[k]
-        elif k not in field_names and _snake(k) in field_names:
+        elif k not in field_names and _snake(k) in field_names | set(retired):
             k = _snake(k)
         if k in renamed:
             raise ParamsError(f"{_path}: duplicate key '{k}' after aliasing")
         renamed[k] = v
+    for k in set(renamed) & set(retired):
+        v = renamed.pop(k)
+        if v != retired[k]:
+            raise ParamsError(
+                f"{_path}.{k}: '{k}' was removed from {cls.__name__}; "
+                f"only its old default {retired[k]!r} is accepted, "
+                f"got {v!r}")
+    for k, moved in getattr(cls, "__retired_values__", {}).items():
+        v = renamed.get(k)
+        if isinstance(v, str) and v in moved:
+            renamed[k] = moved[v]
     json_dict = renamed
     hints = typing.get_type_hints(cls)
     kwargs: dict[str, Any] = {}

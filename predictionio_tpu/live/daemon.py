@@ -171,7 +171,12 @@ class FoldInRunner:
         if config_of is not None:
             try:
                 cfg = config_of()
-            except Exception:
+            except Exception as e:
+                # a record whose params no longer build a config:
+                # fold in at the defaults, and say so
+                logger.warning("fold-in: the algorithm's ALSConfig "
+                               "cannot be built (%s); using the "
+                               "defaults at its rank", e)
                 cfg = None
         self.cfg = cfg or ALSConfig(
             rank=int(self.model.user_factors.shape[1])

@@ -96,16 +96,12 @@ class FoldInSolver:
     """Fixed-capacity row solver over a frozen opposite table.
 
     One instance per daemon/session: it owns the jitted kernel (so the
-    xray signature history is per-process coherent) and the solver
-    backend its rows are solved with.
+    xray signature history is per-process coherent).
     """
 
     def __init__(self, cfg: ALSConfig, max_k: int = _MAX_K):
         self.cfg = cfg
         self.max_k = max_k
-        # the fused kernel is a whole-table training pass; fold-in
-        # solves a handful of rows with the plain solver instead
-        self.solver = "xla" if cfg.solver == "fused" else cfg.solver
         self._kernel = _jit_foldin()
 
     def padded_shape(
@@ -172,7 +168,7 @@ class FoldInSolver:
             implicit=cfg.implicit,
             weighted_lambda=cfg.weighted_lambda,
             precision=cfg.matmul_precision,
-            solver=self.solver,
+            solver=cfg.solver,
         )
         return np.asarray(out)[:n].astype(np.float32)
 

@@ -62,10 +62,13 @@ from ..storage.columnar import Ratings
 logger = logging.getLogger(__name__)
 
 # cap on the grouped-gather slab intermediate ([chunk, K, G, R]): the
-# slab is G (8-16) times the row gather's output, so it's produced in
+# slab is G (8) times the row gather's output, so it's produced in
 # row-chunks of at most this many bytes and shrunk back to [*, K, R] by
 # the in-slab select before the next chunk materializes
 _GROUPED_SLAB_BYTES = 256 * 1024 * 1024
+
+# rows of one grouped-gather slab: the sublanes of a float32 memory tile
+_GATHER_GROUP_ROWS = 8
 
 __all__ = [
     "ALSConfig",
@@ -122,17 +125,17 @@ def gram_chunk_rows(rank: int, n_dev: int = 1) -> int:
 _GATHER_MEMORY_SHARE = 4
 
 
-def gather_chunk_entries(rank: int, n_dev: int = 1, itemsize: int = 4) -> int:
+def gather_chunk_entries(rank: int, n_dev: int = 1) -> int:
     """Most entries (B*K) a bucket chunk may hold under REPLICATED
     placement over ``n_dev`` devices, so that each device's gathered
-    rows ``[B / n_dev, K, R]`` of ``itemsize`` bytes an element stay
-    under a quarter of its memory at ANY rank (16 GB, float32: 262,144
-    entries, 2.1 GB, at rank 2,048, where ``MAX_ENTRIES_PER_BUCKET``
-    alone would gather 34 GB).  Never more than ``MAX_ENTRIES_PER_BUCKET``,
-    which binds up to rank 128.  A row wider than the bound is a chunk of
-    its own: a row's entries are not split."""
+    float32 rows ``[B / n_dev, K, R]`` stay under a quarter of its
+    memory at ANY rank (16 GB: 262,144 entries, 2.1 GB, at rank 2,048,
+    where ``MAX_ENTRIES_PER_BUCKET`` alone would gather 34 GB).  Never
+    more than ``MAX_ENTRIES_PER_BUCKET``, which binds up to rank 128.  A
+    row wider than the bound is a chunk of its own: a row's entries are
+    not split."""
     return min(MAX_ENTRIES_PER_BUCKET,
-               _rows_in_memory_share(rank * itemsize, _GATHER_MEMORY_SHARE)
+               _rows_in_memory_share(rank * 4, _GATHER_MEMORY_SHARE)
                * n_dev)
 
 
@@ -262,11 +265,6 @@ class ALSConfig:
     # truncate pathological rows beyond this many ratings (0 = no cap)
     max_ratings_per_row: int = 0
     min_bucket_k: int = 8
-    # storage dtype of the factor tables themselves (init + iterates).
-    # Whatever this is, Gram accumulation, regularization, and the SPD
-    # solves always run in f32 (bf16 normal equations are numerically
-    # unsafe); use gather_dtype to cut the hot gather's bandwidth instead
-    compute_dtype: str = "float32"
     # MXU precision for the Gram einsums: "highest" (f32), "high" (bf16x3),
     # "default" (bf16).  RMSE parity wants "highest"; ranking-only workloads
     # can trade down.
@@ -276,11 +274,9 @@ class ALSConfig:
     # for float32 systems of R <= 128 on a TPU backend, lax.linalg
     # everywhere else.  "xla" (lax.linalg) and "pallas" (the kernel,
     # through the interpreter on the CPU backend) force a path for
-    # tests and A/B; "fused" is ops/fused_als.py's single-pass
-    # gather+Gram+solve kernel (buckets too wide for its SMEM index
-    # block keep the lax path).  A kernel the backend's compiler
-    # rejects fails the first half-iteration with the compiler's
-    # message; nothing is substituted for it
+    # tests and A/B.  A kernel the backend's compiler rejects fails the
+    # first half-iteration with the compiler's message; nothing is
+    # substituted for it
     solver: str = "auto"
     # rank-sweep strategy: "full" solves the complete R×R normal
     # equations per row (today's behavior, the default); "subspace"
@@ -295,23 +291,15 @@ class ALSConfig:
     # block width B of the subspace sweep (ALX-friendly: smaller B×B
     # systems pack MORE rows per VMEM tile in the Pallas GJ kernel)
     subspace_size: int = 16
-    # gather_dtype and gather_mode concern the GATHERED buckets only: a
-    # row staged dense (`dense_min_count`) reads the float32 table in
-    # order and gathers nothing.
-    # dtype the opposite factor table is GATHERED in: "float32" (exact,
-    # default) or "bfloat16" — the Gram einsums are gather-bandwidth-bound
-    # (see docs/ARCHITECTURE.md cost model), so a bf16 table halves the
-    # bytes the hot gather moves (and the ICI all-gather in sharded mode)
-    # at a small accuracy cost; solves and accumulation stay f32
-    gather_dtype: str = "float32"
-    # how the opposite rows are fetched: "row" (plain jnp.take) or
-    # "grouped" — gather TILE-ALIGNED groups of 8 (f32) / 16 (bf16)
-    # consecutive rows as one [G*R]-lane slab, then take_along_axis the
-    # wanted row.  A rank-64 row is a fraction of one (8,128) memory
-    # tile, so the plain row gather can move up to 16x (f32) / 32x
-    # (bf16) more bytes than it delivers; grouped reads move whole
-    # tiles usefully.  Exact (same rows, same math) — the A/B is pure
-    # gather bandwidth, measured on-chip by bench.py --gather-mode.
+    # how the GATHERED buckets fetch the opposite rows (a row staged
+    # dense, `dense_min_count`, reads the table in order and gathers
+    # nothing): "row" (plain jnp.take) or "grouped" — gather
+    # TILE-ALIGNED groups of 8 consecutive rows as one [G*R]-lane slab,
+    # then take_along_axis the wanted row.  A rank-64 row is a fraction
+    # of one (8,128) memory tile, so the plain row gather can move up to
+    # 16x more bytes than it delivers; grouped reads move whole tiles
+    # usefully.  Exact (same rows, same math) — the A/B is pure gather
+    # bandwidth, measured on-chip by bench.py --gather-mode.
     gather_mode: str = "row"
     # -- pio-scout serve-time retrieval defaults ------------------------
     # Training never reads these; they ride the config object so one
@@ -330,60 +318,25 @@ class ALSConfig:
         # equality with an else-fallthrough, so a typo'd value would
         # silently run the default path (and these strings now arrive
         # straight from user engine.json files via the templates)
-        if self.gather_dtype not in ("float32", "bfloat16"):
-            raise ValueError(
-                f"gather_dtype must be 'float32' or 'bfloat16', "
-                f"got {self.gather_dtype!r}"
-            )
         if self.gather_mode not in ("row", "grouped"):
             raise ValueError(
                 f"gather_mode must be 'row' or 'grouped', "
                 f"got {self.gather_mode!r}"
             )
-        if self.gather_mode == "grouped" and self.solver == "fused":
-            # the fused kernel does its own in-kernel access pattern —
-            # accepting the combination would record gather_mode=grouped
-            # in bench artifacts while actually measuring the fused path
+        if self.solver not in ("auto", "xla", "pallas"):
             raise ValueError(
-                "gather_mode='grouped' does not compose with "
-                "solver='fused' (the fused kernel gathers in-kernel); "
-                "pick one"
-            )
-        if self.solver not in ("auto", "xla", "pallas", "fused"):
-            raise ValueError(
-                f"solver must be 'auto', 'xla', 'pallas' or 'fused', "
+                f"solver must be 'auto', 'xla' or 'pallas', "
                 f"got {self.solver!r}"
-            )
-        if self.gather_dtype == "bfloat16" and self.solver == "fused":
-            # the kernel fetches table rows by one-row DMA, which Mosaic
-            # only aligns for 32-bit rows (v5e: "Slice shape along
-            # dimension 0 must be aligned to tiling (8), but is 1")
-            raise ValueError(
-                "gather_dtype='bfloat16' does not compose with "
-                "solver='fused' (the kernel's row DMAs need a float32 "
-                "table)"
             )
         if self.solver_mode not in ("full", "subspace"):
             raise ValueError(
                 f"solver_mode must be 'full' or 'subspace', "
                 f"got {self.solver_mode!r}"
             )
-        if self.solver_mode == "subspace":
-            if self.subspace_size < 1:
-                raise ValueError(
-                    f"subspace_size must be >= 1, got {self.subspace_size}"
-                )
-            if self.solver == "fused":
-                # the fused kernel is a single-pass full-rank
-                # gather+Gram+solve — there is no block-sweep variant of
-                # it; accepting the combination would silently run the
-                # full solve while the config claims subspace
-                raise ValueError(
-                    "solver_mode='subspace' does not compose with "
-                    "solver='fused' (the fused kernel solves the full "
-                    "R×R system in-kernel); use solver='pallas' or "
-                    "'xla'"
-                )
+        if self.solver_mode == "subspace" and self.subspace_size < 1:
+            raise ValueError(
+                f"subspace_size must be >= 1, got {self.subspace_size}"
+            )
         if self.factor_placement not in ("replicated", "sharded"):
             raise ValueError(
                 f"factor_placement must be 'replicated' or 'sharded', "
@@ -1020,8 +973,8 @@ def _dense_normal_equations(opp: jax.Array, count: jax.Array,
     x ``rating`` (implicit) and ``Bw`` is ``rating`` (explicit) or
     ``count`` + ``alpha`` x ``rating`` (implicit): the sums the gathered
     einsums take over a row's own entries, a repeated pair counted as
-    often as it is held.  ``opp`` is the table itself: ``gather_dtype``
-    and ``gather_mode`` concern the gathered buckets.
+    often as it is held.  ``opp`` is the table itself: ``gather_mode``
+    concerns the gathered buckets.
 
     Summed block by block in float32, the partial Grams added: ONE
     contraction over a popular row's 232,944 outer products would lose
@@ -1104,8 +1057,7 @@ def _solve_path(solver: str, r: int, dtype=jnp.float32) -> str:
     ``"pallas"`` and ``"xla"`` force one; ``"auto"`` takes the kernel
     where it is the faster (a TPU backend, float32 systems no wider than
     a tile holds) and ``lax`` elsewhere: on the CPU backend the kernel
-    would run through the Pallas interpreter.  ``"fused"`` buckets that
-    reach the plain solve keep ``lax``.  The vmapped sweep
+    would run through the Pallas interpreter.  The vmapped sweep
     (`sweep_train_als`) resolves ``"auto"`` to ``"xla"`` itself: a
     Pallas grid does not batch under ``vmap``.
     """
@@ -1202,19 +1154,18 @@ _LOWRANK_RANK_SHARE = 4
 
 
 def _lowrank_form(k: int, r: int, implicit: bool, solver_mode: str,
-                  subspace_size: int, solver: str) -> bool:
+                  subspace_size: int) -> bool:
     """Whether a bucket of static pad width ``k`` at rank ``r`` is solved
     in its K x K form against the shared base (`_lowrank_solve`) and not
     as ``[B, R, R]`` normal equations.
 
     Implicit buckets alone have a base every row shares (``YtY``); the
-    block sweep consumes the gathered rows by block, the fused kernel
-    takes its buckets whole, a dense bucket (``DENSE_K``) gathers
-    nothing; and the form wins only while a row's ``k`` rotated rows and
-    its K x K system cost less than an R x R Gram and factorisation:
-    ``k`` no more than a quarter of the rank."""
+    block sweep consumes the gathered rows by block, a dense bucket
+    (``DENSE_K``) gathers nothing; and the form wins only while a row's
+    ``k`` rotated rows and its K x K system cost less than an R x R Gram
+    and factorisation: ``k`` no more than a quarter of the rank."""
     return (
-        implicit and solver != "fused" and k != DENSE_K
+        implicit and k != DENSE_K
         and not _block_sweeps(solver_mode, subspace_size, r)
         and _LOWRANK_RANK_SHARE * k <= r
     )
@@ -1297,7 +1248,6 @@ def _lowrank_solve(Vm, val, maskf, reg, alpha, base: _GramBase, prec,
     rows' place (the phase probe's ``stop_after="gram"``)."""
     f32 = jnp.float32
     k = Vm.shape[1]
-    Vm = Vm.astype(f32)
 
     def over_slots(coef, rows):
         """``sum_k coef[b, k] rows[b, k, :]``."""
@@ -1362,7 +1312,6 @@ def _half_iteration_impl(
     weighted_lambda: bool,
     precision: str,
     solver: str,
-    gather_dtype: str = "float32",
     gather_mode: str = "row",
     solver_mode: str = "full",
     subspace_size: int = 0,
@@ -1378,9 +1327,8 @@ def _half_iteration_impl(
     out = _solve_staged(
         write, upd, opp, bucket_args, lam, alpha,
         ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
-        precision=precision, solver=solver, gather_dtype=gather_dtype,
-        gather_mode=gather_mode, solver_mode=solver_mode,
-        subspace_size=subspace_size, mesh=mesh,
+        precision=precision, solver=solver, gather_mode=gather_mode,
+        solver_mode=solver_mode, subspace_size=subspace_size, mesh=mesh,
     )
     return upd if out is None else out
 
@@ -1395,8 +1343,7 @@ _half_iteration = xray.instrument("als.half_iteration")(
         jax.jit,
         static_argnames=(
             "ks", "implicit", "weighted_lambda", "precision", "solver",
-            "gather_dtype", "gather_mode", "solver_mode", "subspace_size",
-            "mesh",
+            "gather_mode", "solver_mode", "subspace_size", "mesh",
         ),
         donate_argnums=(0,),
     )(_half_iteration_impl)
@@ -1408,25 +1355,23 @@ _half_iteration = xray.instrument("als.half_iteration")(
     jax.jit,
     static_argnames=(
         "ks", "implicit", "weighted_lambda", "precision", "solver",
-        "gather_dtype", "gather_mode", "solver_mode", "subspace_size",
-        "stop_after",
+        "gather_mode", "solver_mode", "subspace_size", "stop_after",
     ),
 )
 def _half_phase_probe(upd, opp, bucket_args, lam, alpha, *, ks, implicit,
                       weighted_lambda, precision, solver,
-                      gather_dtype="float32", gather_mode="row",
-                      solver_mode="full", subspace_size=0,
-                      stop_after="gather"):
-    """Truncated half-iteration for pio-obs phase tracing: the same
-    kernel prefix ``tools/breakdown_matrix.py`` probes (gather only /
-    gather+Gram), jitted WITHOUT donation — the real, donating half
-    still consumes ``upd`` right after the probes run."""
+                      gather_mode="row", solver_mode="full",
+                      subspace_size=0, stop_after="gather"):
+    """Truncated half-iteration for pio-obs phase tracing: the real
+    half's kernel prefix (gather only / gather+Gram), jitted WITHOUT
+    donation — the real, donating half still consumes ``upd`` right
+    after the probes run."""
     return _solve_staged(
         None, upd, opp, bucket_args, lam, alpha,
         ks=ks, implicit=implicit, weighted_lambda=weighted_lambda,
-        precision=precision, solver=solver, gather_dtype=gather_dtype,
-        gather_mode=gather_mode, solver_mode=solver_mode,
-        subspace_size=subspace_size, stop_after=stop_after,
+        precision=precision, solver=solver, gather_mode=gather_mode,
+        solver_mode=solver_mode, subspace_size=subspace_size,
+        stop_after=stop_after,
     )
 
 
@@ -1467,7 +1412,6 @@ def _solve_buckets(
     weighted_lambda: bool,
     precision: str,
     solver: str,
-    gather_dtype: str = "float32",
     gather_mode: str = "row",
     solver_mode: str = "full",
     subspace_size: int = 0,
@@ -1512,25 +1456,13 @@ def _solve_buckets(
     matrix computed shard-locally + psum'd instead of redundantly from the
     gathered full table.
 
-    ``gather_dtype="bfloat16"`` casts the opposite table once per
-    half-iteration and feeds the MXU bf16 operands with f32 accumulation
-    (``preferred_element_type``): the hot [B, K, R] gather moves half the
-    HBM bytes.  The YtY gram, regularization, and solves stay f32.
-
-    ``solver="fused"`` routes buckets through the single-pass Pallas
-    kernel (`ops/fused_als.py`: in-kernel row-DMA gather + Gram +
-    regularize + Gauss-Jordan; the table stays in HBM).  A bucket whose
-    width has no tile plan (`fused_tile_plan` None: its index block
-    would not fit SMEM) keeps the XLA path below — a choice made from
-    the bucket's static shape, never from a failed compile.
-
     ``mesh`` is the multi-device mesh of the REPLICATED-placement caller
     (whose bucket batches arrive data-sharded): the Pallas kernels then
     run per device (:func:`_per_device`).  The sharded path calls this
     from inside its own ``shard_map`` body and leaves it None.
 
     A DENSE bucket (``k == DENSE_K``; staged under replicated placement
-    with the full solve and no fused kernel, by `ALSTrainer._dense_slots`)
+    with the full solve, by `ALSTrainer._dense_slots`)
     carries its ``[blocks, J, block_rows]`` counts and ratings in the
     place of ``idx`` and ``val``: its normal equations are a blocked
     matmul over ALL of ``opp`` (`_dense_normal_equations`), then the
@@ -1564,38 +1496,27 @@ def _solve_buckets(
         gram = _table_gram(opp, prec)
     lowrank = [
         stop_after != "gather" and _lowrank_form(
-            k, r, implicit, solver_mode, subspace_size, solver)
+            k, r, implicit, solver_mode, subspace_size)
         for k in ks
     ]
     # static, as every k is
     if base is None and any(lowrank):  # piolint: disable=PIO104
         base = _gram_base(gram)
-    opp_g = (
-        opp.astype(jnp.bfloat16)
-        if gather_dtype == "bfloat16" and opp.dtype != jnp.bfloat16
-        else opp
-    )
     f32 = jnp.float32
-    opp_grp = grp = None
+    opp_grp = None
+    grp = _GATHER_GROUP_ROWS
     if gather_mode == "grouped":
-        # tile-aligned slab gather (ALSConfig.gather_mode): group height
-        # = the dtype's memory-tile sublane count (8 f32 / 16 bf16).
-        # The slab table is the 3D view [M/G, G, R] — the SAME row-major
-        # bytes, but XLA tiles the trailing (G, R) dims, so one gathered
-        # [G, R] slice is whole (8,128)/(16,128) tiles.  (The 2D
-        # [M/G, G*R] form would lay the G rows along LANES: a slab row
-        # is then 1 sublane tall and every gather still pays the full
-        # tile-height waste it was meant to eliminate.)
-        grp = 8 * (4 // opp_g.dtype.itemsize)
-        mg = -(-opp_g.shape[0] // grp) * grp
+        # tile-aligned slab gather (ALSConfig.gather_mode).  The slab
+        # table is the 3D view [M/G, G, R] — the SAME row-major bytes,
+        # but XLA tiles the trailing (G, R) dims, so one gathered [G, R]
+        # slice is whole (8,128) tiles.  (The 2D [M/G, G*R] form would
+        # lay the G rows along LANES: a slab row is then 1 sublane tall
+        # and every gather still pays the full tile-height waste it was
+        # meant to eliminate.)
+        mg = -(-opp.shape[0] // grp) * grp
         opp_grp = jnp.pad(
-            opp_g, ((0, mg - opp_g.shape[0]), (0, 0))
+            opp, ((0, mg - opp.shape[0]), (0, 0))
         ).reshape(mg // grp, grp, r)
-    fused = solver == "fused" and stop_after is None
-    if fused:
-        from ..ops.fused_als import (
-            fused_gather_gram_solve, fused_tile_plan,
-        )
     out = None
 
     def regularisation(counts):
@@ -1641,26 +1562,6 @@ def _solve_buckets(
             valid = _valid_slots(counts, k)
             maskf = valid.astype(f32)
         reg = regularisation(counts)
-        if fused and fused_tile_plan(r, k) is not None:
-            if implicit:
-                cwk = alpha.astype(f32) * val * maskf
-                bwk = (1.0 + cwk) * maskf
-                g0 = gram
-            else:
-                cwk = maskf
-                bwk = val * maskf
-                g0 = None
-            if g0 is None:
-                g0 = jnp.zeros((r, r), f32)
-            x = _per_device(
-                functools.partial(fused_gather_gram_solve, precision=prec),
-                mesh,
-                in_specs=(P(), _BATCH, _BATCH, _BATCH, _BATCH, P()),
-                out_specs=_BATCH,
-            )(opp_g, idx, cwk, bwk, reg, g0)
-            with jax.named_scope("als.scatter"):
-                out = upd_write(out, rows, x)
-            continue
         with jax.named_scope("als.gather"):
             if exchange is not None:
                 idx, valid_g = exchange.spread(idx, valid)
@@ -1674,7 +1575,7 @@ def _solve_buckets(
                 # _GROUPED_SLAB_BYTES — the select shrinks each chunk
                 # back to [*, K, R] before the next one materializes.
                 bsz, k_ = idx.shape
-                per_row = k_ * grp * r * opp_grp.dtype.itemsize
+                per_row = k_ * grp * r * 4
                 bc = max(
                     1, min(bsz, _GROUPED_SLAB_BYTES // max(per_row, 1))
                 )
@@ -1701,8 +1602,8 @@ def _solve_buckets(
                     )
                 Vm = Vm * valid_g[..., None].astype(Vm.dtype)
             else:
-                Vm = opp_g[idx] * valid_g[..., None].astype(
-                    opp_g.dtype
+                Vm = opp[idx] * valid_g[..., None].astype(
+                    opp.dtype
                 )                                            # [B, K, R]
             if exchange is not None:
                 if low:  # piolint: disable=PIO104
@@ -1716,7 +1617,7 @@ def _solve_buckets(
                 else:
                     Vm = exchange.collect(Vm)
         if stop_after == "gather":
-            out = (0.0 if out is None else out) + Vm.astype(f32).sum()
+            out = (0.0 if out is None else out) + Vm.sum()
             continue
         # low and sub are static (the rule, the mode), never traced
         if low or sub:  # piolint: disable=PIO104
@@ -1744,25 +1645,22 @@ def _solve_buckets(
                 with jax.named_scope("als.scatter"):
                     out = upd_write(out, rows, res)
             continue
-        # weight vectors are computed in f32 then cast to the gather dtype
-        # right before the einsum, so a mixed-dtype contraction never
-        # silently promotes (and re-materializes) the big Vm operand
         with jax.named_scope("als.gram"):
             if implicit:
                 cw = alpha.astype(f32) * val * maskf     # (c - 1), f32
                 A = gram + jnp.einsum(
-                    "bk,bkr,bks->brs", cw.astype(Vm.dtype), Vm, Vm,
+                    "bk,bkr,bks->brs", cw, Vm, Vm,
                     precision=prec, preferred_element_type=f32,
                 )
                 b = jnp.einsum(
-                    "bk,bkr->br", ((1.0 + cw) * maskf).astype(Vm.dtype),
+                    "bk,bkr->br", (1.0 + cw) * maskf,
                     Vm, precision=prec, preferred_element_type=f32,
                 )
             else:
                 A = jnp.einsum("bkr,bks->brs", Vm, Vm, precision=prec,
                                preferred_element_type=f32)
                 b = jnp.einsum(
-                    "bk,bkr->br", (val * maskf).astype(Vm.dtype), Vm,
+                    "bk,bkr->br", val * maskf, Vm,
                     precision=prec, preferred_element_type=f32,
                 )
         out = solve_and_write(out, rows, A, b, reg)
@@ -1830,7 +1728,7 @@ def _subspace_sweep(
     f32 = jnp.float32
     r = Vm.shape[-1]
     pred = jnp.einsum(
-        "bkr,br->bk", Vm, x0.astype(Vm.dtype),
+        "bkr,br->bk", Vm, x0,
         precision=prec, preferred_element_type=f32,
     )
     # the cache a block's gradient reads and its update advances: the
@@ -1852,7 +1750,7 @@ def _subspace_sweep(
                 H = jax.lax.dynamic_slice_in_dim(
                     gram_rows, s, w, axis=1
                 ) + _sum_over_entries(
-                    "bk,bks,bkt->bst", cw.astype(Vs.dtype), Vs, Vs,
+                    "bk,bks,bkt->bst", cw, Vs, Vs,
                     prec=prec,
                 )
                 # (c-1)·p - c on rated items: cw is masked, so c·mask is
@@ -1860,11 +1758,11 @@ def _subspace_sweep(
                 coef = cw * cache - maskf - cw
                 g = jax.lax.dynamic_slice_in_dim(q, s, w, axis=1) \
                     + _sum_over_entries(
-                        "bk,bks->bs", coef.astype(Vs.dtype), Vs, prec=prec)
+                        "bk,bks->bs", coef, Vs, prec=prec)
             else:
                 H = _sum_over_entries("bks,bkt->bst", Vs, Vs, prec=prec)
                 g = _sum_over_entries(
-                    "bk,bks->bs", cache.astype(Vs.dtype), Vs, prec=prec)
+                    "bk,bks->bs", cache, Vs, prec=prec)
             H = H + reg[:, None, None] * jnp.eye(w, dtype=H.dtype)
             g = g + reg[:, None] * xs
         if gram_probe:
@@ -1874,7 +1772,7 @@ def _subspace_sweep(
         with jax.named_scope("als.block_update"):
             x = jax.lax.dynamic_update_slice_in_dim(x, xs + d, s, axis=1)
             cache = cache + jnp.einsum(
-                "bks,bs->bk", Vs, d.astype(Vs.dtype),
+                "bks,bs->bk", Vs, d,
                 precision=prec, preferred_element_type=f32)
             if implicit:
                 q = q + jnp.einsum("bs,sr->br", d, gram_rows,
@@ -1978,7 +1876,6 @@ def build_sharded_half(
     weighted_lambda: bool,
     precision: str,
     solver: str,
-    gather_dtype: str = "float32",
     gather_mode: str = "row",
     solver_mode: str = "full",
     subspace_size: int = 0,
@@ -2022,10 +1919,9 @@ def build_sharded_half(
       (``_chunk_groups``), run as ONE loop, so a table of 600 chunks
       traces, lowers and compiles one chunk's program a shape.
 
-    Two modes keep a whole-table gather, because what they run reads a
-    whole table: ``solver="fused"`` (the kernel fetches rows by DMA from
-    a table in HBM) all-gathers the opposite table, and the subspace
-    sweep all-gathers the table being UPDATED for its warm start.
+    The subspace sweep keeps one whole-table gather, because it reads a
+    whole table: it all-gathers the table being UPDATED for its warm
+    start.
 
     ``coded=True`` (coded-ALS, arXiv 2105.03631; `parallel/coded.py`)
     builds the straggler-tolerant variant, which reconstructs a late
@@ -2072,7 +1968,7 @@ def build_sharded_half(
         # device decomposes the same psum'd YtY
         base = None
         if any(_lowrank_form(k, upd.shape[-1], implicit, solver_mode,
-                             subspace_size, solver) for k in ks):
+                             subspace_size) for k in ks):
             base = _gram_base(gram)
 
         def chunk_step(k):
@@ -2095,10 +1991,9 @@ def build_sharded_half(
                     ks=(k,), implicit=implicit,
                     weighted_lambda=weighted_lambda,
                     precision=precision, solver=solver,
-                    gather_dtype=gather_dtype, gather_mode=gather_mode,
-                    solver_mode=solver_mode, subspace_size=subspace_size,
-                    upd_table=upd_full, gram=gram, base=base,
-                    exchange=exchange,
+                    gather_mode=gather_mode, solver_mode=solver_mode,
+                    subspace_size=subspace_size, upd_table=upd_full,
+                    gram=gram, base=base, exchange=exchange,
                 )
                 return table if out is None else out
 
@@ -2138,19 +2033,6 @@ def build_sharded_half(
                 # every device
                 with jax.named_scope(EXCHANGE_SCOPE):
                     gram = jax.lax.psum(_table_gram(opp, _prec()), axis)
-            if solver == "fused":
-                # the kernel reads a whole table in HBM; cast BEFORE
-                # the all-gather so bf16 mode also halves ICI traffic
-                opp_send = (
-                    opp.astype(jnp.bfloat16)
-                    if gather_dtype == "bfloat16"
-                    else opp
-                )
-                return solve_core(
-                    upd, jax.lax.all_gather(opp_send, axis, axis=0,
-                                            tiled=True),
-                    gram, lam, alpha, flat_buckets,
-                )
             return solve_core(
                 upd, opp, gram, lam, alpha, flat_buckets,
                 exchange=ShardedRows(axis, opp.shape[0]),
@@ -2166,37 +2048,24 @@ def build_sharded_half(
 
     def coded_body(upd, opp, opp_parity, ok, lam, alpha, *flat_buckets):
         me = jax.lax.axis_index(axis)
-        opp_send = (
-            opp.astype(jnp.bfloat16)
-            if gather_dtype == "bfloat16"
-            else opp
-        )
         # mask the late/dead shard's block out of the gather, then put
-        # its reconstruction back: parity - sum(alive).  The alive sum
-        # rides f32 (the iterate's dtype) so bf16 gather mode does not
-        # erode the reconstruction; with all shards alive the recon
-        # block multiplies by zero and the math is the plain gather.
-        okm = ok[me]
-        masked_send = opp_send * okm.astype(opp_send.dtype)
-        gathered = jax.lax.all_gather(masked_send, axis, axis=0,
-                                      tiled=True)
-        alive_sum = jax.lax.psum(opp * okm.astype(opp.dtype), axis)
-        recon = (opp_parity - alive_sum.astype(f32))
+        # its reconstruction back: parity - sum(alive).  With all shards
+        # alive the recon block multiplies by zero and the math is the
+        # plain gather.
+        okm = ok[me].astype(opp.dtype)
+        alive = opp * okm
+        gathered = jax.lax.all_gather(alive, axis, axis=0, tiled=True)
+        recon = opp_parity - jax.lax.psum(alive, axis)
         blocks = gathered.reshape((d,) + opp.shape)
-        okb = ok.reshape((d,) + (1,) * opp.ndim).astype(opp_send.dtype)
-        opp_full = (
-            blocks * okb
-            + recon[None].astype(opp_send.dtype) * (1.0 - okb)
-        ).reshape(gathered.shape)
+        okb = ok.reshape((d,) + (1,) * opp.ndim).astype(opp.dtype)
+        opp_full = (blocks * okb + recon[None] * (1.0 - okb)).reshape(
+            gathered.shape)
         gram = None
         if implicit:
             # per-shard gram + psum would read the dead shard's data;
             # fold the reconstructed block in explicitly instead
             alive_gram = jax.lax.psum(
-                jnp.einsum(
-                    "mr,ms->rs", opp * okm.astype(opp.dtype),
-                    opp * okm.astype(opp.dtype), precision=_prec(),
-                ),
+                jnp.einsum("mr,ms->rs", alive, alive, precision=_prec()),
                 axis,
             )
             gram = alive_gram + jnp.einsum(
@@ -2204,8 +2073,7 @@ def build_sharded_half(
             ) * (1.0 - jnp.min(ok))
         out = solve_core(upd, opp_full, gram, lam, alpha, flat_buckets)
         # a degraded shard wrote nothing this half: freeze its rows
-        okw = okm.astype(out.dtype)
-        out = out * okw + upd.astype(out.dtype) * (1.0 - okw)
+        out = out * okm + upd * (1.0 - okm)
         # parity of the UPDATED table — the next half's opposite parity
         new_parity = jax.lax.psum(out.astype(f32), axis)
         return out, new_parity
@@ -2405,8 +2273,7 @@ class ALSTrainer:
             "max_rows": gram_chunk_rows(self.system_width, n_dev),
             "max_entries": (
                 exchange_chunk_entries(cfg.rank, n_dev) if self.sharded
-                else gather_chunk_entries(
-                    cfg.rank, n_dev, jnp.dtype(cfg.gather_dtype).itemsize)
+                else gather_chunk_entries(cfg.rank, n_dev)
             ),
         }
         if self.sharded:
@@ -2432,12 +2299,12 @@ class ALSTrainer:
         returns), or None where no row can be staged dense: no row of
         either side (``counts_u``, ``counts_i``) holds the ratings
         `dense_min_count` asks at the least, or the gathered rows
-        themselves are consumed: the subspace sweep and the fused
-        kernel.  (The sharded staging paths never ask: the opposite
-        table is a shard there, and the dense form would be a partial
-        Gram and a ``psum``, which is not built.)"""
+        themselves are consumed: the subspace sweep.  (The sharded
+        staging paths never ask: the opposite table is a shard there, and
+        the dense form would be a partial Gram and a ``psum``, which is
+        not built.)"""
         cfg = self.cfg
-        if cfg.solver_mode == "subspace" or cfg.solver == "fused":
+        if cfg.solver_mode == "subspace":
             return None
         if not any(
             least is not None and counts.max(initial=0) >= least
@@ -2472,8 +2339,7 @@ class ALSTrainer:
         path (``solve_path``, ``solve_systems`` a side): known on the
         host from the staged shapes alone.  A system for each row of
         each bucket, batch padding included, times the rank blocks of a
-        subspace sweep; the buckets the fused kernel takes whole never
-        reach the solve.  Beside them the split by form: the rows whose
+        subspace sweep.  Beside them the split by form: the rows whose
         system is solved K x K against the shared base
         (``lowrank_systems`` a side, by pad width; `_lowrank_form`),
         which ``solve_systems`` counts too; the others are solved at
@@ -2485,16 +2351,10 @@ class ALSTrainer:
         cfg = self.cfg
         width = self.system_width
         self.solve_path = _solve_path(cfg.solver, width)
-        fused = cfg.solver == "fused"
-        if fused:
-            from ..ops.fused_als import fused_tile_plan
         sides = (("user", self._user_side), ("item", self._item_side))
         self.solve_systems = {
             name: -(-cfg.rank // width) * sum(
-                int(bucket[0].size)
-                for bucket, k in zip(side["buckets"], side["ks"])
-                if not (fused and fused_tile_plan(cfg.rank, k) is not None)
-            )
+                int(bucket[0].size) for bucket in side["buckets"])
             for name, side in sides
         }
         self.lowrank_systems = {name: {} for name, _ in sides}
@@ -2502,7 +2362,7 @@ class ALSTrainer:
             by_width = self.lowrank_systems[name]
             for bucket, k in zip(side["buckets"], side["ks"]):
                 if _lowrank_form(k, cfg.rank, cfg.implicit, cfg.solver_mode,
-                                 cfg.subspace_size, cfg.solver):
+                                 cfg.subspace_size):
                     by_width[k] = by_width.get(k, 0) + int(bucket[0].size)
         lowrank = sum(sum(by_width.values())
                       for by_width in self.lowrank_systems.values())
@@ -2551,7 +2411,7 @@ class ALSTrainer:
           (sharded placement: each chunk's ids all-gathered, the
           reduce-scatter of its ``[d*B, K, R]`` partial rows, the solved
           ``[B, R]`` blocks all-gathered, the implicit YtY all-reduced;
-          the whole table where a mode all-gathers it).
+          the whole table where the coded half all-gathers it).
         * ``write_rows``: rows ONE device hands its scatter in a half
           (sharded placement: every chunk's list, ``cap`` rows, padding
           included: a quarter of the side's padded rows on four devices
@@ -2560,11 +2420,12 @@ class ALSTrainer:
           ``[cap, B]``.
         * ``opp_transient_bytes``: the most a device holds in the
           opposite table's place at once (the partial rows and the
-          chunk's own; the whole table where a mode all-gathers it).
+          chunk's own; the whole table where the coded half all-gathers
+          it).
         * ``gram_chunk_bytes``: the largest chunk's float32 Gram on one
           device, ``[B, w, w]`` for systems of width w (`system_width`).
         * ``gather_chunk_bytes``: the largest chunk's gathered rows
-          ``[B, K, R]`` on one device, in the gather dtype; and
+          ``[B, K, R]`` on one device; and
           ``gather_bytes``, all that a half gathers over the devices
           together, padding included (a dense chunk gathers nothing).
         * ``chunks_looped``: chunks that run inside a loop over chunks
@@ -2574,9 +2435,9 @@ class ALSTrainer:
         cfg = self.cfg
         r = cfg.rank
         d = self.mesh.size if self.mesh is not None else 1
-        row_bytes = r * jnp.dtype(cfg.gather_dtype).itemsize
+        row_bytes = r * 4
         table_rows = {"user": self._pad_items, "item": self._pad_users}
-        whole_opp = self.coded or cfg.solver == "fused"
+        whole_opp = self.coded
         sub = self.sweeps_blocks
         self.exchange_bytes, self.opp_transient_bytes = {}, {}
         self.chunks_looped, self.gather_bytes = {}, {}
@@ -2702,7 +2563,6 @@ class ALSTrainer:
             weighted_lambda=cfg.weighted_lambda,
             precision=cfg.matmul_precision,
             solver=cfg.solver,
-            gather_dtype=cfg.gather_dtype,
             gather_mode=cfg.gather_mode,
             solver_mode=cfg.solver_mode,
             subspace_size=cfg.subspace_size,
@@ -3207,7 +3067,7 @@ class ALSTrainer:
         cfg = self.cfg
         key = jax.random.PRNGKey(cfg.seed)
         ku, ki = jax.random.split(key)
-        dtype = jnp.dtype(cfg.compute_dtype)
+        dtype = jnp.float32
         U = jax.random.normal(ku, (self.n_users, cfg.rank), dtype)
         U = U / jnp.sqrt(cfg.rank).astype(dtype)
         V = jax.random.normal(ki, (self.n_items, cfg.rank), dtype)
@@ -3287,7 +3147,6 @@ class ALSTrainer:
             weighted_lambda=cfg.weighted_lambda,
             precision=cfg.matmul_precision,
             solver=cfg.solver,
-            gather_dtype=cfg.gather_dtype,
             gather_mode=cfg.gather_mode,
             solver_mode=cfg.solver_mode,
             subspace_size=cfg.subspace_size,
@@ -3346,7 +3205,6 @@ class ALSTrainer:
                 ks=side["ks"], implicit=cfg.implicit,
                 weighted_lambda=cfg.weighted_lambda,
                 precision=cfg.matmul_precision, solver=cfg.solver,
-                gather_dtype=cfg.gather_dtype,
                 gather_mode=cfg.gather_mode,
                 solver_mode=cfg.solver_mode,
                 subspace_size=cfg.subspace_size,
@@ -3431,6 +3289,11 @@ class ALSTrainer:
                     V = jax.block_until_ready(
                         self._half(V, U, self._item_side, lam=lam))
                 phases["item_half"] = time.perf_counter() - t0
+            # booking the sweep's metrics is a phase of its own: a small
+            # table's sweep is a millisecond, of which the booking is
+            # some fifty microseconds
+            t0 = time.perf_counter()
+            if not trace_phases:
                 TRAIN_PHASE_SECONDS.labels(phase="als.user_half").observe(
                     phases["user_half"]
                 )
@@ -3452,6 +3315,7 @@ class ALSTrainer:
                         gathered)
             for counter, entries in self._gram_entry_counters:
                 counter.inc(entries)
+            phases["counters"] = time.perf_counter() - t0
             if faults.fired("train.nan"):
                 # poison the iterates the way an exploding sweep would;
                 # the convergence watchdog must catch it THIS sweep
@@ -3599,7 +3463,7 @@ def sweep_train_als(
     common = dict(
         implicit=cfg.implicit, weighted_lambda=cfg.weighted_lambda,
         precision=cfg.matmul_precision, solver="xla",
-        gather_dtype=cfg.gather_dtype, gather_mode=cfg.gather_mode,
+        gather_mode=cfg.gather_mode,
         solver_mode=cfg.solver_mode, subspace_size=cfg.subspace_size,
     )
 

@@ -13,12 +13,8 @@ This module makes both visible:
   {kind}`` so cold-start and warm-start deploys are distinguishable on
   ``/metrics``.  Attribution of a compile to a *function* rides a
   thread-local set by :func:`instrument`-wrapped entry points (the
-  repo's jitted ALS halves, the fused gather+Gram+solve kernel's
-  pallas entries — ``als.fused``, whose signature carries the tile
-  plan, table dtype, precision, and gather-impl statics, so a fused
-  recompile's per-arg delta names exactly which of them churned — and
-  the top-k scorers); compiles outside any tracked call book under
-  ``fn="untracked"``.
+  repo's jitted ALS halves and the top-k scorers); compiles outside
+  any tracked call book under ``fn="untracked"``.
 * **Recompilation detector.**  :func:`instrument` wraps a jitted
   callable and fingerprints every call's arg signature (shapes /
   dtypes / static kwargs).  A signature never seen before means XLA is
@@ -494,10 +490,7 @@ def instrument(name: str) -> Callable[[Callable], Callable]:
     Instrumented seams (grep for ``xray.instrument(`` to re-derive):
     ``als.half_iteration`` / ``als.phase_probe`` / ``als.sharded_half``
     / ``als.sweep_half`` / ``als.expand_sides`` / ``als.sq_err_sum``
-    (models/als.py), ``als.fused`` (ops/fused_als.py — BOTH gather
-    impls' pallas entries share the name; the impl shows up in the
-    signature via the entry fn and its static tile-plan kwargs),
-    ``topk.*`` (ops/topk.py), and ``live.foldin_solve``
+    (models/als.py), ``topk.*`` (ops/topk.py), and ``live.foldin_solve``
     (live/foldin.py — a steady fold-in daemon must show one signature
     per padded (B, K) rung, not one per cycle)."""
 

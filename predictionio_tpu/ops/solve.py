@@ -71,7 +71,6 @@ __all__ = [
     "cholesky_solve_batched",
     "slab_work_share",
     "pallas_interpret",
-    "solver_smem_budget",
     "solver_vmem_budget",
     "solver_tile_footprint",
 ]
@@ -223,23 +222,6 @@ def solver_vmem_budget() -> int:
     if env:
         return int(env)
     return 16 << 20
-
-
-def solver_smem_budget() -> int:
-    """Per-core SMEM budget (bytes) for scalar-prefetched operands.
-
-    The fused kernel (`ops/fused_als.py`) prefetches a batch tile's
-    ``[TB, Kpad]`` int32 index block to SMEM
-    (``PrefetchScalarGridSpec``); SMEM is the scalar core's memory and
-    far smaller than VMEM, with no public query API either.  256 KiB is
-    a deliberately conservative planning default;
-    ``PIO_TPU_SMEM_BYTES`` overrides it the same way
-    ``PIO_TPU_VMEM_BYTES`` overrides the VMEM budget.
-    """
-    env = os.environ.get("PIO_TPU_SMEM_BYTES")
-    if env:
-        return int(env)
-    return 256 << 10
 
 
 def _round_up(n: int, m: int) -> int:

@@ -153,6 +153,11 @@ class ECommDataSource(DataSource):
 @dataclass(frozen=True)
 class ECommAlgorithmParams(Params):
     __param_aliases__ = {"lambda": "lam"}
+    # records written before gather_dtype went hold its float32 default
+    __retired_params__ = {"gather_dtype": "float32"}
+    # a model trained with the fused kernel, which went, retrains and
+    # folds in on the default route
+    __retired_values__ = {"solver": {"fused": "auto"}}
 
     rank: int = 10
     num_iterations: int = 20
@@ -168,7 +173,6 @@ class ECommAlgorithmParams(Params):
     solver_mode: str = "full"    # "subspace" = iALS++ block sweep
     subspace_size: int = 16
     factor_placement: str = "replicated"
-    gather_dtype: str = "float32"
     gather_mode: str = "row"
     unseen_only: bool = False
     seen_events: tuple[str, ...] = ("view", "buy")
@@ -203,7 +207,6 @@ class ECommAlgorithm(Algorithm):
                 solver=p.solver, factor_placement=p.factor_placement,
                 solver_mode=p.solver_mode,
                 subspace_size=p.subspace_size,
-                gather_dtype=p.gather_dtype,
                 gather_mode=p.gather_mode,
             ),
             mesh=ctx.mesh,

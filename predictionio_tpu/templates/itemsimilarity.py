@@ -51,6 +51,9 @@ from .similarproduct import Query, SimilarProductDataSource
 @dataclass(frozen=True)
 class ItemSimilarityParams(Params):
     __param_aliases__ = {"lambda": "lam"}
+    # a model trained with the fused kernel, which went, retrains and
+    # folds in on the default route
+    __retired_values__ = {"solver": {"fused": "auto"}}
 
     rank: int = 10
     num_iterations: int = 20

@@ -336,6 +336,11 @@ class ALSAlgorithmParams(Params):
     "seed": 3} (reference `custom-query/engine.json:11-20`)."""
 
     __param_aliases__ = {"lambda": "lam"}
+    # records written before gather_dtype went hold its float32 default
+    __retired_params__ = {"gather_dtype": "float32"}
+    # a model trained with the fused kernel, which went, retrains and
+    # folds in on the default route
+    __retired_values__ = {"solver": {"fused": "auto"}}
 
     rank: int = 10
     num_iterations: int = 20
@@ -347,15 +352,12 @@ class ALSAlgorithmParams(Params):
     # serve-time scoring dtype: "float32" (default) or "bfloat16" (halves
     # HBM reads per query; ranking-only precision cost, training unaffected)
     serving_dtype: str = "float32"
-    # train-time gather dtype for the opposite factor table ("bfloat16"
-    # halves the hot gather's HBM bytes; solves stay f32 — models/als.py)
-    gather_dtype: str = "float32"
     # gather access pattern: "row" | "grouped" (tile-aligned slab
     # gather — models/als.py ALSConfig.gather_mode)
     gather_mode: str = "row"
     # batched SPD solver: "auto" (the ops/solve.py kernel on a TPU,
-    # lax.linalg elsewhere) | "xla" | "pallas" | "fused" (a kernel that
-    # does not compile on this backend fails the train)
+    # lax.linalg elsewhere) | "xla" | "pallas" (a kernel that does not
+    # compile on this backend fails the train)
     solver: str = "auto"
     # rank-sweep strategy: "full" (R×R solve per row) | "subspace"
     # (iALS++ block sweep — engine.json keys solverMode/subspaceSize;
@@ -483,7 +485,6 @@ class ALSAlgorithm(Algorithm):
             implicit=p.implicit,
             alpha=p.alpha,
             weighted_lambda=p.weighted_lambda,
-            gather_dtype=p.gather_dtype,
             gather_mode=p.gather_mode,
             solver=p.solver,
             solver_mode=p.solver_mode,

@@ -195,6 +195,11 @@ class SimilarProductDataSource(DataSource):
 @dataclass(frozen=True)
 class SimilarALSParams(Params):
     __param_aliases__ = {"lambda": "lam"}
+    # records written before gather_dtype went hold its float32 default
+    __retired_params__ = {"gather_dtype": "float32"}
+    # a model trained with the fused kernel, which went, retrains and
+    # folds in on the default route
+    __retired_values__ = {"solver": {"fused": "auto"}}
 
     rank: int = 10
     num_iterations: int = 20
@@ -210,7 +215,6 @@ class SimilarALSParams(Params):
     solver_mode: str = "full"    # "subspace" = iALS++ block sweep
     subspace_size: int = 16
     factor_placement: str = "replicated"
-    gather_dtype: str = "float32"
     gather_mode: str = "row"
 
 
@@ -255,7 +259,6 @@ class SimilarProductAlgorithm(Algorithm):
             solver=p.solver, factor_placement=p.factor_placement,
             solver_mode=p.solver_mode,
             subspace_size=p.subspace_size,
-            gather_dtype=p.gather_dtype,
             gather_mode=p.gather_mode,
         )
 

@@ -45,10 +45,8 @@ def main() -> None:
     mode = sys.argv[8] if len(sys.argv) > 8 else ""
     if home:
         return _run_train_end_to_end(pid, home, out, local=(mode == "local"))
-    if mode.startswith("sharded"):
-        # "sharded" or "sharded:<solver>" (e.g. sharded:fused)
-        _, _, solver = mode.partition(":")
-        return _run_sharded_trainer(pid, db, exch, out, solver or "xla")
+    if mode == "sharded":
+        return _run_sharded_trainer(pid, db, exch, out)
 
     from predictionio_tpu.models.als import ALSConfig, train_als
     from predictionio_tpu.parallel.ingest import (
@@ -89,8 +87,7 @@ def main() -> None:
     print("WORKER_OK", pid, flush=True)
 
 
-def _run_sharded_trainer(pid: int, db: str, exch: str, out: str,
-                         solver: str = "xla") -> None:
+def _run_sharded_trainer(pid: int, db: str, exch: str, out: str) -> None:
     """Sharded-COO multi-host path: sharded scan -> id exchange ->
     row-owner COO exchange -> ALSTrainer.distributed.  No process ever
     holds the full COO; the parent asserts per-process rating bytes are
@@ -100,7 +97,7 @@ def _run_sharded_trainer(pid: int, db: str, exch: str, out: str,
     from predictionio_tpu.parallel.mesh import make_mesh
 
     cfg = ALSConfig(rank=4, num_iterations=3, lam=0.1, seed=3,
-                    factor_placement="sharded", solver=solver)
+                    factor_placement="sharded", solver="xla")
     from predictionio_tpu.storage.sqlite_events import SQLiteEventStore
 
     es = SQLiteEventStore(db)

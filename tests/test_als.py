@@ -6,6 +6,8 @@ The NumPy reference implements the same normal equations MLlib solves
 matching it is the RMSE-parity contract of BASELINE.md.
 """
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -188,8 +190,6 @@ def test_staged_blocks_train_bitwise_as_expansion_in_the_sweep(
     """Two sweeps of `ALSTrainer.run` against a reference half that
     expands every bucket inside the sweep, as the parent's did and the
     sharded path does: the same tables, bit for bit."""
-    import functools
-
     import jax
 
     from predictionio_tpu.models import als
@@ -629,22 +629,6 @@ def test_implicit_single_halfstep_exact():
     )
 
 
-def test_bf16_gather_close_to_f32():
-    """gather_dtype='bfloat16' halves the hot gather's bytes; the result
-    must stay close to exact f32 training (f32 accumulation + solves)."""
-    u, i, v, nu, ni = _toy(density=0.5)
-    base = dict(rank=6, num_iterations=6, lam=0.05, seed=2)
-    exact = train_als((u, i, v), nu, ni, ALSConfig(**base))
-    fast = train_als((u, i, v), nu, ni,
-                     ALSConfig(**base, gather_dtype="bfloat16"))
-    pred_exact = exact.user_factors @ exact.item_factors.T
-    pred_fast = fast.user_factors @ fast.item_factors.T
-    # prediction-matrix agreement within bf16-input tolerance
-    np.testing.assert_allclose(pred_fast, pred_exact, atol=0.15)
-    # and fit quality is essentially unchanged
-    assert abs(rmse(fast, u, i, v) - rmse(exact, u, i, v)) < 0.02
-
-
 def test_grouped_gather_exactly_matches_row_gather():
     """gather_mode='grouped' (tile-aligned slab gather + in-slab select)
     fetches the SAME rows through a different memory access pattern —
@@ -652,8 +636,7 @@ def test_grouped_gather_exactly_matches_row_gather():
     mode combination."""
     u, i, v, nu, ni = _toy(density=0.5)
     for extra in (
-        {},                                          # explicit f32
-        {"gather_dtype": "bfloat16"},                # bf16 slabs (G=16)
+        {},                                          # explicit
         {"implicit": True, "alpha": 2.0},            # implicit branch
     ):
         vals = np.abs(v) + 1.0 if extra.get("implicit") else v
@@ -727,89 +710,62 @@ def test_grouped_gather_sharded_matches_replicated():
     )
 
 
-def test_knob_lattice_consistency():
+# every combination of the path selectors but the baseline itself: the
+# explicit lattice, the implicit form's extreme corner, and the implicit
+# kernel on the baseline's other selectors (implicit xla runs sharded
+# and grouped in their own tests above)
+_KNOB_LATTICE = [
+    (False, solver, mode, placement)
+    for solver in ("xla", "pallas")
+    for mode in ("row", "grouped")
+    for placement in ("replicated", "sharded")
+    if (solver, mode, placement) != ("xla", "row", "replicated")
+] + [(True, "pallas", "grouped", "sharded"),
+     (True, "pallas", "row", "replicated")]
+
+
+@functools.lru_cache(maxsize=None)
+def _knob_lattice_reference(implicit: bool) -> tuple:
+    """(data, config, predictions) of the plain baseline (row/xla
+    /replicated), trained once for each ``implicit``."""
+    u, i, v, nu, ni = _toy(density=0.5, seed=11)
+    vals = np.abs(v) + 1.0 if implicit else v
+    base_kw = dict(rank=4, num_iterations=2, lam=0.1, seed=5,
+                   implicit=implicit, **({"alpha": 2.0} if implicit else {}))
+    ref = train_als((u, i, vals), nu, ni, ALSConfig(**base_kw))
+    return (u, i, vals, nu, ni), base_kw, ref.user_factors @ ref.item_factors.T
+
+
+@pytest.mark.parametrize(
+    "implicit,solver,mode,placement", _KNOB_LATTICE,
+    ids=["-".join((["implicit"] if c[0] else []) + list(c[1:]))
+         for c in _KNOB_LATTICE])
+def test_knob_lattice_consistency(implicit, solver, mode, placement):
     """Every valid combination of the perf knobs must train to the same
-    PREDICTIONS as the plain baseline (f32/row/xla/replicated).
+    PREDICTIONS as the plain baseline (row/xla/replicated).
 
     Single-knob A/B tests miss interaction bugs (e.g. grouped x sharded
-    x bf16); an interaction bug produces garbage, not epsilon drift, so
-    the bounds are deliberately looser than the dedicated single-knob
-    tests' (and hold on REAL TPU kernels, not just the near-exact
+    x pallas); an interaction bug produces garbage, not epsilon drift,
+    so the bound is deliberately looser than the dedicated single-knob
+    tests' (and holds on REAL TPU kernels, not just the near-exact
     interpret mode CPU runs them in — kernel f32 needs ~5e-3 at factor
-    level: tests/test_als.py pallas bound, tests/test_fused_als.py).  Implicit mode adds only two extreme
-    corners: the knob plumbing is implicit-agnostic."""
-    import itertools
-
+    level: tests/test_als.py pallas bound).  Implicit mode adds its
+    extreme corner and its kernel alone: the knob plumbing is
+    implicit-agnostic."""
     from predictionio_tpu.parallel import make_mesh
 
-    mesh = make_mesh()
-    combos = [
-        (False, s, d, m, p)
-        for s, d, m, p in itertools.product(
-            ("xla", "pallas", "fused"),
-            ("float32", "bfloat16"),
-            ("row", "grouped"),
-            ("replicated", "sharded"),
-        )
-    ] + [
-        (True, "pallas", "bfloat16", "grouped", "sharded"),
-        (True, "fused", "float32", "row", "replicated"),
-    ]
-    refs = {}
-    data = {}
-    for implicit, solver, dtype, mode, placement in combos:
-        if solver == "fused" and (
-            mode == "grouped" or dtype == "bfloat16"
-        ):
-            continue  # rejected combinations
-        if implicit not in data:
-            u, i, v, nu, ni = _toy(density=0.5, seed=11)
-            vals = np.abs(v) + 1.0 if implicit else v
-            data[implicit] = (u, i, vals, nu, ni)
-            base_kw = dict(rank=4, num_iterations=2, lam=0.1, seed=5,
-                           implicit=implicit,
-                           **({"alpha": 2.0} if implicit else {}))
-            ref = train_als((u, i, vals), nu, ni, ALSConfig(**base_kw))
-            refs[implicit] = (
-                base_kw, ref.user_factors @ ref.item_factors.T
-            )
-        u, i, vals, nu, ni = data[implicit]
-        base_kw, pred_ref = refs[implicit]
-        cfg_kw = dict(base_kw, solver=solver, gather_dtype=dtype,
-                      gather_mode=mode, factor_placement=placement)
-        got = train_als(
-            (u, i, vals), nu, ni, ALSConfig(**cfg_kw),
-            mesh=mesh if placement == "sharded" else None,
-        )
-        label = f"{solver}/{dtype}/{mode}/{placement}/imp={implicit}"
-        assert np.isfinite(got.user_factors).all(), label
-        assert np.isfinite(got.item_factors).all(), label
-        pred = got.user_factors @ got.item_factors.T
-        atol = 0.2 if dtype == "bfloat16" else 2e-2
-        np.testing.assert_allclose(pred, pred_ref, atol=atol,
-                                   err_msg=label)
-
-
-def test_bf16_gather_implicit_and_sharded():
-    from predictionio_tpu.parallel import make_mesh
-
-    u, i, v, nu, ni = _toy()
-    v = np.abs(v) + 1.0
-    cfg = ALSConfig(rank=4, num_iterations=3, lam=0.1, implicit=True,
-                    alpha=2.0, gather_dtype="bfloat16",
-                    factor_placement="sharded")
-    mesh = make_mesh()
-    sharded = train_als((u, i, v), nu, ni, cfg, mesh=mesh)
-    single = train_als((u, i, v), nu, ni,
-                       ALSConfig(rank=4, num_iterations=3, lam=0.1,
-                                 implicit=True, alpha=2.0,
-                                 gather_dtype="bfloat16"))
-    # bf16 sharded matches bf16 replicated (same math, different layout)
-    np.testing.assert_allclose(
-        sharded.user_factors, single.user_factors, rtol=2e-2, atol=2e-2
+    (u, i, vals, nu, ni), base_kw, pred_ref = _knob_lattice_reference(
+        implicit)
+    cfg = ALSConfig(**base_kw, solver=solver, gather_mode=mode,
+                    factor_placement=placement)
+    got = train_als(
+        (u, i, vals), nu, ni, cfg,
+        mesh=make_mesh() if placement == "sharded" else None,
     )
-    assert np.isfinite(sharded.item_factors).all()
-
+    assert np.isfinite(got.user_factors).all()
+    assert np.isfinite(got.item_factors).all()
+    np.testing.assert_allclose(
+        got.user_factors @ got.item_factors.T, pred_ref, atol=2e-2)
 
 
 def test_device_staging_matches_host_staging():
@@ -1073,16 +1029,14 @@ def test_config_rejects_typo_knob_values():
     the default path (the use sites test exact equality)."""
     with pytest.raises(ValueError, match="solver"):
         ALSConfig(solver="Fused")
+    # the fused gather+Gram+solve kernel is gone: its name is refused
+    # like any other unknown solver
+    with pytest.raises(ValueError, match="solver"):
+        ALSConfig(solver="fused")
     with pytest.raises(ValueError, match="factor_placement"):
         ALSConfig(factor_placement="Sharded")
-    with pytest.raises(ValueError, match="gather_dtype"):
-        ALSConfig(gather_dtype="fp32")
     with pytest.raises(ValueError, match="gather_mode"):
         ALSConfig(gather_mode="tiled")
-    # grouped + fused would record gather_mode=grouped in artifacts
-    # while measuring the fused kernel's own access pattern
-    with pytest.raises(ValueError, match="does not compose"):
-        ALSConfig(gather_mode="grouped", solver="fused")
 
 
 def test_device_expand_sides_reconstruction():
@@ -1126,7 +1080,6 @@ def test_device_expand_sides_reconstruction():
 
 @pytest.mark.parametrize("extra", [
     dict(solver="pallas"),
-    dict(solver="fused"),
     dict(solver="pallas", solver_mode="subspace", subspace_size=2),
 ])
 def test_kernel_solvers_run_per_device_on_a_replicated_mesh(

@@ -426,12 +426,10 @@ def test_the_staged_event_and_the_counter_say_how_often_it_engages(
 @pytest.mark.parametrize("mode", [
     dict(factor_placement="sharded"),
     dict(solver_mode="subspace", subspace_size=4),
-    dict(solver="fused"),
-], ids=["sharded", "subspace", "fused"])
+], ids=["sharded", "subspace"])
 def test_modes_that_consume_gathered_rows_stage_none(mode, monkeypatch):
-    """Sharded placement, the subspace sweep and the fused kernel run
-    what they ran: no dense bucket, the same bits, wherever the floor
-    stands."""
+    """Sharded placement and the subspace sweep run what they ran: no
+    dense bucket, the same bits, wherever the floor stands."""
     from predictionio_tpu.parallel import make_mesh
 
     mesh = make_mesh(2) if "factor_placement" in mode else None

@@ -205,27 +205,26 @@ def test_a_lowrank_bucket_builds_no_batch_of_rank_by_rank_matrices():
 # -- the rule ---------------------------------------------------------------
 
 
-@pytest.mark.parametrize("k,r,implicit,mode,block,solver,takes", [
-    (8, 128, True, "full", 16, "auto", True),
-    (16, 128, True, "full", 16, "xla", True),
-    (32, 128, True, "full", 16, "pallas", True),
-    (64, 128, True, "full", 16, "auto", False),      # over a quarter
-    (128, 128, True, "full", 16, "auto", False),
-    (256, 128, True, "full", 16, "auto", False),     # k >= r
-    (8, 64, True, "full", 16, "auto", True),
-    (16, 64, True, "full", 16, "auto", True),
-    (32, 64, True, "full", 16, "auto", False),
-    (8, 32, True, "full", 16, "auto", True),
-    (8, 10, True, "full", 16, "auto", False),        # the templates' rank
-    (8, 128, False, "full", 16, "auto", False),      # explicit: no base
-    (8, 128, True, "subspace", 16, "auto", False),   # the block sweep
-    (8, 128, True, "subspace", 128, "auto", True),   # ... of one block
-    (8, 128, True, "full", 16, "fused", False),
-    (DENSE_K, 128, True, "full", 16, "auto", False),
+@pytest.mark.parametrize("k,r,implicit,mode,block,takes", [
+    (8, 128, True, "full", 16, True),
+    (16, 128, True, "full", 16, True),
+    (32, 128, True, "full", 16, True),
+    (64, 128, True, "full", 16, False),      # over a quarter
+    (128, 128, True, "full", 16, False),
+    (256, 128, True, "full", 16, False),     # k >= r
+    (8, 64, True, "full", 16, True),
+    (16, 64, True, "full", 16, True),
+    (32, 64, True, "full", 16, False),
+    (8, 32, True, "full", 16, True),
+    (8, 10, True, "full", 16, False),        # the templates' rank
+    (8, 128, False, "full", 16, False),      # explicit: no base
+    (8, 128, True, "subspace", 16, False),   # the block sweep
+    (8, 128, True, "subspace", 128, True),   # ... of one block
+    (DENSE_K, 128, True, "full", 16, False),
 ])
 def test_the_rule_is_a_pure_function_of_static_shapes_and_modes(
-        k, r, implicit, mode, block, solver, takes):
-    assert _lowrank_form(k, r, implicit, mode, block, solver) is takes
+        k, r, implicit, mode, block, takes):
+    assert _lowrank_form(k, r, implicit, mode, block) is takes
 
 
 def _ratings(n_users=90, n_items=400, mean=3.0, seed=7):
@@ -253,9 +252,8 @@ def _spy(monkeypatch):
 @pytest.mark.parametrize("cfg", [
     dict(implicit=False),
     dict(implicit=True, solver_mode="subspace", subspace_size=8),
-    dict(implicit=True, solver="fused"),
     dict(implicit=True, rank=16),
-], ids=["explicit", "subspace", "fused", "rank-16"])
+], ids=["explicit", "subspace", "rank-16"])
 def test_halves_the_rule_leaves_out_never_reach_the_form(cfg, monkeypatch):
     seen = _spy(monkeypatch)
     u, i, v, nu, ni = _ratings()

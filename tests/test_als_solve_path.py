@@ -25,7 +25,11 @@ def _ratings(n_users=40, n_items=24, density=0.4, seed=11):
     dict(implicit=True, alpha=2.0),
     dict(solver_mode="subspace", subspace_size=3),
     dict(implicit=True, solver_mode="subspace", subspace_size=4),
-], ids=["explicit", "implicit", "subspace", "implicit-subspace"])
+    # plain lambda: the same reg for every row, whatever its count
+    dict(weighted_lambda=False),
+    dict(implicit=True, alpha=2.0, weighted_lambda=False),
+], ids=["explicit", "implicit", "subspace", "implicit-subspace",
+        "unweighted", "implicit-unweighted"])
 def test_one_half_agrees_between_the_kernel_and_lax(mode):
     u, i, v, nu, ni = _ratings()
     halves = {}
@@ -55,7 +59,6 @@ def test_the_default_resolves_from_backend_dtype_and_width(monkeypatch):
     assert als_mod._solve_path("auto", 64, jnp.bfloat16) == "lax"
     assert als_mod._solve_path("auto", 64, jnp.float64) == "lax"
     assert als_mod._solve_path("xla", 64) == "lax"
-    assert als_mod._solve_path("fused", 64) == "lax"
 
 
 def test_auto_reaches_the_kernel_where_the_backend_reads_tpu(monkeypatch):

@@ -56,12 +56,9 @@ def test_optimized_config_tried_first_then_safe(patched, monkeypatch,
     monkeypatch.setattr(bench, "_run_inner_supervised", supervised)
     _run(monkeypatch)
     a1, a2 = patched["inner"]
-    # best first: Gauss-Jordan Pallas solves + bf16 gathers + bf16x3
-    # Gram (the fused kernel is not in the chain: it has no chip timing
-    # yet — ROADMAP S3)
-    assert "pallas" in a1 and "high" in a1 and "bfloat16" in a1
-    assert "fused" not in a1
-    # then the conservative all-XLA/f32 config
+    # best first: Pallas solves + bf16x3 Gram
+    assert "pallas" in a1 and "high" in a1
+    # then the conservative all-XLA config
     assert "--solver" not in a2 and "--precision" not in a2
     out = capsys.readouterr().out.strip().splitlines()[-1]
     assert json.loads(out)["platform"] == "tpu"
@@ -179,15 +176,15 @@ def test_inner_raises_when_the_requested_kernel_does_not_compile(
         monkeypatch, capsys):
     """A requested kernel that fails to compile fails the run — no
     record is printed under another solver's name."""
-    from predictionio_tpu.ops import fused_als as fmod
+    from predictionio_tpu.ops import solve as solve_mod
 
     def boom(*a, **k):
         raise RuntimeError("injected lowering failure")
 
-    monkeypatch.setattr(fmod, "fused_gather_gram_solve", boom)
+    monkeypatch.setattr(solve_mod, "cholesky_solve_batched", boom)
     args = bench._parse_args(
         ["--inner", "--scale", "0.001", "--rank", "5", "--iters", "1",
-         "--solver", "fused"]
+         "--solver", "pallas"]
     )
     with pytest.raises(RuntimeError, match="injected lowering failure"):
         bench.run_inner(args)
@@ -200,11 +197,11 @@ def test_inner_records_the_solver_it_ran(capsys):
     full-scale ones."""
     args = bench._parse_args(
         ["--inner", "--scale", "0.001", "--rank", "6", "--iters", "1",
-         "--solver", "fused"]
+         "--solver", "pallas"]
     )
     bench.run_inner(args)
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["solver"] == "fused" and rec["platform"] == "cpu"
+    assert rec["solver"] == "pallas" and rec["platform"] == "cpu"
     assert "solver_requested" not in rec and "degraded" not in rec
     assert rec["train_rmse"] > 0 and rec["rmse_holdout"] > 0
 

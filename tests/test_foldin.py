@@ -493,7 +493,7 @@ def sqlite_storage(tmp_path):
     reset_storage(None)
 
 
-def _train_small(storage, app_name="liveapp"):
+def _train_small(storage, app_name="liveapp", **params):
     from predictionio_tpu.controller import WorkflowContext
     from predictionio_tpu.templates.recommendation import (
         recommendation_engine,
@@ -519,7 +519,7 @@ def _train_small(storage, app_name="liveapp"):
     ep = engine.params_from_variant({
         "datasource": {"params": {"appName": app_name}},
         "algorithms": [{"name": "als", "params": {
-            "rank": 6, "numIterations": 8, "lambda": 0.05}}],
+            "rank": 6, "numIterations": 8, "lambda": 0.05, **params}}],
     })
     ctx = WorkflowContext(storage=storage)
     iid = run_train(engine, ep, ctx=ctx, engine_variant="live.json")
@@ -552,6 +552,55 @@ def test_runner_cycle_end_to_end(sqlite_storage):
     assert stats2["seq"] == 2
     # the daemon's own model composed both deltas
     assert runner.model.users.get("brand_new") >= 0
+
+
+def test_a_record_of_the_fused_solver_folds_in_at_its_own_settings(
+        sqlite_storage):
+    """A model trained with the fused kernel, which went, holds
+    ``solver: "fused"`` in its record.  Its fold-in must still solve the
+    record's own form (implicit, alpha, plain lambda), to the rows a
+    record of the default solver folds in, not the defaults' explicit
+    form."""
+    import dataclasses
+
+    from predictionio_tpu.controller import WorkflowContext
+    from predictionio_tpu.workflow import run_train
+
+    engine, ep, iid, app_id, es = _train_small(
+        sqlite_storage, implicit=True, alpha=4.0, weightedLambda=False)
+    iid_fused = run_train(engine, ep,
+                          ctx=WorkflowContext(storage=sqlite_storage),
+                          engine_variant="live.json")
+    md = sqlite_storage.get_metadata()
+    rec = md.engine_instance_get(iid_fused)
+    ((name, params),) = json.loads(rec.algorithms_params)[0].items()
+    md.engine_instance_update(dataclasses.replace(
+        rec, algorithms_params=json.dumps(
+            [{name: {**params, "solver": "fused"}}])))
+    ep_fused = engine.params_from_instance(md.engine_instance_get(iid_fused))
+    runners = [
+        FoldInRunner(sqlite_storage, engine, p, i, from_now=True,
+                     ctx=WorkflowContext(storage=sqlite_storage,
+                                         mode="Serving"))
+        for p, i in ((ep, iid), (ep_fused, iid_fused))
+    ]
+    for r in runners:
+        assert r.cfg.implicit and r.cfg.alpha == 4.0
+        assert not r.cfg.weighted_lambda
+        assert r.cycle() is None
+    es.insert_batch(
+        [_rate("brand_new", f"i{i}", 3.0, d=2) for i in (1, 3, 5)]
+        + [_rate("u0", "i7", 2.0, d=2)],
+        app_id=app_id,
+    )
+    rows = []
+    for r in runners:
+        stats = r.cycle()
+        assert stats["appendedUsers"] == 1 and stats["patchedUsers"] == 1
+        m = r.model
+        rows.append(m.user_factors[[m.users.get("brand_new"),
+                                    m.users.get("u0")]])
+    np.testing.assert_array_equal(rows[1], rows[0])
 
 
 def test_runner_restart_replays_chain(sqlite_storage):

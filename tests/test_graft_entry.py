@@ -43,7 +43,7 @@ def test_can_run_inprocess_reads_the_configured_platform(monkeypatch):
 
 
 def test_dryrun_body_full_8_devices():
-    """The complete driver dryrun — sharded train, fused kernel,
+    """The complete dry run — sharded train, grouped gather,
     collectives, 2D mesh, ring top-k — on the suite's virtual mesh."""
     import __graft_entry__ as ge
 

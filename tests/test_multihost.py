@@ -318,43 +318,3 @@ def test_run_train_no_full_coo_end_to_end(tmp_path):
         results[0]["predict_items"].tolist()
         == results[1]["predict_items"].tolist()
     )
-
-
-def test_sharded_distributed_trainer_fused_solver(tmp_path):
-    """The fused gather+Gram+solve kernel inside the distributed
-    sharded-COO path (2 jax.distributed processes x 2 devices): the
-    solver must RESOLVE to fused on every process (loud-degrade
-    contract) and the model must match the single-process train —
-    the exact composition a TPU pod runs."""
-    db = tmp_path / "events.db"
-    es = SQLiteEventStore(db)
-    es.init_channel(1)
-    for e in _make_events(n_users=24, n_items=16, seed=1):
-        es.insert(e, app_id=1)
-    frame = es.find_columnar(
-        app_id=1, event_names=["rate"], float_property="rating"
-    )
-    expected = frame.to_ratings(rating_property="rating")
-    es.close()
-
-    from predictionio_tpu.models.als import ALSConfig, train_als
-
-    exp_factors = train_als(
-        expected, cfg=ALSConfig(rank=4, num_iterations=3, lam=0.1, seed=3)
-    )
-    exch = tmp_path / "exchange"
-    exch.mkdir()
-    coordinator = tmp_path / "coord"
-    outs = [tmp_path / f"fu{p}.npz" for p in range(2)]
-    _spawn_workers(
-        2,
-        lambda p: [p, 2, coordinator, db, exch, outs[p], "",
-                   "sharded:fused"],
-        device_count=2,
-    )
-    for o in outs:
-        r = np.load(o, allow_pickle=False)
-        np.testing.assert_allclose(
-            r["user_factors"], exp_factors.user_factors,
-            rtol=1e-3, atol=1e-3,
-        )

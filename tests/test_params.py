@@ -104,3 +104,34 @@ def test_non_dataclass_params_class_raises_params_error():
 
     with pytest.raises(ParamsError, match="not a params dataclass"):
         extract_params(Plain, {"x": 1})
+
+
+def test_retired_key_is_dropped_at_its_old_value_in_either_spelling():
+    @dataclass(frozen=True)
+    class Cfg(Params):
+        __retired_params__ = {"old_knob": "same"}
+
+        rank: int = 1
+
+    assert extract_params(Cfg, {"old_knob": "same", "rank": 2}) == Cfg(2)
+    assert extract_params(Cfg, {"oldKnob": "same"}) == Cfg()
+    with pytest.raises(ParamsError, match="'old_knob' was removed"):
+        extract_params(Cfg, {"oldKnob": "other"})
+    # a retired key is no field: it is refused where it is not declared
+    with pytest.raises(ParamsError, match="unknown key"):
+        extract_params(AlgoParams, {"old_knob": "same"})
+
+
+def test_retired_value_of_a_kept_key_reads_as_its_successor():
+    @dataclass(frozen=True)
+    class Cfg(Params):
+        __retired_values__ = {"mode": {"old": "new"}}
+
+        mode: str = "new"
+        rank: int = 1
+
+    assert extract_params(Cfg, {"mode": "old", "rank": 2}) == Cfg("new", 2)
+    assert extract_params(Cfg, {"mode": "other"}) == Cfg("other")
+    # a value that is no string is left to the field's own conversion
+    with pytest.raises(ParamsError):
+        extract_params(Cfg, {"mode": ["old"]})

@@ -3,9 +3,7 @@
 The reference proves these with hand-picked cases (`DataMapSpec`,
 `LEventAggregatorSpec`, `BiMapSpec`); generated inputs cover the same
 contracts over the whole input space — JSON wire round-trips, the
-$set/$unset/$delete fold semantics, id-index bijection, and the fused
-kernel's VMEM/SMEM tile-plan accounting (a wrong plan fails to compile
-on the chip, so the arithmetic is load-bearing).
+$set/$unset/$delete fold semantics, and id-index bijection.
 """
 
 import datetime as dt
@@ -146,49 +144,6 @@ def test_string_index_bijection(ids):
     np.testing.assert_array_equal(
         ix.decode(ix.encode(ids)), np.asarray(ids)
     )
-
-
-@given(
-    r=st.integers(min_value=2, max_value=128),
-    k=st.integers(min_value=1, max_value=1 << 14),
-    budget_mib=st.integers(min_value=2, max_value=64),
-    smem_kib=st.integers(min_value=4, max_value=1024),
-)
-@settings(max_examples=60, deadline=None, derandomize=True)
-def test_fused_tile_plan_accounting(r, k, budget_mib, smem_kib):
-    """Any plan the planner returns must actually FIT the budgets it
-    was given: padded scratch + the row-copy landing pad +
-    double-buffered IO stay within half of VMEM (the other half is
-    Mosaic's stack temporaries), one batch tile's index block fits
-    SMEM, and dimensions tile (8, 128).  A wrong plan is a compile
-    failure on the chip, so the arithmetic is a contract, not a
-    heuristic."""
-    from predictionio_tpu.ops.fused_als import (
-        _pad8, _pad128, fused_tile_plan,
-    )
-
-    budget = budget_mib << 20
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("PIO_TPU_VMEM_BYTES", str(budget))
-        mp.setenv("PIO_TPU_SMEM_BYTES", str(smem_kib << 10))
-        plan = fused_tile_plan(r, k)
-    if plan is None:
-        return
-    tb, kc = plan
-    assert tb >= 8 and kc >= 128
-    assert tb % 8 == 0 and kc % 128 == 0
-    r8, r128, w128 = _pad8(r), _pad128(r), _pad128(r + 1)
-    fixed = (
-        tb * r8 * r128 * 4          # A scratch
-        + tb * r8 * w128 * 4        # GJ scratch
-        + _pad8(tb) * r128 * 4      # b scratch
-        + tb * kc * r128 * 4        # landing pad of the row copies
-        + 2 * 2 * _pad8(tb) * _pad128(kc) * 4  # cw/bw double-buffered
-        + 2 * _pad8(tb) * r128 * 4  # out double-buffered
-        + r8 * r128 * 4             # gram0
-    )
-    assert fixed <= budget // 2
-    assert tb * (-(-k // kc) * kc) * 4 <= smem_kib << 10
 
 
 # -- sharded-store routing + dedup invariants (round 5) -------------------

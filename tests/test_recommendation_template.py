@@ -106,6 +106,35 @@ def test_train_and_predict_end_to_end(ctx):
     assert scores == sorted(scores, reverse=True)
 
 
+@pytest.mark.parametrize("stored", [
+    {"gather_dtype": "float32"},
+    {"solver": "fused"},
+], ids=["gather-dtype", "fused-solver"])
+def test_a_record_written_before_a_knob_went_still_deploys(ctx, stored):
+    """`pio deploy` serves with the params an instance's record holds.
+    Records written while ``gather_dtype`` was a key hold its float32
+    default; a model once trained with the fused kernel holds
+    ``solver: "fused"``, read as the default route."""
+    import dataclasses
+    import json
+
+    e = recommendation_engine()
+    ep = e.params_from_variant(VARIANT)
+    iid = run_train(e, ep, ctx=ctx, engine_variant="rec.json")
+    md = ctx.storage.get_metadata()
+    rec = md.engine_instance_get(iid)
+    ((name, params),) = json.loads(rec.algorithms_params)[0].items()
+    md.engine_instance_update(dataclasses.replace(
+        rec, algorithms_params=json.dumps([{name: {**params, **stored}}])))
+    deployed = e.params_from_instance(md.engine_instance_get(iid))
+    algo_params = deployed.algorithms[0][1]
+    assert algo_params.solver == "auto"
+    models = prepare_deploy(e, deployed, iid, ctx=ctx)
+    res = e._algorithms(deployed)[0].predict(models[0],
+                                             Query(user="u0", num=3))
+    assert len(res.item_scores) == 3
+
+
 def test_unknown_user_returns_empty(ctx):
     e = recommendation_engine()
     ep = e.params_from_variant(VARIANT)
@@ -336,7 +365,7 @@ def test_bfloat16_serving_matches_f32_ranking(ctx):
 
 
 def test_engine_json_exposes_scaling_knobs(ctx):
-    """solver / factorPlacement / gatherDtype ride engine.json params to
+    """solver / factorPlacement / gatherMode ride engine.json params to
     the trainer — the reference's engine.json is the one config surface a
     template user touches, so the scaling story must be reachable there."""
     from predictionio_tpu.templates.recommendation import (
@@ -351,10 +380,8 @@ def test_engine_json_exposes_scaling_knobs(ctx):
             "name": "als",
             "params": {
                 "rank": 4, "numIterations": 2, "lambda": 0.1,
-                # pallas, not fused: grouped+fused is REJECTED at
-                # config time (the fused kernel gathers in-kernel)
                 "solver": "pallas", "factorPlacement": "sharded",
-                "gatherDtype": "float32", "gatherMode": "grouped",
+                "gatherMode": "grouped",
             },
         }],
     })

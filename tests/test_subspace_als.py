@@ -265,8 +265,10 @@ def test_config_validation():
         ALSConfig(solver_mode="blocked")
     with pytest.raises(ValueError, match="subspace_size"):
         ALSConfig(solver_mode="subspace", subspace_size=0)
-    with pytest.raises(ValueError, match="fused"):
+    # the sweep's block systems go to whichever solver is named
+    with pytest.raises(ValueError, match="solver"):
         ALSConfig(solver_mode="subspace", solver="fused")
+    assert ALSConfig(solver_mode="subspace", solver="pallas").solver == "pallas"
     # default preserves today's behavior
     assert ALSConfig().solver_mode == "full"
 
@@ -529,14 +531,12 @@ def test_gathered_rows_are_bounded_by_bytes_at_any_rank(memory, monkeypatch):
     for rank in (8, 64, 100, 128):
         assert als.gather_chunk_entries(rank) == cap
         assert als.gather_chunk_entries(rank, n_dev=4) == cap
-    assert als.gather_chunk_entries(256, itemsize=2) == cap
     entries = als.gather_chunk_entries(2048)
     # the power of two under a quarter: the quarter itself at 16 GiB
     assert entries == (524_288 if memory == 16 << 30 else 262_144)
     assert cap * 2048 * 4 > 34e9
     assert entries * 2048 * 4 <= memory // 4
     assert als.gather_chunk_entries(2048, n_dev=4) == 4 * entries
-    assert als.gather_chunk_entries(2048, itemsize=2) == 2 * entries
     assert als.gather_chunk_entries(4096) == entries // 2
 
 

@@ -1,18 +1,11 @@
-"""Gather-form timings for ROADMAP S3 (CLI over `ops/gather_probe.py`).
+"""Row versus grouped gather timings for ROADMAP D1 (CLI over
+`ops/gather_probe.py`).
 
-Which in-kernel gather forms the v5e compiler accepts was settled in
-PR 21 (CHANGES.md): only the row-DMA loop.  This script times, at the
-ML-20M table shapes, what is left to compare on the chip:
-
-  * the XLA ``jnp.take`` baseline (what the unfused path pays), f32 and
-    bf16;
-  * the grouped tile-slab take behind ``gather_mode="grouped"``;
-  * the fused kernel's rolling-window ``pltpu.make_async_copy`` row
-    loop (indices scalar-prefetched to SMEM, float32 rows).
-
-Each probe prints one JSON line.  ``--smoke`` runs every form at small
-shapes (CPU interpret-mode shape and logic validation for
-``tools/gate.sh``) and exits nonzero if any form's math is wrong.
+Times, at the ML-20M table shapes, the XLA ``jnp.take`` row gather (what
+the ALS hot loop pays) against the grouped tile-slab take behind
+``gather_mode="grouped"``, float32.  Each probe prints one JSON line.
+``--smoke`` runs both at small shapes (shape and row validation for
+``tools/gate.sh``) and exits nonzero if either gathers wrong rows.
 """
 
 from __future__ import annotations
@@ -25,7 +18,6 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 
 from predictionio_tpu.ops import gather_probe as gp  # noqa: E402
 
@@ -35,7 +27,7 @@ def _emit(rec) -> None:
 
 
 def run_smoke() -> int:
-    """Small-shape run of every form: interpret-mode math validation."""
+    """Small-shape run of both forms: row validation."""
     _emit({"metric": "probe_env", "backend": jax.default_backend(),
            "mode": "smoke",
            "note": "shape/logic validation only"})
@@ -53,9 +45,8 @@ def run_smoke() -> int:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
-                    help="small-shape CPU interpret-mode validation of "
-                    "every gather form (the gate.sh step); exits "
-                    "nonzero on any math mismatch")
+                    help="small-shape validation of both gather forms "
+                    "(the gate.sh step); exits nonzero on a wrong row")
     args = ap.parse_args(argv)
     if args.smoke:
         return run_smoke()
@@ -63,22 +54,9 @@ def main(argv=None) -> int:
     _emit({"metric": "probe_env", "backend": jax.default_backend(),
            "device": str(jax.devices()[0])})
     r = 64
-    _emit({"metric": "section", "form": "xla_take_baseline"})
-    for dtype in (jnp.float32, jnp.bfloat16):
-        _emit(gp.probe_xla_take(26744, 32768, r, dtype))
-        _emit(gp.probe_xla_take(138493, 32768, r, dtype))
-    # r=128: are lane-padded (full-vreg) rows gathered faster per byte?
-    _emit(gp.probe_xla_take(26744, 32768, 128, jnp.float32))
-    _emit({"metric": "section", "form": "xla_grouped_take"})
-    for dtype in (jnp.float32, jnp.bfloat16):
-        # group defaults to the dtype's tile height (8 f32 / 16 bf16)
-        for rec in gp.probe_xla_grouped_take(26744, 32768, r, dtype):
-            _emit(rec)
-        for rec in gp.probe_xla_grouped_take(138493, 32768, r, dtype):
-            _emit(rec)
-    _emit({"metric": "section", "form": "dma_row_gather"})
-    for nout in (4096, 32768):
-        _emit(gp.probe_dma(26744, nout, r))
+    for m in (26744, 138493):
+        _emit(gp.probe_xla_take(m, 32768, r))
+        _emit(gp.probe_xla_grouped_take(m, 32768, r))
     return 0
 
 

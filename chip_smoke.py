@@ -26,7 +26,9 @@ chip its children need.
      float32 numpy reference over the persisted factors; `POST /stop`.
   5. with more than one chip visible, 2 and 4 once more with
      `"factorPlacement": "sharded", "distributedTopk": true` (sharded
-     ALS and the ring top-k).  The device count selects this, no flag.
+     ALS and the sharded top-k, each chip scanning its own shard; the
+     filtered query, which keeps the local scorer, is sent after the
+     count of compiles).  The device count selects this, no flag.
 
 It FAILS (non-zero exit, no result line) when jax finds no TPU, when any
 child fails, when a train or the server reports another platform than
@@ -287,7 +289,7 @@ class Smoke:
         return iid
 
     def serve(self, name: str, engine: Path, iid: str,
-              device: dict) -> None:
+              device: dict, sharded_topk: bool = False) -> None:
         """Deploy, query, check, stop."""
         port_file = self.work / f"{name}.port"
         t0 = time.time()
@@ -316,12 +318,17 @@ class Smoke:
         for user in users:
             answers.append(_query(base, {"user": user, "num": 10}))
         black = [s["item"] for s in answers[0]["itemScores"][:3]]
-        filtered = _query(
-            base, {"user": users[0], "num": 10, "blackList": black})
-        if set(black) & {s["item"] for s in filtered["itemScores"]}:
-            raise SmokeFailure(
-                f"deploy-{name}: blacklisted items were returned")
-        answers.append(filtered)
+
+        def ask_filtered():
+            filtered = _query(
+                base, {"user": users[0], "num": 10, "blackList": black})
+            if set(black) & {s["item"] for s in filtered["itemScores"]}:
+                raise SmokeFailure(
+                    f"deploy-{name}: blacklisted items were returned")
+            answers.append(filtered)
+
+        if not sharded_topk:
+            ask_filtered()
         # concurrent bursts, every client released at once, until the
         # batcher has coalesced one (requests that land while a device
         # call is in flight ride the next one together)
@@ -344,6 +351,12 @@ class Smoke:
                         "maxBatchSeen", 0) >= 2:
                     break
         after = _get(base + "/debug/xray")
+        if sharded_topk:
+            # under distributedTopk a filtered query keeps the local
+            # scorer, whose one-chip table the warm-up does not build (the
+            # table may be one no chip holds): its first batch compiles,
+            # after the count of what the warm-up covers
+            ask_filtered()
         try:
             urllib.request.urlopen(urllib.request.Request(
                 base + "/stop", method="POST"), timeout=10).read()
@@ -442,13 +455,14 @@ class Smoke:
                    device)
         self.serve("auto", auto, iid, device)
         if device["count"] > 1:
-            # more than one chip: sharded ALS and the ring top-k, once
-            ring = self.engine_dir("sharded", {
+            # more than one chip: sharded ALS and the sharded top-k, once
+            sharded = self.engine_dir("sharded", {
                 "factorPlacement": "sharded", "distributedTopk": True})
-            ring_iid = self.train("sharded", ring, device)
+            sharded_iid = self.train("sharded", sharded, device)
             if self.trains[-1]["placement"] != "sharded":
                 raise SmokeFailure(f"sharded train: {self.trains[-1]}")
-            self.serve("sharded", ring, ring_iid, device)
+            self.serve("sharded", sharded, sharded_iid, device,
+                       sharded_topk=True)
         self.check_logs()
         for want, got in zip(("auto", "pallas"), self.trains):
             if got["solver"] != want:

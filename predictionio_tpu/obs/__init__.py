@@ -304,7 +304,7 @@ SHARD_DEGRADED_TOTAL = _registry.counter(
 SHARD_LAG_SECONDS = _registry.histogram(
     "pio_shard_lag_seconds",
     "Host-observed wait on a late shard before serving it from parity "
-    "(op = als.half | topk.ring)",
+    "(op = als.half | topk.sharded)",
     labels=("op",),
     buckets=log_buckets(1e-4, 100.0, per_decade=4),
 )

@@ -50,8 +50,8 @@ TOPK_PATH = get_registry().counter(
     "(block scan, top-k over block maxima, rescoring of the chosen "
     "blocks), blocked_ids (the same with excluded ids applied to the "
     "candidates on the device), blocked_cats (the same with a row's "
-    "allowed items tested as bits inside the scan) or dense (matmul + "
-    "top-k over the whole row, masked or not)",
+    "allowed items tested as bits inside the scan), dense (matmul + top-k "
+    "over the whole row, masked or not) or sharded (one shard a chip)",
     labels=("path",),
 )
 

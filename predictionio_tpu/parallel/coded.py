@@ -9,9 +9,9 @@ other ``d-1`` plus parity::
 
     block_i = parity - sum_{j != i} block_j
 
-so a half-iteration (or a ring-top-k sweep) whose ``i``-th shard is
+so a half-iteration (or a sharded top-k batch) whose ``i``-th shard is
 late or dead completes from the survivors instead of stalling the whole
-ring behind one slow host — the one failure mode a pod slice actually
+mesh behind one slow host — the one failure mode a pod slice actually
 has.  Parity ownership ROTATES per half (RAID-5 style) so the extra
 write bandwidth of keeping parity fresh is spread across the mesh
 rather than hammering one chip.
@@ -67,6 +67,7 @@ __all__ = [
     "ShardHealth",
     "build_parity_fn",
     "build_coded_gather",
+    "row_chunks",
 ]
 
 
@@ -74,20 +75,48 @@ class ParityExhausted(RuntimeError):
     """More shards are missing than the parity code can reconstruct."""
 
 
+# bytes of a shard one step of a parity build or rebuild sums across the
+# mesh: the step's psum in and out are its only temporaries, so a shard of
+# 6 GB and its parity fit a 16 GB chip beside them
+PARITY_CHUNK_BYTES = 256 << 20
+
+
+def row_chunks(shard) -> tuple:
+    """``(step, whole, rest)``: `shard`'s rows as `whole` chunks of `step`
+    rows within `PARITY_CHUNK_BYTES`, then `rest` rows (static numbers)."""
+    rows = shard.shape[0]
+    row_bytes = max(shard.size // max(rows, 1), 1) * shard.dtype.itemsize
+    step = max(1, min(rows, PARITY_CHUNK_BYTES // row_bytes))
+    return step, rows // step, rows % step
+
+
 def build_parity_fn(mesh: Mesh, axis: str = DATA_AXIS):
     """Jitted ``[d*S, R] sharded -> [S, R] replicated`` parity (block sum).
 
-    Called once at trainer/index build and once per half-iteration to
-    refresh the parity of the table that was just updated (inside the
-    coded half itself, which reuses this same psum form); the standalone
-    fn exists for initialization and for serving-side index builds.
+    Summed a row chunk at a time (:func:`row_chunks`), each chunk one
+    ``psum`` written into place: beside the shard and the parity nothing
+    of the shard's size is made.  Called once at trainer/index build; the
+    coded half refreshes the parity of the table it just updated with the
+    same sum inside its own program.
     """
 
     @functools.partial(
         shard_map, mesh=mesh, in_specs=P(axis, None), out_specs=P(),
     )
     def _par(shard):
-        return jax.lax.psum(shard, axis)
+        step, whole, rest = row_chunks(shard)
+
+        def put(par, start, size):
+            part = jax.lax.dynamic_slice_in_dim(shard, start, size)
+            return jax.lax.dynamic_update_slice_in_dim(
+                par, jax.lax.psum(part, axis), start, 0)
+
+        par = jax.lax.fori_loop(
+            0, whole, lambda c, par: put(par, c * step, step),
+            jnp.zeros_like(shard))
+        if rest:   # from shapes alone  # piolint: disable=PIO104
+            par = put(par, whole * step, rest)
+        return par
 
     return jax.jit(_par)
 

@@ -20,6 +20,7 @@ from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..controller import (
@@ -374,12 +375,14 @@ class ALSAlgorithmParams(Params):
     # reconstructed from the other d-1 plus parity instead of stalling
     # the ring (models/als.py ALSConfig.coded_shards)
     coded_shards: bool = False
-    # serve queries through the ring top-k over a mesh-sharded item
-    # table (engine.json key distributedTopk) with parity-coded
-    # straggler tolerance: a shard missing its per-request hop budget
-    # (the serving Deadline, split per shard) is served from parity.
-    # Unfiltered queries only — category/white/blacklist queries keep
-    # the local scorer (per-query masks don't ride the ring)
+    # serve queries through the sharded top-k over a mesh-sharded item
+    # table (engine.json key distributedTopk; ops/distributed_topk: each
+    # chip scans its own shard, one all-gather of the candidates) with
+    # parity-coded straggler tolerance: a shard missing its per-request
+    # budget (the serving Deadline, split per shard) is served from
+    # parity.  Unfiltered queries only — category/white/blacklist
+    # queries keep the local scorer, which needs the whole table on one
+    # chip and is not warmed under this knob
     distributed_topk: bool = False
     # pio-scout two-stage retrieval (engine.json key retrieval):
     # "exact" (default — brute-force scan, the pre-scout behavior),
@@ -388,8 +391,8 @@ class ALSAlgorithmParams(Params):
     # clusters — the catalog-scale mode).  Unfiltered queries only;
     # category/white/blacklist queries keep the exact scorer (a
     # per-query mask over a shortlist can starve it below num).  With
-    # distributedTopk, the ring runs the int8 candidate stage
-    # per shard ("ivf" maps to "int8" there — coarse clusters don't
+    # distributedTopk, each chip runs the int8 candidate stage over its
+    # own shard ("ivf" maps to "int8" there — coarse clusters don't
     # shard).
     retrieval: str = "exact"
     # shortlist width in units of k: candidateFactor*k quantized
@@ -424,6 +427,21 @@ class ALSAlgorithmParams(Params):
             )
 
 
+@jax.jit
+def _device_all_finite(table):
+    return jnp.isfinite(table).all()
+
+
+def _all_finite(table, rows_at_a_time: int = 1 << 20) -> bool:
+    """Whether every entry is finite: a table on the device is tested
+    where it lies (a sharded one on its chips), one on the host a block of
+    rows at a time, with no whole-table temporary."""
+    if isinstance(table, jax.Array):
+        return bool(_device_all_finite(table))
+    return all(np.isfinite(table[lo:lo + rows_at_a_time]).all()
+               for lo in range(0, len(table), rows_at_a_time))
+
+
 @dataclass
 class ALSModel(DeviceTableMixin):
     """Factor tables + id dictionaries + item metadata for filtering."""
@@ -439,9 +457,9 @@ class ALSModel(DeviceTableMixin):
     category_index: Optional[CategoryIndex] = None
 
     def sanity_check(self) -> None:
-        if not np.isfinite(self.user_factors).all():
+        if not _all_finite(self.user_factors):
             raise ValueError("user factors contain non-finite values")
-        if not np.isfinite(self.item_factors).all():
+        if not _all_finite(self.item_factors):
             raise ValueError("item factors contain non-finite values")
 
     def sharded_topk_index(self, retrieval: str = "exact",
@@ -570,6 +588,19 @@ class ALSAlgorithm(Algorithm):
         n = len(model.items)
         if n == 0:
             return
+        shapes = warm_shapes(max_batch, n)
+        if getattr(self.params, "distributed_topk", False):
+            # the sharded index alone: the one-chip table is never made
+            # (under this knob the item table may be one no chip holds).
+            # It compiles every program it can dispatch (clean or
+            # quantized, and parity-coded) per (batch, k), so neither a
+            # first degradation nor a first burst pays a mid-request
+            # compile; a filtered query, which keeps the local scorer,
+            # compiles at its first batch
+            idx = self._sharded_index(model)
+            for b, k in shapes:
+                idx.warm(k, batch=b)
+            return
         table = model.device_item_factors(self._serve_dtype())
         rank = model.item_factors.shape[1]
         warm_batched_topk(
@@ -577,24 +608,13 @@ class ALSAlgorithm(Algorithm):
             table_t=model.device_item_tables(self._serve_dtype()),
             category_model=model,
         )
-        # the other two scorers `batch_predict` may choose, at the
-        # same shapes
-        shapes = warm_shapes(max_batch, n)
         rcfg = self._retrieval_config()
-        if rcfg is not None and not getattr(self.params,
-                                            "distributed_topk", False):
-            # pio-scout: the candidate + rerank executables
+        if rcfg is not None:
+            # pio-scout: the candidate + rerank executables, at the
+            # same shapes
             idx = model.device_ann_index(rcfg)
             for b, k in shapes:
                 idx.warm(k, [b], table)
-        if getattr(self.params, "distributed_topk", False):
-            # the ring index compiles BOTH variants (clean + parity-
-            # coded; + the quantized candidate variant under
-            # retrieval != exact) per (batch, k) — so neither a first
-            # degradation nor a first burst pays a mid-request compile
-            idx = self._sharded_index(model)
-            for b, k in shapes:
-                idx.warm(k, batch=b)
 
     def predict(self, model: ALSModel, query: Query) -> PredictedResult:
         """A lone request is a one-row batch: the same device program,
@@ -644,9 +664,11 @@ class ALSAlgorithm(Algorithm):
             rcfg = self._retrieval_config()
         if unfiltered and getattr(self.params, "distributed_topk",
                                   False):
-            # the parity-coded ring takes a [B, R] query block
-            # natively; per-query filters keep the local scorer below
-            vals, ixs = self._sharded_index(model)(uvecs, k)
+            # every chip scans its own shard for the [B, R] query block;
+            # per-query filters keep the local scorer below
+            with annotate("pio.turn.dispatch", filter=flt.kind,
+                          path="sharded"):
+                vals, ixs = self._sharded_index(model)(uvecs, k)
         elif unfiltered and rcfg is not None:
             # pio-scout two-stage: the batched serving path is exactly
             # where the candidate stage pays — per-batch device work

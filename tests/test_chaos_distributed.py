@@ -371,7 +371,7 @@ def test_ring_warmup_covers_what_serving_dispatches(mesh):
     `chip_smoke.py` checks on the chips for the sharded variant), and no
     query rides a k of its own."""
     from predictionio_tpu.controller.base import instantiate
-    from predictionio_tpu.ops.distributed_topk import _ring_callable
+    from predictionio_tpu.ops.distributed_topk import _sharded_callable
     from predictionio_tpu.storage.bimap import StringIndex
     from predictionio_tpu.templates.recommendation import (
         ALSAlgorithm, ALSModel, Query, recommendation_engine,
@@ -396,7 +396,7 @@ def test_ring_warmup_covers_what_serving_dispatches(mesh):
 
     def executables():
         return {
-            (k, coded): _ring_callable(
+            (k, coded): _sharded_callable(
                 idx.mesh, idx.axis, k, coded)._cache_size()
             for k in (1, 4, 10, 16, 20) for coded in (False, True)
         }

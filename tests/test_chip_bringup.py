@@ -395,7 +395,7 @@ def test_chip_smoke_cpu_dry_run_checks_the_plumbing(tmp_path):
     -> deploy -> singles, a filtered query, a batched burst -> reference
     check, all through the CLI, with both lines saying cpu.  Four
     virtual devices, so the device count also selects the sharded-ALS /
-    ring-top-k variant, as it does on the four-chip host."""
+    sharded-top-k variant, as it does on the four-chip host."""
     proc = _smoke(tmp_path, "--dry-run-cpu", JAX_PLATFORMS="cpu",
                   XLA_FLAGS="--xla_force_host_platform_device_count=4")
     assert proc.returncode == 0, proc.stderr[-4000:]

@@ -44,7 +44,7 @@ def test_can_run_inprocess_reads_the_configured_platform(monkeypatch):
 
 def test_dryrun_body_full_8_devices():
     """The complete dry run — sharded train, grouped gather,
-    collectives, 2D mesh, ring top-k — on the suite's virtual mesh."""
+    collectives, 2D mesh, sharded top-k — on the suite's virtual mesh."""
     import __graft_entry__ as ge
 
     ge._dryrun_body(8)

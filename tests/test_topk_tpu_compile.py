@@ -11,7 +11,10 @@ category cell's size beside its resident index, the pattern of
 `allow_device_ms.cats` held to ITS scopes, and the programs of a model without
 an index held to the parent commit's, lowered for the chip.  Since PR 43
 the ALS solve kernel (`ops/solve.py`) at widths on both sides of its rule
-for width classes.
+for width classes.  The sharded scorer over a 48.19 M-item table on a
+described 2x2: each chip's scan of its own shard with the all-gather of
+the candidates its one collective, and the chunked parity build and coded
+scan fitting a chip beside shard and parity.
 Nothing runs; a compile that passes is not a chip run.  One file, the topology described inside a fixture
 (`on-chip-measurement`, section 2)."""
 
@@ -239,3 +242,90 @@ def test_the_solve_kernel_compiles_for_the_chip_in_its_width_classes(
         tb=solve._tile_rows(width), starts=starts, interpret=False,
     ).compile().as_text()
     assert text.count("tpu_custom_call") >= 1, "the solve is the kernel"
+
+
+# -- the sharded scorer over a table no chip holds, on a described 2x2 --------
+
+N_ITEMS_X4 = 48_190_000        # rec-amazon23-r128-x4: 12,047,500 rows a chip
+CHIP_BYTES = 15.75e9
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    import numpy as np
+
+    return Mesh(np.array(topo.devices), ("data",))
+
+
+def _x4(mesh, shape, dtype, spec):
+    from jax.sharding import NamedSharding
+
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, spec))
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_the_sharded_scan_compiles_for_four_chips_and_moves_no_shard(
+        four_chips, as_on_the_chip, batch):
+    """Each chip's program is the one-chip scan kernel over its 6.17 GB
+    shard, and its only collective the all-gather of the [B, k]
+    candidates: no collective-permute, no all-reduce, no shard-sized
+    temporary."""
+    from jax.sharding import PartitionSpec as P
+
+    from predictionio_tpu.ops import distributed_topk
+
+    compiled = distributed_topk._sharded_callable(
+        four_chips, "data", K, False).lower(
+        _x4(four_chips, (batch, R), jnp.float32, P()),
+        _x4(four_chips, (N_ITEMS_X4, R), jnp.float32, P("data", None)),
+        n_valid=N_ITEMS_X4).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the scan is the Pallas kernel"
+    collectives = re.findall(
+        r" (all-gather|all-reduce|reduce-scatter|all-to-all|"
+        r"collective-permute)(?:-start)?\(", text)
+    assert set(collectives) == {"all-gather"}, collectives
+    gathered = re.findall(r"= (s32\[[\d,]+\])\S* all-gather", text)
+    assert gathered == [f"s32[2,{batch},{4 * K}]"], gathered
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes < 6.2e9, "its own shard alone"
+    assert memory.temp_size_in_bytes < 0.2e9
+
+
+def test_parity_and_the_coded_scan_fit_a_chip_beside_the_shard(
+        four_chips, as_on_the_chip):
+    """The parity built and a late shard rebuilt a row chunk at a time:
+    beside the shard and the parity (12.34 GB) under 0.5 GB of
+    temporaries, where one whole-shard sum would add 6.17 GB."""
+    from jax.sharding import PartitionSpec as P
+
+    from predictionio_tpu.ops import distributed_topk
+    from predictionio_tpu.parallel.coded import build_parity_fn
+
+    table = _x4(four_chips, (N_ITEMS_X4, R), jnp.float32, P("data", None))
+    parity = build_parity_fn(four_chips).lower(table).compile()
+    memory = parity.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.5e9
+    assert (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            + memory.temp_size_in_bytes) < CHIP_BYTES
+    coded = distributed_topk._sharded_callable(
+        four_chips, "data", K, True).lower(
+        _x4(four_chips, (64, R), jnp.float32, P()), table,
+        _x4(four_chips, (N_ITEMS_X4 // 4, R), jnp.float32, P()),
+        _x4(four_chips, (4,), jnp.float32, P()),
+        n_valid=N_ITEMS_X4).compile()
+    memory = coded.memory_analysis()
+    assert 12.3e9 < memory.argument_size_in_bytes < 12.4e9
+    assert memory.temp_size_in_bytes < 0.5e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes) < CHIP_BYTES
+    assert "collective-permute" not in coded.as_text()

@@ -213,6 +213,13 @@ MICROBATCH_ADMISSION_TOTAL = _registry.counter(
     "without ever reaching the device",
     labels=("outcome",),
 )
+BATCH_TURNS_TOTAL = _registry.counter(
+    "pio_batch_turns_total",
+    "Turns of the batch dispatcher by whether their batch was "
+    "dispatched while another turn's batch was still on the device "
+    "(ahead=yes: launched ahead, its prepare under the other's scan)",
+    labels=("ahead",),
+)
 MICROBATCH_TENANTS_PER_BATCH = _registry.histogram(
     "pio_microbatch_tenants_per_batch",
     "Distinct tenants coalesced into one shared-batcher dispatcher "
@@ -246,6 +253,8 @@ def register_segment_family(family: str, histogram_family,
 
 
 register_segment_family("batch", BATCH_TURN_SECONDS, BATCH_SEGMENTS)
+_TURNS_AHEAD = {True: BATCH_TURNS_TOTAL.labels(ahead="yes"),
+                False: BATCH_TURNS_TOTAL.labels(ahead="no")}
 
 SERVE_INFLIGHT.child()
 MICROBATCH_QUEUE_DEPTH.child()
@@ -379,8 +388,11 @@ def batch_turns() -> list:
     number ``turn``, ``t0`` (``perf_counter``), ``rows`` and ``padded``
     rows sent to the device, per segment ``wall`` and thread-``cpu``
     seconds, ``gcSec`` the collector took on the turn's thread, the
-    ``requests`` its completion callbacks answered and ``parts``, the
-    wall seconds of ``complete`` summed over them by what was done."""
+    ``requests`` its completion callbacks answered, ``parts``, the
+    wall seconds of ``complete`` summed over them by what was done, and
+    ``ahead``, whether its batch was dispatched while another turn's was
+    still on the device.  Two turns of one batcher may overlap in time,
+    each on its own thread."""
     return list(_TURNS)
 
 
@@ -401,10 +413,16 @@ class Turn(Timeline):
     summed over the turn's ``requests`` (:meth:`open_part` before each,
     :func:`mark_part` at each step's end, the names :data:`TURN_PARTS`).
     Their sum stays under ``complete``; what is left is the batcher's
-    own loop."""
+    own loop.
+
+    ``watch``, where the batcher sets it, is called with a segment's
+    name each time a scope of that segment closes without an exception,
+    on the turn's own thread: it is how the batcher learns, from inside
+    a batch function it only calls, that the function's ``dispatch`` has
+    enqueued the device work and when its ``fetch`` has the results."""
 
     __slots__ = ("cpu", "rows", "padded", "gc_s", "parts", "requests",
-                 "_c0", "_wall", "_cpu", "_part")
+                 "ahead", "watch", "_c0", "_wall", "_cpu", "_part")
 
     PREFIX = "pio.turn."    # + segment: the scopes that book themselves
 
@@ -416,6 +434,8 @@ class Turn(Timeline):
         self.gc_s = 0.0
         self.parts = dict.fromkeys(TURN_PARTS, 0.0)
         self.requests = 0
+        self.ahead = False
+        self.watch = None
         self._c0 = time.thread_time()
         self._wall = self._cpu = 0.0   # booked so far, all segments
         self._part = self.t0           # where the open part began
@@ -441,7 +461,9 @@ class Turn(Timeline):
             "padded": self.padded, "wall": dict(self.segments),
             "cpu": dict(self.cpu), "gcSec": self.gc_s,
             "requests": self.requests, "parts": dict(self.parts),
+            "ahead": self.ahead,
         })
+        _TURNS_AHEAD[self.ahead].inc()
         return super().finish()
 
 
@@ -591,11 +613,13 @@ class annotate:
         if tl is not None:
             wall = time.perf_counter() - self._t0
             cpu = time.thread_time() - self._c0
-            tl.book(self.name[len(Turn.PREFIX):],
-                    wall - (tl._wall - self._w0),
+            segment = self.name[len(Turn.PREFIX):]
+            tl.book(segment, wall - (tl._wall - self._w0),
                     cpu - (tl._cpu - self._u0))
         if self._cm is not None:
             self._cm.__exit__(*exc)
+        if tl is not None and tl.watch is not None and exc[0] is None:
+            tl.watch(segment)
 
 
 # -- on-demand jax.profiler capture ----------------------------------------

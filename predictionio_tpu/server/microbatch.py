@@ -12,16 +12,28 @@ unchanged QPS, bench_serving.py --threads).
 The TPU-shaped fix is to make concurrency *wider, not deeper*: coalesce
 the queries that arrive while a device call is in flight into ONE
 batched call (`Algorithm.batch_predict` — a [B, R] x [R, M] matmul
-costs barely more than the [R] x [R, M] one).  ONE thread leads every
-turn: the lazily-started dispatcher claims whatever is pending the
-moment the device frees up, runs the batch, and completes its entries.
-Two ways in share its pending queue:
+costs barely more than the [R] x [R, M] one).  A *turn* claims whatever
+is pending, runs the batch and completes its entries, led by one of at
+most two lazily-started dispatcher threads.  **The two-turn rule:** at
+most one turn is between its claim and the close of its batch
+function's ``pio.turn.dispatch`` scope (it holds the *launch token*), so
+batches reach the device in claim order; at most two turns are live.
+The token passes at the close of ``dispatch`` — the device work is
+enqueued there — only while the batcher's own turns show the device part
+of a turn (dispatch end to fetch return) longer than its host part
+(claim to dispatch end, plus fetch return to the last completion): the
+next turn then claims when the batch on the device is expected to end
+within one claim-to-dispatch, or at once when its fetch returns, so its
+prepare and the other's complete run under the chip's scan.  Otherwise,
+and always for a batch function that books no ``dispatch``, the token
+passes at the turn's end, and ONE thread leads every turn back to back.
+Two ways in share the pending queue:
 
 * **Continuous** ``submit_nowait(x, on_done, ...)`` — the event-loop
   edge admits requests *into the queue as they arrive* and returns
-  immediately; the dispatcher fires per-entry completion callbacks.  No
-  thread ever parks per request: the edge stays one loop thread + one
-  dispatcher regardless of concurrency.
+  immediately; the turn's leader fires per-entry completion callbacks.
+  No thread ever parks per request: the edge stays one loop thread + at
+  most two dispatchers regardless of concurrency.
 * **Blocking** ``submit(x)`` — the same admission, then the calling
   thread parks until the dispatcher has completed its entry and returns
   the result (or raises) there.  The in-process callers' form
@@ -37,8 +49,9 @@ queue+service time so the serving edge can reject a request that
 cannot make its SLO *up front* as a structured 503
 (:class:`AdmissionRejected`) rather than queue it to die.
 
-What the dispatcher does between two device calls is one
-``obs/timeline.Turn`` per claim: every
+What a leader does from one claim to its next is one
+``obs/timeline.Turn`` per claim (two may overlap, each on its own
+thread and booking only its own scopes): every
 step below runs under an ``annotate("pio.turn.<segment>")`` (park, claim,
 fetch, complete; an engine's ``batch_predict`` may carve prepare /
 dispatch / decode out of fetch), which is at once a span in any
@@ -208,6 +221,35 @@ class _Entry:
         self.t_run1 = None
 
 
+class _Launch:
+    """One live turn as the launch rule sees it, and that turn's
+    ``Turn.watch``: when it claimed, when its batch function's
+    ``dispatch`` closed (``t_sent``: the device work is enqueued) and
+    when its ``fetch`` returned (``t_back``).  Only the closes of the
+    turn's last execution group count (``final``): a turn of two models
+    has launched once both are enqueued.  ``ahead``: it was dispatched
+    while another turn's batch was still on the device."""
+
+    __slots__ = ("batcher", "t_claim", "t_sent", "t_back", "final",
+                 "ahead")
+
+    def __init__(self, batcher: "MicroBatcher"):
+        self.batcher = batcher
+        self.t_claim = time.perf_counter()
+        self.t_sent = self.t_back = None
+        self.final = False
+        self.ahead = False
+
+    def __call__(self, segment: str) -> None:
+        if not self.final:
+            return
+        if segment == "dispatch" and self.t_sent is None:
+            self.batcher._sent(self)
+        elif segment == "fetch" and self.t_sent is not None \
+                and self.t_back is None:
+            self.batcher._back(self)
+
+
 class MicroBatcher:
     """Coalesce concurrent ``submit(x)`` / ``submit_nowait(x, cb)``
     calls into ``batch_fn([x...])``.
@@ -246,19 +288,34 @@ class MicroBatcher:
         self._cond = TimedCondition("microbatch")
         self._pending: list[_Entry] = []
         self._closed = False
-        self._dispatcher_alive = False
-        # a claimed batch is executing: the admission estimate counts
-        # it as one batch ahead of a new arrival
-        self._in_turn = False
+        # dispatcher threads alive (at most two: the two-turn rule), and
+        # those waiting to claim (at most one: a leader that ends its
+        # turn with another already waiting exits)
+        self._leaders = 0
+        self._parked = 0
+        # turns claimed and not yet completed (at most two); the
+        # admission estimate counts each as one batch ahead of a new
+        # arrival
+        self._in_turn = 0
+        # the launch token: the _Launch between its claim and its
+        # dispatch's close, or None
+        self._sending: Optional[_Launch] = None
+        # launches whose batch is on the device (dispatch closed, fetch
+        # not returned), in claim order; when the latest fetch returned
+        self._on_device: list[_Launch] = []
+        self._t_back = 0.0
         # EWMA of recent device-batch service time: the admission
         # estimator's input.  Seeded 0 (= "no evidence, admit"), so a
         # cold batcher never sheds; mutated only under _cond.
         self._ewma = EwmaEstimator()
-        # full service time of the last turn (all execution groups
-        # back-to-back) — what the EWMA observes; written by _run_batch
-        # on the dispatcher, read by _lead there under the re-acquired
-        # lock
-        self._turn_s = 0.0
+        # the launch rule's inputs, from the turns' own stamps: a
+        # batch's device service time (from the later of its dispatch's
+        # close and the previous batch's fetch return, to its own fetch
+        # return), claim to dispatch close, and the host part of a turn
+        # (that plus fetch return to the turn's end)
+        self._ewma_device = EwmaEstimator()
+        self._ewma_launch = EwmaEstimator()
+        self._ewma_host = EwmaEstimator()
         # observability: how the batcher is actually coalescing.
         # Mutated only under _cond; read through stats() (bare reads
         # tore under concurrency — serving status JSON and the benches
@@ -268,11 +325,12 @@ class MicroBatcher:
         self.max_seen = 0
         self.dispatched = 0
         self.expired = 0
+        self.ahead_turns = 0
 
     def reset_stats(self) -> None:
         with self._cond:
             self.batches = self.requests = self.max_seen = 0
-            self.dispatched = self.expired = 0
+            self.dispatched = self.expired = self.ahead_turns = 0
 
     def stats(self) -> dict:
         """Locked snapshot of the coalescing counters plus the live
@@ -286,21 +344,22 @@ class MicroBatcher:
                 "dispatched": self.dispatched,
                 "expired": self.expired,
                 "queueDepth": len(self._pending),
-                "dispatcher": self._dispatcher_alive,
+                "dispatcher": self._leaders > 0,
                 "ewmaBatchSec": self._ewma.value,
+                "aheadTurns": self.ahead_turns,
             }
 
     # -- admission (pio-surge) ---------------------------------------------
     def estimate_wait_s(self) -> float:
         """Estimated queue + service time a request admitted NOW would
-        experience: (in-flight batch + queued batches ahead + its own
+        experience: (in-flight batches + queued batches ahead + its own
         batch) x the EWMA batch service time.  0.0 until the first
         batch completes — no evidence means admit, never shed."""
         with self._cond:
             ew = self._ewma.value
             if ew <= 0.0:
                 return 0.0
-            ahead = 1.0 if self._in_turn else 0.0
+            ahead = float(self._in_turn)
             ahead += len(self._pending) / float(self.max_batch)
             return (ahead + 1.0) * ew
 
@@ -380,15 +439,18 @@ class MicroBatcher:
         self._cond.notify_all()
 
     def _ensure_dispatcher_locked(self) -> None:
-        if not self._dispatcher_alive:
-            self._dispatcher_alive = True
-            threading.Thread(
-                target=self._dispatch_loop, daemon=True,
-                name="microbatch-dispatch",
-            ).start()
+        if not self._leaders:
+            self._start_leader_locked()
+
+    def _start_leader_locked(self) -> None:
+        self._leaders += 1
+        threading.Thread(
+            target=self._dispatch_loop, daemon=True,
+            name="microbatch-dispatch",
+        ).start()
 
     def close(self) -> None:
-        """Stop accepting ``submit_nowait`` work and let the dispatcher
+        """Stop accepting ``submit_nowait`` work and let the dispatchers
         drain what is pending, then exit."""
         with self._cond:
             self._closed = True
@@ -404,37 +466,125 @@ class MicroBatcher:
         _m_queue_depth.set(float(len(self._pending)))
         return batch
 
+    def _claim_wait_locked(self) -> Optional[float]:
+        """Seconds until a parked leader may claim under the two-turn
+        rule: 0.0 now, None until woken (nothing pending, the launch
+        token held, or two turns live)."""
+        if not self._pending or self._sending is not None \
+                or self._in_turn >= 2:
+            return None
+        if not self._on_device:
+            return 0.0
+        # one turn is live, launched ahead, its batch on the device:
+        # claim when that batch is expected to end within one
+        # claim-to-dispatch, so this one is queued as the chip frees up
+        last = self._on_device[-1]
+        due = (max(last.t_sent, self._t_back) + self._ewma_device.value
+               - self._ewma_launch.value)
+        return max(due - time.perf_counter(), 0.0)
+
+    def _sent(self, launch: _Launch) -> None:
+        """A turn's ``dispatch`` closed (its ``Turn.watch``, on its own
+        thread, lock not held): the batch is on the device's queue.
+        The launch token passes on here while the device part of the
+        batcher's turns outlasts their host part, and a second leader
+        is started if none is waiting to claim."""
+        now = time.perf_counter()
+        with self._cond:
+            launch.t_sent = now
+            launch.ahead = bool(self._on_device)
+            self._on_device.append(launch)
+            self._ewma_launch.observe(now - launch.t_claim)
+            host = self._ewma_host.value
+            if self._sending is launch \
+                    and 0.0 < host < self._ewma_device.value:
+                self._sending = None
+                if not self._parked and self._leaders < 2:
+                    self._start_leader_locked()
+                self._cond.notify_all()
+
+    def _back(self, launch: _Launch) -> None:
+        """A launched turn's ``fetch`` returned: its batch is off the
+        device."""
+        now = time.perf_counter()
+        with self._cond:
+            launch.t_back = now
+            service = now - max(launch.t_sent, self._t_back)
+            self._t_back = max(self._t_back, now)
+            self._on_device.remove(launch)
+            if service > 0.0:
+                # one stall of the host (a collector pause, a thread off
+                # its CPU) reads as one long service time: a sample
+                # counts for at most twice the estimate, so one stall
+                # does not switch the rule, while a real change of the
+                # device part shows within a few turns
+                dev = self._ewma_device
+                dev.observe(min(service, 2.0 * dev.value) if dev.value
+                            else service)
+            self._cond.notify_all()
+
+    def _end_turn_locked(self, launch: _Launch) -> None:
+        now = time.perf_counter()
+        self._in_turn -= 1
+        if self._sending is launch:
+            self._sending = None
+        if launch in self._on_device:   # dispatched, fetch failed
+            self._on_device.remove(launch)
+        if launch.t_back is not None:
+            self._ewma_host.observe(
+                (launch.t_sent - launch.t_claim) + (now - launch.t_back))
+        if launch.ahead:
+            self.ahead_turns += 1
+        self._cond.notify_all()
+
     def _dispatch_loop(self) -> None:
-        """The one thread that leads turns: claims pending entries
-        whenever the device is free, until closed and drained."""
+        """One leader: claims pending entries whenever the two-turn rule
+        lets it (:meth:`_claim_wait_locked`), until closed and drained,
+        or until it ends a turn with another leader waiting to claim:
+        one waiting leader is enough, so a batcher whose turns do not
+        overlap is led by one thread again."""
         register_thread_role("microbatch_dispatcher")
         with self._cond:
             try:
                 while True:
+                    if self._parked:
+                        break   # another leader waits to claim already
                     turn = Turn()
                     with timeline_scope(turn):
-                        with annotate("pio.turn.park"):
-                            while not self._pending and not self._closed:
-                                self._cond.wait()
+                        self._parked += 1
+                        try:
+                            with annotate("pio.turn.park"):
+                                while self._pending or not self._closed:
+                                    wait = self._claim_wait_locked()
+                                    if wait == 0.0:
+                                        break
+                                    self._cond.wait(wait)
+                        finally:
+                            self._parked -= 1
                         if not self._pending:
                             break   # closed and drained
-                        with annotate("pio.turn.claim"):
-                            batch = self._claim_locked()
+                        launch = turn.watch = _Launch(self)
+                        self._sending = launch
+                        self._in_turn += 1
                         try:
-                            self._lead(batch)
+                            with annotate("pio.turn.claim"):
+                                batch = self._claim_locked()
+                            self._lead(batch, launch)
                         except Exception:
                             # _lead's finally already completed the
                             # batch; the dispatcher itself must survive
                             logger.exception("microbatch dispatcher error")
                         finally:
+                            self._end_turn_locked(launch)
+                            turn.ahead = launch.ahead
                             turn.finish()
             finally:
-                self._dispatcher_alive = False
-                if self._pending:
+                self._leaders -= 1
+                if self._pending and not self._leaders:
                     # killed by a BaseException with entries still
                     # queued: a successor claims them, or their callers
                     # would wait for a submit that may never come
-                    self._ensure_dispatcher_locked()
+                    self._start_leader_locked()
                 self._cond.notify_all()
 
     def _book_timeline(self, entry: _Entry, tl) -> None:
@@ -457,9 +607,9 @@ class MicroBatcher:
                     parts.append(("device", entry.t_run1 - entry.t_run0))
         tl.add_block(parts, residual_to="device")
 
-    def _lead(self, batch: list[_Entry]) -> None:
-        """Run one claimed batch on the dispatcher.  Called with the
-        lock HELD; releases it around the device call (and around
+    def _lead(self, batch: list[_Entry], launch: _Launch) -> None:
+        """Run one claimed batch on its leader.  Called with the lock
+        HELD; releases it around the device call (and around
         continuous-path callbacks) and re-acquires.
 
         Claim-time deadline enforcement happens here: entries already
@@ -472,10 +622,10 @@ class MicroBatcher:
         lock state is consistent) must still mark every claimed entry
         done, or its blocking caller parks forever and its event-loop
         request is never answered."""
-        self._in_turn = True
         completed = False
         live: list[_Entry] = []
         n_expired = 0
+        turn_s = 0.0
         with annotate("pio.turn.claim"):
             for e in batch:
                 if e.deadline is not None and e.deadline.expired:
@@ -516,7 +666,7 @@ class MicroBatcher:
             if live:
                 self._cond.release()
                 try:
-                    self._run_batch(live)
+                    turn_s = self._run_batch(live, launch)
                 finally:
                     self._cond.acquire()
             completed = True
@@ -532,15 +682,13 @@ class MicroBatcher:
                         "batch turn aborted before producing results"
                     )
                 e.done = True
-            self._in_turn = False
             if live:
                 self.batches += 1
                 self.max_seen = max(self.max_seen, len(live))
                 # the estimator tracks the FULL turn (every execution
                 # group back-to-back), not just the first group's call
-                if self._turn_s > 0.0:
-                    self._ewma.observe(self._turn_s)
-                    self._turn_s = 0.0
+                if turn_s > 0.0:
+                    self._ewma.observe(turn_s)
             self.requests += len(batch)
             self.expired += n_expired
             # continuous entries: completed by callback on this thread,
@@ -585,9 +733,9 @@ class MicroBatcher:
             g[1].append(e)
         return [by_fn[k] for k in order]
 
-    def _run_batch(self, batch: list[_Entry]) -> None:
-        """Execute one claimed batch as its execution groups, measuring
-        the FULL turn (what the admission estimator predicts).
+    def _run_batch(self, batch: list[_Entry], launch: _Launch) -> float:
+        """Execute one claimed batch as its execution groups; returns
+        the FULL turn's seconds (what the admission estimator predicts).
 
         Each group's continuous-path callbacks fire the moment THAT
         group's device call returns — before the next group runs.  With
@@ -599,10 +747,11 @@ class MicroBatcher:
         t0 = time.perf_counter()
         with annotate("pio.turn.claim"):
             groups = self._group(batch)
-        for fn, entries in groups:
-            self._exec_group(fn, entries)
+        for i, (fn, entries) in enumerate(groups):
+            launch.final = i == len(groups) - 1
+            self._exec_group(fn, entries, launch)
             self._fire_callbacks(entries)
-        self._turn_s = max(time.perf_counter() - t0, 0.0)
+        return max(time.perf_counter() - t0, 0.0)
 
     def _fire_callbacks(self, entries: list[_Entry]) -> None:
         """Book timelines and fire continuous-path callbacks for
@@ -625,7 +774,8 @@ class MicroBatcher:
                     logger.exception(
                         "microbatch completion callback failed")
 
-    def _exec_group(self, fn: Callable, batch: list[_Entry]) -> None:
+    def _exec_group(self, fn: Callable, batch: list[_Entry],
+                    launch: _Launch) -> None:
         """Run one device call; on failure, isolate the blast radius.
 
         A batched device call is all-or-nothing, so one malformed query
@@ -633,7 +783,9 @@ class MicroBatcher:
         (per-request dispatch isolated such failures).  On a batch of
         >1 failing, re-run each item ALONE: good requests succeed, the
         bad one gets its own exception — same outcomes as unbatched
-        serving, paid only on the rare failure path.
+        serving, paid only on the rare failure path.  The retries launch
+        too, so they hold the launch token: no other turn's batch is
+        enqueued between them.
         """
         try:
             with annotate("pio.turn.claim"):
@@ -670,12 +822,25 @@ class MicroBatcher:
             if len(batch) == 1:
                 batch[0].error = exc
                 return
-            for e in batch:
-                try:
-                    (r,) = fn([e.item])
-                    e.value = r
-                except Exception as solo:  # noqa: BLE001
-                    e.error = solo
+            final, launch.final = launch.final, False
+            with self._cond:
+                held = self._sending is launch
+                while not held and self._sending is not None:
+                    self._cond.wait()
+                self._sending = launch
+            try:
+                for e in batch:
+                    try:
+                        (r,) = fn([e.item])
+                        e.value = r
+                    except Exception as solo:  # noqa: BLE001
+                        e.error = solo
+            finally:
+                launch.final = final
+                if not held:
+                    with self._cond:
+                        self._sending = None
+                        self._cond.notify_all()
 
 
 class SharedBatcher(MicroBatcher):
@@ -684,7 +849,9 @@ class SharedBatcher(MicroBatcher):
     A dispatcher a tenant would mean T threads each coalescing only 1/T
     of the traffic and competing for the single device queue.  This
     class keeps :class:`MicroBatcher`'s claim/run core (one pending
-    queue, one lazily-started dispatcher) and changes WHO gets claimed:
+    queue, its lazily-started leaders and the two-turn rule) and changes
+    WHO gets claimed (still under the one lock, whichever leader
+    claims):
 
     * **Claim-time weighted deficit round-robin across tenants.**  Each
       claim walks the tenants with pending entries in rotation order;

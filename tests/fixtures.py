@@ -7,6 +7,8 @@ it, so tests assert pipelines structurally.
 
 from __future__ import annotations
 
+import threading
+import time
 from dataclasses import dataclass
 
 from predictionio_tpu.controller import (
@@ -187,3 +189,63 @@ class ToyEventStore:
 class ExplodingStore:
     def __init__(self, conf):
         raise ValueError("boom from backend constructor")
+
+
+class FakeChip:
+    """A batch function over a device with ONE queue, for the batcher's
+    launch-ahead tests.  A call books ``pio.turn.prepare`` (a sleep of
+    ``prepare_s``), ``pio.turn.dispatch`` (enqueues ``device_s`` of work
+    behind whatever is queued), ``pio.turn.fetch`` (sleeps until its
+    work is done) and ``pio.turn.decode`` (``decode_s``), and answers
+    ``("ok", x)`` per item; with ``dispatch=False`` it books nothing and
+    sleeps through the same time.  ``slow`` maps an item to seconds its
+    batch takes on the device beyond ``device_s``.  ``fail`` maps an item to ``(stage,
+    exception class)``, raised in that stage of every call holding it.
+    ``calls`` keeps per call its turn's number, its items, its thread
+    and the stamps ``t_enter``, ``t_sent`` (inside ``dispatch``) and
+    ``t_back``."""
+
+    def __init__(self, device_s: float, prepare_s: float = 0.002,
+                 decode_s: float = 0.001, dispatch: bool = True,
+                 slow: dict | None = None, fail: dict | None = None):
+        self.device_s, self.prepare_s = device_s, prepare_s
+        self.decode_s, self.dispatch = decode_s, dispatch
+        self.slow, self.fail = slow or {}, fail or {}
+        self.calls: list[dict] = []
+        self._lock = threading.Lock()
+        self._free_at = 0.0
+
+    def _fault(self, xs, stage: str) -> None:
+        for x in xs:
+            hit = self.fail.get(x)
+            if hit is not None and hit[0] == stage:
+                raise hit[1](f"{x!r} fails in {stage}")
+
+    def __call__(self, xs):
+        from predictionio_tpu.obs.timeline import annotate, current_timeline
+
+        turn = current_timeline()
+        call = {"turn": getattr(turn, "turn", None), "items": list(xs),
+                "thread": threading.get_ident(),
+                "t_enter": time.perf_counter()}
+        self.calls.append(call)
+        if not self.dispatch:
+            time.sleep(self.prepare_s + self.device_s + self.decode_s)
+            return [("ok", x) for x in xs]
+        with annotate("pio.turn.prepare"):
+            time.sleep(self.prepare_s)
+            self._fault(xs, "prepare")
+        with annotate("pio.turn.dispatch"):
+            work = self.device_s + sum(self.slow.get(x, 0.0) for x in xs)
+            with self._lock:
+                done = max(time.perf_counter(), self._free_at) + work
+                self._free_at = done
+            call["t_sent"] = time.perf_counter()
+        with annotate("pio.turn.fetch"):
+            time.sleep(max(done - time.perf_counter(), 0.0))
+            self._fault(xs, "fetch")
+        call["t_back"] = time.perf_counter()
+        with annotate("pio.turn.decode"):
+            time.sleep(self.decode_s)
+            self._fault(xs, "decode")
+        return [("ok", x) for x in xs]

@@ -8,7 +8,7 @@ import time
 
 import pytest
 
-from predictionio_tpu.server.microbatch import MicroBatcher
+from predictionio_tpu.server.microbatch import _UNSET, MicroBatcher
 
 
 def test_sequential_results_match_direct():
@@ -741,3 +741,291 @@ def test_blocking_submit_runs_on_the_dispatcher_thread():
     assert not set(ran_on) & ({threading.current_thread()} | set(callers))
     assert b.stats()["requests"] == 33
     b.close()
+
+
+# -- launch-ahead turns (the two-turn rule) ----------------------------------
+
+
+def _stream(b, items, gap_s, timeout=20.0, hold=None):
+    """Admit `items` by callback, one every `gap_s` (`hold(x)`, where
+    given, first waits until `x` may go); per item its one answer as
+    (value, error, turn, t_claim, t_answered)."""
+    answers: dict = {}
+    lock = threading.Lock()
+    done = threading.Event()
+    n = len(items)
+
+    def on_done(e):
+        with lock:
+            answers.setdefault(e.item, []).append(
+                (e.value, e.error, e.turn, e.t_claim, time.perf_counter()))
+            if len(answers) == n:
+                done.set()
+
+    for x in items:
+        if hold is not None:
+            hold(x)
+        b.submit_nowait(x, on_done)
+        time.sleep(gap_s)
+    assert done.wait(timeout), f"{n - len(answers)} never answered"
+    time.sleep(0.05)    # room for a second, wrong answer to land
+    assert all(len(a) == 1 for a in answers.values()), "answered twice"
+    return {x: a[0] for x, a in answers.items()}
+
+
+def _turn_spans(answers) -> dict:
+    """Per turn, from its claim to its last answer."""
+    span = {}
+    for _, _, turn, t_claim, t_done in answers.values():
+        span[turn] = (t_claim, max(span.get(turn, (0.0, t_done))[1], t_done))
+    return span
+
+
+def _check_two_turn_rule(chip, answers) -> int:
+    """The two-turn rule over what a stream saw: no turn claims while
+    another is between its claim and its dispatch (a solo retry counts as
+    a launch from its own start), never more than two turns are live;
+    returns how many pairs of turns overlapped."""
+    span = _turn_spans(answers)
+    claim = {t: s[0] for t, s in span.items()}
+    end = {t: s[1] for t, s in span.items()}
+    launches, seen = [], set()
+    for call in chip.calls:
+        t = call["turn"]
+        start = call["t_enter"] if t in seen else claim[t]
+        seen.add(t)
+        if "t_sent" in call:
+            launches.append((start, call["t_sent"], t))
+    for a0, a1, ta in launches:
+        for b0, b1, tb in launches:
+            assert ta == tb or a1 < b0 or b1 < a0, (
+                f"turn {tb} launched inside turn {ta}'s launch")
+    edges = sorted([(claim[t], 1) for t in claim]
+                   + [(end[t], -1) for t in claim])
+    live = most = 0
+    for _, step in edges:
+        live += step
+        most = max(most, live)
+    assert most <= 2, f"{most} turns live at once"
+    turns = sorted(claim, key=claim.get)
+    return sum(1 for i, a in enumerate(turns) for b in turns[i + 1:]
+               if claim[b] < end[a])
+
+
+def _ahead_count() -> float:
+    from predictionio_tpu.obs.timeline import BATCH_TURNS_TOTAL
+
+    return BATCH_TURNS_TOTAL.labels(ahead="yes").value()
+
+
+@pytest.mark.parametrize("kind, engages", [
+    ("device-bound", True), ("host-bound", False),
+    ("host-bound-stall", False), ("no-dispatch", False),
+])
+def test_launch_ahead_engages_only_where_the_device_part_is_longer(
+        kind, engages):
+    """A batch function whose device part (fetch) outlasts its host part
+    has its next turn claimed and dispatched while its batch is on the
+    device; one whose host part is longer, or which books no `dispatch`,
+    runs one turn after the other on one thread, exactly as before.
+    Either way every entry is answered once, with its own answer.  The
+    batch of item 30 outlasts what the batcher expects of a batch, so
+    the turn claimed behind it is dispatched while it is still on the
+    device: launched ahead, and counted so.  One stall of a host-bound
+    batch's device part (0.3 s, some 75 of its batches) does not switch
+    the rule on."""
+    from fixtures import FakeChip
+
+    chip = {
+        "device-bound": FakeChip(0.03, prepare_s=0.003, slow={30: 0.1}),
+        "host-bound": FakeChip(0.004, prepare_s=0.025),
+        "host-bound-stall": FakeChip(0.004, prepare_s=0.025,
+                                          slow={30: 0.3}),
+        "no-dispatch": FakeChip(0.03, prepare_s=0.003, dispatch=False),
+    }[kind]
+    b = MicroBatcher(chip, max_batch=8)
+    counted = _ahead_count()
+    answers = _stream(b, list(range(60)), 0.003)
+    b.close()
+    assert {x: a[:2] for x, a in answers.items()} == {
+        x: (("ok", x), None) for x in range(60)}
+    overlapped = _check_two_turn_rule(chip, answers)
+    ahead = [_turn(n)["ahead"] for n in {a[2] for a in answers.values()}]
+    assert b.stats()["aheadTurns"] == sum(ahead)
+    assert _ahead_count() - counted >= sum(ahead)
+    if engages:
+        assert overlapped >= 2 and sum(ahead) >= 1
+    else:
+        assert overlapped == 0 and not any(ahead)
+        assert len({c["turn"] for c in chip.calls}) == len(chip.calls)
+
+
+def test_a_failing_overlapped_batch_is_retried_solo_and_spares_the_other():
+    """A batch that fails after its dispatch, with another turn live
+    beside it: its items are re-run one by one (the bad one alone
+    fails), the retries launch under the same rule, and the other turn's
+    entries get their answers."""
+    from fixtures import FakeChip
+
+    # the bad batch stays on the device long enough for the next turn
+    # to be claimed beside it
+    chip = FakeChip(0.03, prepare_s=0.003, slow={"bad": 0.15},
+                    fail={"bad": ("decode", ValueError)})
+    b = MicroBatcher(chip, max_batch=8)
+    items = list(range(30)) + ["bad"] + list(range(30, 60))
+    answers = _stream(b, items, 0.003)
+    b.close()
+    bad_turn = answers["bad"][2]
+    assert isinstance(answers["bad"][1], ValueError)
+    assert {x: a[:2] for x, a in answers.items() if x != "bad"} == {
+        x: (("ok", x), None) for x in items if x != "bad"}
+    claimed = sorted(str(x) for x, a in answers.items() if a[2] == bad_turn)
+    solo = sorted(str(c["items"][0]) for c in chip.calls
+                  if c["turn"] == bad_turn and len(c["items"]) == 1)
+    assert len(claimed) > 1 and solo == claimed
+    assert _check_two_turn_rule(chip, answers) >= 2
+    span = _turn_spans(answers)
+    b0, b1 = span.pop(bad_turn)
+    assert any(t0 < b1 and b0 < t1 for t0, t1 in span.values())
+
+
+def test_close_with_two_turns_live_drains_both():
+    """`close()` while two turns are live: both finish, the rest of the
+    queue is claimed and answered, and both leaders exit."""
+    from fixtures import FakeChip
+
+    chip = FakeChip(0.03, prepare_s=0.003)
+    b = MicroBatcher(chip, max_batch=4)
+    answers, done = {}, threading.Event()
+
+    def on_done(e):
+        answers[e.item] = e.value
+        if len(answers) == 40:
+            done.set()
+
+    for x in range(40):
+        b.submit_nowait(x, on_done)
+    deadline = time.time() + 10
+    while True:
+        with b._cond:
+            if b._in_turn == 2:
+                # both batches count ahead of a new arrival
+                ew = b._ewma.value
+                assert b.estimate_wait_s() == pytest.approx(
+                    (2 + len(b._pending) / 4 + 1) * ew)
+                b.close()
+                break
+        assert time.time() < deadline, "two turns were never live"
+        time.sleep(0.001)
+    assert done.wait(10)
+    assert answers == {x: ("ok", x) for x in range(40)}
+    while b.stats()["dispatcher"]:
+        assert time.time() < deadline + 10, "a leader never exited"
+        time.sleep(0.005)
+    assert b._leaders == 0 and b._in_turn == 0
+
+
+@pytest.mark.parametrize("in_turn", [0, 1, 2])
+def test_estimate_wait_counts_every_batch_ahead(in_turn):
+    """Every live turn is one batch ahead of a new arrival, the queue
+    adds its share of full batches, and the arrival's own is one more."""
+    b = MicroBatcher(lambda xs: list(xs), max_batch=4)
+    with b._cond:
+        b._ewma.value = 0.01
+        b._in_turn = in_turn
+        b._pending = [object()] * 6
+        assert b.estimate_wait_s() == pytest.approx(
+            (in_turn + 6 / 4 + 1) * 0.01)
+        b._pending, b._in_turn = [], 0
+
+
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+@pytest.mark.parametrize("stage", ["prepare", "fetch"])
+def test_a_base_exception_in_either_turn_answers_every_claimed_entry(stage):
+    """A BaseException in the turn being launched (prepare) or in the one
+    on the device (fetch): that turn's entries are answered with the
+    aborted-turn error, the other turn's with their answers, and the
+    batcher serves on."""
+    from fixtures import FakeChip
+
+    # the turn on the device beside the doomed one is slow: in `fetch`
+    # the doomed batch itself, in `prepare` the one before it, which is
+    # dispatched before "doomed" is admitted
+    slow = "doomed" if stage == "fetch" else 29
+    chip = FakeChip(0.03, prepare_s=0.003, slow={slow: 0.15},
+                    fail={"doomed": (stage, KeyboardInterrupt)})
+    b = MicroBatcher(chip, max_batch=8)
+    items = list(range(30)) + ["doomed"] + list(range(30, 60))
+
+    def hold(x):
+        deadline = time.time() + 10
+        while x == "doomed" and stage == "prepare" and not any(
+                29 in c["items"] and "t_sent" in c for c in chip.calls):
+            assert time.time() < deadline, "item 29 never dispatched"
+            time.sleep(0.001)
+
+    answers = _stream(b, items, 0.003, hold=hold)
+    doomed_turn = answers["doomed"][2]
+    for x, (value, error, turn, _, _) in answers.items():
+        if turn == doomed_turn:
+            assert isinstance(error, RuntimeError) and value is _UNSET, x
+            assert "aborted" in str(error)
+        else:
+            assert (value, error) == (("ok", x), None)
+    span = _turn_spans(answers)
+    d0, d1 = span.pop(doomed_turn)
+    assert any(t0 < d1 and d0 < t1 for t0, t1 in span.values())
+    assert b.submit(99) == ("ok", 99)
+    b.close()
+
+
+def test_launch_ahead_under_a_short_switch_interval_loses_no_entry():
+    """Stress: sixteen admitting threads, more than the machine's cores,
+    the interpreter switching threads every 10 us, a device-bound batch
+    function: every entry is answered exactly once, the two-turn rule
+    holds throughout, and the batcher's counts agree with the calls."""
+    import sys
+
+    from fixtures import FakeChip
+
+    chip = FakeChip(0.005, prepare_s=0.0005, decode_s=0.0002)
+    b = MicroBatcher(chip, max_batch=8)
+    answers: dict = {}
+    lock = threading.Lock()
+
+    def on_done(e):
+        with lock:
+            answers.setdefault(e.item, []).append(
+                (e.value, e.error, e.turn, e.t_claim, time.perf_counter()))
+
+    def admit(base):
+        for i in range(25):
+            b.submit_nowait(base + i, on_done)
+            time.sleep(0.0005)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=admit, args=(25 * n,))
+                   for n in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        assert not any(t.is_alive() for t in threads)
+        deadline = time.time() + 30
+        while len(answers) < 400:
+            assert time.time() < deadline, f"{400 - len(answers)} unanswered"
+            time.sleep(0.005)
+    finally:
+        sys.setswitchinterval(old)
+    time.sleep(0.05)
+    b.close()
+    assert all(len(a) == 1 for a in answers.values())
+    answers = {x: a[0] for x, a in answers.items()}
+    assert {x: a[:2] for x, a in answers.items()} == {
+        x: (("ok", x), None) for x in range(400)}
+    _check_two_turn_rule(chip, answers)
+    stats = b.stats()
+    assert stats["requests"] == 400 and stats["batches"] == len(chip.calls)

@@ -123,6 +123,37 @@ def test_zero_weight_tenant_still_drains():
     sb.close()
 
 
+def test_weighted_claims_split_3_to_1_across_two_leaders():
+    """The WDRR claim under launch-ahead turns: with a device-bound batch
+    function two leaders take turns claiming, each claim still under the
+    one lock, and every claim of 8 from deep queues weighted 3:1 takes 6
+    and 2."""
+    from fixtures import FakeChip
+
+    chip = FakeChip(0.02, prepare_s=0.002)
+    sb = SharedBatcher(max_batch=8, pad_batches=False)
+    vh = SharedBatcherView(sb, "heavy", chip, weight=3.0)
+    vl = SharedBatcherView(sb, "light", chip, weight=1.0)
+    out, ev, cb_for = _collector(80)
+    with sb._cond:    # every entry is pending before the first claim
+        for i in range(60):
+            vh.submit_nowait(("heavy", i), cb_for(("heavy", i)))
+        for i in range(20):
+            vl.submit_nowait(("light", i), cb_for(("light", i)))
+    assert ev.wait(20)
+    assert all(out[k] == (("ok", k), None) for k in out)
+    splits = [(sum(1 for t, _ in c["items"] if t == "heavy"),
+               sum(1 for t, _ in c["items"] if t == "light"))
+              for c in chip.calls]
+    assert splits == [(6, 2)] * 10
+    assert len({c["turn"] for c in chip.calls}) == 10
+    # two leaders, the next claimed while the last was on the device
+    assert len({c["thread"] for c in chip.calls}) == 2
+    assert any(b["t_enter"] < a["t_back"]
+               for a, b in zip(chip.calls, chip.calls[1:]))
+    vh.close(); vl.close(); sb.close()
+
+
 def test_hot_weight_update_reshapes_next_claim():
     """Flip the weights between claims: the split flips with them —
     the live-reconfiguration contract behind POST /tenants/weights."""

@@ -244,6 +244,84 @@ def test_mark_part_books_parts_inside_complete_and_leaves_the_segments():
     assert timeline_module._TURNS.maxlen == 16384
 
 
+def test_two_overlapping_turns_book_only_their_own_segments(monkeypatch):
+    """Two turns led at once on two threads, the second launched while
+    the first waits in `fetch`: each books only the scopes of its own
+    thread and still tiles its own wall time, and the perfbench reader
+    `turn_segment_ms` cuts both from the window of their batch spans."""
+    from perfbench import cells
+
+    a_sent, b_sent = threading.Event(), threading.Event()
+    rec, fn_span = {}, {}
+
+    def lead(name, body):
+        turn = Turn()
+        with timeline_scope(turn):
+            body(name)
+        t_end = time.perf_counter()
+        segs = turn.finish()
+        assert sum(segs.values()) == pytest.approx(t_end - turn.t0,
+                                                   abs=2e-4)
+        (rec[name],) = [t for t in batch_turns() if t["turn"] == turn.turn]
+
+    def first(name):
+        with annotate("pio.turn.park"):
+            time.sleep(0.002)
+        t0 = time.perf_counter()
+        with annotate("pio.turn.prepare"):
+            _busy(2.0)
+        with annotate("pio.turn.dispatch"):
+            a_sent.set()
+        with annotate("pio.turn.fetch"):
+            assert b_sent.wait(5)
+            time.sleep(0.01)
+        with annotate("pio.turn.decode"):
+            _busy(1.0)
+        fn_span[name] = (t0, time.perf_counter(), 1)
+
+    def second(name):
+        with annotate("pio.turn.park"):
+            assert a_sent.wait(5)
+        t0 = time.perf_counter()
+        with annotate("pio.turn.prepare"):
+            _busy(3.0)
+        with annotate("pio.turn.dispatch"):
+            b_sent.set()
+        with annotate("pio.turn.fetch"):
+            time.sleep(0.02)
+        fn_span[name] = (t0, time.perf_counter(), 1)
+
+    ta = threading.Thread(target=lead, args=("a", first))
+    ta.start()
+    assert a_sent.wait(5)
+    tb = threading.Thread(target=lead, args=("b", second))
+    tb.start()
+    ta.join(10)
+    tb.join(10)
+    a, b = rec["a"], rec["b"]
+    assert set(a["wall"]) == {"park", "prepare", "dispatch", "fetch",
+                              "decode", "complete"}
+    assert set(b["wall"]) == {"park", "prepare", "dispatch", "fetch",
+                              "complete"}
+    # the first turn's fetch holds the second's whole launch, which its
+    # own thread did not book
+    assert a["wall"]["fetch"] >= 0.01 + b["wall"]["prepare"] * 0.9
+    assert a["wall"]["prepare"] < b["wall"]["prepare"]
+    assert b["t0"] < a["t0"] + sum(a["wall"].values())
+    monkeypatch.setattr(timeline_module, "batch_turns", lambda: [a, b])
+    reader = cells.load_reader("turn_segment_ms")
+    run = {"batch_spans": [fn_span["a"], fn_span["b"]]}
+    # the first turn was parked before the window opened; the second
+    # began to wait inside it
+    for segs, want in ((["fetch"], a["wall"]["fetch"] + b["wall"]["fetch"]),
+                       (["park"], b["wall"]["park"]),
+                       (["prepare", "dispatch"],
+                        a["wall"]["prepare"] + a["wall"]["dispatch"]
+                        + b["wall"]["prepare"] + b["wall"]["dispatch"])):
+        assert reader(run, {"segments": segs}) == pytest.approx(
+            1e3 * want / 2)
+
+
 @pytest.mark.parametrize("scope", ["request", "none"])
 def test_mark_part_is_a_no_op_off_a_turn(scope):
     """Under a request's own timeline (a blocking `predict_json`, the aux
